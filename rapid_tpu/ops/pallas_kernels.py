@@ -24,18 +24,10 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from rapid_tpu.ops.hashing import mix32 as _mix32
-
-try:  # pallas is TPU/Mosaic-gated; keep import soft for CPU-only installs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover  # noqa: BLE001 — any import failure
-    # (missing extra, Mosaic ABI mismatch, partial install) means the same
-    # thing here: no pallas, fall back to the pure-JAX kernels.
-    _HAS_PALLAS = False
 
 _LANES = 128
 
@@ -192,36 +184,6 @@ def delivery_new_bits_pallas(
         interpret=interpret,
     )(blocked_rows, age_kn, epoch.astype(jnp.uint32))
     return out[:, :n]
-
-
-@functools.lru_cache(maxsize=1)
-def pallas_usable() -> bool:
-    """Smoke-test the Mosaic kernel once on tiny shapes: True iff the pallas
-    path compiles, runs, and classifies correctly on the current backend.
-
-    Callers that embed ``use_pallas=True`` inside a LARGER jitted program
-    (the engine) cannot catch a Mosaic failure at their own compile time, so
-    they should consult this before opting in — the kernel is strictly an
-    optimization over the bit-identical jnp core. (``python -O`` safe: the
-    wrong-result check is a real branch, not an assert.)"""
-    if not (_HAS_PALLAS and jax.default_backend() == "tpu"):
-        return False
-    try:
-        # The engine's use_pallas flag gates the DELIVERY kernel, so fitness
-        # is the delivery kernel's alone. Smoke:
-        # k=3, one cohort word, all edges fired at round 0 and unblocked —
-        # every bit must deliver at age >= spread.
-        k = 3
-        blocked = jnp.zeros((k, 256), jnp.uint32)
-        age = jnp.full((k, 256), 9, jnp.int32)
-        bits = delivery_new_bits_pallas(
-            blocked, age, jnp.zeros((1,), jnp.uint32), k, 2, 1000
-        )
-        if int(bits[0, 0]) != (1 << k) - 1:
-            raise RuntimeError("delivery kernel missed matured alerts")
-        return True
-    except Exception:  # noqa: BLE001 — any kernel failure means "don't use it"
-        return False
 
 
 def reports_matrix_to_bits(reports: jnp.ndarray) -> jnp.ndarray:

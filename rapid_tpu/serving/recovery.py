@@ -90,9 +90,8 @@ def write_checkpoint(
 def latest_valid_checkpoint(directory) -> Tuple[Optional[Path], Optional[tuple], List[Path]]:
     """``(path, loaded, corrupt)``: the newest checkpoint that passes its
     integrity checks — with its ALREADY-LOADED ``load_serving_state``
-    tuple, so :func:`resume` never pays the deserialize+device-settle cost
-    twice (at the TPU drill shape the state load dominates the published
-    MTTR) — plus the corrupt files skipped on the way down (newest first).
+    tuple, so :func:`resume` never pays the deserialize cost twice (at the
+    TPU drill shape the state load dominates the published MTTR) — plus the corrupt files skipped on the way down (newest first).
     Corruption is a LOGGED fallback, never a crash — a torn tail must not
     strand the valid predecessor beneath it."""
     directory = Path(directory)

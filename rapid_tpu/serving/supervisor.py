@@ -64,9 +64,8 @@ from rapid_tpu.utils.ledger import LedgerEvent
 class SupervisorBudgets(NamedTuple):
     """The declared per-phase deadline table (milliseconds): how long each
     ticket-wait class may block before the supervisor declares the dispatch
-    wedged. Defaults are far above any healthy CPU/TPU dispatch and far
-    below the historical 240 s watchdog idle — a wedge is named in seconds,
-    not discovered by the session timeout."""
+    wedged. Defaults are far above any healthy CPU/TPU dispatch — a wedge
+    is named in seconds, not discovered by the session timeout."""
 
     submit_ms: float = 60_000.0  # backpressure wait on the oldest ticket
     drain_ms: float = 120_000.0  # the drain sweep's per-ticket waits

@@ -1,7 +1,7 @@
 """ctypes bridge to the native host-runtime library (native/rapid_native.cpp).
 
-Loads ``librapid_native.so`` if present (building it on first use when a
-toolchain is available), exposing batch ring-key construction and the
+Loads the library built from the source on disk if present (``ensure_built``
+builds it when a toolchain is available), exposing batch ring-key construction and the
 configuration-id fold. Every entry point has a pure-Python fallback producing
 bit-identical values; ``RAPID_TPU_NO_NATIVE=1`` disables the native path.
 """
@@ -9,6 +9,7 @@ bit-identical values; ``RAPID_TPU_NO_NATIVE=1`` disables the native path.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -20,16 +21,40 @@ import numpy as np
 LOG = logging.getLogger(__name__)
 
 _REPO_NATIVE_DIR = Path(__file__).resolve().parent.parent.parent / "native"
-_LIB_PATH = _REPO_NATIVE_DIR / "build" / "librapid_native.so"
 
 _lib: Optional[ctypes.CDLL] = None
 _attempted = False
 
 
-def _try_build() -> bool:
-    makefile = _REPO_NATIVE_DIR / "Makefile"
-    if not makefile.exists():
+def _lib_path() -> Optional[Path]:
+    """Where the library built from the source on disk lives, or None when
+    there is no source. The name carries a hash of ``rapid_native.cpp`` (the
+    Makefile computes the same one): ``native/build/`` is git-ignored, so a
+    copied tree can hold a binary no commit describes, and keying the name to
+    the source means such a binary is simply never the one loaded."""
+    try:
+        source = (_REPO_NATIVE_DIR / "rapid_native.cpp").read_bytes()
+    except OSError:
+        return None
+    digest = hashlib.sha256(source).hexdigest()[:16]
+    return _REPO_NATIVE_DIR / "build" / f"librapid_native-{digest}.so"
+
+
+def ensure_built() -> bool:
+    """Bring the native library up to date with ``native/rapid_native.cpp``.
+
+    Always runs ``make``, which is a no-op when the library for this source
+    exists and otherwise builds it and clears out any other (see
+    ``_lib_path``). Call from setup paths (bench, test session start,
+    packaging) — never from the event loop: the compile can take tens of
+    seconds and would stall the protocol."""
+    global _attempted
+    if os.environ.get("RAPID_TPU_NO_NATIVE"):
         return False
+    lib_path = _lib_path()
+    if lib_path is None or not (_REPO_NATIVE_DIR / "Makefile").exists():
+        return False
+    _attempted = False  # allow get_lib to pick up a fresh build
     try:
         subprocess.run(
             ["make", "-C", str(_REPO_NATIVE_DIR)],
@@ -37,24 +62,11 @@ def _try_build() -> bool:
             capture_output=True,
             timeout=120,
         )
-        return _LIB_PATH.exists()
-    except Exception as exc:  # noqa: BLE001 — any build failure means fallback
-        LOG.debug("native build failed: %r", exc)
+    except (OSError, subprocess.SubprocessError) as exc:
+        # No toolchain (or a failed compile) means the Python twin runs.
+        LOG.warning("native build failed, using the Python twin: %r", exc)
         return False
-
-
-def ensure_built() -> bool:
-    """Build the native library if missing. Call from setup paths (bench,
-    test session start, packaging) — never from the event loop: the compile
-    can take tens of seconds and would stall the protocol."""
-    global _attempted
-    if os.environ.get("RAPID_TPU_NO_NATIVE"):
-        return False
-    if _LIB_PATH.exists():
-        return True
-    built = _try_build()
-    _attempted = False  # allow get_lib to pick up a fresh build
-    return built
+    return lib_path.exists()
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -66,10 +78,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
     _attempted = True
     if os.environ.get("RAPID_TPU_NO_NATIVE"):
         return None
-    if not _LIB_PATH.exists():
+    lib_path = _lib_path()
+    if lib_path is None or not lib_path.exists():
         return None
     try:
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        lib = ctypes.CDLL(str(lib_path))
         lib.rapid_xxh64.restype = ctypes.c_uint64
         lib.rapid_xxh64.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64]
         lib.rapid_ring_key.restype = ctypes.c_uint64

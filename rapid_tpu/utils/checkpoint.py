@@ -255,28 +255,6 @@ def _open_npz(path) -> _LoadedNpz:
         ) from exc
 
 
-def _settle_device_owned(tree):
-    """Copy every leaf of a just-loaded pytree into an executable-OWNED
-    device buffer (one jitted identity-copy, ~ms per load).
-
-    Hard-won (root-caused via the bench ``recovery`` drill; sibling note in
-    tools/analysis/device_program.py's cache scoping): on this jaxlib's CPU
-    backend, arrays materialized from host numpy buffers — exactly what a
-    checkpoint load produces — can later be DONATED into an engine
-    executable that was DESERIALIZED from the persistent compilation
-    cache, and the donation then frees memory the backend does not own:
-    an intermittent glibc double-free/segfault (~1 in 3 at the recovery
-    drill's shape). Buffers that are executable OUTPUTS are device-owned
-    and donation-safe, so every loader below routes its pytrees through
-    this copy before handing them to a driver."""
-    import jax
-    import jax.numpy as jnp
-
-    settled = jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))(tree)
-    jax.block_until_ready(settled)
-    return settled
-
-
 def save_engine_state(path, cfg: "EngineConfig", state: "EngineState") -> None:
     arrays = {field: np.asarray(value) for field, value in state._asdict().items()}
     # Derived data is never persisted: ring_perm is a pure function of the
@@ -365,7 +343,7 @@ def load_engine_state(path) -> Tuple["EngineConfig", "EngineState"]:
                 raise KeyError(
                     f"checkpoint missing field {field!r} with no known default"
                 )
-        state = _settle_device_owned(EngineState(**arrays))
+        state = EngineState(**arrays)
     return cfg, state
 
 
@@ -440,6 +418,4 @@ def load_serving_state(path):
             from rapid_tpu.tenancy.fleet import TenantKnobs
 
             knobs = tree(TenantKnobs, "knobs")
-        # None is an empty pytree: knobs settles through unchanged.
-        state, faults, knobs = _settle_device_owned((state, faults, knobs))
     return cfg, state, faults, knobs, meta

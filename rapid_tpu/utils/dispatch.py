@@ -61,7 +61,7 @@ class DispatchSeam:
         """Charge host->device uploads (indices, masks, initial state) to
         the transfer-byte counter. Host-side accounting at the driver seams:
         only arrays that originate on the host are charged, which is exactly
-        the traffic a remote-tunnel deployment pays for."""
+        the traffic that crosses the host-device link."""
         self.metrics.inc(
             "engine_h2d_bytes",
             int(sum(int(getattr(a, "nbytes", 0) or 0) for a in arrays)),

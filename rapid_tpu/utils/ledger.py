@@ -21,9 +21,9 @@ Line shape::
      ...fields}
 
 ``t_s`` is seconds since the *ledger object's* construction (monotonic);
-``wall`` is UTC wall clock for cross-run correlation. The bench's parent
-watchdog and its child workload append to ONE file (O_APPEND line writes are
-atomic for these line sizes), correlated by ``run_id``/``pid``.
+``wall`` is UTC wall clock for cross-run correlation. Several writers may
+append to ONE file (O_APPEND line writes are atomic for these line sizes),
+correlated by ``run_id``/``pid``.
 """
 
 from __future__ import annotations
@@ -45,16 +45,11 @@ class LedgerEvent(Enum):
     RUN_BEGIN = "run_begin"
     RUN_END = "run_end"
     RUN_FAIL = "run_fail"
-    ATTEMPT_BEGIN = "attempt_begin"
-    ATTEMPT_END = "attempt_end"
     STAGE_BEGIN = "stage_begin"
     STAGE_END = "stage_end"
     STAGE_FAIL = "stage_fail"
-    HEARTBEAT_GAP = "heartbeat_gap"
     COMPILE_STATS = "compile_stats"
     DEVICE_MEMORY = "device_memory"
-    WATCHDOG_KILL = "watchdog_kill"
-    SNAPSHOT_REPLAY = "snapshot_replay"
     METRIC = "metric"
     # Self-healing serving runtime (rapid_tpu/serving/supervisor.py +
     # recovery.py): retry/backoff attempts, deadline wedges, checkpoint
@@ -70,8 +65,8 @@ class LedgerEvent(Enum):
 
 
 #: Registered stage names (parameterize via fields — e.g. ``n=`` — never by
-#: minting a new name): the vocabulary perfview's timeline and the parent
-#: watchdog's per-stage budgets are defined over.
+#: minting a new name): the vocabulary perfview's timeline and the bench's
+#: per-stage budgets are defined over.
 STAGE_NAMES = frozenset({
     "devices_init",
     "native_build",
@@ -164,8 +159,7 @@ class RunLedger:
         self.path = str(path)
         self.run_id = run_id or f"run-{os.getpid()}-{int(time.time())}"
         #: ``t_s`` epoch on the monotonic clock. A run spanning several
-        #: processes (watchdog parent + attempt children + a fallback
-        #: continuation) passes the FIRST writer's epoch along with the
+        #: processes passes the FIRST writer's epoch along with the
         #: run id, so every process's t_s lands on one shared timeline
         #: (CLOCK_MONOTONIC is system-wide per boot on the platforms this
         #: runs on).
@@ -174,7 +168,7 @@ class RunLedger:
         parent = os.path.dirname(os.path.abspath(self.path))
         os.makedirs(parent, exist_ok=True)
         # Line-buffered append: one write syscall per line (atomic at these
-        # sizes), so parent and child can share the file.
+        # sizes), so several writers can share the file.
         self._file = open(self.path, "a", buffering=1)
 
     def close(self) -> None:
@@ -211,8 +205,8 @@ class RunLedger:
     def stage(self, name: str, timeout_s: Optional[float] = None,
               **fields: Any):
         """One ledger-bracketed stage: ``stage_begin`` (carrying the
-        caller's per-stage timeout so the watchdog parent can enforce it
-        from the ledger alone), then ``stage_end`` with the measured
+        caller's per-stage budget so a reader sees an overrun from the
+        ledger alone), then ``stage_end`` with the measured
         duration — or ``stage_fail`` with the error, re-raised."""
         begin_fields = dict(fields)
         if timeout_s is not None:
@@ -272,8 +266,7 @@ def last_completed_stage(events: Sequence[Dict[str, Any]]) -> Optional[str]:
 
 def open_stage(events: Sequence[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
     """The latest ``stage_begin`` without a matching ``stage_end``/
-    ``stage_fail`` — the stage a wedged run is stuck in (the watchdog
-    parent's per-stage-timeout input)."""
+    ``stage_fail`` — the stage a killed or hung run was in."""
     open_begin: Optional[Dict[str, Any]] = None
     for record in events:
         event = record.get("event")

@@ -2,7 +2,7 @@
 
 Every spelling of the round-trip the fused-dispatch design exists to avoid,
 inside a ``*_impl`` function and the while-loop body it hands to lax: each
-one is a full tunnel RTT per round on a remote backend.
+one is a blocking host round trip per round on the device.
 """
 
 import numpy as np

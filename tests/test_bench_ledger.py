@@ -1,9 +1,8 @@
 """bench.py end-to-end through the run ledger: a CPU run emits a COMPLETE
 JSONL ledger (every stage bracketed, provenance stamped, metric + run_end
-recorded), and a wedged accelerator fails LOUDLY — nonzero exit with the
-ledger pointing at the last completed stage — unless snapshot replay or CPU
-fallback is explicitly authorized (the acceptance surface of ROADMAP open
-item 2's "fail loudly rather than silently replaying snapshots").
+recorded), and a run that finds no TPU and was not explicitly asked for the
+CPU smoke fails LOUDLY — nonzero exit, the ledger pointing at the stage it
+stopped in.
 """
 
 import json
@@ -15,13 +14,7 @@ from pathlib import Path
 import pytest
 
 import bench
-from rapid_tpu.utils.ledger import (
-    LedgerEvent,
-    RunLedger,
-    last_completed_stage,
-    open_stage,
-    read_ledger,
-)
+from rapid_tpu.utils.ledger import open_stage, read_ledger
 
 REPO = Path(__file__).resolve().parent.parent
 BENCH = str(REPO / "bench.py")
@@ -62,7 +55,7 @@ def _stage_pairs(events):
 
 
 def test_cpu_run_emits_complete_ledger(tmp_path):
-    """The acceptance criterion: a CPU-fallback bench run leaves a complete
+    """The acceptance criterion: an explicit CPU bench run leaves a complete
     ledger — every stage begin+end, provenance stamped, derived metrics
     plausible — and its JSON line agrees with the ledger's metric event.
     One subprocess run also pins the ISSUE-9 headline path: the xl_point
@@ -159,7 +152,7 @@ def test_cpu_run_emits_complete_ledger(tmp_path):
     for stage in ("xl_point", "stretch_point"):
         [(span_begin, close)] = pairs[stage]
         assert close["event"] == "stage_end"
-        assert span_begin["timeout_s"] > 0  # watchdog-enforced budget
+        assert span_begin["timeout_s"] > 0  # budget stamped for the reader
         assert span_begin["n"] == 256  # each point stage records its own N
     assert any(
         e["event"] == "device_memory" and e.get("stage") == "xl_point"
@@ -499,119 +492,6 @@ def test_parse_scale_spellings():
     assert bench._parse_scale("gibberish") == 0
 
 
-_WEDGE_ENV = {
-    "RAPID_TPU_BENCH_SIMULATE_WEDGE": "1",
-    "RAPID_TPU_BENCH_INIT_TIMEOUT_S": "2",
-    "RAPID_TPU_BENCH_ATTEMPTS": "1",
-}
-
-
-def test_wedge_exits_nonzero_without_allow_snapshot(tmp_path):
-    proc, events = _run_bench(
-        tmp_path, env_overrides=_WEDGE_ENV, drop=("JAX_PLATFORMS",),
-        timeout=120,
-    )
-    assert proc.returncode == 1
-    assert "no fallback authorized" in proc.stderr
-    # The one stdout JSON line is an explicit error, never a number.
-    [line] = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    error = json.loads(line)
-    assert error["error"] == "accelerator_wedged"
-    assert "last_completed_stage" in error
-    kinds = [e["event"] for e in events]
-    assert "watchdog_kill" in kinds
-    assert kinds[-1] == "run_fail"
-    [fail] = [e for e in events if e["event"] == "run_fail"]
-    assert fail["outcome"] == "wedged"
-    assert fail["last_completed_stage"] == last_completed_stage(events)
-    assert "snapshot_replay" not in kinds  # nothing replayed silently
-
-
-def test_wedge_failure_is_scoped_to_this_run(tmp_path):
-    # The default ledger path accumulates runs across invocations: a wedge
-    # with ZERO completed stages must report none — never a PREVIOUS run's
-    # last stage (and the watchdog must not inherit its open stages).
-    ledger_path = tmp_path / "ledger.jsonl"
-    old = RunLedger(str(ledger_path), run_id="previous-run")
-    old.emit(LedgerEvent.RUN_BEGIN, mode="inline")
-    with old.stage("state_build", timeout_s=900):
-        pass
-    old.emit(LedgerEvent.STAGE_BEGIN, stage="warmup_compile", timeout_s=900)
-    old.close()  # a previous run that died mid-warmup
-    proc, events = _run_bench(
-        tmp_path, env_overrides=_WEDGE_ENV, drop=("JAX_PLATFORMS",),
-        timeout=120,
-    )
-    assert proc.returncode == 1
-    [line] = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert json.loads(line)["last_completed_stage"] is None
-    [fail] = [e for e in events if e["event"] == "run_fail"
-              and e["run_id"] != "previous-run"]
-    assert fail["last_completed_stage"] is None
-
-
-@pytest.mark.slow
-def test_wedge_with_cpu_fallback_reruns_and_closes_the_run(tmp_path):
-    # --cpu-fallback: the watchdog parent execve's into a CPU continuation
-    # sharing the run id; the successful fallback must CLOSE the run
-    # (run_end outcome=cpu_fallback) — without it the ledger ends at
-    # run_fail and the run reads as failed despite a real measurement.
-    # Rides the unfiltered check.sh pass (~20 s wall: a second full bench
-    # subprocess); the wedge-exits-nonzero and snapshot-replay wedge tests
-    # keep the LOUD-failure contract in tier-1.
-    proc, events = _run_bench(
-        tmp_path, "--cpu-fallback",
-        env_overrides={
-            **_WEDGE_ENV,
-            "RAPID_TPU_BENCH_N": "256",
-            "RAPID_TPU_BENCH_XL_BUDGET_S": "0",
-        },
-        drop=("JAX_PLATFORMS",), timeout=240,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    [line] = [l for l in proc.stdout.splitlines()
-              if l.startswith("{") and '"metric"' in l]
-    assert json.loads(line)["platform"] == "cpu"
-    kinds = [e["event"] for e in events]
-    # The wedge is on record AND the run is closed by the fallback.
-    assert "run_fail" in kinds
-    assert kinds[-1] == "run_end"
-    [end] = [e for e in events if e["event"] == "run_end"]
-    assert end["outcome"] == "cpu_fallback"
-    assert len({e["run_id"] for e in events}) == 1  # one run, one id
-
-
-def test_wedge_with_allow_snapshot_replays_and_marks_ledger(tmp_path):
-    capture = tmp_path / "capture.json"
-    capture.write_text(json.dumps({
-        "metric": "churn_resolution_ms_n100000_churn5pct", "value": 100.9,
-        "unit": "ms", "platform": "tpu", "n_members": 100_000,
-        "captured_at": "2026-07-29T14:06:21Z", "vs_baseline": 4.957,
-    }))
-    proc, events = _run_bench(
-        tmp_path, "--allow-snapshot",
-        env_overrides={**_WEDGE_ENV, "RAPID_TPU_BENCH_SNAPSHOT": str(capture)},
-        drop=("JAX_PLATFORMS",), timeout=120,
-    )
-    assert proc.returncode == 0
-    [line] = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    replayed = json.loads(line)
-    # Unstamped capture: stale, renamed, demoted — and the ledger says so.
-    assert replayed["stale_code"] is True
-    assert replayed["metric"].endswith("_snapshot")
-    [mark] = [e for e in events if e["event"] == "snapshot_replay"]
-    assert mark["stale_code"] is True
-    assert mark["snapshot_path"]
-    # run_fail precedes the replay (the wedge stays on record), and the
-    # successful replay CLOSES the run — perfview's outcome is the latest
-    # terminal event, so an rc-0 replay must not read as FAILED.
-    kinds = [e["event"] for e in events]
-    assert kinds.index("run_fail") < kinds.index("snapshot_replay")
-    assert kinds[-1] == "run_end"
-    [end] = [e for e in events if e["event"] == "run_end"]
-    assert end["outcome"] == "snapshot_replay"
-
-
 def test_ledger_event_vocabulary_is_enforced_in_bench(tmp_path):
     # The runtime guard behind the lint rule: bench cannot invent events.
     from rapid_tpu.utils.ledger import RunLedger
@@ -630,15 +510,38 @@ def test_stage_timeouts_table_covers_all_stages():
 
 
 def test_parse_args_flags_and_env_aliases(monkeypatch):
-    for name in ("RAPID_TPU_BENCH_ALLOW_SNAPSHOT", "RAPID_TPU_BENCH_CPU_FALLBACK",
-                 "RAPID_TPU_BENCH_PROFILE"):
-        monkeypatch.delenv(name, raising=False)
+    monkeypatch.delenv("RAPID_TPU_BENCH_PROFILE", raising=False)
     args = bench._parse_args([])
-    assert not args.allow_snapshot and not args.cpu_fallback
-    assert args.profile is None
-    args = bench._parse_args(["--allow-snapshot", "--cpu-fallback",
-                              "--profile", "/tmp/prof", "--ledger", "x.jsonl"])
-    assert args.allow_snapshot and args.cpu_fallback
+    assert args.profile is None and args.ledger is None
+    args = bench._parse_args(["--profile", "/tmp/prof", "--ledger", "x.jsonl"])
     assert args.profile == "/tmp/prof" and args.ledger == "x.jsonl"
-    monkeypatch.setenv("RAPID_TPU_BENCH_ALLOW_SNAPSHOT", "1")
-    assert bench._parse_args([]).allow_snapshot
+    monkeypatch.setenv("RAPID_TPU_BENCH_PROFILE", "/tmp/envprof")
+    assert bench._parse_args([]).profile == "/tmp/envprof"
+
+
+def test_main_without_a_chip_or_an_explicit_cpu_request_fails(
+    tmp_path, monkeypatch, capsys
+):
+    """One process, chip or fail: on a non-TPU backend ``main`` returns
+    nonzero unless the CALLER set JAX_PLATFORMS=cpu — the ledger names the
+    stage it stopped in and no metric line is printed. (In-process: the
+    session's backend is the CPU mesh, so only the variable is removed.)"""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    ledger_path = tmp_path / "ledger.jsonl"
+    assert bench.main(["--ledger", str(ledger_path)]) != 0
+    captured = capsys.readouterr()
+    assert "not 'tpu'" in captured.err and "JAX_PLATFORMS=cpu" in captured.err
+    assert captured.out == ""
+    events, _ = read_ledger(str(ledger_path))
+    kinds = [e["event"] for e in events]
+    assert kinds[0] == "run_begin" and kinds[-1] == "run_fail"
+    [failed] = [e for e in events if e["event"] == "stage_fail"]
+    assert failed["stage"] == "devices_init"
+    assert "metric" not in kinds
+
+
+def test_bench_is_one_process():
+    # The chip belongs to the process that imports jax: bench starts no
+    # child, so the module has no use for subprocess at all.
+    assert not hasattr(bench, "subprocess")
+    assert "Popen" not in Path(BENCH).read_text()

@@ -102,15 +102,14 @@ def test_read_ledger_tolerates_torn_and_foreign_lines(tmp_path):
     events, skipped = read_ledger(str(path))
     assert [e["event"] for e in events] == ["run_begin"]
     assert skipped == 2
-    # A missing file reads as empty, never raises (the watchdog polls the
-    # ledger before the child has written anything).
+    # A missing file reads as empty, never raises (a reader may poll the
+    # ledger before the writer has written anything).
     assert read_ledger(str(tmp_path / "nope.jsonl")) == ([], 0)
 
 
 def test_shared_t0_puts_processes_on_one_timeline(tmp_path):
-    # A run spans several processes (watchdog parent, attempt children,
-    # fallback continuation); passing the first writer's epoch keeps every
-    # t_s on one timeline instead of restarting at 0 per process.
+    # A run may span several processes; passing the first writer's epoch
+    # keeps every t_s on one timeline instead of restarting at 0 per process.
     import time
 
     path = tmp_path / "run.jsonl"
@@ -118,7 +117,7 @@ def test_shared_t0_puts_processes_on_one_timeline(tmp_path):
     child = RunLedger(str(path), run_id="shared", t0=parent.t0)
     assert child.t0 == parent.t0
     later = RunLedger(str(path), run_id="shared", t0=time.monotonic() - 100.0)
-    later.emit(LedgerEvent.ATTEMPT_BEGIN, attempt=1)
+    later.emit(LedgerEvent.RUN_BEGIN)
     [event] = _events(path)
     assert event["t_s"] >= 100.0  # relative to the injected epoch
     parent.close()
@@ -127,14 +126,14 @@ def test_shared_t0_puts_processes_on_one_timeline(tmp_path):
 
 
 def test_two_writers_share_one_file(tmp_path):
-    # Parent watchdog + child workload append to the same ledger; the
-    # merged stream stays line-parseable and correlated by run_id.
+    # Two writers append to the same ledger; the merged stream stays
+    # line-parseable and correlated by run_id.
     path = tmp_path / "run.jsonl"
     parent = RunLedger(str(path), run_id="shared")
     child = RunLedger(str(path), run_id="shared")
     parent.emit(LedgerEvent.RUN_BEGIN)
     with child.stage("devices_init"):
-        parent.emit(LedgerEvent.ATTEMPT_BEGIN, attempt=1)
+        parent.emit(LedgerEvent.COMPILE_STATS, compiles=1)
     parent.emit(LedgerEvent.RUN_END, outcome="live")
     events, skipped = read_ledger(str(path))
     assert skipped == 0 and len(events) == 5

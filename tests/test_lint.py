@@ -139,7 +139,7 @@ def test_recorder_events_come_from_registered_enum():
 def test_ledger_events_come_from_registered_vocabulary():
     """Every run-ledger ``emit()`` call site in the library, bench.py, and
     tools/ must name its event as ``LedgerEvent.<member>`` — the registered
-    vocabulary tools/perfview.py's timeline rendering (and the watchdog's
+    vocabulary tools/perfview.py's timeline rendering (and the bench's
     per-stage budgets) are defined over. Mirror of the flight-recorder
     EventName rule above; the resolution-tier twin lives in
     tools/analysis/ledger.py (check_ledger) so the CLI gate catches it too.
@@ -235,10 +235,8 @@ def test_full_sweep_with_compiled_gate_stays_under_budget():
     for the family sweep itself, budgeted separately so neither can hide
     the other going superlinear. Collection results — base facts, ladder,
     AND dataflow payload — are cached per session, so only the FIRST
-    sweep in a process pays them (the persistent XLA cache is deliberately
-    NOT used for the audit — see
-    device_program._scoped_disable_persistent_cache); the identity
-    assertions pin that the session caches are real."""
+    sweep in a process pays them; the identity assertions pin that the
+    session caches are real."""
     import time
 
     import staticcheck

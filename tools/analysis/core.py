@@ -25,7 +25,8 @@ from typing import Iterable, List, Optional, Sequence, Tuple
 REPO = Path(__file__).resolve().parent.parent.parent
 
 DEFAULT_ROOTS = (
-    "rapid_tpu", "tests", "examples", "tools", "bench.py", "__graft_entry__.py"
+    "rapid_tpu", "tests", "examples", "tools", "bench.py", "chip_smoke.py",
+    "__graft_entry__.py",
 )
 
 #: Subtrees holding fixture DATA, not code under analysis: the seeded lint

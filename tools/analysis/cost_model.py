@@ -403,9 +403,7 @@ def collect_ladder(
     Returns ``name -> [{"key", "n_eff", "k", "facts"}, ...]``. The base
     point (N=256, K=4) reuses the session's ``collect_facts`` entry (which
     the HLO gate has usually already paid for); every other point compiles
-    fresh via ``build_ladder_spec`` with the persistent compilation cache
-    scoped OFF (the deserialized-executable heap corruption the HLO gate
-    documents applies to donated ladder compiles too). ``require_mesh``
+    via ``build_ladder_spec``. ``require_mesh``
     propagates to the base collection: the GATE needs the full registry
     (its quiescent block reads the sharded step), observational consumers
     (the bench on a single-chip backend) pass False and take whatever the
@@ -420,34 +418,33 @@ def collect_ladder(
             return table
     base_facts = device_program.collect_facts(require_mesh=require_mesh)
     table: Dict[str, List[Dict[str, Any]]] = {}
-    with device_program._scoped_disable_persistent_cache():
-        for name in COST_REGISTRY:
-            series: List[Dict[str, Any]] = []
-            for pt in ladder_points(name):
-                is_base = (
-                    "tenants" not in pt
-                    and pt["n"] == BASE_N
-                    and pt["k"] == BASE_K
-                    and name in base_facts
+    for name in COST_REGISTRY:
+        series: List[Dict[str, Any]] = []
+        for pt in ladder_points(name):
+            is_base = (
+                "tenants" not in pt
+                and pt["n"] == BASE_N
+                and pt["k"] == BASE_K
+                and name in base_facts
+            )
+            if is_base:
+                entry = base_facts[name]
+            else:
+                spec = device_program.build_ladder_spec(
+                    name, pt["n"], pt["k"], BASE_C,
+                    tenants=pt.get("tenants"),
                 )
-                if is_base:
-                    entry = base_facts[name]
-                else:
-                    spec = device_program.build_ladder_spec(
-                        name, pt["n"], pt["k"], BASE_C,
-                        tenants=pt.get("tenants"),
-                    )
-                    compiled, _reasons = device_program._compile_program(spec)
-                    entry = device_program.extract_facts(
-                        compiled, spec["donated_leaves"], pt["n"], BASE_C
-                    )
-                series.append({
-                    "key": point_key(pt),
-                    "n_eff": pt["n_eff"],
-                    "k": pt["k"],
-                    "facts": entry_cost_facts(entry),
-                })
-            table[name] = series
+                compiled, _reasons = device_program._compile_program(spec)
+                entry = device_program.extract_facts(
+                    compiled, spec["donated_leaves"], pt["n"], BASE_C
+                )
+            series.append({
+                "key": point_key(pt),
+                "n_eff": pt["n_eff"],
+                "k": pt["k"],
+                "facts": entry_cost_facts(entry),
+            })
+        table[name] = series
     _LADDER_CACHE = (table, have_mesh)
     return table
 
@@ -904,44 +901,43 @@ def check_cost_model(
     ladder = tuple(namespace.get("COST_LADDER", (8, 16, 32, 64)))
     c = namespace.get("AUDIT_C", 1)
     findings: List[Finding] = []
-    with device_program._scoped_disable_persistent_cache():
-        for name, builder in programs.items():
-            loc = (rel, linenos.get(name, 1))
-            entry_lock = locked.get(name, {})
-            fact_names = sorted(entry_lock.get("facts", {}))
-            series = []
-            for n in ladder:
-                spec = builder(n)
-                compiled, _reasons = device_program._compile_program(spec)
-                entry = device_program.extract_facts(
-                    compiled, spec.get("donated_leaves", 0), n, c
-                )
-                series.append((n, entry_cost_facts(entry)))
-            ceiling = entry_lock.get("ceiling", DEFAULT_CEILING)
-            for fact in fact_names:
-                if not all(fact in facts for _n, facts in series):
-                    continue
-                fitted = fit_scaling(
-                    [((n, 1), facts[fact]) for n, facts in series],
-                    FACT_TOLERANCES.get(fact, DEFAULT_TOLERANCE),
-                )
-                if "error" in fitted:
-                    findings.append(Finding(
-                        *loc, "cost-unexplained",
-                        f"{name}: {fact} refused to classify — "
-                        f"{fitted['error']}",
-                    ))
-                    continue
-                if CLASS_RANK[fitted["class"]] > CLASS_RANK[ceiling]:
-                    findings.append(Finding(
-                        *loc, "cost-superlinear",
-                        f"{name}: {fact} fitted {fitted['class']} (leading "
-                        f"coeff {_round_sig(fitted['coeff'], 4)}) exceeds "
-                        f"the entrypoint's {ceiling} ceiling",
-                    ))
-                    continue
-                findings.extend(compare_fact_fit(
-                    name, fact, fitted,
-                    entry_lock.get("facts", {}).get(fact, {}), loc,
+    for name, builder in programs.items():
+        loc = (rel, linenos.get(name, 1))
+        entry_lock = locked.get(name, {})
+        fact_names = sorted(entry_lock.get("facts", {}))
+        series = []
+        for n in ladder:
+            spec = builder(n)
+            compiled, _reasons = device_program._compile_program(spec)
+            entry = device_program.extract_facts(
+                compiled, spec.get("donated_leaves", 0), n, c
+            )
+            series.append((n, entry_cost_facts(entry)))
+        ceiling = entry_lock.get("ceiling", DEFAULT_CEILING)
+        for fact in fact_names:
+            if not all(fact in facts for _n, facts in series):
+                continue
+            fitted = fit_scaling(
+                [((n, 1), facts[fact]) for n, facts in series],
+                FACT_TOLERANCES.get(fact, DEFAULT_TOLERANCE),
+            )
+            if "error" in fitted:
+                findings.append(Finding(
+                    *loc, "cost-unexplained",
+                    f"{name}: {fact} refused to classify — "
+                    f"{fitted['error']}",
                 ))
+                continue
+            if CLASS_RANK[fitted["class"]] > CLASS_RANK[ceiling]:
+                findings.append(Finding(
+                    *loc, "cost-superlinear",
+                    f"{name}: {fact} fitted {fitted['class']} (leading "
+                    f"coeff {_round_sig(fitted['coeff'], 4)}) exceeds "
+                    f"the entrypoint's {ceiling} ceiling",
+                ))
+                continue
+            findings.extend(compare_fact_fit(
+                name, fact, fitted,
+                entry_lock.get("facts", {}).get(fact, {}), loc,
+            ))
     return sorted(set(findings), key=lambda f: (f.lineno, f.check, f.message))

@@ -647,18 +647,17 @@ def collect_telemetry_facts(force: bool = False) -> Dict[str, int]:
     )
     from rapid_tpu.utils.engine_telemetry import TELEMETRY_DIGEST_FIELDS
 
-    with _scoped_disable_persistent_cache():
-        vc = VirtualCluster.create(
-            AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
-            fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
-            telemetry=True,
-        )
-        vc.assign_cohorts_roundrobin()
-        for _ in range(QUIESCENT_SOAK_ROUNDS):
-            vc.step()
-        # telemetry-fetch-ok: audit boundary — a one-off gate measurement,
-        # not an engine hot path.
-        digest = np.asarray(telemetry_digest(vc.telem))
+    vc = VirtualCluster.create(
+        AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
+        fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
+        telemetry=True,
+    )
+    vc.assign_cohorts_roundrobin()
+    for _ in range(QUIESCENT_SOAK_ROUNDS):
+        vc.step()
+    # telemetry-fetch-ok: audit boundary — a one-off gate measurement,
+    # not an engine hot path.
+    digest = np.asarray(telemetry_digest(vc.telem))
     rounds = int(digest[list(TELEMETRY_DIGEST_FIELDS).index("rounds")])
     _TELEMETRY_FACTS_CACHE = {
         "lane_bytes_per_device": int(telemetry_bytes_total(vc.cfg)),
@@ -691,70 +690,22 @@ def collect_trace_facts(force: bool = False) -> Dict[str, int]:
     from rapid_tpu.models.state import trace_bytes_total
     from rapid_tpu.models.virtual_cluster import VirtualCluster
 
-    with _scoped_disable_persistent_cache():
-        vc = VirtualCluster.create(
-            AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
-            fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
-            telemetry=True, trace=AUDIT_TRACE_R,
-        )
-        vc.assign_cohorts_roundrobin()
-        for _ in range(QUIESCENT_SOAK_ROUNDS):
-            vc.step()
-        rounds = int(vc.activity["rounds"])
-        cursor = int(vc.trace["rounds_recorded"])
+    vc = VirtualCluster.create(
+        AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
+        fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
+        telemetry=True, trace=AUDIT_TRACE_R,
+    )
+    vc.assign_cohorts_roundrobin()
+    for _ in range(QUIESCENT_SOAK_ROUNDS):
+        vc.step()
+    rounds = int(vc.activity["rounds"])
+    cursor = int(vc.trace["rounds_recorded"])
     _TRACE_FACTS_CACHE = {
         "ring_bytes_per_device": int(trace_bytes_total(vc.cfg)),
         "capacity": AUDIT_TRACE_R,
         "soak_cursor_delta": cursor - rounds,
     }
     return _TRACE_FACTS_CACHE
-
-
-class _scoped_disable_persistent_cache:
-    """SCOPED: turn jax's persistent compilation cache OFF for the audit
-    compiles, restoring the previous config after.
-
-    Hard-won (root-caused via a reproducible segfault): on this jaxlib's
-    CPU backend, SHARDED executables deserialized from the persistent
-    cache poison the process — later sharded+donated executions (the
-    test_parallel equivalence runs) die in native code. The audit compiles
-    the sharded step/wave every process, so with a warm cache it would hit
-    exactly that deserialize path. Fresh compiles cost ~15 s once per
-    process (the session cache absorbs every later consumer) and keep the
-    gate's facts coming from a REAL backend compile — also true inside
-    bench.py, which deliberately enables the cache process-wide for its
-    own single-device workload. (An earlier revision of this note claimed
-    single-device deserialization was fine; the bench ``recovery`` drill's
-    bit-identity assertion later DISPROVED that — deserialized
-    single-device executables corrupt the heap under donated executions
-    too, sometimes a glibc abort and sometimes SILENT scribbling over
-    unrelated live buffers. bench.py now scopes the cache OFF around that
-    drill exactly the way this class scopes it off around the audit, and
-    utils/checkpoint.py settles loaded pytrees into executable-owned
-    buffers before any donation.)"""
-
-    def __enter__(self) -> None:
-        import jax
-
-        self._restore = False
-        try:
-            self._prev = jax.config.jax_compilation_cache_dir
-            jax.config.update("jax_compilation_cache_dir", None)
-            self._restore = True
-        except Exception:  # noqa: BLE001 — a jax without the knob has no
-            # persistent cache to disable; compile proceeds as before.
-            pass
-
-    def __exit__(self, *_exc: Any) -> None:
-        import jax
-
-        if not self._restore:
-            return
-        try:
-            jax.config.update("jax_compilation_cache_dir", self._prev)
-        except Exception:  # noqa: BLE001 — restoring a knob that could not
-            # be set back is the same no-op as never having touched it.
-            pass
 
 
 def collect_facts(
@@ -788,19 +739,18 @@ def collect_facts(
             f"{AUDIT_DEVICES}, as tests/conftest.py and the staticcheck "
             f"CLI do)"
         )
-    with _scoped_disable_persistent_cache():
-        registry = _build_registry()
-        facts = {}
-        for name, spec in registry.items():
-            compiled, reasons = _compile_program(spec)
-            entry = extract_facts(
-                compiled, spec["donated_leaves"], AUDIT_N, AUDIT_C,
-                donation_reasons=reasons,
-                tenant_block=spec.get("tenant_block"),
-            )
-            if spec.get("waiver"):
-                entry["donation"]["waiver"] = spec["waiver"]
-            facts[name] = entry
+    registry = _build_registry()
+    facts = {}
+    for name, spec in registry.items():
+        compiled, reasons = _compile_program(spec)
+        entry = extract_facts(
+            compiled, spec["donated_leaves"], AUDIT_N, AUDIT_C,
+            donation_reasons=reasons,
+            tenant_block=spec.get("tenant_block"),
+        )
+        if spec.get("waiver"):
+            entry["donation"]["waiver"] = spec["waiver"]
+        facts[name] = entry
     _FACTS_CACHE = (facts, have_mesh)
     return facts
 

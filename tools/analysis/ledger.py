@@ -5,7 +5,7 @@ its event names come from the registered ``LedgerEvent`` enum and its stage
 names from the ``STAGE_NAMES`` registry — the exact discipline the flight
 recorder's ``EventName`` rule enforces in tests/test_lint.py. A free-form
 string would silently fork the vocabulary: perfview's stage timeline and the
-watchdog's per-stage budgets would stop seeing the event.
+bench's per-stage budgets would stop seeing the event.
 
 Two checks, applied only to files that import ``rapid_tpu.utils.ledger``
 (so unrelated ``.emit()``/``.stage()`` methods elsewhere are never touched):

@@ -7,16 +7,14 @@ runs, and can I trust the numbers". Two input kinds, freely mixed:
 
 - **Run ledgers** (``*.jsonl``, what ``bench.py --ledger`` appends — see
   rapid_tpu/utils/ledger.py): rendered as a stage timeline — every stage's
-  begin/duration/status, compile + device-memory stats, heartbeat gaps,
-  watchdog kills, snapshot replays, and the run outcome with the last
-  completed stage. A wedged run reads as "died in <stage>", not a mystery.
+  begin/duration/status, compile + device-memory stats, the recovery
+  timeline, and the run outcome with the last completed stage. A failed
+  run reads as "died in <stage>", not a mystery.
 
 - **Bench metric JSON** (``*.json``, the one-line artifact each bench round
   emits — BENCH_r01.json ...): rendered as a perf trajectory table, one row
-  per round, flagging every point that is NOT a live measurement of the
-  code it claims to measure: ``snapshot`` (replayed evidence), ``stale``
-  (snapshot measured different code than HEAD), ``wedged`` (live attempt
-  died), ``hole`` (explicit accelerator-unavailable marker),
+  per round, flagging every point that is not a trustworthy measurement:
+  ``hole`` (the artifact carries an ``error`` instead of a value),
   ``suspect-rate`` (a derived rate outside plausibility bounds — the
   alert_deliveries_per_sec ≈ 5e10 class of bug), ``headline-missing``
   (an audited round that carries neither the ``n1M_crash1pct_ms``
@@ -81,11 +79,6 @@ from rapid_tpu.utils.ledger import (  # noqa: E402
 SUSPECT_RATE_PER_SEC = 1e9
 
 _POINT_EVENTS = (
-    LedgerEvent.ATTEMPT_BEGIN.value,
-    LedgerEvent.ATTEMPT_END.value,
-    LedgerEvent.HEARTBEAT_GAP.value,
-    LedgerEvent.WATCHDOG_KILL.value,
-    LedgerEvent.SNAPSHOT_REPLAY.value,
     LedgerEvent.COMPILE_STATS.value,
     LedgerEvent.DEVICE_MEMORY.value,
     # Self-healing serving runtime (ISSUE 15): the recovery timeline —
@@ -229,24 +222,16 @@ def render_ledger(path: str, events: List[Dict[str, Any]], skipped: int) -> str:
         e for e in events
         if e.get("event") in (LedgerEvent.RUN_FAIL.value, LedgerEvent.RUN_END.value)
     ]
-    fails = [e for e in terminal if e.get("event") == LedgerEvent.RUN_FAIL.value]
     stuck = open_stage(events)
-    # The LATEST terminal event wins: a --cpu-fallback/--allow-snapshot run
-    # records the wedge (run_fail) and THEN closes successfully (run_end) —
-    # event order, not event kind, decides the outcome.
     if terminal and terminal[-1]["event"] == LedgerEvent.RUN_FAIL.value:
         last = terminal[-1].get("last_completed_stage") or last_completed_stage(events)
-        where = f"; wedged in {stuck['stage']!r}" if stuck else ""
+        where = f"; stuck in {stuck['stage']!r}" if stuck else ""
         lines.append(
             f"outcome: FAILED ({terminal[-1].get('outcome') or terminal[-1].get('error')})"
             f" — last completed stage: {last or 'none'}{where}"
         )
     elif terminal:
-        note = (
-            f" (after run_fail: {fails[-1].get('outcome') or fails[-1].get('error')})"
-            if fails else ""
-        )
-        lines.append(f"outcome: {terminal[-1].get('outcome', 'completed')}{note}")
+        lines.append(f"outcome: {terminal[-1].get('outcome', 'completed')}")
     else:
         where = f" (in {stuck['stage']!r})" if stuck else ""
         lines.append(f"outcome: still running or killed mid-run{where}")
@@ -295,12 +280,6 @@ def point_flags(
     if "error" in data:
         flags.append("hole")
         return flags
-    if data.get("live_attempt") == "wedged":
-        flags.append("wedged")
-    if data.get("capture") == "session_snapshot":
-        flags.append("snapshot")
-    if data.get("stale_code"):
-        flags.append("stale")
     for key, value in data.items():
         if key.endswith("_per_sec") and isinstance(value, (int, float)):
             if value > SUSPECT_RATE_PER_SEC:
