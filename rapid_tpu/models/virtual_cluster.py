@@ -49,7 +49,7 @@ from rapid_tpu.ops.rings import (
     ring_topology_from_perm,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
-from rapid_tpu.utils.dispatch import DispatchSeam
+from rapid_tpu.utils.dispatch import DispatchSeam, scope
 from rapid_tpu.utils.health import NodeHealth
 from rapid_tpu.utils.metrics import Metrics
 
@@ -68,6 +68,7 @@ def _validate_delivery_prob(permille: int) -> None:
         )
 
 
+@scope("edge_masks")
 def _edge_masks(cfg: EngineConfig, state: EngineState, faults: FaultInputs):
     """Per-edge observer masks: (observer_active[n,k], blocked_rows[w*k,n]).
 
@@ -99,6 +100,7 @@ def _edge_masks(cfg: EngineConfig, state: EngineState, faults: FaultInputs):
     return observer_active, blocked_rows
 
 
+@scope("fd_tick")
 def _fd_tick(cfg: EngineConfig, state: EngineState, faults: FaultInputs, observer_active):
     """Every observer probes its subjects; edges past the failure threshold
     emit one DOWN alert (semantics of PingPongFailureDetector + the
@@ -141,6 +143,7 @@ def _fd_tick(cfg: EngineConfig, state: EngineState, faults: FaultInputs, observe
     return fd_count, fd_hist, fd_fired, fire
 
 
+@scope("deliver")
 def _deliver_alerts(cfg: EngineConfig, state: EngineState, fire_round, blocked_rows):
     """Per-cohort delivered alert bitmasks, ``new_bits[c, n]`` (bit k = ring
     k's alert for subject n has reached cohort c).
@@ -211,6 +214,7 @@ def _deliver_alerts(cfg: EngineConfig, state: EngineState, fire_round, blocked_r
     return new_bits
 
 
+@scope("cut_detection")
 def _cohort_cut_detection(cfg: EngineConfig, state: EngineState, new_bits, heard_down):
     """The engine's cut-detection seam: C independent watermark detectors
     batched over the (mesh-sharded) cohort axis. The pass itself lives in
@@ -275,10 +279,18 @@ def _compute_round(
     # Stamp at the lane's (policy) dtype: round_idx is int32 and a bare
     # where() would re-widen the whole [n, k] lane. In-envelope round
     # indices (< fire_never) cast losslessly.
-    fire_round = jnp.where(
-        fire, state.round_idx.astype(state.fire_round.dtype), state.fire_round
-    )
-    alerts_emitted = jnp.sum(fire, dtype=jnp.int32)
+    with scope("fd_tick"):
+        fire_round = jnp.where(
+            fire, state.round_idx.astype(state.fire_round.dtype), state.fire_round
+        )
+        alerts_emitted = jnp.sum(fire, dtype=jnp.int32)
+        # Whether any fired alert has yet to mature (the delivery gate below).
+        fired_any = jnp.any(fd_fired)
+        last_mature = (
+            jnp.max(jnp.where(fd_fired, fire_round, jnp.int32(-1)))
+            + cfg.delivery_spread
+        )
+        need_delivery = fired_any & (state.round_idx <= last_mature)
 
     # 2. Broadcast delivery: alert for edge (s, ring) originates at the edge's
     #    observer; cohort c hears it unless that observer is rx-blocked, and
@@ -290,20 +302,17 @@ def _compute_round(
     #    delays and rx-blocks are fixed between view changes, so past
     #    max(fire_round) + spread the delivered mask is static and already
     #    OR-merged into report_bits — recomputing it adds nothing.
-    fired_any = jnp.any(fd_fired)
-    last_mature = (
-        jnp.max(jnp.where(fd_fired, fire_round, jnp.int32(-1)))
-        + cfg.delivery_spread
-    )
-    need_delivery = fired_any & (state.round_idx <= last_mature)
     new_bits = jax.lax.cond(
         need_delivery,
         lambda: _deliver_alerts(cfg, state, fire_round, blocked_rows),
-        lambda: jnp.zeros((c, n), dtype=state.report_bits.dtype),
+        scope("deliver_skip")(
+            lambda: jnp.zeros((c, n), dtype=state.report_bits.dtype)
+        ),
     )
     # Alerts for ALIVE subjects are DOWN reports; join-pending subjects'
     # reports are UP and must not arm implicit invalidation.
-    heard_down = jnp.any((new_bits != 0) & state.alive[None, :], axis=1)  # [c]
+    with scope("cut_detection"):
+        heard_down = jnp.any((new_bits != 0) & state.alive[None, :], axis=1)  # [c]
 
     # 3. Cut detection per cohort.
     report_bits, released, announced, seen_down, proposed_now, prop_masks = _cohort_cut_detection(
@@ -316,42 +325,44 @@ def _compute_round(
     # cond-gated: an extra lax.cond in the round body costs more compile
     # time across every engine program than the masked reductions cost to
     # run).
-    prop_hi_new, prop_lo_new = jax.vmap(
-        lambda mask: masked_set_hash(state.id_hi, state.id_lo, mask)
-    )(prop_masks)
-    prop_hi = jnp.where(proposed_now, prop_hi_new, state.prop_hi)
-    prop_lo = jnp.where(proposed_now, prop_lo_new, state.prop_lo)
-    prop_mask = jnp.where(proposed_now[:, None], prop_masks, state.prop_mask)
+    with scope("cut_detection"):
+        prop_hi_new, prop_lo_new = jax.vmap(
+            lambda mask: masked_set_hash(state.id_hi, state.id_lo, mask)
+        )(prop_masks)
+        prop_hi = jnp.where(proposed_now, prop_hi_new, state.prop_hi)
+        prop_lo = jnp.where(proposed_now, prop_lo_new, state.prop_lo)
+        prop_mask = jnp.where(proposed_now[:, None], prop_masks, state.prop_mask)
 
-    # 4. Fast-round votes: each live member votes its cohort's proposal, once
-    #    per configuration (FastPaxos.java:94-108).
-    cohort = state.cohort_of
-    cohort_announced = announced[cohort]
-    can_vote = state.alive & ~faults.crashed & ~state.vote_valid & cohort_announced
-    vote_hi = jnp.where(can_vote, prop_hi[cohort], state.vote_hi)
-    vote_lo = jnp.where(can_vote, prop_lo[cohort], state.vote_lo)
-    vote_valid = state.vote_valid | can_vote
+    with scope("tally"):
+        # 4. Fast-round votes: each live member votes its cohort's proposal, once
+        #    per configuration (FastPaxos.java:94-108).
+        cohort = state.cohort_of
+        cohort_announced = announced[cohort]
+        can_vote = state.alive & ~faults.crashed & ~state.vote_valid & cohort_announced
+        vote_hi = jnp.where(can_vote, prop_hi[cohort], state.vote_hi)
+        vote_lo = jnp.where(can_vote, prop_lo[cohort], state.vote_lo)
+        vote_valid = state.vote_valid | can_vote
 
-    # 5. Quorum tally over all N votes (FastPaxos.java:125-156).
-    tally = tally_candidates(
-        vote_hi, vote_lo, vote_valid, prop_hi, prop_lo, announced, state.n_members
-    )
-    fast_decided = tally.decided
+        # 5. Quorum tally over all N votes (FastPaxos.java:125-156).
+        tally = tally_candidates(
+            vote_hi, vote_lo, vote_valid, prop_hi, prop_lo, announced, state.n_members
+        )
+        fast_decided = tally.decided
 
-    # 5a'. Casting a fast-round vote also primes the classic acceptor state:
-    #      rnd = vrnd = (1, 1), vval = the vote (Paxos.java:246-260). The
-    #      fast round is always round 1; classic rounds start at 2.
-    prime = can_vote & (state.cp_rnd_r < 1)
-    cp_rnd_r = jnp.where(prime, 1, state.cp_rnd_r)
-    cp_rnd_i = jnp.where(prime, 1, state.cp_rnd_i)
-    cp_vrnd_r = jnp.where(prime, 1, state.cp_vrnd_r)
-    cp_vrnd_i = jnp.where(prime, 1, state.cp_vrnd_i)
-    cp_vval_src = jnp.where(prime, cohort, state.cp_vval_src)
+        # 5a'. Casting a fast-round vote also primes the classic acceptor state:
+        #      rnd = vrnd = (1, 1), vval = the vote (Paxos.java:246-260). The
+        #      fast round is always round 1; classic rounds start at 2.
+        prime = can_vote & (state.cp_rnd_r < 1)
+        cp_rnd_r = jnp.where(prime, 1, state.cp_rnd_r)
+        cp_rnd_i = jnp.where(prime, 1, state.cp_rnd_i)
+        cp_vrnd_r = jnp.where(prime, 1, state.cp_vrnd_r)
+        cp_vrnd_i = jnp.where(prime, 1, state.cp_vrnd_i)
+        cp_vval_src = jnp.where(prime, cohort, state.cp_vval_src)
 
-    rounds_undecided = jnp.where(
-        jnp.any(announced) & ~fast_decided, state.rounds_undecided + 1, state.rounds_undecided
-    )
-    fallback_due = (rounds_undecided >= cfg.fallback_rounds) & jnp.any(announced) & ~fast_decided
+        rounds_undecided = jnp.where(
+            jnp.any(announced) & ~fast_decided, state.rounds_undecided + 1, state.rounds_undecided
+        )
+        fallback_due = (rounds_undecided >= cfg.fallback_rounds) & jnp.any(announced) & ~fast_decided
 
     # 5b. Classic-Paxos fallback, message-level (Paxos.java:98-238): one
     #     attempt per engine round once the recovery delay expires. R =
@@ -368,6 +379,7 @@ def _compute_round(
     #     per-cohort rx-block masks as alerts, so partitioned coordinators
     #     genuinely fail and rotation recovers. Cond-gated: the common fast
     #     path skips the cumsum/gathers entirely.
+    @scope("classic")
     def classic_attempt(cp):
         cp_rnd_r, cp_rnd_i, cp_vrnd_r, cp_vrnd_i, cp_vval_src = cp
         # Lane (policy) dtypes the attempt's stores must land at: racer
@@ -498,6 +510,7 @@ def _compute_round(
             chosen_winner,
         )
 
+    @scope("classic_skip")
     def no_attempt(cp):
         cp_rnd_r, cp_rnd_i, cp_vrnd_r, cp_vrnd_i, cp_vval_src = cp
         return (
@@ -511,23 +524,24 @@ def _compute_round(
         no_attempt,
         (cp_rnd_r, cp_rnd_i, cp_vrnd_r, cp_vrnd_i, cp_vval_src),
     )
-    classic_epoch = jnp.where(fallback_due, state.classic_epoch + 1, state.classic_epoch)
+    with scope("tally"):
+        classic_epoch = jnp.where(fallback_due, state.classic_epoch + 1, state.classic_epoch)
 
-    decided = fast_decided | fb_decided
-    winner_cohort = jnp.where(
-        fast_decided,
-        jnp.argmax(announced & (prop_hi == tally.winner_hi) & (prop_lo == tally.winner_lo)),
-        jnp.maximum(chosen, 0),
-    )
-    # Materialize the decided cut as a one-hot masked reduction over the
-    # cohort axis — on the cohort-meshed state this lowers to a reduce-class
-    # psum of [n] bools, where the old dynamic row gather
-    # (prop_mask[winner_cohort]) would redistribute across the cohort axis
-    # as gather/permute traffic in every round of the hot loop.
-    winner_mask = decided & jnp.any(
-        prop_mask & (jnp.arange(c, dtype=jnp.int32) == winner_cohort)[:, None],
-        axis=0,
-    )
+        decided = fast_decided | fb_decided
+        winner_cohort = jnp.where(
+            fast_decided,
+            jnp.argmax(announced & (prop_hi == tally.winner_hi) & (prop_lo == tally.winner_lo)),
+            jnp.maximum(chosen, 0),
+        )
+        # Materialize the decided cut as a one-hot masked reduction over the
+        # cohort axis — on the cohort-meshed state this lowers to a reduce-class
+        # psum of [n] bools, where the old dynamic row gather
+        # (prop_mask[winner_cohort]) would redistribute across the cohort axis
+        # as gather/permute traffic in every round of the hot loop.
+        winner_mask = decided & jnp.any(
+            prop_mask & (jnp.arange(c, dtype=jnp.int32) == winner_cohort)[:, None],
+            axis=0,
+        )
 
     round_state = state._replace(
         fd_count=fd_count,
@@ -570,27 +584,28 @@ def _compute_round(
     # Device telemetry plane (write-only; see the docstring contract).
     # Scalars reuse reductions computed above; [c, n]/[c] lanes accumulate
     # elementwise at their native grain.
-    active_cn, invalidated_cn = telemetry_cut_masks(
-        state.report_bits, new_bits, report_bits,
-        state.alive | state.join_pending, cfg.h, cfg.l,
-    )
-    decided_i = decided.astype(jnp.int32)
-    # Decision-path split, same vocabulary as the host protocol's
-    # FastPaxos.decided_path ("classic" iff the classic fallback decided).
-    bucket = undecided_log2_bucket(rounds_undecided, TELEMETRY_BUCKETS)
-    telem = TelemetryLanes(
-        tl_rounds=telem.tl_rounds + 1,
-        tl_alerts=telem.tl_alerts + alerts_emitted,
-        tl_active=telem.tl_active + active_cn.astype(jnp.int32),
-        tl_invalidated=telem.tl_invalidated + invalidated_cn.astype(jnp.int32),
-        tl_proposals=telem.tl_proposals + proposed_now.astype(jnp.int32),
-        tl_tally_sum=telem.tl_tally_sum + jnp.where(decided, tally.max_count, 0),
-        tl_fast_decisions=telem.tl_fast_decisions + fast_decided.astype(jnp.int32),
-        tl_classic_decisions=telem.tl_classic_decisions + fb_decided.astype(jnp.int32),
-        tl_conflict_rounds=telem.tl_conflict_rounds
-        + (jnp.any(announced) & ~fast_decided).astype(jnp.int32),
-        tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
-    )
+    with scope("observers"):
+        active_cn, invalidated_cn = telemetry_cut_masks(
+            state.report_bits, new_bits, report_bits,
+            state.alive | state.join_pending, cfg.h, cfg.l,
+        )
+        decided_i = decided.astype(jnp.int32)
+        # Decision-path split, same vocabulary as the host protocol's
+        # FastPaxos.decided_path ("classic" iff the classic fallback decided).
+        bucket = undecided_log2_bucket(rounds_undecided, TELEMETRY_BUCKETS)
+        telem = TelemetryLanes(
+            tl_rounds=telem.tl_rounds + 1,
+            tl_alerts=telem.tl_alerts + alerts_emitted,
+            tl_active=telem.tl_active + active_cn.astype(jnp.int32),
+            tl_invalidated=telem.tl_invalidated + invalidated_cn.astype(jnp.int32),
+            tl_proposals=telem.tl_proposals + proposed_now.astype(jnp.int32),
+            tl_tally_sum=telem.tl_tally_sum + jnp.where(decided, tally.max_count, 0),
+            tl_fast_decisions=telem.tl_fast_decisions + fast_decided.astype(jnp.int32),
+            tl_classic_decisions=telem.tl_classic_decisions + fb_decided.astype(jnp.int32),
+            tl_conflict_rounds=telem.tl_conflict_rounds
+            + (jnp.any(announced) & ~fast_decided).astype(jnp.int32),
+            tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
+        )
     if trace is None:
         return round_state, decided, winner_mask, events, telem
 
@@ -601,30 +616,31 @@ def _compute_round(
     # the epoch bumps only when the caller commits the view change), so the
     # decoded (epoch, round) pairs are lexicographically strictly increasing
     # — the wrap-monotonicity contract tests/test_trace_ring.py pins.
-    slot = jax.lax.rem(trace.tr_cursor, jnp.int32(cfg.trace))
-    trace = TraceRing(
-        tr_round=trace.tr_round.at[slot].set(state.round_idx),
-        tr_epoch=trace.tr_epoch.at[slot].set(state.config_epoch),
-        tr_active=trace.tr_active.at[slot].set(
-            jnp.sum(active_cn, dtype=jnp.int32)
-        ),
-        tr_alerts=trace.tr_alerts.at[slot].set(alerts_emitted),
-        tr_proposals=trace.tr_proposals.at[slot].set(
-            jnp.sum(proposed_now, dtype=jnp.int32)
-        ),
-        tr_tally=trace.tr_tally.at[slot].set(jnp.where(decided, tally.max_count, 0)),
-        tr_path=trace.tr_path.at[slot].set(
-            fast_decided.astype(jnp.int32) + 2 * fb_decided.astype(jnp.int32)
-        ),
-        tr_conflict=trace.tr_conflict.at[slot].set(
-            (jnp.any(announced) & ~fast_decided).astype(jnp.int32)
-        ),
-        tr_undecided=trace.tr_undecided.at[slot].set(
-            rounds_undecided.astype(jnp.int32)
-        ),
-        tr_cursor=trace.tr_cursor + 1,
-        tr_wraps=trace.tr_wraps + (slot == cfg.trace - 1).astype(jnp.int32),
-    )
+    with scope("observers"):
+        slot = jax.lax.rem(trace.tr_cursor, jnp.int32(cfg.trace))
+        trace = TraceRing(
+            tr_round=trace.tr_round.at[slot].set(state.round_idx),
+            tr_epoch=trace.tr_epoch.at[slot].set(state.config_epoch),
+            tr_active=trace.tr_active.at[slot].set(
+                jnp.sum(active_cn, dtype=jnp.int32)
+            ),
+            tr_alerts=trace.tr_alerts.at[slot].set(alerts_emitted),
+            tr_proposals=trace.tr_proposals.at[slot].set(
+                jnp.sum(proposed_now, dtype=jnp.int32)
+            ),
+            tr_tally=trace.tr_tally.at[slot].set(jnp.where(decided, tally.max_count, 0)),
+            tr_path=trace.tr_path.at[slot].set(
+                fast_decided.astype(jnp.int32) + 2 * fb_decided.astype(jnp.int32)
+            ),
+            tr_conflict=trace.tr_conflict.at[slot].set(
+                (jnp.any(announced) & ~fast_decided).astype(jnp.int32)
+            ),
+            tr_undecided=trace.tr_undecided.at[slot].set(
+                rounds_undecided.astype(jnp.int32)
+            ),
+            tr_cursor=trace.tr_cursor + 1,
+            tr_wraps=trace.tr_wraps + (slot == cfg.trace - 1).astype(jnp.int32),
+        )
     return round_state, decided, winner_mask, events, telem, trace
 
 
@@ -649,6 +665,7 @@ def classic_coordinator_targets(epoch: int, n_active: int, racers: int):
     return targets
 
 
+@scope("view_change")
 def apply_view_change_impl(
     cfg: EngineConfig, state: EngineState, winner_mask
 ) -> EngineState:
@@ -723,7 +740,7 @@ def engine_step_impl(
     new_state = jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner_mask),
-        lambda s: s,
+        scope("view_keep")(lambda s: s),
         round_state,
     )
     return new_state, events
@@ -751,7 +768,7 @@ def engine_step_telem_impl(
     new_state = jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner_mask),
-        lambda s: s,
+        scope("view_keep")(lambda s: s),
         round_state,
     )
     return new_state, telem, events
@@ -779,7 +796,7 @@ def engine_step_trace_impl(
     new_state = jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner_mask),
-        lambda s: s,
+        scope("view_keep")(lambda s: s),
         round_state,
     )
     return new_state, telem, trace, events
@@ -842,6 +859,7 @@ def trace_digest_impl(trace: TraceRing) -> jnp.ndarray:
 trace_digest = jax.jit(trace_digest_impl)  # donate-ok: read-only boundary fetch; the ring stays live
 
 
+@scope("sync_checksum")
 def sync_checksum_impl(state: EngineState, faults: FaultInputs):
     """Scalar checksum depending on every state/fault array — the barrier
     ``VirtualCluster.sync`` fetches (a scalar that depends on every array
@@ -897,7 +915,7 @@ def run_to_decision_impl(cfg: EngineConfig, state: EngineState, faults: FaultInp
     state = jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner),
-        lambda s: s,
+        scope("view_keep")(lambda s: s),
         state,
     )
     return (state, steps, decided, winner)
@@ -938,7 +956,7 @@ def run_to_decision_telem_impl(
     state = jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner),
-        lambda s: s,
+        scope("view_keep")(lambda s: s),
         state,
     )
     return (state, telem, steps, decided, winner)
@@ -986,7 +1004,7 @@ def run_to_decision_trace_impl(
     state = jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner),
-        lambda s: s,
+        scope("view_keep")(lambda s: s),
         state,
     )
     return (state, telem, trace, steps, decided, winner)
@@ -1067,11 +1085,12 @@ def run_until_membership_impl(
             return s2, _edge_masks(cfg, s2, faults)
 
         state, edge_masks = jax.lax.cond(
-            decided, commit, lambda s: (s, edge_masks), state
+            decided, commit, scope("view_keep")(lambda s: (s, edge_masks)), state
         )
-        sizes = jnp.where(
-            decided, sizes.at[cuts].set(state.n_members), sizes
-        )
+        with scope("loop_result"):
+            sizes = jnp.where(
+                decided, sizes.at[cuts].set(state.n_members), sizes
+            )
         # A convergence that ran out of budget undecided cannot make further
         # progress (the outer loop would spin): latch and exit.
         return (state, steps, cuts + decided.astype(jnp.int32), ~decided, sizes, edge_masks)
@@ -1087,7 +1106,8 @@ def run_until_membership_impl(
     state, steps, cuts, stalled, sizes, _ = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
-    resolved = (state.n_members == target) & (cuts >= min_cuts)
+    with scope("loop_result"):
+        resolved = (state.n_members == target) & (cuts >= min_cuts)
     return (state, steps, cuts, resolved, sizes)
 
 
@@ -1142,11 +1162,12 @@ def run_until_membership_telem_impl(
             return s2, _edge_masks(cfg, s2, faults)
 
         state, edge_masks = jax.lax.cond(
-            decided, commit, lambda s: (s, edge_masks), state
+            decided, commit, scope("view_keep")(lambda s: (s, edge_masks)), state
         )
-        sizes = jnp.where(
-            decided, sizes.at[cuts].set(state.n_members), sizes
-        )
+        with scope("loop_result"):
+            sizes = jnp.where(
+                decided, sizes.at[cuts].set(state.n_members), sizes
+            )
         return (
             state, telem, steps, cuts + decided.astype(jnp.int32), ~decided,
             sizes, edge_masks,
@@ -1164,7 +1185,8 @@ def run_until_membership_telem_impl(
     state, telem, steps, cuts, stalled, sizes, _ = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
-    resolved = (state.n_members == target) & (cuts >= min_cuts)
+    with scope("loop_result"):
+        resolved = (state.n_members == target) & (cuts >= min_cuts)
     return (state, telem, steps, cuts, resolved, sizes)
 
 
@@ -1222,11 +1244,12 @@ def run_until_membership_trace_impl(
             return s2, _edge_masks(cfg, s2, faults)
 
         state, edge_masks = jax.lax.cond(
-            decided, commit, lambda s: (s, edge_masks), state
+            decided, commit, scope("view_keep")(lambda s: (s, edge_masks)), state
         )
-        sizes = jnp.where(
-            decided, sizes.at[cuts].set(state.n_members), sizes
-        )
+        with scope("loop_result"):
+            sizes = jnp.where(
+                decided, sizes.at[cuts].set(state.n_members), sizes
+            )
         return (
             state, telem, trace, steps, cuts + decided.astype(jnp.int32),
             ~decided, sizes, edge_masks,
@@ -1245,7 +1268,8 @@ def run_until_membership_trace_impl(
     state, telem, trace, steps, cuts, stalled, sizes, _ = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
-    resolved = (state.n_members == target) & (cuts >= min_cuts)
+    with scope("loop_result"):
+        resolved = (state.n_members == target) & (cuts >= min_cuts)
     return (state, telem, trace, steps, cuts, resolved, sizes)
 
 
@@ -1472,12 +1496,14 @@ class VirtualCluster(DispatchSeam):
     def crash(self, slots: Sequence[int]) -> None:
         """Crash-stop the given slots (unresponsive until revived). Device-side
         scatter: only the slot indices cross the host->device boundary."""
-        idx = self._slot_index(slots)
-        self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(True))
+        with self._dispatch("inject_crash"):
+            idx = self._slot_index(slots)
+            self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(True))
 
     def revive(self, slots: Sequence[int]) -> None:
-        idx = self._slot_index(slots)
-        self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(False))
+        with self._dispatch("inject_crash"):
+            idx = self._slot_index(slots)
+            self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(False))
 
     def _stamp_fired_edges(self, idx: jnp.ndarray, edge_mask) -> None:
         """Mark (slot, ring) edges as fired at the current round (device-side
@@ -1589,14 +1615,16 @@ class VirtualCluster(DispatchSeam):
         configuration id."""
         slots = np.asarray(slots)
         state = self.state
-        idx = self._slot_index(slots)
+        idx = None
         if check_admissible:
             # Enforce the rejoin discipline host-side (the engine's
             # UUIDAlreadySeenError): current members, already-pending
             # joiners, and retired identity lanes are not admissible. Index
             # on device first so the ONE device->host fetch carries [j]
             # bools, not the whole [n] state.
-            bad = np.asarray((state.alive | state.join_pending | state.retired)[idx])
+            with self._dispatch("inject_join_admit"):
+                idx = self._slot_index(slots)
+                bad = np.asarray((state.alive | state.join_pending | state.retired)[idx])
             self._account_d2h(bad.nbytes)
             if bad.any():
                 raise ValueError(
@@ -1604,28 +1632,31 @@ class VirtualCluster(DispatchSeam):
                     f"{slots[bad].tolist()}"
                 )
 
-        # Expected observers (gatekeepers) of each joiner: the alive ring
-        # predecessors of its keys. Everything below is device-side
-        # gather/scatter — only the slot indices cross the boundary, which
-        # is what keeps a bootstrap wave from paying O(k*n) transfer traffic.
-        pred = predecessor_of_keys(
-            state.key_hi, state.key_lo, state.alive,
-            state.key_hi[:, idx], state.key_lo[:, idx],
-            perm=state.ring_perm,  # sort-free: this sits in bootstrap's timed path
-        )  # [k, j]
+        with self._dispatch("inject_join_place"):
+            if idx is None:
+                idx = self._slot_index(slots)
+            # Expected observers (gatekeepers) of each joiner: the alive ring
+            # predecessors of its keys. Everything below is device-side
+            # gather/scatter — only the slot indices cross the boundary, which
+            # is what keeps a bootstrap wave from paying O(k*n) transfer traffic.
+            pred = predecessor_of_keys(
+                state.key_hi, state.key_lo, state.alive,
+                state.key_hi[:, idx], state.key_lo[:, idx],
+                perm=state.ring_perm,  # sort-free: this sits in bootstrap's timed path
+            )  # [k, j]
 
-        # The gatekeeper IS the joiner's observer pre-admission (for both
-        # alert delivery and implicit invalidation). predecessor_of_keys
-        # computes at int32; the scatter narrows to the lane's policy dtype.
-        pred_n = pred.astype(state.obs_idx.dtype)
-        self.state = state._replace(
-            join_pending=state.join_pending.at[idx].set(True),
-            obs_idx=state.obs_idx.at[:, idx].set(pred_n),
-            inval_obs=state.inval_obs.at[:, idx].set(pred_n),
-        )
-        # Mark each (joiner, ring) edge as fired now where a gatekeeper
-        # exists; delivery (rx-block + jitter) happens in the round body.
-        self._stamp_fired_edges(idx, (pred >= 0).T)
+            # The gatekeeper IS the joiner's observer pre-admission (for both
+            # alert delivery and implicit invalidation). predecessor_of_keys
+            # computes at int32; the scatter narrows to the lane's policy dtype.
+            pred_n = pred.astype(state.obs_idx.dtype)
+            self.state = state._replace(
+                join_pending=state.join_pending.at[idx].set(True),
+                obs_idx=state.obs_idx.at[:, idx].set(pred_n),
+                inval_obs=state.inval_obs.at[:, idx].set(pred_n),
+            )
+            # Mark each (joiner, ring) edge as fired now where a gatekeeper
+            # exists; delivery (rx-block + jitter) happens in the round body.
+            self._stamp_fired_edges(idx, (pred >= 0).T)
 
     def assign_cohorts(self, cohort_of: np.ndarray) -> None:
         # Host-side cast first so the transfer counter charges the bytes
@@ -1664,13 +1695,13 @@ class VirtualCluster(DispatchSeam):
 
     # -- execution ------------------------------------------------------
 
-    def _step(self, phase: str) -> StepEvents:
+    def _step(self, phase: str, **tags) -> StepEvents:
         """ONE body for both step spellings: only the dispatch-phase label
-        differs, so a change here cannot diverge the streamed path from the
-        batch path the bit-identity tests pin."""
+        (and the span's tags) differ, so a change here cannot diverge the
+        streamed path from the batch path the bit-identity tests pin."""
         self.metrics.inc("engine_steps")
         self.metrics.inc("engine_convergence_steps")
-        with self._dispatch(phase):
+        with self._dispatch(phase, **tags):
             if self.trace_ring is not None:
                 self.state, self.telem, self.trace_ring, events = engine_step_trace(
                     self.cfg, self.state, self.telem, self.trace_ring, self.faults
@@ -1686,15 +1717,17 @@ class VirtualCluster(DispatchSeam):
     def step(self) -> StepEvents:
         return self._step("step")
 
-    def stream_step(self) -> StepEvents:
+    def stream_step(self, wave: Optional[int] = None) -> StepEvents:
         """One ENQUEUED engine round for the streaming pipeline
         (rapid_tpu/serving): the same compiled ``engine_step`` program as
         :meth:`step` — bit-identical math — accounted under the
         ``stream_enqueue`` phase and guaranteed fetch-free, so the host
         returns as soon as JAX has queued the dispatch. The returned events
         stay device-resident (they are the stream driver's completion
-        ticket); reading them here would put a host sync on the pipeline."""
-        return self._step("stream_enqueue")
+        ticket); reading them here would put a host sync on the pipeline.
+        ``wave`` (the stream driver's wave index) tags the round's span so a
+        wave's enqueues and the fetch that retires it share an identifier."""
+        return self._step("stream_enqueue", wave=wave)
 
     def sync(self) -> int:
         """Force completion of all pending uploads/compute on the cluster

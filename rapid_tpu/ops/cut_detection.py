@@ -40,6 +40,7 @@ from rapid_tpu.ops.pallas_kernels import (
     _popcount32,
     watermark_merge_classify_impl,
 )
+from rapid_tpu.utils.dispatch import scope
 
 
 class CutState(NamedTuple):
@@ -180,6 +181,7 @@ def cohort_watermark_pass(
     stable = cls == 2
     flux = cls == 1
 
+    @scope("invalidation")
     def with_implicit(report_bits):
         # Implicit edge invalidation (MultiNodeCutDetector.java:137-164): the
         # union (pending-stable | flux) is invariant under the pass, so one
@@ -204,7 +206,9 @@ def cohort_watermark_pass(
         return jnp.where(subject_mask[None, :], merged, 0)
 
     need_invalidation = jnp.any(flux & seen_down[:, None])
-    report_bits = jax.lax.cond(need_invalidation, with_implicit, lambda r: r, report_bits)
+    report_bits = jax.lax.cond(
+        need_invalidation, with_implicit, scope("invalidation_skip")(lambda r: r), report_bits
+    )
 
     tally2 = _popcount32(report_bits)
     stable2 = tally2 >= h

@@ -20,6 +20,7 @@ import numpy as np
 
 from rapid_tpu.ops.hashing import lex_argsort
 from rapid_tpu.protocol.view import ring_key
+from rapid_tpu.utils.dispatch import scope
 
 
 class RingTopology(NamedTuple):
@@ -184,6 +185,7 @@ def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopolo
 
 
 @jax.jit
+@scope("join_predecessors")
 def predecessor_of_keys(
     key_hi: jnp.ndarray,
     key_lo: jnp.ndarray,

@@ -420,7 +420,7 @@ class StreamDriver:
         self._apply(wave)
         events = None
         for _ in range(self.rounds_per_wave):
-            events = self.target.stream_step()
+            events = self.target.stream_step(wave=self.waves_submitted)
         # The last round's decided flag is the wave's ticket: a fresh
         # output buffer (never donated away by later rounds), ready exactly
         # when every dispatch of this wave has executed.
@@ -529,7 +529,7 @@ class StreamDriver:
         ``drain`` sweep) for the injected deadline waiter; the telemetry
         phase stays ``stream_fetch`` either way."""
         idx, t_submit, ticket = self._pending.popleft()
-        with self.target._dispatch("stream_fetch"):
+        with self.target._dispatch("stream_fetch", wave=idx):
             if self._ticket_wait is not None:
                 self._ticket_wait(budget_phase, idx, ticket)
             else:

@@ -814,20 +814,21 @@ class TenantFleet(DispatchSeam):
         autotune sweep) observes its cuts in its own results."""
         return self._step("fleet_step")
 
-    def stream_step(self) -> StepEvents:
+    def stream_step(self, wave: Optional[int] = None) -> StepEvents:
         """One ENQUEUED batched round for the streaming pipeline
         (rapid_tpu/serving): the same compiled ``fleet_step`` program as
         :meth:`step` — bit-identical per tenant — accounted under the
         ``stream_enqueue`` phase and guaranteed fetch-free; the stacked
-        events stay device-resident (the stream driver's ticket)."""
-        return self._step("stream_enqueue")
+        events stay device-resident (the stream driver's ticket); ``wave``
+        tags the round's span with the stream driver's wave index."""
+        return self._step("stream_enqueue", wave=wave)
 
-    def _step(self, phase: str) -> StepEvents:
+    def _step(self, phase: str, **tags) -> StepEvents:
         """ONE body for both step spellings: only the dispatch-phase label
-        differs, so a change here cannot diverge the streamed path from the
-        batch path the bit-identity tests pin."""
+        (and the span's tags) differ, so a change here cannot diverge the
+        streamed path from the batch path the bit-identity tests pin."""
         self.metrics.inc("engine_tenant_rounds", self.b)
-        with self._dispatch(phase):
+        with self._dispatch(phase, **tags):
             if self.trace_ring is not None:
                 self.state, self.telem, self.trace_ring, events = (
                     fleet_step_trace(
@@ -852,20 +853,21 @@ class TenantFleet(DispatchSeam):
         behind the in-flight dispatches (no fetch, no sync). Host-side
         bounds check first: jnp scatters CLAMP out-of-range indices, which
         would silently crash tenant b-1 / slot n-1 on a typo."""
-        arr = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
-        if arr.size and (
-            arr[:, 0].min() < 0 or arr[:, 0].max() >= self.b
-            or arr[:, 1].min() < 0 or arr[:, 1].max() >= self.cfg.n
-        ):
-            raise IndexError(
-                f"(tenant, slot) pairs out of range [0, {self.b}) x "
-                f"[0, {self.cfg.n}): {arr.tolist()}"
+        with self._dispatch("inject_crash"):
+            arr = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
+            if arr.size and (
+                arr[:, 0].min() < 0 or arr[:, 0].max() >= self.b
+                or arr[:, 1].min() < 0 or arr[:, 1].max() >= self.cfg.n
+            ):
+                raise IndexError(
+                    f"(tenant, slot) pairs out of range [0, {self.b}) x "
+                    f"[0, {self.cfg.n}): {arr.tolist()}"
+                )
+            self._account_h2d(arr)
+            idx = jnp.asarray(arr)
+            self.faults = self.faults._replace(
+                crashed=self.faults.crashed.at[idx[:, 0], idx[:, 1]].set(True)
             )
-        self._account_h2d(arr)
-        idx = jnp.asarray(arr)
-        self.faults = self.faults._replace(
-            crashed=self.faults.crashed.at[idx[:, 0], idx[:, 1]].set(True)
-        )
 
     def run_to_decision(self, max_steps: int = 64):
         """Every tenant runs to its own first view change in one dispatch;

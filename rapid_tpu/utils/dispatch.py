@@ -18,12 +18,25 @@ as JAX has queued the program (host time spent *submitting*), while a fetch
 phase brackets the explicit synchronization boundaries (host time spent
 *blocked on the device*). Their separation is what makes overlap efficiency
 measurable from the histograms alone (``serving/stream.py``).
+
+One span vocabulary, host and device. Every ``_dispatch`` block is also a
+``rapid:<phase>`` span on the JAX profiler's clock (``profiling.annotate``),
+so a trace taken around live traffic shows the host phases beside the device
+operations they enqueue; the histograms are the always-on sums and the
+profiler trace is the span store. Inside the compiled programs the round's
+phases and the arms of its conditionals carry ``jax.named_scope`` names from
+:data:`ENGINE_SCOPES` (:func:`scope`), registered and enforced at write time
+like the phases.
 """
 
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
+
+import jax
+
+from rapid_tpu.utils.profiling import annotate
 
 #: The registered dispatch-phase vocabulary — every ``_dispatch(...)`` entry
 #: across the engine drivers. Parameterize by metric fields, never by
@@ -47,7 +60,54 @@ ENGINE_DISPATCH_PHASES = frozenset({
     # the explicit fetch boundaries they synchronize at.
     "stream_enqueue",
     "stream_fetch",
+    # Fault and membership injection (host work between dispatches): the
+    # crash/revive scatter enqueue, the join wave's blocking admissibility
+    # fetch, and its fetch-free gatekeeper placement.
+    "inject_crash",
+    "inject_join_admit",
+    "inject_join_place",
 })
+
+#: Prefix of a dispatch phase's span on the profiler's clock.
+SPAN_PREFIX = "rapid:"
+
+#: The registered device-scope vocabulary: every ``jax.named_scope`` inside
+#: the compiled engine programs (the round's phases, both arms of each
+#: conditional, the injection and barrier programs). A device event's
+#: op-name path carries the scopes it was traced under, so a profile reads
+#: per phase instead of per ``fusion.<n>``. No name may contain a needle of
+#: ``parallel/hlo_facts.source_of`` or a marker of ``classify_location``.
+ENGINE_SCOPES = (
+    "edge_masks",
+    "fd_tick",
+    "deliver",
+    "deliver_skip",
+    "cut_detection",
+    "invalidation",
+    "invalidation_skip",
+    "tally",
+    "classic",
+    "classic_skip",
+    "view_change",
+    "view_keep",
+    "observers",
+    "join_predecessors",
+    "sync_checksum",
+    "loop_result",
+)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a registered engine scope: a context
+    manager that also decorates a function. Metadata only — the compiled
+    program computes the same thing — and, like a dispatch phase, a name
+    outside :data:`ENGINE_SCOPES` raises here, at write time."""
+    if name not in ENGINE_SCOPES:
+        raise ValueError(
+            f"unregistered engine scope {name!r}; add it to "
+            f"rapid_tpu.utils.dispatch.ENGINE_SCOPES"
+        )
+    return jax.named_scope(name)
 
 
 class DispatchSeam:
@@ -71,25 +131,34 @@ class DispatchSeam:
         self.metrics.inc("engine_d2h_bytes", int(nbytes))
 
     @contextmanager
-    def _dispatch(self, entry: str):
-        """Time one device dispatch (and any fetch the caller performs
-        inside the block) into the bounded per-phase latency histogram
+    def _dispatch(self, entry: str, **tags):
+        """Time one driver operation (a device dispatch and any fetch the
+        caller performs inside the block, or an injection's host work) into
+        the bounded per-phase latency histogram
         (``engine_dispatch_ms{phase=<entry>}``) and bump the dispatch
         counter — the engine's per-dispatch observability grain. ``entry``
         must come from :data:`ENGINE_DISPATCH_PHASES`; a typo fails here,
-        at write time, instead of silently forking the series set."""
+        at write time, instead of silently forking the series set.
+
+        The same block is the span ``rapid:<entry>`` on the profiler's
+        clock, tagged ``seq`` (this driver's operation count, so the spans
+        of one commit or one wave read in order and nesting on the thread
+        gives the parent) and the caller's ``tags`` (the stream's
+        ``wave=<index>``). With no trace running the span is a flag test."""
         if entry not in ENGINE_DISPATCH_PHASES:
             raise ValueError(
                 f"unregistered engine dispatch phase {entry!r}; add it to "
                 f"rapid_tpu.utils.dispatch.ENGINE_DISPATCH_PHASES"
             )
         self.metrics.inc("engine_dispatches")
+        seq = self.metrics.counters["engine_dispatches"]
         start = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.metrics.record_ms(
-                "engine_dispatch",
-                (time.perf_counter() - start) * 1000.0,
-                phase=entry,
-            )
+        with annotate(SPAN_PREFIX + entry, seq=seq, **tags):
+            try:
+                yield
+            finally:
+                self.metrics.record_ms(
+                    "engine_dispatch",
+                    (time.perf_counter() - start) * 1000.0,
+                    phase=entry,
+                )

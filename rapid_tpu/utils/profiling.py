@@ -7,6 +7,11 @@ to TensorBoard-compatible traces without touching call sites:
     with trace("/tmp/rapid-trace"):
         vc.run_to_decision()
 
+Inside a trace every driver operation is a ``rapid:<phase>`` host span
+(``utils/dispatch.py``) and every device operation's op-name path carries the
+engine scope it was traced under (``ENGINE_SCOPES``), so Perfetto or xprof
+shows the host phases and the round's phases on one clock.
+
 Hardened for production use (bench.py wires it in as the opt-in
 ``--profile`` stage):
 
@@ -23,7 +28,7 @@ Hardened for production use (bench.py wires it in as the opt-in
 from __future__ import annotations
 
 import logging
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
 logger = logging.getLogger(__name__)
 
@@ -89,15 +94,16 @@ def trace(log_dir: str):
                 logger.warning("jax.profiler.stop_trace() failed: %r", exc)
 
 
-def annotate(name: str):
-    """Named trace span for host-side phases (shows up in the profile);
-    a no-op context manager when the profiler is unavailable."""
-    if profiler_available():
-        import jax
+def annotate(name: str, **tags):
+    """Named host span on the profiler's clock: THE place this package
+    makes a ``jax.profiler.TraceAnnotation`` (``DispatchSeam._dispatch``
+    opens its ``rapid:<phase>`` spans through here). ``tags`` become the
+    span's arguments in the trace (``seq=12``, ``wave=3``; a tag that is
+    None is left out). With no trace running the span costs a flag test;
+    jax 0.9 is the one installation (PR 21), so there is no probe and no
+    fallback."""
+    import jax
 
-        try:
-            return jax.profiler.TraceAnnotation(name)
-        except Exception as exc:  # noqa: BLE001 — same opt-in-diagnostic
-            # contract as trace(): degrade to a no-op span.
-            logger.warning("TraceAnnotation(%r) unavailable: %r", name, exc)
-    return nullcontext()
+    return jax.profiler.TraceAnnotation(
+        name, **{key: value for key, value in tags.items() if value is not None}
+    )

@@ -126,14 +126,16 @@ def test_dispatch_histogram_is_bounded_and_per_entrypoint():
     family = vc.metrics.phase_timings["engine_dispatch"]
     # Latencies land in the shared bounded instrument, keyed by entrypoint.
     assert isinstance(family["step"], LogHistogram)
-    assert set(family) <= {"step", "run_to_decision", "run_until_membership", "sync"}
-    assert family["step"].count == 40
+    # ... and the injection is a driver operation like any other.
+    assert set(family) <= {
+        "step", "run_to_decision", "run_until_membership", "sync", "inject_crash"}
+    assert family["step"].count == 40 and family["inject_crash"].count == 1
     summary = family["step"].summary()
     # Bounded memory: the summary is O(NUM_BUCKETS) however many dispatches
     # were recorded, and conserves the sample count.
     assert len(summary["buckets"]) <= NUM_BUCKETS + 1
     assert sum(summary["buckets"].values()) == 40
-    assert vc.metrics.counters["engine_dispatches"] == 41
+    assert vc.metrics.counters["engine_dispatches"] == 42
 
 
 def test_convergence_step_and_cut_counters():

@@ -1,7 +1,9 @@
 """utils/profiling hardening: graceful no-op where jax.profiler is missing
-or refuses to start, eager rejection of nested trace() blocks, and no-op
-annotate spans. (The happy path — a real trace landing on disk around a
-real convergence — is covered by tests/test_pallas_kernels.py.)
+or refuses to start, eager rejection of nested trace() blocks, and annotate
+as the one maker of trace annotations (no probe, no fallback; free while no
+trace runs). (The happy path — a real trace landing on disk around a real
+convergence — is covered by tests/test_pallas_kernels.py; the program's own
+spans in a real trace by tests/test_spans.py.)
 """
 
 import logging
@@ -42,8 +44,13 @@ def test_noop_when_profiler_unavailable(tmp_path, monkeypatch, caplog):
             ran.append(True)
     assert ran  # the block still executed
     assert any("unavailable" in r.message for r in caplog.records)
-    # annotate degrades to a no-op context manager.
-    with profiling.annotate("phase"):
+    # annotate has no probe of its own: it makes a real TraceAnnotation
+    # whatever trace() decided, and with no trace running the span is free.
+    import jax
+
+    span = profiling.annotate("phase", seq=1)
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    with span:
         ran.append(True)
     assert len(ran) == 2
 

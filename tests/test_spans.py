@@ -1,0 +1,267 @@
+"""One span vocabulary inside the program.
+
+Device: every scope of ``ENGINE_SCOPES`` is in the op-name metadata of the
+lowered programs that trace it, and both arms of the round's conditionals
+carry their own name. Host: every driver operation, the injection path
+included, is a ``rapid:<phase>`` span in the profiler's trace with a rising
+``seq``, and a streamed wave's enqueues and the fetch that retires it share a
+``wave``. The lowered texts are traced here (no compile); the traced drive
+runs in a process of its own, once for the module, because the programs it
+compiles must stay out of this session (tier-1 ends within 1 % of
+``vm.max_map_count``).
+"""
+
+import json
+import os
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import jax.numpy as jnp
+import pytest
+
+from rapid_tpu.utils import profiling
+from rapid_tpu.utils.dispatch import ENGINE_DISPATCH_PHASES, ENGINE_SCOPES, scope
+
+REPO = Path(__file__).resolve().parent.parent
+
+ROUND = {"edge_masks", "fd_tick", "deliver", "deliver_skip", "cut_detection",
+         "invalidation", "tally", "classic", "view_change"}
+#: Arms that return their operands trace no operation, so no op name can
+#: carry them: the names are in the code (and in the vocabulary) for the day
+#: the compiler or a later change gives those arms work of their own.
+IDENTITY_ARMS = {"invalidation_skip", "classic_skip", "view_keep"}
+EXPECTED = {
+    "run_until_membership": ROUND | {"loop_result"},
+    "engine_step": ROUND,
+    "fleet_step": ROUND,
+    "engine_step_trace": ROUND | {"observers"},
+    "sync_checksum": {"sync_checksum"},
+    "predecessor_of_keys": {"join_predecessors"},
+}
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")
+
+
+def _paths(lowered) -> set:
+    """The op-name paths of a lowered program's debug locations."""
+    return {p for p in re.findall(r'"([^"\n]*)"', lowered.as_text(debug_info=True)) if "/" in p}
+
+
+def _scopes(paths) -> set:
+    found = set()
+    for path in paths:
+        for part in path.split("/"):
+            while part not in ENGINE_SCOPES and _WRAPPED.match(part):
+                part = _WRAPPED.match(part).group(1)  # vmap(fd_tick) -> fd_tick
+            if part in ENGINE_SCOPES:
+                found.add(part)
+    return found
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    from rapid_tpu.models import virtual_cluster as vcm
+    from rapid_tpu.ops.rings import predecessor_of_keys
+    from rapid_tpu.tenancy import fleet as fleetm
+
+    kw = dict(n_slots=32, k=3, h=3, l=1, cohorts=2, fd_threshold=2,
+              delivery_spread=1, concurrent_coordinators=2)
+    vc = vcm.VirtualCluster.create(28, **kw)
+    traced = vcm.VirtualCluster.create(28, telemetry=True, trace=4, **kw)
+    fleet = fleetm.TenantFleet.create(
+        2, 28, n_slots=32, k=3, cohorts=2, knobs=[(3, 1, 2)] * 2, delivery_spread=1)
+    i32, s = jnp.int32, vc.state
+    idx = jnp.arange(28, 30)
+    return {
+        "run_until_membership": vcm.run_until_membership.lower(
+            vc.cfg, s, vc.faults, i32(28), i32(16), 4, i32(1)),
+        "engine_step": vcm.engine_step.lower(vc.cfg, s, vc.faults),
+        "fleet_step": fleetm.fleet_step.lower(fleet.cfg, fleet.state, fleet.faults, fleet.knobs),
+        "engine_step_trace": vcm.engine_step_trace.lower(
+            traced.cfg, traced.state, traced.telem, traced.trace_ring, traced.faults),
+        "sync_checksum": vcm.sync_checksum.lower(s, vc.faults),
+        "predecessor_of_keys": predecessor_of_keys.lower(
+            s.key_hi, s.key_lo, s.alive, s.key_hi[:, idx], s.key_lo[:, idx], perm=s.ring_perm),
+    }
+
+
+@pytest.mark.parametrize("program", sorted(EXPECTED))
+def test_lowered_program_carries_its_scopes(lowered, program):
+    assert _scopes(_paths(lowered[program])) == EXPECTED[program]
+
+
+def test_every_registered_scope_is_traced_somewhere_or_is_an_identity_arm():
+    assert set().union(*EXPECTED.values()) | IDENTITY_ARMS == set(ENGINE_SCOPES)
+    assert len(set(ENGINE_SCOPES)) == len(ENGINE_SCOPES)
+
+
+@pytest.mark.parametrize("program", ["run_until_membership", "engine_step"])
+@pytest.mark.parametrize("arms", [
+    ("deliver", "deliver_skip"), ("invalidation",), ("classic",), ("view_change",)])
+def test_each_arm_of_a_conditional_carries_its_own_name(lowered, program, arms):
+    paths = _paths(lowered[program])
+    branches = {}
+    for path in paths:
+        match = re.search(r"cond/(branch_\d+_fun)/(\w+)", path)
+        if match and match.group(2) in arms:
+            branches.setdefault(match.group(2), set()).add(match.group(1))
+    # every arm that traces an operation is named, and no branch has two names
+    assert set(branches) == set(arms)
+    assert all(len(found) == 1 for found in branches.values())
+    assert len({next(iter(found)) for found in branches.values()}) == len(arms)
+
+
+def test_under_vmap_both_arms_are_traced_into_the_fleet_step(lowered):
+    # vmap turns the per-cluster cond into a select: both arms run, and the
+    # names ride inside the transform's brackets
+    paths = _paths(lowered["fleet_step"])
+    assert any("vmap(view_change)" in p for p in paths)
+    assert any("vmap(deliver)" in p for p in paths) and any("vmap(deliver_skip)" in p for p in paths)
+
+
+def test_scope_names_keep_clear_of_the_hlo_gates_needles():
+    from rapid_tpu.parallel import hlo_facts
+
+    for name in ENGINE_SCOPES:
+        assert hlo_facts.source_of(name) == "other", name
+        assert hlo_facts.classify_location("jit(f)/" + name + "/add") == "prologue", name
+
+
+def test_benchmarks_scope_list_is_the_programs():
+    with open(REPO / "benchmarks" / "scopes.json", encoding="utf-8") as handle:
+        assert json.load(handle)["scopes"] == list(ENGINE_SCOPES)
+
+
+@pytest.mark.parametrize("kind", ["scope", "phase"])
+def test_an_unregistered_name_raises_at_write_time(kind):
+    from rapid_tpu.models.virtual_cluster import VirtualCluster
+
+    if kind == "scope":
+        with pytest.raises(ValueError, match="unregistered engine scope 'fd_tik'"):
+            scope("fd_tik")
+        assert "fd_tick" in ENGINE_SCOPES
+        return
+    vc = VirtualCluster.create(12, n_slots=16, k=3, h=3, l=1)
+    with pytest.raises(ValueError, match="unregistered engine dispatch phase 'inject_crsh'"):
+        with vc._dispatch("inject_crsh"):
+            pass
+    assert {"inject_crash", "inject_join_admit", "inject_join_place"} <= ENGINE_DISPATCH_PHASES
+
+
+def test_annotate_is_a_trace_annotation_with_tags_and_free_without_a_trace():
+    import jax
+
+    span = profiling.annotate("rapid:sync", seq=3, wave=None)
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    with span:  # no trace running: nothing is recorded, nothing raises
+        pass
+
+
+def test_one_construction_site_for_trace_annotations():
+    sites = [
+        str(path.relative_to(REPO)) for path in (REPO / "rapid_tpu").rglob("*.py")
+        if re.search(r"TraceAnnotation\(", path.read_text(encoding="utf-8"))
+    ]
+    assert sites == ["rapid_tpu/utils/profiling.py"]
+
+
+# -- the host spans in a real trace ---------------------------------------------
+
+_DRIVE = r"""
+import glob, json, sys
+import jax
+from rapid_tpu.models.virtual_cluster import VirtualCluster
+from rapid_tpu.serving.stream import StreamDriver, StreamWave
+from rapid_tpu.utils import profiling
+
+def build():
+    vc = VirtualCluster.create(28, n_slots=40, k=3, h=3, l=1, cohorts=2, fd_threshold=2)
+    vc.assign_cohorts_roundrobin()
+    vc.sync()
+    return vc
+
+def commit(vc, victim, joiner, check):
+    vc.crash([victim])
+    vc.inject_join_wave([joiner], check_admissible=check)
+    vc.sync()
+    return vc.run_until_membership(28, max_steps=64, max_cuts=4, min_cuts=1)
+
+def stream(vc, first):
+    # No opportunistic reaping: every wave is then retired by a blocking fetch,
+    # whatever the machine's speed.
+    driver = StreamDriver(vc, rounds_per_wave=2, depth=2, ticket_ready=lambda index, ticket: False)
+    for i in range(4):
+        driver.submit(StreamWave(crash=(first + i,), join=()))
+    driver.drain()
+
+warm, checked, unchecked, streamed = build(), build(), build(), build()
+commit(warm, 1, 30, True); commit(warm, 2, 31, False); stream(warm, 5)  # compile everything first
+out = {}
+for name, work in (
+    ("checked", lambda: commit(checked, 1, 30, True)),
+    ("unchecked", lambda: commit(unchecked, 1, 30, False)),
+    ("streamed", lambda: stream(streamed, 5)),
+):
+    where = sys.argv[1] + "/" + name
+    with profiling.trace(where):
+        work()
+    data = jax.profiler.ProfileData.from_file(glob.glob(where + "/**/*.xplane.pb", recursive=True)[0])
+    spans = [
+        (e.start_ns, e.name, {k: v for k, v in dict(e.stats).items() if not k.startswith("_")})
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events if e.name.startswith("rapid:")
+    ]
+    out[name] = [[n, s] for _, n, s in sorted(spans, key=lambda t: t[0])]
+print("SPANS " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def spans(tmp_path_factory):
+    done = subprocess.run(
+        [sys.executable, "-c", _DRIVE, str(tmp_path_factory.mktemp("spans"))],
+        cwd=str(REPO), env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=600,
+    )
+    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-4000:]
+    line = next(l for l in done.stdout.splitlines() if l.startswith("SPANS "))
+    return json.loads(line[len("SPANS "):])
+
+
+def test_a_commit_leaves_its_host_spans_with_rising_seq(spans):
+    names = [name for name, _ in spans["checked"]]
+    assert names == [
+        "rapid:inject_crash", "rapid:inject_join_admit", "rapid:inject_join_place",
+        "rapid:sync", "rapid:run_until_membership",
+    ]
+    seqs = [tags["seq"] for _, tags in spans["checked"]]
+    assert seqs == sorted(seqs) and len(set(seqs)) == len(seqs)
+    assert seqs == list(range(seqs[0], seqs[0] + 5))  # the driver's own operation count
+
+
+def test_an_unchecked_join_wave_opens_no_admit_span(spans):
+    names = [name for name, _ in spans["unchecked"]]
+    assert "rapid:inject_join_admit" not in names
+    assert names == [
+        "rapid:inject_crash", "rapid:inject_join_place", "rapid:sync", "rapid:run_until_membership",
+    ]
+
+
+def test_a_waves_enqueues_and_the_fetch_that_retires_it_share_a_wave(spans):
+    enqueued, fetched = {}, {}
+    for name, tags in spans["streamed"]:
+        if name == "rapid:stream_enqueue":
+            enqueued.setdefault(tags["wave"], []).append(tags["seq"])
+        elif name == "rapid:stream_fetch" and "wave" in tags:
+            fetched[tags["wave"]] = tags["seq"]
+    assert sorted(enqueued) == [0, 1, 2, 3] and all(len(v) == 2 for v in enqueued.values())
+    assert sorted(fetched) == [0, 1, 2, 3]  # every wave is retired by a fetch that names it
+    for wave, seq in fetched.items():
+        assert seq > max(enqueued[wave])
+    # depth 2: wave 0 is retired only after wave 1 was enqueued
+    assert fetched[0] > max(enqueued[1])
+    # the constructor's and the drain's own fetches carry no wave
+    assert any(name == "rapid:stream_fetch" and "wave" not in tags for name, tags in spans["streamed"])
+    # the crash before each wave's rounds is a span too
+    assert sum(name == "rapid:inject_crash" for name, _ in spans["streamed"]) == 4
