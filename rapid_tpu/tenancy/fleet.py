@@ -28,11 +28,23 @@ replica groups, and the ``device_program`` gate freezes that budget
 Batched-control-flow tradeoffs, stated plainly:
 
 - vmap turns the per-cluster ``lax.cond`` view-change gate into a select —
-  the commit math (sort-free ring rebuild, O(N) scans) runs every round and
-  is masked away for undecided tenants. For fleet deployments (hundreds of
-  SMALL clusters, ~1K members each) that is a constant factor on a round
-  body of the same order, not a scale break; the 1M-member single-cluster
-  path keeps its gated commit untouched.
+  the commit math (sort-free ring rebuild, O(N) scans) runs for every
+  tenant in every round and is masked away for the undecided. The chip said
+  what that costs (ledger, PR 25, 256 tenants of 1,000 under a trickle):
+  114 ms of view change on top of a round whose own phases take 31 ms, in
+  seven rounds of eight in which no tenant decided. So there are two step
+  programs, chosen by whether the caller hands a mesh, never by a knob:
+  the MESHLESS step the drivers dispatch (:func:`fleet_step_gated_impl`)
+  vmaps ``_compute_round`` alone and applies the view change under ONE
+  scalar ``lax.cond(any(decided))`` taken outside the vmap — a round in
+  which nobody decided skips the rebuild, a round in which anybody did pays
+  it for all (per-tenant select inside the arm), and
+  ``engine_fleet_commit_rounds`` counts how often that is. On a
+  ``'tenant'``-sharded mesh that any() is a cross-tenant reduce, which the
+  zero-cross-tenant budget forbids, so :func:`fleet_step_impl` (behind
+  :func:`make_fleet_step` and the analyzers' ladder) keeps the lockstep
+  select unchanged; a per-shard gate (``shard_map``, local any) belongs
+  with the four-chip cell that can measure it (ROADMAP B7).
 - the fleet wave runs LOCKSTEP: a ``fori_loop`` over the step budget with
   per-tenant freeze masking, instead of a batched while. A batched while's
   predicate is an any() across tenants — a cross-tenant collective in the
@@ -66,8 +78,6 @@ from rapid_tpu.models.virtual_cluster import (
     _compute_round,
     apply_view_change_impl,
     engine_step_impl,
-    engine_step_telem_impl,
-    engine_step_trace_impl,
     run_to_decision_impl,
     run_to_decision_telem_impl,
     run_to_decision_trace_impl,
@@ -84,7 +94,7 @@ from rapid_tpu.parallel.mesh import (
     match_partition_rules,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
-from rapid_tpu.utils.dispatch import DispatchSeam
+from rapid_tpu.utils.dispatch import DispatchSeam, scope
 from rapid_tpu.utils.health import NodeHealth
 from rapid_tpu.utils.metrics import Metrics
 
@@ -177,6 +187,60 @@ def fleet_step_impl(
     return jax.vmap(one)(state, faults, knobs)
 
 
+def fleet_step_gated_impl(
+    cfg: EngineConfig,
+    state: EngineState,
+    faults: FaultInputs,
+    knobs: TenantKnobs,
+    commit_rounds,
+    telem: Optional[TelemetryLanes] = None,
+    trace: Optional[TraceRing] = None,
+):
+    """The MESHLESS fleet step the drivers dispatch (module docstring): one
+    protocol round for every tenant with the view change under ONE scalar
+    gate. ``_compute_round`` is vmapped alone; the commit —
+    ``apply_view_change_impl`` vmapped, then the per-tenant select — sits in
+    the taken arm of ``lax.cond(any(decided))`` outside the vmap, so a round
+    in which no tenant decided runs no ring rebuild. Per-tenant results are
+    bit-identical to :func:`fleet_step_impl` (and to B separate
+    ``VirtualCluster.step`` runs): the same two functions on the same
+    values, only the place of the condition differs.
+
+    ``telem``/``trace`` ride along as optional pytrees exactly as
+    ``_compute_round`` takes them (``None`` traces no observer code), so the
+    three jitted spellings below are this one body. ``commit_rounds`` is the
+    device-carried int32 behind ``engine_fleet_commit_rounds``: the rounds in
+    which the gate opened, fetched only at the driver's host-sync boundaries.
+
+    Returns ``(state, commit_rounds, events, telem, trace)``."""
+
+    def one_round(state, faults, kn, telem, trace):
+        out = _compute_round(_tenant_cfg(cfg, kn), state, faults, None, telem, trace)
+        return out + (None,) * (6 - len(out))  # absent observers stay None
+
+    round_state, decided, winner, events, telem, trace = jax.vmap(one_round)(
+        state, faults, knobs, telem, trace
+    )
+
+    def commit_one(kn, round_state, winner, decided):
+        committed = apply_view_change_impl(_tenant_cfg(cfg, kn), round_state, winner)
+        with scope("view_change"):
+            return jax.tree_util.tree_map(
+                lambda com, rnd: jnp.where(decided, com, rnd), committed, round_state
+            )
+
+    any_decided = jnp.any(decided)
+    new_state = jax.lax.cond(
+        any_decided,
+        lambda s: jax.vmap(commit_one)(knobs, s, winner, decided),
+        scope("view_keep")(lambda s: s),
+        round_state,
+    )
+    return (
+        new_state, commit_rounds + any_decided.astype(jnp.int32), events, telem, trace
+    )
+
+
 def fleet_run_to_decision_impl(
     cfg: EngineConfig,
     state: EngineState,
@@ -264,10 +328,11 @@ def fleet_wave_impl(
 
 # ---------------------------------------------------------------------------
 # Device telemetry plane, fleet grain: the SAME TelemetryLanes pytree with a
-# leading [t] axis, threaded through vmapped twins of the entrypoints above.
-# These are separate entrypoints (never default arguments on the existing
-# ones) so a telemetry=0 fleet keeps compiling byte-identical programs —
-# the hlo.lock.json gate holds the existing fleet3d entries frozen.
+# leading [t] axis, threaded through vmapped twins of the convergence and
+# wave entrypoints above (the gated step takes the lanes as an optional
+# pytree instead). These are separate entrypoints (never default arguments
+# on the existing ones) so a telemetry=0 fleet keeps compiling byte-identical
+# programs — the hlo.lock.json gate holds the existing fleet3d entries frozen.
 # ---------------------------------------------------------------------------
 
 
@@ -278,24 +343,6 @@ def initial_fleet_telemetry(cfg: EngineConfig, tenants: int) -> TelemetryLanes:
         lambda x: jnp.zeros((tenants,) + x.shape, x.dtype),
         initial_telemetry(cfg),
     )
-
-
-def fleet_step_telem_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-) -> Tuple[EngineState, TelemetryLanes, StepEvents]:
-    """:func:`fleet_step_impl` with per-tenant telemetry lanes riding along
-    (``engine_step_telem_impl`` vmapped). Per-tenant counters are
-    bit-identical to B separate telemetry-enabled ``VirtualCluster`` steps —
-    the lanes vmap exactly like the state they observe."""
-
-    def one(state, telem, faults, kn):
-        return engine_step_telem_impl(_tenant_cfg(cfg, kn), state, telem, faults)
-
-    return jax.vmap(one)(state, telem, faults, knobs)
 
 
 def fleet_run_to_decision_telem_impl(
@@ -400,27 +447,6 @@ def initial_fleet_trace(cfg: EngineConfig, tenants: int) -> TraceRing:
         lambda x: jnp.zeros((tenants,) + x.shape, x.dtype),
         initial_trace(cfg),
     )
-
-
-def fleet_step_trace_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    trace: TraceRing,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-) -> Tuple[EngineState, TelemetryLanes, TraceRing, StepEvents]:
-    """:func:`fleet_step_telem_impl` with per-tenant trace rings riding
-    along (``engine_step_trace_impl`` vmapped). Each tenant's ring records
-    ITS OWN rounds — cursor, wraps, and records are bit-identical to B
-    separate trace-enabled ``VirtualCluster`` steps."""
-
-    def one(state, telem, trace, faults, kn):
-        return engine_step_trace_impl(
-            _tenant_cfg(cfg, kn), state, telem, trace, faults
-        )
-
-    return jax.vmap(one)(state, telem, trace, faults, knobs)
 
 
 def fleet_run_to_decision_trace_impl(
@@ -555,7 +581,11 @@ def tenant_health_impl(cfg: EngineConfig, state: EngineState) -> jnp.ndarray:
 
 tenant_health = jax.jit(tenant_health_impl, static_argnums=(0,))  # donate-ok: read-only health reduction — the state must survive the scan
 
-fleet_step = jax.jit(fleet_step_impl, static_argnums=(0,), donate_argnums=(1,))
+# The three spellings of the gated step (state and the commit-round counter
+# always donated; the observers' lanes where they ride).
+fleet_step = jax.jit(
+    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4)
+)
 fleet_run_to_decision = jax.jit(
     fleet_run_to_decision_impl, static_argnums=(0,), donate_argnums=(1,)
 )
@@ -564,7 +594,7 @@ fleet_wave = jax.jit(
 )
 
 fleet_step_telem = jax.jit(
-    fleet_step_telem_impl, static_argnums=(0,), donate_argnums=(1, 2)
+    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5)
 )
 fleet_run_to_decision_telem = jax.jit(
     fleet_run_to_decision_telem_impl, static_argnums=(0,), donate_argnums=(1, 2)
@@ -576,7 +606,7 @@ fleet_wave_telem = jax.jit(
 fleet_telemetry_digest = jax.jit(jax.vmap(telemetry_digest_impl))
 
 fleet_step_trace = jax.jit(
-    fleet_step_trace_impl, static_argnums=(0,), donate_argnums=(1, 2, 3)
+    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5, 6)
 )
 fleet_run_to_decision_trace = jax.jit(
     fleet_run_to_decision_trace_impl,
@@ -682,6 +712,11 @@ class TenantFleet(DispatchSeam):
         # tenant -> raw frozen membership captured at quarantine time (the
         # per-tenant freeze-lane inputs; see quarantine()).
         self._quarantined: dict = {}
+        # Rounds in which the step's view-change gate opened: carried on the
+        # device (the jitted step adds to it), mirrored into
+        # engine_fleet_commit_rounds only at host-sync boundaries.
+        self._commit_rounds = jnp.zeros((), dtype=jnp.int32)
+        self._commit_rounds_stale = False
         # Device telemetry plane: per-tenant lanes + the host-side activity
         # cache, zero-minted at attach (every series exists from scrape 0)
         # and refreshed ONLY at host-sync boundaries.
@@ -828,22 +863,20 @@ class TenantFleet(DispatchSeam):
         (and the span's tags) differ, so a change here cannot diverge the
         streamed path from the batch path the bit-identity tests pin."""
         self.metrics.inc("engine_tenant_rounds", self.b)
+        self._commit_rounds_stale = True
+        step = (
+            fleet_step_trace if self.trace_ring is not None
+            else fleet_step_telem if self.telem is not None
+            else fleet_step
+        )
         with self._dispatch(phase, **tags):
-            if self.trace_ring is not None:
-                self.state, self.telem, self.trace_ring, events = (
-                    fleet_step_trace(
-                        self.cfg, self.state, self.telem, self.trace_ring,
-                        self.faults, self.knobs,
-                    )
-                )
-            elif self.telem is not None:
-                self.state, self.telem, events = fleet_step_telem(
-                    self.cfg, self.state, self.telem, self.faults, self.knobs
-                )
-            else:
-                self.state, events = fleet_step(
-                    self.cfg, self.state, self.faults, self.knobs
-                )
+            (
+                self.state, self._commit_rounds, events,
+                self.telem, self.trace_ring,
+            ) = step(
+                self.cfg, self.state, self.faults, self.knobs,
+                self._commit_rounds, self.telem, self.trace_ring,
+            )
         return events
 
     def stream_crash(self, pairs) -> None:
@@ -899,6 +932,7 @@ class TenantFleet(DispatchSeam):
                 )
             )
         self._account_d2h(obs.nbytes)
+        self._refresh_commit_rounds()
         rounds = obs[0]
         was_decided = obs[1].astype(bool)
         self.metrics.inc("engine_tenant_rounds", int(rounds.sum()))
@@ -989,7 +1023,9 @@ class TenantFleet(DispatchSeam):
     def _refresh_activity(self) -> None:
         """Refresh the per-tenant activity cache from the device lanes —
         called ONLY at host-sync boundaries (sync / health_scan / the
-        stream driver's fetch seam), never on the dispatch hot path."""
+        stream driver's fetch seam), never on the dispatch hot path. The
+        step's commit-round counter rides the same boundaries."""
+        self._refresh_commit_rounds()
         if self.telem is None:
             return
         # telemetry-fetch-ok: host-sync boundary — the caller is already
@@ -1011,6 +1047,24 @@ class TenantFleet(DispatchSeam):
                 engine_telemetry.trace_summary(tdigest[t], self.cfg.trace)
                 for t in range(self.b)
             ]
+
+    def _refresh_commit_rounds(self) -> None:
+        """Mirror the device-carried count of rounds in which the step's
+        view-change gate opened into ``engine_fleet_commit_rounds`` (one
+        4-byte fetch, charged; host-sync boundaries only, and only if a step
+        ran since the last one — a fleet driven by the fused loops pays
+        nothing). Over the count of ``engine_dispatch_ms{phase="fleet_step"|
+        "stream_enqueue"}`` it is the share of fleet rounds that paid a view
+        change."""
+        if not self._commit_rounds_stale:
+            return
+        total = int(self._commit_rounds)  # host-sync-ok: the caller's boundary
+        self._account_d2h(4)
+        self.metrics.inc(
+            "engine_fleet_commit_rounds",
+            total - self.metrics.counters.get("engine_fleet_commit_rounds", 0),
+        )
+        self._commit_rounds_stale = False
 
     @property
     def activity(self) -> Optional[dict]:
@@ -1199,6 +1253,9 @@ class TenantFleet(DispatchSeam):
                     "tenant_rounds_total": int(tenant_rounds),
                     "tenant_cuts_total": int(
                         counters.get("engine_tenant_cuts", 0)
+                    ),
+                    "fleet_commit_rounds_total": int(
+                        counters.get("engine_fleet_commit_rounds", 0)
                     ),
                     "tenant_rounds_per_dispatch": round(
                         tenant_rounds / dispatches, 3

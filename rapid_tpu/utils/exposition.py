@@ -95,6 +95,10 @@ TENANCY_KNOWN_COUNTERS = (
     "engine_tenant_rounds",
     "engine_tenant_cuts",
     "engine_tenant_quarantines",
+    # Fleet rounds in which the step's view-change gate opened (any tenant
+    # decided): over the fleet_step/stream_enqueue dispatch count, the share
+    # of fleet rounds that paid a view change (tenancy/fleet.py).
+    "engine_fleet_commit_rounds",
 )
 
 #: Streaming-tier counters zero-filled on snapshots whose ``engine`` section

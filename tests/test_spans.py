@@ -18,6 +18,7 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -36,6 +37,7 @@ EXPECTED = {
     "run_until_membership": ROUND | {"loop_result"},
     "engine_step": ROUND,
     "fleet_step": ROUND,
+    "mesh_fleet_step": ROUND,
     "engine_step_trace": ROUND | {"observers"},
     "sync_checksum": {"sync_checksum"},
     "predecessor_of_keys": {"join_predecessors"},
@@ -63,6 +65,7 @@ def _scopes(paths) -> set:
 def lowered():
     from rapid_tpu.models import virtual_cluster as vcm
     from rapid_tpu.ops.rings import predecessor_of_keys
+    from rapid_tpu.parallel.mesh import make_mesh
     from rapid_tpu.tenancy import fleet as fleetm
 
     kw = dict(n_slots=32, k=3, h=3, l=1, cohorts=2, fd_threshold=2,
@@ -77,7 +80,11 @@ def lowered():
         "run_until_membership": vcm.run_until_membership.lower(
             vc.cfg, s, vc.faults, i32(28), i32(16), 4, i32(1)),
         "engine_step": vcm.engine_step.lower(vc.cfg, s, vc.faults),
-        "fleet_step": fleetm.fleet_step.lower(fleet.cfg, fleet.state, fleet.faults, fleet.knobs),
+        "fleet_step": fleetm.fleet_step.lower(
+            fleet.cfg, fleet.state, fleet.faults, fleet.knobs, i32(0)),
+        "mesh_fleet_step": fleetm.make_fleet_step(
+            fleet.cfg, make_mesh(jax.devices()[:8], shape=(2, 2, 2)),
+        ).lower(fleet.state, fleet.faults, fleet.knobs),
         "engine_step_trace": vcm.engine_step_trace.lower(
             traced.cfg, traced.state, traced.telem, traced.trace_ring, traced.faults),
         "sync_checksum": vcm.sync_checksum.lower(s, vc.faults),
@@ -113,11 +120,27 @@ def test_each_arm_of_a_conditional_carries_its_own_name(lowered, program, arms):
 
 
 def test_under_vmap_both_arms_are_traced_into_the_fleet_step(lowered):
-    # vmap turns the per-cluster cond into a select: both arms run, and the
-    # names ride inside the transform's brackets
+    # vmap turns a per-cluster cond into a select: both arms run, and the
+    # names ride inside the transform's brackets. That holds for the round's
+    # own conditionals in both fleet steps, and for the view change in the
+    # mesh's lockstep step, which has no conditional around it.
+    for program in ("fleet_step", "mesh_fleet_step"):
+        paths = _paths(lowered[program])
+        assert any("vmap(view_change)" in p for p in paths)
+        assert any("vmap(deliver)" in p for p in paths) and any("vmap(deliver_skip)" in p for p in paths)
+    assert not any("cond/" in p for p in _paths(lowered["mesh_fleet_step"]))
+
+
+def test_the_meshless_fleet_step_gates_its_view_change_on_one_conditional(lowered):
+    # the step the drivers dispatch: the vmapped view change lies under one
+    # arm of one scalar conditional taken outside the vmap, and nowhere else
     paths = _paths(lowered["fleet_step"])
-    assert any("vmap(view_change)" in p for p in paths)
-    assert any("vmap(deliver)" in p for p in paths) and any("vmap(deliver_skip)" in p for p in paths)
+    view_change = {p for p in paths if "view_change" in p}
+    arms = {re.search(r"(?:^|/)cond/(branch_\d+_fun)/vmap\(view_change\)/", p) for p in view_change}
+    assert view_change and None not in arms
+    assert len({m.group(1) for m in arms}) == 1
+    # the other arm returns its operand: it traces no operation (IDENTITY_ARMS)
+    assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(paths)))
 
 
 def test_scope_names_keep_clear_of_the_hlo_gates_needles():

@@ -20,6 +20,7 @@ fleet, and the ShardingShapeError/pad_to_multiple discipline for a tenant
 count that does not divide the tenant axis.
 """
 
+import functools
 import random
 
 import numpy as np
@@ -180,6 +181,120 @@ def test_grid_wave_parity_multi_phase():
 # ---------------------------------------------------------------------------
 # Knob discipline
 # ---------------------------------------------------------------------------
+
+
+# ---------------------------------------------------------------------------
+# The gated step (one scalar cond(any(decided)) outside the vmap — the
+# program the drivers dispatch) against the lockstep select (vmap of the
+# per-cluster step — the mesh program): every leaf equal after every round.
+# ---------------------------------------------------------------------------
+
+GATE_TENANTS = 4
+#: Which tenants lose a member before round 0, per scenario.
+GATE_SCENARIOS = {"none_decides": (), "some_decide": (0, 2), "all_decide": (0, 1, 2, 3)}
+GATE_SPELLINGS = {
+    "plain": {},
+    "telem": {"telemetry": True},
+    "trace": {"telemetry": True, "trace": 4},
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _lockstep_step(spelling):
+    """The lockstep reference for a spelling: ``fleet_step_impl`` itself, or
+    the same vmap of the per-cluster step with the observers riding (what
+    ``fleet_step_impl`` is to ``engine_step_impl``). One jit a spelling."""
+    from rapid_tpu.models import virtual_cluster as vcm
+    from rapid_tpu.tenancy import fleet as fleetm
+
+    per_cluster = {
+        "telem": vcm.engine_step_telem_impl,
+        "trace": vcm.engine_step_trace_impl,
+    }.get(spelling)
+
+    def step(cfg, state, faults, knobs, *observers):
+        if per_cluster is None:
+            return fleetm.fleet_step_impl(cfg, state, faults, knobs)
+        return jax.vmap(
+            lambda st, ft, kn, *obs: per_cluster(
+                fleetm._tenant_cfg(cfg, kn), st, *obs, ft
+            )
+        )(state, faults, knobs, *observers)
+
+    return jax.jit(step, static_argnums=(0,))
+
+
+@pytest.mark.parametrize("scenario", sorted(GATE_SCENARIOS))
+@pytest.mark.parametrize("spelling", sorted(GATE_SPELLINGS))
+def test_gated_step_is_bit_identical_to_the_lockstep_step(spelling, scenario):
+    victims = GATE_SCENARIOS[scenario]
+    fleet = TenantFleet.create(
+        GATE_TENANTS, 28, n_slots=32, k=3, cohorts=2,
+        knobs=[(3, 1, 2)] * GATE_TENANTS, delivery_spread=1,
+        **GATE_SPELLINGS[spelling],
+    )
+    for t in victims:
+        fleet.faults = fleet.faults._replace(
+            crashed=fleet.faults.crashed.at[t, 3 + t].set(True)
+        )
+    reference = _lockstep_step(spelling)
+    copy = lambda tree: jax.tree_util.tree_map(jnp.copy, tree)
+    ref_state = copy(fleet.state)
+    ref_observers = tuple(
+        copy(o) for o in (fleet.telem, fleet.trace_ring) if o is not None
+    )
+    decided_rounds = []
+    for _ in range(8):
+        events = fleet.step()
+        ref_state, *ref_observers, ref_events = reference(
+            fleet.cfg, ref_state, fleet.faults, fleet.knobs, *ref_observers
+        )
+        got = (fleet.state, events, fleet.telem, fleet.trace_ring)
+        want = (ref_state, ref_events, *ref_observers)
+        got_leaves, want_leaves = map(jax.tree_util.tree_leaves, (got, want))
+        assert len(got_leaves) == len(want_leaves)
+        for ours, theirs in zip(got_leaves, want_leaves):
+            assert ours.dtype == theirs.dtype
+            np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+        decided_rounds.append(np.asarray(events.decided))
+    decided_rounds = np.stack(decided_rounds)
+    # the scenario is what it says: exactly the victims' tenants decide, and
+    # (unless all do at once) some round passes with the gate shut
+    assert set(np.nonzero(decided_rounds.any(axis=0))[0].tolist()) == set(victims)
+    assert (~decided_rounds.any(axis=1)).any()
+    assert int(fleet._commit_rounds) == int(decided_rounds.any(axis=1).sum())
+
+
+def test_commit_round_counter_rides_the_fetch_boundaries_only():
+    """``engine_fleet_commit_rounds``: carried on the device by the streamed
+    step (no fetch, no byte moves while waves are submitted) and mirrored at
+    the drain; it counts the rounds whose events had any decision."""
+    from rapid_tpu.serving.stream import FleetWave, StreamDriver
+
+    fleet = TenantFleet.create(
+        4, 28, n_slots=32, k=3, cohorts=2, knobs=[(3, 1, 2)] * 4,
+        delivery_spread=1,
+    )
+    driver = StreamDriver(fleet, rounds_per_wave=8, depth=2)
+    seen, stream_step = [], fleet.stream_step
+
+    def recording_step(wave=None):
+        seen.append(stream_step(wave=wave))
+        return seen[-1]
+
+    fleet.stream_step = recording_step
+    d2h = fleet.metrics.counters["engine_d2h_bytes"]
+    for crashes in [((0, 3), (2, 5)), ((1, 4),), ((0, 6), (3, 7))]:
+        driver.submit(FleetWave(crash=crashes))
+        assert fleet.metrics.counters["engine_d2h_bytes"] == d2h
+    assert fleet.metrics.counters.get("engine_fleet_commit_rounds", 0) == 0
+    driver.drain()
+    opened = sum(bool(np.asarray(ev.decided).any()) for ev in seen)
+    assert len(seen) == 24 and 3 <= opened < 24
+    assert fleet.metrics.counters["engine_fleet_commit_rounds"] == opened
+    assert fleet.metrics.counters["engine_d2h_bytes"] > d2h
+    tenancy = fleet.telemetry_snapshot()["engine"]["tenancy"]
+    assert tenancy["fleet_commit_rounds_total"] == opened
 
 
 def test_fleet_rejects_mismatched_static_geometry():
