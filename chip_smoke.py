@@ -13,8 +13,9 @@ is one process, imports jax once, and exits nonzero unless the platform is
   outcomes, then repeats on fresh state with zero compiles allowed;
 - 1,000,000 members, 8 cohorts, 1 % crash in one ``run_to_decision``;
 - one profiler trace, which must hold a device plane with events;
-- on a four-device host, the 1M crash again as ``make_sharded_wave`` over a
-  ('cohort','nodes') = (2,2) mesh, with every device holding its shards.
+- on a four-device host, the 1M crash again through the same driver built on
+  a ('cohort','nodes') = (2,2) mesh (``VirtualCluster.create(..., mesh=...)``),
+  with every device holding its shards.
 
 Each stage prints one line when it completes, so a failure names its stage.
 Every time printed is a smoke reading of set-up cost, not a result. The last
@@ -82,9 +83,9 @@ def run_smoke(
     """The smoke's body. ``use_pallas`` is passed, never detected. ``twin``
     re-runs the churn on the other delivery core and compares; ``trace``
     captures and checks one profiler trace; ``mesh_devices`` (four devices)
-    adds the sharded 1M-shape wave. Returns the compile totals it observed."""
+    adds the 1M-shape crash on a sharded cluster. Returns the compile totals
+    it observed."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from rapid_tpu.models.virtual_cluster import VirtualCluster
@@ -165,10 +166,12 @@ def run_smoke(
     # -- 1M members, 1 % crash, one single-dispatch convergence --
     n_crash_xl = n_xl // 100
 
-    def build_xl():
+    def build_xl(mesh=None):
+        # use_pallas stays off under a mesh (state.py: EngineConfig.use_pallas).
         vcx = VirtualCluster.create(
             n_xl, k=10, h=9, l=4, cohorts=cohorts_xl, fd_threshold=3, seed=7,
-            use_pallas=use_pallas, delivery_spread=2, pallas_lanes=128,
+            use_pallas=use_pallas and mesh is None, delivery_spread=2,
+            pallas_lanes=128, mesh=mesh,
         )
         vcx.assign_cohorts_roundrobin()
         victims_xl = np.random.default_rng(7).choice(n_xl, size=n_crash_xl, replace=False)
@@ -200,27 +203,23 @@ def run_smoke(
             events = device_trace_events(trace_dir)
         _done("trace", events_per_plane=events)
 
-    # -- four devices: the 1M crash as a sharded wave on a (2,2) mesh --
+    # -- four devices: the 1M crash through the driver on a (2,2) mesh --
     if mesh_devices is not None:
-        from rapid_tpu.parallel.mesh import (
-            make_mesh, make_sharded_wave, shard_faults, shard_state,
-        )
+        from rapid_tpu.parallel.mesh import make_mesh, off_table
 
         t0 = time.monotonic()
-        mesh = make_mesh(mesh_devices, shape=(2, 2))
-        # use_pallas stays off under the mesh (state.py: EngineConfig.use_pallas).
-        vcs, _ = build_xl()
-        cfg = vcs.cfg._replace(use_pallas=False)
-        wave = make_sharded_wave(cfg, mesh, max_cuts=max_cuts)
-        state, _, cuts, resolved, _ = wave(
-            shard_state(vcs.state, mesh), shard_faults(vcs.faults, mesh),
-            jnp.int32(n_xl - n_crash_xl), jnp.int32(96), jnp.int32(1),
-        )
-        _require(bool(resolved), "sharded wave resolved")
-        _require(int(state.n_members) == n_xl - n_crash_xl, "sharded membership")
+        vcs, _ = build_xl(make_mesh(mesh_devices, shape=(2, 2)))
+        rounds, decided, _, members = vcs.run_to_decision(max_steps=96)
+        _require(decided, f"sharded xl point decided (rounds={rounds})")
+        _require(members == n_xl - n_crash_xl, "sharded membership")
         _require(
-            (np.asarray(state.alive) == alive_xl).all(),
-            "sharded wave: same alive mask as the one-device run",
+            (vcs.alive_mask == alive_xl).all(),
+            "sharded cluster: same alive mask as the one-device run",
+        )
+        state = vcs.state
+        _require(
+            off_table(state, vcs.mesh) == () and off_table(vcs.faults, vcs.mesh) == (),
+            "every leaf on the rule table's sharding after the run",
         )
         for name, lane, parts in (
             ("alive", state.alive, (2,)), ("report_bits", state.report_bits, (2, 2)),
@@ -233,8 +232,8 @@ def run_smoke(
                 f"{name}: shards are {want_shape} slices, not replicas",
             )
         _done(
-            "sharded_xl", mesh="cohort=2,nodes=2", cuts=int(cuts),
-            members=int(state.n_members), wall_s=round(time.monotonic() - t0, 1),
+            "sharded_xl", mesh="cohort=2,nodes=2", rounds=rounds, members=members,
+            wall_s=round(time.monotonic() - t0, 1),
         )
 
     return engine_telemetry.compile_snapshot()

@@ -16,6 +16,7 @@ reduction here is a sum/any over N, which XLA lowers to psum over ICI.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Optional, Sequence, Tuple
 
@@ -1278,9 +1279,37 @@ run_until_membership_trace = jax.jit(
 )
 
 
+#: A driver verb's one-device program by how many pytrees it carries: the
+#: state alone, with the telemetry lanes, with lanes and trace ring. A
+#: cluster on a mesh takes the same verb from
+#: ``parallel/mesh.sharded_program`` instead.
+_ROUND_PROGRAMS = {
+    "step": (engine_step, engine_step_telem, engine_step_trace),
+    "decision": (run_to_decision, run_to_decision_telem, run_to_decision_trace),
+    "wave": (
+        run_until_membership, run_until_membership_telem,
+        run_until_membership_trace,
+    ),
+}
+
+
+def _mesh_lib():
+    """``rapid_tpu.parallel.mesh``, imported at first use: it imports this
+    module's round bodies."""
+    from rapid_tpu.parallel import mesh
+
+    return mesh
+
+
 class VirtualCluster(DispatchSeam):
     """Host driver around the device engine: owns the state, injects faults
     and join waves, and runs rounds until convergence.
+
+    Handed a ``mesh`` (``parallel/mesh.make_mesh``), the same driver runs the
+    cluster sharded over it: every leaf lies where ``PARTITION_RULES`` puts
+    it, every verb dispatches ``sharded_program``'s form of its program and
+    returns the state on the same shardings, and the methods, the dispatch
+    phases and the counters are those of a one-device cluster.
 
     This is the deployment the BASELINE targets: N virtual Rapid endpoints
     co-located on TPU hosts, alerts/votes as device-array writes.
@@ -1290,10 +1319,11 @@ class VirtualCluster(DispatchSeam):
     vocabulary across this driver, the fleet, and the streaming pipeline.
     """
 
-    def __init__(self, cfg: EngineConfig, state: EngineState):
+    def __init__(self, cfg: EngineConfig, state: EngineState, mesh=None):
         self.cfg = cfg
-        self.state = state
-        self.faults = FaultInputs.none(cfg)
+        self.mesh = mesh
+        self.state = state if mesh is None else _mesh_lib().adopt(state, mesh)
+        self.faults = self._fresh(FaultInputs.none)
         self._rng = np.random.default_rng(0)
         # Engine-level telemetry: host-side counters over device dispatches
         # (the per-node flight recorder has no device analog — the engine's
@@ -1301,6 +1331,11 @@ class VirtualCluster(DispatchSeam):
         # events are process-global (one XLA cache per process), captured by
         # the engine_telemetry collector and read at snapshot time.
         self.metrics = Metrics()
+        if mesh is not None:
+            # How many devices hold the state, and how many leaves a verb
+            # has ever left off the rule table (tests hold it at 0).
+            self.metrics.inc("engine_state_devices", mesh.devices.size)
+            self.metrics.inc("engine_sharding_drift", 0)
         # Attached by rapid_tpu.serving.StreamDriver: the streaming pipeline
         # surfaces its sustained-throughput stats through this cluster's
         # telemetry snapshot (None = batch-only driver, no stream section).
@@ -1313,7 +1348,7 @@ class VirtualCluster(DispatchSeam):
         # device beside the state; the host keeps only a digest cache,
         # zero-minted at attach (the exposition series exist from the first
         # scrape, never mid-run) and refreshed ONLY at host-sync boundaries.
-        self.telem = initial_telemetry(cfg) if cfg.telemetry else None
+        self.telem = self._fresh(initial_telemetry) if cfg.telemetry else None
         self._activity = (
             engine_telemetry.zero_activity_summary(cfg.n, cfg.c)
             if cfg.telemetry
@@ -1330,13 +1365,42 @@ class VirtualCluster(DispatchSeam):
             )
         if cfg.trace < 0:
             raise ValueError(f"trace capacity must be >= 0, got {cfg.trace}")
-        self.trace_ring = initial_trace(cfg) if cfg.trace else None
+        self.trace_ring = self._fresh(initial_trace) if cfg.trace else None
         self._trace = (
             engine_telemetry.zero_trace_summary(cfg.trace)
             if cfg.trace
             else None
         )
         engine_telemetry.install()
+
+    # -- placement ------------------------------------------------------
+
+    def _fresh(self, make):
+        """``make(cfg)``: zeroed lanes, made on their shards under a mesh."""
+        if self.mesh is None:
+            return make(self.cfg)
+        return _mesh_lib().fresh_on_mesh(make, self.cfg, self.mesh)
+
+    def _upload(self, field: str, host_array: np.ndarray) -> jnp.ndarray:
+        """A whole host-made lane to the device(s) that hold ``field``."""
+        self._account_h2d(host_array)
+        if self.mesh is None:
+            return jnp.asarray(host_array)
+        return _mesh_lib().place_leaf(field, host_array, self.mesh)
+
+    def _note_placement(self) -> None:
+        """After a verb on a mesh: count the leaves that left the rule
+        table (``engine_sharding_drift``). Host work, no device call."""
+        if self.mesh is None:
+            return
+        off = _mesh_lib().off_table
+        drifted = sum(
+            len(off(tree, self.mesh))
+            for tree in (self.state, self.faults, self.telem, self.trace_ring)
+            if tree is not None
+        )
+        if drifted:
+            self.metrics.inc("engine_sharding_drift", drifted)
 
     # -- construction ---------------------------------------------------
 
@@ -1361,6 +1425,7 @@ class VirtualCluster(DispatchSeam):
         compact: bool = False,
         telemetry: bool = False,
         trace: int = 0,
+        mesh=None,
     ) -> "VirtualCluster":
         """Synthetic cluster: slot identities are random 64-bit lanes (the
         host never materializes 100K endpoint strings; interop deployments
@@ -1374,8 +1439,23 @@ class VirtualCluster(DispatchSeam):
         ``trace=R`` (requires telemetry) additionally records the last R
         rounds into the device round-trace ring (models/state.TraceRing) —
         same bit-identity and byte-identity contracts, pinned by
-        tests/test_trace_ring.py."""
+        tests/test_trace_ring.py. ``mesh`` builds the cluster sharded over a
+        ``('nodes',)`` or ``('cohort','nodes')`` device mesh: the identity
+        arrays go from the host to their shards and the state is made
+        there, never whole on one device. Slots default to the least
+        multiple of the ``nodes`` axis at or above ``n_members``; slots or
+        cohorts that do not divide their axis raise ``ShardingShapeError``.
+        The Mosaic delivery kernel is not partitioned yet: ``use_pallas``
+        with a mesh raises."""
+        if mesh is not None and use_pallas:
+            raise ValueError(
+                "use_pallas is off under a mesh: the delivery kernel is not "
+                "partitioned (EngineConfig.use_pallas)"
+            )
         n = n_slots if n_slots is not None else n_members
+        if mesh is not None and n_slots is None:
+            pmesh = _mesh_lib()
+            n = pmesh.pad_to_multiple(n_members, mesh.shape[pmesh.NODE_AXIS])
         assert n >= n_members
         _validate_delivery_prob(delivery_prob_permille)
         cfg = EngineConfig(
@@ -1397,9 +1477,14 @@ class VirtualCluster(DispatchSeam):
         id_lo = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
         alive = np.zeros(n, dtype=bool)
         alive[:n_members] = True
-        cluster = cls(cfg, initial_state(cfg, key_hi, key_lo, id_hi, id_lo, alive))
+        identity = (key_hi, key_lo, id_hi, id_lo, alive)
+        if mesh is None:
+            state = initial_state(cfg, *identity)
+        else:
+            state = _mesh_lib().initial_state_on_mesh(cfg, mesh, *identity)
+        cluster = cls(cfg, state, mesh=mesh)
         cluster._rng = rng
-        cluster._account_h2d(key_hi, key_lo, id_hi, id_lo, alive)
+        cluster._account_h2d(*identity)
         return cluster
 
     @classmethod
@@ -1499,11 +1584,13 @@ class VirtualCluster(DispatchSeam):
         with self._dispatch("inject_crash"):
             idx = self._slot_index(slots)
             self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(True))
+            self._note_placement()
 
     def revive(self, slots: Sequence[int]) -> None:
         with self._dispatch("inject_crash"):
             idx = self._slot_index(slots)
             self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(False))
+            self._note_placement()
 
     def _stamp_fired_edges(self, idx: jnp.ndarray, edge_mask) -> None:
         """Mark (slot, ring) edges as fired at the current round (device-side
@@ -1557,6 +1644,7 @@ class VirtualCluster(DispatchSeam):
         # Inline crash scatter with the already-validated, already-uploaded
         # index (a self.crash(slots) call would bounds-check and upload again).
         self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(True))
+        self._note_placement()
 
     def set_flaky_edges(self, probe_fail: np.ndarray) -> None:
         """Arbitrary per-(subject, ring) probe failures — asymmetric/one-way
@@ -1564,8 +1652,7 @@ class VirtualCluster(DispatchSeam):
         # Cast on host first: what crosses the boundary (and what the byte
         # counter charges) is the 1-byte bool array, not the caller's dtype.
         arr = np.asarray(probe_fail, dtype=bool)
-        self._account_h2d(arr)
-        self.faults = self.faults._replace(probe_fail=jnp.asarray(arr))
+        self.faults = self.faults._replace(probe_fail=self._upload("probe_fail", arr))
 
     def stagger_fd_counts(self, rng: np.random.Generator, spread_rounds: int) -> None:
         """Randomize per-edge detection latency: failure detectors fire up to
@@ -1584,8 +1671,7 @@ class VirtualCluster(DispatchSeam):
         # Cast host-side first: the byte counter charges what actually
         # uploads (the policy-dtype lane, not the rng's int64 draw).
         narrowed = (-offsets).astype(cdt)
-        self._account_h2d(narrowed)
-        self.state = self.state._replace(fd_count=jnp.asarray(narrowed))
+        self.state = self.state._replace(fd_count=self._upload("fd_count", narrowed))
 
     def inject_join_wave(
         self, slots: Sequence[int], check_admissible: bool = True
@@ -1657,6 +1743,7 @@ class VirtualCluster(DispatchSeam):
             # Mark each (joiner, ring) edge as fired now where a gatekeeper
             # exists; delivery (rx-block + jitter) happens in the round body.
             self._stamp_fired_edges(idx, (pred >= 0).T)
+            self._note_placement()
 
     def assign_cohorts(self, cohort_of: np.ndarray) -> None:
         # Host-side cast first so the transfer counter charges the bytes
@@ -1665,8 +1752,7 @@ class VirtualCluster(DispatchSeam):
         arr = np.asarray(
             cohort_of, dtype=np.dtype(compaction_policy(self.cfg).cohort)
         )
-        self._account_h2d(arr)
-        self.state = self.state._replace(cohort_of=jnp.asarray(arr))
+        self.state = self.state._replace(cohort_of=self._upload("cohort_of", arr))
 
     def assign_cohorts_roundrobin(self) -> None:
         """Spread the N slots evenly over the C receiver cohorts — the
@@ -1683,8 +1769,7 @@ class VirtualCluster(DispatchSeam):
         old alerts. Re-stamped alerts redeliver within ``delivery_spread``
         rounds — a re-broadcast after the topology change."""
         arr = np.asarray(rx_block, dtype=bool)  # charge the uploaded width
-        self._account_h2d(arr)
-        self.faults = self.faults._replace(rx_block=jnp.asarray(arr))
+        self.faults = self.faults._replace(rx_block=self._upload("rx_block", arr))
         self.state = self.state._replace(
             fire_round=jnp.where(
                 self.state.fd_fired,
@@ -1692,8 +1777,38 @@ class VirtualCluster(DispatchSeam):
                 self.state.fire_round,
             )
         )
+        self._note_placement()
 
     # -- execution ------------------------------------------------------
+
+    def _advance(self, verb: str, *controls, max_cuts: Optional[int] = None):
+        """Dispatch ``verb``'s round program ("step", "decision", "wave")
+        on the pytrees this driver carries and keep what comes back;
+        returns the program's observations. One body for a one-device
+        cluster and a meshed one: what differs is where the program comes
+        from, by what the constructor was handed (a mesh or none)."""
+        carried = tuple(
+            tree for tree in (self.state, self.telem, self.trace_ring)
+            if tree is not None
+        )
+        if self.mesh is None:
+            program = functools.partial(
+                _ROUND_PROGRAMS[verb][len(carried) - 1], self.cfg
+            )
+            if max_cuts is not None:  # the wave's static argument, by position
+                controls = (*controls[:2], max_cuts, *controls[2:])
+        else:
+            program = _mesh_lib().sharded_program(
+                verb, self.cfg, self.mesh, len(carried), max_cuts
+            )
+        out = program(*carried, self.faults, *controls)
+        self.state = out[0]
+        if self.telem is not None:
+            self.telem = out[1]
+        if self.trace_ring is not None:
+            self.trace_ring = out[2]
+        self._note_placement()
+        return out[len(carried):]
 
     def _step(self, phase: str, **tags) -> StepEvents:
         """ONE body for both step spellings: only the dispatch-phase label
@@ -1702,16 +1817,7 @@ class VirtualCluster(DispatchSeam):
         self.metrics.inc("engine_steps")
         self.metrics.inc("engine_convergence_steps")
         with self._dispatch(phase, **tags):
-            if self.trace_ring is not None:
-                self.state, self.telem, self.trace_ring, events = engine_step_trace(
-                    self.cfg, self.state, self.telem, self.trace_ring, self.faults
-                )
-            elif self.telem is not None:
-                self.state, self.telem, events = engine_step_telem(
-                    self.cfg, self.state, self.telem, self.faults
-                )
-            else:
-                self.state, events = engine_step(self.cfg, self.state, self.faults)
+            (events,) = self._advance("step")
         return events
 
     def step(self) -> StepEvents:
@@ -1795,23 +1901,9 @@ class VirtualCluster(DispatchSeam):
         if max_steps > 255:  # not an assert: python -O must not skip this
             raise ValueError(f"max_steps packs into 8 bits, got {max_steps}")
         with self._dispatch("run_to_decision"):
-            if self.trace_ring is not None:
-                (
-                    self.state, self.telem, self.trace_ring, steps, decided,
-                    winner,
-                ) = run_to_decision_trace(
-                    self.cfg, self.state, self.telem, self.trace_ring,
-                    self.faults, jnp.int32(max_steps),
-                )
-            elif self.telem is not None:
-                self.state, self.telem, steps, decided, winner = run_to_decision_telem(
-                    self.cfg, self.state, self.telem, self.faults,
-                    jnp.int32(max_steps),
-                )
-            else:
-                self.state, steps, decided, winner = run_to_decision(
-                    self.cfg, self.state, self.faults, jnp.int32(max_steps)
-                )
+            steps, decided, winner = self._advance(
+                "decision", jnp.int32(max_steps)
+            )
             if self.cfg.n < (1 << 22):
                 # Layout: bits 0-7 steps, bit 8 decided, bits 9-30 membership
                 # — one scalar fetch total.
@@ -1857,29 +1949,10 @@ class VirtualCluster(DispatchSeam):
             # Not an assert: python -O must not skip this.
             raise ValueError(f"target must be in [0, {self.cfg.n}]: {target}")
         with self._dispatch("run_until_membership"):
-            if self.trace_ring is not None:
-                (
-                    self.state, self.telem, self.trace_ring, steps, cuts,
-                    resolved, sizes,
-                ) = run_until_membership_trace(
-                    self.cfg, self.state, self.telem, self.trace_ring,
-                    self.faults, jnp.int32(target), jnp.int32(max_steps),
-                    int(max_cuts), jnp.int32(min_cuts),
-                )
-            elif self.telem is not None:
-                self.state, self.telem, steps, cuts, resolved, sizes = (
-                    run_until_membership_telem(
-                        self.cfg, self.state, self.telem, self.faults,
-                        jnp.int32(target), jnp.int32(max_steps), int(max_cuts),
-                        jnp.int32(min_cuts),
-                    )
-                )
-            else:
-                self.state, steps, cuts, resolved, sizes = run_until_membership(
-                    self.cfg, self.state, self.faults,
-                    jnp.int32(target), jnp.int32(max_steps), int(max_cuts),
-                    jnp.int32(min_cuts),
-                )
+            steps, cuts, resolved, sizes = self._advance(
+                "wave", jnp.int32(target), jnp.int32(max_steps),
+                jnp.int32(min_cuts), max_cuts=int(max_cuts),
+            )
             obs = np.asarray(
                 jnp.concatenate(
                     [jnp.stack([steps, cuts, resolved.astype(jnp.int32)]), sizes]

@@ -167,6 +167,17 @@ def _alive_first_order(perm, alive):
     )
 
 
+#: From this many slots on, ``ring_topology_from_perm`` takes the K rings
+#: one at a time (a ``lax.map``) instead of all at once (a ``vmap``). The
+#: TPU compiler handles a scan or a scatter over the long axis of a
+#: ``[K, N]`` array far worse than K scans over ``[N]``: at N = 10M on a
+#: (1,4) mesh the batched form compiles in 214 s with 8.4 GB of temporaries
+#: a device, the ring-at-a-time form in 9 s with 0.17 GB (compiled here for
+#: a described v5e:2x2, PR 27). Below the threshold the programs are what
+#: they were, byte for byte. A size read off the shape, not an option.
+RING_AT_A_TIME_SLOTS = 1 << 22
+
+
 def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopology:
     """``ring_topology`` without the sort: derive all K rings' topology from
     the static key-order permutations (``ring_perms``) and the current alive
@@ -177,10 +188,17 @@ def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopolo
     ring_perm at the policy's index width (int8/int16,
     models/state.compaction_policy) and gathers/scatters index with it
     directly; the returned tables are int32 (position arithmetic
-    accumulates wide here) and the caller narrows on store."""
-    obs, subj, order = jax.vmap(_from_perm_single, in_axes=(0, None))(
-        jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
-    )
+    accumulates wide here) and the caller narrows on store. Very large
+    rings go one at a time (:data:`RING_AT_A_TIME_SLOTS`), same values."""
+    perm, alive = jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
+    if perm.shape[-1] >= RING_AT_A_TIME_SLOTS:
+        obs, subj, order = jax.lax.map(
+            lambda ring: _from_perm_single(ring, alive), perm
+        )
+    else:
+        obs, subj, order = jax.vmap(_from_perm_single, in_axes=(0, None))(
+            perm, alive
+        )
     return RingTopology(obs_idx=obs, subj_idx=subj, order=order)
 
 

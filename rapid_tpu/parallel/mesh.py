@@ -46,6 +46,7 @@ not divide the node axis raises the same named ``ShardingShapeError``
 
 from __future__ import annotations
 
+import functools
 import re
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -59,13 +60,18 @@ from rapid_tpu.models.state import (
     FaultInputs,
     TelemetryLanes,
     TraceRing,
+    initial_state,
 )
 from rapid_tpu.models.virtual_cluster import (
     engine_step_impl,
     engine_step_telem_impl,
     engine_step_trace_impl,
+    run_to_decision_impl,
+    run_to_decision_telem_impl,
+    run_to_decision_trace_impl,
     run_until_membership_impl,
     run_until_membership_telem_impl,
+    run_until_membership_trace_impl,
 )
 
 NODE_AXIS = "nodes"
@@ -205,6 +211,7 @@ def _resolve_spec(spec: Spec, mesh: Mesh) -> P:
     return P(*(ax if ax is None or ax in mesh.axis_names else None for ax in spec))
 
 
+@functools.lru_cache(maxsize=None)
 def _shardings_for(cls, mesh: Mesh):
     specs = match_partition_rules(PARTITION_RULES, cls._fields)
     return cls(
@@ -368,105 +375,158 @@ def shard_faults(faults: FaultInputs, mesh: Mesh) -> FaultInputs:
     return shard_pytree(faults, fault_shardings(mesh), mesh=mesh)
 
 
-def make_sharded_step(cfg: EngineConfig, mesh: Mesh):
-    """jit the engine step with explicit in/out shardings over ``mesh``
-    (1-D or 2-D).
+def adopt(tree, mesh: Mesh):
+    """A pytree of engine leaves (``EngineState``, ``FaultInputs``,
+    ``TelemetryLanes``, ``TraceRing``) onto ``mesh`` by the rule table:
+    arrays that already lie on devices move device to device (nothing moves
+    where a leaf is on the table already), host arrays go up shard by shard.
+    Shapes are validated first, like :func:`shard_pytree`'s."""
+    shardings = _shardings_for(type(tree), mesh)
 
-    Output events replicate (they are scalars plus the [n] winner mask, which
-    stays sharded).
-    """
-    st_sh = state_shardings(mesh)
-    ft_sh = fault_shardings(mesh)
+    def place(path, x, sharding):
+        if not isinstance(x, jax.Array):
+            return shard_pytree(x, sharding, mesh=mesh)
+        _validate_leaf(jax.tree_util.keystr(path), x.shape, sharding)
+        return jax.device_put(x, sharding)
 
-    return jax.jit(
-        lambda state, faults: engine_step_impl(cfg, state, faults),
-        in_shardings=(st_sh, ft_sh),
-        out_shardings=None,  # let XLA propagate; state stays mesh-sharded
-        donate_argnums=(0,),
+    return jax.tree_util.tree_map_with_path(place, tree, shardings)
+
+
+def place_leaf(field: str, host_array, mesh: Mesh):
+    """One host array up to the shards of engine leaf ``field``."""
+    spec = match_partition_rules(PARTITION_RULES, (field,))[field]
+    return shard_pytree(
+        np.asarray(host_array), NamedSharding(mesh, _resolve_spec(spec, mesh)),
+        mesh=mesh,
     )
+
+
+def off_table(tree, mesh: Mesh) -> Tuple[str, ...]:
+    """The fields of an engine pytree whose sharding is not the rule
+    table's on ``mesh`` (a ``[k,n]`` leaf the compiler replicated, a lane
+    an eager scatter gathered to one device). Host work only."""
+    shardings = _shardings_for(type(tree), mesh)
+    return tuple(
+        field
+        for field, leaf, want in zip(tree._fields, tree, shardings)
+        if not leaf.sharding.is_equivalent_to(want, leaf.ndim)
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _fresh_program(make, cfg: EngineConfig, mesh: Mesh):
+    kind = type(jax.eval_shape(functools.partial(make, cfg)))
+    return jax.jit(  # donate-ok: no arguments; the leaves are made on their shards
+        functools.partial(make, cfg), out_shardings=_shardings_for(kind, mesh)
+    )
+
+
+def fresh_on_mesh(make, cfg: EngineConfig, mesh: Mesh):
+    """``make(cfg)`` (``FaultInputs.none``, ``initial_telemetry``,
+    ``initial_trace``) with every leaf made on its own shards: nothing is
+    staged whole on one device or uploaded from the host."""
+    return _fresh_program(make, cfg, mesh)()
+
+
+@functools.lru_cache(maxsize=None)
+def _initial_state_program(cfg: EngineConfig, mesh: Mesh):
+    return jax.jit(  # donate-ok: the identity arrays are inputs, not state
+        functools.partial(initial_state, cfg), out_shardings=state_shardings(mesh)
+    )
+
+
+def initial_state_on_mesh(cfg: EngineConfig, mesh: Mesh, key_hi, key_lo, id_hi, id_lo, alive):
+    """``initial_state`` built on the mesh: the host's identity arrays go up
+    shard by shard, and the one sort, the topology and every zeroed lane are
+    made by one program whose outputs are the rule table's. A slot count or
+    a cohort count that does not divide its axis raises
+    :class:`ShardingShapeError` naming the leaf."""
+    shapes = jax.eval_shape(
+        functools.partial(initial_state, cfg), key_hi, key_lo, id_hi, id_lo, alive
+    )
+    for field, leaf, sharding in zip(shapes._fields, shapes, state_shardings(mesh)):
+        _validate_leaf(field, leaf.shape, sharding)
+    names = ("key_hi", "key_lo", "id_hi", "id_lo", "alive")
+    placed = [
+        place_leaf(name, x, mesh)
+        for name, x in zip(names, (key_hi, key_lo, id_hi, id_lo, alive))
+    ]
+    return _initial_state_program(cfg, mesh)(*placed)
+
+
+#: A driver verb's device program by how many pytrees it carries: the state
+#: alone, with the telemetry lanes, with lanes and trace ring.
+_ROUND_IMPLS = {
+    "step": (engine_step_impl, engine_step_telem_impl, engine_step_trace_impl),
+    "decision": (
+        run_to_decision_impl, run_to_decision_telem_impl,
+        run_to_decision_trace_impl,
+    ),
+    "wave": (
+        run_until_membership_impl, run_until_membership_telem_impl,
+        run_until_membership_trace_impl,
+    ),
+}
+#: Per verb: scalar control arguments after the faults, and observations
+#: after the carried pytrees (their placement is the compiler's).
+_ROUND_ARITY = {"step": (0, 1), "decision": (1, 3), "wave": (3, 4)}
+
+
+@functools.lru_cache(maxsize=None)
+def sharded_program(
+    verb: str, cfg: EngineConfig, mesh: Mesh, carried: int = 1,
+    max_cuts: Optional[int] = None,
+):
+    """THE sharded form of a driver verb, the one ``VirtualCluster`` on a
+    mesh dispatches: ``step`` (one round), ``decision``
+    (``run_to_decision``) or ``wave`` (``run_until_membership``, multiple
+    view changes in one dispatch) jitted over ``mesh`` (1-D or 2-D).
+    ``carried`` pytrees (state; + telemetry lanes; + trace ring) are donated
+    and come back with the rule table's shardings, stated and not left to
+    propagation: no leaf can drift or silently replicate between verbs, and
+    donation aliases every buffer. Call as ``program(*carried, faults,
+    *controls) -> (*carried, *observations)``; the wave's controls are
+    ``(target, max_steps, min_cuts)`` and ``max_cuts`` is its static bound
+    (the other verbs take none). One program per (verb, cfg, mesh):
+    a second cluster of the same shape compiles nothing."""
+    impl = _ROUND_IMPLS[verb][carried - 1]
+    controls, observed = _ROUND_ARITY[verb]
+    tables = (
+        state_shardings(mesh), telemetry_shardings(mesh), trace_shardings(mesh)
+    )[:carried]
+    if verb == "wave":
+        def program(*args):
+            return impl(cfg, *args[:-1], max_cuts, args[-1])
+    else:
+        def program(*args):
+            return impl(cfg, *args)
+    return jax.jit(
+        program,
+        in_shardings=(*tables, fault_shardings(mesh), *(None,) * controls),
+        out_shardings=(*tables, *(None,) * observed),
+        donate_argnums=tuple(range(carried)),
+    )
+
+
+# The names the analyzers register (tools/analysis/device_program.py,
+# tools/collective_audit.py) and tests/test_parallel*.py drive; each is the
+# driver's own program.
+
+
+def make_sharded_step(cfg: EngineConfig, mesh: Mesh):
+    """``sharded_program("step", ...)``: ``step(state, faults) ->
+    (state, events)``."""
+    return sharded_program("step", cfg, mesh)
 
 
 def make_sharded_wave(cfg: EngineConfig, mesh: Mesh, max_cuts: int = 8):
-    """jit the whole-wave convergence loop (``run_until_membership_impl`` —
-    multiple view changes in one dispatch) with the mesh's shardings: the
-    multi-chip twin of the single-chip bench hot path, and — on the 2-D
-    ``('cohort', 'nodes')`` mesh — the 1M+ headline configuration. Returns
-    ``wave(state, faults, target, max_steps, min_cuts) ->
-    (state, steps, cuts, resolved, sizes)``; the scalar observations and
-    the [max_cuts] sizes vector replicate."""
-    st_sh = state_shardings(mesh)
-    ft_sh = fault_shardings(mesh)
-
-    return jax.jit(
-        lambda state, faults, target, max_steps, min_cuts: (
-            run_until_membership_impl(
-                cfg, state, faults, target, max_steps, max_cuts, min_cuts
-            )
-        ),
-        in_shardings=(st_sh, ft_sh, None, None, None),
-        out_shardings=None,  # XLA propagates; state stays mesh-sharded
-        donate_argnums=(0,),
-    )
+    """``sharded_program("wave", ...)``: ``wave(state, faults, target,
+    max_steps, min_cuts) -> (state, steps, cuts, resolved, sizes)``."""
+    return sharded_program("wave", cfg, mesh, 1, max_cuts)
 
 
 def make_sharded_step_telem(cfg: EngineConfig, mesh: Mesh):
-    """:func:`make_sharded_step` with the telemetry lanes riding along —
-    the audited ``sharded_step_telem`` entrypoint: the plane's lanes shard
-    on the same mesh via :func:`telemetry_shardings`, and the HLO lock
-    pins that turning them on adds zero hot-loop collectives and zero
-    host transfers to the compiled program."""
-    st_sh = state_shardings(mesh)
-    ft_sh = fault_shardings(mesh)
-    tl_sh = telemetry_shardings(mesh)
-
-    return jax.jit(
-        lambda state, telem, faults: engine_step_telem_impl(
-            cfg, state, telem, faults
-        ),
-        in_shardings=(st_sh, tl_sh, ft_sh),
-        out_shardings=None,  # XLA propagates; state/lanes stay mesh-sharded
-        donate_argnums=(0, 1),
-    )
-
-
-def make_sharded_step_trace(cfg: EngineConfig, mesh: Mesh):
-    """:func:`make_sharded_step_telem` with the round-trace ring riding
-    along — the audited ``step_trace`` program's mesh twin: the ring's
-    lanes replicate via :func:`trace_shardings` (per-round scalars carry no
-    meshed axis), so trace=R adds zero hot-loop collectives and zero host
-    transfers on any mesh."""
-    st_sh = state_shardings(mesh)
-    ft_sh = fault_shardings(mesh)
-    tl_sh = telemetry_shardings(mesh)
-    tr_sh = trace_shardings(mesh)
-
-    return jax.jit(
-        lambda state, telem, trace, faults: engine_step_trace_impl(
-            cfg, state, telem, trace, faults
-        ),
-        in_shardings=(st_sh, tl_sh, tr_sh, ft_sh),
-        out_shardings=None,  # XLA propagates; state/lanes/ring stay mesh-sharded
-        donate_argnums=(0, 1, 2),
-    )
-
-
-def make_sharded_wave_telem(cfg: EngineConfig, mesh: Mesh, max_cuts: int = 8):
-    """:func:`make_sharded_wave` with telemetry lanes in the convergence
-    carry — the audited ``sharded_wave_telem`` entrypoint. Returns
-    ``wave(state, telem, faults, target, max_steps, min_cuts) ->
-    (state, telem, steps, cuts, resolved, sizes)``."""
-    st_sh = state_shardings(mesh)
-    ft_sh = fault_shardings(mesh)
-    tl_sh = telemetry_shardings(mesh)
-
-    return jax.jit(
-        lambda state, telem, faults, target, max_steps, min_cuts: (
-            run_until_membership_telem_impl(
-                cfg, state, telem, faults, target, max_steps, max_cuts,
-                min_cuts,
-            )
-        ),
-        in_shardings=(st_sh, tl_sh, ft_sh, None, None, None),
-        out_shardings=None,  # XLA propagates; state/lanes stay mesh-sharded
-        donate_argnums=(0, 1),
-    )
+    """``sharded_program("step", ..., carried=2)``: the audited
+    ``sharded_step_telem`` entrypoint, ``step(state, telem, faults) ->
+    (state, telem, events)``."""
+    return sharded_program("step", cfg, mesh, 2)

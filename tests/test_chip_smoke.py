@@ -23,11 +23,14 @@ REPO = Path(__file__).resolve().parent.parent
 # ---------------------------------------------------------------------------
 
 # The tiny geometry: the 1M-shape stage gets the churn's slot count (400 + 10
-# joiner slots) so the two share their small compiled programs.
+# joiner slots) so the two share their small compiled programs. Four of the
+# session's virtual CPU devices (the environment's XLA_FLAGS carries over)
+# stand in for a four-chip host, so the mesh stage runs through the driver.
 _BODY = (
-    "import chip_smoke\n"
+    "import jax, chip_smoke\n"
     "totals = chip_smoke.run_smoke(n_churn=400, cohorts_churn=8, n_xl=410,"
-    " cohorts_xl=8, use_pallas=False, twin=False, trace=False, repeats=2)\n"
+    " cohorts_xl=8, use_pallas=False, twin=False, trace=False, repeats=2,"
+    " mesh_devices=jax.devices()[:4])\n"
     "print('TOTAL_COMPILES', totals['compiles'])\n"
 )
 
@@ -305,7 +308,7 @@ def test_smoke_body_runs_tiny_on_cpu(cpu_runs):
     # body itself requires that its warm-up really compiled.
     out, err = cpu_runs["body"].communicate(timeout=240)
     assert cpu_runs["body"].returncode == 0, err[-2000:]
-    for stage in ("churn_warmup", "churn_repeats", "crash_xl"):
+    for stage in ("churn_warmup", "churn_repeats", "crash_xl", "sharded_xl"):
         assert f"stage {stage} ok" in out
     assert "repeats=2 compiles=0" in out  # the repeat window
     assert "stage churn_twin" not in out and "stage trace" not in out

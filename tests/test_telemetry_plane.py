@@ -229,21 +229,19 @@ def test_stream_drive_bit_identical_and_drain_is_the_fetch_boundary():
 
 
 def test_sharded_telem_wave_bit_identical_and_fleet_lanes_shard():
-    """The lanes under a real device mesh: the sharded telem wave
-    (``make_sharded_wave_telem``) matches the single-device fused drive
-    bit for bit — results AND lanes — and tenant-stacked lanes place onto
-    the 3-D fleet mesh through the same rule table
+    """The lanes under a real device mesh: a ``VirtualCluster`` built on
+    the mesh runs the telem wave (``sharded_program("wave", ..., carried=2)``)
+    through ``run_until_membership`` and matches the single-device fused
+    drive bit for bit — results AND lanes — and tenant-stacked lanes place
+    onto the 3-D fleet mesh through the same rule table
     (``fleet_telemetry_shardings``: leading 'tenant' axis on every leaf,
     values unchanged by placement)."""
     from rapid_tpu.parallel.mesh import (
         TENANT_AXIS,
         fleet_telemetry_shardings,
         make_mesh,
-        make_sharded_wave_telem,
-        shard_faults,
+        off_table,
         shard_pytree,
-        shard_state,
-        telemetry_shardings,
     )
 
     single = _cluster(telemetry=True, seed=6)
@@ -253,20 +251,17 @@ def test_sharded_telem_wave_bit_identical_and_fleet_lanes_shard():
     )
     assert resolved1
 
-    vc = _cluster(telemetry=True, seed=6)
-    vc.crash([2, 7])
     mesh = make_mesh(jax.devices()[:8])
-    wave = make_sharded_wave_telem(vc.cfg, mesh, max_cuts=8)
-    state, telem, steps, cuts, resolved, _ = wave(
-        shard_state(vc.state, mesh),
-        shard_pytree(vc.telem, telemetry_shardings(mesh), mesh=mesh),
-        shard_faults(vc.faults, mesh),
-        jnp.int32(22), jnp.int32(64), jnp.int32(1),
+    vc = _cluster(telemetry=True, seed=6, mesh=mesh)
+    vc.crash([2, 7])
+    steps, cuts, resolved, _ = vc.run_until_membership(
+        22, max_steps=64, min_cuts=1
     )
-    assert bool(resolved)
-    assert (int(steps), int(cuts)) == (r1, c1)
-    assert _trees_equal(state, single.state)
-    assert _trees_equal(_lanes_host(telem), _lanes_host(single.telem))
+    assert resolved
+    assert (steps, cuts) == (r1, c1)
+    assert _trees_equal(vc.state, single.state)
+    assert _trees_equal(_lanes_host(vc.telem), _lanes_host(single.telem))
+    assert off_table(vc.telem, mesh) == () and off_table(vc.state, mesh) == ()
 
     # Tenant-stacked lanes on the ('tenant', 'cohort', 'nodes') mesh.
     singles = _fleet(telemetry=True, b=4)

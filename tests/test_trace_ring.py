@@ -352,21 +352,18 @@ def test_stream_drive_bit_identical_and_drain_attributes_waves():
 
 
 def test_sharded_step_trace_bit_identical_and_fleet_rings_shard():
-    """The ring under a real device mesh: ``make_sharded_step_trace``
-    matches the single-device per-step drive bit for bit — state, lanes,
-    AND ring — and tenant-stacked rings place onto the 3-D fleet mesh
-    through the same rule table (``fleet_trace_shardings``: leading
-    'tenant' axis, lane dims replicated, values unchanged)."""
+    """The ring under a real device mesh: a ``VirtualCluster`` built on the
+    mesh steps ``sharded_program("step", ..., carried=3)`` and matches the
+    single-device per-step drive bit for bit — state, lanes, AND ring — and
+    tenant-stacked rings place onto the 3-D fleet mesh through the same
+    rule table (``fleet_trace_shardings``: leading 'tenant' axis, lane dims
+    replicated, values unchanged)."""
     from rapid_tpu.parallel.mesh import (
         TENANT_AXIS,
         fleet_trace_shardings,
         make_mesh,
-        make_sharded_step_trace,
-        shard_faults,
+        off_table,
         shard_pytree,
-        shard_state,
-        telemetry_shardings,
-        trace_shardings,
     )
 
     single = _cluster(trace=R, seed=6)
@@ -374,21 +371,18 @@ def test_sharded_step_trace_bit_identical_and_fleet_rings_shard():
     for _ in range(8):
         single.step()
 
-    vc = _cluster(trace=R, seed=6)
-    vc.crash([2, 7])
     mesh = make_mesh(jax.devices()[:8])
-    step = make_sharded_step_trace(vc.cfg, mesh)
-    state = shard_state(vc.state, mesh)
-    telem = shard_pytree(vc.telem, telemetry_shardings(mesh), mesh=mesh)
-    ring = shard_pytree(vc.trace_ring, trace_shardings(mesh), mesh=mesh)
-    faults = shard_faults(vc.faults, mesh)
+    vc = _cluster(trace=R, seed=6, mesh=mesh)
+    vc.crash([2, 7])
     for _ in range(8):
-        state, telem, ring, _events = step(state, telem, ring, faults)
-    assert _trees_equal(state, single.state)
-    assert _trees_equal(_host(telem), _host(single.telem))
-    assert _trees_equal(_host(ring), _host(single.trace_ring))
+        vc.step()
+    assert _trees_equal(vc.state, single.state)
+    assert _trees_equal(_host(vc.telem), _host(single.telem))
+    assert _trees_equal(_host(vc.trace_ring), _host(single.trace_ring))
+    assert off_table(vc.trace_ring, mesh) == () and off_table(vc.telem, mesh) == ()
     single.sync()
-    assert single.trace["rounds_recorded"] == 8
+    vc.sync()
+    assert single.trace["rounds_recorded"] == vc.trace["rounds_recorded"] == 8
 
     fleet = TenantFleet.from_clusters(_fleet(trace=R, b=4))
     shardings = fleet_trace_shardings(
