@@ -808,6 +808,56 @@ engine_step_trace = jax.jit(
 )
 
 
+def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest):
+    """The MESHLESS per-round step the driver dispatches: the math of
+    :func:`engine_step_impl` with the per-edge masks CARRIED from round to
+    round beside the state instead of rebuilt in every round. ``rest`` is
+    ``([telem, [trace,]] faults, masks)``, so the three jits below are this
+    one body (the observers optional pytrees, as ``_compute_round`` takes
+    them) and :meth:`VirtualCluster._advance` calls it like any verb.
+
+    ``masks`` must be ``_edge_masks(cfg, state, faults)`` for exactly these
+    inputs (the driver's business: :class:`CarriedMasks`). They are a pure
+    function of ``alive``, ``obs_idx``, ``crashed`` and ``rx_block``; the
+    round leaves those four alone and a committed cut changes the first two,
+    so the taken arm of the view-change gate rebuilds them for the committed
+    state — what the fused loops' ``commit`` does — and the other arm hands
+    them back. The masks returned are those of ``(new_state,
+    faults)``. Per round the state, events and observer lanes are
+    bit-identical to :func:`engine_step_impl`'s: the same functions on the
+    same values, only the place of the build differs.
+
+    Returns ``(state, [telem, [trace,]] events, masks)``."""
+    *observers, faults, masks = rest
+    round_state, decided, winner_mask, events, *observers = _compute_round(
+        cfg, state, faults, masks, *observers
+    )
+
+    def commit(s):
+        committed = apply_view_change_impl(cfg, s, winner_mask)
+        return committed, _edge_masks(cfg, committed, faults)
+
+    new_state, masks = jax.lax.cond(
+        decided, commit, scope("view_keep")(lambda s: (s, masks)), round_state
+    )
+    return (new_state, *observers, events, masks)
+
+
+# State, observers' lanes and masks donated: the faults alone stay the caller's.
+engine_step_carried = jax.jit(
+    engine_step_carried_impl, static_argnums=(0,), donate_argnums=(1, 3)
+)
+engine_step_carried_telem = jax.jit(
+    engine_step_carried_impl, static_argnums=(0,), donate_argnums=(1, 2, 4)
+)
+engine_step_carried_trace = jax.jit(
+    engine_step_carried_impl, static_argnums=(0,), donate_argnums=(1, 2, 3, 5)
+)
+#: The build program: dispatched by the driver only when the masks it
+#: carries are not those of the inputs it is about to pass.
+edge_masks_build = jax.jit(_edge_masks, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
+
+
 def telemetry_digest_impl(telem: TelemetryLanes) -> jnp.ndarray:
     """The telemetry lanes reduced to one small int32 vector — THE place the
     plane's cross-shard reductions live, dispatched only at the existing
@@ -1282,15 +1332,64 @@ run_until_membership_trace = jax.jit(
 #: A driver verb's one-device program by how many pytrees it carries: the
 #: state alone, with the telemetry lanes, with lanes and trace ring. A
 #: cluster on a mesh takes the same verb from
-#: ``parallel/mesh.sharded_program`` instead.
+#: ``parallel/mesh.sharded_program`` instead (its step is ``engine_step``'s
+#: body, which builds the masks in every round).
 _ROUND_PROGRAMS = {
-    "step": (engine_step, engine_step_telem, engine_step_trace),
+    "step": (
+        engine_step_carried, engine_step_carried_telem,
+        engine_step_carried_trace,
+    ),
     "decision": (run_to_decision, run_to_decision_telem, run_to_decision_trace),
     "wave": (
         run_until_membership, run_until_membership_telem,
         run_until_membership_trace,
     ),
 }
+
+
+class CarriedMasks:
+    """The per-edge masks a meshless per-round driver carries beside its
+    state, and the decision whether they are still those of its inputs.
+
+    Staleness is identity, decided on the host: the masks are remembered
+    with the four leaves ``_edge_masks`` reads (after a step: the step's own
+    outputs and the faults it was passed) and reused only while the driver's
+    ``state.alive``, ``state.obs_idx``, ``faults.crashed`` and
+    ``faults.rx_block`` ARE those objects. Every seam that can change one of
+    them — an injection, another verb, an assignment to ``state`` or
+    ``faults`` from outside the class — leaves a NEW array there, so no seam
+    has to report itself; a seam that leaves the four alone
+    (``set_flaky_edges``, a fired-edge stamp) costs no build."""
+
+    def __init__(self, build):
+        self._build = build  # the driver's build program
+        self._masks = None
+        self._sources = ()
+
+    @staticmethod
+    def _inputs(driver) -> tuple:
+        return (
+            driver.state.alive, driver.state.obs_idx,
+            driver.faults.crashed, driver.faults.rx_block,
+        )
+
+    def for_step(self, driver):
+        """The masks to pass to the step about to be dispatched: the
+        carried ones, or a fresh build (one dispatch, no fetch)."""
+        if self._masks is not None and all(
+            now is built
+            for now, built in zip(self._inputs(driver), self._sources)
+        ):
+            driver.metrics.inc("engine_edge_mask_reuses")
+            return self._masks
+        driver.metrics.inc("engine_edge_mask_builds")
+        return self._build(driver.cfg, driver.state, driver.faults)
+
+    def keep(self, driver, masks) -> None:
+        """After the step: ``masks`` are those of the driver's state and
+        faults as they stand now."""
+        self._masks = masks
+        self._sources = self._inputs(driver)
 
 
 def _mesh_lib():
@@ -1344,6 +1443,7 @@ class VirtualCluster(DispatchSeam):
         # self-healing tier's checkpoint/retry/wedge stats (None = no
         # supervision, no recovery section).
         self.recovery = None
+        self._carried = CarriedMasks(edge_masks_build)
         # Device telemetry plane (cfg.telemetry == 1): the lanes live on
         # device beside the state; the host keeps only a digest cache,
         # zero-minted at attach (the exposition series exist from the first
@@ -1813,11 +1913,20 @@ class VirtualCluster(DispatchSeam):
     def _step(self, phase: str, **tags) -> StepEvents:
         """ONE body for both step spellings: only the dispatch-phase label
         (and the span's tags) differ, so a change here cannot diverge the
-        streamed path from the batch path the bit-identity tests pin."""
+        streamed path from the batch path the bit-identity tests pin.
+        Without a mesh the step carries the per-edge masks
+        (:func:`engine_step_carried_impl`); on a mesh it is ``engine_step``'s
+        sharded form, which builds them in every round."""
         self.metrics.inc("engine_steps")
         self.metrics.inc("engine_convergence_steps")
         with self._dispatch(phase, **tags):
-            (events,) = self._advance("step")
+            if self.mesh is not None:
+                (events,) = self._advance("step")
+            else:
+                events, masks = self._advance(
+                    "step", self._carried.for_step(self)
+                )
+                self._carried.keep(self, masks)
         return events
 
     def step(self) -> StepEvents:
@@ -1825,8 +1934,9 @@ class VirtualCluster(DispatchSeam):
 
     def stream_step(self, wave: Optional[int] = None) -> StepEvents:
         """One ENQUEUED engine round for the streaming pipeline
-        (rapid_tpu/serving): the same compiled ``engine_step`` program as
-        :meth:`step` — bit-identical math — accounted under the
+        (rapid_tpu/serving): the same compiled program as :meth:`step`
+        (``engine_step``'s math on carried masks, bit-identical to it: see
+        :meth:`_step`), accounted under the
         ``stream_enqueue`` phase and guaranteed fetch-free, so the host
         returns as soon as JAX has queued the dispatch. The returned events
         stay device-resident (they are the stream driver's completion

@@ -17,10 +17,13 @@ that workload:
   chews through this one.
 - **Double-buffered inputs.** Every engine entrypoint donates its state
   pytree (38/38 leaves aliased, frozen in ``hlo.lock.json``), so the state
-  buffers ping-pong in place; the per-wave fault deltas land in fresh
-  buffers the host writes while the previous wave's buffers are still
-  feeding in-flight dispatches. Donation is what makes this safe: the
-  driver never hands the device a buffer the host might still mutate.
+  buffers ping-pong in place — and with them the per-edge masks the meshless
+  step carries from round to round, rebuilt only after an injection and in a
+  cut's taken arm (``CarriedMasks``: a wave of eight rounds builds them
+  twice, not eight times); the per-wave fault deltas land in fresh buffers
+  the host writes while the previous wave's buffers are still feeding
+  in-flight dispatches. Donation is what makes this safe: the driver never
+  hands the device a buffer the host might still mutate.
 - **Explicit fetch boundaries.** The only host syncs are the completion
   ticket waits (the last round's device-resident ``StepEvents.decided``)
   and the drain-time epoch fetch, both accounted under the
@@ -38,8 +41,10 @@ kinds), so chaos schedules stream through the same pipe
 
 Bit-identity bar: a schedule driven wave-by-wave through the stream driver
 yields exactly the cuts, config ids, and final state pytree of the same
-schedule driven through the batch seams — same compiled programs, same
-inputs, same order; only the synchronization structure differs. Pinned by
+schedule driven through the batch seams — the same math on the same
+inputs in the same order (``stream_step`` IS ``step``'s program; against
+the fused loops and the mesh's ``engine_step`` only the place of the mask
+build and the synchronization structure differ). Pinned by
 ``tests/test_stream.py`` for both the single-cluster and fleet paths.
 """
 

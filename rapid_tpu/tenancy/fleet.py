@@ -39,7 +39,11 @@ Batched-control-flow tradeoffs, stated plainly:
   scalar ``lax.cond(any(decided))`` taken outside the vmap — a round in
   which nobody decided skips the rebuild, a round in which anybody did pays
   it for all (per-tenant select inside the arm), and
-  ``engine_fleet_commit_rounds`` counts how often that is. On a
+  ``engine_fleet_commit_rounds`` counts how often that is. The same step
+  carries the stacked per-edge masks from round to round and rebuilds them
+  in that arm only (ledger, PR 27: 54 of a 100 ms round went into building
+  them anew in every round); ``engine_edge_mask_builds`` counts the builds
+  the driver dispatches because its inputs changed under it. On a
   ``'tenant'``-sharded mesh that any() is a cross-tenant reduce, which the
   zero-cross-tenant budget forbids, so :func:`fleet_step_impl` (behind
   :func:`make_fleet_step` and the analyzers' ladder) keeps the lockstep
@@ -74,8 +78,10 @@ from rapid_tpu.models.state import (
     initial_trace,
 )
 from rapid_tpu.models.virtual_cluster import (
+    CarriedMasks,
     VirtualCluster,
     _compute_round,
+    _edge_masks,
     apply_view_change_impl,
     engine_step_impl,
     run_to_decision_impl,
@@ -187,24 +193,39 @@ def fleet_step_impl(
     return jax.vmap(one)(state, faults, knobs)
 
 
+def fleet_edge_masks_impl(cfg: EngineConfig, state: EngineState, faults: FaultInputs):
+    """``_edge_masks`` for every tenant (it reads no knob): the stacked
+    masks the gated step carries."""
+    return jax.vmap(lambda s, f: _edge_masks(cfg, s, f))(state, faults)
+
+
 def fleet_step_gated_impl(
     cfg: EngineConfig,
     state: EngineState,
     faults: FaultInputs,
     knobs: TenantKnobs,
     commit_rounds,
+    masks,
     telem: Optional[TelemetryLanes] = None,
     trace: Optional[TraceRing] = None,
 ):
     """The MESHLESS fleet step the drivers dispatch (module docstring): one
     protocol round for every tenant with the view change under ONE scalar
-    gate. ``_compute_round`` is vmapped alone; the commit —
-    ``apply_view_change_impl`` vmapped, then the per-tenant select — sits in
-    the taken arm of ``lax.cond(any(decided))`` outside the vmap, so a round
-    in which no tenant decided runs no ring rebuild. Per-tenant results are
-    bit-identical to :func:`fleet_step_impl` (and to B separate
-    ``VirtualCluster.step`` runs): the same two functions on the same
-    values, only the place of the condition differs.
+    gate and the per-edge masks CARRIED from round to round. ``_compute_round``
+    is vmapped alone, over the stacked ``masks`` it is handed; the commit —
+    ``apply_view_change_impl`` vmapped, the per-tenant select, then
+    ``_edge_masks`` vmapped over the committed state — sits in the taken arm
+    of ``lax.cond(any(decided))`` outside the vmap, so a round in which no
+    tenant decided runs no ring rebuild and no mask build. Per-tenant results
+    are bit-identical to :func:`fleet_step_impl` (and to B separate
+    ``VirtualCluster.step`` runs): the same functions on the same values,
+    only the place of the condition and of the build differs.
+
+    ``masks`` must be :func:`fleet_edge_masks_impl` of exactly ``(state,
+    faults)`` (the driver's business: ``CarriedMasks``); those returned are
+    the masks of ``(new_state, faults)``. The arm rebuilds them for EVERY
+    tenant: they are a pure function of state and faults, so an undecided
+    tenant gets its old values back and no per-tenant select is needed.
 
     ``telem``/``trace`` ride along as optional pytrees exactly as
     ``_compute_round`` takes them (``None`` traces no observer code), so the
@@ -212,14 +233,14 @@ def fleet_step_gated_impl(
     device-carried int32 behind ``engine_fleet_commit_rounds``: the rounds in
     which the gate opened, fetched only at the driver's host-sync boundaries.
 
-    Returns ``(state, commit_rounds, events, telem, trace)``."""
+    Returns ``(state, commit_rounds, masks, events, telem, trace)``."""
 
-    def one_round(state, faults, kn, telem, trace):
-        out = _compute_round(_tenant_cfg(cfg, kn), state, faults, None, telem, trace)
+    def one_round(state, faults, kn, masks, telem, trace):
+        out = _compute_round(_tenant_cfg(cfg, kn), state, faults, masks, telem, trace)
         return out + (None,) * (6 - len(out))  # absent observers stay None
 
     round_state, decided, winner, events, telem, trace = jax.vmap(one_round)(
-        state, faults, knobs, telem, trace
+        state, faults, knobs, masks, telem, trace
     )
 
     def commit_one(kn, round_state, winner, decided):
@@ -229,15 +250,17 @@ def fleet_step_gated_impl(
                 lambda com, rnd: jnp.where(decided, com, rnd), committed, round_state
             )
 
+    def commit(s):
+        committed = jax.vmap(commit_one)(knobs, s, winner, decided)
+        return committed, fleet_edge_masks_impl(cfg, committed, faults)
+
     any_decided = jnp.any(decided)
-    new_state = jax.lax.cond(
-        any_decided,
-        lambda s: jax.vmap(commit_one)(knobs, s, winner, decided),
-        scope("view_keep")(lambda s: s),
-        round_state,
+    new_state, masks = jax.lax.cond(
+        any_decided, commit, scope("view_keep")(lambda s: (s, masks)), round_state
     )
     return (
-        new_state, commit_rounds + any_decided.astype(jnp.int32), events, telem, trace
+        new_state, commit_rounds + any_decided.astype(jnp.int32), masks, events,
+        telem, trace,
     )
 
 
@@ -581,11 +604,14 @@ def tenant_health_impl(cfg: EngineConfig, state: EngineState) -> jnp.ndarray:
 
 tenant_health = jax.jit(tenant_health_impl, static_argnums=(0,))  # donate-ok: read-only health reduction — the state must survive the scan
 
-# The three spellings of the gated step (state and the commit-round counter
-# always donated; the observers' lanes where they ride).
+# The three spellings of the gated step (state, the commit-round counter and
+# the carried masks always donated; the observers' lanes where they ride).
 fleet_step = jax.jit(
-    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4)
+    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5)
 )
+#: The build program: dispatched by the driver only when the masks it
+#: carries are not those of the inputs it is about to pass.
+fleet_edge_masks = jax.jit(fleet_edge_masks_impl, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
 fleet_run_to_decision = jax.jit(
     fleet_run_to_decision_impl, static_argnums=(0,), donate_argnums=(1,)
 )
@@ -594,7 +620,7 @@ fleet_wave = jax.jit(
 )
 
 fleet_step_telem = jax.jit(
-    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5)
+    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5, 6)
 )
 fleet_run_to_decision_telem = jax.jit(
     fleet_run_to_decision_telem_impl, static_argnums=(0,), donate_argnums=(1, 2)
@@ -606,7 +632,7 @@ fleet_wave_telem = jax.jit(
 fleet_telemetry_digest = jax.jit(jax.vmap(telemetry_digest_impl))
 
 fleet_step_trace = jax.jit(
-    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5, 6)
+    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5, 6, 7)
 )
 fleet_run_to_decision_trace = jax.jit(
     fleet_run_to_decision_trace_impl,
@@ -717,6 +743,7 @@ class TenantFleet(DispatchSeam):
         # engine_fleet_commit_rounds only at host-sync boundaries.
         self._commit_rounds = jnp.zeros((), dtype=jnp.int32)
         self._commit_rounds_stale = False
+        self._carried = CarriedMasks(fleet_edge_masks)
         # Device telemetry plane: per-tenant lanes + the host-side activity
         # cache, zero-minted at attach (every series exists from scrape 0)
         # and refreshed ONLY at host-sync boundaries.
@@ -852,7 +879,8 @@ class TenantFleet(DispatchSeam):
     def stream_step(self, wave: Optional[int] = None) -> StepEvents:
         """One ENQUEUED batched round for the streaming pipeline
         (rapid_tpu/serving): the same compiled ``fleet_step`` program as
-        :meth:`step` — bit-identical per tenant — accounted under the
+        :meth:`step` (``fleet_step_impl``'s math under one gate and on carried
+        masks, bit-identical to it per tenant), accounted under the
         ``stream_enqueue`` phase and guaranteed fetch-free; the stacked
         events stay device-resident (the stream driver's ticket); ``wave``
         tags the round's span with the stream driver's wave index."""
@@ -871,12 +899,14 @@ class TenantFleet(DispatchSeam):
         )
         with self._dispatch(phase, **tags):
             (
-                self.state, self._commit_rounds, events,
+                self.state, self._commit_rounds, masks, events,
                 self.telem, self.trace_ring,
             ) = step(
                 self.cfg, self.state, self.faults, self.knobs,
-                self._commit_rounds, self.telem, self.trace_ring,
+                self._commit_rounds, self._carried.for_step(self), self.telem,
+                self.trace_ring,
             )
+            self._carried.keep(self, masks)
         return events
 
     def stream_crash(self, pairs) -> None:

@@ -85,6 +85,12 @@ ENGINE_KNOWN_COUNTERS = (
     "engine_cuts_committed",
     "engine_h2d_bytes",
     "engine_d2h_bytes",
+    # The meshless per-round step carries its per-edge masks
+    # (models/virtual_cluster.py::CarriedMasks): build programs dispatched
+    # because the step's inputs had changed, and steps that started on the
+    # carried masks. Builds inside a cut's taken arm are the cuts themselves.
+    "engine_edge_mask_builds",
+    "engine_edge_mask_reuses",
 )
 
 #: Tenant-fleet counters zero-filled on snapshots whose ``engine`` section

@@ -62,6 +62,8 @@ GOLDEN_ENGINE_METRIC_NAMES = [
     "rapid_engine_dispatch_ms_count",
     "rapid_engine_dispatch_ms_sum",
     "rapid_engine_dispatches_total",
+    "rapid_engine_edge_mask_builds_total",
+    "rapid_engine_edge_mask_reuses_total",
     "rapid_engine_h2d_bytes_total",
     "rapid_engine_live_buffer_bytes",
     "rapid_engine_live_buffers",

@@ -11,6 +11,7 @@ compiles must stay out of this session (tier-1 ends within 1 % of
 ``vm.max_map_count``).
 """
 
+import hashlib
 import json
 import os
 import re
@@ -36,8 +37,12 @@ IDENTITY_ARMS = {"invalidation_skip", "classic_skip", "view_keep"}
 EXPECTED = {
     "run_until_membership": ROUND | {"loop_result"},
     "engine_step": ROUND,
+    "engine_step_carried": ROUND,
+    "edge_masks_build": {"edge_masks"},
     "fleet_step": ROUND,
+    "fleet_edge_masks": {"edge_masks"},
     "mesh_fleet_step": ROUND,
+    "mesh_step": ROUND,
     "engine_step_trace": ROUND | {"observers"},
     "sync_checksum": {"sync_checksum"},
     "predecessor_of_keys": {"join_predecessors"},
@@ -65,7 +70,7 @@ def _scopes(paths) -> set:
 def lowered():
     from rapid_tpu.models import virtual_cluster as vcm
     from rapid_tpu.ops.rings import predecessor_of_keys
-    from rapid_tpu.parallel.mesh import make_mesh
+    from rapid_tpu.parallel.mesh import make_mesh, make_sharded_step, sharded_program
     from rapid_tpu.tenancy import fleet as fleetm
 
     kw = dict(n_slots=32, k=3, h=3, l=1, cohorts=2, fd_threshold=2,
@@ -76,15 +81,29 @@ def lowered():
         2, 28, n_slots=32, k=3, cohorts=2, knobs=[(3, 1, 2)] * 2, delivery_spread=1)
     i32, s = jnp.int32, vc.state
     idx = jnp.arange(28, 30)
+    # the carried masks as shapes: nothing is compiled or run here
+    masks = jax.eval_shape(vcm.edge_masks_build, vc.cfg, s, vc.faults)
+    fleet_masks = jax.eval_shape(
+        fleetm.fleet_edge_masks, fleet.cfg, fleet.state, fleet.faults)
+    mesh = make_mesh(jax.devices()[:4], shape=(1, 4))  # cluster-10m's layout
     return {
         "run_until_membership": vcm.run_until_membership.lower(
             vc.cfg, s, vc.faults, i32(28), i32(16), 4, i32(1)),
         "engine_step": vcm.engine_step.lower(vc.cfg, s, vc.faults),
+        "engine_step_carried": vcm.engine_step_carried.lower(vc.cfg, s, vc.faults, masks),
+        "edge_masks_build": vcm.edge_masks_build.lower(vc.cfg, s, vc.faults),
         "fleet_step": fleetm.fleet_step.lower(
-            fleet.cfg, fleet.state, fleet.faults, fleet.knobs, i32(0)),
+            fleet.cfg, fleet.state, fleet.faults, fleet.knobs, i32(0), fleet_masks),
+        "fleet_edge_masks": fleetm.fleet_edge_masks.lower(
+            fleet.cfg, fleet.state, fleet.faults),
+        "fleet_run_to_decision": fleetm.fleet_run_to_decision.lower(
+            fleet.cfg, fleet.state, fleet.faults, fleet.knobs, i32(16)),
         "mesh_fleet_step": fleetm.make_fleet_step(
             fleet.cfg, make_mesh(jax.devices()[:8], shape=(2, 2, 2)),
         ).lower(fleet.state, fleet.faults, fleet.knobs),
+        "mesh_step": make_sharded_step(vc.cfg, mesh).lower(s, vc.faults),
+        "mesh_run_to_decision": sharded_program("decision", vc.cfg, mesh).lower(
+            s, vc.faults, i32(16)),
         "engine_step_trace": vcm.engine_step_trace.lower(
             traced.cfg, traced.state, traced.telem, traced.trace_ring, traced.faults),
         "sync_checksum": vcm.sync_checksum.lower(s, vc.faults),
@@ -103,7 +122,8 @@ def test_every_registered_scope_is_traced_somewhere_or_is_an_identity_arm():
     assert len(set(ENGINE_SCOPES)) == len(ENGINE_SCOPES)
 
 
-@pytest.mark.parametrize("program", ["run_until_membership", "engine_step"])
+@pytest.mark.parametrize(
+    "program", ["run_until_membership", "engine_step", "engine_step_carried"])
 @pytest.mark.parametrize("arms", [
     ("deliver", "deliver_skip"), ("invalidation",), ("classic",), ("view_change",)])
 def test_each_arm_of_a_conditional_carries_its_own_name(lowered, program, arms):
@@ -141,6 +161,50 @@ def test_the_meshless_fleet_step_gates_its_view_change_on_one_conditional(lowere
     assert len({m.group(1) for m in arms}) == 1
     # the other arm returns its operand: it traces no operation (IDENTITY_ARMS)
     assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(paths)))
+
+
+@pytest.mark.parametrize("program", ["engine_step_carried", "fleet_step"])
+def test_the_meshless_step_builds_its_masks_in_the_cuts_taken_arm_only(lowered, program):
+    # the step the drivers dispatch carries its per-edge masks: the one build
+    # it still traces lies under the arm of the view-change conditional that
+    # also holds the view change, and a round that commits nothing runs none
+    paths = _paths(lowered[program])
+    arm = r"(?:^|/)cond/(branch_\d+_fun)/(?:vmap\()?%s\)?/"
+    builds = {p for p in paths if "edge_masks" in p}
+    build_arms = {re.search(arm % "edge_masks", p) for p in builds}
+    assert builds and None not in build_arms
+    view_arms = {m.group(1) for p in paths if (m := re.search(arm % "view_change", p))}
+    assert {m.group(1) for m in build_arms} == view_arms and len(view_arms) == 1
+    # where the mesh's step, the parent's program, builds them in every round
+    assert any("edge_masks" in p and "cond/" not in p for p in _paths(lowered["mesh_step"]))
+
+
+@pytest.mark.parametrize("program", ["edge_masks_build", "fleet_edge_masks"])
+def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
+    paths = {p for p in _paths(lowered[program]) if p.startswith("jit(")}  # not the source files
+    assert paths and not any("cond/" in p for p in paths)
+    assert all(re.match(r"jit\(\w+\)/(?:vmap\()?edge_masks\)?/", p) for p in paths)
+
+
+#: First sixteen hex digits of the SHA-256 of ``lowered.as_text()`` at commit
+#: f450378 (the parent of PR 28), at this module's tiny shapes: the programs
+#: of the cells the carried masks bypass (churn5's fused wave, crash10's
+#: fleet decision, cluster-10m's meshed decision) and the two mesh steps.
+#: A PR that means to change one of them replaces its digest with the one
+#: the failure prints.
+PARENT_PROGRAMS = {
+    "run_until_membership": "1f6637696631d51b",
+    "fleet_run_to_decision": "7b23cb5f877db09b",
+    "mesh_run_to_decision": "2e585f0987f0656f",
+    "mesh_step": "9343185e4ea60084",
+    "mesh_fleet_step": "721316093e3bd78d",
+}
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
+def test_a_program_the_carried_masks_bypass_lowers_to_the_parents_text(lowered, program):
+    text = lowered[program].as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_PROGRAMS[program]
 
 
 def test_scope_names_keep_clear_of_the_hlo_gates_needles():
