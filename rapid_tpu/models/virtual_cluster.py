@@ -251,9 +251,11 @@ def _compute_round(
     ``telem`` (the device telemetry plane, ``cfg.telemetry == 1``): when a
     :class:`TelemetryLanes` pytree is passed, the round accumulates into it
     and the return grows a fifth element — the updated lanes. The branch is
-    a PYTHON-level ``if``: with ``telem=None`` (telemetry off) no telemetry
-    code is traced at all, so the compiled program is byte-identical to the
-    pre-telemetry engine (the hlo.lock.json gate freezes that). Telemetry
+    a PYTHON-level ``if``: with no observer passed no observer code is
+    traced. THAT is the invariant that keeps an observers-off program the
+    same program, and it is why every round body above this function is
+    written once with the observers as optional pytrees (``*observers``)
+    and jitted once per observer count, not copied per count. Telemetry
     is write-only — nothing below reads a ``tl_`` lane — so engine results
     are bit-identical on vs off by construction, and every accumulation is
     either an already-computed round scalar or elementwise at the lane's
@@ -732,19 +734,62 @@ def apply_view_change_impl(
     )
 
 
-def engine_step_impl(
-    cfg: EngineConfig, state: EngineState, faults: FaultInputs
-) -> Tuple[EngineState, StepEvents]:
-    """One full protocol round including conditional view-change application
-    (the per-step driver path)."""
-    round_state, decided, winner_mask, events = _compute_round(cfg, state, faults)
-    new_state = jax.lax.cond(
+def _view_change_gate(cfg: EngineConfig, state: EngineState, decided, winner_mask):
+    """THE gate around the commit for a body that carries no masks: the
+    view change if the round ``decided``, else the state as it came."""
+    return jax.lax.cond(
         decided,
         lambda s: apply_view_change_impl(cfg, s, winner_mask),
         scope("view_keep")(lambda s: s),
-        round_state,
+        state,
     )
-    return new_state, events
+
+
+def _view_change_gate_masks(
+    cfg: EngineConfig, state: EngineState, faults: FaultInputs, masks,
+    decided, winner_mask,
+):
+    """The gate for a body that carries the per-edge masks beside the
+    state: the view change AND the mask rebuild ride one cond. Topology
+    (and with it the observer-active/delivery masks) changes ONLY when a cut
+    commits, so the rebuild's pack + permutation gathers are per-CUT work,
+    gated exactly like the ring rebuild — never unconditional hot-loop
+    traffic (the compiled-program gate pins this: the wave's hot loop stays
+    reduce-class on both the 1-D and the 2-D mesh). Returns ``(state,
+    masks)``, the masks those of the returned state and ``faults``."""
+
+    def commit(s):
+        committed = apply_view_change_impl(cfg, s, winner_mask)
+        return committed, _edge_masks(cfg, committed, faults)
+
+    return jax.lax.cond(
+        decided, commit, scope("view_keep")(lambda s: (s, masks)), state
+    )
+
+
+# Every round program below follows ONE convention, the one
+# ``parallel/mesh.sharded_program`` and ``VirtualCluster._advance`` speak:
+#
+#     program(cfg, state, *observers, faults, *rest)
+#         -> (state, *observers, *observations)
+#
+# ``observers`` is ``()``, ``(telem,)`` or ``(telem, trace)``, handed to
+# ``_compute_round`` as it takes them and carried through a loop directly
+# after the state. A driver jits one body once per observer count.
+
+
+def engine_step_impl(cfg: EngineConfig, state: EngineState, *rest):
+    """One full protocol round including conditional view-change application:
+    the MESH's per-round step (``sharded_program("step")``) and the
+    analyzers' reference, which builds the per-edge masks in every round.
+    ``rest`` is ``(*observers, faults)``; returns ``(state, *observers,
+    events)``."""
+    *observers, faults = rest
+    round_state, decided, winner_mask, events, *observers = _compute_round(
+        cfg, state, faults, None, *observers
+    )
+    new_state = _view_change_gate(cfg, round_state, decided, winner_mask)
+    return (new_state, *observers, events)
 
 
 # Donating step for the long-running driver loop (state buffers reused in
@@ -753,106 +798,34 @@ engine_step = jax.jit(engine_step_impl, static_argnums=(0,), donate_argnums=(1,)
 engine_step_nodonate = jax.jit(engine_step_impl, static_argnums=(0,))  # donate-ok: compile-check / dry-run variant; callers keep their state buffers
 
 
-def engine_step_telem_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    faults: FaultInputs,
-) -> Tuple[EngineState, TelemetryLanes, StepEvents]:
-    """:func:`engine_step_impl` with the telemetry plane riding along — a
-    SEPARATE entrypoint (not a default argument on the existing one) so the
-    telemetry=0 programs and their donation layout stay untouched, which is
-    what lets the hlo.lock.json diff stay purely additive."""
-    round_state, decided, winner_mask, events, telem = _compute_round(
-        cfg, state, faults, None, telem
-    )
-    new_state = jax.lax.cond(
-        decided,
-        lambda s: apply_view_change_impl(cfg, s, winner_mask),
-        scope("view_keep")(lambda s: s),
-        round_state,
-    )
-    return new_state, telem, events
-
-
-engine_step_telem = jax.jit(
-    engine_step_telem_impl, static_argnums=(0,), donate_argnums=(1, 2)
-)
-
-
-def engine_step_trace_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    trace: TraceRing,
-    faults: FaultInputs,
-) -> Tuple[EngineState, TelemetryLanes, TraceRing, StepEvents]:
-    """:func:`engine_step_telem_impl` with the round-trace ring riding along
-    — a SEPARATE entrypoint again (the ``telemetry`` convention), so the
-    trace=0 programs and their donation layout stay untouched and the
-    hlo.lock.json diff stays purely additive."""
-    round_state, decided, winner_mask, events, telem, trace = _compute_round(
-        cfg, state, faults, None, telem, trace
-    )
-    new_state = jax.lax.cond(
-        decided,
-        lambda s: apply_view_change_impl(cfg, s, winner_mask),
-        scope("view_keep")(lambda s: s),
-        round_state,
-    )
-    return new_state, telem, trace, events
-
-
-engine_step_trace = jax.jit(
-    engine_step_trace_impl, static_argnums=(0,), donate_argnums=(1, 2, 3)
-)
-
-
 def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest):
     """The MESHLESS per-round step the driver dispatches: the math of
     :func:`engine_step_impl` with the per-edge masks CARRIED from round to
     round beside the state instead of rebuilt in every round. ``rest`` is
-    ``([telem, [trace,]] faults, masks)``, so the three jits below are this
-    one body (the observers optional pytrees, as ``_compute_round`` takes
-    them) and :meth:`VirtualCluster._advance` calls it like any verb.
+    ``(*observers, faults, masks)``.
 
     ``masks`` must be ``_edge_masks(cfg, state, faults)`` for exactly these
     inputs (the driver's business: :class:`CarriedMasks`). They are a pure
     function of ``alive``, ``obs_idx``, ``crashed`` and ``rx_block``; the
     round leaves those four alone and a committed cut changes the first two,
     so the taken arm of the view-change gate rebuilds them for the committed
-    state — what the fused loops' ``commit`` does — and the other arm hands
+    state — what the fused loops do — and the other arm hands
     them back. The masks returned are those of ``(new_state,
     faults)``. Per round the state, events and observer lanes are
     bit-identical to :func:`engine_step_impl`'s: the same functions on the
     same values, only the place of the build differs.
 
-    Returns ``(state, [telem, [trace,]] events, masks)``."""
+    Returns ``(state, *observers, events, masks)``."""
     *observers, faults, masks = rest
     round_state, decided, winner_mask, events, *observers = _compute_round(
         cfg, state, faults, masks, *observers
     )
-
-    def commit(s):
-        committed = apply_view_change_impl(cfg, s, winner_mask)
-        return committed, _edge_masks(cfg, committed, faults)
-
-    new_state, masks = jax.lax.cond(
-        decided, commit, scope("view_keep")(lambda s: (s, masks)), round_state
+    new_state, masks = _view_change_gate_masks(
+        cfg, round_state, faults, masks, decided, winner_mask
     )
     return (new_state, *observers, events, masks)
 
 
-# State, observers' lanes and masks donated: the faults alone stay the caller's.
-engine_step_carried = jax.jit(
-    engine_step_carried_impl, static_argnums=(0,), donate_argnums=(1, 3)
-)
-engine_step_carried_telem = jax.jit(
-    engine_step_carried_impl, static_argnums=(0,), donate_argnums=(1, 2, 4)
-)
-engine_step_carried_trace = jax.jit(
-    engine_step_carried_impl, static_argnums=(0,), donate_argnums=(1, 2, 3, 5)
-)
 #: The build program: dispatched by the driver only when the masks it
 #: carries are not those of the inputs it is about to pass.
 edge_masks_build = jax.jit(_edge_masks, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
@@ -934,163 +907,77 @@ def sync_checksum_impl(state: EngineState, faults: FaultInputs):
 sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the state stays live
 
 
-def run_to_decision_impl(cfg: EngineConfig, state: EngineState, faults: FaultInputs, max_steps):
-    """Protocol rounds until a view change commits — entirely on device.
-
-    A ``lax.while_loop`` around ``engine_step_impl``: the host dispatches ONE
-    program per convergence instead of one per round, removing the per-round
-    device->host sync that dominates small-round convergences. Returns
-    (state, steps_taken, decided, winner_mask).
-    """
-    n = cfg.n
-
-    def cond(carry):
-        _, steps, decided, _ = carry
-        return (~decided) & (steps < max_steps)
-
-    # Topology and faults are fixed until the loop exits (it exits on the
-    # first decision), so the per-edge gather hoists out of the round body.
-    edge_masks = _edge_masks(cfg, state, faults)
-
-    def body(carry):
-        state, steps, _, _ = carry
-        round_state, decided, winner_mask, _ = _compute_round(
-            cfg, state, faults, edge_masks
-        )
-        return (round_state, steps + 1, decided, winner_mask)
-
-    init = (state, jnp.int32(0), jnp.bool_(False), jnp.zeros((n,), dtype=bool))
-    state, steps, decided, winner = jax.lax.while_loop(cond, body, init)
-    # Apply the (at most one) view change after the loop: the round body stays
-    # sort-free, and the ring rebuild runs exactly once per convergence.
-    state = jax.lax.cond(
-        decided,
-        lambda s: apply_view_change_impl(cfg, s, winner),
-        scope("view_keep")(lambda s: s),
-        state,
-    )
-    return (state, steps, decided, winner)
-
-
-run_to_decision = jax.jit(
-    run_to_decision_impl, static_argnums=(0,), donate_argnums=(1,)
-)
-
-
-def run_to_decision_telem_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    faults: FaultInputs,
-    max_steps,
+def _converge(
+    cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
+    masks, steps, max_steps,
 ):
-    """:func:`run_to_decision_impl` with the telemetry lanes joining the
-    while-loop carry (separate entrypoint; same rationale as
-    :func:`engine_step_telem_impl`)."""
-    n = cfg.n
+    """THE inner convergence loop: rounds over fixed per-edge ``masks``
+    (topology and faults are fixed until a cut commits, so the per-edge
+    gather is hoisted out of the round body by every caller) until one
+    decides or ``steps`` reaches ``max_steps``. The round body stays
+    sort-free: the caller applies the (at most one) view change after the
+    loop, so the ring rebuild runs exactly once per convergence. Returns
+    ``(round_state, observers, steps, decided, winner_mask)``."""
 
     def cond(carry):
-        _, _, steps, decided, _ = carry
+        *_, steps, decided, _ = carry
         return (~decided) & (steps < max_steps)
 
-    edge_masks = _edge_masks(cfg, state, faults)
-
     def body(carry):
-        state, telem, steps, _, _ = carry
-        round_state, decided, winner_mask, _, telem = _compute_round(
-            cfg, state, faults, edge_masks, telem
+        state, *observers, steps, _, _ = carry
+        round_state, decided, winner_mask, _, *observers = _compute_round(
+            cfg, state, faults, masks, *observers
         )
-        return (round_state, telem, steps + 1, decided, winner_mask)
-
-    init = (state, telem, jnp.int32(0), jnp.bool_(False), jnp.zeros((n,), dtype=bool))
-    state, telem, steps, decided, winner = jax.lax.while_loop(cond, body, init)
-    state = jax.lax.cond(
-        decided,
-        lambda s: apply_view_change_impl(cfg, s, winner),
-        scope("view_keep")(lambda s: s),
-        state,
-    )
-    return (state, telem, steps, decided, winner)
-
-
-run_to_decision_telem = jax.jit(
-    run_to_decision_telem_impl, static_argnums=(0,), donate_argnums=(1, 2)
-)
-
-
-def run_to_decision_trace_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    trace: TraceRing,
-    faults: FaultInputs,
-    max_steps,
-):
-    """:func:`run_to_decision_telem_impl` with the trace ring joining the
-    while-loop carry — the fused convergence stops being a black box: every
-    round of the loop leaves one record, and the ring's last R survive to
-    the boundary fetch."""
-    n = cfg.n
-
-    def cond(carry):
-        _, _, _, steps, decided, _ = carry
-        return (~decided) & (steps < max_steps)
-
-    edge_masks = _edge_masks(cfg, state, faults)
-
-    def body(carry):
-        state, telem, trace, steps, _, _ = carry
-        round_state, decided, winner_mask, _, telem, trace = _compute_round(
-            cfg, state, faults, edge_masks, telem, trace
-        )
-        return (round_state, telem, trace, steps + 1, decided, winner_mask)
+        return (round_state, *observers, steps + 1, decided, winner_mask)
 
     init = (
-        state, telem, trace, jnp.int32(0), jnp.bool_(False),
-        jnp.zeros((n,), dtype=bool),
+        state, *observers, steps, jnp.bool_(False),
+        jnp.zeros((cfg.n,), dtype=bool),
     )
-    state, telem, trace, steps, decided, winner = jax.lax.while_loop(
-        cond, body, init
+    state, *observers, steps, decided, winner = jax.lax.while_loop(cond, body, init)
+    return state, observers, steps, decided, winner
+
+
+def run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
+    """Protocol rounds until a view change commits — entirely on device.
+
+    A ``lax.while_loop`` around the round: the host dispatches ONE
+    program per convergence instead of one per round, removing the per-round
+    device->host sync that dominates small-round convergences. With
+    observers the fused convergence stops being a black box: every round of
+    the loop accumulates into the lanes and leaves one record in the ring.
+    ``rest`` is ``(*observers, faults, max_steps)``; returns
+    ``(state, *observers, steps_taken, decided, winner_mask)``.
+    """
+    *observers, faults, max_steps = rest
+    masks = _edge_masks(cfg, state, faults)
+    state, observers, steps, decided, winner = _converge(
+        cfg, state, observers, faults, masks, jnp.int32(0), max_steps
     )
-    state = jax.lax.cond(
-        decided,
-        lambda s: apply_view_change_impl(cfg, s, winner),
-        scope("view_keep")(lambda s: s),
-        state,
-    )
-    return (state, telem, trace, steps, decided, winner)
+    state = _view_change_gate(cfg, state, decided, winner)
+    return (state, *observers, steps, decided, winner)
 
 
-run_to_decision_trace = jax.jit(
-    run_to_decision_trace_impl, static_argnums=(0,), donate_argnums=(1, 2, 3)
-)
-
-
-def run_until_membership_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    faults: FaultInputs,
-    target,
-    max_steps,
-    max_cuts,
-    min_cuts,
-):
+def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
     """Protocol rounds through MULTIPLE view changes until the membership
     reaches ``target`` — one device dispatch for a whole churn/bootstrap
-    wave instead of one per cut.
+    wave instead of one per cut. ``rest`` is ``(*observers, faults, target,
+    max_steps, max_cuts, min_cuts)``.
 
     Structure: an outer loop of convergences, each of which (a) runs the
     same sort-free inner round loop as ``run_to_decision_impl`` over the
     hoisted per-edge masks, and (b) applies the view change WITH the
-    per-edge mask rebuild inside the same lax.cond (topology and the
-    implicit-alert stamps change only when a cut commits, so the mask
-    pack + permutation gathers are per-CUT work in a gated branch — the
-    compiled hot loop stays reduce-class on every mesh, which the
-    device_program gate freezes). Each dispatch+fetch pair costs a host
+    per-edge mask rebuild inside the same lax.cond
+    (:func:`_view_change_gate_masks`). Each dispatch+fetch pair costs a host
     round trip, so resolving a 2-cut churn or a bootstrap admission wave in
-    one dispatch removes that many from the measured wall clock.
+    one dispatch removes that many from the measured wall clock. The
+    observers accumulate ACROSS the wave's view changes — a commit never
+    resets the lanes or the ring, so a multi-cut wave reads as one
+    round-indexed story, the epoch stamp marking where each view change
+    landed.
 
-    Returns (state, total_steps, cuts_committed, resolved, sizes) where
+    Returns (state, *observers, total_steps, cuts_committed, resolved,
+    sizes) where
     ``sizes[i]`` is the membership after the i-th committed cut (-1 beyond
     ``cuts``) — the paper's Table 1 "intermediate views" instrument,
     observed without any per-cut fetch. ``max_cuts`` is static (it sizes
@@ -1099,44 +986,20 @@ def run_until_membership_impl(
     target" alone would resolve vacuously before the first cut — requiring
     at least min_cuts committed cuts makes the loop actually run the churn.
     """
-    n = cfg.n
+    *observers, faults, target, max_steps, max_cuts, min_cuts = rest
 
     def outer_cond(carry):
-        state, steps, cuts, stalled, _, _ = carry
+        state, *_, steps, cuts, stalled, _, _ = carry
         resolved = (state.n_members == target) & (cuts >= min_cuts)
         return (~resolved) & (~stalled) & (steps < max_steps) & (cuts < max_cuts)
 
     def outer_body(carry):
-        state, steps, cuts, _, sizes, edge_masks = carry
-
-        def inner_cond(carry):
-            _, steps, decided, _ = carry
-            return (~decided) & (steps < max_steps)
-
-        def inner_body(carry):
-            state, steps, _, _ = carry
-            round_state, decided, winner_mask, _ = _compute_round(
-                cfg, state, faults, edge_masks
-            )
-            return (round_state, steps + 1, decided, winner_mask)
-
-        init = (state, steps, jnp.bool_(False), jnp.zeros((n,), dtype=bool))
-        state, steps, decided, winner = jax.lax.while_loop(
-            inner_cond, inner_body, init
+        state, *observers, steps, cuts, _, sizes, masks = carry
+        state, observers, steps, decided, winner = _converge(
+            cfg, state, observers, faults, masks, steps, max_steps
         )
-        # The view change AND the per-edge mask rebuild ride one cond:
-        # topology (and with it the observer-active/delivery masks) changes
-        # ONLY when a cut commits, so the mask rebuild's pack + permutation
-        # gathers are per-CUT work, gated exactly like the ring rebuild —
-        # never unconditional hot-loop traffic (the compiled-program gate
-        # pins this: the wave's hot loop stays reduce-class on both the 1-D
-        # and the 2-D mesh).
-        def commit(s):
-            s2 = apply_view_change_impl(cfg, s, winner)
-            return s2, _edge_masks(cfg, s2, faults)
-
-        state, edge_masks = jax.lax.cond(
-            decided, commit, scope("view_keep")(lambda s: (s, edge_masks)), state
+        state, masks = _view_change_gate_masks(
+            cfg, state, faults, masks, decided, winner
         )
         with scope("loop_result"):
             sizes = jnp.where(
@@ -1144,206 +1007,54 @@ def run_until_membership_impl(
             )
         # A convergence that ran out of budget undecided cannot make further
         # progress (the outer loop would spin): latch and exit.
-        return (state, steps, cuts + decided.astype(jnp.int32), ~decided, sizes, edge_masks)
-
-    init = (
-        state,
-        jnp.int32(0),
-        jnp.int32(0),
-        jnp.bool_(False),
-        jnp.full((max_cuts,), -1, dtype=jnp.int32),
-        _edge_masks(cfg, state, faults),
-    )
-    state, steps, cuts, stalled, sizes, _ = jax.lax.while_loop(
-        outer_cond, outer_body, init
-    )
-    with scope("loop_result"):
-        resolved = (state.n_members == target) & (cuts >= min_cuts)
-    return (state, steps, cuts, resolved, sizes)
-
-
-run_until_membership = jax.jit(
-    run_until_membership_impl, static_argnums=(0, 5), donate_argnums=(1,)
-)
-
-
-def run_until_membership_telem_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    faults: FaultInputs,
-    target,
-    max_steps,
-    max_cuts,
-    min_cuts,
-):
-    """:func:`run_until_membership_impl` with the telemetry lanes joining
-    both loop carries (separate entrypoint; same rationale as
-    :func:`engine_step_telem_impl`). Telemetry accumulates ACROSS the
-    wave's view changes — the lanes are never reset by a commit, so a
-    multi-cut wave reads as one activity story."""
-    n = cfg.n
-
-    def outer_cond(carry):
-        state, _, steps, cuts, stalled, _, _ = carry
-        resolved = (state.n_members == target) & (cuts >= min_cuts)
-        return (~resolved) & (~stalled) & (steps < max_steps) & (cuts < max_cuts)
-
-    def outer_body(carry):
-        state, telem, steps, cuts, _, sizes, edge_masks = carry
-
-        def inner_cond(carry):
-            _, _, steps, decided, _ = carry
-            return (~decided) & (steps < max_steps)
-
-        def inner_body(carry):
-            state, telem, steps, _, _ = carry
-            round_state, decided, winner_mask, _, telem = _compute_round(
-                cfg, state, faults, edge_masks, telem
-            )
-            return (round_state, telem, steps + 1, decided, winner_mask)
-
-        init = (state, telem, steps, jnp.bool_(False), jnp.zeros((n,), dtype=bool))
-        state, telem, steps, decided, winner = jax.lax.while_loop(
-            inner_cond, inner_body, init
-        )
-
-        def commit(s):
-            s2 = apply_view_change_impl(cfg, s, winner)
-            return s2, _edge_masks(cfg, s2, faults)
-
-        state, edge_masks = jax.lax.cond(
-            decided, commit, scope("view_keep")(lambda s: (s, edge_masks)), state
-        )
-        with scope("loop_result"):
-            sizes = jnp.where(
-                decided, sizes.at[cuts].set(state.n_members), sizes
-            )
         return (
-            state, telem, steps, cuts + decided.astype(jnp.int32), ~decided,
-            sizes, edge_masks,
+            state, *observers, steps, cuts + decided.astype(jnp.int32),
+            ~decided, sizes, masks,
         )
 
     init = (
         state,
-        telem,
+        *observers,
         jnp.int32(0),
         jnp.int32(0),
         jnp.bool_(False),
         jnp.full((max_cuts,), -1, dtype=jnp.int32),
         _edge_masks(cfg, state, faults),
     )
-    state, telem, steps, cuts, stalled, sizes, _ = jax.lax.while_loop(
+    state, *observers, steps, cuts, _, sizes, _ = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
     with scope("loop_result"):
         resolved = (state.n_members == target) & (cuts >= min_cuts)
-    return (state, telem, steps, cuts, resolved, sizes)
+    return (state, *observers, steps, cuts, resolved, sizes)
 
 
-run_until_membership_telem = jax.jit(
-    run_until_membership_telem_impl, static_argnums=(0, 6), donate_argnums=(1, 2)
-)
-
-
-def run_until_membership_trace_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    trace: TraceRing,
-    faults: FaultInputs,
-    target,
-    max_steps,
-    max_cuts,
-    min_cuts,
-):
-    """:func:`run_until_membership_telem_impl` with the trace ring joining
-    both loop carries. Like the telemetry lanes the ring is never reset by a
-    commit — a multi-cut wave decodes as one round-indexed story, the epoch
-    stamp marking where each view change landed."""
-    n = cfg.n
-
-    def outer_cond(carry):
-        state, _, _, steps, cuts, stalled, _, _ = carry
-        resolved = (state.n_members == target) & (cuts >= min_cuts)
-        return (~resolved) & (~stalled) & (steps < max_steps) & (cuts < max_cuts)
-
-    def outer_body(carry):
-        state, telem, trace, steps, cuts, _, sizes, edge_masks = carry
-
-        def inner_cond(carry):
-            _, _, _, steps, decided, _ = carry
-            return (~decided) & (steps < max_steps)
-
-        def inner_body(carry):
-            state, telem, trace, steps, _, _ = carry
-            round_state, decided, winner_mask, _, telem, trace = _compute_round(
-                cfg, state, faults, edge_masks, telem, trace
-            )
-            return (round_state, telem, trace, steps + 1, decided, winner_mask)
-
-        init = (
-            state, telem, trace, steps, jnp.bool_(False),
-            jnp.zeros((n,), dtype=bool),
+def jit_per_observer_count(impl, static=(), donated=()):
+    """Three jits of one round body, by how many observers ride along (0:
+    none, 1: the telemetry lanes, 2: lanes and trace ring). ``cfg`` is
+    static and the state and the observers are donated; ``static`` and
+    ``donated`` name further argument positions as they stand with no
+    observer, and shift with the count."""
+    return tuple(
+        jax.jit(
+            impl,
+            static_argnums=(0, *(at + k for at in static)),
+            donate_argnums=(*range(1, 2 + k), *(at + k for at in donated)),
         )
-        state, telem, trace, steps, decided, winner = jax.lax.while_loop(
-            inner_cond, inner_body, init
-        )
-
-        def commit(s):
-            s2 = apply_view_change_impl(cfg, s, winner)
-            return s2, _edge_masks(cfg, s2, faults)
-
-        state, edge_masks = jax.lax.cond(
-            decided, commit, scope("view_keep")(lambda s: (s, edge_masks)), state
-        )
-        with scope("loop_result"):
-            sizes = jnp.where(
-                decided, sizes.at[cuts].set(state.n_members), sizes
-            )
-        return (
-            state, telem, trace, steps, cuts + decided.astype(jnp.int32),
-            ~decided, sizes, edge_masks,
-        )
-
-    init = (
-        state,
-        telem,
-        trace,
-        jnp.int32(0),
-        jnp.int32(0),
-        jnp.bool_(False),
-        jnp.full((max_cuts,), -1, dtype=jnp.int32),
-        _edge_masks(cfg, state, faults),
+        for k in range(3)
     )
-    state, telem, trace, steps, cuts, stalled, sizes, _ = jax.lax.while_loop(
-        outer_cond, outer_body, init
-    )
-    with scope("loop_result"):
-        resolved = (state.n_members == target) & (cuts >= min_cuts)
-    return (state, telem, trace, steps, cuts, resolved, sizes)
 
 
-run_until_membership_trace = jax.jit(
-    run_until_membership_trace_impl, static_argnums=(0, 7), donate_argnums=(1, 2, 3)
-)
-
-
-#: A driver verb's one-device program by how many pytrees it carries: the
-#: state alone, with the telemetry lanes, with lanes and trace ring. A
-#: cluster on a mesh takes the same verb from
-#: ``parallel/mesh.sharded_program`` instead (its step is ``engine_step``'s
-#: body, which builds the masks in every round).
+#: A driver verb's one-device programs, by observer count. The step's carried
+#: masks (its last argument) are donated too, the faults alone stay the
+#: caller's; the wave's static ``max_cuts`` sits after the faults and two
+#: controls. A cluster on a mesh takes the same verb from
+#: ``parallel/mesh.sharded_program`` instead (its step is
+#: ``engine_step_impl``, which builds the masks in every round).
 _ROUND_PROGRAMS = {
-    "step": (
-        engine_step_carried, engine_step_carried_telem,
-        engine_step_carried_trace,
-    ),
-    "decision": (run_to_decision, run_to_decision_telem, run_to_decision_trace),
-    "wave": (
-        run_until_membership, run_until_membership_telem,
-        run_until_membership_trace,
-    ),
+    "step": jit_per_observer_count(engine_step_carried_impl, donated=(3,)),
+    "decision": jit_per_observer_count(run_to_decision_impl),
+    "wave": jit_per_observer_count(run_until_membership_impl, static=(5,)),
 }
 
 

@@ -64,14 +64,8 @@ from rapid_tpu.models.state import (
 )
 from rapid_tpu.models.virtual_cluster import (
     engine_step_impl,
-    engine_step_telem_impl,
-    engine_step_trace_impl,
     run_to_decision_impl,
-    run_to_decision_telem_impl,
-    run_to_decision_trace_impl,
     run_until_membership_impl,
-    run_until_membership_telem_impl,
-    run_until_membership_trace_impl,
 )
 
 NODE_AXIS = "nodes"
@@ -454,18 +448,11 @@ def initial_state_on_mesh(cfg: EngineConfig, mesh: Mesh, key_hi, key_lo, id_hi, 
     return _initial_state_program(cfg, mesh)(*placed)
 
 
-#: A driver verb's device program by how many pytrees it carries: the state
-#: alone, with the telemetry lanes, with lanes and trace ring.
+#: A driver verb's device program: one body, whatever rides beside the state.
 _ROUND_IMPLS = {
-    "step": (engine_step_impl, engine_step_telem_impl, engine_step_trace_impl),
-    "decision": (
-        run_to_decision_impl, run_to_decision_telem_impl,
-        run_to_decision_trace_impl,
-    ),
-    "wave": (
-        run_until_membership_impl, run_until_membership_telem_impl,
-        run_until_membership_trace_impl,
-    ),
+    "step": engine_step_impl,
+    "decision": run_to_decision_impl,
+    "wave": run_until_membership_impl,
 }
 #: Per verb: scalar control arguments after the faults, and observations
 #: after the carried pytrees (their placement is the compiler's).
@@ -481,15 +468,16 @@ def sharded_program(
     mesh dispatches: ``step`` (one round), ``decision``
     (``run_to_decision``) or ``wave`` (``run_until_membership``, multiple
     view changes in one dispatch) jitted over ``mesh`` (1-D or 2-D).
-    ``carried`` pytrees (state; + telemetry lanes; + trace ring) are donated
-    and come back with the rule table's shardings, stated and not left to
+    ``carried`` pytrees (state; + telemetry lanes; + trace ring) only size
+    the sharding tables and the donation (the body is the same): they are
+    donated and come back with the rule table's shardings, stated and not left to
     propagation: no leaf can drift or silently replicate between verbs, and
     donation aliases every buffer. Call as ``program(*carried, faults,
     *controls) -> (*carried, *observations)``; the wave's controls are
     ``(target, max_steps, min_cuts)`` and ``max_cuts`` is its static bound
     (the other verbs take none). One program per (verb, cfg, mesh):
     a second cluster of the same shape compiles nothing."""
-    impl = _ROUND_IMPLS[verb][carried - 1]
+    impl = _ROUND_IMPLS[verb]
     controls, observed = _ROUND_ARITY[verb]
     tables = (
         state_shardings(mesh), telemetry_shardings(mesh), trace_shardings(mesh)

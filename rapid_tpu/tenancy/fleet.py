@@ -84,9 +84,8 @@ from rapid_tpu.models.virtual_cluster import (
     _edge_masks,
     apply_view_change_impl,
     engine_step_impl,
+    jit_per_observer_count,
     run_to_decision_impl,
-    run_to_decision_telem_impl,
-    run_to_decision_trace_impl,
     telemetry_digest_impl,
     trace_digest_impl,
 )
@@ -199,19 +198,12 @@ def fleet_edge_masks_impl(cfg: EngineConfig, state: EngineState, faults: FaultIn
     return jax.vmap(lambda s, f: _edge_masks(cfg, s, f))(state, faults)
 
 
-def fleet_step_gated_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    commit_rounds,
-    masks,
-    telem: Optional[TelemetryLanes] = None,
-    trace: Optional[TraceRing] = None,
-):
+def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
     """The MESHLESS fleet step the drivers dispatch (module docstring): one
     protocol round for every tenant with the view change under ONE scalar
-    gate and the per-edge masks CARRIED from round to round. ``_compute_round``
+    gate and the per-edge masks CARRIED from round to round. ``rest`` is
+    ``(*observers, faults, knobs, commit_rounds, masks)``, the round
+    programs' one convention (``models/virtual_cluster.py``). ``_compute_round``
     is vmapped alone, over the stacked ``masks`` it is handed; the commit —
     ``apply_view_change_impl`` vmapped, the per-tenant select, then
     ``_edge_masks`` vmapped over the committed state — sits in the taken arm
@@ -227,20 +219,18 @@ def fleet_step_gated_impl(
     tenant: they are a pure function of state and faults, so an undecided
     tenant gets its old values back and no per-tenant select is needed.
 
-    ``telem``/``trace`` ride along as optional pytrees exactly as
-    ``_compute_round`` takes them (``None`` traces no observer code), so the
-    three jitted spellings below are this one body. ``commit_rounds`` is the
-    device-carried int32 behind ``engine_fleet_commit_rounds``: the rounds in
-    which the gate opened, fetched only at the driver's host-sync boundaries.
+    ``commit_rounds`` is the device-carried int32 behind
+    ``engine_fleet_commit_rounds``: the rounds in which the gate opened,
+    fetched only at the driver's host-sync boundaries.
 
-    Returns ``(state, commit_rounds, masks, events, telem, trace)``."""
+    Returns ``(state, *observers, commit_rounds, masks, events)``."""
+    *observers, faults, knobs, commit_rounds, masks = rest
 
-    def one_round(state, faults, kn, masks, telem, trace):
-        out = _compute_round(_tenant_cfg(cfg, kn), state, faults, masks, telem, trace)
-        return out + (None,) * (6 - len(out))  # absent observers stay None
+    def one_round(state, faults, kn, masks, *observers):
+        return _compute_round(_tenant_cfg(cfg, kn), state, faults, masks, *observers)
 
-    round_state, decided, winner, events, telem, trace = jax.vmap(one_round)(
-        state, faults, knobs, masks, telem, trace
+    round_state, decided, winner, events, *observers = jax.vmap(one_round)(
+        state, faults, knobs, masks, *observers
     )
 
     def commit_one(kn, round_state, winner, decided):
@@ -259,39 +249,30 @@ def fleet_step_gated_impl(
         any_decided, commit, scope("view_keep")(lambda s: (s, masks)), round_state
     )
     return (
-        new_state, commit_rounds + any_decided.astype(jnp.int32), masks, events,
-        telem, trace,
+        new_state, *observers, commit_rounds + any_decided.astype(jnp.int32),
+        masks, events,
     )
 
 
-def fleet_run_to_decision_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    max_steps,
-):
+def fleet_run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
     """Per-tenant single-dispatch convergence: ``run_to_decision_impl``
-    vmapped. The batched while's predicate reduces across tenants (vmap's
-    any()), so this entrypoint is for SINGLE-DEVICE driver dispatch — the
-    mesh-audited fleet entrypoints are the step and the lockstep wave."""
+    vmapped; ``rest`` is ``(*observers, faults, knobs, max_steps)``, the
+    observers' lanes stacked like the state. The batched while's predicate
+    reduces across tenants (vmap's any()), so this entrypoint is for
+    SINGLE-DEVICE driver dispatch — the mesh-audited fleet entrypoints are
+    the step and the lockstep wave."""
+    *observers, faults, knobs, max_steps = rest
 
-    def one(state, faults, kn):
-        return run_to_decision_impl(_tenant_cfg(cfg, kn), state, faults, max_steps)
+    def one(state, *rest):
+        *observers, faults, kn = rest
+        return run_to_decision_impl(
+            _tenant_cfg(cfg, kn), state, *observers, faults, max_steps
+        )
 
-    return jax.vmap(one)(state, faults, knobs)
+    return jax.vmap(one)(state, *observers, faults, knobs)
 
 
-def fleet_wave_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    target,
-    max_steps,
-    max_cuts: int,
-    min_cuts,
-):
+def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
     """The fleet's whole-wave loop: every tenant runs convergences through
     MULTIPLE view changes until its own ``target`` membership (at least its
     own ``min_cuts`` cuts), all in one dispatch — the batched twin of
@@ -303,16 +284,33 @@ def fleet_wave_impl(
     ``apply_view_change_impl`` sequence on the same values, only the loop
     skeleton differs (pinned by tests/test_tenancy.py's differential grid).
 
-    Returns ``(state, steps[t], cuts[t], resolved[t], sizes[t, max_cuts])``.
-    """
+    ``rest`` is ``(*observers, faults, knobs, target, max_steps, max_cuts,
+    min_cuts)``. The observers' lanes are select-gated by the SAME
+    ``active`` mask that freezes a finished tenant's state: a tenant that
+    coasts after resolving accumulates no phantom rounds, its ring's cursor
+    holds still and its slots are never overwritten (quarantined tenants —
+    done from iteration 0 — record nothing), so counters and decoded ring
+    stay bit-identical to a per-cluster ``run_until_membership`` drive
+    (pinned with the state parity in tests/test_telemetry_plane.py and
+    tests/test_trace_ring.py). No reduction ever touches the lanes here —
+    the digest is the only cross-shard telemetry reduction, and it runs at
+    fetch boundaries, never inside this loop.
 
-    def one(state, faults, kn, tgt, mc):
+    Returns ``(state, *observers, steps[t], cuts[t], resolved[t],
+    sizes[t, max_cuts])``.
+    """
+    *observers, faults, knobs, target, max_steps, max_cuts, min_cuts = rest
+
+    def one(state, *rest):
+        *observers, faults, kn, tgt, mc = rest
         tcfg = _tenant_cfg(cfg, kn)
 
         def body(_i, carry):
-            state, steps, cuts, sizes, done = carry
+            state, *observers, steps, cuts, sizes, done = carry
             active = ~done & (steps < max_steps)
-            round_state, decided, winner, _ = _compute_round(tcfg, state, faults)
+            round_state, decided, winner, _, *round_observers = _compute_round(
+                tcfg, state, faults, None, *observers
+            )
             committed = apply_view_change_impl(tcfg, round_state, winner)
             commit = active & decided
             picked = jax.tree_util.tree_map(
@@ -321,6 +319,10 @@ def fleet_wave_impl(
                 ),
                 state, round_state, committed,
             )
+            observers = jax.tree_util.tree_map(
+                lambda old, new: jnp.where(active, new, old),
+                observers, round_observers,
+            )
             steps = jnp.where(active, steps + 1, steps)
             sizes = jnp.where(
                 commit, sizes.at[cuts].set(committed.n_members), sizes
@@ -328,10 +330,11 @@ def fleet_wave_impl(
             cuts = cuts + commit.astype(jnp.int32)
             resolved = (picked.n_members == tgt) & (cuts >= mc)
             done = done | (commit & resolved) | (cuts >= max_cuts)
-            return (picked, steps, cuts, sizes, done)
+            return (picked, *observers, steps, cuts, sizes, done)
 
         init = (
             state,
+            *observers,
             jnp.int32(0),
             jnp.int32(0),
             jnp.full((max_cuts,), -1, dtype=jnp.int32),
@@ -340,22 +343,20 @@ def fleet_wave_impl(
             # when no cuts are demanded.
             (state.n_members == tgt) & (mc <= jnp.int32(0)),
         )
-        state, steps, cuts, sizes, _ = jax.lax.fori_loop(
+        state, *observers, steps, cuts, sizes, _ = jax.lax.fori_loop(
             0, max_steps, body, init
         )
         resolved = (state.n_members == tgt) & (cuts >= mc)
-        return (state, steps, cuts, resolved, sizes)
+        return (state, *observers, steps, cuts, resolved, sizes)
 
-    return jax.vmap(one)(state, faults, knobs, target, min_cuts)
+    return jax.vmap(one)(state, *observers, faults, knobs, target, min_cuts)
 
 
 # ---------------------------------------------------------------------------
-# Device telemetry plane, fleet grain: the SAME TelemetryLanes pytree with a
-# leading [t] axis, threaded through vmapped twins of the convergence and
-# wave entrypoints above (the gated step takes the lanes as an optional
-# pytree instead). These are separate entrypoints (never default arguments
-# on the existing ones) so a telemetry=0 fleet keeps compiling byte-identical
-# programs — the hlo.lock.json gate holds the existing fleet3d entries frozen.
+# The observers at fleet grain: the SAME TelemetryLanes / TraceRing pytrees
+# with a leading [t] axis, riding through the programs above as optional
+# pytrees (an observers-off fleet traces none of their code: the invariant
+# said on ``_compute_round``).
 # ---------------------------------------------------------------------------
 
 
@@ -368,101 +369,6 @@ def initial_fleet_telemetry(cfg: EngineConfig, tenants: int) -> TelemetryLanes:
     )
 
 
-def fleet_run_to_decision_telem_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    max_steps,
-):
-    """:func:`fleet_run_to_decision_impl` with telemetry: the batched while
-    carries the lanes per tenant (single-device driver entrypoint, same as
-    its untelemetered twin)."""
-
-    def one(state, telem, faults, kn):
-        return run_to_decision_telem_impl(
-            _tenant_cfg(cfg, kn), state, telem, faults, max_steps
-        )
-
-    return jax.vmap(one)(state, telem, faults, knobs)
-
-
-def fleet_wave_telem_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    target,
-    max_steps,
-    max_cuts: int,
-    min_cuts,
-):
-    """The lockstep fleet wave with telemetry lanes in the carry. The lanes
-    are select-gated by the SAME ``active`` mask that freezes a finished
-    tenant's state: a tenant that coasts after resolving accumulates no
-    phantom rounds, so its counters stay bit-identical to a per-cluster
-    ``run_until_membership_telem`` drive (pinned with the state parity in
-    tests/test_telemetry_plane.py). No reduction ever touches the lanes
-    here — the digest is the only cross-shard telemetry reduction, and it
-    runs at fetch boundaries, never inside this loop."""
-
-    def one(state, telem, faults, kn, tgt, mc):
-        tcfg = _tenant_cfg(cfg, kn)
-
-        def body(_i, carry):
-            state, telem, steps, cuts, sizes, done = carry
-            active = ~done & (steps < max_steps)
-            round_state, decided, winner, _, round_telem = _compute_round(
-                tcfg, state, faults, None, telem
-            )
-            committed = apply_view_change_impl(tcfg, round_state, winner)
-            commit = active & decided
-            picked = jax.tree_util.tree_map(
-                lambda old, rnd, com: jnp.where(
-                    active, jnp.where(commit, com, rnd), old
-                ),
-                state, round_state, committed,
-            )
-            telem = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(active, new, old),
-                telem, round_telem,
-            )
-            steps = jnp.where(active, steps + 1, steps)
-            sizes = jnp.where(
-                commit, sizes.at[cuts].set(committed.n_members), sizes
-            )
-            cuts = cuts + commit.astype(jnp.int32)
-            resolved = (picked.n_members == tgt) & (cuts >= mc)
-            done = done | (commit & resolved) | (cuts >= max_cuts)
-            return (picked, telem, steps, cuts, sizes, done)
-
-        init = (
-            state,
-            telem,
-            jnp.int32(0),
-            jnp.int32(0),
-            jnp.full((max_cuts,), -1, dtype=jnp.int32),
-            (state.n_members == tgt) & (mc <= jnp.int32(0)),
-        )
-        state, telem, steps, cuts, sizes, _ = jax.lax.fori_loop(
-            0, max_steps, body, init
-        )
-        resolved = (state.n_members == tgt) & (cuts >= mc)
-        return (state, telem, steps, cuts, resolved, sizes)
-
-    return jax.vmap(one)(state, telem, faults, knobs, target, min_cuts)
-
-
-# ---------------------------------------------------------------------------
-# Round-trace ring, fleet grain: the SAME TraceRing pytree with a leading
-# [t] axis, threaded through vmapped twins of the telemetry entrypoints.
-# Separate entrypoints again (never default arguments) so trace=0 fleets —
-# telemetry-on or off — keep compiling byte-identical programs.
-# ---------------------------------------------------------------------------
-
-
 def initial_fleet_trace(cfg: EngineConfig, tenants: int) -> TraceRing:
     """All-zero trace rings for ``tenants`` clusters: the single-cluster
     ring with a leading tenant axis, matching the stacked lane layout."""
@@ -470,97 +376,6 @@ def initial_fleet_trace(cfg: EngineConfig, tenants: int) -> TraceRing:
         lambda x: jnp.zeros((tenants,) + x.shape, x.dtype),
         initial_trace(cfg),
     )
-
-
-def fleet_run_to_decision_trace_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    trace: TraceRing,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    max_steps,
-):
-    """:func:`fleet_run_to_decision_telem_impl` with the ring in the batched
-    while carry (single-device driver entrypoint, same as its twins)."""
-
-    def one(state, telem, trace, faults, kn):
-        return run_to_decision_trace_impl(
-            _tenant_cfg(cfg, kn), state, telem, trace, faults, max_steps
-        )
-
-    return jax.vmap(one)(state, telem, trace, faults, knobs)
-
-
-def fleet_wave_trace_impl(
-    cfg: EngineConfig,
-    state: EngineState,
-    telem: TelemetryLanes,
-    trace: TraceRing,
-    faults: FaultInputs,
-    knobs: TenantKnobs,
-    target,
-    max_steps,
-    max_cuts: int,
-    min_cuts,
-):
-    """The lockstep fleet wave with trace rings in the carry. The ring is
-    select-gated by the SAME ``active`` mask that freezes a finished
-    tenant's state and telemetry: a coasting tenant's cursor holds still
-    and its slots are never overwritten, so the decoded ring stays
-    bit-identical to a per-cluster ``run_until_membership_trace`` drive
-    (quarantined tenants — done from iteration 0 — record nothing)."""
-
-    def one(state, telem, trace, faults, kn, tgt, mc):
-        tcfg = _tenant_cfg(cfg, kn)
-
-        def body(_i, carry):
-            state, telem, trace, steps, cuts, sizes, done = carry
-            active = ~done & (steps < max_steps)
-            round_state, decided, winner, _, round_telem, round_trace = (
-                _compute_round(tcfg, state, faults, None, telem, trace)
-            )
-            committed = apply_view_change_impl(tcfg, round_state, winner)
-            commit = active & decided
-            picked = jax.tree_util.tree_map(
-                lambda old, rnd, com: jnp.where(
-                    active, jnp.where(commit, com, rnd), old
-                ),
-                state, round_state, committed,
-            )
-            telem = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(active, new, old),
-                telem, round_telem,
-            )
-            trace = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(active, new, old),
-                trace, round_trace,
-            )
-            steps = jnp.where(active, steps + 1, steps)
-            sizes = jnp.where(
-                commit, sizes.at[cuts].set(committed.n_members), sizes
-            )
-            cuts = cuts + commit.astype(jnp.int32)
-            resolved = (picked.n_members == tgt) & (cuts >= mc)
-            done = done | (commit & resolved) | (cuts >= max_cuts)
-            return (picked, telem, trace, steps, cuts, sizes, done)
-
-        init = (
-            state,
-            telem,
-            trace,
-            jnp.int32(0),
-            jnp.int32(0),
-            jnp.full((max_cuts,), -1, dtype=jnp.int32),
-            (state.n_members == tgt) & (mc <= jnp.int32(0)),
-        )
-        state, telem, trace, steps, cuts, sizes, _ = jax.lax.fori_loop(
-            0, max_steps, body, init
-        )
-        resolved = (state.n_members == tgt) & (cuts >= mc)
-        return (state, telem, trace, steps, cuts, resolved, sizes)
-
-    return jax.vmap(one)(state, telem, trace, faults, knobs, target, min_cuts)
 
 
 def tenant_health_impl(cfg: EngineConfig, state: EngineState) -> jnp.ndarray:
@@ -604,44 +419,20 @@ def tenant_health_impl(cfg: EngineConfig, state: EngineState) -> jnp.ndarray:
 
 tenant_health = jax.jit(tenant_health_impl, static_argnums=(0,))  # donate-ok: read-only health reduction — the state must survive the scan
 
-# The three spellings of the gated step (state, the commit-round counter and
-# the carried masks always donated; the observers' lanes where they ride).
-fleet_step = jax.jit(
-    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5)
-)
 #: The build program: dispatched by the driver only when the masks it
 #: carries are not those of the inputs it is about to pass.
 fleet_edge_masks = jax.jit(fleet_edge_masks_impl, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
-fleet_run_to_decision = jax.jit(
-    fleet_run_to_decision_impl, static_argnums=(0,), donate_argnums=(1,)
-)
-fleet_wave = jax.jit(
-    fleet_wave_impl, static_argnums=(0, 6), donate_argnums=(1,)
-)
-
-fleet_step_telem = jax.jit(
-    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5, 6)
-)
-fleet_run_to_decision_telem = jax.jit(
-    fleet_run_to_decision_telem_impl, static_argnums=(0,), donate_argnums=(1, 2)
-)
-fleet_wave_telem = jax.jit(
-    fleet_wave_telem_impl, static_argnums=(0, 7), donate_argnums=(1, 2)
-)
+#: A fleet verb's programs by observer count, like the cluster's
+#: ``_ROUND_PROGRAMS`` (the knobs ride after the faults). The step's
+#: commit-round counter and carried masks (its last two arguments) are donated
+#: too; the wave's static ``max_cuts`` sits after faults, knobs and two controls.
+_FLEET_PROGRAMS = {
+    "step": jit_per_observer_count(fleet_step_gated_impl, donated=(4, 5)),
+    "decision": jit_per_observer_count(fleet_run_to_decision_impl),
+    "wave": jit_per_observer_count(fleet_wave_impl, static=(6,)),
+}
 # donate-ok: read-only boundary fetch — the per-tenant lanes stay live.
 fleet_telemetry_digest = jax.jit(jax.vmap(telemetry_digest_impl))
-
-fleet_step_trace = jax.jit(
-    fleet_step_gated_impl, static_argnums=(0,), donate_argnums=(1, 4, 5, 6, 7)
-)
-fleet_run_to_decision_trace = jax.jit(
-    fleet_run_to_decision_trace_impl,
-    static_argnums=(0,),
-    donate_argnums=(1, 2, 3),
-)
-fleet_wave_trace = jax.jit(
-    fleet_wave_trace_impl, static_argnums=(0, 8), donate_argnums=(1, 2, 3)
-)
 # donate-ok: read-only boundary fetch — the per-tenant rings stay live.
 fleet_trace_digest = jax.jit(jax.vmap(trace_digest_impl))
 
@@ -864,6 +655,28 @@ class TenantFleet(DispatchSeam):
 
     # -- execution ------------------------------------------------------
 
+    def _advance(self, verb: str, *controls, max_cuts: Optional[int] = None):
+        """Dispatch ``verb``'s fleet program ("step", "decision", "wave") on
+        the pytrees this driver carries and keep what comes back; returns
+        the program's observations. The short twin of
+        ``VirtualCluster._advance``: a fleet takes no mesh, and its knobs
+        ride after the faults."""
+        carried = tuple(
+            tree for tree in (self.state, self.telem, self.trace_ring)
+            if tree is not None
+        )
+        if max_cuts is not None:  # the wave's static argument, by position
+            controls = (*controls[:2], max_cuts, *controls[2:])
+        out = _FLEET_PROGRAMS[verb][len(carried) - 1](
+            self.cfg, *carried, self.faults, self.knobs, *controls
+        )
+        self.state = out[0]
+        if self.telem is not None:
+            self.telem = out[1]
+        if self.trace_ring is not None:
+            self.trace_ring = out[2]
+        return out[len(carried):]
+
     def step(self) -> StepEvents:
         """One protocol round for every tenant — one dispatch, B clusters
         (``engine_dispatch_ms{phase="fleet_step"}``).
@@ -892,19 +705,9 @@ class TenantFleet(DispatchSeam):
         streamed path from the batch path the bit-identity tests pin."""
         self.metrics.inc("engine_tenant_rounds", self.b)
         self._commit_rounds_stale = True
-        step = (
-            fleet_step_trace if self.trace_ring is not None
-            else fleet_step_telem if self.telem is not None
-            else fleet_step
-        )
         with self._dispatch(phase, **tags):
-            (
-                self.state, self._commit_rounds, masks, events,
-                self.telem, self.trace_ring,
-            ) = step(
-                self.cfg, self.state, self.faults, self.knobs,
-                self._commit_rounds, self._carried.for_step(self), self.telem,
-                self.trace_ring,
+            self._commit_rounds, masks, events = self._advance(
+                "step", self._commit_rounds, self._carried.for_step(self)
             )
             self._carried.keep(self, masks)
         return events
@@ -937,25 +740,9 @@ class TenantFleet(DispatchSeam):
         returns ``(rounds[t], decided[t], winner[t, n] on device,
         members[t])`` with one packed observation fetch."""
         with self._dispatch("fleet_decision"):
-            if self.trace_ring is not None:
-                self.state, self.telem, self.trace_ring, steps, decided, winner = (
-                    fleet_run_to_decision_trace(
-                        self.cfg, self.state, self.telem, self.trace_ring,
-                        self.faults, self.knobs, jnp.int32(max_steps),
-                    )
-                )
-            elif self.telem is not None:
-                self.state, self.telem, steps, decided, winner = (
-                    fleet_run_to_decision_telem(
-                        self.cfg, self.state, self.telem, self.faults,
-                        self.knobs, jnp.int32(max_steps),
-                    )
-                )
-            else:
-                self.state, steps, decided, winner = fleet_run_to_decision(
-                    self.cfg, self.state, self.faults, self.knobs,
-                    jnp.int32(max_steps),
-                )
+            steps, decided, winner = self._advance(
+                "decision", jnp.int32(max_steps)
+            )
             obs = np.asarray(
                 jnp.stack(
                     [steps, decided.astype(jnp.int32), self.state.n_members]
@@ -1006,31 +793,10 @@ class TenantFleet(DispatchSeam):
             )
         self._account_h2d(targets, min_cuts)
         with self._dispatch("fleet_wave"):
-            if self.trace_ring is not None:
-                (
-                    self.state, self.telem, self.trace_ring,
-                    steps, cuts, resolved, sizes,
-                ) = fleet_wave_trace(
-                    self.cfg, self.state, self.telem, self.trace_ring,
-                    self.faults, self.knobs, jnp.asarray(targets),
-                    jnp.int32(max_steps), int(max_cuts),
-                    jnp.asarray(min_cuts),
-                )
-            elif self.telem is not None:
-                self.state, self.telem, steps, cuts, resolved, sizes = (
-                    fleet_wave_telem(
-                        self.cfg, self.state, self.telem, self.faults,
-                        self.knobs, jnp.asarray(targets),
-                        jnp.int32(max_steps), int(max_cuts),
-                        jnp.asarray(min_cuts),
-                    )
-                )
-            else:
-                self.state, steps, cuts, resolved, sizes = fleet_wave(
-                    self.cfg, self.state, self.faults, self.knobs,
-                    jnp.asarray(targets), jnp.int32(max_steps), int(max_cuts),
-                    jnp.asarray(min_cuts),
-                )
+            steps, cuts, resolved, sizes = self._advance(
+                "wave", jnp.asarray(targets), jnp.int32(max_steps),
+                jnp.asarray(min_cuts), max_cuts=int(max_cuts),
+            )
             obs = np.asarray(
                 jnp.concatenate(
                     [steps, cuts, resolved.astype(jnp.int32), sizes.reshape(-1)]

@@ -15,15 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from rapid_tpu.models.virtual_cluster import (
-    VirtualCluster,
-    engine_step,
-    engine_step_impl,
-    engine_step_telem,
-    engine_step_telem_impl,
-    engine_step_trace,
-    engine_step_trace_impl,
-)
+from rapid_tpu.models.virtual_cluster import VirtualCluster, engine_step_impl
 from rapid_tpu.serving.stream import FleetWave, StreamDriver, StreamWave
 from rapid_tpu.tenancy.fleet import TenantFleet, _tenant_cfg, fleet_step_impl
 
@@ -66,7 +58,12 @@ def _clone(tree):
 
 # -- the parent's step, driven through the same driver object -----------------
 
-_PARENT_ROUND = (engine_step_impl, engine_step_telem_impl, engine_step_trace_impl)
+# ``engine_step_impl`` (the mesh's step, the masks built inside) jitted once
+# per observer count, the state and the observers donated.
+_PARENT_STEP = tuple(
+    jax.jit(engine_step_impl, static_argnums=(0,), donate_argnums=tuple(range(1, 2 + k)))
+    for k in range(3)
+)
 
 
 def _parent_fleet_step_impl(cfg, knobs, faults, state, *observers):
@@ -74,7 +71,7 @@ def _parent_fleet_step_impl(cfg, knobs, faults, state, *observers):
         return fleet_step_impl(cfg, state, faults, knobs)
 
     def one(kn, f, s, *obs):
-        return _PARENT_ROUND[len(obs)](_tenant_cfg(cfg, kn), s, *obs, f)
+        return engine_step_impl(_tenant_cfg(cfg, kn), s, *obs, f)
 
     return jax.vmap(one)(knobs, faults, state, *observers)
 
@@ -92,8 +89,7 @@ def _parent_step(driver):
     if isinstance(driver, TenantFleet):
         out = _parent_fleet_step(driver.cfg, driver.knobs, driver.faults, *carried)
     else:
-        program = (engine_step, engine_step_telem, engine_step_trace)[len(carried) - 1]
-        out = program(driver.cfg, *carried, driver.faults)
+        out = _PARENT_STEP[len(carried) - 1](driver.cfg, *carried, driver.faults)
     driver.state = out[0]
     if driver.telem is not None:
         driver.telem = out[1]

@@ -34,19 +34,30 @@ ROUND = {"edge_masks", "fd_tick", "deliver", "deliver_skip", "cut_detection",
 #: carry them: the names are in the code (and in the vocabulary) for the day
 #: the compiler or a later change gives those arms work of their own.
 IDENTITY_ARMS = {"invalidation_skip", "classic_skip", "view_keep"}
+#: The drivers' round programs by the names their level-0 jits carry; a
+#: level adds its suffix. The cases below are generated from the drivers' own
+#: tables (``_ROUND_PROGRAMS``, ``_FLEET_PROGRAMS``): one for each verb and
+#: observer count of ``VirtualCluster`` and ``TenantFleet``.
+LEVELS = ("", "_telem", "_trace")
+CLUSTER_VERBS = {"step": "engine_step_carried", "decision": "run_to_decision",
+                 "wave": "run_until_membership"}
+FLEET_VERBS = {"step": "fleet_step", "decision": "fleet_run_to_decision", "wave": "fleet_wave"}
 EXPECTED = {
-    "run_until_membership": ROUND | {"loop_result"},
+    name + suffix: ROUND | ({"observers"} if level else set())
+    | ({"loop_result"} if name == "run_until_membership" else set())
+    for name in (*CLUSTER_VERBS.values(), *FLEET_VERBS.values())
+    for level, suffix in enumerate(LEVELS)
+}
+EXPECTED.update({
     "engine_step": ROUND,
-    "engine_step_carried": ROUND,
     "edge_masks_build": {"edge_masks"},
-    "fleet_step": ROUND,
     "fleet_edge_masks": {"edge_masks"},
     "mesh_fleet_step": ROUND,
     "mesh_step": ROUND,
     "engine_step_trace": ROUND | {"observers"},
     "sync_checksum": {"sync_checksum"},
     "predecessor_of_keys": {"join_predecessors"},
-}
+})
 _WRAPPED = re.compile(r"^\w+\((.*)\)$")
 
 
@@ -75,10 +86,14 @@ def lowered():
 
     kw = dict(n_slots=32, k=3, h=3, l=1, cohorts=2, fd_threshold=2,
               delivery_spread=1, concurrent_coordinators=2)
-    vc = vcm.VirtualCluster.create(28, **kw)
-    traced = vcm.VirtualCluster.create(28, telemetry=True, trace=4, **kw)
-    fleet = fleetm.TenantFleet.create(
-        2, 28, n_slots=32, k=3, cohorts=2, knobs=[(3, 1, 2)] * 2, delivery_spread=1)
+    observers = ({}, {"telemetry": True}, {"telemetry": True, "trace": 4})
+    clusters = [vcm.VirtualCluster.create(28, **kw, **obs) for obs in observers]
+    fleets = [
+        fleetm.TenantFleet.create(
+            2, 28, n_slots=32, k=3, cohorts=2, knobs=[(3, 1, 2)] * 2, delivery_spread=1, **obs)
+        for obs in observers
+    ]
+    vc, traced, fleet = clusters[0], clusters[2], fleets[0]
     i32, s = jnp.int32, vc.state
     idx = jnp.arange(28, 30)
     # the carried masks as shapes: nothing is compiled or run here
@@ -86,30 +101,40 @@ def lowered():
     fleet_masks = jax.eval_shape(
         fleetm.fleet_edge_masks, fleet.cfg, fleet.state, fleet.faults)
     mesh = make_mesh(jax.devices()[:4], shape=(1, 4))  # cluster-10m's layout
-    return {
-        "run_until_membership": vcm.run_until_membership.lower(
-            vc.cfg, s, vc.faults, i32(28), i32(16), 4, i32(1)),
+    out = {}
+    # every (verb, observer count) of both drivers, as the driver's _advance
+    # hands the arguments over: carried pytrees, faults (and knobs), controls
+    per_tenant = jnp.full((2,), 28, i32), jnp.ones((2,), i32)
+    for level, (one, many) in enumerate(zip(clusters, fleets)):
+        carried = [t for t in (one.state, one.telem, one.trace_ring) if t is not None]
+        for verb, controls in (("step", (masks,)), ("decision", (i32(16),)),
+                               ("wave", (i32(28), i32(16), 4, i32(1)))):
+            out[CLUSTER_VERBS[verb] + LEVELS[level]] = vcm._ROUND_PROGRAMS[verb][level].lower(
+                one.cfg, *carried, one.faults, *controls)
+        carried = [t for t in (many.state, many.telem, many.trace_ring) if t is not None]
+        for verb, controls in (("step", (i32(0), fleet_masks)), ("decision", (i32(16),)),
+                               ("wave", (per_tenant[0], i32(16), 4, per_tenant[1]))):
+            out[FLEET_VERBS[verb] + LEVELS[level]] = fleetm._FLEET_PROGRAMS[verb][level].lower(
+                many.cfg, *carried, many.faults, many.knobs, *controls)
+    out.update({
         "engine_step": vcm.engine_step.lower(vc.cfg, s, vc.faults),
-        "engine_step_carried": vcm.engine_step_carried.lower(vc.cfg, s, vc.faults, masks),
         "edge_masks_build": vcm.edge_masks_build.lower(vc.cfg, s, vc.faults),
-        "fleet_step": fleetm.fleet_step.lower(
-            fleet.cfg, fleet.state, fleet.faults, fleet.knobs, i32(0), fleet_masks),
         "fleet_edge_masks": fleetm.fleet_edge_masks.lower(
             fleet.cfg, fleet.state, fleet.faults),
-        "fleet_run_to_decision": fleetm.fleet_run_to_decision.lower(
-            fleet.cfg, fleet.state, fleet.faults, fleet.knobs, i32(16)),
         "mesh_fleet_step": fleetm.make_fleet_step(
             fleet.cfg, make_mesh(jax.devices()[:8], shape=(2, 2, 2)),
         ).lower(fleet.state, fleet.faults, fleet.knobs),
         "mesh_step": make_sharded_step(vc.cfg, mesh).lower(s, vc.faults),
         "mesh_run_to_decision": sharded_program("decision", vc.cfg, mesh).lower(
             s, vc.faults, i32(16)),
-        "engine_step_trace": vcm.engine_step_trace.lower(
+        # the mesh's step body with both observers riding
+        "engine_step_trace": jax.jit(vcm.engine_step_impl, static_argnums=(0,)).lower(
             traced.cfg, traced.state, traced.telem, traced.trace_ring, traced.faults),
         "sync_checksum": vcm.sync_checksum.lower(s, vc.faults),
         "predecessor_of_keys": predecessor_of_keys.lower(
             s.key_hi, s.key_lo, s.alive, s.key_hi[:, idx], s.key_lo[:, idx], perm=s.ring_perm),
-    }
+    })
+    return out
 
 
 @pytest.mark.parametrize("program", sorted(EXPECTED))

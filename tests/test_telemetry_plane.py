@@ -5,10 +5,10 @@ engine produces bit-identical results — full state/fault pytrees, cut
 sequences, configuration-id chains, decision rounds — to the telemetry=0
 engine on every driver spelling (per-step, fused convergence, multi-cut
 wave, fleet lockstep, streaming pipeline). The lanes themselves must be
-path-independent: the fused ``run_to_decision_telem`` while-loop and a
+path-independent: the fused ``run_to_decision`` while-loop (lanes riding) and a
 per-step drive accumulate the same counters, and a fleet tenant's lanes
 match a per-cluster drive exactly (the wave's coast-gating pin promised in
-``fleet_wave_telem_impl``'s docstring).
+``fleet_wave_impl``'s docstring).
 
 Budget (the PR-10 convention): the small-grid cluster+fleet+stream
 differentials are the compile-bearing tier-1 representatives; the larger
@@ -144,7 +144,7 @@ def _fleet(telemetry, b=3, n=16, seed0=10):
 
 
 def test_fleet_wave_lanes_bit_identical_to_per_cluster_drives():
-    """The fleet_wave_telem coast-gating pin: tenants resolving at different
+    """The fleet wave's coast-gating pin: tenants resolving at different
     rounds coast frozen — no phantom lane accumulation — so each tenant's
     lanes equal its own per-cluster ``run_until_membership`` drive, raw
     int32 for raw int32; and the wave itself matches the telemetry=0 wave."""

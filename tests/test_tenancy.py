@@ -207,10 +207,7 @@ def _lockstep_step(spelling):
     from rapid_tpu.models import virtual_cluster as vcm
     from rapid_tpu.tenancy import fleet as fleetm
 
-    per_cluster = {
-        "telem": vcm.engine_step_telem_impl,
-        "trace": vcm.engine_step_trace_impl,
-    }.get(spelling)
+    per_cluster = None if spelling == "plain" else vcm.engine_step_impl
 
     def step(cfg, state, faults, knobs, *observers):
         if per_cluster is None:

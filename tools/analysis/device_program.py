@@ -115,8 +115,6 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     from rapid_tpu.models.virtual_cluster import (
         VirtualCluster,
         engine_step_impl,
-        engine_step_telem_impl,
-        engine_step_trace_impl,
         run_to_decision_impl,
         run_until_membership_impl,
         sync_checksum_impl,
@@ -219,7 +217,7 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     telem_leaves = len(jax.tree_util.tree_leaves(telem))
     registry["step_telem"] = {
         "jit": jax.jit(
-            lambda s, t, f: engine_step_telem_impl(cfg_t, s, t, f),
+            lambda s, t, f: engine_step_impl(cfg_t, s, t, f),
             donate_argnums=(0, 1),
         ),
         "args": (state, telem, faults),
@@ -240,7 +238,7 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     trace_leaves = len(jax.tree_util.tree_leaves(trace_ring))
     registry["step_trace"] = {
         "jit": jax.jit(
-            lambda s, t, r, f: engine_step_trace_impl(cfg_tr, s, t, r, f),
+            lambda s, t, r, f: engine_step_impl(cfg_tr, s, t, r, f),
             donate_argnums=(0, 1, 2),
         ),
         "args": (state, telem, trace_ring, faults),
@@ -406,8 +404,6 @@ def build_ladder_spec(
     from rapid_tpu.models.virtual_cluster import (
         VirtualCluster,
         engine_step_impl,
-        engine_step_telem_impl,
-        engine_step_trace_impl,
         run_to_decision_impl,
         run_until_membership_impl,
         sync_checksum_impl,
@@ -483,7 +479,7 @@ def build_ladder_spec(
         telem = initial_telemetry(cfg_t)
         return {
             "jit": jax.jit(
-                lambda s, t, f: engine_step_telem_impl(cfg_t, s, t, f),
+                lambda s, t, f: engine_step_impl(cfg_t, s, t, f),
                 donate_argnums=(0, 1),
             ),
             "args": (state, telem, faults),
@@ -497,7 +493,7 @@ def build_ladder_spec(
         ring = initial_trace(cfg_tr)
         return {
             "jit": jax.jit(
-                lambda s, t, r, f: engine_step_trace_impl(cfg_tr, s, t, r, f),
+                lambda s, t, r, f: engine_step_impl(cfg_tr, s, t, r, f),
                 donate_argnums=(0, 1, 2),
             ),
             "args": (state, telem, ring, faults),
