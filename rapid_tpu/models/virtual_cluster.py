@@ -50,7 +50,7 @@ from rapid_tpu.ops.rings import (
     ring_topology_from_perm,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
-from rapid_tpu.utils.dispatch import DispatchSeam, scope
+from rapid_tpu.utils.dispatch import DispatchSeam, cond_across, scope
 from rapid_tpu.utils.health import NodeHealth
 from rapid_tpu.utils.metrics import Metrics
 
@@ -216,7 +216,9 @@ def _deliver_alerts(cfg: EngineConfig, state: EngineState, fire_round, blocked_r
 
 
 @scope("cut_detection")
-def _cohort_cut_detection(cfg: EngineConfig, state: EngineState, new_bits, heard_down):
+def _cohort_cut_detection(
+    cfg: EngineConfig, state: EngineState, new_bits, heard_down, batch_axis=None
+):
     """The engine's cut-detection seam: C independent watermark detectors
     batched over the (mesh-sharded) cohort axis. The pass itself lives in
     ``rapid_tpu.ops.cut_detection.cohort_watermark_pass`` (the cohort-grain
@@ -234,6 +236,7 @@ def _cohort_cut_detection(cfg: EngineConfig, state: EngineState, new_bits, heard
         cfg.h,
         cfg.l,
         cfg.k,
+        batch_axis,
     )
 
 
@@ -241,6 +244,8 @@ def _compute_round(
     cfg: EngineConfig, state: EngineState, faults: FaultInputs, edge_masks=None,
     telem: Optional[TelemetryLanes] = None,
     trace: Optional[TraceRing] = None,
+    *,
+    batch_axis=None,
 ):
     """One protocol round WITHOUT view-change application: returns the
     round-advanced state plus (decided, winner_mask, events). Keeping the
@@ -271,7 +276,16 @@ def _compute_round(
     scalar the round already computed), and the ring's active-subject count
     reuses the telemetry block's cut-mask reduction — which is why
     ``trace`` requires ``telem`` (trace is a refinement of the telemetry
-    plane, enforced at driver construction)."""
+    plane, enforced at driver construction).
+
+    ``batch_axis`` (the name an enclosing ``vmap`` gave its batch axis; the
+    two meshless fleet programs of ``tenancy/fleet.py`` hand one): a third
+    Python-level branch. With ``None`` not one traced operation changes. With
+    a name the round's three conditionals (``deliver``, ``invalidation``,
+    ``classic``) stay conditionals under the ``vmap``, each taken when SOME
+    member of the batch needs its arm (``utils/dispatch.cond_across``), and
+    the return ends with one more element: ``int32[2]``, whether
+    ``invalidation`` and ``classic`` ran in this round, not batched."""
     n, k, c = cfg.n, cfg.k, cfg.c
 
     # 1. Failure-detector tick -> fresh DOWN alerts per (subject, ring) edge.
@@ -305,7 +319,8 @@ def _compute_round(
     #    delays and rx-blocks are fixed between view changes, so past
     #    max(fire_round) + spread the delivered mask is static and already
     #    OR-merged into report_bits — recomputing it adds nothing.
-    new_bits = jax.lax.cond(
+    new_bits, _ = cond_across(
+        batch_axis,
         need_delivery,
         lambda: _deliver_alerts(cfg, state, fire_round, blocked_rows),
         scope("deliver_skip")(
@@ -318,9 +333,10 @@ def _compute_round(
         heard_down = jnp.any((new_bits != 0) & state.alive[None, :], axis=1)  # [c]
 
     # 3. Cut detection per cohort.
-    report_bits, released, announced, seen_down, proposed_now, prop_masks = _cohort_cut_detection(
-        cfg, state, new_bits, heard_down
-    )
+    (
+        report_bits, released, announced, seen_down, proposed_now, prop_masks,
+        invalidation_ran,
+    ) = _cohort_cut_detection(cfg, state, new_bits, heard_down, batch_axis)
     # Proposal identity = commutative set-hash of the cut's member identities
     # (the canonical-sort-free equivalent of the ring-0-sorted endpoint list,
     # MembershipService.java:346-348). Per-cohort hash reductions over N —
@@ -521,7 +537,10 @@ def _compute_round(
             jnp.bool_(False), jnp.int32(-1),
         )
 
-    cp_rnd_r, cp_rnd_i, cp_vrnd_r, cp_vrnd_i, cp_vval_src, fb_decided, chosen = jax.lax.cond(
+    (
+        cp_rnd_r, cp_rnd_i, cp_vrnd_r, cp_vrnd_i, cp_vval_src, fb_decided, chosen,
+    ), classic_ran = cond_across(
+        batch_axis,
         fallback_due,
         classic_attempt,
         no_attempt,
@@ -581,8 +600,15 @@ def _compute_round(
         prop_hi=prop_hi,
         prop_lo=prop_lo,
     )
+    # What a named batch axis adds to the end of the return.
+    arms_ran = ()
+    if batch_axis is not None:
+        with scope("tally"):
+            arms_ran = (
+                jnp.stack([invalidation_ran, classic_ran]).astype(jnp.int32),
+            )
     if telem is None:
-        return round_state, decided, winner_mask, events
+        return (round_state, decided, winner_mask, events, *arms_ran)
 
     # Device telemetry plane (write-only; see the docstring contract).
     # Scalars reuse reductions computed above; [c, n]/[c] lanes accumulate
@@ -610,7 +636,7 @@ def _compute_round(
             tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
         )
     if trace is None:
-        return round_state, decided, winner_mask, events, telem
+        return (round_state, decided, winner_mask, events, telem, *arms_ran)
 
     # Device round-trace ring (write-only; one record per round into slot
     # cursor % R). Every field is a scalar computed above — the ring adds
@@ -644,7 +670,7 @@ def _compute_round(
             tr_cursor=trace.tr_cursor + 1,
             tr_wraps=trace.tr_wraps + (slot == cfg.trace - 1).astype(jnp.int32),
         )
-    return round_state, decided, winner_mask, events, telem, trace
+    return (round_state, decided, winner_mask, events, telem, trace, *arms_ran)
 
 
 def _rotation_seed(epoch_u32, j: int):
@@ -909,7 +935,7 @@ sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the
 
 def _converge(
     cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
-    masks, steps, max_steps,
+    masks, steps, max_steps, batch_axis=None,
 ):
     """THE inner convergence loop: rounds over fixed per-edge ``masks``
     (topology and faults are fixed until a cut commits, so the per-edge
@@ -917,28 +943,39 @@ def _converge(
     decides or ``steps`` reaches ``max_steps``. The round body stays
     sort-free: the caller applies the (at most one) view change after the
     loop, so the ring rebuild runs exactly once per convergence. Returns
-    ``(round_state, observers, steps, decided, winner_mask)``."""
+    ``(round_state, observers, steps, decided, winner_mask, arm_rounds)``.
+
+    ``batch_axis`` goes down to the round (:func:`_compute_round`). With a
+    name the loop also carries ``arm_rounds``, ``int32[2]``: the rounds in
+    which ``invalidation`` and ``classic`` ran. With ``None`` it is ``None``,
+    a pytree of no leaves: the carry is the one it always was."""
 
     def cond(carry):
-        *_, steps, decided, _ = carry
+        *_, steps, decided, _, _ = carry
         return (~decided) & (steps < max_steps)
 
     def body(carry):
-        state, *observers, steps, _, _ = carry
+        state, *observers, steps, _, _, arm_rounds = carry
         round_state, decided, winner_mask, _, *observers = _compute_round(
-            cfg, state, faults, masks, *observers
+            cfg, state, faults, masks, *observers, batch_axis=batch_axis
         )
-        return (round_state, *observers, steps + 1, decided, winner_mask)
+        if batch_axis is not None:
+            *observers, arms_ran = observers
+            arm_rounds = arm_rounds + arms_ran
+        return (round_state, *observers, steps + 1, decided, winner_mask, arm_rounds)
 
     init = (
         state, *observers, steps, jnp.bool_(False),
         jnp.zeros((cfg.n,), dtype=bool),
+        None if batch_axis is None else jnp.zeros((2,), dtype=jnp.int32),
     )
-    state, *observers, steps, decided, winner = jax.lax.while_loop(cond, body, init)
-    return state, observers, steps, decided, winner
+    state, *observers, steps, decided, winner, arm_rounds = jax.lax.while_loop(
+        cond, body, init
+    )
+    return state, observers, steps, decided, winner, arm_rounds
 
 
-def run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
+def run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest, batch_axis=None):
     """Protocol rounds until a view change commits — entirely on device.
 
     A ``lax.while_loop`` around the round: the host dispatches ONE
@@ -947,15 +984,19 @@ def run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
     observers the fused convergence stops being a black box: every round of
     the loop accumulates into the lanes and leaves one record in the ring.
     ``rest`` is ``(*observers, faults, max_steps)``; returns
-    ``(state, *observers, steps_taken, decided, winner_mask)``.
+    ``(state, *observers, steps_taken, decided, winner_mask)``, and under a
+    ``vmap`` that hands the name of its ``batch_axis`` (:func:`_converge`)
+    one element more, the loop's ``arm_rounds``.
     """
     *observers, faults, max_steps = rest
     masks = _edge_masks(cfg, state, faults)
-    state, observers, steps, decided, winner = _converge(
-        cfg, state, observers, faults, masks, jnp.int32(0), max_steps
+    state, observers, steps, decided, winner, arm_rounds = _converge(
+        cfg, state, observers, faults, masks, jnp.int32(0), max_steps, batch_axis
     )
     state = _view_change_gate(cfg, state, decided, winner)
-    return (state, *observers, steps, decided, winner)
+    if batch_axis is None:
+        return (state, *observers, steps, decided, winner)
+    return (state, *observers, steps, decided, winner, arm_rounds)
 
 
 def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
@@ -995,7 +1036,7 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
 
     def outer_body(carry):
         state, *observers, steps, cuts, _, sizes, masks = carry
-        state, observers, steps, decided, winner = _converge(
+        state, observers, steps, decided, winner, _ = _converge(
             cfg, state, observers, faults, masks, steps, max_steps
         )
         state, masks = _view_change_gate_masks(

@@ -40,7 +40,7 @@ from rapid_tpu.ops.pallas_kernels import (
     _popcount32,
     watermark_merge_classify_impl,
 )
-from rapid_tpu.utils.dispatch import scope
+from rapid_tpu.utils.dispatch import cond_across, scope
 
 
 class CutState(NamedTuple):
@@ -142,6 +142,7 @@ def cohort_watermark_pass(
     h,  # Python int or traced int32 (per-tenant fleet watermarks)
     l,
     k: int,
+    batch_axis=None,
 ):
     """Batched per-cohort watermark pass over uint32 ring-report bitmasks
     (:func:`process_alert_batch` semantics over a leading cohort axis, gated
@@ -151,7 +152,7 @@ def cohort_watermark_pass(
     report_bits/released: ``[c, n]`` per-cohort detector state;
     seen_down/announced/heard_down: ``[c]`` cohort lanes; subject_mask:
     ``[n]``; inval_obs: ``[k, n]``. Returns ``(report_bits, released,
-    announced, seen_down, propose, proposal_mask)``.
+    announced, seen_down, propose, proposal_mask, invalidation_ran)``.
 
     Sharding discipline (the 2-D mesh contract): the merge + popcount + H/L
     classification is plain elementwise jnp on ``[c, n]`` — XLA's own
@@ -164,6 +165,16 @@ def cohort_watermark_pass(
     subjects in flux after a DOWN event (lax.cond): in pure crash/join
     rounds every subject jumps straight past H, so the expensive gather is
     skipped — and on the mesh the gathered traffic stays cond-gated.
+
+    ``batch_axis`` names the batch axis of an enclosing ``vmap`` (the two
+    meshless fleet programs of ``tenancy/fleet.py`` hand one). An unnamed
+    ``vmap`` makes the conditional a select: the K ``[c, n]`` gathers run
+    for every tenant in every round. Named, the conditional is taken on
+    "some tenant needs it" (:func:`cond_across`) and ``invalidation_ran``
+    is that scalar: a round in which no tenant has a subject in flux skips
+    the gathers for the whole fleet. The fleet's mesh programs name none,
+    because there that any() would be a collective across the ``'tenant'``
+    axis.
     """
     c, n = report_bits.shape
     # The impl, not the jitted wrapper: the tenant fleet vmaps this pass
@@ -206,8 +217,9 @@ def cohort_watermark_pass(
         return jnp.where(subject_mask[None, :], merged, 0)
 
     need_invalidation = jnp.any(flux & seen_down[:, None])
-    report_bits = jax.lax.cond(
-        need_invalidation, with_implicit, scope("invalidation_skip")(lambda r: r), report_bits
+    report_bits, invalidation_ran = cond_across(
+        batch_axis, need_invalidation, with_implicit,
+        scope("invalidation_skip")(lambda r: r), report_bits,
     )
 
     tally2 = _popcount32(report_bits)
@@ -223,6 +235,7 @@ def cohort_watermark_pass(
         seen_down,
         propose,
         proposal_mask,
+        invalidation_ran,
     )
 
 

@@ -49,6 +49,29 @@ Batched-control-flow tradeoffs, stated plainly:
   :func:`make_fleet_step` and the analyzers' ladder) keeps the lockstep
   select unchanged; a per-shard gate (``shard_map``, local any) belongs
   with the four-chip cell that can measure it (ROADMAP B7).
+- the round's OWN conditionals (``deliver``, ``invalidation``, ``classic``)
+  meet the same fate under an unnamed vmap: a batched predicate makes each a
+  select that runs both arms for every tenant in every round. The chip said
+  what that costs (ledger, PR 29, 256 tenants of 1,000): ``invalidation``
+  17.6 ms and ``classic`` 6.3 ms of a 59-65 ms round, where a single cluster
+  takes the first in a few rounds of a commit and the second in none. So the
+  two MESHLESS programs the drivers dispatch (:func:`fleet_step_gated_impl`
+  and :func:`fleet_run_to_decision_impl`) give their vmap a name
+  (:data:`FLEET_BATCH_AXIS`) and hand it down to ``_compute_round``, where
+  each conditional is then taken on "some tenant needs the arm", the
+  predicate reduced over that axis to one scalar
+  (``utils/dispatch.cond_across``): a round in which no tenant needs an arm
+  skips it for the whole fleet, a round in which one does pays it for all
+  (per-tenant select inside the arm, so each tenant's result is what the
+  select gave). ``engine_fleet_invalidation_rounds`` and
+  ``engine_fleet_classic_rounds`` count how often that is. In the fused
+  decision a tenant that has decided is frozen by the batched while, but
+  its frozen predicates still count in the reduce until the slowest tenant
+  decides: that can cost time, never a result. Again the caller decides,
+  never a knob: :func:`fleet_step_impl` and :func:`fleet_wave_impl` (the
+  mesh's and the analyzers' programs) name no axis and keep the select,
+  because on a ``'tenant'``-sharded mesh that reduce is a cross-tenant
+  collective in the hottest place of the program.
 - the fleet wave runs LOCKSTEP: a ``fori_loop`` over the step budget with
   per-tenant freeze masking, instead of a batched while. A batched while's
   predicate is an any() across tenants — a cross-tenant collective in the
@@ -114,6 +137,21 @@ KNOB_FIELDS = ("h", "l", "fd_threshold", "fallback_rounds")
 
 FLEET_STATIC_FIELDS = tuple(
     f for f in EngineConfig._fields if f not in KNOB_FIELDS
+)
+
+#: The name the two meshless fleet programs give their ``vmap``'s batch axis
+#: (module docstring: the round's conditionals are taken on "some tenant
+#: needs the arm"). Not ``TENANT_AXIS``: that is a mesh's axis, and a program
+#: on a mesh names no batch axis.
+FLEET_BATCH_AXIS = "fleet_tenants"
+
+#: The counters behind the device-carried ``gate_rounds`` vector, in its
+#: order: the rounds in which the step's view-change gate opened, and those
+#: in which the round's ``invalidation`` and ``classic`` arms ran.
+GATE_ROUND_COUNTERS = (
+    "engine_fleet_commit_rounds",
+    "engine_fleet_invalidation_rounds",
+    "engine_fleet_classic_rounds",
 )
 
 #: Partition rules for the fleet-level knob pytree, in the exact
@@ -202,9 +240,11 @@ def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
     """The MESHLESS fleet step the drivers dispatch (module docstring): one
     protocol round for every tenant with the view change under ONE scalar
     gate and the per-edge masks CARRIED from round to round. ``rest`` is
-    ``(*observers, faults, knobs, commit_rounds, masks)``, the round
+    ``(*observers, faults, knobs, gate_rounds, masks)``, the round
     programs' one convention (``models/virtual_cluster.py``). ``_compute_round``
-    is vmapped alone, over the stacked ``masks`` it is handed; the commit —
+    is vmapped alone, over the stacked ``masks`` it is handed and under
+    :data:`FLEET_BATCH_AXIS`, so its own conditionals stay conditionals on
+    "some tenant needs the arm" (module docstring); the commit —
     ``apply_view_change_impl`` vmapped, the per-tenant select, then
     ``_edge_masks`` vmapped over the committed state — sits in the taken arm
     of ``lax.cond(any(decided))`` outside the vmap, so a round in which no
@@ -219,19 +259,25 @@ def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
     tenant: they are a pure function of state and faults, so an undecided
     tenant gets its old values back and no per-tenant select is needed.
 
-    ``commit_rounds`` is the device-carried int32 behind
-    ``engine_fleet_commit_rounds``: the rounds in which the gate opened,
-    fetched only at the driver's host-sync boundaries.
+    ``gate_rounds`` is the device-carried ``int32[3]`` behind
+    :data:`GATE_ROUND_COUNTERS`: the rounds in which the view-change gate
+    opened and those in which ``invalidation`` and ``classic`` ran, fetched
+    only at the driver's host-sync boundaries.
 
-    Returns ``(state, *observers, commit_rounds, masks, events)``."""
-    *observers, faults, knobs, commit_rounds, masks = rest
+    Returns ``(state, *observers, gate_rounds, masks, events)``."""
+    *observers, faults, knobs, gate_rounds, masks = rest
 
     def one_round(state, faults, kn, masks, *observers):
-        return _compute_round(_tenant_cfg(cfg, kn), state, faults, masks, *observers)
+        return _compute_round(
+            _tenant_cfg(cfg, kn), state, faults, masks, *observers,
+            batch_axis=FLEET_BATCH_AXIS,
+        )
 
-    round_state, decided, winner, events, *observers = jax.vmap(one_round)(
-        state, faults, knobs, masks, *observers
-    )
+    # the round's last output, which arms ran, is one value for the fleet
+    round_state, decided, winner, events, *observers, arms_ran = jax.vmap(
+        one_round, axis_name=FLEET_BATCH_AXIS,
+        out_axes=(*(0,) * (4 + len(observers)), None),
+    )(state, faults, knobs, masks, *observers)
 
     def commit_one(kn, round_state, winner, decided):
         committed = apply_view_change_impl(_tenant_cfg(cfg, kn), round_state, winner)
@@ -248,10 +294,10 @@ def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
     new_state, masks = jax.lax.cond(
         any_decided, commit, scope("view_keep")(lambda s: (s, masks)), round_state
     )
-    return (
-        new_state, *observers, commit_rounds + any_decided.astype(jnp.int32),
-        masks, events,
+    gate_rounds = gate_rounds + jnp.concatenate(
+        [any_decided.astype(jnp.int32)[None], arms_ran]
     )
+    return (new_state, *observers, gate_rounds, masks, events)
 
 
 def fleet_run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
@@ -260,16 +306,28 @@ def fleet_run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
     observers' lanes stacked like the state. The batched while's predicate
     reduces across tenants (vmap's any()), so this entrypoint is for
     SINGLE-DEVICE driver dispatch — the mesh-audited fleet entrypoints are
-    the step and the lockstep wave."""
+    the step and the lockstep wave — and it names :data:`FLEET_BATCH_AXIS`
+    like the gated step: a round of the loop in which no tenant needs
+    ``invalidation`` or ``classic`` runs neither (module docstring).
+
+    Returns ``(state, *observers, steps[t], decided[t], winner[t, n],
+    arm_rounds)``, the last ``int32[2]``: the loop's rounds in which
+    ``invalidation`` and ``classic`` ran."""
     *observers, faults, knobs, max_steps = rest
 
     def one(state, *rest):
         *observers, faults, kn = rest
         return run_to_decision_impl(
-            _tenant_cfg(cfg, kn), state, *observers, faults, max_steps
+            _tenant_cfg(cfg, kn), state, *observers, faults, max_steps,
+            batch_axis=FLEET_BATCH_AXIS,
         )
 
-    return jax.vmap(one)(state, *observers, faults, knobs)
+    *out, arm_rounds = jax.vmap(one, axis_name=FLEET_BATCH_AXIS)(
+        state, *observers, faults, knobs
+    )
+    # The batched while freezes a tenant's carry once it has decided, its
+    # count with it: the tenant that ran longest counted every round.
+    return (*out, jnp.max(arm_rounds, axis=0))
 
 
 def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
@@ -424,7 +482,7 @@ tenant_health = jax.jit(tenant_health_impl, static_argnums=(0,))  # donate-ok: r
 fleet_edge_masks = jax.jit(fleet_edge_masks_impl, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
 #: A fleet verb's programs by observer count, like the cluster's
 #: ``_ROUND_PROGRAMS`` (the knobs ride after the faults). The step's
-#: commit-round counter and carried masks (its last two arguments) are donated
+#: gate-round counters and carried masks (its last two arguments) are donated
 #: too; the wave's static ``max_cuts`` sits after faults, knobs and two controls.
 _FLEET_PROGRAMS = {
     "step": jit_per_observer_count(fleet_step_gated_impl, donated=(4, 5)),
@@ -529,11 +587,13 @@ class TenantFleet(DispatchSeam):
         # tenant -> raw frozen membership captured at quarantine time (the
         # per-tenant freeze-lane inputs; see quarantine()).
         self._quarantined: dict = {}
-        # Rounds in which the step's view-change gate opened: carried on the
-        # device (the jitted step adds to it), mirrored into
-        # engine_fleet_commit_rounds only at host-sync boundaries.
-        self._commit_rounds = jnp.zeros((), dtype=jnp.int32)
-        self._commit_rounds_stale = False
+        # Rounds in which the step's gates opened (GATE_ROUND_COUNTERS):
+        # carried on the device (the jitted step adds to it), mirrored into
+        # the counters only at host-sync boundaries; ``_seen`` is what was
+        # mirrored so far.
+        self._gate_rounds = jnp.zeros((len(GATE_ROUND_COUNTERS),), dtype=jnp.int32)
+        self._gate_rounds_seen = np.zeros((len(GATE_ROUND_COUNTERS),), dtype=np.int32)
+        self._gate_rounds_stale = False
         self._carried = CarriedMasks(fleet_edge_masks)
         # Device telemetry plane: per-tenant lanes + the host-side activity
         # cache, zero-minted at attach (every series exists from scrape 0)
@@ -704,10 +764,10 @@ class TenantFleet(DispatchSeam):
         (and the span's tags) differ, so a change here cannot diverge the
         streamed path from the batch path the bit-identity tests pin."""
         self.metrics.inc("engine_tenant_rounds", self.b)
-        self._commit_rounds_stale = True
+        self._gate_rounds_stale = True
         with self._dispatch(phase, **tags):
-            self._commit_rounds, masks, events = self._advance(
-                "step", self._commit_rounds, self._carried.for_step(self)
+            self._gate_rounds, masks, events = self._advance(
+                "step", self._gate_rounds, self._carried.for_step(self)
             )
             self._carried.keep(self, masks)
         return events
@@ -738,23 +798,29 @@ class TenantFleet(DispatchSeam):
     def run_to_decision(self, max_steps: int = 64):
         """Every tenant runs to its own first view change in one dispatch;
         returns ``(rounds[t], decided[t], winner[t, n] on device,
-        members[t])`` with one packed observation fetch."""
+        members[t])`` with one packed observation fetch, which also brings
+        the loop's rounds in which ``invalidation`` and ``classic`` ran."""
         with self._dispatch("fleet_decision"):
-            steps, decided, winner = self._advance(
+            steps, decided, winner, arm_rounds = self._advance(
                 "decision", jnp.int32(max_steps)
             )
             obs = np.asarray(
-                jnp.stack(
-                    [steps, decided.astype(jnp.int32), self.state.n_members]
+                jnp.concatenate(
+                    [steps, decided.astype(jnp.int32), self.state.n_members,
+                     arm_rounds]
                 )
             )
         self._account_d2h(obs.nbytes)
-        self._refresh_commit_rounds()
-        rounds = obs[0]
-        was_decided = obs[1].astype(bool)
+        self._refresh_gate_rounds()
+        rounds, was_decided, members, arm_rounds = np.split(
+            obs, [self.b, 2 * self.b, 3 * self.b]
+        )
+        was_decided = was_decided.astype(bool)
         self.metrics.inc("engine_tenant_rounds", int(rounds.sum()))
         self.metrics.inc("engine_tenant_cuts", int(was_decided.sum()))
-        return rounds, was_decided, winner, obs[2]
+        for name, ran in zip(GATE_ROUND_COUNTERS[1:], arm_rounds):
+            self.metrics.inc(name, int(ran))
+        return rounds, was_decided, winner, members
 
     def run_until_membership(
         self,
@@ -820,8 +886,8 @@ class TenantFleet(DispatchSeam):
         """Refresh the per-tenant activity cache from the device lanes —
         called ONLY at host-sync boundaries (sync / health_scan / the
         stream driver's fetch seam), never on the dispatch hot path. The
-        step's commit-round counter rides the same boundaries."""
-        self._refresh_commit_rounds()
+        step's gate-round counters ride the same boundaries."""
+        self._refresh_gate_rounds()
         if self.telem is None:
             return
         # telemetry-fetch-ok: host-sync boundary — the caller is already
@@ -844,23 +910,25 @@ class TenantFleet(DispatchSeam):
                 for t in range(self.b)
             ]
 
-    def _refresh_commit_rounds(self) -> None:
-        """Mirror the device-carried count of rounds in which the step's
-        view-change gate opened into ``engine_fleet_commit_rounds`` (one
-        4-byte fetch, charged; host-sync boundaries only, and only if a step
-        ran since the last one — a fleet driven by the fused loops pays
-        nothing). Over the count of ``engine_dispatch_ms{phase="fleet_step"|
-        "stream_enqueue"}`` it is the share of fleet rounds that paid a view
-        change."""
-        if not self._commit_rounds_stale:
+    def _refresh_gate_rounds(self) -> None:
+        """Mirror the device-carried counts of rounds in which the step's
+        gates opened into :data:`GATE_ROUND_COUNTERS` (one 12-byte fetch,
+        charged; host-sync boundaries only, and only if a step ran since the
+        last one — a fleet driven by the fused loops pays nothing). Over the
+        count of ``engine_dispatch_ms{phase="fleet_step"|"stream_enqueue"}``
+        they are the shares of fleet rounds that paid a view change, an
+        ``invalidation`` and a ``classic`` attempt."""
+        if not self._gate_rounds_stale:
             return
-        total = int(self._commit_rounds)  # host-sync-ok: the caller's boundary
-        self._account_d2h(4)
-        self.metrics.inc(
-            "engine_fleet_commit_rounds",
-            total - self.metrics.counters.get("engine_fleet_commit_rounds", 0),
-        )
-        self._commit_rounds_stale = False
+        # a copy: the step donates the device's vector away
+        totals = np.array(self._gate_rounds)  # host-sync-ok: the caller's boundary
+        self._account_d2h(totals.nbytes)
+        for name, total, seen in zip(
+            GATE_ROUND_COUNTERS, totals, self._gate_rounds_seen
+        ):
+            self.metrics.inc(name, int(total - seen))
+        self._gate_rounds_seen = totals
+        self._gate_rounds_stale = False
 
     @property
     def activity(self) -> Optional[dict]:
@@ -1052,6 +1120,12 @@ class TenantFleet(DispatchSeam):
                     ),
                     "fleet_commit_rounds_total": int(
                         counters.get("engine_fleet_commit_rounds", 0)
+                    ),
+                    "fleet_invalidation_rounds_total": int(
+                        counters.get("engine_fleet_invalidation_rounds", 0)
+                    ),
+                    "fleet_classic_rounds_total": int(
+                        counters.get("engine_fleet_classic_rounds", 0)
                     ),
                     "tenant_rounds_per_dispatch": round(
                         tenant_rounds / dispatches, 3

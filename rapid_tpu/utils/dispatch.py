@@ -26,7 +26,8 @@ operations they enqueue; the histograms are the always-on sums and the
 profiler trace is the span store. Inside the compiled programs the round's
 phases and the arms of its conditionals carry ``jax.named_scope`` names from
 :data:`ENGINE_SCOPES` (:func:`scope`), registered and enforced at write time
-like the phases.
+like the phases; :func:`cond_across` is the form those conditionals take, so
+that a batched program which names its batch axis keeps them conditionals.
 """
 
 from __future__ import annotations
@@ -35,6 +36,7 @@ import time
 from contextlib import contextmanager
 
 import jax
+import jax.numpy as jnp
 
 from rapid_tpu.utils.profiling import annotate
 
@@ -108,6 +110,35 @@ def scope(name: str):
             f"rapid_tpu.utils.dispatch.ENGINE_SCOPES"
         )
     return jax.named_scope(name)
+
+
+def cond_across(axis, pred, taken, skipped, *operands):
+    """``lax.cond(pred, taken, skipped, *operands)`` for a conditional inside
+    a round that may run under a ``vmap``; returns ``(result, opened)``.
+
+    ``axis`` is the name the enclosing ``vmap`` gave its batch axis, or
+    ``None``. With ``None`` this IS the plain ``lax.cond`` (``opened`` is
+    ``pred``): a cluster, and a fleet program that names no axis, trace what
+    they always traced, and under an unnamed ``vmap`` the batched predicate
+    turns the conditional into a select that runs both arms for everybody.
+    With a name the predicate is reduced over that axis to ``opened``, one
+    scalar that ``vmap`` does not batch, so the conditional stays one: the
+    taken arm computes ``taken`` for every member of the batch and selects
+    per member by the member's own ``pred`` (what the select did, so each
+    member's result is bit-identical), and a round in which nobody's
+    ``pred`` holds runs ``skipped`` alone. On a mesh that shards the batch
+    axis the reduce would be a collective across it; callers there pass no
+    name."""
+    if axis is None:
+        return jax.lax.cond(pred, taken, skipped, *operands), pred
+    opened = jax.lax.psum(pred.astype(jnp.int32), axis) > 0
+
+    def taken_by_some(*operands):
+        return jax.tree_util.tree_map(
+            lambda t, s: jnp.where(pred, t, s), taken(*operands), skipped(*operands)
+        )
+
+    return jax.lax.cond(opened, taken_by_some, skipped, *operands), opened
 
 
 class DispatchSeam:

@@ -105,6 +105,11 @@ TENANCY_KNOWN_COUNTERS = (
     # decided): over the fleet_step/stream_enqueue dispatch count, the share
     # of fleet rounds that paid a view change (tenancy/fleet.py).
     "engine_fleet_commit_rounds",
+    # Fleet rounds in which the round's own gated arms ran for the fleet
+    # (some tenant had a subject in flux after a DOWN event; some tenant's
+    # classic fallback was due), by the gated step and the fused decision.
+    "engine_fleet_invalidation_rounds",
+    "engine_fleet_classic_rounds",
 )
 
 #: Streaming-tier counters zero-filled on snapshots whose ``engine`` section

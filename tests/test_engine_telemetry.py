@@ -329,6 +329,10 @@ GOLDEN_FLEET_METRIC_NAMES = sorted(
         "rapid_engine_tenant_rounds_total",
         # Rounds in which the gated step's view change ran (ISSUE 26).
         "rapid_engine_fleet_commit_rounds_total",
+        # Rounds in which the round's own gated arms ran for the fleet
+        # (ISSUE 30).
+        "rapid_engine_fleet_invalidation_rounds_total",
+        "rapid_engine_fleet_classic_rounds_total",
         "rapid_engine_tenant_rounds_per_dispatch",
         "rapid_engine_tenants",
         # Quarantine census (ISSUE 15): the zero-filled cumulative counter

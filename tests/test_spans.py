@@ -112,7 +112,7 @@ def lowered():
             out[CLUSTER_VERBS[verb] + LEVELS[level]] = vcm._ROUND_PROGRAMS[verb][level].lower(
                 one.cfg, *carried, one.faults, *controls)
         carried = [t for t in (many.state, many.telem, many.trace_ring) if t is not None]
-        for verb, controls in (("step", (i32(0), fleet_masks)), ("decision", (i32(16),)),
+        for verb, controls in (("step", (jnp.zeros((3,), i32), fleet_masks)), ("decision", (i32(16),)),
                                ("wave", (per_tenant[0], i32(16), 4, per_tenant[1]))):
             out[FLEET_VERBS[verb] + LEVELS[level]] = fleetm._FLEET_PROGRAMS[verb][level].lower(
                 many.cfg, *carried, many.faults, many.knobs, *controls)
@@ -164,16 +164,47 @@ def test_each_arm_of_a_conditional_carries_its_own_name(lowered, program, arms):
     assert len({next(iter(found)) for found in branches.values()}) == len(arms)
 
 
-def test_under_vmap_both_arms_are_traced_into_the_fleet_step(lowered):
-    # vmap turns a per-cluster cond into a select: both arms run, and the
-    # names ride inside the transform's brackets. That holds for the round's
-    # own conditionals in both fleet steps, and for the view change in the
-    # mesh's lockstep step, which has no conditional around it.
-    for program in ("fleet_step", "mesh_fleet_step"):
-        paths = _paths(lowered[program])
-        assert any("vmap(view_change)" in p for p in paths)
-        assert any("vmap(deliver)" in p for p in paths) and any("vmap(deliver_skip)" in p for p in paths)
-    assert not any("cond/" in p for p in _paths(lowered["mesh_fleet_step"]))
+def test_under_an_unnamed_vmap_both_arms_are_traced_into_the_fleet_step(lowered):
+    # An unnamed vmap turns a per-cluster cond into a select: both arms run,
+    # and the names ride inside the transform's brackets. That is the mesh's
+    # lockstep step, for the round's own conditionals and for the view
+    # change: it has no conditional at all (a predicate reduced over the
+    # 'tenant' axis would be a collective across it).
+    paths = _paths(lowered["mesh_fleet_step"])
+    for arm in ("view_change", "deliver", "deliver_skip", "invalidation", "classic"):
+        assert any(re.search(r"(?:vmap\(|/)%s\)?(?:/|$)" % arm, p) for p in paths), arm
+    assert not any("cond/" in p for p in paths)
+    # the meshless step's view change is vmapped inside its one arm
+    assert any("vmap(view_change)" in p for p in _paths(lowered["fleet_step"]))
+
+
+GATED_ARMS = ("deliver", "invalidation", "classic")
+
+
+@pytest.mark.parametrize("program", [
+    name + suffix for name in ("fleet_step", "fleet_run_to_decision") for suffix in LEVELS])
+def test_the_meshless_fleet_programs_keep_the_rounds_conditionals(lowered, program):
+    # under the named batch axis the round's three conditionals stay
+    # conditionals (taken when some tenant needs the arm): every operation
+    # traced under their scopes lies in an arm, each scope in one arm only
+    paths = _paths(lowered[program])
+    for arm in GATED_ARMS:
+        under = {p for p in paths if re.search(r"(?:^|/)%s(?:/|$)" % arm, p)}
+        assert under, arm
+        branches = {re.search(r"(?:^|/)cond/(branch_\d+_fun)/%s(?:/|$)" % arm, p) for p in under}
+        assert None not in branches, (arm, sorted(under)[:3])
+        assert len({m.group(1) for m in branches}) == 1, arm
+        assert not any("vmap(%s)" % arm in p for p in paths), arm
+
+
+@pytest.mark.parametrize("program", ["mesh_fleet_step", "fleet_wave"])
+def test_the_programs_that_name_no_batch_axis_keep_the_select(lowered, program):
+    # the mesh's step and the lockstep wave (no cell runs it): both arms of
+    # every conditional of the round, for every tenant, in every round
+    paths = _paths(lowered[program])
+    for arm in GATED_ARMS:
+        under = {p for p in paths if re.search(r"(?:vmap\(|/)%s\)?(?:/|$)" % arm, p)}
+        assert under and not any("cond/" in p for p in under), arm
 
 
 def test_the_meshless_fleet_step_gates_its_view_change_on_one_conditional(lowered):
@@ -184,8 +215,10 @@ def test_the_meshless_fleet_step_gates_its_view_change_on_one_conditional(lowere
     arms = {re.search(r"(?:^|/)cond/(branch_\d+_fun)/vmap\(view_change\)/", p) for p in view_change}
     assert view_change and None not in arms
     assert len({m.group(1) for m in arms}) == 1
-    # the other arm returns its operand: it traces no operation (IDENTITY_ARMS)
-    assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(paths)))
+    # the other arm returns its operand: it traces no operation (IDENTITY_ARMS);
+    # the conditionals inside the vmap are the round's own
+    outside = [p for p in paths if "cond/" in p and "/vmap(" not in p.split("cond/")[0]]
+    assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(outside)))
 
 
 @pytest.mark.parametrize("program", ["engine_step_carried", "fleet_step"])
@@ -215,11 +248,13 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: f450378 (the parent of PR 28), at this module's tiny shapes: the programs
 #: of the cells the carried masks bypass (churn5's fused wave, crash10's
 #: fleet decision, cluster-10m's meshed decision) and the two mesh steps.
+#: ``fleet_run_to_decision`` is PR 30's: it names its batch axis, and the
+#: round's conditionals in it are conditionals; the other four name none.
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
     "run_until_membership": "1f6637696631d51b",
-    "fleet_run_to_decision": "7b23cb5f877db09b",
+    "fleet_run_to_decision": "e415a468c57334a8",
     "mesh_run_to_decision": "2e585f0987f0656f",
     "mesh_step": "9343185e4ea60084",
     "mesh_fleet_step": "721316093e3bd78d",

@@ -22,6 +22,7 @@ count that does not divide the tenant axis.
 
 import functools
 import random
+import re
 
 import numpy as np
 import pytest
@@ -79,6 +80,14 @@ def _drive_single(vc, max_steps):
             ids.append(vc.config_id)
             rounds.append(i)
     return cuts, ids, rounds
+
+
+def _assert_leaves_equal(got, want, label):
+    got_leaves, want_leaves = map(jax.tree_util.tree_leaves, (got, want))
+    assert len(got_leaves) == len(want_leaves), label
+    for ours, theirs in zip(got_leaves, want_leaves):
+        assert ours.dtype == theirs.dtype, label
+        np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs), err_msg=str(label))
 
 
 def _injected_tenants(telemetry=False):
@@ -246,20 +255,17 @@ def test_gated_step_is_bit_identical_to_the_lockstep_step(spelling, scenario):
         ref_state, *ref_observers, ref_events = reference(
             fleet.cfg, ref_state, fleet.faults, fleet.knobs, *ref_observers
         )
-        got = (fleet.state, events, fleet.telem, fleet.trace_ring)
-        want = (ref_state, ref_events, *ref_observers)
-        got_leaves, want_leaves = map(jax.tree_util.tree_leaves, (got, want))
-        assert len(got_leaves) == len(want_leaves)
-        for ours, theirs in zip(got_leaves, want_leaves):
-            assert ours.dtype == theirs.dtype
-            np.testing.assert_array_equal(np.asarray(ours), np.asarray(theirs))
+        _assert_leaves_equal(
+            (fleet.state, events, fleet.telem, fleet.trace_ring),
+            (ref_state, ref_events, *ref_observers), (spelling, scenario),
+        )
         decided_rounds.append(np.asarray(events.decided))
     decided_rounds = np.stack(decided_rounds)
     # the scenario is what it says: exactly the victims' tenants decide, and
     # (unless all do at once) some round passes with the gate shut
     assert set(np.nonzero(decided_rounds.any(axis=0))[0].tolist()) == set(victims)
     assert (~decided_rounds.any(axis=1)).any()
-    assert int(fleet._commit_rounds) == int(decided_rounds.any(axis=1).sum())
+    assert int(fleet._gate_rounds[0]) == int(decided_rounds.any(axis=1).sum())
 
 
 def test_commit_round_counter_rides_the_fetch_boundaries_only():
@@ -292,6 +298,139 @@ def test_commit_round_counter_rides_the_fetch_boundaries_only():
     assert fleet.metrics.counters["engine_d2h_bytes"] > d2h
     tenancy = fleet.telemetry_snapshot()["engine"]["tenancy"]
     assert tenancy["fleet_commit_rounds_total"] == opened
+
+
+# ---------------------------------------------------------------------------
+# The round's own gated arms (``cond_across``): under the meshless programs'
+# named batch axis ``invalidation`` and ``classic`` run when SOME tenant needs
+# them, for every tenant, each selecting by its own predicate. The two mixed
+# cases that select exists for, against separate ``VirtualCluster`` runs (and
+# the lockstep select, for the step): every state leaf equal, tenant by tenant.
+# ---------------------------------------------------------------------------
+
+ARM_TENANTS = 4
+ARM_KW = dict(n_slots=32, k=3, h=3, l=1, cohorts=2, fd_threshold=2, delivery_spread=1)
+
+
+def _arm_clusters(scenario):
+    """Four tenants of the gate tests' geometry. ``invalidation``: tenant 2
+    loses a member, the others nothing. ``classic``: tenant 1 (fallback after
+    two undecided rounds) has a contested cut, its minority cohort deaf to
+    every observer of the second victim; tenant 3 loses one member and
+    decides fast; tenants 0 and 2 nothing."""
+    clusters = []
+    for t in range(ARM_TENANTS):
+        low = {"fallback_rounds": 2} if (scenario, t) == ("classic", 1) else {}
+        vc = VirtualCluster.create(28, seed=t, **ARM_KW, **low)
+        vc.assign_cohorts_roundrobin()
+        clusters.append(vc)
+    if scenario == "invalidation":
+        clusters[2].crash([5])
+        return clusters
+    contested = clusters[1]
+    cohort_of = np.zeros(contested.cfg.n, dtype=np.int32)
+    cohort_of[19:] = 1
+    contested.assign_cohorts(cohort_of)
+    contested.crash([3, 11])
+    deaf = np.zeros((contested.cfg.c, contested.cfg.n), dtype=bool)
+    deaf[1, np.asarray(contested.state.obs_idx)[:, 11]] = True
+    contested.set_rx_block(deaf)
+    clusters[3].crash([7])
+    return clusters
+
+
+@pytest.mark.parametrize("verb", ["step", "run_to_decision"])
+@pytest.mark.parametrize("scenario", ["invalidation", "classic"])
+def test_a_gated_arm_taken_by_one_tenant_leaves_every_tenant_bit_identical(scenario, verb):
+    singles = _arm_clusters(scenario)
+    fleet = TenantFleet.from_clusters(_arm_clusters(scenario))
+    if verb == "run_to_decision":
+        rounds, decided, _, members = fleet.run_to_decision(max_steps=12)
+        for t, vc in enumerate(singles):
+            assert vc.run_to_decision(max_steps=12)[:2] == (rounds[t], decided[t]), t
+            assert vc.membership_size == members[t], t
+    else:
+        reference = _lockstep_step("plain")
+        ref_state = jax.tree_util.tree_map(jnp.copy, fleet.state)
+        fast = np.zeros((12, ARM_TENANTS), dtype=bool)
+        slow = np.zeros((12, ARM_TENANTS), dtype=bool)
+        for i in range(12):
+            events = fleet.step()
+            ref_state, ref_events = reference(fleet.cfg, ref_state, fleet.faults, fleet.knobs)
+            _assert_leaves_equal((fleet.state, events), (ref_state, ref_events), i)
+            for t, vc in enumerate(singles):
+                single = vc.step()
+                assert bool(single.decided) == bool(events.decided[t]), (i, t)
+            fast[i] = np.asarray(events.fast_decided)
+            slow[i] = np.asarray(events.decided) & ~fast[i]
+        # the scenario is what it says: who decided, and by which path
+        want_fast, want_slow = {"invalidation": ({2}, set()), "classic": ({3}, {1})}[scenario]
+        assert set(np.nonzero(fast.any(axis=0))[0].tolist()) == want_fast
+        assert set(np.nonzero(slow.any(axis=0))[0].tolist()) == want_slow
+    for t, vc in enumerate(singles):
+        _assert_leaves_equal(fleet.tenant_state(t), vc.state, (scenario, verb, t))
+    # the arm ran, in a round in which the tenants with no fault (not one
+    # report bit, so no subject in flux and no proposal) did not need it
+    fleet.sync()
+    counters = fleet.metrics.counters
+    assert counters["engine_fleet_invalidation_rounds"] >= 1
+    assert (counters["engine_fleet_classic_rounds"] >= 1) == (scenario == "classic")
+    idle = [0, 1, 3] if scenario == "invalidation" else [0, 2]
+    assert not np.asarray(fleet.state.report_bits)[idle].any()
+    assert not np.asarray(fleet.state.announced)[idle].any()
+
+
+def test_arm_round_counters_ride_the_fetch_boundaries_only():
+    """``engine_fleet_invalidation_rounds`` / ``engine_fleet_classic_rounds``
+    on the paper's step (10 crashes in every tenant of 1,000, K,H,L = 10,9,3):
+    every tenant decides in the fifth round, some subject is in flux in two
+    of the five and no fallback is ever due. Carried on the device by the
+    step, mirrored with the commit rounds at the sync and nowhere else; the
+    fused decision brings them with the observation it fetches anyway."""
+    tenants, members = 3, 1000
+
+    def crashed_fleet():
+        fleet = TenantFleet.create(
+            tenants, members, n_slots=members, k=10, cohorts=8,
+            knobs=[(9, 3, 3)] * tenants, delivery_spread=2,
+        )
+        victims = np.random.default_rng(5)
+        fleet.stream_crash([
+            (t, int(slot)) for t in range(tenants)
+            for slot in victims.choice(members, 10, replace=False)
+        ])
+        return fleet
+
+    fleet = crashed_fleet()
+    d2h = fleet.metrics.counters["engine_d2h_bytes"]
+    decided = [np.asarray(fleet.step().decided) for _ in range(5)]
+    assert [d.all() for d in decided] == [False] * 4 + [True]
+    assert not any(d.any() for d in decided[:4])
+    counters = fleet.metrics.counters
+    assert counters["engine_d2h_bytes"] == d2h  # five steps: not one byte fetched
+    assert "engine_fleet_invalidation_rounds" not in counters
+    fleet.sync()
+    assert counters["engine_d2h_bytes"] == d2h + 12  # the one int32[3] fetch
+    assert counters["engine_fleet_commit_rounds"] == 1
+    assert counters["engine_fleet_invalidation_rounds"] == 2
+    assert counters["engine_fleet_classic_rounds"] == 0
+    fleet.sync()  # no step since: nothing to fetch
+    assert counters["engine_d2h_bytes"] == d2h + 12
+    tenancy = fleet.telemetry_snapshot()["engine"]["tenancy"]
+    assert tenancy["fleet_invalidation_rounds_total"] == 2
+    assert tenancy["fleet_classic_rounds_total"] == 0
+    scrape = fleet.prometheus_text()
+    assert re.search(r'^rapid_engine_fleet_invalidation_rounds_total\{[^}]*\} 2$', scrape, re.M)
+    assert re.search(r'^rapid_engine_fleet_classic_rounds_total\{[^}]*\} 0$', scrape, re.M)
+
+    fused = crashed_fleet()
+    d2h = fused.metrics.counters["engine_d2h_bytes"]
+    rounds, was_decided, _, _ = fused.run_to_decision(max_steps=16)
+    assert rounds.tolist() == [5] * tenants and was_decided.all()
+    # the packed observation, two int32 longer; no fetch beside it
+    assert fused.metrics.counters["engine_d2h_bytes"] == d2h + 4 * (3 * tenants + 2)
+    assert fused.metrics.counters["engine_fleet_invalidation_rounds"] == 2
+    assert fused.metrics.counters["engine_fleet_classic_rounds"] == 0
 
 
 def test_fleet_rejects_mismatched_static_geometry():
