@@ -168,14 +168,32 @@ def _alive_first_order(perm, alive):
 
 
 #: From this many slots on, ``ring_topology_from_perm`` takes the K rings
-#: one at a time (a ``lax.map``) instead of all at once (a ``vmap``). The
-#: TPU compiler handles a scan or a scatter over the long axis of a
-#: ``[K, N]`` array far worse than K scans over ``[N]``: at N = 10M on a
-#: (1,4) mesh the batched form compiles in 214 s with 8.4 GB of temporaries
-#: a device, the ring-at-a-time form in 9 s with 0.17 GB (compiled here for
-#: a described v5e:2x2, PR 27). Below the threshold the programs are what
-#: they were, byte for byte. A size read off the shape, not an option.
-RING_AT_A_TIME_SLOTS = 1 << 22
+#: one at a time (a ``lax.map``) instead of all at once (a ``vmap``): one
+#: algorithm under two schedules, chosen from the ring length the operand
+#: shows. Two measurements place the bound (TPU v5e; PERF.md section 6):
+#:
+#: * It may not lie above 2**22. The TPU compiler handles a scan or a
+#:   scatter over the long axis of a ``[K, N]`` array far worse than K of
+#:   them over ``[N]``: at N = 10M on a (1,4) mesh the batched form compiles
+#:   in 214 s with 8.4 GB of temporaries a device, the ring-at-a-time form in
+#:   9 s with 0.17 GB (compiled for a described v5e:2x2, PR 27).
+#: * One at a time also *runs* faster, by more the longer the ring. A whole
+#:   view change with K = 10, batched against one at a time, a 1 % and a 5 %
+#:   cut alike (PR 32, ms): 3.4 / 3.5 at 1,000 slots and 4.5 / 4.4 at 4,000
+#:   (a tie inside a call's spread), 8.9 / 8.5 at 16,000 (ranges touching),
+#:   15.2 / 13.0 at 32,000 (apart from here on), 27.9 / 23.1 at 64,000,
+#:   45.2 / 37.6 at 102,500, 104.4 / 93.3 at 262,144, 224.5 / 182.5 at
+#:   524,288, 466.6 / 344.2 at 1,000,000. In the cells: a 1M commit 874 ->
+#:   705 ms (its view change 508.5 -> 340.3, compiling as long either way,
+#:   196 / 198 s), a 100K churn commit 131.1 -> 123.9 ms, the 100K trickle
+#:   11.79 -> 12.17 view changes/s, warm set-up the same.
+#:
+#: So the bound is the shortest swept length at which the two forms' timings
+#: lie apart. Under it the forms tie and all K rings stay in one program,
+#: which is what the fleet's 1,000 slots under a tenant ``vmap`` run (there
+#: a ``lax.map`` would sit inside the ``vmap``: another question, with
+#: another control). A size read off the shape, not an option.
+RING_AT_A_TIME_SLOTS = 32_000
 
 
 def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopology:
@@ -188,8 +206,8 @@ def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopolo
     ring_perm at the policy's index width (int8/int16,
     models/state.compaction_policy) and gathers/scatters index with it
     directly; the returned tables are int32 (position arithmetic
-    accumulates wide here) and the caller narrows on store. Very large
-    rings go one at a time (:data:`RING_AT_A_TIME_SLOTS`), same values."""
+    accumulates wide here) and the caller narrows on store. Long rings go
+    one at a time (:data:`RING_AT_A_TIME_SLOTS`), same values."""
     perm, alive = jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
     if perm.shape[-1] >= RING_AT_A_TIME_SLOTS:
         obs, subj, order = jax.lax.map(

@@ -231,12 +231,38 @@ def test_an_existing_state_is_adopted_onto_the_mesh(one_device):
         assert leaf.sharding.is_equivalent_to(want, leaf.ndim)
 
 
-def test_rings_one_at_a_time_give_the_batched_topology(monkeypatch):
+def _ring_case(name):
+    """(perm [5, 600], alive [600]) for one corner of the aliveness range."""
     rng = np.random.default_rng(2)
     perm = np.stack([rng.permutation(600) for _ in range(5)]).astype(np.int32)
-    alive = rng.random(600) < 0.8
+    alive = {
+        "four_fifths_alive": rng.random(600) < 0.8,
+        "int16_perm": rng.random(600) < 0.8,
+        "all_alive": np.ones(600, dtype=bool),
+        "one_alive": np.arange(600) == 311,
+        "none_alive": np.zeros(600, dtype=bool),
+        # the first and the last slot of ring 0's key order
+        "ends_of_a_ring": np.isin(np.arange(600), perm[0, [0, -1]]),
+    }[name]
+    # int16 is the compact engine's index width
+    return perm.astype(np.int16 if name == "int16_perm" else np.int32), alive
+
+
+@pytest.mark.parametrize("case", [
+    "four_fifths_alive", "all_alive", "one_alive", "none_alive", "ends_of_a_ring", "int16_perm",
+])
+def test_rings_one_at_a_time_give_the_batched_topology(monkeypatch, case):
+    perm, alive = _ring_case(case)
+    assert perm.shape[-1] < rings.RING_AT_A_TIME_SLOTS
     batched = rings.ring_topology_from_perm(perm, alive)
     monkeypatch.setattr(rings, "RING_AT_A_TIME_SLOTS", 512)
     one_at_a_time = rings.ring_topology_from_perm(perm, alive)
     for a, b in zip(batched, one_at_a_time):
-        assert a.dtype == b.dtype and (np.asarray(a) == np.asarray(b)).all()
+        assert a.dtype == b.dtype == np.int32 and (np.asarray(a) == np.asarray(b)).all()
+    if case in ("one_alive", "none_alive"):  # under two alive nobody observes anybody
+        assert (np.asarray(one_at_a_time.obs_idx) == -1).all()
+        assert (np.asarray(one_at_a_time.subj_idx) == -1).all()
+    if case == "ends_of_a_ring":  # the two wrap round to each other on every ring
+        first, last = int(perm[0, 0]), int(perm[0, -1])
+        assert (np.asarray(one_at_a_time.obs_idx)[:, first] == last).all()
+        assert (np.asarray(one_at_a_time.subj_idx)[:, last] == first).all()

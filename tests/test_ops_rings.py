@@ -3,6 +3,9 @@
 import numpy as np
 import pytest
 
+import jax
+
+from rapid_tpu.ops import rings
 from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
@@ -141,3 +144,53 @@ def test_expected_observers_of_joiners():
     for jx, joiner in enumerate(joiners):
         expected = [slot_of[o] for o in view.expected_observers_of(joiner)]
         assert pred[:, jx].tolist() == expected
+
+
+def _primitives(jaxpr):
+    """Every primitive of a jaxpr, the bodies of its calls and loops included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+#: The form ``ring_topology_from_perm`` takes is a static fact of the ring
+#: length: (perm's shape, its dtype, tenants it is ``vmap``ped over, walks the
+#: rings one at a time). The sizes are the benchmark's configurations'
+#: (PERF.md section 4); int16 is the compact engine's index width.
+RING_FORMS = {
+    "cluster-1m": ((10, 1_000_000), np.int32, None, True),
+    "cluster-10m": ((10, 10_000_000), np.int32, None, True),
+    "cluster-100k": ((10, 102_500), np.int32, None, True),
+    "paper-fleet-1k": ((10, 1000), np.int32, 256, False),
+    "at_the_threshold": ((10, rings.RING_AT_A_TIME_SLOTS), np.int32, None, True),
+    "one_under_the_threshold": ((10, rings.RING_AT_A_TIME_SLOTS - 1), np.int32, None, False),
+    "compact_index_width": ((10, 1000), np.int16, None, False),
+}
+
+
+@pytest.mark.parametrize("name", list(RING_FORMS))
+def test_the_ring_length_picks_the_form_of_the_rebuild(name):
+    # Traced over shapes alone: no buffer of ten million slots is ever made.
+    shape, dtype, tenants, one_at_a_time = RING_FORMS[name]
+    fn, lead = ring_topology_from_perm, ()
+    if tenants is not None:
+        fn, lead = jax.vmap(ring_topology_from_perm), (tenants,)
+    traced = jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct(lead + shape, dtype),
+        jax.ShapeDtypeStruct(lead + shape[-1:], np.bool_),
+    )
+    assert [v.aval.shape for v in traced.jaxpr.outvars] == [lead + shape] * 3
+    assert [v.aval.dtype for v in traced.jaxpr.outvars] == [np.int32] * 3
+    loops = [p for p in _primitives(traced.jaxpr) if p in ("scan", "while")]
+    assert loops == (["scan"] if one_at_a_time else []), (name, loops)
+
+
+def test_the_threshold_lies_between_the_cells_it_separates():
+    # The fleet's clusters keep all K rings in one program; cluster-100k,
+    # cluster-1m and cluster-10m (whose compile time placed the first bound,
+    # 2**22, PR 27) walk them one at a time.
+    assert 1000 < rings.RING_AT_A_TIME_SLOTS <= 102_500
