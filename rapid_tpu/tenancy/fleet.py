@@ -68,18 +68,33 @@ Batched-control-flow tradeoffs, stated plainly:
   decision a tenant that has decided is frozen by the batched while, but
   its frozen predicates still count in the reduce until the slowest tenant
   decides: that can cost time, never a result. Again the caller decides,
-  never a knob: :func:`fleet_step_impl` and :func:`fleet_wave_impl` (the
-  mesh's and the analyzers' programs) name no axis and keep the select,
+  never a knob: :func:`fleet_step_impl` and :func:`fleet_wave_lockstep_impl`
+  (the mesh's and the analyzers' programs) name no axis and keep the select,
   because on a ``'tenant'``-sharded mesh that reduce is a cross-tenant
   collective in the hottest place of the program.
-- the fleet wave runs LOCKSTEP: a ``fori_loop`` over the step budget with
-  per-tenant freeze masking, instead of a batched while. A batched while's
-  predicate is an any() across tenants — a cross-tenant collective in the
-  hottest location of the program, which the zero-cross-tenant budget
-  forbids. The loop predicate here is a replicated counter; finished
-  tenants coast. (``fleet_run_to_decision`` keeps the dynamic batched
-  while for single-device driver use, where there is no mesh and the any()
-  is free.)
+- the whole-wave loop has the same two forms. On a mesh it runs LOCKSTEP
+  (:func:`fleet_wave_lockstep_impl`): a ``fori_loop`` over the whole step
+  budget with per-tenant freeze masking, the masks built and the view
+  change select-applied in every iteration for every tenant, because a
+  batched while's predicate is an any() across tenants — a cross-tenant
+  collective in the hottest location of the program, which the
+  zero-cross-tenant budget forbids; its predicate is a replicated counter
+  and finished tenants coast. The MESHLESS wave the driver dispatches
+  (:func:`fleet_wave_impl`, ``TenantFleet.run_until_membership``) pays none
+  of that: a ``while_loop`` over the gated step's own round
+  (:func:`_gated_round`) that ends when no tenant is active, on masks built
+  before the loop and rebuilt only in the view-change arm; a bootstrap wave
+  of three rounds and one cut runs three rounds, one view change and two
+  mask builds, not 192 of each. ``engine_fleet_wave_rounds`` counts the
+  lockstep rounds such loops ran, and the three gate counters take the
+  loop's share. (``fleet_run_to_decision`` is the same kind of program: a
+  dynamic batched while for single-device driver use, where there is no
+  mesh and the any() is free.)
+- joins reach a stacked fleet through :meth:`TenantFleet.inject_join_wave`:
+  ``(tenant, slot)`` pairs, padded per tenant on the device and placed by
+  ``predecessor_of_keys`` vmapped over the tenant axis
+  (:func:`fleet_join_place_impl`), bit-identical to
+  ``VirtualCluster.inject_join_wave`` on every tenant before stacking.
 """
 
 from __future__ import annotations
@@ -97,6 +112,7 @@ from rapid_tpu.models.state import (
     StepEvents,
     TelemetryLanes,
     TraceRing,
+    compaction_policy,
     initial_telemetry,
     initial_trace,
 )
@@ -112,6 +128,7 @@ from rapid_tpu.models.virtual_cluster import (
     telemetry_digest_impl,
     trace_digest_impl,
 )
+from rapid_tpu.ops.rings import predecessor_of_keys
 from rapid_tpu.parallel.mesh import (
     TENANT_AXIS,
     Mesh,
@@ -236,36 +253,80 @@ def fleet_edge_masks_impl(cfg: EngineConfig, state: EngineState, faults: FaultIn
     return jax.vmap(lambda s, f: _edge_masks(cfg, s, f))(state, faults)
 
 
-def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
-    """The MESHLESS fleet step the drivers dispatch (module docstring): one
-    protocol round for every tenant with the view change under ONE scalar
-    gate and the per-edge masks CARRIED from round to round. ``rest`` is
-    ``(*observers, faults, knobs, gate_rounds, masks)``, the round
-    programs' one convention (``models/virtual_cluster.py``). ``_compute_round``
-    is vmapped alone, over the stacked ``masks`` it is handed and under
-    :data:`FLEET_BATCH_AXIS`, so its own conditionals stay conditionals on
-    "some tenant needs the arm" (module docstring); the commit —
+def fleet_join_admit_impl(state: EngineState, idx):
+    """The rejoin discipline's lane at ``idx`` (``[m, 2]`` of ``(tenant,
+    slot)``): ``[m]`` bools, True where the slot is a member, already
+    pending or retired, so not admissible as a joiner."""
+    occupied = state.alive | state.join_pending | state.retired
+    return occupied[idx[:, 0], idx[:, 1]]
+
+
+@scope("join_predecessors")
+def fleet_join_place_impl(cfg: EngineConfig, state: EngineState, idx, width: int):
+    """``VirtualCluster.inject_join_wave``'s placement for every tenant at
+    once: ``idx`` is ``[m, 2]`` of ``(tenant, slot)``, ``width`` (static) the
+    most joiners any one tenant has in it. The pairs are laid out ``[t,
+    width]`` on the device (a stable sort by tenant; a tenant with fewer
+    joiners, or none, is padded with slot ``n``, which every scatter drops),
+    then each tenant's gatekeepers come from ``predecessor_of_keys`` over its
+    own rings and go into ``join_pending``, ``obs_idx``, ``inval_obs`` and
+    the fired-edge stamps exactly as the cluster's method writes them."""
+    n, (m, tenants) = cfg.n, (idx.shape[0], state.alive.shape[0])
+    never = compaction_policy(cfg).fire_never
+    order = jnp.argsort(idx[:, 0], stable=True)
+    tenant, slot = idx[order, 0], idx[order, 1]
+    count = jnp.zeros((tenants,), jnp.int32).at[tenant].add(1)
+    place = jnp.arange(m, dtype=jnp.int32) - (jnp.cumsum(count) - count)[tenant]
+    slots = jnp.full((tenants, width), n, jnp.int32).at[tenant, place].set(slot)
+
+    def one(state, slots):
+        at = jnp.minimum(slots, n - 1)  # a padded query reads a real key; its answer is dropped
+        pred = predecessor_of_keys(
+            state.key_hi, state.key_lo, state.alive,
+            state.key_hi[:, at], state.key_lo[:, at], perm=state.ring_perm,
+        )  # [k, width]
+        pred_n = pred.astype(state.obs_idx.dtype)
+        fired = (pred >= 0).T  # [width, k]
+        rdt = state.fire_round.dtype
+        return state._replace(
+            join_pending=state.join_pending.at[slots].set(True, mode="drop"),
+            obs_idx=state.obs_idx.at[:, slots].set(pred_n, mode="drop"),
+            inval_obs=state.inval_obs.at[:, slots].set(pred_n, mode="drop"),
+            fd_fired=state.fd_fired.at[slots].set(fired, mode="drop"),
+            fire_round=state.fire_round.at[slots].set(
+                jnp.where(fired, state.round_idx.astype(rdt), jnp.asarray(never, rdt)),
+                mode="drop",
+            ),
+        )
+
+    return jax.vmap(one)(state, slots)
+
+
+def _gated_round(cfg: EngineConfig, state: EngineState, observers, faults, knobs, masks, active=None):
+    """One protocol round for every tenant on the stacked ``masks`` it is
+    handed, with the view change under ONE scalar gate: the body the gated
+    step and the whole-wave loop share. ``_compute_round`` is vmapped alone
+    under :data:`FLEET_BATCH_AXIS`, so its own conditionals stay conditionals
+    on "some tenant needs the arm" (module docstring); the commit,
     ``apply_view_change_impl`` vmapped, the per-tenant select, then
-    ``_edge_masks`` vmapped over the committed state — sits in the taken arm
-    of ``lax.cond(any(decided))`` outside the vmap, so a round in which no
-    tenant decided runs no ring rebuild and no mask build. Per-tenant results
-    are bit-identical to :func:`fleet_step_impl` (and to B separate
-    ``VirtualCluster.step`` runs): the same functions on the same values,
-    only the place of the condition and of the build differs.
+    ``_edge_masks`` vmapped over the committed state, sits in the taken arm
+    of ``lax.cond(any(commits))`` outside the vmap, so a round in which no
+    tenant commits runs no ring rebuild and no mask build. The arm rebuilds
+    the masks for EVERY tenant: they are a pure function of ``alive``,
+    ``obs_idx`` and the faults, which a round leaves alone, so a tenant that
+    does not commit gets its old values back and no per-tenant select is
+    needed.
 
-    ``masks`` must be :func:`fleet_edge_masks_impl` of exactly ``(state,
-    faults)`` (the driver's business: ``CarriedMasks``); those returned are
-    the masks of ``(new_state, faults)``. The arm rebuilds them for EVERY
-    tenant: they are a pure function of state and faults, so an undecided
-    tenant gets its old values back and no per-tenant select is needed.
+    ``active`` (a loop's ``[t]`` bools, or ``None`` for a single step) is a
+    Python-level branch: with ``None`` every tenant that decided commits and
+    not one traced operation is added. With a lane, a tenant outside it is
+    FROZEN: it does not commit, and its state and observer lanes come back
+    as they went in (its masks with them, by the rule above).
 
-    ``gate_rounds`` is the device-carried ``int32[3]`` behind
-    :data:`GATE_ROUND_COUNTERS`: the rounds in which the view-change gate
-    opened and those in which ``invalidation`` and ``classic`` ran, fetched
-    only at the driver's host-sync boundaries.
-
-    Returns ``(state, *observers, gate_rounds, masks, events)``."""
-    *observers, faults, knobs, gate_rounds, masks = rest
+    Returns ``(state, observers, masks, commits[t], events, gates)``, the
+    last ``int32[3]`` in :data:`GATE_ROUND_COUNTERS`' order: whether the
+    view-change gate opened, and whether ``invalidation`` and ``classic``
+    ran, in this round."""
 
     def one_round(state, faults, kn, masks, *observers):
         return _compute_round(
@@ -274,30 +335,63 @@ def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
         )
 
     # the round's last output, which arms ran, is one value for the fleet
-    round_state, decided, winner, events, *observers, arms_ran = jax.vmap(
+    round_state, decided, winner, events, *round_observers, arms_ran = jax.vmap(
         one_round, axis_name=FLEET_BATCH_AXIS,
         out_axes=(*(0,) * (4 + len(observers)), None),
     )(state, faults, knobs, masks, *observers)
+    commits = decided if active is None else decided & active
 
-    def commit_one(kn, round_state, winner, decided):
+    def commit_one(kn, round_state, winner, commits):
         committed = apply_view_change_impl(_tenant_cfg(cfg, kn), round_state, winner)
         with scope("view_change"):
             return jax.tree_util.tree_map(
-                lambda com, rnd: jnp.where(decided, com, rnd), committed, round_state
+                lambda com, rnd: jnp.where(commits, com, rnd), committed, round_state
             )
 
     def commit(s):
-        committed = jax.vmap(commit_one)(knobs, s, winner, decided)
+        committed = jax.vmap(commit_one)(knobs, s, winner, commits)
         return committed, fleet_edge_masks_impl(cfg, committed, faults)
 
-    any_decided = jnp.any(decided)
+    any_commits = jnp.any(commits)
     new_state, masks = jax.lax.cond(
-        any_decided, commit, scope("view_keep")(lambda s: (s, masks)), round_state
+        any_commits, commit, scope("view_keep")(lambda s: (s, masks)), round_state
     )
-    gate_rounds = gate_rounds + jnp.concatenate(
-        [any_decided.astype(jnp.int32)[None], arms_ran]
+    gates = jnp.concatenate([any_commits.astype(jnp.int32)[None], arms_ran])
+    if active is not None:
+        new_state, round_observers = jax.vmap(
+            lambda on, new, old: jax.tree_util.tree_map(
+                lambda n, o: jnp.where(on, n, o), new, old
+            )
+        )(active, (new_state, round_observers), (state, list(observers)))
+    return new_state, round_observers, masks, commits, events, gates
+
+
+def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
+    """The MESHLESS fleet step the drivers dispatch (module docstring): one
+    protocol round for every tenant with the view change under ONE scalar
+    gate and the per-edge masks CARRIED from round to round
+    (:func:`_gated_round`). ``rest`` is ``(*observers, faults, knobs,
+    gate_rounds, masks)``, the round programs' one convention
+    (``models/virtual_cluster.py``). Per-tenant results are bit-identical to
+    :func:`fleet_step_impl` (and to B separate ``VirtualCluster.step``
+    runs): the same functions on the same values, only the place of the
+    condition and of the build differs.
+
+    ``masks`` must be :func:`fleet_edge_masks_impl` of exactly ``(state,
+    faults)`` (the driver's business: ``CarriedMasks``); those returned are
+    the masks of ``(new_state, faults)``.
+
+    ``gate_rounds`` is the device-carried ``int32[3]`` behind
+    :data:`GATE_ROUND_COUNTERS`: the rounds in which the view-change gate
+    opened and those in which ``invalidation`` and ``classic`` ran, fetched
+    only at the driver's host-sync boundaries.
+
+    Returns ``(state, *observers, gate_rounds, masks, events)``."""
+    *observers, faults, knobs, gate_rounds, masks = rest
+    new_state, observers, masks, _, events, gates = _gated_round(
+        cfg, state, observers, faults, knobs, masks
     )
-    return (new_state, *observers, gate_rounds, masks, events)
+    return (new_state, *observers, gate_rounds + gates, masks, events)
 
 
 def fleet_run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
@@ -334,13 +428,102 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
     """The fleet's whole-wave loop: every tenant runs convergences through
     MULTIPLE view changes until its own ``target`` membership (at least its
     own ``min_cuts`` cuts), all in one dispatch — the batched twin of
-    ``run_until_membership_impl``, restructured LOCKSTEP (module docstring):
-    one flat ``fori_loop`` over the shared step budget, each iteration one
-    engine round per tenant with the view change select-applied and
-    finished tenants frozen in place. Per-tenant results are bit-identical
-    to the nested per-cluster loop — the same ``_compute_round`` /
-    ``apply_view_change_impl`` sequence on the same values, only the loop
-    skeleton differs (pinned by tests/test_tenancy.py's differential grid).
+    ``run_until_membership_impl`` at the gated step's price. A
+    ``while_loop`` over :func:`_gated_round` that ends when no tenant is
+    active (or at ``max_steps``): the per-edge masks are built once before
+    the loop and again only in the view-change arm, the view change runs in
+    the rounds in which some active tenant decided, and the round's
+    ``deliver`` / ``invalidation`` / ``classic`` arms in the rounds in which
+    some tenant needs them (module docstring). A tenant that has resolved,
+    or has spent its cuts or its steps, is frozen in place by the round's
+    ``active`` lane until the slowest one is done. Per-tenant results are
+    bit-identical to the nested per-cluster loop — the same
+    ``_compute_round`` / ``apply_view_change_impl`` sequence on the same
+    values, only the loop skeleton differs (pinned by tests/test_tenancy.py's
+    differential grid).
+
+    ``rest`` is ``(*observers, faults, knobs, target, max_steps, max_cuts,
+    min_cuts)``. The observers' lanes are frozen by the SAME ``active`` lane
+    that freezes a finished tenant's state: a tenant that coasts after
+    resolving accumulates no phantom rounds, its ring's cursor holds still
+    and its slots are never overwritten (quarantined tenants — done from
+    iteration 0 — record nothing), so counters and decoded ring stay
+    bit-identical to a per-cluster ``run_until_membership`` drive (pinned
+    with the state parity in tests/test_telemetry_plane.py and
+    tests/test_trace_ring.py). The loop's predicate and its gates reduce
+    over the tenants, so this is a SINGLE-DEVICE program like the gated step
+    and the fused decision; a ``'tenant'``-sharded mesh takes
+    :func:`fleet_wave_lockstep_impl`.
+
+    Returns ``(state, *observers, steps[t], cuts[t], resolved[t],
+    sizes[t, max_cuts], loop_rounds)``, the last ``int32[4]``: the lockstep
+    rounds the loop ran (the slowest tenant's count), then, in
+    :data:`GATE_ROUND_COUNTERS`' order, those in which the view-change gate
+    opened and in which ``invalidation`` and ``classic`` ran.
+    """
+    *observers, faults, knobs, target, max_steps, max_cuts, min_cuts = rest
+    tenants = target.shape[0]
+
+    def active_of(steps, done):
+        return ~done & (steps < max_steps)
+
+    def cond(carry):
+        *_, steps, _, _, done, _ = carry
+        return jnp.any(active_of(steps, done))
+
+    def body(carry):
+        state, *observers, masks, steps, cuts, sizes, done, loop_rounds = carry
+        active = active_of(steps, done)
+        state, observers, masks, commits, _, gates = _gated_round(
+            cfg, state, observers, faults, knobs, masks, active
+        )
+        with scope("loop_result"):
+            steps = steps + active.astype(jnp.int32)
+            sizes = jnp.where(
+                commits[:, None],
+                jax.vmap(lambda row, at, members: row.at[at].set(members))(
+                    sizes, cuts, state.n_members
+                ),
+                sizes,
+            )
+            cuts = cuts + commits.astype(jnp.int32)
+            resolved = (state.n_members == target) & (cuts >= min_cuts)
+            done = done | (commits & resolved) | (cuts >= max_cuts)
+            loop_rounds = loop_rounds + jnp.concatenate(
+                [jnp.ones((1,), jnp.int32), gates]
+            )
+        return (state, *observers, masks, steps, cuts, sizes, done, loop_rounds)
+
+    init = (
+        state,
+        *observers,
+        fleet_edge_masks_impl(cfg, state, faults),
+        jnp.zeros((tenants,), jnp.int32),
+        jnp.zeros((tenants,), jnp.int32),
+        jnp.full((tenants, max_cuts), -1, dtype=jnp.int32),
+        # The equal-churn trap guard, same as the nested loop's entry
+        # condition: already-at-target only resolves vacuously when no cuts
+        # are demanded.
+        (state.n_members == target) & (min_cuts <= 0),
+        jnp.zeros((1 + len(GATE_ROUND_COUNTERS),), jnp.int32),
+    )
+    state, *observers, _, steps, cuts, sizes, _, loop_rounds = jax.lax.while_loop(
+        cond, body, init
+    )
+    with scope("loop_result"):
+        resolved = (state.n_members == target) & (cuts >= min_cuts)
+    return (state, *observers, steps, cuts, resolved, sizes, loop_rounds)
+
+
+def fleet_wave_lockstep_impl(cfg: EngineConfig, state: EngineState, *rest):
+    """The whole-wave loop for a ``'tenant'``-sharded MESH
+    (:func:`make_fleet_wave`; the drivers dispatch :func:`fleet_wave_impl`),
+    restructured LOCKSTEP (module docstring): one flat ``fori_loop`` over
+    the shared step budget, each iteration one engine round per tenant with
+    the masks built anew, the view change select-applied and finished
+    tenants frozen in place, so that nothing in it reduces across tenants.
+    Per-tenant results are bit-identical to the nested per-cluster loop and
+    to :func:`fleet_wave_impl`.
 
     ``rest`` is ``(*observers, faults, knobs, target, max_steps, max_cuts,
     min_cuts)``. The observers' lanes are select-gated by the SAME
@@ -480,6 +663,12 @@ tenant_health = jax.jit(tenant_health_impl, static_argnums=(0,))  # donate-ok: r
 #: The build program: dispatched by the driver only when the masks it
 #: carries are not those of the inputs it is about to pass.
 fleet_edge_masks = jax.jit(fleet_edge_masks_impl, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
+fleet_join_admit = jax.jit(fleet_join_admit_impl)  # donate-ok: reads three lanes of a state that stays live
+#: The join wave's placement: the state is donated (five lanes are written in
+#: place, the rest pass through), ``width`` is static.
+fleet_join_place = jax.jit(
+    fleet_join_place_impl, static_argnums=(0, 3), donate_argnums=(1,)
+)
 #: A fleet verb's programs by observer count, like the cluster's
 #: ``_ROUND_PROGRAMS`` (the knobs ride after the faults). The step's
 #: gate-round counters and carried masks (its last two arguments) are donated
@@ -529,7 +718,7 @@ def make_fleet_wave(cfg: EngineConfig, mesh: Mesh, max_cuts: int = 8):
 
     return jax.jit(
         lambda state, faults, knobs, target, max_steps, min_cuts: (
-            fleet_wave_impl(
+            fleet_wave_lockstep_impl(
                 cfg, state, faults, knobs, target, max_steps, max_cuts,
                 min_cuts,
             )
@@ -553,11 +742,14 @@ class TenantFleet(DispatchSeam):
     :class:`DispatchSeam` — one phase vocabulary across every driver).
 
     Construction is by stacking ordinary per-tenant ``VirtualCluster``
-    builds (:meth:`from_clusters`) — every injection seam (crash, join
-    wave, rx-block, cohort assignment) stays the single-cluster API, run
-    per tenant BEFORE stacking; the fleet then steps all of them per
-    dispatch. ``tests/test_tenancy.py`` pins that this round-trip is
-    bit-identical to driving the B clusters separately."""
+    builds (:meth:`from_clusters`) — the rx-block, flaky-edge and cohort
+    seams stay the single-cluster API, run per tenant BEFORE stacking; the
+    fleet then steps all of them per dispatch. ``tests/test_tenancy.py``
+    pins that this round-trip is bit-identical to driving the B clusters
+    separately. Crashes and join waves also reach the STACKED state
+    (:meth:`stream_crash`, :meth:`inject_join_wave`: ``(tenant, slot)``
+    pairs, device-side), bit-identical to the cluster's methods run per
+    tenant before stacking (``tests/test_fleet_joins.py``)."""
 
     def __init__(
         self,
@@ -772,28 +964,66 @@ class TenantFleet(DispatchSeam):
             self._carried.keep(self, masks)
         return events
 
+    def _pair_index(self, pairs) -> jnp.ndarray:
+        """Host-side bounds check of ``(tenant, slot)`` pairs, then upload:
+        jnp gathers and scatters CLAMP out-of-range indices, which would
+        silently touch tenant b-1 / slot n-1 on a typo."""
+        arr = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
+        if arr.size and (
+            arr[:, 0].min() < 0 or arr[:, 0].max() >= self.b
+            or arr[:, 1].min() < 0 or arr[:, 1].max() >= self.cfg.n
+        ):
+            raise IndexError(
+                f"(tenant, slot) pairs out of range [0, {self.b}) x "
+                f"[0, {self.cfg.n}): {arr.tolist()}"
+            )
+        self._account_h2d(arr)
+        return jnp.asarray(arr)
+
     def stream_crash(self, pairs) -> None:
         """Crash ``(tenant, slot)`` pairs mid-stream: one device-side
         scatter onto the stacked crash mask — only the [m, 2] int32 index
         array crosses the host->device boundary, and the update enqueues
-        behind the in-flight dispatches (no fetch, no sync). Host-side
-        bounds check first: jnp scatters CLAMP out-of-range indices, which
-        would silently crash tenant b-1 / slot n-1 on a typo."""
+        behind the in-flight dispatches (no fetch, no sync)."""
         with self._dispatch("inject_crash"):
-            arr = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
-            if arr.size and (
-                arr[:, 0].min() < 0 or arr[:, 0].max() >= self.b
-                or arr[:, 1].min() < 0 or arr[:, 1].max() >= self.cfg.n
-            ):
-                raise IndexError(
-                    f"(tenant, slot) pairs out of range [0, {self.b}) x "
-                    f"[0, {self.cfg.n}): {arr.tolist()}"
-                )
-            self._account_h2d(arr)
-            idx = jnp.asarray(arr)
+            idx = self._pair_index(pairs)
             self.faults = self.faults._replace(
                 crashed=self.faults.crashed.at[idx[:, 0], idx[:, 1]].set(True)
             )
+
+    def inject_join_wave(self, pairs, check_admissible: bool = True) -> None:
+        """Admit ``(tenant, slot)`` joiners into the STACKED state: the
+        batched ``VirtualCluster.inject_join_wave`` (its docstring has the
+        protocol and the rejoin discipline), bit-identical to calling it on
+        every tenant before stacking. Tenants may have different numbers of
+        joiners in one call, or none. Only the ``[m, 2]`` index array
+        crosses to the device; with ``check_admissible`` one fetch of
+        ``[m]`` bools comes back (``inject_join_admit``), and the placement
+        (``inject_join_place``: every joiner's gatekeepers on every ring,
+        the scatters and the fired-edge stamps, one program) enqueues
+        without a fetch. The state comes back as new arrays, so the carried
+        masks are rebuilt before the next step by ``CarriedMasks``' own
+        identity rule."""
+        pairs = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
+        idx = None
+        if check_admissible:
+            with self._dispatch("inject_join_admit"):
+                idx = self._pair_index(pairs)
+                bad = np.asarray(fleet_join_admit(self.state, idx))
+            self._account_d2h(bad.nbytes)
+            if bad.any():
+                raise ValueError(
+                    f"(tenant, slot) pairs not admissible as joiners "
+                    f"(member/pending/retired): {pairs[bad].tolist()}"
+                )
+        with self._dispatch("inject_join_place"):
+            if idx is None:
+                idx = self._pair_index(pairs)
+            if len(pairs):
+                # the one host-side number the layout needs: the most joiners
+                # a tenant has in this call (a new value is a new program)
+                width = int(np.bincount(pairs[:, 0]).max())
+                self.state = fleet_join_place(self.cfg, self.state, idx, width)
 
     def run_to_decision(self, max_steps: int = 64):
         """Every tenant runs to its own first view change in one dispatch;
@@ -831,9 +1061,13 @@ class TenantFleet(DispatchSeam):
     ):
         """The fleet wave: every tenant resolves its own churn — through
         its own number of view changes — to its own target membership, in
-        ONE lockstep dispatch. ``targets``/``min_cuts`` broadcast from
+        ONE dispatch that ends when the slowest tenant is done
+        (:func:`fleet_wave_impl`). ``targets``/``min_cuts`` broadcast from
         scalars or give one value per tenant. Returns ``(rounds[t],
-        cuts[t], resolved[t], sizes[t, max_cuts])`` as host arrays."""
+        cuts[t], resolved[t], sizes[t, max_cuts])`` as host arrays; the same
+        fetch brings the lockstep rounds the loop ran
+        (``engine_fleet_wave_rounds``) and those in which its gates opened
+        (:data:`GATE_ROUND_COUNTERS`)."""
         targets = np.broadcast_to(
             np.asarray(targets, dtype=np.int32), (self.b,)
         ).copy()
@@ -859,23 +1093,27 @@ class TenantFleet(DispatchSeam):
             )
         self._account_h2d(targets, min_cuts)
         with self._dispatch("fleet_wave"):
-            steps, cuts, resolved, sizes = self._advance(
+            steps, cuts, resolved, sizes, loop_rounds = self._advance(
                 "wave", jnp.asarray(targets), jnp.int32(max_steps),
                 jnp.asarray(min_cuts), max_cuts=int(max_cuts),
             )
             obs = np.asarray(
                 jnp.concatenate(
-                    [steps, cuts, resolved.astype(jnp.int32), sizes.reshape(-1)]
+                    [steps, cuts, resolved.astype(jnp.int32), sizes.reshape(-1),
+                     loop_rounds]
                 )
             )
         self._account_d2h(obs.nbytes)
+        self._refresh_gate_rounds()
         b = self.b
-        rounds, n_cuts = obs[:b], obs[b : 2 * b]
-        resolved_h = obs[2 * b : 3 * b].astype(bool)
-        sizes_h = obs[3 * b :].reshape(b, max_cuts)
+        rounds, n_cuts, resolved_h, sizes_h, loop_rounds = np.split(
+            obs, [b, 2 * b, 3 * b, (3 + max_cuts) * b]
+        )
         self.metrics.inc("engine_tenant_rounds", int(rounds.sum()))
         self.metrics.inc("engine_tenant_cuts", int(n_cuts.sum()))
-        return rounds, n_cuts, resolved_h, sizes_h
+        for name, ran in zip(("engine_fleet_wave_rounds", *GATE_ROUND_COUNTERS), loop_rounds):
+            self.metrics.inc(name, int(ran))
+        return rounds, n_cuts, resolved_h.astype(bool), sizes_h.reshape(b, max_cuts)
 
     def sync(self) -> None:
         """Complete all pending uploads/compute on the fleet state."""
@@ -1126,6 +1364,9 @@ class TenantFleet(DispatchSeam):
                     ),
                     "fleet_classic_rounds_total": int(
                         counters.get("engine_fleet_classic_rounds", 0)
+                    ),
+                    "fleet_wave_rounds_total": int(
+                        counters.get("engine_fleet_wave_rounds", 0)
                     ),
                     "tenant_rounds_per_dispatch": round(
                         tenant_rounds / dispatches, 3

@@ -110,6 +110,10 @@ TENANCY_KNOWN_COUNTERS = (
     # classic fallback was due), by the gated step and the fused decision.
     "engine_fleet_invalidation_rounds",
     "engine_fleet_classic_rounds",
+    # Lockstep rounds the fleet's whole-wave loops ran (the slowest tenant's
+    # count, wave by wave): the wave's share of the three counters above
+    # over this is the share of its rounds that paid each arm.
+    "engine_fleet_wave_rounds",
 )
 
 #: Streaming-tier counters zero-filled on snapshots whose ``engine`` section

@@ -333,6 +333,8 @@ GOLDEN_FLEET_METRIC_NAMES = sorted(
         # (ISSUE 30).
         "rapid_engine_fleet_invalidation_rounds_total",
         "rapid_engine_fleet_classic_rounds_total",
+        # Lockstep rounds the whole-wave loops ran (ISSUE 33).
+        "rapid_engine_fleet_wave_rounds_total",
         "rapid_engine_tenant_rounds_per_dispatch",
         "rapid_engine_tenants",
         # Quarantine census (ISSUE 15): the zero-filled cumulative counter

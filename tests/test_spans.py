@@ -44,7 +44,7 @@ CLUSTER_VERBS = {"step": "engine_step_carried", "decision": "run_to_decision",
 FLEET_VERBS = {"step": "fleet_step", "decision": "fleet_run_to_decision", "wave": "fleet_wave"}
 EXPECTED = {
     name + suffix: ROUND | ({"observers"} if level else set())
-    | ({"loop_result"} if name == "run_until_membership" else set())
+    | ({"loop_result"} if name in ("run_until_membership", "fleet_wave") else set())
     for name in (*CLUSTER_VERBS.values(), *FLEET_VERBS.values())
     for level, suffix in enumerate(LEVELS)
 }
@@ -53,6 +53,7 @@ EXPECTED.update({
     "edge_masks_build": {"edge_masks"},
     "fleet_edge_masks": {"edge_masks"},
     "mesh_fleet_step": ROUND,
+    "mesh_fleet_wave": ROUND,
     "mesh_step": ROUND,
     "engine_step_trace": ROUND | {"observers"},
     "sync_checksum": {"sync_checksum"},
@@ -101,6 +102,7 @@ def lowered():
     fleet_masks = jax.eval_shape(
         fleetm.fleet_edge_masks, fleet.cfg, fleet.state, fleet.faults)
     mesh = make_mesh(jax.devices()[:4], shape=(1, 4))  # cluster-10m's layout
+    mesh3d = make_mesh(jax.devices()[:8], shape=(2, 2, 2))
     out = {}
     # every (verb, observer count) of both drivers, as the driver's _advance
     # hands the arguments over: carried pytrees, faults (and knobs), controls
@@ -121,9 +123,10 @@ def lowered():
         "edge_masks_build": vcm.edge_masks_build.lower(vc.cfg, s, vc.faults),
         "fleet_edge_masks": fleetm.fleet_edge_masks.lower(
             fleet.cfg, fleet.state, fleet.faults),
-        "mesh_fleet_step": fleetm.make_fleet_step(
-            fleet.cfg, make_mesh(jax.devices()[:8], shape=(2, 2, 2)),
-        ).lower(fleet.state, fleet.faults, fleet.knobs),
+        "mesh_fleet_step": fleetm.make_fleet_step(fleet.cfg, mesh3d).lower(
+            fleet.state, fleet.faults, fleet.knobs),
+        "mesh_fleet_wave": fleetm.make_fleet_wave(fleet.cfg, mesh3d, max_cuts=4).lower(
+            fleet.state, fleet.faults, fleet.knobs, per_tenant[0], i32(16), per_tenant[1]),
         "mesh_step": make_sharded_step(vc.cfg, mesh).lower(s, vc.faults),
         "mesh_run_to_decision": sharded_program("decision", vc.cfg, mesh).lower(
             s, vc.faults, i32(16)),
@@ -182,7 +185,8 @@ GATED_ARMS = ("deliver", "invalidation", "classic")
 
 
 @pytest.mark.parametrize("program", [
-    name + suffix for name in ("fleet_step", "fleet_run_to_decision") for suffix in LEVELS])
+    name + suffix for name in ("fleet_step", "fleet_run_to_decision", "fleet_wave")
+    for suffix in LEVELS])
 def test_the_meshless_fleet_programs_keep_the_rounds_conditionals(lowered, program):
     # under the named batch axis the round's three conditionals stay
     # conditionals (taken when some tenant needs the arm): every operation
@@ -197,20 +201,23 @@ def test_the_meshless_fleet_programs_keep_the_rounds_conditionals(lowered, progr
         assert not any("vmap(%s)" % arm in p for p in paths), arm
 
 
-@pytest.mark.parametrize("program", ["mesh_fleet_step", "fleet_wave"])
+@pytest.mark.parametrize("program", ["mesh_fleet_step", "mesh_fleet_wave"])
 def test_the_programs_that_name_no_batch_axis_keep_the_select(lowered, program):
-    # the mesh's step and the lockstep wave (no cell runs it): both arms of
-    # every conditional of the round, for every tenant, in every round
+    # the mesh's step and its lockstep wave (the drivers' wave is gated
+    # since PR 33): both arms of every conditional of the round, for every
+    # tenant, in every round
     paths = _paths(lowered[program])
     for arm in GATED_ARMS:
         under = {p for p in paths if re.search(r"(?:vmap\(|/)%s\)?(?:/|$)" % arm, p)}
         assert under and not any("cond/" in p for p in under), arm
 
 
-def test_the_meshless_fleet_step_gates_its_view_change_on_one_conditional(lowered):
-    # the step the drivers dispatch: the vmapped view change lies under one
-    # arm of one scalar conditional taken outside the vmap, and nowhere else
-    paths = _paths(lowered["fleet_step"])
+@pytest.mark.parametrize("program", ["fleet_step", "fleet_wave"])
+def test_the_meshless_fleet_programs_gate_the_view_change_on_one_conditional(lowered, program):
+    # the step and the whole-wave loop the drivers dispatch: the vmapped view
+    # change lies under one arm of one scalar conditional taken outside the
+    # vmap, and nowhere else
+    paths = _paths(lowered[program])
     view_change = {p for p in paths if "view_change" in p}
     arms = {re.search(r"(?:^|/)cond/(branch_\d+_fun)/vmap\(view_change\)/", p) for p in view_change}
     assert view_change and None not in arms
@@ -219,6 +226,19 @@ def test_the_meshless_fleet_step_gates_its_view_change_on_one_conditional(lowere
     # the conditionals inside the vmap are the round's own
     outside = [p for p in paths if "cond/" in p and "/vmap(" not in p.split("cond/")[0]]
     assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(outside)))
+
+
+def test_the_wave_builds_its_masks_before_the_loop_and_in_the_cuts_arm_only(lowered):
+    # one build at the top level of the program, one under the view change's
+    # arm inside the while, none in the body proper: a round that commits
+    # nothing builds no masks (the mesh's lockstep wave builds them in every
+    # iteration)
+    builds = {p for p in _paths(lowered["fleet_wave"]) if "edge_masks" in p}
+    in_loop = {p for p in builds if "while/body" in p}
+    assert builds - in_loop and in_loop
+    assert all(re.search(r"while/body/(?:\w+/)*cond/branch_\d+_fun/vmap\(edge_masks\)/", p) for p in in_loop)
+    lockstep = {p for p in _paths(lowered["mesh_fleet_wave"]) if "edge_masks" in p}
+    assert lockstep and not any("cond/" in p for p in lockstep)
 
 
 @pytest.mark.parametrize("program", ["engine_step_carried", "fleet_step"])
