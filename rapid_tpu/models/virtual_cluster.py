@@ -775,13 +775,15 @@ def _view_change_gate_masks(
     cfg: EngineConfig, state: EngineState, faults: FaultInputs, masks,
     decided, winner_mask,
 ):
-    """The gate for a body that carries the per-edge masks beside the
-    state: the view change AND the mask rebuild ride one cond. Topology
-    (and with it the observer-active/delivery masks) changes ONLY when a cut
-    commits, so the rebuild's pack + permutation gathers are per-CUT work,
-    gated exactly like the ring rebuild — never unconditional hot-loop
-    traffic (the compiled-program gate pins this: the wave's hot loop stays
-    reduce-class on both the 1-D and the 2-D mesh). Returns ``(state,
+    """The gate for the per-round step, which hands the per-edge masks
+    back to its driver beside the state: the view change AND the mask
+    rebuild ride one cond. Topology (and with it the observer-active/delivery
+    masks) changes ONLY when a cut commits, so the rebuild's pack +
+    permutation gathers are per-CUT work, gated exactly like the ring
+    rebuild, never unconditional per-round traffic; the rounds that follow
+    the cut inside a streamed wave read what the arm built. (The whole-wave
+    loop carries no masks across a commit: it builds at the head of each
+    convergence, :func:`run_until_membership_impl`.) Returns ``(state,
     masks)``, the masks those of the returned state and ``faults``."""
 
     def commit(s):
@@ -835,11 +837,11 @@ def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest):
     function of ``alive``, ``obs_idx``, ``crashed`` and ``rx_block``; the
     round leaves those four alone and a committed cut changes the first two,
     so the taken arm of the view-change gate rebuilds them for the committed
-    state — what the fused loops do — and the other arm hands
-    them back. The masks returned are those of ``(new_state,
-    faults)``. Per round the state, events and observer lanes are
-    bit-identical to :func:`engine_step_impl`'s: the same functions on the
-    same values, only the place of the build differs.
+    state and the other arm hands them back. The masks returned are those
+    of ``(new_state, faults)``, for the driver's next round. Per round the
+    state, events and observer lanes are bit-identical to
+    :func:`engine_step_impl`'s: the same functions on the same values, only
+    the place of the build differs.
 
     Returns ``(state, *observers, events, masks)``."""
     *observers, faults, masks = rest
@@ -1005,11 +1007,14 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
     wave instead of one per cut. ``rest`` is ``(*observers, faults, target,
     max_steps, max_cuts, min_cuts)``.
 
-    Structure: an outer loop of convergences, each of which (a) runs the
-    same sort-free inner round loop as ``run_to_decision_impl`` over the
-    hoisted per-edge masks, and (b) applies the view change WITH the
-    per-edge mask rebuild inside the same lax.cond
-    (:func:`_view_change_gate_masks`). Each dispatch+fetch pair costs a host
+    Structure: an outer loop of convergences, each of which (a) builds the
+    per-edge masks of the topology it starts from, (b) runs the same
+    sort-free inner round loop as ``run_to_decision_impl`` over them, and
+    (c) applies the view change under :func:`_view_change_gate`. The masks
+    are built where they are first read and not where the topology changes:
+    one build a convergence, outside the inner round loop, and none after
+    the wave's last commit (nor in a wave that is resolved at entry), which
+    no round follows. Each dispatch+fetch pair costs a host
     round trip, so resolving a 2-cut churn or a bootstrap admission wave in
     one dispatch removes that many from the measured wall clock. The
     observers accumulate ACROSS the wave's view changes — a commit never
@@ -1030,18 +1035,21 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
     *observers, faults, target, max_steps, max_cuts, min_cuts = rest
 
     def outer_cond(carry):
-        state, *_, steps, cuts, stalled, _, _ = carry
+        state, *_, steps, cuts, stalled, _ = carry
         resolved = (state.n_members == target) & (cuts >= min_cuts)
         return (~resolved) & (~stalled) & (steps < max_steps) & (cuts < max_cuts)
 
     def outer_body(carry):
-        state, *observers, steps, cuts, _, sizes, masks = carry
+        state, *observers, steps, cuts, _, sizes = carry
+        # Built here, where the convergence reads them, and not in the cut's
+        # arm: every iteration starts from a topology no build has seen (the
+        # wave's first, or the one a commit just left), and the wave's last
+        # commit is followed by no round that would read a rebuild.
+        masks = _edge_masks(cfg, state, faults)
         state, observers, steps, decided, winner, _ = _converge(
             cfg, state, observers, faults, masks, steps, max_steps
         )
-        state, masks = _view_change_gate_masks(
-            cfg, state, faults, masks, decided, winner
-        )
+        state = _view_change_gate(cfg, state, decided, winner)
         with scope("loop_result"):
             sizes = jnp.where(
                 decided, sizes.at[cuts].set(state.n_members), sizes
@@ -1050,7 +1058,7 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
         # progress (the outer loop would spin): latch and exit.
         return (
             state, *observers, steps, cuts + decided.astype(jnp.int32),
-            ~decided, sizes, masks,
+            ~decided, sizes,
         )
 
     init = (
@@ -1060,9 +1068,8 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
         jnp.int32(0),
         jnp.bool_(False),
         jnp.full((max_cuts,), -1, dtype=jnp.int32),
-        _edge_masks(cfg, state, faults),
     )
-    state, *observers, steps, cuts, _, sizes, _ = jax.lax.while_loop(
+    state, *observers, steps, cuts, _, sizes = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
     with scope("loop_result"):

@@ -43,7 +43,8 @@ Batched-control-flow tradeoffs, stated plainly:
   carries the stacked per-edge masks from round to round and rebuilds them
   in that arm only (ledger, PR 27: 54 of a 100 ms round went into building
   them anew in every round); ``engine_edge_mask_builds`` counts the builds
-  the driver dispatches because its inputs changed under it. On a
+  the driver dispatches because its inputs changed under it (and those the
+  whole-wave loop runs after a commit, below). On a
   ``'tenant'``-sharded mesh that any() is a cross-tenant reduce, which the
   zero-cross-tenant budget forbids, so :func:`fleet_step_impl` (behind
   :func:`make_fleet_step` and the analyzers' ladder) keeps the lockstep
@@ -83,13 +84,17 @@ Batched-control-flow tradeoffs, stated plainly:
   (:func:`fleet_wave_impl`, ``TenantFleet.run_until_membership``) pays none
   of that: a ``while_loop`` over the gated step's own round
   (:func:`_gated_round`) that ends when no tenant is active, on masks built
-  before the loop and rebuilt only in the view-change arm; a bootstrap wave
-  of three rounds and one cut runs three rounds, one view change and two
-  mask builds, not 192 of each. ``engine_fleet_wave_rounds`` counts the
-  lockstep rounds such loops ran, and the three gate counters take the
-  loop's share. (``fleet_run_to_decision`` is the same kind of program: a
-  dynamic batched while for single-device driver use, where there is no
-  mesh and the any() is free.)
+  before the loop and rebuilt only at the head of a round that follows a
+  commit: where they are next read, not where the topology changed, because
+  the loop may end with that commit (the step, whose driver reads them in
+  its next round, keeps the rebuild in the view-change arm). A bootstrap
+  wave of three rounds and one cut runs three rounds, one view change and
+  one mask build, not 192 of each. ``engine_fleet_wave_rounds`` counts the
+  lockstep rounds such loops ran, the three gate counters take the loop's
+  share, and ``engine_edge_mask_builds`` the builds it ran after a commit
+  (0 for a wave that lands in one cut). (``fleet_run_to_decision`` is the
+  same kind of program: a dynamic batched while for single-device driver
+  use, where there is no mesh and the any() is free.)
 - joins reach a stacked fleet through :meth:`TenantFleet.inject_join_wave`:
   ``(tenant, slot)`` pairs, padded per tenant on the device and placed by
   ``predecessor_of_keys`` vmapped over the tenant axis
@@ -169,6 +174,13 @@ GATE_ROUND_COUNTERS = (
     "engine_fleet_commit_rounds",
     "engine_fleet_invalidation_rounds",
     "engine_fleet_classic_rounds",
+)
+
+#: The counters behind the whole-wave loop's ``loop_rounds`` vector, in its
+#: order: the lockstep rounds it ran, its share of the three gate counters,
+#: and the mask builds it ran at the head of a round that followed a commit.
+WAVE_LOOP_COUNTERS = (
+    "engine_fleet_wave_rounds", *GATE_ROUND_COUNTERS, "engine_edge_mask_builds",
 )
 
 #: Partition rules for the fleet-level knob pytree, in the exact
@@ -302,20 +314,30 @@ def fleet_join_place_impl(cfg: EngineConfig, state: EngineState, idx, width: int
     return jax.vmap(one)(state, slots)
 
 
-def _gated_round(cfg: EngineConfig, state: EngineState, observers, faults, knobs, masks, active=None):
+def _gated_round(
+    cfg: EngineConfig, state: EngineState, observers, faults, knobs, masks,
+    active=None, rebuild_masks=True,
+):
     """One protocol round for every tenant on the stacked ``masks`` it is
     handed, with the view change under ONE scalar gate: the body the gated
     step and the whole-wave loop share. ``_compute_round`` is vmapped alone
     under :data:`FLEET_BATCH_AXIS`, so its own conditionals stay conditionals
     on "some tenant needs the arm" (module docstring); the commit,
-    ``apply_view_change_impl`` vmapped, the per-tenant select, then
-    ``_edge_masks`` vmapped over the committed state, sits in the taken arm
-    of ``lax.cond(any(commits))`` outside the vmap, so a round in which no
-    tenant commits runs no ring rebuild and no mask build. The arm rebuilds
-    the masks for EVERY tenant: they are a pure function of ``alive``,
-    ``obs_idx`` and the faults, which a round leaves alone, so a tenant that
-    does not commit gets its old values back and no per-tenant select is
-    needed.
+    ``apply_view_change_impl`` vmapped and the per-tenant select, sits in the
+    taken arm of ``lax.cond(any(commits))`` outside the vmap, so a round in
+    which no tenant commits runs no ring rebuild.
+
+    ``rebuild_masks`` is a Python-level branch, the caller's: who reads the
+    masks after this round. The step hands them to its driver, whose next
+    rounds read them, so (``True``) ``_edge_masks`` vmapped over the
+    committed state rides the same arm, and the masks returned are those of
+    the state returned. The arm rebuilds them for EVERY tenant: they are a
+    pure function of ``alive``, ``obs_idx`` and the faults, which a round
+    leaves alone, so a tenant that does not commit gets its old values back
+    and no per-tenant select is needed. A loop may end with this round, so
+    (``False``) the arm builds nothing and the masks come back as they went
+    in: STALE whenever ``gates[0]`` is set, for the loop to rebuild at the
+    head of a round that will read them.
 
     ``active`` (a loop's ``[t]`` bools, or ``None`` for a single step) is a
     Python-level branch: with ``None`` every tenant that decided commits and
@@ -349,13 +371,22 @@ def _gated_round(cfg: EngineConfig, state: EngineState, observers, faults, knobs
             )
 
     def commit(s):
-        committed = jax.vmap(commit_one)(knobs, s, winner, commits)
+        return jax.vmap(commit_one)(knobs, s, winner, commits)
+
+    def commit_and_build(s):
+        committed = commit(s)
         return committed, fleet_edge_masks_impl(cfg, committed, faults)
 
     any_commits = jnp.any(commits)
-    new_state, masks = jax.lax.cond(
-        any_commits, commit, scope("view_keep")(lambda s: (s, masks)), round_state
-    )
+    if rebuild_masks:
+        new_state, masks = jax.lax.cond(
+            any_commits, commit_and_build,
+            scope("view_keep")(lambda s: (s, masks)), round_state,
+        )
+    else:
+        new_state = jax.lax.cond(
+            any_commits, commit, scope("view_keep")(lambda s: s), round_state
+        )
     gates = jnp.concatenate([any_commits.astype(jnp.int32)[None], arms_ran])
     if active is not None:
         new_state, round_observers = jax.vmap(
@@ -431,8 +462,10 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
     ``run_until_membership_impl`` at the gated step's price. A
     ``while_loop`` over :func:`_gated_round` that ends when no tenant is
     active (or at ``max_steps``): the per-edge masks are built once before
-    the loop and again only in the view-change arm, the view change runs in
-    the rounds in which some active tenant decided, and the round's
+    the loop and again only at the head of a round that follows a commit
+    (one carried scalar, ``stale``: the build sits where the masks are next
+    read, so the commit the wave ends with builds none), the view change
+    runs in the rounds in which some active tenant decided, and the round's
     ``deliver`` / ``invalidation`` / ``classic`` arms in the rounds in which
     some tenant needs them (module docstring). A tenant that has resolved,
     or has spent its cuts or its steps, is frozen in place by the round's
@@ -456,10 +489,12 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
     :func:`fleet_wave_lockstep_impl`.
 
     Returns ``(state, *observers, steps[t], cuts[t], resolved[t],
-    sizes[t, max_cuts], loop_rounds)``, the last ``int32[4]``: the lockstep
+    sizes[t, max_cuts], loop_rounds)``, the last ``int32[5]``: the lockstep
     rounds the loop ran (the slowest tenant's count), then, in
     :data:`GATE_ROUND_COUNTERS`' order, those in which the view-change gate
-    opened and in which ``invalidation`` and ``classic`` ran.
+    opened and in which ``invalidation`` and ``classic`` ran, then the mask
+    builds the loop ran (its ``stale`` arm: the rounds that followed a
+    commit; the build before the loop is not among them).
     """
     *observers, faults, knobs, target, max_steps, max_cuts, min_cuts = rest
     tenants = target.shape[0]
@@ -472,10 +507,15 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
         return jnp.any(active_of(steps, done))
 
     def body(carry):
-        state, *observers, masks, steps, cuts, sizes, done, loop_rounds = carry
+        state, *observers, masks, stale, steps, cuts, sizes, done, loop_rounds = carry
         active = active_of(steps, done)
+        # The round before this one committed: its view change left the
+        # masks behind, and this round is the first to read them.
+        masks = jax.lax.cond(
+            stale, lambda: fleet_edge_masks_impl(cfg, state, faults), lambda: masks
+        )
         state, observers, masks, commits, _, gates = _gated_round(
-            cfg, state, observers, faults, knobs, masks, active
+            cfg, state, observers, faults, knobs, masks, active, rebuild_masks=False
         )
         with scope("loop_result"):
             steps = steps + active.astype(jnp.int32)
@@ -490,14 +530,15 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
             resolved = (state.n_members == target) & (cuts >= min_cuts)
             done = done | (commits & resolved) | (cuts >= max_cuts)
             loop_rounds = loop_rounds + jnp.concatenate(
-                [jnp.ones((1,), jnp.int32), gates]
+                [jnp.ones((1,), jnp.int32), gates, stale.astype(jnp.int32)[None]]
             )
-        return (state, *observers, masks, steps, cuts, sizes, done, loop_rounds)
+        return (state, *observers, masks, gates[0] > 0, steps, cuts, sizes, done, loop_rounds)
 
     init = (
         state,
         *observers,
         fleet_edge_masks_impl(cfg, state, faults),
+        jnp.bool_(False),
         jnp.zeros((tenants,), jnp.int32),
         jnp.zeros((tenants,), jnp.int32),
         jnp.full((tenants, max_cuts), -1, dtype=jnp.int32),
@@ -505,9 +546,9 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
         # condition: already-at-target only resolves vacuously when no cuts
         # are demanded.
         (state.n_members == target) & (min_cuts <= 0),
-        jnp.zeros((1 + len(GATE_ROUND_COUNTERS),), jnp.int32),
+        jnp.zeros((len(WAVE_LOOP_COUNTERS),), jnp.int32),
     )
-    state, *observers, _, steps, cuts, sizes, _, loop_rounds = jax.lax.while_loop(
+    state, *observers, _, _, steps, cuts, sizes, _, loop_rounds = jax.lax.while_loop(
         cond, body, init
     )
     with scope("loop_result"):
@@ -1066,8 +1107,10 @@ class TenantFleet(DispatchSeam):
         scalars or give one value per tenant. Returns ``(rounds[t],
         cuts[t], resolved[t], sizes[t, max_cuts])`` as host arrays; the same
         fetch brings the lockstep rounds the loop ran
-        (``engine_fleet_wave_rounds``) and those in which its gates opened
-        (:data:`GATE_ROUND_COUNTERS`)."""
+        (``engine_fleet_wave_rounds``), those in which its gates opened
+        (:data:`GATE_ROUND_COUNTERS`) and the mask builds it ran after a
+        commit (into ``engine_edge_mask_builds``, beside the builds the
+        step's driver dispatches)."""
         targets = np.broadcast_to(
             np.asarray(targets, dtype=np.int32), (self.b,)
         ).copy()
@@ -1111,7 +1154,7 @@ class TenantFleet(DispatchSeam):
         )
         self.metrics.inc("engine_tenant_rounds", int(rounds.sum()))
         self.metrics.inc("engine_tenant_cuts", int(n_cuts.sum()))
-        for name, ran in zip(("engine_fleet_wave_rounds", *GATE_ROUND_COUNTERS), loop_rounds):
+        for name, ran in zip(WAVE_LOOP_COUNTERS, loop_rounds):
             self.metrics.inc(name, int(ran))
         return rounds, n_cuts, resolved_h.astype(bool), sizes_h.reshape(b, max_cuts)
 

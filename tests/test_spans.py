@@ -60,6 +60,10 @@ EXPECTED.update({
     "predecessor_of_keys": {"join_predecessors"},
 })
 _WRAPPED = re.compile(r"^\w+\((.*)\)$")
+#: The fixture keeps a whole-wave loop's jaxpr beside its lowering, under
+#: this prefix: op-name paths say under WHICH arm an operation lies, not under
+#: which conditional, and those loops have two.
+JAXPR = "jaxpr:"
 
 
 def _paths(lowered) -> set:
@@ -111,13 +115,17 @@ def lowered():
         carried = [t for t in (one.state, one.telem, one.trace_ring) if t is not None]
         for verb, controls in (("step", (masks,)), ("decision", (i32(16),)),
                                ("wave", (i32(28), i32(16), 4, i32(1)))):
-            out[CLUSTER_VERBS[verb] + LEVELS[level]] = vcm._ROUND_PROGRAMS[verb][level].lower(
+            program = vcm._ROUND_PROGRAMS[verb][level].trace(
                 one.cfg, *carried, one.faults, *controls)
+            out[CLUSTER_VERBS[verb] + LEVELS[level]] = program.lower()
+        out[JAXPR + CLUSTER_VERBS["wave"] + LEVELS[level]] = program.jaxpr.jaxpr  # the wave: traced last
         carried = [t for t in (many.state, many.telem, many.trace_ring) if t is not None]
         for verb, controls in (("step", (jnp.zeros((3,), i32), fleet_masks)), ("decision", (i32(16),)),
                                ("wave", (per_tenant[0], i32(16), 4, per_tenant[1]))):
-            out[FLEET_VERBS[verb] + LEVELS[level]] = fleetm._FLEET_PROGRAMS[verb][level].lower(
+            program = fleetm._FLEET_PROGRAMS[verb][level].trace(
                 many.cfg, *carried, many.faults, many.knobs, *controls)
+            out[FLEET_VERBS[verb] + LEVELS[level]] = program.lower()
+        out[JAXPR + FLEET_VERBS["wave"] + LEVELS[level]] = program.jaxpr.jaxpr  # the wave: traced last
     out.update({
         "engine_step": vcm.engine_step.lower(vc.cfg, s, vc.faults),
         "edge_masks_build": vcm.edge_masks_build.lower(vc.cfg, s, vc.faults),
@@ -228,15 +236,53 @@ def test_the_meshless_fleet_programs_gate_the_view_change_on_one_conditional(low
     assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(outside)))
 
 
-def test_the_wave_builds_its_masks_before_the_loop_and_in_the_cuts_arm_only(lowered):
-    # one build at the top level of the program, one under the view change's
-    # arm inside the while, none in the body proper: a round that commits
-    # nothing builds no masks (the mesh's lockstep wave builds them in every
-    # iteration)
-    builds = {p for p in _paths(lowered["fleet_wave"]) if "edge_masks" in p}
-    in_loop = {p for p in builds if "while/body" in p}
-    assert builds - in_loop and in_loop
-    assert all(re.search(r"while/body/(?:\w+/)*cond/branch_\d+_fun/vmap\(edge_masks\)/", p) for p in in_loop)
+def _placed(jaxpr, inside=(), path="", conds=None):
+    """``(where, op-name path)`` of every equation of a jaxpr, sub-jaxprs
+    walked in program order. ``where`` is the control flow an equation lies
+    under, outermost first: ``"while"`` for a loop's body and ``(n, arm)``
+    for arm ``arm`` of the program's ``n``-th conditional (counted in that
+    order). A ``jit`` wrapper and a loop's predicate add nothing to it."""
+    conds = [0] if conds is None else conds
+    for eqn in jaxpr.eqns:
+        here = "/".join(part for part in (path, str(eqn.source_info.name_stack)) if part)
+        yield inside, here
+        if eqn.primitive.name == "cond":
+            nth, conds[0] = conds[0], conds[0] + 1
+            for arm, branch in enumerate(eqn.params["branches"]):
+                yield from _placed(branch.jaxpr, (*inside, (nth, arm)), here, conds)
+        elif eqn.primitive.name == "while":
+            yield from _placed(eqn.params["body_jaxpr"].jaxpr, (*inside, "while"), here, conds)
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _placed(sub, inside, here, conds)
+
+
+@pytest.mark.parametrize("level", LEVELS)
+@pytest.mark.parametrize("program", ["fleet_wave", "run_until_membership"])
+def test_a_whole_wave_loop_builds_its_masks_where_a_round_will_read_them(lowered, program, level):
+    # The masks are built at the point of first use after the state they
+    # depend on changed, never at the point of change: the loop may end with
+    # the commit, and then nothing reads a rebuild.
+    placed = list(_placed(lowered[JAXPR + program + level]))
+
+    def where(name):
+        return {inside for inside, path in placed if name in _scopes({path})}
+
+    builds, (view_change,) = where("edge_masks"), where("view_change")
+    # the view change: the taken arm of one conditional of the loop's body
+    loop, (cut, taken) = view_change
+    assert (loop, taken) == ("while", 1)
+    if program == "fleet_wave":
+        # one build before the loop, one in the taken arm of a conditional of
+        # its own at the head of the body (no conditional of the round comes
+        # before it), which is not the view change's
+        head = min(at[1][0] for at, _ in placed if len(at) > 1 and at[0] == "while" and at[1] != "while")
+        assert builds == {(), ("while", (head, 1))} and head != cut
+    else:
+        # one build a convergence: in the outer body, outside any conditional
+        # and outside the inner round loop, and none before the outer loop
+        assert builds == {("while",)}
+        assert ("while", "while") in {inside for inside, _ in placed}
     lockstep = {p for p in _paths(lowered["mesh_fleet_wave"]) if "edge_masks" in p}
     assert lockstep and not any("cond/" in p for p in lockstep)
 
@@ -270,10 +316,12 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: fleet decision, cluster-10m's meshed decision) and the two mesh steps.
 #: ``fleet_run_to_decision`` is PR 30's: it names its batch axis, and the
 #: round's conditionals in it are conditionals; the other four name none.
+#: ``run_until_membership`` is PR 34's: it builds its masks at the head of
+#: each convergence and no longer in the cut's arm.
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "1f6637696631d51b",
+    "run_until_membership": "8b3e59538974cb08",
     "fleet_run_to_decision": "e415a468c57334a8",
     "mesh_run_to_decision": "2e585f0987f0656f",
     "mesh_step": "9343185e4ea60084",
