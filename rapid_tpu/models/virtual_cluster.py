@@ -50,7 +50,7 @@ from rapid_tpu.ops.rings import (
     ring_topology_from_perm,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
-from rapid_tpu.utils.dispatch import DispatchSeam, cond_across, scope
+from rapid_tpu.utils.dispatch import DispatchSeam, cond_across, scope, setup_stage
 from rapid_tpu.utils.health import NodeHealth
 from rapid_tpu.utils.metrics import Metrics
 
@@ -1329,19 +1329,22 @@ class VirtualCluster(DispatchSeam):
             telemetry=int(telemetry),
             trace=int(trace),
         )
-        rng = np.random.default_rng(seed)
-        key_hi = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
-        key_lo = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
-        id_hi = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
-        id_lo = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
-        alive = np.zeros(n, dtype=bool)
-        alive[:n_members] = True
-        identity = (key_hi, key_lo, id_hi, id_lo, alive)
-        if mesh is None:
-            state = initial_state(cfg, *identity)
-        else:
-            state = _mesh_lib().initial_state_on_mesh(cfg, mesh, *identity)
-        cluster = cls(cfg, state, mesh=mesh)
+        with setup_stage("create"):
+            with setup_stage("create.keys"):
+                rng = np.random.default_rng(seed)
+                key_hi = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
+                key_lo = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
+                id_hi = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
+                id_lo = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
+                alive = np.zeros(n, dtype=bool)
+                alive[:n_members] = True
+                identity = (key_hi, key_lo, id_hi, id_lo, alive)
+            with setup_stage("create.state"):
+                if mesh is None:
+                    state = initial_state(cfg, *identity)
+                else:
+                    state = _mesh_lib().initial_state_on_mesh(cfg, mesh, *identity)
+            cluster = cls(cfg, state, mesh=mesh)
         cluster._rng = rng
         cluster._account_h2d(*identity)
         return cluster
@@ -1407,17 +1410,21 @@ class VirtualCluster(DispatchSeam):
             telemetry=int(telemetry),
             trace=int(trace),
         )
-        key_hi0, key_lo0 = endpoint_ring_keys(endpoints, k, topology=topology)
-        key_hi = np.zeros((k, n), dtype=np.uint32)
-        key_lo = np.zeros((k, n), dtype=np.uint32)
-        key_hi[:, : len(endpoints)] = np.asarray(key_hi0)
-        key_lo[:, : len(endpoints)] = np.asarray(key_lo0)
-        rng = np.random.default_rng(1234)
-        id_hi = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
-        id_lo = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
-        alive = np.zeros(n, dtype=bool)
-        alive[:n_members] = True
-        cluster = cls(cfg, initial_state(cfg, key_hi, key_lo, id_hi, id_lo, alive))
+        with setup_stage("create"):
+            with setup_stage("create.keys"):
+                key_hi0, key_lo0 = endpoint_ring_keys(endpoints, k, topology=topology)
+                key_hi = np.zeros((k, n), dtype=np.uint32)
+                key_lo = np.zeros((k, n), dtype=np.uint32)
+                key_hi[:, : len(endpoints)] = np.asarray(key_hi0)
+                key_lo[:, : len(endpoints)] = np.asarray(key_lo0)
+                rng = np.random.default_rng(1234)
+                id_hi = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
+                id_lo = rng.integers(0, 2**32, size=(n,), dtype=np.uint32)
+                alive = np.zeros(n, dtype=bool)
+                alive[:n_members] = True
+            with setup_stage("create.state"):
+                state = initial_state(cfg, key_hi, key_lo, id_hi, id_lo, alive)
+            cluster = cls(cfg, state)
         cluster._account_h2d(key_hi, key_lo, id_hi, id_lo, alive)
         return cluster
 
@@ -1905,6 +1912,7 @@ class VirtualCluster(DispatchSeam):
                 "cohorts": self.cfg.c,
                 "use_pallas": self.cfg.use_pallas,
                 "compile": engine_telemetry.compile_snapshot(),
+                "setup": engine_telemetry.setup_snapshot(),
                 "memory": engine_telemetry.device_memory_snapshot(),
                 # Streaming tier (rapid_tpu/serving): present only when a
                 # StreamDriver is attached — batch-only scrapes keep their

@@ -144,7 +144,7 @@ from rapid_tpu.parallel.mesh import (
     match_partition_rules,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
-from rapid_tpu.utils.dispatch import DispatchSeam, scope
+from rapid_tpu.utils.dispatch import DispatchSeam, scope, setup_stage
 from rapid_tpu.utils.health import NodeHealth
 from rapid_tpu.utils.metrics import Metrics
 
@@ -935,16 +935,20 @@ class TenantFleet(DispatchSeam):
             raise ValueError(f"need {tenants} seeds, got {len(seeds)}")
         if knobs is not None and len(knobs) != tenants:
             raise ValueError(f"need {tenants} knob triples, got {len(knobs)}")
-        clusters = []
-        for i in range(tenants):
-            h, l, fd = knobs[i] if knobs is not None else (9, 4, 3)
-            vc = VirtualCluster.create(
-                n_members, n_slots=n_slots, k=k, h=h, l=l, cohorts=cohorts,
-                fd_threshold=fd, seed=seeds[i], **engine_kwargs,
-            )
-            vc.assign_cohorts_roundrobin()
-            clusters.append(vc)
-        return cls.from_clusters(clusters)
+        with setup_stage("fleet_create"):
+            clusters = []
+            with setup_stage("fleet_create.tenants"):
+                for i in range(tenants):
+                    h, l, fd = knobs[i] if knobs is not None else (9, 4, 3)
+                    vc = VirtualCluster.create(
+                        n_members, n_slots=n_slots, k=k, h=h, l=l, cohorts=cohorts,
+                        fd_threshold=fd, seed=seeds[i], **engine_kwargs,
+                    )
+                    vc.assign_cohorts_roundrobin()
+                    clusters.append(vc)
+            with setup_stage("fleet_create.stack"):
+                fleet = cls.from_clusters(clusters)
+        return fleet
 
     # -- execution ------------------------------------------------------
 
@@ -1392,6 +1396,7 @@ class TenantFleet(DispatchSeam):
                 "cohorts": self.cfg.c,
                 "use_pallas": self.cfg.use_pallas,
                 "compile": engine_telemetry.compile_snapshot(),
+                "setup": engine_telemetry.setup_snapshot(),
                 "memory": engine_telemetry.device_memory_snapshot(),
                 "tenancy": {
                     "tenants": self.b,

@@ -28,6 +28,13 @@ phases and the arms of its conditionals carry ``jax.named_scope`` names from
 :data:`ENGINE_SCOPES` (:func:`scope`), registered and enforced at write time
 like the phases; :func:`cond_across` is the form those conditionals take, so
 that a batched program which names its batch axis keeps them conditionals.
+
+Set-up has the same treatment. The constructors' work runs inside
+:func:`setup_stage` blocks, ``rapid:setup.<stage>`` spans from the registered
+:data:`ENGINE_SETUP_STAGES`, whose wall seconds ``engine_telemetry.
+setup_snapshot()`` adds up; and a dispatch phase or a stage, while open, is the
+span under which ``engine_telemetry``'s collector files every trace, lowering,
+compile and cache load that happens (``compile_snapshot()["by_span"]``).
 """
 
 from __future__ import annotations
@@ -38,6 +45,7 @@ from contextlib import contextmanager
 import jax
 import jax.numpy as jnp
 
+from rapid_tpu.utils import engine_telemetry
 from rapid_tpu.utils.profiling import annotate
 
 #: The registered dispatch-phase vocabulary — every ``_dispatch(...)`` entry
@@ -72,6 +80,51 @@ ENGINE_DISPATCH_PHASES = frozenset({
 
 #: Prefix of a dispatch phase's span on the profiler's clock.
 SPAN_PREFIX = "rapid:"
+
+#: The registered set-up stage vocabulary, placed by what the constructors
+#: do: every ``setup_stage(...)`` block of ``VirtualCluster.create`` /
+#: ``from_endpoints`` and ``TenantFleet.create``. A dotted stage lies inside
+#: the stage before its dot, so its seconds are inside its parent's.
+ENGINE_SETUP_STAGES = frozenset({
+    # The whole of VirtualCluster.create / from_endpoints: the host's ring
+    # keys and identity draws, then initial_state (the ring build on the
+    # device; under a mesh with the identity arrays' placement, which only
+    # enqueues: 0.05 s at 10M, so it has no stage), the driver's own lanes.
+    "create",
+    "create.keys",
+    "create.state",
+    # The whole of TenantFleet.create: the loop of VirtualCluster.create
+    # calls, then the stack into one fleet state.
+    "fleet_create",
+    "fleet_create.tenants",
+    "fleet_create.stack",
+})
+
+
+@contextmanager
+def setup_stage(stage: str):
+    """One block of a constructor's work: the span ``rapid:setup.<stage>`` on
+    the profiler's clock (a ``profiling.trace`` around a restart shows the
+    stages beside the device operations they enqueue), the span that the
+    pipeline events inside it are filed under, and the block's wall seconds
+    added to ``engine_telemetry.setup_snapshot()``. Like a dispatch phase, a
+    stage outside :data:`ENGINE_SETUP_STAGES` raises here, at write time.
+    The collector is installed here, not only by the driver the constructor
+    ends in, so the first ``create`` of a process is heard compiling."""
+    if stage not in ENGINE_SETUP_STAGES:
+        raise ValueError(
+            f"unregistered engine set-up stage {stage!r}; add it to "
+            f"rapid_tpu.utils.dispatch.ENGINE_SETUP_STAGES"
+        )
+    engine_telemetry.install()
+    name = engine_telemetry.SETUP_PREFIX + stage
+    with annotate(SPAN_PREFIX + name):
+        depth = engine_telemetry.push_span(name)
+        start = time.perf_counter()
+        try:
+            yield
+        finally:
+            engine_telemetry.close_stage(stage, depth, time.perf_counter() - start)
 
 #: The registered device-scope vocabulary: every ``jax.named_scope`` inside
 #: the compiled engine programs (the round's phases, both arms of each
@@ -175,7 +228,9 @@ class DispatchSeam:
         clock, tagged ``seq`` (this driver's operation count, so the spans
         of one commit or one wave read in order and nesting on the thread
         gives the parent) and the caller's ``tags`` (the stream's
-        ``wave=<index>``). With no trace running the span is a flag test."""
+        ``wave=<index>``). With no trace running the span is a flag test.
+        While the block is open it is also the span that a compile inside it
+        is filed under (``compile_snapshot()["by_span"][entry]``)."""
         if entry not in ENGINE_DISPATCH_PHASES:
             raise ValueError(
                 f"unregistered engine dispatch phase {entry!r}; add it to "
@@ -185,9 +240,11 @@ class DispatchSeam:
         seq = self.metrics.counters["engine_dispatches"]
         start = time.perf_counter()
         with annotate(SPAN_PREFIX + entry, seq=seq, **tags):
+            depth = engine_telemetry.push_span(entry)
             try:
                 yield
             finally:
+                engine_telemetry.pop_span(depth)
                 self.metrics.record_ms(
                     "engine_dispatch",
                     (time.perf_counter() - start) * 1000.0,

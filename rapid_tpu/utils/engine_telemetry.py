@@ -11,9 +11,13 @@ events, plus best-effort device-memory probes, consumed by
 Compile events are inherently process-global (the XLA compilation cache and
 the persistent on-disk cache are shared by every engine instance in the
 process), so the collector is a module singleton: ``install()`` registers
-the listeners once, ``compile_snapshot()`` reads the monotonic totals, and
-callers that want per-phase attribution diff two snapshots around the work
-(``CompileDelta``).
+the listeners once and ``compile_snapshot()`` reads the monotonic totals.
+The collector keeps who and where itself: every trace, lowering and load
+(a compile, or a read from the persistent cache) goes, with its exact seconds,
+to the program that caused it and to the program span that was open
+(``utils/dispatch.py``: a dispatch phase or a constructor's set-up stage), and
+``setup_snapshot()`` holds the wall seconds of those stages. (``CompileDelta``
+diffs two snapshots around a block, for the bench.)
 
 Everything degrades gracefully: a JAX build without ``jax.monitoring`` (or
 without ``memory_stats``/``live_arrays``) yields zero counters / ``None``
@@ -23,9 +27,10 @@ engine it observes.
 
 from __future__ import annotations
 
+import collections
 import logging
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from rapid_tpu.utils.histogram import LogHistogram
 
@@ -44,37 +49,235 @@ _EVENT_COUNTERS = {
 #: the process's compile count, its sum the total compile wall time.
 _COMPILE_DURATION_EVENT = "/jax/core/compile/backend_compile_duration"
 
+#: The three duration events jax 0.9.0 records with a ``fun_name`` (listed
+#: from ``jax/_src/dispatch.py``; each also records a scalar of the same name
+#: when it STARTS), by the stage of the pipeline they time. The last wraps
+#: ``compile_or_get_cached``: a compile when cold, a cache read and a
+#: deserialise when warm, hence "load".
+_PIPELINE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _COMPILE_DURATION_EVENT: "load",
+}
+
+#: The cache's own durations (no ``fun_name``; recorded inside "load", on a
+#: hit only): the read + deserialise, and the cache's estimate of the compile
+#: seconds it spared (stored compile time minus the read; may be negative).
+_CACHE_DURATION_EVENTS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+    "/jax/compilation_cache/compile_time_saved_sec": "cache_saved",
+}
+
+#: What ``by_span`` files an event under when no program span is open.
+OUTSIDE = "outside"
+#: ``by_program`` shows this many programs, the largest by total seconds, and
+#: one more row, ``"other"``, that holds everybody else's sums.
+PROGRAM_ROWS = 32
+OTHER = "other"
+#: ``recent`` remembers this many "load" events.
+RECENT_LOADS = 64
+#: Prefix of a set-up stage's name on the span stack and in ``by_span``
+#: (``utils/dispatch.py::setup_stage``; a dispatch phase has none).
+SETUP_PREFIX = "setup."
+#: ``setup_snapshot``'s row for the outermost stage blocks alone.
+OUTERMOST = "outermost"
+
+_STAGE_FIELDS = ("trace_s", "lower_s", "load_s")
+
+
+def _program_of(fun_name: Any) -> str:
+    """One key for a program's three events: the trace event names the
+    function (``outer_fn``), lowering and compilation name its module
+    (``jit(outer_fn)``)."""
+    name = str(fun_name)
+    if name.endswith(")") and "(" in name:
+        return name[name.index("(") + 1 : -1]
+    return name
+
 
 class _CompileCollector:
     """Monotonic process-wide compile/cache totals (thread-safe: monitoring
-    callbacks can fire from compile worker threads)."""
+    callbacks can fire from compile worker threads), what the set-up stages
+    took, and the stack of open program spans that says where an event ran.
+
+    jax 0.9.0 records every event here synchronously on the thread that
+    dispatched the program (verified by listening with the thread's id), so
+    with one driving thread, as in every cell, the innermost open span is the
+    driver call that caused the event. The span stack is the PROCESS's, not a
+    thread's: an event from another thread is filed under whatever span is
+    innermost at that moment, whoever opened it. The nesting of the pipeline's
+    own intervals (a jit traced inside a jit's trace) is kept per thread."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
+        self._thread = threading.local()
         self.counters: Dict[str, int] = {
             name: 0 for name in _EVENT_COUNTERS.values()
         }
         self.compiles = 0
         self.compile_ms_hist = LogHistogram()
+        self.pipeline_s: Dict[str, float] = dict.fromkeys(
+            (*_PIPELINE_EVENTS.values(), *_CACHE_DURATION_EVENTS.values()), 0.0
+        )
+        self.by_span: Dict[str, Dict[str, float]] = {}
+        self.by_program: Dict[str, Dict[str, float]] = {}
+        self.recent: collections.deque = collections.deque(maxlen=RECENT_LOADS)
+        self.spans: List[str] = []
+        self.setup: Dict[str, Dict[str, float]] = {
+            OUTERMOST: {"count": 0, "wall_s": 0.0}
+        }
+
+    # -- listeners -------------------------------------------------------
 
     def on_event(self, event: str, **_kwargs: Any) -> None:
         name = _EVENT_COUNTERS.get(event)
         if name is not None:
             with self._lock:
                 self.counters[name] += 1
+            if name != "persistent_cache_misses":
+                # Recorded inside the load they belong to, the request before
+                # the hit and both before that load's duration: remembered
+                # until then (``from_cache``). A miss is recorded only when
+                # the compiled program is written back, so it tells nothing.
+                self._thread.from_cache = name == "persistent_cache_hits"
 
-    def on_duration(self, event: str, duration_secs: float, **_kwargs: Any) -> None:
-        if event == _COMPILE_DURATION_EVENT:
+    def on_start(self, event: str, _value: float, **_kwargs: Any) -> None:
+        """A pipeline interval opens (jax records the start time as a scalar
+        under the duration event's name): remember it, so that the interval's
+        end can tell what of its seconds an inner interval already took."""
+        stage = _PIPELINE_EVENTS.get(event)
+        if stage is not None:
+            self._open_intervals().append([stage, 0.0])
+
+    def on_duration(self, event: str, duration_secs: float, **kwargs: Any) -> None:
+        cache_sum = _CACHE_DURATION_EVENTS.get(event)
+        if cache_sum is not None:
             with self._lock:
+                self.pipeline_s[cache_sum] += duration_secs
+            return
+        stage = _PIPELINE_EVENTS.get(event)
+        if stage is None:
+            return
+        # Exact seconds, each counted once: an interval inside one of its own
+        # stage (a jit traced while its caller is traced) is its parent's, so
+        # a trace goes whole to the program that caused it; one inside another
+        # stage is taken out of its parent. Without the start scalars (an
+        # older jax) every interval counts whole.
+        own: Optional[float] = duration_secs
+        intervals = self._open_intervals()
+        if intervals and intervals[-1][0] == stage:
+            inside = intervals.pop()[1]
+            own = duration_secs - inside
+            if intervals and intervals[-1][0] == stage:
+                intervals[-1][1] += inside
+                own = None
+            elif intervals:
+                intervals[-1][1] += duration_secs
+        from_cache = None
+        if stage == "load":
+            from_cache = getattr(self._thread, "from_cache", None)
+            self._thread.from_cache = None
+        program = _program_of(kwargs.get("fun_name", "?"))
+        with self._lock:
+            if stage == "load":
                 self.compiles += 1
                 self.compile_ms_hist.observe(duration_secs * 1000.0)
+            if own is None:
+                return
+            span = self.spans[-1] if self.spans else OUTSIDE
+            field = stage + "_s"
+            self.pipeline_s[stage] += own
+            for table, key in ((self.by_span, span), (self.by_program, program)):
+                row = table.get(key)
+                if row is None:
+                    row = table[key] = self._row(table is self.by_program)
+                row[field] += own
+            if stage == "load":
+                self.by_span[span]["programs"] += 1
+                row = self.by_program[program]
+                row["count"] += 1
+                row["from_cache"] += bool(from_cache)
+                self.recent.append((program, own, span, from_cache))
+            if len(self.by_program) > 2 * PROGRAM_ROWS:
+                # Bounded whatever a long-lived process compiles. Twice the
+                # shown rows are kept, so that a new program's three events
+                # meet in one row before it is judged.
+                self.by_program = self._folded()
+
+    def _open_intervals(self) -> list:
+        try:
+            return self._thread.intervals
+        except AttributeError:
+            self._thread.intervals = []
+            return self._thread.intervals
+
+    @staticmethod
+    def _row(program: bool) -> Dict[str, float]:
+        row: Dict[str, float] = dict.fromkeys(_STAGE_FIELDS, 0.0)
+        row.update({"count": 0, "from_cache": 0} if program else {"programs": 0})
+        return row
+
+    @staticmethod
+    def _seconds(row: Dict[str, float]) -> float:
+        return sum(row[field] for field in _STAGE_FIELDS)
+
+    def _folded(self) -> Dict[str, Dict[str, float]]:
+        """``by_program`` as it is shown: the :data:`PROGRAM_ROWS` largest
+        rows by seconds, and ``"other"`` with everybody else's sums."""
+        ranked = sorted(
+            (item for item in self.by_program.items() if item[0] != OTHER),
+            key=lambda item: self._seconds(item[1]), reverse=True,
+        )
+        table = {name: dict(row) for name, row in ranked[:PROGRAM_ROWS]}
+        rest = [row for _, row in ranked[PROGRAM_ROWS:]]
+        if OTHER in self.by_program:
+            rest.append(self.by_program[OTHER])
+        if rest:
+            other = table[OTHER] = self._row(True)
+            for row in rest:
+                for field, value in row.items():
+                    other[field] += value
+        return table
+
+    # -- spans and stages --------------------------------------------------
+
+    def push_span(self, name: str) -> int:
+        """Open a program span (a dispatch phase, ``setup.<stage>``); returns
+        what :meth:`pop_span` takes to close it and everything above it."""
+        self.spans.append(name)
+        return len(self.spans) - 1
+
+    def pop_span(self, depth: int) -> None:
+        del self.spans[depth:]
+
+    def close_stage(self, stage: str, depth: int, wall_s: float) -> None:
+        """A set-up stage block ends: its span goes (:meth:`pop_span`) and its
+        wall seconds are added to its stage and, if no other stage is open
+        around it, to ``"outermost"``."""
+        self.pop_span(depth)
+        with self._lock:
+            outermost = not any(name.startswith(SETUP_PREFIX) for name in self.spans)
+            for name in (stage, OUTERMOST) if outermost else (stage,):
+                row = self.setup.setdefault(name, {"count": 0, "wall_s": 0.0})
+                row["count"] += 1
+                row["wall_s"] += wall_s
+
+    # -- reading -----------------------------------------------------------
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             out: Dict[str, Any] = dict(self.counters)
             out["compiles"] = self.compiles
             out["compile_ms"] = self.compile_ms_hist.summary()
+            out["pipeline_s"] = dict(self.pipeline_s)
+            out["by_span"] = {name: dict(row) for name, row in self.by_span.items()}
+            out["by_program"] = self._folded()
+            out["recent"] = [list(event) for event in self.recent]
         return out
+
+    def setup_snapshot(self) -> Dict[str, Dict[str, float]]:
+        with self._lock:
+            return {name: dict(row) for name, row in self.setup.items()}
 
 
 _COLLECTOR = _CompileCollector()
@@ -109,6 +312,11 @@ def install() -> bool:
             logger.warning("engine compile telemetry disabled: %r", exc)
             _installed = False
             return False
+        try:
+            monitoring.register_scalar_listener(_COLLECTOR.on_start)
+        except Exception as exc:  # noqa: BLE001 — without the start scalars
+            # nested intervals count twice; the totals still mean something.
+            logger.warning("pipeline intervals will not nest: %r", exc)
         _installed = True
         return True
 
@@ -116,9 +324,38 @@ def install() -> bool:
 def compile_snapshot() -> Dict[str, Any]:
     """Monotonic process-wide compile/cache totals:
     ``{compiles, compile_ms: <histogram summary>, persistent_cache_hits,
-    persistent_cache_misses, cache_requests}``. All zeros when capture is
-    unavailable (callers need not care)."""
+    persistent_cache_misses, cache_requests}``, all zeros when capture is
+    unavailable (callers need not care), and who took the pipeline's seconds
+    (exact float sums; an event this jax does not record reads 0):
+
+    - ``pipeline_s``: ``{trace, lower, load, cache_retrieval, cache_saved}``,
+      process totals; ``load`` is compile-or-read-from-the-cache and holds
+      ``cache_retrieval``;
+    - ``by_span``: per innermost open program span at the moment of the event
+      (a dispatch phase, ``setup.<stage>``, or ``"outside"``):
+      ``{trace_s, lower_s, load_s, programs}``;
+    - ``by_program``: per ``fun_name`` ``{trace_s, lower_s, load_s, count,
+      from_cache}`` (loads, and how many of them the persistent cache
+      served), the 32 largest by seconds and ``"other"`` for the rest;
+    - ``recent``: the last 64 loads as ``[fun_name, seconds, span,
+      from_cache]`` (``from_cache`` None where the cache was not asked).
+    """
     return _COLLECTOR.snapshot()
+
+
+def setup_snapshot() -> Dict[str, Dict[str, float]]:
+    """Wall seconds inside the set-up stage blocks (``utils/dispatch.py::
+    setup_stage``): ``{stage: {count, wall_s}}``. Stages nest, a child's
+    seconds are inside its parent's; ``"outermost"`` adds up the blocks that
+    had no stage open around them, so it counts every second once."""
+    return _COLLECTOR.setup_snapshot()
+
+
+#: The span stack and the stage sums, for ``utils/dispatch.py`` (the one
+#: module that opens spans): ``_dispatch`` and ``setup_stage`` push and pop.
+push_span = _COLLECTOR.push_span
+pop_span = _COLLECTOR.pop_span
+close_stage = _COLLECTOR.close_stage
 
 
 class CompileDelta:

@@ -177,6 +177,12 @@ _ENGINE_COMPILE_COUNTERS = (
     ("cache_requests", "compile_cache_requests"),
 )
 
+#: ``engine.compile.pipeline_s`` sums rendered as
+#: ``rapid_engine_pipeline_seconds_total{stage=...}``, zero-filled
+#: (``cache_saved`` stays JSON-only: an estimate that can fall is no counter;
+#: so does ``by_program``: a label per program name has no bound).
+_ENGINE_PIPELINE_STAGES = ("trace", "lower", "load", "cache_retrieval")
+
 #: ``engine.memory`` gauge keys (``None`` probes render as NaN so the
 #: series set is identical on platforms without allocator stats).
 _ENGINE_MEMORY_GAUGES = (
@@ -465,6 +471,16 @@ def prometheus_text(snapshot: Dict[str, Any]) -> str:
         compile_ms = compile_stats.get("compile_ms")
         if isinstance(compile_ms, dict):
             out.histogram(f"{_PREFIX}_engine_compile_ms", compile_ms, node=node)
+        pipeline_s = compile_stats.get("pipeline_s") or {}
+        for stage in _ENGINE_PIPELINE_STAGES:
+            out.sample(f"{_PREFIX}_engine_pipeline_seconds_total", "counter",
+                       pipeline_s.get(stage, 0.0), node=node, stage=stage)
+        # Wall seconds inside the constructors' set-up stages; "outermost"
+        # is there from the first scrape, a stage from its first block.
+        setup = engine.get("setup") or {"outermost": {}}
+        for stage in sorted(setup):
+            out.sample(f"{_PREFIX}_engine_setup_seconds_total", "counter",
+                       setup[stage].get("wall_s", 0.0), node=node, stage=stage)
         memory = engine.get("memory") or {}
         for key in _ENGINE_MEMORY_GAUGES:
             value = memory.get(key)

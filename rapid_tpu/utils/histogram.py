@@ -31,7 +31,9 @@ GROWTH = 2.0 ** 0.5
 FIRST_UPPER_MS = 0.01
 
 #: Finite buckets; one extra overflow bucket (index NUM_BUCKETS) plays the
-#: Prometheus ``+Inf`` role.
+#: Prometheus ``+Inf`` role. Inside it the schedule goes on (``tail_index``),
+#: kept sparsely, so a quantile past the last finite bound keeps the same
+#: error contract instead of reading the max.
 NUM_BUCKETS = 64
 
 #: The fixed schedule: ``UPPER_BOUNDS_MS[i]`` is the inclusive upper bound of
@@ -48,6 +50,19 @@ def bucket_index(value_ms: float) -> int:
     return bisect_left(UPPER_BOUNDS_MS, value_ms)
 
 
+def tail_index(value_ms: float) -> int:
+    """For a value in the overflow bucket: the index (>= NUM_BUCKETS) of the
+    bucket it would hold on the schedule continued past its last finite
+    bound, ``FIRST_UPPER_MS * GROWTH**i``. The logarithm only guesses; the
+    two loops make the bounds exact (``bound(i - 1) < value <= bound(i)``)."""
+    idx = max(NUM_BUCKETS, math.ceil(math.log(value_ms / FIRST_UPPER_MS, GROWTH)))
+    while idx > NUM_BUCKETS and FIRST_UPPER_MS * GROWTH ** (idx - 1) >= value_ms:
+        idx -= 1
+    while FIRST_UPPER_MS * GROWTH**idx < value_ms:
+        idx += 1
+    return idx
+
+
 class LogHistogram:
     """Fixed-schedule log-bucketed histogram of millisecond durations.
 
@@ -58,19 +73,30 @@ class LogHistogram:
     bucket counts, counts, and sums, and takes the max of maxima: associative
     and commutative over everything except ``last`` (which is a display
     nicety, defined as the most recent operand's last sample).
+
+    The overflow bucket's samples are also counted by :func:`tail_index` in
+    a sparse ``{index: count}``, which is what holds the rank bound past the
+    last finite bound (a 9-hour sample is no latency; the store under
+    ``compile_ms`` and the property tests reach it). It grows by one entry
+    per occupied sqrt(2) step, some 2,000 for every finite float.
     """
 
-    __slots__ = ("_counts", "count", "sum", "max", "last")
+    __slots__ = ("_counts", "_tail", "count", "sum", "max", "last")
 
     def __init__(self) -> None:
         self._counts: List[int] = [0] * (NUM_BUCKETS + 1)
+        self._tail: Dict[int, int] = {}
         self.count = 0
         self.sum = 0.0
         self.max = 0.0
         self.last = 0.0
 
     def observe(self, value_ms: float) -> None:
-        self._counts[bucket_index(value_ms)] += 1
+        idx = bucket_index(value_ms)
+        self._counts[idx] += 1
+        if idx == NUM_BUCKETS and value_ms != math.inf:  # inf has no step; it is the max
+            tail = tail_index(value_ms)
+            self._tail[tail] = self._tail.get(tail, 0) + 1
         self.count += 1
         self.sum += value_ms
         if value_ms > self.max:
@@ -83,6 +109,8 @@ class LogHistogram:
         """Fold ``other`` into self (in place); returns self for chaining."""
         for i, c in enumerate(other._counts):
             self._counts[i] += c
+        for i, c in other._tail.items():
+            self._tail[i] = self._tail.get(i, 0) + c
         self.count += other.count
         self.sum += other.sum
         if other.max > self.max:
@@ -107,12 +135,17 @@ class LogHistogram:
             return 0.0
         rank = min(self.count, max(1, math.ceil(q * self.count)))
         cumulative = 0
-        for i, c in enumerate(self._counts):
+        for i, c in enumerate(self._counts[:NUM_BUCKETS]):
             cumulative += c
             if cumulative >= rank:
-                bound = UPPER_BOUNDS_MS[i] if i < NUM_BUCKETS else self.max
-                return min(bound, self.max)
-        return self.max  # unreachable: cumulative reaches count
+                return min(UPPER_BOUNDS_MS[i], self.max)
+        for i in sorted(self._tail):
+            cumulative += self._tail[i]
+            if cumulative >= rank:
+                return min(FIRST_UPPER_MS * GROWTH**i, self.max)
+        # An overflow count without its tail (a summary written before the
+        # tail existed): all that is known of those samples is the max.
+        return self.max
 
     # -- snapshots -----------------------------------------------------
 
@@ -121,16 +154,21 @@ class LogHistogram:
         bucket counts (``{index: count}``, string keys for JSON round-trip)
         the Prometheus renderer and cross-node mergers consume. Size is
         O(NUM_BUCKETS) no matter how many samples were recorded."""
-        return {
+        out: Dict[str, object] = {
             "count": self.count,
             "last": round(self.last, 3),
             "p50": round(self.quantile(0.50), 3),
             "p90": round(self.quantile(0.90), 3),
             "p99": round(self.quantile(0.99), 3),
-            "max": round(self.max, 3),
+            # Exact: ``from_summary`` restores it, and ``quantile`` clamps
+            # to it (a rounded max could read below a recorded sample).
+            "max": self.max,
             "sum": round(self.sum, 3),
             "buckets": {str(i): c for i, c in enumerate(self._counts) if c},
         }
+        if self._tail:
+            out["tail"] = {str(i): c for i, c in sorted(self._tail.items())}
+        return out
 
     @classmethod
     def from_summary(cls, summary: Dict[str, object]) -> "LogHistogram":
@@ -142,6 +180,8 @@ class LogHistogram:
             idx = int(key)
             if 0 <= idx <= NUM_BUCKETS:
                 out._counts[idx] += int(c)
+        for key, c in (summary.get("tail") or {}).items():
+            out._tail[int(key)] = int(c)
         out.count = int(summary.get("count", 0))
         out.sum = float(summary.get("sum", 0.0))
         out.max = float(summary.get("max", 0.0))

@@ -69,6 +69,10 @@ GOLDEN_ENGINE_METRIC_NAMES = [
     "rapid_engine_live_buffers",
     "rapid_engine_persistent_cache_hits_total",
     "rapid_engine_persistent_cache_misses_total",
+    # Who took set-up's seconds (ISSUE 35): the jax pipeline by stage, and
+    # the constructors' set-up stages. No per-program series.
+    "rapid_engine_pipeline_seconds_total",
+    "rapid_engine_setup_seconds_total",
     "rapid_engine_steps_total",
     "rapid_kicked_total",
     "rapid_membership_size",
@@ -96,7 +100,12 @@ def test_snapshot_engine_section_shape_and_serializable():
     assert set(engine["compile"]) == {
         "compiles", "compile_ms", "persistent_cache_hits",
         "persistent_cache_misses", "cache_requests",
+        "pipeline_s", "by_span", "by_program", "recent",
     }
+    assert set(engine["compile"]["pipeline_s"]) == {
+        "trace", "lower", "load", "cache_retrieval", "cache_saved",
+    }
+    assert "outermost" in engine["setup"]
     assert set(engine["memory"]) == {
         "live_buffers", "live_buffer_bytes",
         "device_bytes_in_use", "device_peak_bytes",
@@ -117,6 +126,236 @@ def test_compile_events_are_captured():
     snap = engine_telemetry.compile_snapshot()
     assert snap["compiles"] >= 1
     assert snap["compile_ms"]["count"] == snap["compiles"]
+
+
+# ---------------------------------------------------------------------------
+# Set-up from the inside (ISSUE 35): every pipeline event goes to the program
+# that caused it and to the span it ran under; the constructors' stages
+# ---------------------------------------------------------------------------
+
+_STAGE_SUMS = ("trace_s", "lower_s", "load_s")
+
+
+def _fresh_program(length):
+    """A jit nobody has called, on a shape nobody has used: one trace, one
+    lowering and one compile when it is first called."""
+
+    def probe_fresh_shape(x):
+        return (x * 5 + 2).sum()
+
+    return jax.jit(probe_fresh_shape), jnp.arange(length)
+
+
+def _row_delta(before, after, table, key):
+    was = before[table].get(key, {})
+    return {f: v - was.get(f, 0) for f, v in after[table][key].items()}
+
+
+def test_compile_inside_a_dispatch_phase_is_named_by_program_and_phase():
+    vc = _cluster()
+    probe, x = _fresh_program(181)
+    before = engine_telemetry.compile_snapshot()
+    with vc._dispatch("run_to_decision"):
+        probe(x)
+    after = engine_telemetry.compile_snapshot()
+    span = _row_delta(before, after, "by_span", "run_to_decision")
+    program = _row_delta(before, after, "by_program", "probe_fresh_shape")
+    for row in (span, program):
+        assert all(row[field] > 0 for field in _STAGE_SUMS), row
+    assert span["programs"] == program["count"] == 1
+    name, seconds, where, from_cache = after["recent"][-1]
+    assert (name, where) == ("probe_fresh_shape", "run_to_decision")
+    assert seconds == pytest.approx(program["load_s"])
+    # The process totals moved by exactly what the two tables filed.
+    for stage, field in zip(("trace", "lower", "load"), _STAGE_SUMS):
+        moved = after["pipeline_s"][stage] - before["pipeline_s"][stage]
+        assert moved == pytest.approx(span[field])
+    assert after["compiles"] - before["compiles"] == 1
+    # ... and both drivers' scrapes carry it.
+    assert "probe_fresh_shape" in vc.telemetry_snapshot()["engine"]["compile"]["by_program"]
+    fleet_compile = _fleet().telemetry_snapshot()["engine"]["compile"]
+    assert fleet_compile["by_span"]["run_to_decision"]["programs"] >= 1
+    assert ["probe_fresh_shape", seconds, "run_to_decision", from_cache] in fleet_compile["recent"]
+
+
+def test_compile_outside_any_span_is_filed_outside():
+    engine_telemetry.install()
+    probe, x = _fresh_program(183)
+    before = engine_telemetry.compile_snapshot()
+    probe(x)
+    after = engine_telemetry.compile_snapshot()
+    outside = _row_delta(before, after, "by_span", engine_telemetry.OUTSIDE)
+    assert outside["programs"] == 1
+    assert all(outside[field] > 0 for field in _STAGE_SUMS)
+    assert after["recent"][-1][2] == engine_telemetry.OUTSIDE
+
+
+def test_span_stack_is_restored_under_nesting_and_after_an_exception():
+    from rapid_tpu.utils.dispatch import setup_stage
+
+    vc = _cluster()
+    spans = engine_telemetry._COLLECTOR.spans
+    assert spans == []
+    with setup_stage("create"):
+        with vc._dispatch("stream_enqueue", wave=3):
+            assert spans == ["setup.create", "stream_enqueue"]
+        assert spans == ["setup.create"]
+        with pytest.raises(RuntimeError):
+            with vc._dispatch("step"):
+                with setup_stage("create.state"):
+                    assert spans == ["setup.create", "step", "setup.create.state"]
+                    raise RuntimeError("inside two spans")
+        assert spans == ["setup.create"]
+    assert spans == []
+    # The failed blocks were still timed: the stage and the phase both count.
+    assert engine_telemetry.setup_snapshot()["create.state"]["count"] >= 1
+    assert vc.metrics.phase_timings["engine_dispatch"]["step"].count == 1
+
+
+def test_unregistered_setup_stage_raises_at_write_time():
+    from rapid_tpu.utils.dispatch import ENGINE_SETUP_STAGES, setup_stage
+
+    with pytest.raises(ValueError, match="unregistered engine set-up stage"):
+        with setup_stage("warm_up"):
+            pass
+    assert "warm_up" not in engine_telemetry.setup_snapshot()
+    assert len(ENGINE_SETUP_STAGES) <= 8
+    # A dotted stage names a registered parent.
+    assert all(s.rsplit(".", 1)[0] in ENGINE_SETUP_STAGES for s in ENGINE_SETUP_STAGES)
+
+
+def _stage_deltas(before, after):
+    return {
+        stage: {
+            field: value - before.get(stage, {}).get(field, 0)
+            for field, value in row.items()
+        }
+        for stage, row in after.items()
+    }
+
+
+def test_constructors_leave_nested_stage_sums():
+    before = engine_telemetry.setup_snapshot()
+    _cluster()
+    mid = engine_telemetry.setup_snapshot()
+    made = _stage_deltas(before, mid)
+    assert made["create"]["count"] == made["outermost"]["count"] == 1
+    assert made["create.keys"]["count"] == made["create.state"]["count"] == 1
+    assert 0 < made["create.keys"]["wall_s"] + made["create.state"]["wall_s"] <= made["create"]["wall_s"]
+    assert made["outermost"]["wall_s"] == pytest.approx(made["create"]["wall_s"])
+
+    _fleet(b=4)
+    made = _stage_deltas(mid, engine_telemetry.setup_snapshot())
+    # Four creates inside the fleet's loop: counted as stages, and inside
+    # fleet_create, which alone is outermost.
+    assert made["create"]["count"] == 4 and made["fleet_create"]["count"] == 1
+    assert made["outermost"]["count"] == 1
+    assert made["outermost"]["wall_s"] == pytest.approx(made["fleet_create"]["wall_s"])
+    assert made["create"]["wall_s"] <= made["fleet_create.tenants"]["wall_s"]
+    assert (
+        made["fleet_create.tenants"]["wall_s"] + made["fleet_create.stack"]["wall_s"]
+        <= made["fleet_create"]["wall_s"]
+    )
+
+
+def _feed(collector, stage, name, seconds, inside=()):
+    """One pipeline interval as jax records it: the start scalar, whatever
+    runs inside, the duration."""
+    event = {v: k for k, v in engine_telemetry._PIPELINE_EVENTS.items()}[stage]
+    collector.on_start(event, 0.0, fun_name=name)
+    for inner in inside:
+        _feed(collector, *inner)
+    collector.on_duration(event, seconds, fun_name=name)
+
+
+def test_by_program_stays_bounded_and_other_conserves_the_sums():
+    collector = engine_telemetry._CompileCollector()
+    rows = engine_telemetry.PROGRAM_ROWS
+    names = [f"program_{i}" for i in range(5 * rows)]
+    for i, name in enumerate(names):
+        _feed(collector, "trace", name, 0.001 * (i + 1))
+        _feed(collector, "lower", f"jit({name})", 0.002 * (i + 1))
+        _feed(collector, "load", f"jit({name})", 0.003 * (i + 1))
+        assert len(collector.by_program) <= 2 * rows + 1
+    snap = collector.snapshot()
+    table = snap["by_program"]
+    assert len(table) == rows + 1 and engine_telemetry.OTHER in table
+    # The largest stayed under their own names, whole.
+    assert table[names[-1]] == {
+        "trace_s": pytest.approx(0.001 * len(names)), "lower_s": pytest.approx(0.002 * len(names)),
+        "load_s": pytest.approx(0.003 * len(names)), "count": 1, "from_cache": 0,
+    }
+    for stage, field in zip(("trace", "lower", "load"), _STAGE_SUMS):
+        assert sum(row[field] for row in table.values()) == pytest.approx(snap["pipeline_s"][stage])
+    assert sum(row["count"] for row in table.values()) == snap["compiles"] == len(names)
+    assert len(snap["recent"]) == engine_telemetry.RECENT_LOADS
+    json.dumps(snap)
+
+
+def test_nested_pipeline_intervals_count_every_second_once():
+    collector = engine_telemetry._CompileCollector()
+    depth = collector.push_span("fleet_wave")
+    # outer's trace (1.0 s) holds inner's trace (0.4 s), which holds an eager
+    # helper's lowering and compile (0.1 + 0.2 s): trace time is 0.7 s, all
+    # outer's; the helper keeps its own.
+    _feed(collector, "trace", "outer", 1.0, inside=[
+        ("trace", "inner", 0.4, [("lower", "jit(helper)", 0.1), ("load", "jit(helper)", 0.2)]),
+    ])
+    collector.pop_span(depth)
+    snap = collector.snapshot()
+    assert snap["pipeline_s"]["trace"] == pytest.approx(0.7)
+    assert snap["pipeline_s"]["lower"] == pytest.approx(0.1)
+    assert snap["pipeline_s"]["load"] == pytest.approx(0.2)
+    assert snap["by_program"]["outer"]["trace_s"] == pytest.approx(0.7)
+    assert "inner" not in snap["by_program"]
+    assert snap["by_program"]["helper"]["load_s"] == pytest.approx(0.2)
+    assert snap["by_span"] == {"fleet_wave": {
+        "trace_s": pytest.approx(0.7), "lower_s": pytest.approx(0.1),
+        "load_s": pytest.approx(0.2), "programs": 1,
+    }}
+    assert collector.spans == []
+    # An end without its start (a jax that records no start scalar) counts whole.
+    collector.on_duration("/jax/core/compile/jaxpr_trace_duration", 0.25, fun_name="late")
+    assert collector.snapshot()["by_span"][engine_telemetry.OUTSIDE]["trace_s"] == pytest.approx(0.25)
+
+
+def test_a_load_knows_whether_the_persistent_cache_served_it():
+    collector = engine_telemetry._CompileCollector()
+    load = "/jax/core/compile/backend_compile_duration"
+    collector.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    collector.on_event("/jax/compilation_cache/cache_hits")
+    collector.on_duration("/jax/compilation_cache/compile_time_saved_sec", 3.5)
+    collector.on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.5)
+    collector.on_duration(load, 0.6, fun_name="jit(served)")
+    collector.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    collector.on_duration(load, 2.0, fun_name="jit(compiled)")
+    collector.on_duration(load, 0.1, fun_name="jit(cache_not_asked)")
+    snap = collector.snapshot()
+    assert [event[3] for event in snap["recent"]] == [True, False, None]
+    assert snap["by_program"]["served"]["from_cache"] == 1
+    assert snap["by_program"]["compiled"]["from_cache"] == 0
+    assert snap["pipeline_s"]["cache_retrieval"] == 0.5 and snap["pipeline_s"]["cache_saved"] == 3.5
+    assert snap["persistent_cache_hits"] == 1 and snap["cache_requests"] == 2
+    # An event this jax does not record reads 0 and an unknown one is dropped.
+    collector.on_duration("/jax/some/other_duration", 9.0, fun_name="x")
+    assert collector.snapshot()["pipeline_s"] == snap["pipeline_s"]
+
+
+def test_pipeline_and_setup_series_carry_stage_labels_and_no_program_label():
+    vc = _cluster()
+    text = vc.prometheus_text()
+    for stage in ("trace", "lower", "load", "cache_retrieval"):
+        assert f'rapid_engine_pipeline_seconds_total{{node="virtual-cluster/16",stage="{stage}"}}' in text
+    assert 'rapid_engine_setup_seconds_total{node="virtual-cluster/16",stage="outermost"}' in text
+    assert 'stage="create.state"' in text
+    assert "probe_fresh_shape" not in text and "fun_name" not in text and "cache_saved" not in text
+    # A snapshot written before the sums existed still renders both families.
+    legacy = vc.telemetry_snapshot()
+    legacy["engine"]["compile"] = {"compiles": 3}
+    del legacy["engine"]["setup"]
+    old = exposition.prometheus_text(legacy)
+    assert 'rapid_engine_pipeline_seconds_total{node="virtual-cluster/16",stage="load"} 0' in old
+    assert 'rapid_engine_setup_seconds_total{node="virtual-cluster/16",stage="outermost"} 0' in old
 
 
 def test_dispatch_histogram_is_bounded_and_per_entrypoint():

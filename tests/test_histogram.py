@@ -18,6 +18,7 @@ from rapid_tpu.utils.histogram import (
     LogHistogram,
     bucket_index,
     cumulative_from_summary,
+    tail_index,
 )
 from rapid_tpu.utils.metrics import Metrics
 
@@ -53,6 +54,40 @@ def test_quantiles_track_samples_within_one_bucket():
     assert hist.quantile(0.99) == 100.0  # clamped to the exact max
     assert hist.quantile(1.0) == 100.0
     assert LogHistogram().quantile(0.5) == 0.0
+
+
+def test_overflow_bucket_keeps_the_rank_bound_and_round_trips():
+    # Past the last finite bound the schedule goes on, sparsely: a quantile
+    # there is still within GROWTH of the order statistic, not the max.
+    last = UPPER_BOUNDS_MS[-1]
+    samples = [last * 1.01, last * 1.9, last * 30, 1e9]
+    hist = LogHistogram()
+    for s in samples:
+        hist.observe(s)
+    assert hist._counts[NUM_BUCKETS] == 4 and sum(hist._tail.values()) == 4
+    for q, true_q in ((0.25, samples[0]), (0.5, samples[1]), (0.75, samples[2]), (1.0, samples[3])):
+        assert true_q <= hist.quantile(q) <= true_q * GROWTH * (1 + 1e-12)
+    assert tail_index(last * 1.01) == NUM_BUCKETS
+    assert tail_index(FIRST_UPPER_MS * GROWTH**70) in (70, 71)  # an exact bound, either side of rounding
+    merged = LogHistogram().merge(hist).merge(hist)
+    assert merged.quantile(0.5) == hist.quantile(0.5) and merged.count == 8
+    back = LogHistogram.from_summary(json.loads(json.dumps(hist.summary())))
+    assert back._tail == hist._tail and back.max == hist.max
+    assert [back.quantile(q) for q in (0.25, 0.5, 0.75)] == [hist.quantile(q) for q in (0.25, 0.5, 0.75)]
+    # Prometheus still sees one +Inf bucket, and a summary written before the
+    # tail existed reads the max there, as it always did.
+    assert hist.cumulative_buckets()[-1] == ("+Inf", 4)
+    legacy = hist.summary()
+    del legacy["tail"]
+    assert LogHistogram.from_summary(legacy).quantile(0.25) == hist.max
+    # The max is kept exact (1.0625 once came back as 1.062), and an infinite
+    # sample is the max and raises nothing.
+    one = LogHistogram()
+    one.observe(1.0625)
+    assert LogHistogram.from_summary(one.summary()).max == 1.0625
+    one.observe(float("inf"))
+    assert one.quantile(1.0) == float("inf") and one._tail == {}
+    assert "tail" not in one.summary()
 
 
 def test_merge_adds_counts_and_keeps_max():
