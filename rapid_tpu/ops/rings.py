@@ -115,35 +115,113 @@ def ring_perms(key_hi: jnp.ndarray, key_lo: jnp.ndarray) -> jnp.ndarray:
     return lex_argsort((jnp.asarray(key_hi), jnp.asarray(key_lo))).astype(jnp.int32)
 
 
+def ring_walk_pieces(n: int) -> tuple[int, int]:
+    """``(piece_bits, pieces)`` of the ring walk's scan word at ring length
+    ``n`` (:func:`_from_perm_single`): a position takes the top
+    ``b = bit_length(n - 1)`` bits of a 32-bit lane, a piece of the slot the
+    ``32 - b`` bits under it, and a slot (``b`` bits too) goes in
+    ``ceil(b / (32 - b))`` pieces, one scan pair a piece: 1 piece up to
+    65,536 slots (the fleets' 1,000 and 2,000), 2 up to 2,097,152
+    (``cluster-100k``'s 102,500, ``cluster-1m``), 3 up to 16,777,216
+    (``cluster-10m``). A static fact of the shape, like
+    :data:`RING_AT_A_TIME_SLOTS`: no option, and nothing to scrape."""
+    slot_bits = max(1, (n - 1).bit_length())
+    piece_bits = 32 - slot_bits
+    return piece_bits, -(-slot_bits // piece_bits)
+
+
+_INT32_MIN, _INT32_MAX = np.int32(-(2**31)), np.int32(2**31 - 1)
+
+
+def _ordered_int32(word):
+    """uint32 -> the int32 that orders the same (top bit flipped), so that
+    the walk scans int32 lanes: the TPU compiler runs a ``cummax`` / reverse
+    ``cummin`` pair over uint32 slower and compiles it twice as long (at
+    ``[1000000]`` 2.55 ms against 1.75, first call 95 s against 45; at
+    ``[102500]`` 1.21 against 0.92 ms, 46 s against 7; PR 37, TPU v5e)."""
+    return jax.lax.bitcast_convert_type(word ^ jnp.uint32(0x80000000), jnp.int32)
+
+
+def _ordered_uint32(word):
+    """The inverse of :func:`_ordered_int32`."""
+    return jax.lax.bitcast_convert_type(word, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+@jax.jit
 def _from_perm_single(perm, alive):
     """One ring, sort-free: (obs_idx[N], subj_idx[N], order[N]) from the
-    static key order. Successor among alive = next alive position in the
-    fixed circular order (suffix-min scan); predecessor = previous
-    (prefix-max scan); the alive-first ``order`` is a stable partition
+    static key order. Successor among alive = slot at the next alive
+    position in the fixed circular order, predecessor = slot at the
+    previous one; the alive-first ``order`` is a stable partition
     (rank scans + one scatter). Bit-identical to ``_ring_topology_single``:
     restricting a fixed total order to the alive subset IS the alive
     order, and lex_argsort is stable so dead slots tie-break identically.
+
+    The walk scans WHO sits at the neighbouring position, not WHERE it is:
+    the scanned 32-bit word holds the position in its high bits and a piece
+    of the slot at that position in its low ``piece_bits``
+    (:func:`ring_walk_pieces`). Among alive positions the words order as the
+    positions do, so a suffix-min returns (in the low bits) the slot of the
+    first alive position at or after p and a prefix-max that of the last at
+    or before p; a shift by one along the ring makes the neighbour strict,
+    and a slot wider than the lane's spare bits takes one scan pair a piece,
+    put together again with shifts. No ``perm[...]`` by a computed position
+    is left (PR 37; before it the scans carried positions and two gathers
+    read the slots: 6.63 ms each of a 34.7 ms ring at 1M, where a scan pair
+    is 1.8 ms; the ring is 22.0 ms now, the fleet's ``[256, 10, 1000]``
+    52.0 ms for 115.2, TPU v5e). The words are unsigned, the lanes scanned
+    are int32 (:func:`_ordered_int32`).
+
+    Dead positions scan as the least word (prefix-max) and the greatest
+    (suffix-min). Either can tie with a real word (position 0 holding slot
+    piece 0; at n an exact power of two, the last position holding an
+    all-ones piece), and a tie carries the same low bits, so it is harmless;
+    "no alive position on that side" is never read off a word but off two
+    scalars, the first and the last alive position (element 0 of the
+    suffix-min, the last element of the prefix-max): at or past the last
+    alive position the walk wraps to the first alive slot, at or before the
+    first to the last, scalars of the same scans. With fewer than two alive
+    every entry is -1 whatever the scans hold.
+
+    Jitted, so that an eager caller dispatches one program and not each of
+    the walk's forty small operations: ``initial_state`` below
+    :data:`RING_AT_A_TIME_SLOTS` is eager, and a fleet builds hundreds of
+    tenants through it. Inside a traced caller the jit is inlined.
     """
     n = perm.shape[0]
     ao = alive[perm]  # alive bit per ring position
-    pos = jnp.arange(n, dtype=jnp.int32)
     n_alive = jnp.sum(ao.astype(jnp.int32))
+    piece_bits, pieces = ring_walk_pieces(n)
+    pos = jnp.arange(n, dtype=jnp.uint32)
+    pos_field = pos << piece_bits
+    slot = perm.astype(jnp.uint32)  # perm may come at int8 / int16 (compact)
+    piece_mask = jnp.uint32((1 << piece_bits) - 1)
 
-    idx_succ = jnp.where(ao, pos, n)  # sentinel past the end
-    suffix_min = jax.lax.cummin(idx_succ, reverse=True)
-    first_alive = suffix_min[0]
-    nxt = jnp.concatenate([suffix_min[1:], jnp.full((1,), n, dtype=jnp.int32)])
-    succ_pos = jnp.where(nxt >= n, first_alive, nxt)  # wrap to ring start
-
-    idx_pred = jnp.where(ao, pos, -1)
-    prefix_max = jax.lax.cummax(idx_pred)
-    last_alive = prefix_max[-1]
-    prv = jnp.concatenate([jnp.full((1,), -1, dtype=jnp.int32), prefix_max[:-1]])
-    pred_pos = jnp.where(prv < 0, last_alive, prv)  # wrap to ring end
+    succ_slot = pred_slot = jnp.zeros((n,), dtype=jnp.uint32)
+    for piece in range(pieces):
+        shift = piece * piece_bits
+        word = _ordered_int32(pos_field | ((slot >> shift) & piece_mask))
+        suffix_min = _ordered_uint32(
+            jax.lax.cummin(jnp.where(ao, word, _INT32_MAX), reverse=True)
+        )
+        prefix_max = _ordered_uint32(jax.lax.cummax(jnp.where(ao, word, _INT32_MIN)))
+        first_alive, last_alive = suffix_min[0], prefix_max[-1]
+        nxt = jnp.where(
+            pos >= (last_alive >> piece_bits),  # nobody alive further on: wrap
+            first_alive,
+            jnp.concatenate([suffix_min[1:], first_alive[None]]),
+        )
+        prv = jnp.where(
+            pos <= (first_alive >> piece_bits),  # nobody alive before: wrap
+            last_alive,
+            jnp.concatenate([last_alive[None], prefix_max[:-1]]),
+        )
+        succ_slot |= (nxt & piece_mask) << shift
+        pred_slot |= (prv & piece_mask) << shift
 
     valid = ao & (n_alive >= 2)
-    succ_slot = jnp.where(valid, perm[jnp.clip(succ_pos, 0, n - 1)], -1)
-    pred_slot = jnp.where(valid, perm[jnp.clip(pred_pos, 0, n - 1)], -1)
+    succ_slot = jnp.where(valid, succ_slot.astype(jnp.int32), -1)
+    pred_slot = jnp.where(valid, pred_slot.astype(jnp.int32), -1)
     # full(-1), not zeros: if perm were ever not a permutation (corrupted
     # state), unwritten entries must read as the documented "no observer"
     # sentinel, never as valid slot 0.
@@ -170,26 +248,36 @@ def _alive_first_order(perm, alive):
 #: From this many slots on, ``ring_topology_from_perm`` takes the K rings
 #: one at a time (a ``lax.map``) instead of all at once (a ``vmap``): one
 #: algorithm under two schedules, chosen from the ring length the operand
-#: shows. Two measurements place the bound (TPU v5e; PERF.md section 6):
+#: shows. Measurements that place the bound (TPU v5e; PERF.md section 6):
 #:
 #: * It may not lie above 2**22. The TPU compiler handles a scan or a
 #:   scatter over the long axis of a ``[K, N]`` array far worse than K of
 #:   them over ``[N]``: at N = 10M on a (1,4) mesh the batched form compiles
 #:   in 214 s with 8.4 GB of temporaries a device, the ring-at-a-time form in
 #:   9 s with 0.17 GB (compiled for a described v5e:2x2, PR 27).
-#: * One at a time also *runs* faster, by more the longer the ring. A whole
-#:   view change with K = 10, batched against one at a time, a 1 % and a 5 %
-#:   cut alike (PR 32, ms): 3.4 / 3.5 at 1,000 slots and 4.5 / 4.4 at 4,000
-#:   (a tie inside a call's spread), 8.9 / 8.5 at 16,000 (ranges touching),
-#:   15.2 / 13.0 at 32,000 (apart from here on), 27.9 / 23.1 at 64,000,
-#:   45.2 / 37.6 at 102,500, 104.4 / 93.3 at 262,144, 224.5 / 182.5 at
-#:   524,288, 466.6 / 344.2 at 1,000,000. In the cells: a 1M commit 874 ->
-#:   705 ms (its view change 508.5 -> 340.3, compiling as long either way,
-#:   196 / 198 s), a 100K churn commit 131.1 -> 123.9 ms, the 100K trickle
-#:   11.79 -> 12.17 view changes/s, warm set-up the same.
+#: * One at a time also *ran* faster with the walk that gathered by position
+#:   (before PR 37), by more the longer the ring. A whole view change with
+#:   K = 10, batched against one at a time, a 1 % and a 5 % cut alike (PR 32,
+#:   ms): 3.4 / 3.5 at 1,000 slots and 4.5 / 4.4 at 4,000 (a tie inside a
+#:   call's spread), 8.9 / 8.5 at 16,000 (ranges touching), 15.2 / 13.0 at
+#:   32,000 (apart from here on), 27.9 / 23.1 at 64,000, 45.2 / 37.6 at
+#:   102,500, 104.4 / 93.3 at 262,144, 224.5 / 182.5 at 524,288, 466.6 /
+#:   344.2 at 1,000,000. In the cells: a 1M commit 874 -> 705 ms (its view
+#:   change 508.5 -> 340.3, compiling as long either way, 196 / 198 s), a
+#:   100K churn commit 131.1 -> 123.9 ms, the 100K trickle 11.79 -> 12.17
+#:   view changes/s, warm set-up the same.
+#: * With the walk that carries the slot in its scan word (PR 37) the same
+#:   sweep, re-taken beside the old walk in one process (old walk: 4.37 /
+#:   4.30, 8.71 / 8.22, 15.12 / 12.97, 45.13 / 37.65), reads 3.74 / 3.71 at
+#:   4,000, 5.66 / 6.31 at 16,000 (batched ahead), 8.60 / 8.67 at 32,000 and
+#:   24.56 / 24.28 at 102,500 (ties inside a call's spread): the gathers by
+#:   position were what the batched form paid most for, and without them the
+#:   forms tie up to 102,500. Not re-taken: 262,144 and longer, where the
+#:   first reason alone holds the bound down. The bound stays where it is
+#:   (PERF.md section 7 has the open question).
 #:
 #: So the bound is the shortest swept length at which the two forms' timings
-#: lie apart. Under it the forms tie and all K rings stay in one program,
+#: lay apart when it was placed. Under it all K rings stay in one program,
 #: which is what the fleet's 1,000 slots under a tenant ``vmap`` run (there
 #: a ``lax.map`` would sit inside the ``vmap``: another question, with
 #: another control). A size read off the shape, not an option.
@@ -207,7 +295,8 @@ def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopolo
     models/state.compaction_policy) and gathers/scatters index with it
     directly; the returned tables are int32 (position arithmetic
     accumulates wide here) and the caller narrows on store. Long rings go
-    one at a time (:data:`RING_AT_A_TIME_SLOTS`), same values."""
+    one at a time (:data:`RING_AT_A_TIME_SLOTS`), same values; a ring's walk
+    scans one word pair a piece of the slot (:func:`ring_walk_pieces`)."""
     perm, alive = jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
     if perm.shape[-1] >= RING_AT_A_TIME_SLOTS:
         obs, subj, order = jax.lax.map(

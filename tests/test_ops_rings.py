@@ -87,29 +87,90 @@ def test_topology_single_and_two_nodes():
     assert (np.asarray(topo.obs_idx)[:, 4] == 2).all()
 
 
-@pytest.mark.parametrize("n,k,alive_frac", [
-    (4, 3, 1.0),      # minimum viable ring
-    (64, 10, 0.9),    # sparse deaths
-    (257, 7, 0.5),    # half dead, odd N
-    (100, 10, 0.02),  # near-empty: 2 alive
-    (50, 5, 0.0),     # nobody alive
-    (33, 4, None),    # exactly ONE alive (below the 2-node floor)
+def _alive_case(spec, n, perm, rng):
+    """The alive mask a case names: a share, or one of the walk's corners.
+    ``perm`` is ring 0's key order; the corner cases that speak of a ring
+    position are built with keys that put the same slot there in every ring
+    (``_keys``)."""
+    if isinstance(spec, float):
+        return rng.random(n) < spec
+    alive = np.zeros(n, dtype=bool)
+    if spec == "all":
+        alive[:] = True
+    elif spec == "one":  # below the 2-node floor
+        alive[n // 2] = True
+    elif spec == "two":
+        alive[[n // 3, n - 2]] = True
+    elif spec == "last_position_only":  # one alive, and the scans' wrap holds it
+        alive[perm[-1]] = True
+    elif spec == "both_ends":  # every neighbour is found by wrapping
+        alive[[perm[0], perm[-1]]] = True
+    elif spec == "slot0_at_position0_alive":  # the word 0 is a real word
+        alive = rng.random(n) < 0.5
+        alive[0] = True
+    elif spec == "slot0_at_position0_dead":
+        alive = rng.random(n) < 0.5
+        alive[0] = False
+    elif spec != "none":
+        raise ValueError(spec)
+    return alive
+
+
+def _keys(n, k, rng):
+    """Random ring keys, with slot 0 first and slot n - 1 last in EVERY
+    ring's key order, so that a case can speak of a ring position."""
+    key_hi = rng.integers(1, 2**32 - 1, size=(k, n), dtype=np.uint32)
+    key_lo = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
+    key_hi[:, 0], key_lo[:, 0] = 0, 0
+    key_hi[:, n - 1], key_lo[:, n - 1] = 2**32 - 1, 2**32 - 1
+    return key_hi, key_lo
+
+
+@pytest.mark.parametrize("n,k,alive_frac,idx", [
+    (4, 3, 1.0, np.int32),      # minimum viable ring
+    (64, 10, 0.9, np.int32),    # sparse deaths
+    (257, 7, 0.5, np.int32),    # half dead, odd N
+    (100, 10, 0.02, np.int32),  # near-empty: 2 alive
+    (50, 5, 0.0, np.int32),     # nobody alive
+    (33, 4, "one", np.int32),   # exactly ONE alive (below the 2-node floor)
+    # The packed walk (PR 37) at the lengths where its word changes shape:
+    # an exact power of two fills the position field, one above it takes a
+    # bit more; 65,536 is the last length of one piece, 102,500 takes two.
+    (1024, 3, 0.9, np.int32),
+    (1025, 3, 0.9, np.int32),
+    (65536, 2, 0.99, np.int32),
+    (65536, 2, "both_ends", np.int32),
+    (2000, 10, 0.97, np.int32),     # paper-fleet-2k's ring: one piece
+    (102_500, 2, 0.99, np.int32),   # cluster-100k's ring: two pieces
+    (102_500, 2, 0.01, np.int32),
+    (102_500, 1, "both_ends", np.int32),
+    (1000, 4, "none", np.int32),
+    (1000, 4, "one", np.int32),
+    (1000, 4, "two", np.int32),
+    (1000, 4, "all", np.int32),
+    (1000, 4, "last_position_only", np.int32),
+    (1000, 4, "both_ends", np.int32),
+    (1000, 4, "slot0_at_position0_alive", np.int32),
+    (1000, 4, "slot0_at_position0_dead", np.int32),
+    (1024, 4, "slot0_at_position0_alive", np.int32),
+    (1024, 4, "both_ends", np.int32),
+    # The compact engine hands perm over at the policy's index width.
+    (100, 10, 0.9, np.int8),
+    (127, 3, "both_ends", np.int8),
+    (2000, 10, 0.9, np.int16),
+    (32767, 2, "slot0_at_position0_alive", np.int16),
 ])
-def test_from_perm_matches_sorting_topology(n, k, alive_frac):
+def test_from_perm_matches_sorting_topology(n, k, alive_frac, idx):
     # The sort-free scan path (used by every view change) must be
     # bit-identical to the argsort definition across the aliveness range,
     # including the <2-alive floor where every entry is -1.
     rng = np.random.default_rng(n * 31 + k)
-    key_hi = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
-    key_lo = rng.integers(0, 2**32, size=(k, n), dtype=np.uint32)
-    if alive_frac is None:
-        alive = np.zeros(n, dtype=bool)
-        alive[n // 2] = True
-    else:
-        alive = rng.random(n) < alive_frac
+    key_hi, key_lo = _keys(n, k, rng)
     perm = ring_perms(key_hi, key_lo)
+    alive = _alive_case(alive_frac, n, np.asarray(perm[0]), rng)
     want = ring_topology(key_hi, key_lo, alive)
-    got = ring_topology_from_perm(perm, alive)
+    got = jax.jit(ring_topology_from_perm)(perm.astype(idx), alive)
+    assert got.obs_idx.dtype == got.subj_idx.dtype == np.int32
     np.testing.assert_array_equal(np.asarray(got.obs_idx), np.asarray(want.obs_idx))
     np.testing.assert_array_equal(np.asarray(got.subj_idx), np.asarray(want.subj_idx))
     np.testing.assert_array_equal(np.asarray(got.order), np.asarray(want.order))
@@ -125,6 +186,32 @@ def test_from_perm_matches_sorting_topology(n, k, alive_frac):
             predecessor_of_keys(key_hi, key_lo, alive, qhi, qlo, perm=perm)
         ),
     )
+
+
+@pytest.mark.parametrize("n,alive_frac", [
+    (1000, 0.9),            # one piece (the fleet's ring)
+    (70_000, 0.99),         # two pieces
+    (70_000, "both_ends"),
+])
+def test_both_schedules_of_the_rings_give_the_sorting_topology(n, alive_frac, monkeypatch):
+    # All K rings at once (``vmap``) and one at a time (``lax.map``) are one
+    # walk under two schedules: the same input, the same tables, the oracle's.
+    k = 3
+    rng = np.random.default_rng(n)
+    key_hi, key_lo = _keys(n, k, rng)
+    perm = ring_perms(key_hi, key_lo)
+    alive = _alive_case(alive_frac, n, np.asarray(perm[0]), rng)
+    want = ring_topology(key_hi, key_lo, alive)
+    for bound, loop in ((n + 1, []), (n, ["scan"])):
+        monkeypatch.setattr(rings, "RING_AT_A_TIME_SLOTS", bound)
+        form = jax.jit(lambda p, a: ring_topology_from_perm(p, a))  # traced after the patch
+        assert [
+            name for name in _primitives(jax.make_jaxpr(form)(perm, alive).jaxpr)
+            if name in ("scan", "while")
+        ] == loop
+        got = form(perm, alive)
+        np.testing.assert_array_equal(np.asarray(got.obs_idx), np.asarray(want.obs_idx))
+        np.testing.assert_array_equal(np.asarray(got.subj_idx), np.asarray(want.subj_idx))
 
 
 def test_expected_observers_of_joiners():
@@ -194,3 +281,50 @@ def test_the_threshold_lies_between_the_cells_it_separates():
     # cluster-1m and cluster-10m (whose compile time placed the first bound,
     # 2**22, PR 27) walk them one at a time.
     assert 1000 < rings.RING_AT_A_TIME_SLOTS <= 102_500
+
+
+#: Ring length -> the pieces a slot takes in the walk's scan word
+#: (``rings.ring_walk_pieces``): the benchmark's configurations and the
+#: lengths on either side of each step.
+WALK_PIECES = [
+    (2, 1), (1000, 1), (2000, 1), (65_536, 1), (65_537, 2), (102_500, 2),
+    (1_000_000, 2), (2_097_152, 2), (2_097_153, 3), (10_000_000, 3),
+    (16_777_216, 3), (16_777_217, 4),
+]
+
+
+@pytest.mark.parametrize("n,pieces", WALK_PIECES)
+def test_the_ring_length_gives_the_walks_piece_count(n, pieces):
+    # Traced over shapes alone (nothing of ten million slots is made or run):
+    # one prefix-max and one suffix-min a piece, and the only gathers are
+    # ``alive[perm]`` (the walk's and ``_alive_first_order``'s own).
+    piece_bits, got = rings.ring_walk_pieces(n)
+    assert got == pieces
+    slot_bits = 32 - piece_bits
+    assert (n - 1) < 1 << slot_bits and pieces * piece_bits >= slot_bits
+    traced = jax.make_jaxpr(rings._from_perm_single)(
+        jax.ShapeDtypeStruct((n,), np.int32), jax.ShapeDtypeStruct((n,), np.bool_)
+    )
+    names = list(_primitives(traced.jaxpr))
+    assert names.count("cummax") == names.count("cummin") == pieces
+    assert names.count("gather") == 2
+    assert [v.aval.dtype for v in traced.jaxpr.outvars] == [np.int32] * 3
+
+
+def test_the_walk_gathers_nothing_by_a_position_it_computed():
+    """Lowered text only (no compile): of a function that returns just
+    ``obs_idx`` and ``subj_idx`` of one ring at ``[4096]``, the one gather
+    left is ``alive[perm]`` and the two scatters are the ``.at[perm].set``.
+    On the parent (before PR 37) the same text holds THREE gathers: the two
+    more are ``perm[succ_pos]`` and ``perm[pred_pos]``, by the positions its
+    own scans had just produced."""
+    lowered = jax.jit(lambda p, a: rings._from_perm_single(p, a)[:2]).lower(
+        jax.ShapeDtypeStruct((4096,), np.int32), jax.ShapeDtypeStruct((4096,), np.bool_)
+    )
+    text = lowered.as_text()
+    assert text.count('"stablehlo.gather"(') == 1
+    assert text.count('"stablehlo.scatter"(') == 2
+    assert text.count('"stablehlo.reduce_window"(') == 2  # 4,096 slots: one piece
+    gather = next(l for l in text.splitlines() if '"stablehlo.gather"(' in l)
+    assert "tensor<4096xi1>" in gather  # it reads the alive mask
+

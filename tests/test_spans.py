@@ -317,15 +317,20 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: ``fleet_run_to_decision`` is PR 30's: it names its batch axis, and the
 #: round's conditionals in it are conditionals; the other four name none.
 #: ``run_until_membership`` is PR 34's: it builds its masks at the head of
-#: each convergence and no longer in the cut's arm.
+#: each convergence and no longer in the cut's arm. All five were re-taken
+#: at PR 37: each holds the view change, whose ring walk now scans words that
+#: carry the neighbour's slot (``ops/rings.py::_from_perm_single``) and no
+#: longer gathers ``perm`` by the positions it scanned; nothing else in them
+#: moved (``edge_masks_build`` and the other programs without a view change
+#: lower to the parent's text).
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "8b3e59538974cb08",
-    "fleet_run_to_decision": "e415a468c57334a8",
-    "mesh_run_to_decision": "2e585f0987f0656f",
-    "mesh_step": "9343185e4ea60084",
-    "mesh_fleet_step": "721316093e3bd78d",
+    "run_until_membership": "d3137e28c54a50a8",
+    "fleet_run_to_decision": "d9544e797625e589",
+    "mesh_run_to_decision": "1deb6dfbff3b0481",
+    "mesh_step": "019dfe463db06908",
+    "mesh_fleet_step": "b4b9ec392b1f54ea",
 }
 
 
