@@ -464,6 +464,47 @@ class FaultInputs(NamedTuple):
         )
 
 
+#: Loss in permille at which a member's ingress is wholly dead: every probe
+#: into it and every reply to it is lost, and it hears no alert or proposal.
+LINK_LOSS_DEAD = 1000
+
+
+class LinkFaults(NamedTuple):
+    """One-way link faults as a device-resident lane of its own beside
+    :class:`FaultInputs`: per-member INGRESS loss and one on/off schedule
+    for all faulty members (the paper's Fig. 9 flip-flopping one-way
+    partition at 1000 permille, its Fig. 10 lossy ingress below that). A
+    faulty member keeps sending: it is reported by its observers and reports
+    its own subjects, whose replies it does not hear.
+
+    The lane is OPTIONAL at the Python level, on the observers' pattern:
+    the round programs take it as the keyword ``links`` and hand it back
+    last; with ``None`` they trace not one operation more, so a cluster
+    that never set it runs the programs it always ran. One engine round is
+    one failure-detector interval. The lane keeps its own clock (``age``):
+    ``round_idx`` starts again at every view change and a schedule must not.
+    """
+
+    loss_permille: jnp.ndarray  # [n] int32 — ingress loss per member, 0 = healthy
+    on_rounds: jnp.ndarray  # [] int32 — rounds of a period the faults are on
+    off_rounds: jnp.ndarray  # [] int32 — rounds they are off; 0 = always on
+    seed: jnp.ndarray  # [] uint32 — salt of the probe draws
+    age: jnp.ndarray  # [] int32 — rounds run since the lane was set
+    probes_lost: jnp.ndarray  # [] int32 — probes the lane failed since it was set
+
+    @staticmethod
+    def none(cfg: EngineConfig) -> "LinkFaults":
+        """A set lane that names nobody (the setter scatters into it)."""
+        return LinkFaults(
+            loss_permille=jnp.zeros((cfg.n,), dtype=jnp.int32),
+            on_rounds=jnp.int32(0),
+            off_rounds=jnp.int32(0),
+            seed=jnp.uint32(0),
+            age=jnp.int32(0),
+            probes_lost=jnp.int32(0),
+        )
+
+
 class StepEvents(NamedTuple):
     """Observable outcomes of one engine step (host-side driver reads these)."""
 

@@ -18,17 +18,19 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from rapid_tpu.models.state import (
+    LINK_LOSS_DEAD,
     TELEMETRY_BUCKETS,
     EngineConfig,
     EngineState,
     FaultInputs,
+    LinkFaults,
     StepEvents,
     TelemetryLanes,
     TraceRing,
@@ -102,7 +104,69 @@ def _edge_masks(cfg: EngineConfig, state: EngineState, faults: FaultInputs):
 
 
 @scope("fd_tick")
-def _fd_tick(cfg: EngineConfig, state: EngineState, faults: FaultInputs, observer_active):
+def _observer_loss(cfg: EngineConfig, state: EngineState, links: LinkFaults):
+    """``[n, k]`` uint32: the lane's loss at the OBSERVER of every (subject,
+    ring) edge, schedule apart. THE gather of the lane, and a function of the
+    lane and the topology alone, which no round of a convergence changes:
+    the convergence loop computes it once, before its rounds
+    (:func:`_converge`), as every loop hoists the per-edge masks."""
+    obs = state.obs_idx.T  # [n, k]
+    at_observer = links.loss_permille.astype(jnp.uint32)[
+        jnp.clip(obs, 0, cfg.n - 1).astype(jnp.int32)
+    ]
+    return jnp.where(obs >= 0, at_observer, jnp.uint32(0))
+
+
+@scope("fd_tick")
+def link_probe_draws(
+    cfg: EngineConfig, state: EngineState, links: LinkFaults, observer_loss=None
+):
+    """This round's probe outcomes under the link-fault lane: ``(lost[n, k],
+    deaf[n])``. The probe of edge (subject s, ring j, observer o) is lost
+    when the request is lost at s's ingress or the reply at o's: two
+    independent draws from a hash of (edge, ``round_idx``, configuration
+    epoch, the lane's seed) against the two members' loss, the hash-stream
+    idiom of :func:`_deliver_alerts`. ``x % 1000`` lies in [0, 999], so
+    1000 permille loses every probe and 0 none whatever is drawn. In a round
+    of the schedule's off-phase every member's loss is 0. ``deaf`` names the
+    members whose ingress is wholly dead in this round: they hear no alert
+    and no proposal, so the tally leaves them out.
+
+    A pure function of ``obs_idx``, two scalars of the state and the lane:
+    the tests fetch it round by round and replay the detector over it
+    (``benchmarks/link_model.py``). ``observer_loss`` is
+    :func:`_observer_loss` of the same state and lane where a loop has it
+    already; the schedule gates after it."""
+    n, k = cfg.n, cfg.k
+    period = jnp.maximum(links.on_rounds + links.off_rounds, 1)
+    on = (links.off_rounds == 0) | (jnp.remainder(links.age, period) < links.on_rounds)
+    if observer_loss is None:
+        observer_loss = _observer_loss(cfg, state, links)
+    loss_obs = jnp.where(on, observer_loss, jnp.uint32(0))  # [n, k]
+    loss = jnp.where(on, links.loss_permille.astype(jnp.uint32), jnp.uint32(0))  # [n]
+    round_salt = (
+        (state.round_idx.astype(jnp.uint32) * jnp.uint32(0x9E3779B1))
+        ^ (state.config_epoch.astype(jnp.uint32) * jnp.uint32(0x27D4EB2F))
+        ^ links.seed
+    )
+    request = mix32(
+        (jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(0x85EBCA77))[:, None]
+        ^ (jnp.arange(k, dtype=jnp.uint32) * jnp.uint32(0xC2B2AE3D))[None, :]
+        ^ round_salt
+    )
+    # An independent stream for the reply, as the delivery gate's is.
+    reply = mix32(request ^ jnp.uint32(0xA511E9B3))
+    lost = ((request % jnp.uint32(1000)) < loss[:, None]) | (
+        (reply % jnp.uint32(1000)) < loss_obs
+    )
+    return lost, loss >= LINK_LOSS_DEAD
+
+
+@scope("fd_tick")
+def _fd_tick(
+    cfg: EngineConfig, state: EngineState, faults: FaultInputs, observer_active,
+    link_lost=None,
+):
     """Every observer probes its subjects; edges past the failure threshold
     emit one DOWN alert (semantics of PingPongFailureDetector + the
     edge-failure notification path, MembershipService.java:472-495).
@@ -112,6 +176,8 @@ def _fd_tick(cfg: EngineConfig, state: EngineState, faults: FaultInputs, observe
     when >= fd_threshold of the last fd_window probe outcomes failed.
     Intermittent blips age out of the window; the counter latches them."""
     subject_down = faults.crashed[:, None] | faults.probe_fail
+    if link_lost is not None:  # the link-fault lane's draws of this round
+        subject_down = subject_down | link_lost
     probe_failed = observer_active & subject_down & state.alive[:, None]
 
     if cfg.fd_window:
@@ -246,6 +312,8 @@ def _compute_round(
     trace: Optional[TraceRing] = None,
     *,
     batch_axis=None,
+    links: Optional[LinkFaults] = None,
+    observer_loss=None,
 ):
     """One protocol round WITHOUT view-change application: returns the
     round-advanced state plus (decided, winner_mask, events). Keeping the
@@ -285,14 +353,37 @@ def _compute_round(
     ``classic``) stay conditionals under the ``vmap``, each taken when SOME
     member of the batch needs its arm (``utils/dispatch.cond_across``), and
     the return ends with one more element: ``int32[2]``, whether
-    ``invalidation`` and ``classic`` ran in this round, not batched."""
+    ``invalidation`` and ``classic`` ran in this round, not batched.
+
+    ``links`` (the link-fault lane, :class:`LinkFaults`): a fourth
+    Python-level branch. With ``None`` not one traced operation changes.
+    With a lane the round draws its probes' outcomes (:func:`link_probe_draws`,
+    OR-ed into the detector's tick beside ``faults.probe_fail``), leaves a
+    member whose ingress is wholly dead out of ``can_vote``, and the return
+    ends with the lane, a round older and with the probes it failed added.
+    ``observer_loss`` is the lane's gather where the caller's loop made it
+    before its rounds (:func:`_observer_loss`)."""
     n, k, c = cfg.n, cfg.k, cfg.c
 
     # 1. Failure-detector tick -> fresh DOWN alerts per (subject, ring) edge.
     if edge_masks is None:
         edge_masks = _edge_masks(cfg, state, faults)
     observer_active, blocked_rows = edge_masks
-    fd_count, fd_hist, fd_fired, fire = _fd_tick(cfg, state, faults, observer_active)
+    link_lost = deaf = None
+    if links is not None:
+        link_lost, deaf = link_probe_draws(cfg, state, links, observer_loss)
+        with scope("fd_tick"):
+            links = links._replace(
+                age=links.age + 1,
+                probes_lost=links.probes_lost + jnp.sum(
+                    link_lost & observer_active & state.alive[:, None],
+                    dtype=jnp.int32,
+                ),
+            )
+    links_out = _lane_tail(links)
+    fd_count, fd_hist, fd_fired, fire = _fd_tick(
+        cfg, state, faults, observer_active, link_lost
+    )
     # Stamp at the lane's (policy) dtype: round_idx is int32 and a bare
     # where() would re-widen the whole [n, k] lane. In-envelope round
     # indices (< fire_never) cast losslessly.
@@ -358,6 +449,8 @@ def _compute_round(
         cohort = state.cohort_of
         cohort_announced = announced[cohort]
         can_vote = state.alive & ~faults.crashed & ~state.vote_valid & cohort_announced
+        if deaf is not None:  # hears no proposal this round; may vote in a later one
+            can_vote = can_vote & ~deaf
         vote_hi = jnp.where(can_vote, prop_hi[cohort], state.vote_hi)
         vote_lo = jnp.where(can_vote, prop_lo[cohort], state.vote_lo)
         vote_valid = state.vote_valid | can_vote
@@ -608,7 +701,7 @@ def _compute_round(
                 jnp.stack([invalidation_ran, classic_ran]).astype(jnp.int32),
             )
     if telem is None:
-        return (round_state, decided, winner_mask, events, *arms_ran)
+        return (round_state, decided, winner_mask, events, *arms_ran, *links_out)
 
     # Device telemetry plane (write-only; see the docstring contract).
     # Scalars reuse reductions computed above; [c, n]/[c] lanes accumulate
@@ -636,7 +729,7 @@ def _compute_round(
             tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
         )
     if trace is None:
-        return (round_state, decided, winner_mask, events, telem, *arms_ran)
+        return (round_state, decided, winner_mask, events, telem, *arms_ran, *links_out)
 
     # Device round-trace ring (write-only; one record per round into slot
     # cursor % R). Every field is a scalar computed above — the ring adds
@@ -670,7 +763,7 @@ def _compute_round(
             tr_cursor=trace.tr_cursor + 1,
             tr_wraps=trace.tr_wraps + (slot == cfg.trace - 1).astype(jnp.int32),
         )
-    return (round_state, decided, winner_mask, events, telem, trace, *arms_ran)
+    return (round_state, decided, winner_mask, events, telem, trace, *arms_ran, *links_out)
 
 
 def _rotation_seed(epoch_u32, j: int):
@@ -804,20 +897,37 @@ def _view_change_gate_masks(
 # ``observers`` is ``()``, ``(telem,)`` or ``(telem, trace)``, handed to
 # ``_compute_round`` as it takes them and carried through a loop directly
 # after the state. A driver jits one body once per observer count.
+#
+# The link-fault lane rides outside the positions: the one-device programs
+# take it as the keyword ``links`` and, given one, hand it back LAST, after
+# the observations. A call without the keyword is the call it always was.
 
 
-def engine_step_impl(cfg: EngineConfig, state: EngineState, *rest):
+def _lane_tail(links) -> tuple:
+    """What a program's return ends with: the lane where one is set."""
+    return () if links is None else (links,)
+
+
+def _lane_off(outputs, links):
+    """``(outputs, lane)`` of a ``_compute_round`` return: the lane is its
+    last element where one went in, else ``None``."""
+    if links is None:
+        return outputs, None
+    return outputs[:-1], outputs[-1]
+
+
+def engine_step_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
     """One full protocol round including conditional view-change application:
     the MESH's per-round step (``sharded_program("step")``) and the
     analyzers' reference, which builds the per-edge masks in every round.
     ``rest`` is ``(*observers, faults)``; returns ``(state, *observers,
     events)``."""
     *observers, faults = rest
-    round_state, decided, winner_mask, events, *observers = _compute_round(
-        cfg, state, faults, None, *observers
+    (round_state, decided, winner_mask, events, *observers), links = _lane_off(
+        _compute_round(cfg, state, faults, None, *observers, links=links), links
     )
     new_state = _view_change_gate(cfg, round_state, decided, winner_mask)
-    return (new_state, *observers, events)
+    return (new_state, *observers, events, *_lane_tail(links))
 
 
 # Donating step for the long-running driver loop (state buffers reused in
@@ -826,7 +936,7 @@ engine_step = jax.jit(engine_step_impl, static_argnums=(0,), donate_argnums=(1,)
 engine_step_nodonate = jax.jit(engine_step_impl, static_argnums=(0,))  # donate-ok: compile-check / dry-run variant; callers keep their state buffers
 
 
-def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest):
+def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
     """The MESHLESS per-round step the driver dispatches: the math of
     :func:`engine_step_impl` with the per-edge masks CARRIED from round to
     round beside the state instead of rebuilt in every round. ``rest`` is
@@ -845,18 +955,35 @@ def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest):
 
     Returns ``(state, *observers, events, masks)``."""
     *observers, faults, masks = rest
-    round_state, decided, winner_mask, events, *observers = _compute_round(
-        cfg, state, faults, masks, *observers
+    (round_state, decided, winner_mask, events, *observers), links = _lane_off(
+        _compute_round(cfg, state, faults, masks, *observers, links=links), links
     )
     new_state, masks = _view_change_gate_masks(
         cfg, round_state, faults, masks, decided, winner_mask
     )
-    return (new_state, *observers, events, masks)
+    return (new_state, *observers, events, masks, *_lane_tail(links))
 
 
 #: The build program: dispatched by the driver only when the masks it
 #: carries are not those of the inputs it is about to pass.
 edge_masks_build = jax.jit(_edge_masks, static_argnums=(0,))  # donate-ok: reads four leaves of a state that stays live
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def link_faults_place(n: int, packed) -> LinkFaults:
+    """A new link-fault lane in one upload and one dispatch: ``packed`` is
+    ``uint32[4 + m]``, the four controls (loss in permille, on rounds, off
+    rounds, seed) and then the ``m`` faulty slots of ``n``. The lane's clock
+    and its count start at 0."""
+    controls, idx = packed[:4].astype(jnp.int32), packed[4:].astype(jnp.int32)
+    return LinkFaults(
+        loss_permille=jnp.zeros((n,), dtype=jnp.int32).at[idx].set(controls[0]),
+        on_rounds=controls[1],
+        off_rounds=controls[2],
+        seed=packed[3],
+        age=jnp.int32(0),
+        probes_lost=jnp.int32(0),
+    )
 
 
 def telemetry_digest_impl(telem: TelemetryLanes) -> jnp.ndarray:
@@ -912,13 +1039,13 @@ trace_digest = jax.jit(trace_digest_impl)  # donate-ok: read-only boundary fetch
 
 
 @scope("sync_checksum")
-def sync_checksum_impl(state: EngineState, faults: FaultInputs):
+def sync_checksum_impl(state: EngineState, faults: FaultInputs, links=None):
     """Scalar checksum depending on every state/fault array — the barrier
     ``VirtualCluster.sync`` fetches (a scalar that depends on every array
     cannot arrive before all of them are computed).
     Module-level and jitted so the compiled-program gate audits the sync
     dispatch like every other registered entrypoint."""
-    return (
+    checksum = (
         jnp.sum(state.key_hi, dtype=jnp.uint32)
         + jnp.sum(state.key_lo, dtype=jnp.uint32)
         + jnp.sum(state.id_hi, dtype=jnp.uint32)
@@ -930,6 +1057,11 @@ def sync_checksum_impl(state: EngineState, faults: FaultInputs):
         + jnp.sum(faults.crashed).astype(jnp.uint32)
         + jnp.sum(faults.probe_fail).astype(jnp.uint32)
     )
+    if links is not None:  # a set lane's scatter is behind the barrier too
+        checksum = checksum + sum(
+            jnp.sum(leaf).astype(jnp.uint32) for leaf in links
+        )
+    return checksum
 
 
 sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the state stays live
@@ -937,7 +1069,7 @@ sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the
 
 def _converge(
     cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
-    masks, steps, max_steps, batch_axis=None,
+    masks, steps, max_steps, batch_axis=None, links=None,
 ):
     """THE inner convergence loop: rounds over fixed per-edge ``masks``
     (topology and faults are fixed until a cut commits, so the per-edge
@@ -945,39 +1077,54 @@ def _converge(
     decides or ``steps`` reaches ``max_steps``. The round body stays
     sort-free: the caller applies the (at most one) view change after the
     loop, so the ring rebuild runs exactly once per convergence. Returns
-    ``(round_state, observers, steps, decided, winner_mask, arm_rounds)``.
+    ``(round_state, observers, steps, decided, winner_mask, arm_rounds,
+    links)``.
 
     ``batch_axis`` goes down to the round (:func:`_compute_round`). With a
     name the loop also carries ``arm_rounds``, ``int32[2]``: the rounds in
     which ``invalidation`` and ``classic`` ran. With ``None`` it is ``None``,
-    a pytree of no leaves: the carry is the one it always was."""
+    a pytree of no leaves: the carry is the one it always was. So is the
+    link-fault lane (``links``) where none is set; a set lane rides the
+    carry's end and ages with every round, and its one gather (the loss at
+    every edge's observer) is made here, once, beside the masks."""
+    observer_loss = None if links is None else _observer_loss(cfg, state, links)
 
     def cond(carry):
-        *_, steps, decided, _, _ = carry
+        *_, steps, decided, _, _, _ = carry
         return (~decided) & (steps < max_steps)
 
     def body(carry):
-        state, *observers, steps, _, _, arm_rounds = carry
-        round_state, decided, winner_mask, _, *observers = _compute_round(
-            cfg, state, faults, masks, *observers, batch_axis=batch_axis
+        state, *observers, steps, _, _, arm_rounds, links = carry
+        (round_state, decided, winner_mask, _, *observers), links = _lane_off(
+            _compute_round(
+                cfg, state, faults, masks, *observers, batch_axis=batch_axis,
+                links=links, observer_loss=observer_loss,
+            ),
+            links,
         )
         if batch_axis is not None:
             *observers, arms_ran = observers
             arm_rounds = arm_rounds + arms_ran
-        return (round_state, *observers, steps + 1, decided, winner_mask, arm_rounds)
+        return (
+            round_state, *observers, steps + 1, decided, winner_mask, arm_rounds,
+            links,
+        )
 
     init = (
         state, *observers, steps, jnp.bool_(False),
         jnp.zeros((cfg.n,), dtype=bool),
         None if batch_axis is None else jnp.zeros((2,), dtype=jnp.int32),
+        links,
     )
-    state, *observers, steps, decided, winner, arm_rounds = jax.lax.while_loop(
+    state, *observers, steps, decided, winner, arm_rounds, links = jax.lax.while_loop(
         cond, body, init
     )
-    return state, observers, steps, decided, winner, arm_rounds
+    return state, observers, steps, decided, winner, arm_rounds, links
 
 
-def run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest, batch_axis=None):
+def run_to_decision_impl(
+    cfg: EngineConfig, state: EngineState, *rest, batch_axis=None, links=None
+):
     """Protocol rounds until a view change commits — entirely on device.
 
     A ``lax.while_loop`` around the round: the host dispatches ONE
@@ -992,16 +1139,17 @@ def run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest, batch_axi
     """
     *observers, faults, max_steps = rest
     masks = _edge_masks(cfg, state, faults)
-    state, observers, steps, decided, winner, arm_rounds = _converge(
-        cfg, state, observers, faults, masks, jnp.int32(0), max_steps, batch_axis
+    state, observers, steps, decided, winner, arm_rounds, links = _converge(
+        cfg, state, observers, faults, masks, jnp.int32(0), max_steps, batch_axis,
+        links,
     )
     state = _view_change_gate(cfg, state, decided, winner)
     if batch_axis is None:
-        return (state, *observers, steps, decided, winner)
-    return (state, *observers, steps, decided, winner, arm_rounds)
+        return (state, *observers, steps, decided, winner, *_lane_tail(links))
+    return (state, *observers, steps, decided, winner, arm_rounds, *_lane_tail(links))
 
 
-def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
+def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
     """Protocol rounds through MULTIPLE view changes until the membership
     reaches ``target`` — one device dispatch for a whole churn/bootstrap
     wave instead of one per cut. ``rest`` is ``(*observers, faults, target,
@@ -1035,19 +1183,19 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
     *observers, faults, target, max_steps, max_cuts, min_cuts = rest
 
     def outer_cond(carry):
-        state, *_, steps, cuts, stalled, _ = carry
+        state, *_, steps, cuts, stalled, _, _ = carry
         resolved = (state.n_members == target) & (cuts >= min_cuts)
         return (~resolved) & (~stalled) & (steps < max_steps) & (cuts < max_cuts)
 
     def outer_body(carry):
-        state, *observers, steps, cuts, _, sizes = carry
+        state, *observers, steps, cuts, _, sizes, links = carry
         # Built here, where the convergence reads them, and not in the cut's
         # arm: every iteration starts from a topology no build has seen (the
         # wave's first, or the one a commit just left), and the wave's last
         # commit is followed by no round that would read a rebuild.
         masks = _edge_masks(cfg, state, faults)
-        state, observers, steps, decided, winner, _ = _converge(
-            cfg, state, observers, faults, masks, steps, max_steps
+        state, observers, steps, decided, winner, _, links = _converge(
+            cfg, state, observers, faults, masks, steps, max_steps, links=links
         )
         state = _view_change_gate(cfg, state, decided, winner)
         with scope("loop_result"):
@@ -1058,7 +1206,7 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
         # progress (the outer loop would spin): latch and exit.
         return (
             state, *observers, steps, cuts + decided.astype(jnp.int32),
-            ~decided, sizes,
+            ~decided, sizes, links,
         )
 
     init = (
@@ -1068,13 +1216,14 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest):
         jnp.int32(0),
         jnp.bool_(False),
         jnp.full((max_cuts,), -1, dtype=jnp.int32),
+        links,
     )
-    state, *observers, steps, cuts, _, sizes = jax.lax.while_loop(
+    state, *observers, steps, cuts, _, sizes, links = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
     with scope("loop_result"):
         resolved = (state.n_members == target) & (cuts >= min_cuts)
-    return (state, *observers, steps, cuts, resolved, sizes)
+    return (state, *observers, steps, cuts, resolved, sizes, *_lane_tail(links))
 
 
 def jit_per_observer_count(impl, static=(), donated=()):
@@ -1182,6 +1331,17 @@ class VirtualCluster(DispatchSeam):
         self.mesh = mesh
         self.state = state if mesh is None else _mesh_lib().adopt(state, mesh)
         self.faults = self._fresh(FaultInputs.none)
+        # The link-fault lane (models/state.LinkFaults): None until
+        # ``set_link_faults`` names somebody, and while it is None every verb
+        # dispatches the program it always did. ``_link_lost_seen`` is what
+        # of the lane's ``probes_lost`` the counter has already been given,
+        # and ``_links_kept`` the lane the last dispatch handed back: a lane
+        # that is not that object was set or assigned since (the identity
+        # idiom of ``CarriedMasks``), and the counter takes all it has lost.
+        self.links: Optional[LinkFaults] = None
+        self._links_kept: Optional[LinkFaults] = None
+        self._link_lost_seen = 0
+        self._controls: Dict[int, jnp.ndarray] = {}  # see ``_control``
         self._rng = np.random.default_rng(0)
         # Engine-level telemetry: host-side counters over device dispatches
         # (the per-node flight recorder has no device analog — the engine's
@@ -1430,9 +1590,9 @@ class VirtualCluster(DispatchSeam):
 
     # -- fault & membership injection ----------------------------------
 
-    def _slot_index(self, slots: Sequence[int]) -> jnp.ndarray:
-        """Host-side bounds check, then upload. jnp's gather/scatter CLAMPS
-        out-of-range indices instead of raising (a typo'd slot would silently
+    def _checked_slots(self, slots: Sequence[int]) -> np.ndarray:
+        """Host-side bounds check. jnp's gather/scatter CLAMPS out-of-range
+        indices instead of raising (a typo'd slot would silently
         inspect/mutate slot n-1), so every lifecycle mutation validates on
         host where it is free — no extra fetch, the indices originate here."""
         arr = np.asarray(slots, dtype=np.int32)
@@ -1441,6 +1601,11 @@ class VirtualCluster(DispatchSeam):
                 f"slot indices out of range [0, {self.cfg.n}): "
                 f"{arr[(arr < 0) | (arr >= self.cfg.n)].tolist()}"
             )
+        return arr
+
+    def _slot_index(self, slots: Sequence[int]) -> jnp.ndarray:
+        """Host-side bounds check (:meth:`_checked_slots`), then upload."""
+        arr = self._checked_slots(slots)
         self._account_h2d(arr)
         return jnp.asarray(arr)
 
@@ -1511,6 +1676,70 @@ class VirtualCluster(DispatchSeam):
         # index (a self.crash(slots) call would bounds-check and upload again).
         self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(True))
         self._note_placement()
+
+    def set_link_faults(
+        self, slots: Sequence[int], loss_permille: int = LINK_LOSS_DEAD,
+        on_rounds: int = 0, off_rounds: int = 0, seed: int = 0,
+    ) -> None:
+        """One-way link faults on the given slots (models/state.LinkFaults):
+        each loses ``loss_permille`` of what is sent TO it and keeps sending,
+        in the on-phases of a schedule of ``on_rounds`` on / ``off_rounds``
+        off that starts with the next round (``off_rounds`` 0: always on).
+        The call replaces whatever lane stood before; with no slots it
+        clears it, and the cluster is back on the programs of a cluster that
+        never had one. Device-side scatter: only the slot indices and four
+        scalars cross the host->device boundary, in ONE upload (what a
+        transfer costs the host is the call, not the bytes). The per-edge
+        masks stay valid (they read ``alive``, ``crashed`` and ``rx_block``
+        only)."""
+        if self.mesh is not None:
+            raise ValueError(
+                "link faults are off under a mesh: the lane has no "
+                "partition rule (parallel/mesh.PARTITION_RULES)"
+            )
+        if not 0 <= loss_permille <= LINK_LOSS_DEAD:
+            raise ValueError(
+                f"loss_permille must be in [0, {LINK_LOSS_DEAD}], got {loss_permille}"
+            )
+        if on_rounds < 0 or off_rounds < 0 or (off_rounds and not on_rounds):
+            raise ValueError(
+                f"need on_rounds >= 0 and off_rounds >= 0, and an on-phase "
+                f"where there is an off-phase: got {on_rounds} on, {off_rounds} off"
+            )
+        with self._dispatch("inject_link_faults"):
+            # Minted with the first lane, so the series is in every scrape
+            # from then on; a cluster that never sets one never grows it.
+            self.metrics.inc("engine_link_probes_lost", 0)
+            if not len(slots):
+                self.links = None
+                return
+            packed = np.concatenate([
+                np.asarray(
+                    [loss_permille, on_rounds, off_rounds, seed & 0xFFFFFFFF],
+                    dtype=np.uint32,
+                ),
+                self._checked_slots(slots).astype(np.uint32),
+            ])
+            self._account_h2d(packed)
+            self.links = link_faults_place(self.cfg.n, jnp.asarray(packed))
+
+    def _fetch(self, observation) -> np.ndarray:
+        """A verb's int32 observation, fetched flat and charged to the
+        transfer counter. A set lane's ``probes_lost`` rides the same
+        transfer, four bytes more, and ``engine_link_probes_lost`` gets what
+        the lane lost since its last fetch."""
+        if self.links is not None:
+            observation = jnp.concatenate(
+                [jnp.ravel(observation), self.links.probes_lost[None]]
+            )
+        fetched = np.asarray(observation).reshape(-1)
+        self._account_d2h(fetched.nbytes)
+        if self.links is not None:
+            lost = int(fetched[-1])
+            self.metrics.inc("engine_link_probes_lost", lost - self._link_lost_seen)
+            self._link_lost_seen = lost
+            fetched = fetched[:-1]
+        return fetched
 
     def set_flaky_edges(self, probe_fail: np.ndarray) -> None:
         """Arbitrary per-(subject, ring) probe failures — asymmetric/one-way
@@ -1667,7 +1896,15 @@ class VirtualCluster(DispatchSeam):
             program = _mesh_lib().sharded_program(
                 verb, self.cfg, self.mesh, len(carried), max_cuts
             )
-        out = program(*carried, self.faults, *controls)
+        if self.links is None:
+            out = program(*carried, self.faults, *controls)
+        else:
+            if self.links is not self._links_kept:
+                self._link_lost_seen = 0
+            *out, self.links = program(
+                *carried, self.faults, *controls, links=self.links
+            )
+            self._links_kept = self.links
         self.state = out[0]
         if self.telem is not None:
             self.telem = out[1]
@@ -1675,6 +1912,21 @@ class VirtualCluster(DispatchSeam):
             self.trace_ring = out[2]
         self._note_placement()
         return out[len(carried):]
+
+    def _control(self, value: int) -> jnp.ndarray:
+        """A verb's control scalar (a round budget, a target) as a device
+        ``int32``, made once a value: a verb called again with the numbers
+        it had before uploads nothing. Every transfer is a call the host
+        waits in before it can dispatch: on the chip's machine 0.40 ms a
+        ``jnp.int32`` made in the call, 0.19 ms a host scalar handed to the
+        jitted call, against 0.38 ms for the whole dispatch with the scalars
+        on the device already (PERF section 6, PR 38)."""
+        scalar = self._controls.get(value)
+        if scalar is None:
+            if len(self._controls) >= 64:  # a stream of ever-new targets
+                self._controls.clear()
+            scalar = self._controls[value] = jnp.int32(value)
+        return scalar
 
     def _step(self, phase: str, **tags) -> StepEvents:
         """ONE body for both step spellings: only the dispatch-phase label
@@ -1716,7 +1968,9 @@ class VirtualCluster(DispatchSeam):
         state and return a cheap checksum (``sync_checksum_impl`` — one
         compiled dispatch, audited by the device_program gate)."""
         with self._dispatch("sync"):
-            checksum = int(sync_checksum(self.state, self.faults))
+            checksum = int(
+                sync_checksum(self.state, self.faults, *_lane_tail(self.links))
+            )
         self._account_d2h(4)
         self._refresh_activity()
         return checksum
@@ -1778,28 +2032,27 @@ class VirtualCluster(DispatchSeam):
             raise ValueError(f"max_steps packs into 8 bits, got {max_steps}")
         with self._dispatch("run_to_decision"):
             steps, decided, winner = self._advance(
-                "decision", jnp.int32(max_steps)
+                "decision", self._control(max_steps)
             )
             if self.cfg.n < (1 << 22):
                 # Layout: bits 0-7 steps, bit 8 decided, bits 9-30 membership
                 # — one scalar fetch total.
-                packed = int(
+                packed = int(self._fetch(
                     steps
                     | (decided.astype(jnp.int32) << 8)
                     | (self.state.n_members << 9)
-                )
-                self._account_d2h(4)
+                )[0])
                 rounds = packed & 0xFF
                 was_decided = bool((packed >> 8) & 1)
                 members = packed >> 9
             else:
                 # Membership no longer fits beside the flags in a positive
                 # int32: pay a second fetch rather than return garbage.
-                packed = int(steps | (decided.astype(jnp.int32) << 8))
-                self._account_d2h(8)
+                packed = int(self._fetch(steps | (decided.astype(jnp.int32) << 8))[0])
                 rounds = packed & 0xFF
                 was_decided = bool(packed >> 8)
                 members = int(self.state.n_members)
+                self._account_d2h(4)
         self.metrics.inc("engine_convergence_steps", rounds)
         if was_decided:
             self.metrics.inc("engine_cuts_committed")
@@ -1826,15 +2079,14 @@ class VirtualCluster(DispatchSeam):
             raise ValueError(f"target must be in [0, {self.cfg.n}]: {target}")
         with self._dispatch("run_until_membership"):
             steps, cuts, resolved, sizes = self._advance(
-                "wave", jnp.int32(target), jnp.int32(max_steps),
-                jnp.int32(min_cuts), max_cuts=int(max_cuts),
+                "wave", self._control(target), self._control(max_steps),
+                self._control(min_cuts), max_cuts=int(max_cuts),
             )
-            obs = np.asarray(
+            obs = self._fetch(
                 jnp.concatenate(
                     [jnp.stack([steps, cuts, resolved.astype(jnp.int32)]), sizes]
                 )
             )
-        self._account_d2h(obs.nbytes)
         n_cuts = int(obs[1])
         self.metrics.inc("engine_convergence_steps", int(obs[0]))
         self.metrics.inc("engine_cuts_committed", n_cuts)

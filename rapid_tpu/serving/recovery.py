@@ -39,6 +39,7 @@ import jax
 
 from rapid_tpu.utils.checkpoint import (
     CheckpointCorruptError,
+    load_link_faults,
     load_serving_state,
     save_serving_state,
 )
@@ -77,7 +78,8 @@ def write_checkpoint(
     }
     path = _checkpoint_path(directory, wave_index)
     save_serving_state(
-        path, target.cfg, target.state, target.faults, knobs=knobs, meta=meta
+        path, target.cfg, target.state, target.faults, knobs=knobs, meta=meta,
+        links=getattr(target, "links", None),
     )
     for stale in sorted(
         (p for p in directory.iterdir() if _CKPT_RE.search(p.name)),
@@ -169,6 +171,7 @@ def resume(
     else:
         target = VirtualCluster(cfg, state)
         target.faults = faults
+        target.links = load_link_faults(path)
     wave_index = int(meta["wave_index"])
     supervisor = Supervisor(
         target,

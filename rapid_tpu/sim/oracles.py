@@ -37,7 +37,7 @@ protocol's safety and liveness claims (paper §3, §5):
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from rapid_tpu.sim.faults import WATERMARK_H, FaultSchedule
 from rapid_tpu.sim.scenario import RunResult
@@ -260,7 +260,35 @@ def cuts_refine(fine_cuts: Sequence[Set], coarse_groups: Sequence[Sequence[froze
     return None
 
 
-def inject_engine_event(vc, event) -> int:
+def _oneway_victims(vc, slots) -> Optional[List[int]]:
+    """The set the link-fault lane should name after a one-way partition of
+    ``slots``: them and whoever it names already. ``None`` where the lane
+    cannot stand for the host's run: a cluster the lane does not reach (a
+    mesh), or a healthy member with L or more of its K observers in the set.
+    Its deaf observers report it (their egress is open) and hold it between
+    the watermarks, where the host's static detector, which blacklists the
+    victim and lets the victim detect nobody, reports nothing. At the
+    schedules' N of about ten a victim watches some member on four rings
+    now and then; at a deployment's N it does not."""
+    import numpy as np
+
+    if vc.mesh is not None:
+        return None
+    standing = [] if vc.links is None else np.nonzero(
+        np.asarray(vc.links.loss_permille)
+    )[0].tolist()
+    victims = sorted(set(standing) | set(slots))
+    named = np.zeros(vc.cfg.n, dtype=bool)
+    named[victims] = True
+    obs = np.asarray(vc.state.obs_idx)  # [k, n]
+    false_reports = (named[np.clip(obs, 0, vc.cfg.n - 1)] & (obs >= 0)).sum(axis=0)
+    healthy = np.asarray(vc.state.alive) & ~named
+    if (false_reports[healthy] >= vc.cfg.l).any():
+        return None
+    return victims
+
+
+def inject_engine_event(vc, event, oneway_as_crash: bool = False) -> int:
     """Apply one membership-phase event to an engine cluster and return its
     expected-membership delta — THE host-event -> engine-seam mapping,
     shared by the differential replay below and the tenancy chaos compiler
@@ -268,9 +296,16 @@ def inject_engine_event(vc, event) -> int:
     schedule means at the engine grain:
 
     - ``join``/``leave`` — the engine's own injection seams;
-    - ``crash``/``partition_oneway``/``committee_crash`` — detector-identical
-      crash-stops (the engine has no committee; the victim's removal is
-      what the membership chain must agree on);
+    - ``partition_oneway`` — the link-fault lane (``set_link_faults`` at
+      1000 permille, always on): the victim's ingress is dead and its egress
+      open, so its observers report it, it reports its own subjects (false
+      reports the low watermark absorbs) and it casts no vote. Where the
+      lane cannot stand for the host's run (:func:`_oneway_victims`), and for
+      a caller whose cluster goes on into a fleet, which carries no lane
+      (``oneway_as_crash``), the victim crash-stops instead: the same cut;
+    - ``crash``/``committee_crash`` — crash-stops (the engine has no
+      committee; the victim's removal is what the membership chain must
+      agree on);
     - ``false_alert``/``alert_storm`` (H-crossing, normalized by
       ``membership_phases`` to carry the cumulative ring set) — per-(subject,
       ring) probe failures (``set_flaky_edges``): the engine's observers of
@@ -292,7 +327,11 @@ def inject_engine_event(vc, event) -> int:
         probe[subject, rings] = True
         vc.set_flaky_edges(probe)
         return -1  # only H-crossing lies appear in phase groups
-    # crash / partition_oneway / committee_crash are detector-identical.
+    if kind == "partition_oneway" and not oneway_as_crash:
+        victims = _oneway_victims(vc, slots)
+        if victims is not None:
+            vc.set_link_faults(victims)
+            return -len(slots)
     vc.crash(slots)
     return -len(slots)
 

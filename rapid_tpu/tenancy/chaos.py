@@ -362,7 +362,8 @@ def _inject_group(vc: VirtualCluster, group: List[FaultEvent]) -> int:
     """Apply one membership phase group's events to a tenant's cluster via
     the shared host-event -> engine-seam mapping. Returns the membership
     delta."""
-    return sum(inject_engine_event(vc, event) for event in group)
+    # A fleet carries no link-fault lane: a one-way partition stays a crash.
+    return sum(inject_engine_event(vc, event, oneway_as_crash=True) for event in group)
 
 
 def run_fleet(

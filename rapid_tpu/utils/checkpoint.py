@@ -359,6 +359,7 @@ def save_serving_state(
     faults,
     knobs=None,
     meta: Optional[Dict[str, Any]] = None,
+    links=None,
 ) -> None:
     """One crash-consistent checkpoint of a serving target: the state AND
     fault pytrees (and, for a fleet, the [t] knob lanes) exactly as stored —
@@ -367,13 +368,17 @@ def save_serving_state(
     (unlike :func:`save_engine_state`, ``ring_perm`` is persisted too: the
     stacked/packed shapes cannot be re-derived by the single-cluster
     recompute, and bit-exact resume is the whole point here). ``meta`` is a
-    small JSON-serializable dict (the supervisor's wave cursor). Sealed +
-    atomic like every writer in this module."""
+    small JSON-serializable dict (the supervisor's wave cursor). ``links`` is
+    a cluster's link-fault lane where one is set (``VirtualCluster.links``);
+    :func:`load_link_faults` reads it back. Sealed + atomic like every
+    writer in this module."""
     entries = dict(_cfg_entries(cfg))
     entries["__meta__"] = np.frombuffer(
         json.dumps(meta or {}, sort_keys=True).encode(), dtype=np.uint8
     )
-    for prefix, tree in (("state", state), ("faults", faults), ("knobs", knobs)):
+    for prefix, tree in (
+        ("state", state), ("faults", faults), ("knobs", knobs), ("links", links),
+    ):
         if tree is None:
             continue
         for field, value in tree._asdict().items():
@@ -419,3 +424,22 @@ def load_serving_state(path):
 
             knobs = tree(TenantKnobs, "knobs")
     return cfg, state, faults, knobs, meta
+
+
+def load_link_faults(path):
+    """The link-fault lane of a serving checkpoint, or ``None`` where the
+    archive holds none (a cluster that had none set, or a writer older than
+    the lane): such a cluster resumes unset. Reads the lane's members only;
+    :func:`load_serving_state` returns what it always did."""
+    import jax.numpy as jnp
+
+    from rapid_tpu.models.state import LinkFaults
+
+    with _open_npz(path) as data:
+        keys = {field: f"links__{field}" for field in LinkFaults._fields}
+        missing = sorted(field for field, key in keys.items() if key not in data)
+        if len(missing) == len(keys):
+            return None
+        if missing:
+            raise KeyError(f"serving checkpoint holds a link-fault lane without {missing}")
+        return LinkFaults(**{field: jnp.asarray(data[key]) for field, key in keys.items()})

@@ -76,6 +76,9 @@ ENGINE_DISPATCH_PHASES = frozenset({
     "inject_crash",
     "inject_join_admit",
     "inject_join_place",
+    # The link-fault lane's setter: bounds check, index upload and one
+    # placement program, enqueued without a fetch.
+    "inject_link_faults",
 })
 
 #: Prefix of a dispatch phase's span on the profiler's clock.
