@@ -185,7 +185,7 @@ WIDE_POLICY = CompactionPolicy(
 #: arithmetic on exactly these names; the two sets are pinned equal by
 #: tests/test_state_compaction.py.
 NARROWABLE_LANES = frozenset({
-    "ring_perm", "obs_idx", "subj_idx", "inval_obs", "cohort_of",
+    "ring_perm", "obs_idx", "inval_obs", "cohort_of",
     "fd_count", "fd_hist", "fire_round", "report_bits",
     "cp_rnd_r", "cp_rnd_i", "cp_vrnd_r", "cp_vrnd_i", "cp_vval_src",
     "classic_epoch", "rounds_undecided",
@@ -247,7 +247,6 @@ LANE_SPECS: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "id_lo": (("n",), "uint32"),
     "alive": (("n",), "bool"),
     "obs_idx": (("k", "n"), "idx"),
-    "subj_idx": (("k", "n"), "idx"),
     "inval_obs": (("k", "n"), "idx"),
     "config_epoch": ((), "int32"),
     "config_hi": ((), "uint32"),
@@ -315,7 +314,6 @@ class EngineState(NamedTuple):
     id_lo: jnp.ndarray  # [n] uint32
     alive: jnp.ndarray  # [n] bool — current membership
     obs_idx: jnp.ndarray  # [k, n] int32 — ring successor (observer) per slot
-    subj_idx: jnp.ndarray  # [k, n] int32 — ring predecessor (subject) per slot
     inval_obs: jnp.ndarray  # [k, n] int32 — invalidation-observer table
     config_epoch: jnp.ndarray  # int32 — counts view changes
     config_hi: jnp.ndarray  # uint32 — commutative config-id lanes
@@ -411,7 +409,6 @@ def initial_state(cfg: EngineConfig, key_hi, key_lo, id_hi, id_lo, alive) -> Eng
         id_lo=jnp.asarray(id_lo, dtype=jnp.uint32),
         alive=alive,
         obs_idx=topo.obs_idx.astype(idt),
-        subj_idx=topo.subj_idx.astype(idt),
         # A copy, not an alias: engine_step donates its input state, and the
         # runtime rejects the same buffer donated twice.
         inval_obs=jnp.copy(topo.obs_idx.astype(idt)),

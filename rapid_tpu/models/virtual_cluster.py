@@ -819,7 +819,6 @@ def apply_view_change_impl(
         obs_idx=jnp.where(
             still_pending[None, :], state.obs_idx, topo.obs_idx.astype(idt)
         ),
-        subj_idx=topo.subj_idx.astype(idt),
         inval_obs=jnp.where(
             still_pending[None, :], state.inval_obs, topo.obs_idx.astype(idt)
         ),

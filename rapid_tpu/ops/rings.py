@@ -177,11 +177,12 @@ def _from_perm_single(perm, alive):
     piece 0; at n an exact power of two, the last position holding an
     all-ones piece), and a tie carries the same low bits, so it is harmless;
     "no alive position on that side" is never read off a word but off two
-    scalars, the first and the last alive position (element 0 of the
-    suffix-min, the last element of the prefix-max): at or past the last
+    scalars, the least and the greatest alive word: at or past the last
     alive position the walk wraps to the first alive slot, at or before the
-    first to the last, scalars of the same scans. With fewer than two alive
-    every entry is -1 whatever the scans hold.
+    first to the last. They are a ``min`` and a ``max`` of their own, not
+    ends of the scans, so each side needs its own scan only: a caller that
+    takes ``obs_idx`` alone (the engine) compiles one scan a piece and one
+    scatter. With fewer than two alive every entry is -1 anyway.
 
     Jitted, so that an eager caller dispatches one program and not each of
     the walk's forty small operations: ``initial_state`` below
@@ -201,11 +202,12 @@ def _from_perm_single(perm, alive):
     for piece in range(pieces):
         shift = piece * piece_bits
         word = _ordered_int32(pos_field | ((slot >> shift) & piece_mask))
-        suffix_min = _ordered_uint32(
-            jax.lax.cummin(jnp.where(ao, word, _INT32_MAX), reverse=True)
-        )
-        prefix_max = _ordered_uint32(jax.lax.cummax(jnp.where(ao, word, _INT32_MIN)))
-        first_alive, last_alive = suffix_min[0], prefix_max[-1]
+        floor = jnp.where(ao, word, _INT32_MIN)  # a dead position: the least word,
+        ceiling = jnp.where(ao, word, _INT32_MAX)  # or the greatest
+        suffix_min = _ordered_uint32(jax.lax.cummin(ceiling, reverse=True))
+        prefix_max = _ordered_uint32(jax.lax.cummax(floor))
+        first_alive = _ordered_uint32(jnp.min(ceiling))
+        last_alive = _ordered_uint32(jnp.max(floor))
         nxt = jnp.where(
             pos >= (last_alive >> piece_bits),  # nobody alive further on: wrap
             first_alive,

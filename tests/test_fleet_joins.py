@@ -95,7 +95,7 @@ def joined():
 
 
 @pytest.mark.parametrize("leaf", [
-    "join_pending", "obs_idx", "inval_obs", "fd_fired", "fire_round", "alive", "subj_idx",
+    "join_pending", "obs_idx", "inval_obs", "fd_fired", "fire_round", "alive", "retired",
 ])
 def test_injection_writes_what_the_clusters_method_writes(joined, leaf):
     stacked, alone = joined["injected"]
