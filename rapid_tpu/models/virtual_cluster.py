@@ -314,6 +314,7 @@ def _compute_round(
     batch_axis=None,
     links: Optional[LinkFaults] = None,
     observer_loss=None,
+    paths=None,
 ):
     """One protocol round WITHOUT view-change application: returns the
     round-advanced state plus (decided, winner_mask, events). Keeping the
@@ -362,7 +363,14 @@ def _compute_round(
     member whose ingress is wholly dead out of ``can_vote``, and the return
     ends with the lane, a round older and with the probes it failed added.
     ``observer_loss`` is the lane's gather where the caller's loop made it
-    before its rounds (:func:`_observer_loss`)."""
+    before its rounds (:func:`_observer_loss`).
+
+    ``paths`` (the consensus-path counts, ``int32[3]`` in the order of
+    ``exposition.CONSENSUS_PATH_COUNTERS``): a fifth Python-level branch.
+    With ``None`` not one traced operation changes. With the counts the
+    return ends with them, after the link-fault lane, three scalars the
+    round computed anyway added in: whether the classic attempt ran
+    (``fallback_due``), whether it decided, whether the fast round did."""
     n, k, c = cfg.n, cfg.k, cfg.c
 
     # 1. Failure-detector tick -> fresh DOWN alerts per (subject, ring) edge.
@@ -380,7 +388,6 @@ def _compute_round(
                     dtype=jnp.int32,
                 ),
             )
-    links_out = _lane_tail(links)
     fd_count, fd_hist, fd_fired, fire = _fd_tick(
         cfg, state, faults, observer_active, link_lost
     )
@@ -475,6 +482,17 @@ def _compute_round(
             jnp.any(announced) & ~fast_decided, state.rounds_undecided + 1, state.rounds_undecided
         )
         fallback_due = (rounds_undecided >= cfg.fallback_rounds) & jnp.any(announced) & ~fast_decided
+        # The classic arm's coordinator rule counts identical VALUES
+        # (Paxos.java:287-308): ``value_of[i]`` is the first cohort whose
+        # proposal is cohort i's. Made HERE, outside the conditional: an
+        # operand of a conditional lives in HBM, and with ``prop_hi`` /
+        # ``prop_lo`` among the arm's operands the votes' two gathers above
+        # read their tables from there, 37 % slower on the v5e (PR 43's
+        # refusal; PERF section 6). The arm takes this one [c] word instead.
+        value_of = jnp.argmax(
+            (prop_hi[:, None] == prop_hi[None, :]) & (prop_lo[:, None] == prop_lo[None, :]),
+            axis=1,
+        ).astype(jnp.int32)
 
     # 5b. Classic-Paxos fallback, message-level (Paxos.java:98-238): one
     #     attempt per engine round once the recovery delay expires. R =
@@ -551,9 +569,11 @@ def _compute_round(
             # instance of Paxos.java:287-308: a fast-chosen value holds
             # > N/4 of any majority quorum and at most one value can be
             # fast-chosen, so the plurality contains it whenever one
-            # exists). If NO quorum member has accepted anything, safety
-            # permits a free choice: propose an announced cut
-            # (Paxos.java:310-326's any-proposed-value clause).
+            # exists). An acceptor's vval is kept as the cohort whose
+            # proposal it took, so the cohorts that announced the same cut
+            # pool their counts (``value_of``). If NO quorum member has
+            # accepted anything, safety permits a free choice: propose an
+            # announced cut (Paxos.java:310-326's any-proposed-value clause).
             voters = q1 & (cp_vval_src >= 0)
             mv_r = jnp.max(jnp.where(voters, cp_vrnd_r, -1))
             mv_i = jnp.max(jnp.where(voters & (cp_vrnd_r == mv_r), cp_vrnd_i, -1))
@@ -563,9 +583,20 @@ def _compute_round(
                 axis=1,
                 dtype=jnp.int32,
             )
+            value_counts = jnp.where(
+                max_counts > 0,
+                jnp.sum(
+                    jnp.where(
+                        value_of[:, None] == value_of[None, :], max_counts[None, :], 0
+                    ),
+                    axis=1,
+                    dtype=jnp.int32,
+                ),
+                0,
+            )
             chosen = jnp.where(
                 jnp.any(max_counts > 0),
-                jnp.argmax(max_counts).astype(cdt),
+                jnp.argmax(value_counts).astype(cdt),
                 jnp.where(
                     jnp.any(announced), jnp.argmax(announced).astype(cdt), -1
                 ),
@@ -641,6 +672,10 @@ def _compute_round(
     )
     with scope("tally"):
         classic_epoch = jnp.where(fallback_due, state.classic_epoch + 1, state.classic_epoch)
+        if paths is not None:
+            paths = paths + jnp.stack(
+                [fallback_due, fb_decided, fast_decided]
+            ).astype(jnp.int32)
 
         decided = fast_decided | fb_decided
         winner_cohort = jnp.where(
@@ -700,8 +735,9 @@ def _compute_round(
             arms_ran = (
                 jnp.stack([invalidation_ran, classic_ran]).astype(jnp.int32),
             )
+    lanes_out = _lane_tail(links, paths)
     if telem is None:
-        return (round_state, decided, winner_mask, events, *arms_ran, *links_out)
+        return (round_state, decided, winner_mask, events, *arms_ran, *lanes_out)
 
     # Device telemetry plane (write-only; see the docstring contract).
     # Scalars reuse reductions computed above; [c, n]/[c] lanes accumulate
@@ -729,7 +765,7 @@ def _compute_round(
             tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
         )
     if trace is None:
-        return (round_state, decided, winner_mask, events, telem, *arms_ran, *links_out)
+        return (round_state, decided, winner_mask, events, telem, *arms_ran, *lanes_out)
 
     # Device round-trace ring (write-only; one record per round into slot
     # cursor % R). Every field is a scalar computed above — the ring adds
@@ -763,7 +799,7 @@ def _compute_round(
             tr_cursor=trace.tr_cursor + 1,
             tr_wraps=trace.tr_wraps + (slot == cfg.trace - 1).astype(jnp.int32),
         )
-    return (round_state, decided, winner_mask, events, telem, trace, *arms_ran, *links_out)
+    return (round_state, decided, winner_mask, events, telem, trace, *arms_ran, *lanes_out)
 
 
 def _rotation_seed(epoch_u32, j: int):
@@ -897,36 +933,47 @@ def _view_change_gate_masks(
 # ``_compute_round`` as it takes them and carried through a loop directly
 # after the state. A driver jits one body once per observer count.
 #
-# The link-fault lane rides outside the positions: the one-device programs
-# take it as the keyword ``links`` and, given one, hand it back LAST, after
-# the observations. A call without the keyword is the call it always was.
+# Two lanes ride outside the positions: the one-device programs take the
+# link-fault lane as the keyword ``links`` and the consensus-path counts as
+# the keyword ``paths`` and, given either, hand it back LAST, after the
+# observations (``links`` before ``paths`` where both are set). A call
+# without the keywords is the call it always was.
 
 
-def _lane_tail(links) -> tuple:
-    """What a program's return ends with: the lane where one is set."""
-    return () if links is None else (links,)
+def _lane_tail(*lanes) -> tuple:
+    """What a program's return ends with: the lanes that are set."""
+    return tuple(lane for lane in lanes if lane is not None)
 
 
-def _lane_off(outputs, links):
-    """``(outputs, lane)`` of a ``_compute_round`` return: the lane is its
-    last element where one went in, else ``None``."""
-    if links is None:
-        return outputs, None
-    return outputs[:-1], outputs[-1]
+def _lane_off(outputs, *lanes):
+    """``(outputs, *lanes)`` of a return that ends with :func:`_lane_tail`
+    of ``lanes``: each lane is the element it went back as, or ``None``
+    where none went in."""
+    riding = len(_lane_tail(*lanes))
+    tail = iter(outputs[len(outputs) - riding:])
+    return (
+        outputs[: len(outputs) - riding],
+        *(None if lane is None else next(tail) for lane in lanes),
+    )
 
 
-def engine_step_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
+def engine_step_impl(
+    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None
+):
     """One full protocol round including conditional view-change application:
     the MESH's per-round step (``sharded_program("step")``) and the
     analyzers' reference, which builds the per-edge masks in every round.
     ``rest`` is ``(*observers, faults)``; returns ``(state, *observers,
     events)``."""
     *observers, faults = rest
-    (round_state, decided, winner_mask, events, *observers), links = _lane_off(
-        _compute_round(cfg, state, faults, None, *observers, links=links), links
+    (round_state, decided, winner_mask, events, *observers), links, paths = _lane_off(
+        _compute_round(
+            cfg, state, faults, None, *observers, links=links, paths=paths
+        ),
+        links, paths,
     )
     new_state = _view_change_gate(cfg, round_state, decided, winner_mask)
-    return (new_state, *observers, events, *_lane_tail(links))
+    return (new_state, *observers, events, *_lane_tail(links, paths))
 
 
 # Donating step for the long-running driver loop (state buffers reused in
@@ -935,7 +982,9 @@ engine_step = jax.jit(engine_step_impl, static_argnums=(0,), donate_argnums=(1,)
 engine_step_nodonate = jax.jit(engine_step_impl, static_argnums=(0,))  # donate-ok: compile-check / dry-run variant; callers keep their state buffers
 
 
-def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
+def engine_step_carried_impl(
+    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None
+):
     """The MESHLESS per-round step the driver dispatches: the math of
     :func:`engine_step_impl` with the per-edge masks CARRIED from round to
     round beside the state instead of rebuilt in every round. ``rest`` is
@@ -954,13 +1003,16 @@ def engine_step_carried_impl(cfg: EngineConfig, state: EngineState, *rest, links
 
     Returns ``(state, *observers, events, masks)``."""
     *observers, faults, masks = rest
-    (round_state, decided, winner_mask, events, *observers), links = _lane_off(
-        _compute_round(cfg, state, faults, masks, *observers, links=links), links
+    (round_state, decided, winner_mask, events, *observers), links, paths = _lane_off(
+        _compute_round(
+            cfg, state, faults, masks, *observers, links=links, paths=paths
+        ),
+        links, paths,
     )
     new_state, masks = _view_change_gate_masks(
         cfg, round_state, faults, masks, decided, winner_mask
     )
-    return (new_state, *observers, events, masks, *_lane_tail(links))
+    return (new_state, *observers, events, masks, *_lane_tail(links, paths))
 
 
 #: The build program: dispatched by the driver only when the masks it
@@ -983,6 +1035,18 @@ def link_faults_place(n: int, packed) -> LinkFaults:
         age=jnp.int32(0),
         probes_lost=jnp.int32(0),
     )
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def partition_place(c: int, n: int, deaf: int, packed) -> jnp.ndarray:
+    """The ``[c, n]`` receive-block lane of a one-way partition in one upload
+    and one dispatch: ``packed`` is ``int32[deaf + m]``, the ``deaf`` cohorts
+    that stop hearing and then the ``m`` sender slots they stop hearing
+    from. Every (cohort, sender) pair is blocked, nobody else."""
+    cohorts, senders = packed[:deaf], packed[deaf:]
+    return jnp.zeros((c, n), dtype=bool).at[
+        cohorts[:, None], senders[None, :]
+    ].set(True)
 
 
 def telemetry_digest_impl(telem: TelemetryLanes) -> jnp.ndarray:
@@ -1068,7 +1132,7 @@ sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the
 
 def _converge(
     cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
-    masks, steps, max_steps, batch_axis=None, links=None,
+    masks, steps, max_steps, batch_axis=None, links=None, paths=None,
 ):
     """THE inner convergence loop: rounds over fixed per-edge ``masks``
     (topology and faults are fixed until a cut commits, so the per-edge
@@ -1077,7 +1141,7 @@ def _converge(
     sort-free: the caller applies the (at most one) view change after the
     loop, so the ring rebuild runs exactly once per convergence. Returns
     ``(round_state, observers, steps, decided, winner_mask, arm_rounds,
-    links)``.
+    links, paths)``.
 
     ``batch_axis`` goes down to the round (:func:`_compute_round`). With a
     name the loop also carries ``arm_rounds``, ``int32[2]``: the rounds in
@@ -1085,44 +1149,46 @@ def _converge(
     a pytree of no leaves: the carry is the one it always was. So is the
     link-fault lane (``links``) where none is set; a set lane rides the
     carry's end and ages with every round, and its one gather (the loss at
-    every edge's observer) is made here, once, beside the masks."""
+    every edge's observer) is made here, once, beside the masks. The
+    consensus-path counts (``paths``) ride behind it the same way."""
     observer_loss = None if links is None else _observer_loss(cfg, state, links)
 
     def cond(carry):
-        *_, steps, decided, _, _, _ = carry
+        *_, steps, decided, _, _, _, _ = carry
         return (~decided) & (steps < max_steps)
 
     def body(carry):
-        state, *observers, steps, _, _, arm_rounds, links = carry
-        (round_state, decided, winner_mask, _, *observers), links = _lane_off(
+        state, *observers, steps, _, _, arm_rounds, links, paths = carry
+        (round_state, decided, winner_mask, _, *observers), links, paths = _lane_off(
             _compute_round(
                 cfg, state, faults, masks, *observers, batch_axis=batch_axis,
-                links=links, observer_loss=observer_loss,
+                links=links, observer_loss=observer_loss, paths=paths,
             ),
-            links,
+            links, paths,
         )
         if batch_axis is not None:
             *observers, arms_ran = observers
             arm_rounds = arm_rounds + arms_ran
         return (
             round_state, *observers, steps + 1, decided, winner_mask, arm_rounds,
-            links,
+            links, paths,
         )
 
     init = (
         state, *observers, steps, jnp.bool_(False),
         jnp.zeros((cfg.n,), dtype=bool),
         None if batch_axis is None else jnp.zeros((2,), dtype=jnp.int32),
-        links,
+        links, paths,
     )
-    state, *observers, steps, decided, winner, arm_rounds, links = jax.lax.while_loop(
-        cond, body, init
-    )
-    return state, observers, steps, decided, winner, arm_rounds, links
+    (
+        state, *observers, steps, decided, winner, arm_rounds, links, paths
+    ) = jax.lax.while_loop(cond, body, init)
+    return state, observers, steps, decided, winner, arm_rounds, links, paths
 
 
 def run_to_decision_impl(
-    cfg: EngineConfig, state: EngineState, *rest, batch_axis=None, links=None
+    cfg: EngineConfig, state: EngineState, *rest, batch_axis=None, links=None,
+    paths=None,
 ):
     """Protocol rounds until a view change commits — entirely on device.
 
@@ -1138,17 +1204,20 @@ def run_to_decision_impl(
     """
     *observers, faults, max_steps = rest
     masks = _edge_masks(cfg, state, faults)
-    state, observers, steps, decided, winner, arm_rounds, links = _converge(
+    state, observers, steps, decided, winner, arm_rounds, links, paths = _converge(
         cfg, state, observers, faults, masks, jnp.int32(0), max_steps, batch_axis,
-        links,
+        links, paths,
     )
     state = _view_change_gate(cfg, state, decided, winner)
+    lanes = _lane_tail(links, paths)
     if batch_axis is None:
-        return (state, *observers, steps, decided, winner, *_lane_tail(links))
-    return (state, *observers, steps, decided, winner, arm_rounds, *_lane_tail(links))
+        return (state, *observers, steps, decided, winner, *lanes)
+    return (state, *observers, steps, decided, winner, arm_rounds, *lanes)
 
 
-def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
+def run_until_membership_impl(
+    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None
+):
     """Protocol rounds through MULTIPLE view changes until the membership
     reaches ``target`` — one device dispatch for a whole churn/bootstrap
     wave instead of one per cut. ``rest`` is ``(*observers, faults, target,
@@ -1182,19 +1251,20 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest, link
     *observers, faults, target, max_steps, max_cuts, min_cuts = rest
 
     def outer_cond(carry):
-        state, *_, steps, cuts, stalled, _, _ = carry
+        state, *_, steps, cuts, stalled, _, _, _ = carry
         resolved = (state.n_members == target) & (cuts >= min_cuts)
         return (~resolved) & (~stalled) & (steps < max_steps) & (cuts < max_cuts)
 
     def outer_body(carry):
-        state, *observers, steps, cuts, _, sizes, links = carry
+        state, *observers, steps, cuts, _, sizes, links, paths = carry
         # Built here, where the convergence reads them, and not in the cut's
         # arm: every iteration starts from a topology no build has seen (the
         # wave's first, or the one a commit just left), and the wave's last
         # commit is followed by no round that would read a rebuild.
         masks = _edge_masks(cfg, state, faults)
-        state, observers, steps, decided, winner, _, links = _converge(
-            cfg, state, observers, faults, masks, steps, max_steps, links=links
+        state, observers, steps, decided, winner, _, links, paths = _converge(
+            cfg, state, observers, faults, masks, steps, max_steps, links=links,
+            paths=paths,
         )
         state = _view_change_gate(cfg, state, decided, winner)
         with scope("loop_result"):
@@ -1205,7 +1275,7 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest, link
         # progress (the outer loop would spin): latch and exit.
         return (
             state, *observers, steps, cuts + decided.astype(jnp.int32),
-            ~decided, sizes, links,
+            ~decided, sizes, links, paths,
         )
 
     init = (
@@ -1215,14 +1285,16 @@ def run_until_membership_impl(cfg: EngineConfig, state: EngineState, *rest, link
         jnp.int32(0),
         jnp.bool_(False),
         jnp.full((max_cuts,), -1, dtype=jnp.int32),
-        links,
+        links, paths,
     )
-    state, *observers, steps, cuts, _, sizes, links = jax.lax.while_loop(
+    state, *observers, steps, cuts, _, sizes, links, paths = jax.lax.while_loop(
         outer_cond, outer_body, init
     )
     with scope("loop_result"):
         resolved = (state.n_members == target) & (cuts >= min_cuts)
-    return (state, *observers, steps, cuts, resolved, sizes, *_lane_tail(links))
+    return (
+        state, *observers, steps, cuts, resolved, sizes, *_lane_tail(links, paths)
+    )
 
 
 def jit_per_observer_count(impl, static=(), donated=()):
@@ -1340,6 +1412,14 @@ class VirtualCluster(DispatchSeam):
         self.links: Optional[LinkFaults] = None
         self._links_kept: Optional[LinkFaults] = None
         self._link_lost_seen = 0
+        # The consensus-path counts (``int32[3]``, in the order of
+        # ``exposition.CONSENSUS_PATH_COUNTERS``): None until the first
+        # ``set_partition``, and while it is None every verb dispatches the
+        # program and fetches the bytes it always did. From then on the
+        # counts ride every round program and every ``_fetch``;
+        # ``_paths_seen`` is what the counters have been given of them.
+        self.paths: Optional[jnp.ndarray] = None
+        self._paths_seen = np.zeros(len(exposition.CONSENSUS_PATH_COUNTERS), np.int64)
         self._controls: Dict[int, jnp.ndarray] = {}  # see ``_control``
         self._rng = np.random.default_rng(0)
         # Engine-level telemetry: host-side counters over device dispatches
@@ -1589,16 +1669,20 @@ class VirtualCluster(DispatchSeam):
 
     # -- fault & membership injection ----------------------------------
 
-    def _checked_slots(self, slots: Sequence[int]) -> np.ndarray:
+    def _checked_slots(
+        self, slots: Sequence[int], size: Optional[int] = None, what: str = "slot"
+    ) -> np.ndarray:
         """Host-side bounds check. jnp's gather/scatter CLAMPS out-of-range
         indices instead of raising (a typo'd slot would silently
         inspect/mutate slot n-1), so every lifecycle mutation validates on
-        host where it is free — no extra fetch, the indices originate here."""
+        host where it is free — no extra fetch, the indices originate here.
+        ``size`` and ``what`` name another axis than the slots (the cohorts)."""
+        size = self.cfg.n if size is None else size
         arr = np.asarray(slots, dtype=np.int32)
-        if arr.size and (arr.min() < 0 or arr.max() >= self.cfg.n):
+        if arr.size and (arr.min() < 0 or arr.max() >= size):
             raise IndexError(
-                f"slot indices out of range [0, {self.cfg.n}): "
-                f"{arr[(arr < 0) | (arr >= self.cfg.n)].tolist()}"
+                f"{what} indices out of range [0, {size}): "
+                f"{arr[(arr < 0) | (arr >= size)].tolist()}"
             )
         return arr
 
@@ -1726,13 +1810,27 @@ class VirtualCluster(DispatchSeam):
         """A verb's int32 observation, fetched flat and charged to the
         transfer counter. A set lane's ``probes_lost`` rides the same
         transfer, four bytes more, and ``engine_link_probes_lost`` gets what
-        the lane lost since its last fetch."""
-        if self.links is not None:
-            observation = jnp.concatenate(
-                [jnp.ravel(observation), self.links.probes_lost[None]]
-            )
+        the lane lost since its last fetch; the consensus-path counts,
+        where a partition has ever been set, ride behind it, twelve bytes,
+        and the three counters get what the rounds since the last fetch
+        added (a ``step`` fetches nothing: its counts arrive with the next
+        verb that does)."""
+        tail = _lane_tail(
+            None if self.links is None else self.links.probes_lost[None],
+            self.paths,
+        )
+        if tail:
+            observation = jnp.concatenate([jnp.ravel(observation), *tail])
         fetched = np.asarray(observation).reshape(-1)
         self._account_d2h(fetched.nbytes)
+        if self.paths is not None:
+            counts = fetched[-len(self._paths_seen):].astype(np.int64)
+            for name, more in zip(
+                exposition.CONSENSUS_PATH_COUNTERS, counts - self._paths_seen
+            ):
+                self.metrics.inc(name, int(more))
+            self._paths_seen = counts
+            fetched = fetched[: -len(counts)]
         if self.links is not None:
             lost = int(fetched[-1])
             self.metrics.inc("engine_link_probes_lost", lost - self._link_lost_seen)
@@ -1863,7 +1961,13 @@ class VirtualCluster(DispatchSeam):
         old alerts. Re-stamped alerts redeliver within ``delivery_spread``
         rounds — a re-broadcast after the topology change."""
         arr = np.asarray(rx_block, dtype=bool)  # charge the uploaded width
-        self.faults = self.faults._replace(rx_block=self._upload("rx_block", arr))
+        with self._dispatch("inject_partition"):
+            self._place_rx_block(self._upload("rx_block", arr))
+
+    def _place_rx_block(self, lane: jnp.ndarray) -> None:
+        """A new receive-block lane, however it was made, and the re-stamp
+        of the fired edges that goes with it (see :meth:`set_rx_block`)."""
+        self.faults = self.faults._replace(rx_block=lane)
         self.state = self.state._replace(
             fire_round=jnp.where(
                 self.state.fd_fired,
@@ -1872,6 +1976,51 @@ class VirtualCluster(DispatchSeam):
             )
         )
         self._note_placement()
+
+    def set_partition(self, cohorts: Sequence[int], senders: Sequence[int]) -> None:
+        """A one-way partition at the receivers: the named ``cohorts`` stop
+        hearing the named ``senders`` (slots) — their alerts, their votes and
+        their classic-round messages, everything ``rx_block`` governs — and
+        the senders hear and send as before; everybody else as before. The
+        call replaces whatever receive blocking stood before; with no
+        cohorts or no senders it clears it. Device-side scatter: only the
+        two index lists cross the host->device boundary, in ONE upload (a
+        dense lane is ``c * n`` bytes, :meth:`set_rx_block`), and the state
+        and faults afterwards are those of ``set_rx_block`` of the equal
+        dense array, the re-stamp of the fired edges included.
+
+        From the first call on the cluster counts its consensus path on the
+        device (``engine_classic_rounds``: rounds in which the classic
+        attempt ran; ``engine_classic_decisions`` / ``engine_fast_decisions``:
+        which arm decided a committed cut): twelve bytes more in every
+        observation fetched, in ``metrics`` and in the scrape from then on.
+        A cluster that never sets a partition never grows them.
+
+        Off under a mesh (the placement makes the lane whole on one device;
+        ``set_rx_block`` places a host-made lane on its shards)."""
+        if self.mesh is not None:
+            raise ValueError(
+                "set_partition is off under a mesh: the placement program "
+                "makes the lane on one device (use set_rx_block)"
+            )
+        deaf = self._checked_slots(cohorts, self.cfg.c, "cohort")
+        unheard = self._checked_slots(senders)
+        with self._dispatch("inject_partition"):
+            if self.paths is None:
+                # Minted with the first partition, so the series are in
+                # every scrape from then on.
+                for name in exposition.CONSENSUS_PATH_COUNTERS:
+                    self.metrics.inc(name, 0)
+                self.paths = jnp.zeros((len(self._paths_seen),), dtype=jnp.int32)
+            if deaf.size and unheard.size:
+                packed = np.concatenate([deaf, unheard])
+                self._account_h2d(packed)
+                lane = partition_place(
+                    self.cfg.c, self.cfg.n, int(deaf.size), jnp.asarray(packed)
+                )
+            else:  # healed: nothing crosses
+                lane = jnp.zeros((self.cfg.c, self.cfg.n), dtype=bool)
+            self._place_rx_block(lane)
 
     # -- execution ------------------------------------------------------
 
@@ -1895,15 +2044,18 @@ class VirtualCluster(DispatchSeam):
             program = _mesh_lib().sharded_program(
                 verb, self.cfg, self.mesh, len(carried), max_cuts
             )
-        if self.links is None:
-            out = program(*carried, self.faults, *controls)
-        else:
-            if self.links is not self._links_kept:
-                self._link_lost_seen = 0
-            *out, self.links = program(
-                *carried, self.faults, *controls, links=self.links
-            )
-            self._links_kept = self.links
+        if self.links is not None and self.links is not self._links_kept:
+            self._link_lost_seen = 0
+        lanes = {
+            name: lane
+            for name, lane in (("links", self.links), ("paths", self.paths))
+            if lane is not None
+        }
+        out, self.links, self.paths = _lane_off(
+            program(*carried, self.faults, *controls, **lanes),
+            self.links, self.paths,
+        )
+        self._links_kept = self.links
         self.state = out[0]
         if self.telem is not None:
             self.telem = out[1]

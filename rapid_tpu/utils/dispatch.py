@@ -79,6 +79,11 @@ ENGINE_DISPATCH_PHASES = frozenset({
     # The link-fault lane's setter: bounds check, index upload and one
     # placement program, enqueued without a fetch.
     "inject_link_faults",
+    # The partition seams (``set_partition``: cohort indices and sender
+    # slots in one upload and one placement program; ``set_rx_block``: the
+    # whole lane uploaded), each with the re-stamp of the fired edges,
+    # enqueued without a fetch.
+    "inject_partition",
 })
 
 #: Prefix of a dispatch phase's span on the profiler's clock.

@@ -93,6 +93,21 @@ ENGINE_KNOWN_COUNTERS = (
     "engine_edge_mask_reuses",
 )
 
+#: The cluster driver's counters of the consensus path, in the order of the
+#: ``int32[3]`` the round programs carry them in (``VirtualCluster.paths``):
+#: rounds in which the classic-Paxos attempt ran (the fast round had not
+#: decided ``fallback_rounds`` after the first announcement), and which arm
+#: decided each committed cut. Named after the fleet's
+#: ``engine_fleet_classic_rounds``. They are minted, all three at 0, by a
+#: cluster's first ``set_partition`` and are in every scrape of it from then
+#: on; a cluster that never sets a partition never grows them (its programs
+#: and its fetches are those of a cluster without the counts).
+CONSENSUS_PATH_COUNTERS = (
+    "engine_classic_rounds",
+    "engine_classic_decisions",
+    "engine_fast_decisions",
+)
+
 #: Tenant-fleet counters zero-filled on snapshots whose ``engine`` section
 #: carries a ``tenancy`` block (``TenantFleet.telemetry_snapshot``) — the
 #: fleet tier's series set is stable from the first scrape, and a

@@ -726,6 +726,24 @@ def test_stream_vocabulary_complete_from_attach_not_first_completion():
     assert names == GOLDEN_STREAM_METRIC_NAMES
 
 
+def test_a_partitioned_clusters_scrape_grows_the_consensus_path_and_nothing_else():
+    """ISSUE 43: the three counters of the consensus path are minted by a
+    cluster's first ``set_partition``, at 0, and are in every scrape of it
+    from then on; a cluster that never sets one keeps the golden list."""
+    assert exposition.CONSENSUS_PATH_COUNTERS == (
+        "engine_classic_rounds", "engine_classic_decisions", "engine_fast_decisions")
+    vc = _cluster()
+    vc.sync()  # a first dispatch, so the phase histogram's series exist
+    assert exposition.metric_names(vc.prometheus_text()) == GOLDEN_ENGINE_METRIC_NAMES
+    vc.set_partition([0], [1, 2])
+    names = exposition.metric_names(vc.prometheus_text())
+    assert names == sorted(set(GOLDEN_ENGINE_METRIC_NAMES) | {
+        f"rapid_{name}_total" for name in exposition.CONSENSUS_PATH_COUNTERS})
+    assert vc.metrics.phase_timings["engine_dispatch"]["inject_partition"].count == 1
+    vc.set_partition([], [])  # healing clears the lane, never the series
+    assert exposition.metric_names(vc.prometheus_text()) == names
+
+
 def test_dispatch_phase_vocabulary_enforced_at_write_time():
     # Satellite (ISSUE 11): the phase vocabulary is enforced where it is
     # WRITTEN — a typo'd phase raises instead of silently minting a new

@@ -325,14 +325,20 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: lower to the parent's text). And again at PR 42: the state holds no
 #: predecessor table, so each loses that output, its scatter and the walk's
 #: prefix-max; the programs without a view change lower to the parent's text.
+#: And at PR 44, for one hunk alone: each holds the classic arm, whose
+#: coordinator rule now pools the counts of cohorts that announced the same
+#: cut (``value_of``, a ``[c]`` word made under ``tally``; a ``[c, c]`` compare
+#: and a sum in the arm). Before that hunk the five, and the 29 other programs
+#: of this module's fixture, lowered to PR 42's text with the consensus-path
+#: counts (``paths=None``) in the tree.
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "78a849db9e4ec4da",
-    "fleet_run_to_decision": "ebec7af3a60b9f21",
-    "mesh_run_to_decision": "3ad030700ccf9ac9",
-    "mesh_step": "c814f4b793a90cd1",
-    "mesh_fleet_step": "986883665438b5fa",
+    "run_until_membership": "07baf15ab9f7d6ee",
+    "fleet_run_to_decision": "4f425d02c7df30ee",
+    "mesh_run_to_decision": "f9f50a341c6c0493",
+    "mesh_step": "4b9bc0f720085706",
+    "mesh_fleet_step": "c5f493e7b027d63d",
 }
 
 
@@ -369,6 +375,7 @@ def test_an_unregistered_name_raises_at_write_time(kind):
         with vc._dispatch("inject_crsh"):
             pass
     assert {"inject_crash", "inject_join_admit", "inject_join_place"} <= ENGINE_DISPATCH_PHASES
+    assert {"inject_link_faults", "inject_partition"} <= ENGINE_DISPATCH_PHASES
 
 
 def test_annotate_is_a_trace_annotation_with_tags_and_free_without_a_trace():
