@@ -75,7 +75,7 @@ def _detector_experiment_fn():
             new_bits = _deliver_alerts(cfg, state, state.fire_round, blocked_rows)
             heard_down = jnp.any((new_bits != 0) & state.alive[None, :], axis=1)
             (report_bits, released, announced, seen_down, proposed_now,
-             prop_masks, _) = _cohort_cut_detection(cfg, state, new_bits, heard_down)
+             prop_masks, *_) = _cohort_cut_detection(cfg, state, new_bits, heard_down)
             state = state._replace(
                 report_bits=report_bits, released=released,
                 announced=announced, seen_down=seen_down,

@@ -548,6 +548,8 @@ TELEMETRY_LANE_SPECS: Dict[str, Tuple[str, ...]] = {
     "tl_fast_decisions": (),
     "tl_classic_decisions": (),
     "tl_conflict_rounds": (),
+    "tl_invalidation_rounds": (),
+    "tl_invalidation_dense_rounds": (),
     "tl_undecided_hist": ("b",),
 }
 
@@ -587,6 +589,15 @@ class TelemetryLanes(NamedTuple):
     # decide — the per-tenant conflict-rate numerator ("The Performance of
     # Paxos and Fast Paxos": the fast path's win hinges on collision rate).
     tl_conflict_rounds: jnp.ndarray  # [] int32
+    # Rounds in which this cluster (this tenant) needed the implicit-
+    # invalidation arm (some cohort had a subject in flux after a DOWN
+    # report), and those of them in which it took the DENSE loop over all n
+    # slots: its subjects in flux overflowed the compacted form's bucket
+    # (ops/cut_detection.invalidation_bucket), or the program traces the
+    # dense loop alone (a mesh's). 0 of the second over a window of traffic
+    # says the bucket held it.
+    tl_invalidation_rounds: jnp.ndarray  # [] int32
+    tl_invalidation_dense_rounds: jnp.ndarray  # [] int32
     tl_undecided_hist: jnp.ndarray  # [TELEMETRY_BUCKETS] int32 — log2(rounds-undecided) at decision
 
 

@@ -283,7 +283,8 @@ def _deliver_alerts(cfg: EngineConfig, state: EngineState, fire_round, blocked_r
 
 @scope("cut_detection")
 def _cohort_cut_detection(
-    cfg: EngineConfig, state: EngineState, new_bits, heard_down, batch_axis=None
+    cfg: EngineConfig, state: EngineState, new_bits, heard_down, batch_axis=None,
+    dense_invalidation=False,
 ):
     """The engine's cut-detection seam: C independent watermark detectors
     batched over the (mesh-sharded) cohort axis. The pass itself lives in
@@ -303,6 +304,7 @@ def _cohort_cut_detection(
         cfg.l,
         cfg.k,
         batch_axis,
+        dense_invalidation,
     )
 
 
@@ -315,6 +317,7 @@ def _compute_round(
     links: Optional[LinkFaults] = None,
     observer_loss=None,
     paths=None,
+    dense_invalidation=False,
 ):
     """One protocol round WITHOUT view-change application: returns the
     round-advanced state plus (decided, winner_mask, events). Keeping the
@@ -370,7 +373,17 @@ def _compute_round(
     With ``None`` not one traced operation changes. With the counts the
     return ends with them, after the link-fault lane, three scalars the
     round computed anyway added in: whether the classic attempt ran
-    (``fallback_due``), whether it decided, whether the fast round did."""
+    (``fallback_due``), whether it decided, whether the fast round did.
+
+    ``dense_invalidation`` (handed by a program's builder, never by a user): a
+    sixth Python-level branch. ``False`` is every one-device program: the
+    ``invalidation`` arm looks observers up for the subjects in flux alone
+    and falls back to the dense loop when they overflow its bucket
+    (``ops/cut_detection.cohort_watermark_pass``). ``True`` traces the dense
+    loop alone, the program of before: ``parallel/mesh.sharded_program``
+    (the compaction is a global operation over the node axis the mesh
+    shards) and the fleet's unnamed-``vmap`` programs (a nested conditional
+    would be a select that runs both forms)."""
     n, k, c = cfg.n, cfg.k, cfg.c
 
     # 1. Failure-detector tick -> fresh DOWN alerts per (subject, ring) edge.
@@ -433,8 +446,10 @@ def _compute_round(
     # 3. Cut detection per cohort.
     (
         report_bits, released, announced, seen_down, proposed_now, prop_masks,
-        invalidation_ran,
-    ) = _cohort_cut_detection(cfg, state, new_bits, heard_down, batch_axis)
+        invalidation_ran, invalidation_own,
+    ) = _cohort_cut_detection(
+        cfg, state, new_bits, heard_down, batch_axis, dense_invalidation
+    )
     # Proposal identity = commutative set-hash of the cut's member identities
     # (the canonical-sort-free equivalent of the ring-0-sorted endpoint list,
     # MembershipService.java:346-348). Per-cohort hash reductions over N —
@@ -762,6 +777,10 @@ def _compute_round(
             tl_classic_decisions=telem.tl_classic_decisions + fb_decided.astype(jnp.int32),
             tl_conflict_rounds=telem.tl_conflict_rounds
             + (jnp.any(announced) & ~fast_decided).astype(jnp.int32),
+            tl_invalidation_rounds=telem.tl_invalidation_rounds
+            + invalidation_own[0].astype(jnp.int32),
+            tl_invalidation_dense_rounds=telem.tl_invalidation_dense_rounds
+            + invalidation_own[1].astype(jnp.int32),
             tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
         )
     if trace is None:
@@ -958,7 +977,8 @@ def _lane_off(outputs, *lanes):
 
 
 def engine_step_impl(
-    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None
+    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None,
+    dense_invalidation=False,
 ):
     """One full protocol round including conditional view-change application:
     the MESH's per-round step (``sharded_program("step")``) and the
@@ -968,7 +988,8 @@ def engine_step_impl(
     *observers, faults = rest
     (round_state, decided, winner_mask, events, *observers), links, paths = _lane_off(
         _compute_round(
-            cfg, state, faults, None, *observers, links=links, paths=paths
+            cfg, state, faults, None, *observers, links=links, paths=paths,
+            dense_invalidation=dense_invalidation,
         ),
         links, paths,
     )
@@ -1069,6 +1090,8 @@ def telemetry_digest_impl(telem: TelemetryLanes) -> jnp.ndarray:
             telem.tl_fast_decisions,
             telem.tl_classic_decisions,
             telem.tl_conflict_rounds,
+            telem.tl_invalidation_rounds,
+            telem.tl_invalidation_dense_rounds,
         ]),
         telem.tl_undecided_hist,
     ])
@@ -1133,6 +1156,7 @@ sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the
 def _converge(
     cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
     masks, steps, max_steps, batch_axis=None, links=None, paths=None,
+    dense_invalidation=False,
 ):
     """THE inner convergence loop: rounds over fixed per-edge ``masks``
     (topology and faults are fixed until a cut commits, so the per-edge
@@ -1150,7 +1174,8 @@ def _converge(
     link-fault lane (``links``) where none is set; a set lane rides the
     carry's end and ages with every round, and its one gather (the loss at
     every edge's observer) is made here, once, beside the masks. The
-    consensus-path counts (``paths``) ride behind it the same way."""
+    consensus-path counts (``paths``) ride behind it the same way.
+    ``dense_invalidation`` goes down to the round like ``batch_axis``."""
     observer_loss = None if links is None else _observer_loss(cfg, state, links)
 
     def cond(carry):
@@ -1163,6 +1188,7 @@ def _converge(
             _compute_round(
                 cfg, state, faults, masks, *observers, batch_axis=batch_axis,
                 links=links, observer_loss=observer_loss, paths=paths,
+                dense_invalidation=dense_invalidation,
             ),
             links, paths,
         )
@@ -1188,7 +1214,7 @@ def _converge(
 
 def run_to_decision_impl(
     cfg: EngineConfig, state: EngineState, *rest, batch_axis=None, links=None,
-    paths=None,
+    paths=None, dense_invalidation=False,
 ):
     """Protocol rounds until a view change commits — entirely on device.
 
@@ -1206,7 +1232,7 @@ def run_to_decision_impl(
     masks = _edge_masks(cfg, state, faults)
     state, observers, steps, decided, winner, arm_rounds, links, paths = _converge(
         cfg, state, observers, faults, masks, jnp.int32(0), max_steps, batch_axis,
-        links, paths,
+        links, paths, dense_invalidation,
     )
     state = _view_change_gate(cfg, state, decided, winner)
     lanes = _lane_tail(links, paths)
@@ -1216,7 +1242,8 @@ def run_to_decision_impl(
 
 
 def run_until_membership_impl(
-    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None
+    cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None,
+    dense_invalidation=False,
 ):
     """Protocol rounds through MULTIPLE view changes until the membership
     reaches ``target`` — one device dispatch for a whole churn/bootstrap
@@ -1264,7 +1291,7 @@ def run_until_membership_impl(
         masks = _edge_masks(cfg, state, faults)
         state, observers, steps, decided, winner, _, links, paths = _converge(
             cfg, state, observers, faults, masks, steps, max_steps, links=links,
-            paths=paths,
+            paths=paths, dense_invalidation=dense_invalidation,
         )
         state = _view_change_gate(cfg, state, decided, winner)
         with scope("loop_result"):

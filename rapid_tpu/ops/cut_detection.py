@@ -22,8 +22,10 @@ Two grains live here:
   dimension is a REAL mesh axis on the 2-D ``('cohort', 'nodes')`` engine
   mesh: everything in the pass is either elementwise on ``[c, n]``
   (shard-local) or a per-cohort reduction over the node axis (a psum over
-  node-axis subgroups) — nothing reduces or gathers over the cohort axis,
-  so per-device watermark state is ``[c/dc, n/dn]``, not ``[c, n]``. The
+  node-axis subgroups) — on a mesh nothing reduces or gathers over the
+  cohort axis, so per-device watermark state is ``[c/dc, n/dn]``, not
+  ``[c, n]`` (the one-device programs' compacted invalidation arm does
+  reduce over it; the mesh's programs do not trace that form). The
   cross-cohort work (3N/4 quorum count, winner selection, classic
   fallback) lives in the consensus tally, not here.
 """
@@ -130,6 +132,61 @@ def process_alert_batch(
     )
 
 
+#: Lanes of a vector tile: the bucket is whole tiles, and the compaction ranks
+#: the slots by rows of this many.
+_LANES = 128
+
+
+def invalidation_bucket(n: int) -> int:
+    """Slots the compacted form of the implicit-invalidation arm holds: a
+    sixteenth of the ``n`` slots, rounded up to whole 128-lane tiles. Only
+    crashed, faulty or joining subjects can be in flux, and the densest
+    traffic any cell sends has 2.4 % of its slots there at once (10,000 of
+    1,000,000, 2,492 of 102,500, 500 of 50,000); a round with more takes
+    the dense arm."""
+    return -(-n // (16 * _LANES)) * _LANES
+
+
+def _count_below(table: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
+    """For every ``t[j]``: how many entries of ``table[j]`` (or of the one
+    row all share) lie below it. Ascending rows: the insertion point."""
+    return jnp.sum(table < t[:, None], axis=1, dtype=jnp.int32)
+
+
+def _first_set_slots(need: jnp.ndarray, cap: int) -> jnp.ndarray:
+    """The positions of the first ``cap`` set entries of ``need[n]``, in
+    order; entries past the count of set ones are some slot of ``[0, n)``.
+
+    The form is the chip's (PERF.md section 6, PR 45: 0.52 ms at
+    ``[1000000]`` where ``jnp.nonzero(size=)``'s N-update scatter-add takes
+    9.0, a rank scatter 6.1, a binary search of the ranks 8.5 and a sort
+    1.3): one running count over the slots laid out in rows of 128, then
+    the j-th set slot is looked up, not scattered to: its row is how many
+    rows END below j + 1 (a dense compare against the row ends, through one
+    more level of 128 rows where there are many), its lane how many of that
+    row's counts lie below j + 1. Two row gathers of ``cap`` rows and
+    compares, no update per slot."""
+    n = need.shape[0]
+    rows = -(-n // _LANES)
+    rank = jnp.cumsum(
+        jnp.pad(need, (0, rows * _LANES - n)).astype(jnp.int32)
+    ).reshape(rows, _LANES)
+    t = jnp.arange(1, cap + 1, dtype=jnp.int32)
+    ends = rank[:, -1]
+    if rows <= 8 * _LANES:
+        row = _count_below(ends[None, :], t)
+    else:
+        groups = -(-rows // _LANES)
+        ends = jnp.pad(
+            ends, (0, groups * _LANES - rows),
+            constant_values=jnp.iinfo(jnp.int32).max,
+        ).reshape(groups, _LANES)
+        group = jnp.minimum(_count_below(ends[None, :, -1], t), groups - 1)
+        row = group * _LANES + _count_below(ends[group], t)
+    row = jnp.minimum(row, rows - 1)
+    return jnp.minimum(row * _LANES + _count_below(rank[row], t), n - 1)
+
+
 def cohort_watermark_pass(
     report_bits: jnp.ndarray,
     new_bits: jnp.ndarray,
@@ -143,6 +200,7 @@ def cohort_watermark_pass(
     l,
     k: int,
     batch_axis=None,
+    dense_invalidation: bool = False,
 ):
     """Batched per-cohort watermark pass over uint32 ring-report bitmasks
     (:func:`process_alert_batch` semantics over a leading cohort axis, gated
@@ -152,7 +210,13 @@ def cohort_watermark_pass(
     report_bits/released: ``[c, n]`` per-cohort detector state;
     seen_down/announced/heard_down: ``[c]`` cohort lanes; subject_mask:
     ``[n]``; inval_obs: ``[k, n]``. Returns ``(report_bits, released,
-    announced, seen_down, propose, proposal_mask, invalidation_ran)``.
+    announced, seen_down, propose, proposal_mask, invalidation_ran,
+    invalidation_own)``. ``invalidation_ran``: whether the implicit-invalidation
+    arm ran in this pass (under a named ``batch_axis``: for the batch).
+    ``invalidation_own``, two bools, is what the telemetry plane adds up, and
+    a member's own under any ``vmap``: whether THESE detectors needed the arm,
+    and whether they took the dense loop for it (their subjects in flux
+    overflowed the bucket; or always, in a program that traces no other form).
 
     Sharding discipline (the 2-D mesh contract): the merge + popcount + H/L
     classification is plain elementwise jnp on ``[c, n]`` — XLA's own
@@ -160,21 +224,41 @@ def cohort_watermark_pass(
     shapes (ops/pallas_kernels.py module docstring) and it partitions
     shard-locally on a ``('cohort', 'nodes')`` mesh. The per-cohort
     release/propose decisions are reductions over the NODE axis only
-    (per-shard psums); nothing here reduces over the cohort axis. The
-    implicit-invalidation gather only runs when some cohort actually has
-    subjects in flux after a DOWN event (lax.cond): in pure crash/join
-    rounds every subject jumps straight past H, so the expensive gather is
-    skipped — and on the mesh the gathered traffic stays cond-gated.
+    (per-shard psums). The implicit-invalidation arm only runs when some
+    cohort actually has subjects in flux after a DOWN event (lax.cond): in
+    pure crash/join rounds every subject jumps straight past H, so the
+    expensive gather is skipped — and on the mesh the gathered traffic
+    stays cond-gated. The arm has two forms:
+
+    - the DENSE loop: K ``[c, n]`` gathers ``in_union[:, inval_obs[ring]]``
+      over all n slots and one ``[c, n]`` merge. It reduces over no axis
+      and gathers along the node axis only, so it is the form every program
+      of ``parallel/mesh.sharded_program`` takes (``dense_invalidation=True``
+      traces it alone: the mesh's programs are the programs they were);
+    - the COMPACTED form, every one-device program's: the arm's result is
+      zero wherever the subject is not in flux in some cohort that has seen
+      a DOWN report, and only crashed, faulty or joining subjects can be.
+      It reduces ``flux & seen_down`` over the COHORT axis to ``need[n]``,
+      compacts the set slots over the NODE axis into one bucket of
+      :func:`invalidation_bucket` slots, looks the K observers up for those
+      alone and writes the bits back at them. Both steps are global over an
+      axis a mesh shards (a replicated compaction of a 10 M-slot ring would
+      cost what it saves), which is why the mesh keeps the dense loop. A
+      round with more slots in flux than the bucket holds takes the dense
+      loop (a nested ``lax.cond``), so the result is exact at any activity.
 
     ``batch_axis`` names the batch axis of an enclosing ``vmap`` (the two
     meshless fleet programs of ``tenancy/fleet.py`` hand one). An unnamed
     ``vmap`` makes the conditional a select: the K ``[c, n]`` gathers run
-    for every tenant in every round. Named, the conditional is taken on
-    "some tenant needs it" (:func:`cond_across`) and ``invalidation_ran``
-    is that scalar: a round in which no tenant has a subject in flux skips
-    the gathers for the whole fleet. The fleet's mesh programs name none,
-    because there that any() would be a collective across the ``'tenant'``
-    axis.
+    for every tenant in every round (and a nested conditional would run
+    both forms, so those programs pass ``dense_invalidation=True`` too).
+    Named, the conditional is taken on "some tenant needs it"
+    (:func:`cond_across`) and ``invalidation_ran`` is that scalar: a round
+    in which no tenant has a subject in flux skips the gathers for the whole
+    fleet; the choice of form goes the same way ("some tenant overflows its
+    bucket" takes the dense loop for that round, selected per tenant). The
+    fleet's mesh programs name none, because there that any() would be a
+    collective across the ``'tenant'`` axis.
     """
     c, n = report_bits.shape
     # The impl, not the jitted wrapper: the tenant fleet vmaps this pass
@@ -192,8 +276,7 @@ def cohort_watermark_pass(
     stable = cls == 2
     flux = cls == 1
 
-    @scope("invalidation")
-    def with_implicit(report_bits):
+    def dense(report_bits):
         # Implicit edge invalidation (MultiNodeCutDetector.java:137-164): the
         # union (pending-stable | flux) is invariant under the pass, so one
         # masked OR is the fixpoint. Already-released subjects left the
@@ -216,11 +299,60 @@ def cohort_watermark_pass(
         merged = report_bits | implicit_bits
         return jnp.where(subject_mask[None, :], merged, 0)
 
+    @scope("invalidation")
+    def with_implicit(report_bits):
+        if dense_invalidation:
+            return dense(report_bits)
+        # Only a subject in flux in SOME cohort that has seen a DOWN report
+        # can gain a bit: compact those slots into one bucket and look the
+        # K observers up for them alone. (Reduced here and not beside the
+        # predicate below: a quiet round pays for nothing of it.) ``flux``
+        # lies inside ``subject_mask`` already (a tally of l >= 1 needs a
+        # merged bit and the merge is masked); the AND makes the write-back
+        # need no mask of its own whatever l is.
+        need = jnp.any(flux & seen_down[:, None], axis=0) & subject_mask  # [n]
+        count = jnp.sum(need, dtype=jnp.int32)
+        cap = invalidation_bucket(n)
+
+        def compacted(report_bits):
+            # The dense loop's pass for the ``cap`` slots ``idx`` names
+            # alone, all K rings in one ``[c, k, cap]`` look-up (ten
+            # sixteenths of a ``[c, n]`` lane). A padding row (``live``
+            # false) names slot 0 and is disarmed, so it carries zero bits,
+            # and the write-back ADDS the bits a slot lacks: the OR for a
+            # slot named once, nothing for a padding row, whatever a backend
+            # makes of a repeated index.
+            live = jnp.arange(cap, dtype=jnp.int32) < count
+            idx = jnp.where(live, _first_set_slots(need, cap), 0)
+            in_union = (stable & ~released) | flux  # [c, n]
+            bdt = report_bits.dtype
+            armed = flux[:, idx] & seen_down[:, None] & live[None, :]  # [c, cap]
+            obs = inval_obs[:, idx]  # [k, cap]
+            gathered = in_union[:, jnp.clip(obs, 0, n - 1)]  # [c, k, cap]
+            implicit = armed[:, None, :] & gathered & (obs >= 0)[None]
+            ring_bit = jnp.arange(k, dtype=bdt)[None, :, None]
+            implicit_bits = jnp.bitwise_or.reduce(
+                implicit.astype(bdt) << ring_bit, axis=1
+            )
+            return report_bits.at[:, idx].add(implicit_bits & ~report_bits[:, idx])
+
+        overflows = count > cap
+        merged, _ = cond_across(batch_axis, overflows, dense, compacted, report_bits)
+        return merged, overflows
+
     need_invalidation = jnp.any(flux & seen_down[:, None])
-    report_bits, invalidation_ran = cond_across(
-        batch_axis, need_invalidation, with_implicit,
-        scope("invalidation_skip")(lambda r: r), report_bits,
-    )
+    if dense_invalidation:
+        # the conditional of before, output for output: no flag rides it
+        report_bits, invalidation_ran = cond_across(
+            batch_axis, need_invalidation, with_implicit,
+            scope("invalidation_skip")(lambda r: r), report_bits,
+        )
+        ran_dense = need_invalidation
+    else:
+        (report_bits, ran_dense), invalidation_ran = cond_across(
+            batch_axis, need_invalidation, with_implicit,
+            scope("invalidation_skip")(lambda r: (r, jnp.bool_(False))), report_bits,
+        )
 
     tally2 = _popcount32(report_bits)
     stable2 = tally2 >= h
@@ -236,6 +368,7 @@ def cohort_watermark_pass(
         propose,
         proposal_mask,
         invalidation_ran,
+        (need_invalidation, ran_dense),
     )
 
 

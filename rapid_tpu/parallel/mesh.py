@@ -127,7 +127,8 @@ PARTITION_RULES: Tuple[Tuple[str, Spec], ...] = (
     (r"tl_proposals", (COHORT_AXIS,)),
     (
         r"tl_rounds|tl_alerts|tl_tally_sum|tl_fast_decisions"
-        r"|tl_classic_decisions|tl_conflict_rounds|tl_undecided_hist",
+        r"|tl_classic_decisions|tl_conflict_rounds|tl_invalidation_rounds"
+        r"|tl_invalidation_dense_rounds|tl_undecided_hist",
         (),  # replicated-ok: per-engine scalar counters + the 8-bucket histogram
     ),
     # Round-trace ring (models/state.TraceRing): every lane is a per-round
@@ -482,12 +483,17 @@ def sharded_program(
     tables = (
         state_shardings(mesh), telemetry_shardings(mesh), trace_shardings(mesh)
     )[:carried]
+    # The invalidation arm's compacted form reduces over the cohort axis and
+    # compacts over the node axis, both of which a mesh shards: every
+    # sharded program keeps the dense loop (ops/cut_detection.py).
     if verb == "wave":
         def program(*args):
-            return impl(cfg, *args[:-1], max_cuts, args[-1])
+            return impl(
+                cfg, *args[:-1], max_cuts, args[-1], dense_invalidation=True
+            )
     else:
         def program(*args):
-            return impl(cfg, *args)
+            return impl(cfg, *args, dense_invalidation=True)
     return jax.jit(
         program,
         in_shardings=(*tables, fault_shardings(mesh), *(None,) * controls),

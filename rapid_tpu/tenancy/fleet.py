@@ -254,7 +254,10 @@ def fleet_step_impl(
     [t, n] winner masks)."""
 
     def one(state, faults, kn):
-        return engine_step_impl(_tenant_cfg(cfg, kn), state, faults)
+        # no named axis: a nested conditional would be a select (both forms)
+        return engine_step_impl(
+            _tenant_cfg(cfg, kn), state, faults, dense_invalidation=True
+        )
 
     return jax.vmap(one)(state, faults, knobs)
 
@@ -591,7 +594,7 @@ def fleet_wave_lockstep_impl(cfg: EngineConfig, state: EngineState, *rest):
             state, *observers, steps, cuts, sizes, done = carry
             active = ~done & (steps < max_steps)
             round_state, decided, winner, _, *round_observers = _compute_round(
-                tcfg, state, faults, None, *observers
+                tcfg, state, faults, None, *observers, dense_invalidation=True
             )
             committed = apply_view_change_impl(tcfg, round_state, winner)
             commit = active & decided

@@ -449,6 +449,8 @@ TELEMETRY_DIGEST_FIELDS = (
     "decisions_fast",
     "decisions_classic",
     "conflict_rounds",
+    "invalidation_rounds",
+    "invalidation_dense_rounds",
 )
 
 

@@ -220,6 +220,8 @@ _ENGINE_ACTIVITY_COUNTERS = (
     "proposals",
     "tally_sum",
     "conflict_rounds",
+    "invalidation_rounds",
+    "invalidation_dense_rounds",
 )
 
 #: ``engine.activity`` derived gauges (``rapid_engine_activity_<name>``):

@@ -829,6 +829,8 @@ GOLDEN_ACTIVITY_METRIC_NAMES = [
     "rapid_engine_activity_conflict_rate",
     "rapid_engine_activity_conflict_rounds_total",
     "rapid_engine_activity_fast_path_share",
+    "rapid_engine_activity_invalidation_dense_rounds_total",
+    "rapid_engine_activity_invalidation_rounds_total",
     "rapid_engine_activity_invalidations_total",
     "rapid_engine_activity_peak_active_fraction",
     "rapid_engine_activity_proposals_total",
@@ -892,6 +894,88 @@ def test_activity_series_measure_after_the_sync_boundary():
         if line.startswith("rapid_engine_activity_rounds_total")
     )
     assert int(rounds_line.split()[-1]) > 0
+
+
+def test_the_invalidation_lanes_count_the_rounds_a_tenant_needed_the_arm():
+    # The fleet carries its own count of the rounds `invalidation` ran for
+    # SOME tenant (GATE_ROUND_COUNTERS, the pass's `invalidation_ran`); the
+    # lanes add up, in every tenant's plane, the rounds THAT tenant needed
+    # it, and of those the ones that took the dense loop: none here, the
+    # bucket of 128 holds all 32 slots.
+    import numpy as np
+
+    from rapid_tpu.tenancy import TenantFleet
+
+    fleet = TenantFleet.create(
+        3, 28, n_slots=32, k=10, cohorts=2, knobs=[(9, 4, 2)] * 3,
+        delivery_spread=3, telemetry=True,
+    )
+    crashed = np.zeros((3, 32), dtype=bool)
+    crashed[0, [3, 9, 17]] = crashed[1, [5]] = True  # tenant 2 stays quiet
+    fleet.faults = fleet.faults._replace(crashed=jnp.asarray(crashed))
+    for _ in range(12):
+        fleet.step()
+    fleet.sync()
+    ran = fleet.metrics.counters["engine_fleet_invalidation_rounds"]
+    own = [activity["invalidation_rounds"] for activity in fleet.tenant_activity]
+    assert 0 < max(own) <= ran <= sum(own) < 24 and own[2] == 0
+    for activity in fleet.tenant_activity:
+        assert activity["rounds"] == 12
+        assert activity["invalidation_dense_rounds"] == 0
+    digest = np.asarray(fleet_digest(fleet))
+    fields = engine_telemetry.TELEMETRY_DIGEST_FIELDS
+    assert fields[-2:] == ("invalidation_rounds", "invalidation_dense_rounds")
+    assert digest[:, len(fields) - 2].tolist() == own
+    assert digest[:, len(fields) - 1].tolist() == [0] * 3
+    text = fleet.prometheus_text()
+    assert (
+        'rapid_engine_activity_invalidation_rounds_total'
+        f'{{node="tenant-fleet/3x32",tenant="1"}} {own[1]}'
+    ) in text
+    assert (
+        'rapid_engine_activity_invalidation_dense_rounds_total'
+        '{node="tenant-fleet/3x32"} 0'
+    ) in text
+
+
+def fleet_digest(fleet):
+    from rapid_tpu.tenancy.fleet import fleet_telemetry_digest
+
+    return fleet_telemetry_digest(fleet.telem)
+
+
+@pytest.mark.parametrize("observed", [False, True])
+def test_only_an_observed_round_reads_the_dense_flag(observed):
+    # The lanes are written where a TelemetryLanes pytree rides along and
+    # nowhere else: in the observers-off step the `invalidation`
+    # conditional's second output, whether the arm ran dense, flows into no
+    # output of the program (dead code the compiler drops); with the lanes
+    # riding it is added to one.
+    from rapid_tpu.models import virtual_cluster as vcm
+
+    vc = VirtualCluster.create(
+        28, n_slots=32, k=3, h=3, l=1, cohorts=2, fd_threshold=2, telemetry=observed
+    )
+    carried = (vc.state, vc.telem) if observed else (vc.state,)
+    masks = jax.eval_shape(vcm.edge_masks_build, vc.cfg, vc.state, vc.faults)
+    jaxpr = vcm._ROUND_PROGRAMS["step"][int(observed)].trace(
+        vc.cfg, *carried, vc.faults, masks
+    ).jaxpr.jaxpr
+    (arm,) = [  # the round's only conditional traced under `cut_detection`
+        eqn for eqn in jaxpr.eqns
+        if eqn.primitive.name == "cond"
+        and str(eqn.source_info.name_stack) == "cut_detection"
+    ]
+    bits, ran_dense = arm.outvars
+    assert ran_dense.aval.shape == () and ran_dense.aval.dtype == bool
+    assert len(arm.params["branches"]) == 2
+    tainted = {id(ran_dense)}  # what the flag flows into, in program order
+    for eqn in jaxpr.eqns:
+        if any(id(var) in tainted for var in eqn.invars):
+            tainted.update(id(var) for var in eqn.outvars)
+    reaches_an_output = any(id(var) in tainted for var in jaxpr.outvars)
+    assert reaches_an_output == observed
+    assert any(any(var is bits for var in eqn.invars) for eqn in jaxpr.eqns)
 
 
 def test_fleet_activity_carries_per_tenant_labels():

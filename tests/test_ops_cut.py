@@ -8,14 +8,22 @@ union-of-proposals coincides with end-of-batch semantics (see
 rapid_tpu/ops/cut_detection.py docstring).
 """
 
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from rapid_tpu.ops.cut_detection import (
     CutState,
+    _first_set_slots,
     alerts_to_report_matrix,
+    cohort_watermark_pass,
+    invalidation_bucket,
     process_alert_batch,
 )
+from rapid_tpu.ops.pallas_kernels import watermark_merge_classify_impl
 from rapid_tpu.ops.rings import endpoint_ring_keys, predecessor_of_keys, ring_topology
 from rapid_tpu.protocol.cut_detector import MultiNodeCutDetector
 from rapid_tpu.protocol.view import MembershipView
@@ -234,3 +242,190 @@ def test_state_accumulates_across_batches():
     r2 = process_alert_batch(r1.state, m2, np.asarray(True), inval_obs, subject_mask, H, L)
     assert bool(r2.propose)
     assert np.asarray(r2.proposal_mask)[slot_of[subject]]
+
+
+# -- the cohort pass: the compacted invalidation arm against the dense loop -----
+#
+# ``cohort_watermark_pass`` looks observers up for the subjects in flux alone
+# (one bucket of ``invalidation_bucket(n)`` slots) and keeps the dense loop
+# over all n slots for a round that overflows the bucket;
+# ``dense_invalidation=True`` traces the dense loop alone (the mesh's programs)
+# and is the oracle here: every return bit for bit.
+
+#: (cohorts, slots, report dtype, k, h, l): ragged slot counts, the three
+#: report-lane dtypes of the compaction policy.
+PASS_SHAPES = {
+    "c1_n257_u32": (1, 257, np.uint32, 10, 9, 4),
+    "c8_n300_u16": (8, 300, np.uint16, 10, 9, 4),
+    "c64_n2500_u16": (64, 2500, np.uint16, 10, 9, 4),
+    "c8_n4999_u8": (8, 4999, np.uint8, 8, 7, 3),
+}
+#: what a case puts in flux: none (the arm is skipped), a few, exactly the
+#: bucket, one more (the overflow arm), and a few of which only some cohorts
+#: have seen a DOWN report.
+PASS_KINDS = ("zero", "few", "at_cap", "over_cap", "some_unseen")
+
+
+def _pass_case(shape, kind, seed=0):
+    """Inputs of one pass in which exactly ``in_flux`` slots have ``flux &
+    seen_down`` in some cohort. Everybody else is absent, past H (some of
+    them released: they legitimize nothing), or unsubscribed with stray
+    bits the merge must clear; ``inval_obs`` has -1 rows and names subjects
+    in flux, past H, released and absent alike."""
+    c, n, dt, k, h, l = PASS_SHAPES[shape]
+    rng = np.random.default_rng([seed, c, n, PASS_KINDS.index(kind)])
+    cap = invalidation_bucket(n)
+    in_flux = {
+        "zero": 0, "few": max(3, n // 100), "at_cap": min(cap, n - 40),
+        "over_cap": cap + 1, "some_unseen": max(3, n // 100),
+    }[kind]
+    order = rng.permutation(n)
+    fluxed, stable_, stray = order[:in_flux], order[in_flux:in_flux + 20], order[-15:]
+
+    def bits_of(tally):  # `tally` random ring bits
+        rings = np.argsort(rng.random(tally.shape + (k,)), axis=-1)
+        return ((rings < tally[..., None]) << np.arange(k)).sum(-1)
+
+    tally = np.zeros((c, n), dtype=np.int64)
+    # in flux in cohort 0 always; in the others in flux, past H or absent
+    tally[:, fluxed] = rng.choice([0, l, h - 1, h, k], size=(c, in_flux))
+    tally[0, fluxed] = rng.integers(l, h, size=in_flux)
+    tally[:, stable_] = rng.integers(h, k + 1, size=(c, len(stable_)))
+    tally[:, stray] = rng.integers(1, k + 1, size=(c, len(stray)))
+    merged = bits_of(tally)
+    new = np.where(rng.random((c, n)) < 0.4, merged, merged & bits_of(tally // 2))
+    old = np.where(rng.random((c, n)) < 0.4, merged, merged & ~new)
+    assert ((old | new) == merged).all()
+    subject = np.ones(n, dtype=bool)
+    subject[stray] = False
+    seen = np.ones(c, dtype=bool)
+    heard = np.zeros(c, dtype=bool)
+    if kind == "some_unseen" and c > 1:
+        seen[1::2] = False  # cohort 0 stays armed
+        heard[1] = True  # and one hears its first DOWN in this very pass
+    released = np.zeros((c, n), dtype=bool)
+    released[:, stable_[:8]] = rng.random((c, 8)) < 0.5
+    announced = rng.random(c) < 0.2
+    named = np.concatenate([fluxed, stable_, stray, order[in_flux + 20:in_flux + 30]])
+    obs = rng.choice(named, size=(k, n)).astype(np.int32)
+    obs[rng.random((k, n)) < 0.15] = -1
+    obs[:, order[::7]] = -1
+    args = (old.astype(dt), new.astype(dt), seen, released, announced, subject, obs, heard)
+    return tuple(jnp.asarray(a) for a in args), (k, h, l), in_flux, cap
+
+
+@functools.lru_cache(maxsize=None)
+def _pass_program(k, h, l, dense_invalidation, tenants=False):
+    def one(*args):
+        return cohort_watermark_pass(
+            *args, h, l, k, "tenants" if tenants else None, dense_invalidation
+        )
+
+    if not tenants:
+        return jax.jit(one)
+    return jax.jit(jax.vmap(
+        one, axis_name="tenants", out_axes=(*(0,) * 6, None, 0)
+    ))
+
+
+@pytest.fixture(scope="module")
+def pass_program():
+    """The jitted pass by ``(k, h, l, dense_invalidation, tenants)``; the
+    module gives back what it compiled (tier-1 runs near the process's limit
+    of memory maps)."""
+    yield _pass_program
+    _pass_program.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("kind", PASS_KINDS)
+@pytest.mark.parametrize("shape", sorted(PASS_SHAPES))
+def test_the_compacted_invalidation_arm_is_the_dense_loop_bit_for_bit(pass_program, shape, kind):
+    args, (k, h, l), in_flux, cap = _pass_case(shape, kind)
+    got = pass_program(k, h, l, False)(*args)
+    want = pass_program(k, h, l, True)(*args)
+    for name, g, w in zip(
+        ("report_bits", "released", "announced", "seen_down", "propose",
+         "proposal_mask", "invalidation_ran"), got, want,
+    ):
+        assert g.dtype == w.dtype and np.array_equal(g, w), name
+    ran = bool(got[6])
+    assert ran == (in_flux > 0)
+    # the dense-only program ran dense whenever it ran; the compacted one
+    # only past its bucket, and says so
+    assert [bool(flag) for flag in want[7]] == [ran, ran]
+    assert [bool(flag) for flag in got[7]] == [ran, in_flux > cap]
+    if kind == "over_cap":
+        assert [bool(flag) for flag in got[7]] == [True, True]
+    # what the write-back leans on: nothing is in flux outside the subject
+    # mask (the merge is masked and a tally of l >= 1 needs a merged bit),
+    # and exactly `in_flux` slots are armed
+    old, new, seen, _, _, subject, _, heard = args
+    c, n = old.shape
+    merged, cls = watermark_merge_classify_impl(
+        old, new, jnp.broadcast_to(subject[None, :], (c, n)), h, l
+    )
+    flux = np.asarray(cls == 1)
+    assert not (flux & ~np.asarray(subject)[None, :]).any()
+    armed = (flux & np.asarray(seen | heard)[:, None]).any(axis=0)
+    assert int(armed.sum()) == in_flux
+    if ran:  # the arm did something to look at: some slot gained a bit
+        assert (np.asarray(got[0]) != np.asarray(merged)).any()
+
+
+@pytest.mark.parametrize("kinds,dense", [
+    (("zero", "few", "over_cap"), True),
+    (("zero", "few", "at_cap"), False),
+    (("zero", "zero", "zero"), None),
+])
+def test_under_a_named_vmap_one_tenants_overflow_takes_the_dense_arm_for_the_round(
+    pass_program, kinds, dense
+):
+    cases = [_pass_case("c8_n300_u16", kind, seed=7 + t) for t, kind in enumerate(kinds)]
+    k, h, l = cases[0][1]
+    args = tuple(jnp.stack(leaves) for leaves in zip(*(case[0] for case in cases)))
+    got = pass_program(k, h, l, False, tenants=True)(*args)
+    want = pass_program(k, h, l, True, tenants=True)(*args)
+    for g, w in zip(got[:7], want[:7]):
+        assert np.array_equal(g, w)
+    # and every tenant is the pass it would be alone
+    for t, case in enumerate(cases):
+        alone = pass_program(k, h, l, True)(*case[0])
+        for g, a in zip(got[:6], alone[:6]):
+            assert np.array_equal(g[t], a)
+    # the arm ran or not for the batch; who needed it and who overflowed is
+    # each tenant's own, as it would be alone
+    assert got[6].shape == () and bool(got[6]) == (dense is not None)
+    needed, dense_loop = (flags.tolist() for flags in got[7])
+    assert needed == [kind != "zero" for kind in kinds]
+    assert dense_loop == [kind == "over_cap" for kind in kinds]
+    assert [flags.tolist() for flags in want[7]] == [needed, needed]
+
+
+def test_the_invalidation_bucket_is_a_function_of_the_slot_count_alone():
+    # a sixteenth of the slots in whole 128-lane tiles: the cells' sizes
+    assert [invalidation_bucket(n) for n in (1_000, 2_000, 50_000, 102_500, 1_000_000)] == [
+        128, 128, 3_200, 6_528, 62_592,
+    ]
+    for n in (1, 127, 2_048, 2_049, 10_000_000):
+        cap = invalidation_bucket(n)
+        assert cap % 128 == 0 and cap * 16 >= n and (cap - 128) * 16 < n
+
+
+@pytest.mark.parametrize("n", [1, 127, 128, 129, 4999, 131_072, 131_073, 1_000_000])
+def test_the_compaction_looks_the_set_slots_up_in_order(n):
+    # one level of rows to 131,072 slots (1,024 rows of 128), two beyond;
+    # nothing set, a few, exactly the bucket, more than it, everything
+    cap = invalidation_bucket(n)
+    compact = jax.jit(_first_set_slots, static_argnums=1)
+    rng = np.random.default_rng(n)
+    for share in (0.0, 0.001, 0.05, None, 0.2, 1.0):
+        need = rng.random(n) < share if share is not None else np.zeros(n, dtype=bool)
+        if share is None:
+            need[rng.choice(n, size=min(cap, n), replace=False)] = True
+        want = np.nonzero(need)[0][:cap]
+        got = np.asarray(compact(jnp.asarray(need), cap))
+        assert got.shape == (cap,) and got.dtype == np.int32
+        assert np.array_equal(got[:len(want)], want), share
+        assert got.min() >= 0 and got.max() < n  # the rest: some slot, never outside
+    jax.clear_caches()
