@@ -57,6 +57,12 @@ from rapid_tpu.utils.health import NodeHealth
 from rapid_tpu.utils.metrics import Metrics
 
 
+#: The length a small wave's slot indices are uploaded at
+#: (``VirtualCluster._slot_index``): a Poisson stream of 8 events a wave
+#: passes it in 0.4 % of its waves.
+SMALL_WAVE_SLOTS = 16
+
+
 def cohort_words(c: int) -> int:
     """uint32 words needed to carry one bit per receiver cohort."""
     return (c + 31) // 32
@@ -84,14 +90,19 @@ def _edge_masks(cfg: EngineConfig, state: EngineState, faults: FaultInputs):
     Both outputs depend only on (topology, faults), fixed between view
     changes, so convergence loops hoist this out of the round body
     entirely.
+
+    An edge looks its observer up ONCE. What it needs of that member sits
+    in one per-member ``uint32`` table built here and gone at the return:
+    rows ``0..w-1`` are the member's packed ``rx_block`` words and row ``w``
+    is its ``active`` bit (``alive & ~crashed``), a row of its own for
+    every ``c``, so no word of ``blocked_rows`` ever lends a bit. The
+    outputs are those of one gather for each of the two, bit for bit
+    (``tests/test_edge_masks.py`` keeps that body as the oracle).
     """
     n, k, c = cfg.n, cfg.k, cfg.c
     w = cohort_words(c)
     obs = state.obs_idx.T  # [n, k] — observer of (subject s, ring k)
     obs_clamped = jnp.clip(obs, 0, n - 1)
-
-    active = state.alive & ~faults.crashed
-    observer_active = (obs >= 0) & active[obs_clamped]
 
     # Pack rx_block over the cohort axis, then gather per observer.
     pad = w * 32 - c
@@ -99,7 +110,11 @@ def _edge_masks(cfg: EngineConfig, state: EngineState, faults: FaultInputs):
     rxb = rxb.reshape(w, 32, n)
     bit_weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
     words = jnp.sum(rxb * bit_weights[None, :, None], axis=1, dtype=jnp.uint32)  # [w, n]
-    blocked_rows = words[:, obs_clamped.T].reshape(w * k, n)  # THE gather
+    active = state.alive & ~faults.crashed
+    table = jnp.concatenate([words, active[None, :].astype(jnp.uint32)])  # [w + 1, n]
+    at_observer = table[:, obs_clamped.T]  # [w + 1, k, n] — THE gather
+    observer_active = (obs >= 0) & (at_observer[w] != 0).T
+    blocked_rows = at_observer[:w].reshape(w * k, n)
     return observer_active, blocked_rows
 
 
@@ -1713,9 +1728,23 @@ class VirtualCluster(DispatchSeam):
             )
         return arr
 
-    def _slot_index(self, slots: Sequence[int]) -> jnp.ndarray:
-        """Host-side bounds check (:meth:`_checked_slots`), then upload."""
+    def _slot_index(self, slots: Sequence[int], wave: bool = False) -> jnp.ndarray:
+        """Host-side bounds check (:meth:`_checked_slots`), then upload.
+
+        ``wave``: the slots are one wave's events, of which a stream brings
+        a handful at a time and every count sooner or later. Up to
+        :data:`SMALL_WAVE_SLOTS` of them go up as a vector of exactly that
+        length, filled with the out-of-range slot ``n``: an update at ``n``
+        is dropped by every scatter and a look-up at ``n`` reads a row
+        nothing keeps, so the padded entries change no bit, and a wave of a
+        size this process has not seen dispatches the programs it already
+        has where it would compile a set of its own in the serving path. A
+        larger wave (a batch seam's) goes up as it is."""
         arr = self._checked_slots(slots)
+        if wave and 0 < arr.size < SMALL_WAVE_SLOTS:
+            arr = np.concatenate(
+                [arr, np.full(SMALL_WAVE_SLOTS - arr.size, self.cfg.n, dtype=arr.dtype)]
+            )
         self._account_h2d(arr)
         return jnp.asarray(arr)
 
@@ -1723,13 +1752,13 @@ class VirtualCluster(DispatchSeam):
         """Crash-stop the given slots (unresponsive until revived). Device-side
         scatter: only the slot indices cross the host->device boundary."""
         with self._dispatch("inject_crash"):
-            idx = self._slot_index(slots)
+            idx = self._slot_index(slots, wave=True)
             self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(True))
             self._note_placement()
 
     def revive(self, slots: Sequence[int]) -> None:
         with self._dispatch("inject_crash"):
-            idx = self._slot_index(slots)
+            idx = self._slot_index(slots, wave=True)
             self.faults = self.faults._replace(crashed=self.faults.crashed.at[idx].set(False))
             self._note_placement()
 
@@ -1939,7 +1968,7 @@ class VirtualCluster(DispatchSeam):
 
         with self._dispatch("inject_join_place"):
             if idx is None:
-                idx = self._slot_index(slots)
+                idx = self._slot_index(slots, wave=True)
             # Expected observers (gatekeepers) of each joiner: the alive ring
             # predecessors of its keys. Everything below is device-side
             # gather/scatter — only the slot indices cross the boundary, which

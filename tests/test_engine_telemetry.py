@@ -19,7 +19,7 @@ sys.path.insert(0, str(REPO / "tools"))
 
 import clustertop  # noqa: E402  — tools/clustertop.py, the live dashboard
 
-from rapid_tpu.models.virtual_cluster import VirtualCluster  # noqa: E402
+from rapid_tpu.models.virtual_cluster import SMALL_WAVE_SLOTS, VirtualCluster  # noqa: E402
 from rapid_tpu.utils import engine_telemetry, exposition  # noqa: E402
 from rapid_tpu.utils.histogram import NUM_BUCKETS, LogHistogram  # noqa: E402
 
@@ -401,7 +401,8 @@ def test_transfer_byte_accounting():
     base_h2d = vc.metrics.counters["engine_h2d_bytes"]
     assert base_h2d >= 3 * 16 * 4 * 2 + 16 * 4 * 2 + 16
     vc.crash([1, 2, 3])
-    assert vc.metrics.counters["engine_h2d_bytes"] == base_h2d + 3 * 4
+    # a small wave's indices go up at one fixed length (``_slot_index``)
+    assert vc.metrics.counters["engine_h2d_bytes"] == base_h2d + SMALL_WAVE_SLOTS * 4
     d2h0 = vc.metrics.counters["engine_d2h_bytes"]
     assert vc.membership_size == 16
     assert vc.metrics.counters["engine_d2h_bytes"] == d2h0 + 4

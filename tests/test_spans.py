@@ -341,14 +341,18 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: arm (``ops/cut_detection.py``). The three mesh programs were NOT: they
 #: trace the dense loop alone (``dense_invalidation=True``) and lower to PR
 #: 44's text, as do the four programs of the fixture without the arm.
+#: At PR 46 all five were re-taken for the mask build alone: each holds
+#: ``_edge_masks``, which now gathers once an edge (the packed ``rx_block``
+#: words and the ``active`` bit in one table; ``tests/test_edge_masks.py``
+#: holds its outputs to the two-gather body). Nothing else in them moved.
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "995ae43fec998dc0",
-    "fleet_run_to_decision": "26164840ab3db2a8",
-    "mesh_run_to_decision": "f9f50a341c6c0493",
-    "mesh_step": "4b9bc0f720085706",
-    "mesh_fleet_step": "c5f493e7b027d63d",
+    "run_until_membership": "a9aaeaefe5df6b28",
+    "fleet_run_to_decision": "e6128c17d81d0869",
+    "mesh_run_to_decision": "9aa4e92bfc4b0239",
+    "mesh_step": "3c7084d0051bee1e",
+    "mesh_fleet_step": "4055ed74d22a4b65",
 }
 
 

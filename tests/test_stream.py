@@ -26,6 +26,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from benchmarks import membership_model
+from benchmarks.generators import churn
 from rapid_tpu.models.virtual_cluster import VirtualCluster
 from rapid_tpu.serving import (
     STREAMABLE_KINDS,
@@ -185,6 +187,117 @@ def test_streamed_fleet_is_bit_identical_to_batch():
     )
     assert result.cuts == int(batch.config_epochs().sum())
     assert result.waves == 5
+
+
+# ---------------------------------------------------------------------------
+# The cluster trickle's twin: the cell's own arrivals at cohort counts that
+# leave spare bits in the last cohort word (8) and that fill their words
+# (32, 64), streamed through the carried step
+# ---------------------------------------------------------------------------
+
+#: ``cluster-100k.trickle`` at a few hundred members: the configuration's
+#: {K, H, L}, detector, spread and coordinators, ``trickle.json``'s waves
+#: (Poisson 8 events a wave, a wave all joins or all crashes, 8 rounds a
+#: wave, two waves in flight), spare slots for the joins.
+TRICKLE = dict(members=300, slots=380, rounds_per_wave=8, depth=2, waves=16)
+
+
+def _trickle_cluster(cohorts):
+    vc = VirtualCluster.create(
+        TRICKLE["members"], n_slots=TRICKLE["slots"], k=10, h=9, l=4,
+        cohorts=cohorts, fd_threshold=3, seed=46, delivery_spread=2,
+        concurrent_coordinators=2,
+    )
+    vc.assign_cohorts_roundrobin()
+    vc.stagger_fd_counts(np.random.default_rng(46), 3)
+    return vc
+
+
+def _trickle_waves():
+    """One cycle of the cell's arrivals as ``(crash, join)`` pair arrays."""
+    source = churn.PoissonChurn(
+        TRICKLE["members"], TRICKLE["slots"], 8.0, 0.5, TRICKLE["waves"],
+        20180711, np.random.SeedSequence(46),
+    )
+    return list(source.cycle())
+
+
+def _view(vc) -> dict:
+    state = vc.state
+    return {
+        "alive": np.asarray(state.alive)[None],
+        "epoch": np.asarray(state.config_epoch)[None],
+        "config_hi": np.asarray(state.config_hi)[None],
+        "config_lo": np.asarray(state.config_lo)[None],
+    }
+
+
+def _model_checks(model, before, view, cuts_counted) -> dict:
+    """The stream generator's seven numbers (``benchmarks/generators/
+    stream.py``), every one a count of violations."""
+    numbers = model.compare_view(view["alive"])
+    numbers.update(model.compare_epochs(before, view))
+    numbers["unresolved"] = int((view["alive"].sum(axis=1) != model.sizes()).sum())
+    numbers["cut_sizes_unaccounted"] = abs(
+        int((view["epoch"] - before["epoch"]).sum()) - cuts_counted)
+    assert set(numbers) == set(membership_model.LIMITS)
+    return numbers
+
+
+@pytest.mark.parametrize("cohorts", [8, 32, 64])
+def test_streamed_trickle_twin_holds_the_model_after_every_wave(cohorts):
+    """Joins and crashes in ONE stream through the carried step and the
+    build program, at cohort counts with and without a spare bit in their
+    last word: the pipelined stream, a stream drained and checked after
+    every wave, and the batch seam agree bit for bit (state, faults, cuts a
+    wave, configuration ids), and the membership model's seven checks read
+    0 after every wave."""
+    waves = _trickle_waves()
+    assert any(len(join) for _, join in waves) and any(len(crash) for crash, _ in waves)
+
+    def as_wave(crash, join):
+        return StreamWave(crash=tuple(crash[:, 1].tolist()), join=tuple(join[:, 1].tolist()))
+
+    pipelined = _trickle_cluster(cohorts)
+    driver = StreamDriver(pipelined, TRICKLE["rounds_per_wave"], TRICKLE["depth"])
+    for crash, join in waves:
+        driver.submit(as_wave(crash, join))
+    result = driver.drain()
+
+    checked = _trickle_cluster(cohorts)
+    before = _view(checked)
+    model = membership_model.MembershipModel(before["alive"])
+    driver = StreamDriver(checked, TRICKLE["rounds_per_wave"], TRICKLE["depth"])
+    stream_cuts, stream_ids = [], []
+    for w, (crash, join) in enumerate(waves):
+        model.apply(crash, join)
+        driver.submit(as_wave(crash, join))
+        stream_cuts.append(driver.drain().cuts)
+        stream_ids.append(checked.config_id)
+        numbers = _model_checks(model, before, _view(checked), stream_cuts[-1])
+        assert membership_model.failures(numbers) == 0, (w, numbers)
+
+    batch = _trickle_cluster(cohorts)
+    batch_cuts, batch_ids, total = [], [], 0
+    for crash, join in waves:
+        cuts, _ = _batch_drive_cluster(batch, [as_wave(crash, join)], TRICKLE["rounds_per_wave"])
+        total += len(cuts)
+        batch_cuts.append(total)
+        batch_ids.append(batch.config_id)
+
+    assert batch_cuts[-1] >= len(waves) - 2, "the trickle cut nearly nothing: vacuous"
+    assert stream_cuts == batch_cuts and stream_ids == batch_ids
+    assert result.cuts == batch_cuts[-1]
+    for streamed in (pipelined, checked):
+        assert _trees_equal(streamed.state, batch.state)
+        assert _trees_equal(streamed.faults, batch.faults)
+        assert streamed.config_id == batch.config_id
+    # both forms of the carried masks ran: the build program after every
+    # injection, the step's own rebuild (reused by the next round) after a cut
+    counters = pipelined.metrics.counters
+    assert counters["engine_edge_mask_builds"] >= len(waves) - 2
+    assert counters["engine_edge_mask_reuses"] > counters["engine_edge_mask_builds"]
+    jax.clear_caches()  # tier-1 runs near the process's limit of memory maps
 
 
 @pytest.mark.slow
@@ -401,6 +514,37 @@ def test_stream_join_wave_skips_admissibility_fetch():
     assert vc2.metrics.counters["engine_d2h_bytes"] == d2h0 + 2
     with pytest.raises(ValueError, match="not admissible"):
         vc2.inject_join_wave([30])  # already pending: the check still bites
+
+
+def test_a_small_wave_of_an_unseen_size_compiles_nothing():
+    """The stream's waves are a handful of events and every count occurs
+    sooner or later (a cell whose spare slots run out turns its join waves
+    into crash waves of the joins' sizes): up to ``SMALL_WAVE_SLOTS`` events
+    go up at ONE length, so a size this process never saw, crashes, joins or
+    both in one wave, dispatches the programs the first waves compiled."""
+    from rapid_tpu.models.virtual_cluster import SMALL_WAVE_SLOTS
+    from rapid_tpu.utils import engine_telemetry
+
+    vc = _cluster()
+    driver = StreamDriver(vc, rounds_per_wave=4, depth=2)
+    driver.submit(StreamWave(crash=(1, 2, 3)))
+    driver.submit(StreamWave(join=(30, 31)))
+    first = driver.drain()
+    assert first.cuts >= 1
+    seen = engine_telemetry.compile_snapshot()["compiles"]
+    driver.submit(StreamWave(crash=(4,), join=(32, 33, 34)))
+    driver.submit(StreamWave(crash=(5, 6, 7, 8, 9)))
+    driver.submit(StreamWave(join=tuple(range(35, 40))))
+    assert driver.drain().cuts > first.cuts
+    assert engine_telemetry.compile_snapshot()["compiles"] == seen
+    alive = np.asarray(vc.state.alive)
+    assert not alive[[1, 2, 3, 4, 5, 6, 7, 8, 9]].any() and alive[30:40].all()
+    # a batch seam's wave goes up as it is
+    h2d = vc.metrics.counters["engine_h2d_bytes"]
+    vc.crash(list(range(10, 10 + SMALL_WAVE_SLOTS)))
+    assert vc.metrics.counters["engine_h2d_bytes"] == h2d + 4 * SMALL_WAVE_SLOTS
+    vc.crash([0])
+    assert vc.metrics.counters["engine_h2d_bytes"] == h2d + 8 * SMALL_WAVE_SLOTS
 
 
 def test_stream_driver_enforces_admissibility_host_side():
