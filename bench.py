@@ -256,7 +256,8 @@ def memory_report(hlo_audit: dict, *, n: int, k_rings: int, cohorts: int,
 def hlo_audit_summary() -> dict:
     """Per-entrypoint compiled-program facts at the fixed audit shapes
     (tools/analysis/device_program.py, session-cached): collective counts
-    split hot-loop vs total, payload bytes, temp memory, and donation
+    split hot-loop (the round loop alone since PR 47; every loop level in
+    earlier rounds) vs total, payload bytes, temp memory, and donation
     outcomes — the communication-budget companion to the latency metrics,
     diffable across BENCH_r* rounds by tools/perfview.py. Any failure
     (too few devices, an import gap) degrades to ``{"error": ...}`` —
@@ -270,7 +271,7 @@ def hlo_audit_summary() -> dict:
         # Observational mode: on a single-chip backend (the TPU v5 lite0,
         # or un-forced CPU) the four single-device entrypoints still audit;
         # the sharded pair joins whenever >= 8 devices exist. The strict
-        # full-registry requirement belongs to the lockfile GATE, not here.
+        # full-registry requirement belongs to the staticcheck GATE, not here.
         facts = device_program.collect_facts(require_mesh=False)
     except Exception as exc:  # noqa: BLE001 — strictly observational: any
         # compile/import failure reports the reason in-line instead of
@@ -297,107 +298,6 @@ def hlo_audit_summary() -> dict:
             "donation_dropped": entry["donation"]["dropped"],
         }
     return summary
-
-
-def cost_report() -> dict:
-    """Scaling-law cost axis of the trajectory (ISSUE 18), never silently
-    absent: the zero-churn round's ``quiescent_round_cost`` (rides the
-    session's ``collect_facts`` compiles the hlo_audit stage already paid;
-    ROADMAP item 3's sparse restructure must shrink it round over round)
-    and the fitted per-entrypoint scaling classes from the geometry
-    ladder. The ladder costs real compile seconds, so
-    ``RAPID_TPU_BENCH_COST_LADDER=0`` suppresses it EXPLICITLY for smoke
-    runs — every suppressed or unavailable branch yields a named status,
-    exactly like the headline/fleet plans."""
-    tools_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tools")
-    if tools_dir not in sys.path:
-        sys.path.append(tools_dir)
-    try:
-        from analysis import cost_model
-    except Exception as exc:  # noqa: BLE001 — strictly observational
-        reason = {"status": f"unavailable: {exc}"}
-        return {"quiescent_round_cost": reason, "cost_fit": dict(reason)}
-    try:
-        quiescent = cost_model.collect_quiescent_cost(require_mesh=False)
-    except Exception as exc:  # noqa: BLE001 — strictly observational
-        quiescent = None
-        quiescent_status = f"unavailable: {exc}"
-    else:
-        quiescent_status = (
-            "unavailable: no sharded step in this collection "
-            "(needs the 8-device mesh)"
-        )
-    out = {
-        "quiescent_round_cost": (
-            quiescent if quiescent is not None
-            else {"status": quiescent_status}
-        ),
-    }
-    if not _env_int("RAPID_TPU_BENCH_COST_LADDER", 1):
-        out["cost_fit"] = {
-            "status": "suppressed:RAPID_TPU_BENCH_COST_LADDER=0"
-        }
-        return out
-    try:
-        table = cost_model.collect_ladder(require_mesh=False)
-        fits, refusals = cost_model.fit_ladder(table)
-    except Exception as exc:  # noqa: BLE001 — strictly observational
-        out["cost_fit"] = {"status": f"unavailable: {exc}"}
-        return out
-    out["cost_fit"] = {
-        name: {fact: fit["class"] for fact, fit in sorted(per.items())}
-        for name, per in sorted(fits.items())
-    }
-    if refusals:
-        out["cost_fit_refused"] = [
-            f"{name}/{fact}: {why}" for name, fact, why in refusals
-        ]
-    return out
-
-
-def dataflow_summary() -> dict:
-    """Jaxpr provenance axis of the trajectory (ISSUE 19), never silently
-    absent: the observer-silence / tenant-isolation verdicts and the
-    sparse-opportunity coverage from the registry trace (compile-free;
-    the byte-pricing join rides the session's ``collect_facts`` compiles
-    the hlo_audit stage already paid). The trace still costs a few
-    seconds, so ``RAPID_TPU_BENCH_DATAFLOW=0`` suppresses it EXPLICITLY
-    for smoke runs — every suppressed or unavailable branch yields a
-    named status, exactly like the cost ladder."""
-    if not _env_int("RAPID_TPU_BENCH_DATAFLOW", 1):
-        return {
-            "dataflow": {"status": "suppressed:RAPID_TPU_BENCH_DATAFLOW=0"}
-        }
-    tools_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tools")
-    if tools_dir not in sys.path:
-        sys.path.append(tools_dir)
-    try:
-        from analysis import dataflow
-
-        payload, findings = dataflow.collect_dataflow(require_mesh=False)
-    except Exception as exc:  # noqa: BLE001 — strictly observational
-        return {"dataflow": {"status": f"unavailable: {exc}"}}
-    opp = payload["opportunity_map"]
-    tenant = payload["tenant_isolation"]
-    return {
-        "dataflow": {
-            "status": "ok" if not findings else f"findings:{len(findings)}",
-            "observer_silent": all(
-                e["observer_silent"] for e in payload["entrypoints"].values()
-            ),
-            "tenant_isolated": (
-                all(t["proven"] for t in tenant.values()) if tenant else None
-            ),
-            "opportunity_coverage_pct": opp.get("coverage_pct"),
-            "opportunity_claimed_bytes": opp.get("claimed_bytes"),
-            "opportunity_total_bytes": opp.get(
-                "total_collective_payload_bytes"
-            ),
-            **({"opportunity_status": opp["status"]} if "status" in opp else {}),
-            "carry_only_lanes": payload["carry_only_lanes"],
-            **({"findings": [str(f) for f in findings]} if findings else {}),
-        }
-    }
 
 
 # ---------------------------------------------------------------------------
@@ -1510,7 +1410,7 @@ def run_workload(ledger, profile_dir=None) -> None:
     # BENCH_r* trajectory carries the communication budget alongside the
     # latency numbers and tools/perfview.py can flag collective-count
     # drift between rounds. On TPU this is the first compiled-collective
-    # evidence per round; the lockfile GATE (CPU-pinned) stays in the test
+    # evidence per round; the staticcheck GATE (CPU-pinned) stays in the test
     # session — here the facts are recorded, not judged.
     with ledger.stage("hlo_audit", timeout_s=_stage_timeout("hlo_audit")):
         with _heartbeat("hlo audit compile"):
@@ -1531,30 +1431,6 @@ def run_workload(ledger, profile_dir=None) -> None:
             f"vs {mem_fields['bytes_per_member_wide']:.0f} wide "
             f"({mem_fields['mem_status']}); 100M sizing "
             f"{mem_fields['mem_sizing']['100M']['compact_gb']:.0f} GB"
-        )
-        # Scaling-law cost axis (ISSUE 18): quiescent round cost +
-        # fitted classes, riding the same stage (and its compiles).
-        with _heartbeat("cost ladder compile"):
-            cost_fields = cost_report()
-        fit = cost_fields["cost_fit"]
-        _mark(
-            "cost fit: " + (
-                fit["status"] if "status" in fit
-                else f"{len(fit)} entrypoints classified"
-            )
-        )
-        # Jaxpr provenance axis (ISSUE 19): observer-silence and
-        # tenant-isolation verdicts plus the sparse-opportunity coverage,
-        # riding the same stage (the byte join reuses its compiles).
-        with _heartbeat("dataflow trace"):
-            dataflow_fields = dataflow_summary()
-        df = dataflow_fields["dataflow"]
-        _mark(
-            "dataflow: " + (
-                df["status"] if df["status"] != "ok"
-                else f"proofs ok, opportunity map covers "
-                     f"{df['opportunity_coverage_pct']}% of quiescent bytes"
-            )
         )
 
     # Opt-in jax.profiler capture (--profile DIR): one extra resolved churn
@@ -1707,16 +1583,6 @@ def run_workload(ledger, profile_dir=None) -> None:
         # 100k->100M deployment sizing, and the never-silently-absent
         # mem_status — perfview renders the MEM column from these.
         **mem_fields,
-        # Scaling-law cost axis (ISSUE 18): the zero-churn round's frozen
-        # per-round cost + fitted per-entrypoint scaling classes (or the
-        # named suppressed/unavailable status) — perfview renders the
-        # COSTFIT column from these.
-        **cost_fields,
-        # Jaxpr dataflow provenance axis (ISSUE 19): proof verdicts + the
-        # sparse-opportunity coverage (or the named suppressed/unavailable
-        # status) — perfview renders the OPPTY column and the
-        # dataflow-missing trust flag from these.
-        **dataflow_fields,
         # Engine-tier provenance for the trajectory: how much compile time
         # this run paid and whether the persistent cache carried it.
         "compiles": engine_compiles["compiles"],

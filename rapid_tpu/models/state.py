@@ -116,7 +116,7 @@ class EngineConfig(NamedTuple):
     compact: int = 0
     # Device-resident telemetry plane (an int knob, like ``compact``): 0 =
     # off — the round bodies trace NO telemetry code and compile
-    # byte-identical programs (the hlo.lock.json gate freezes that); 1 = a
+    # byte-identical programs (nothing of the plane is traced); 1 = a
     # :class:`TelemetryLanes` pytree rides beside the state through the
     # jitted round bodies, accumulating per-round activity/tally/conflict
     # counters on-device. Telemetry never changes engine results: the lanes
@@ -125,7 +125,7 @@ class EngineConfig(NamedTuple):
     telemetry: int = 0
     # Device round-trace ring capacity R (an int knob holding the SIZE, not
     # a boolean): 0 = off — the round bodies trace NO ring code and compile
-    # byte-identical programs (frozen by the hlo.lock.json gate, like
+    # byte-identical programs (nothing of the ring is traced, like
     # ``telemetry``); R > 0 = a :class:`TraceRing` of the last R per-round
     # records rides beside the state through the jitted round bodies. The
     # ring is a REFINEMENT of the telemetry plane (its active-subject count
@@ -611,8 +611,8 @@ def initial_telemetry(cfg: EngineConfig) -> TelemetryLanes:
 
 
 def telemetry_bytes_total(cfg: EngineConfig) -> int:
-    """At-rest bytes of one cluster's telemetry lanes (all int32) — the
-    figure the hlo.lock.json ``telemetry`` block freezes per device."""
+    """At-rest bytes of one cluster's telemetry lanes (all int32), per
+    device."""
     dims = {"n": cfg.n, "k": cfg.k, "c": cfg.c, "b": TELEMETRY_BUCKETS}
     total = 0
     for shape in TELEMETRY_LANE_SPECS.values():
@@ -696,9 +696,9 @@ def initial_trace(cfg: EngineConfig) -> TraceRing:
 
 
 def trace_bytes_total(cfg: EngineConfig) -> int:
-    """At-rest bytes of one cluster's trace ring (all int32) — the frozen
-    per-device figure the hlo.lock.json ``trace`` block carries: R rounds of
-    history at a byte cost fixed by config, not by event rate."""
+    """At-rest bytes of one cluster's trace ring (all int32), per
+    device: R rounds of history at a byte cost fixed by config, not by
+    event rate."""
     dims = {"r": cfg.trace}
     total = 0
     for shape in TRACE_LANE_SPECS.values():

@@ -3,8 +3,8 @@
 The classifier that lived here (collective-kind matching, payload
 accounting, the hot-loop/cond/prologue location attribution) grew into
 ``rapid_tpu.parallel.hlo_facts`` when the ``device_program`` analyzer
-family (tools/analysis/device_program.py) started freezing its facts into
-``tools/analysis/hlo.lock.json``. This module stays as the compatible
+family (tools/analysis/device_program.py) started reading its facts. This
+module stays as the compatible
 import surface for the existing consumers (``tests/test_parallel.py``,
 ``tools/collective_audit.py``): same names, one definition, and a plain
 package-relative import — no path games, so an installed distribution of

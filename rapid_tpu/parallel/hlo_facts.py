@@ -12,9 +12,10 @@ library, never the reverse).
 
 Everything here is derived from two pieces of metadata XLA records in the
 compiled artifact: the shape string of each op (payload accounting) and the
-``op_name`` jax attaches (location attribution — "…/while/body/…" is the
-convergence hot loop, "…/cond/…" a lax.cond branch). The module header's
-``input_output_alias`` table is the compiled truth about buffer donation:
+``op_name`` jax attaches (location attribution — "…/while/body/…" is a
+loop level, the one around the round's ``fd_tick`` scope the round loop,
+"…/cond/…" a lax.cond branch). The module header's ``input_output_alias``
+table is the compiled truth about buffer donation:
 a ``donate_argnums`` argument either appears there or was dropped.
 """
 
@@ -33,7 +34,7 @@ COLLECTIVE_KINDS = (
 
 #: Host<->device transfer ops: a compiled engine program must not smuggle
 #: host round-trips into the dispatch (the whole point of the fused-engine
-#: design); any of these appearing is budget-checked against the lock.
+#: design); any of these appearing in a registered program is a finding.
 TRANSFER_OPS = (
     "infeed",
     "outfeed",
@@ -84,61 +85,6 @@ def shape_bytes(shape_str: str, unknown: Optional[List[str]] = None) -> int:
     return (total_bits + 7) // 8
 
 
-def shape_operand_bytes(
-    shape_str: str, unknown: Optional[List[str]] = None
-) -> List[int]:
-    """Per-operand payload bytes of a (possibly tuple) shape string.
-
-    A variadic all-reduce carries a tuple shape — ``(u32[64]{0},
-    f32[64]{0})`` — and :func:`shape_bytes` prices the whole tuple as one
-    sum. This returns one entry per array leaf instead, so callers can
-    account BOTH the total payload (sum) and the largest single operand:
-    the scaling-class fit must see totals (multi-operand fusion cannot
-    hide payload growth inside a tuple) while per-operand sizes keep the
-    largest-single-payload classing honest. Unknown dtypes follow the
-    :func:`shape_bytes` contract: appended to ``unknown`` when a list is
-    passed (the operand is skipped), else ``ValueError``."""
-    out: List[int] = []
-    for dtype, dims in _SHAPE_RE.findall(shape_str):
-        bits = DTYPE_BITS.get(dtype)
-        if bits is None:
-            if unknown is None:
-                raise ValueError(f"unknown HLO dtype {dtype!r} in {shape_str!r}")
-            unknown.append(dtype)
-            continue
-        elems = 1
-        for d in dims.split(","):
-            if d:
-                elems *= int(d)
-        out.append((elems * bits + 7) // 8)
-    return out
-
-
-def compiled_cost_analysis(compiled) -> Optional[Dict[str, float]]:
-    """Normalized ``compiled.cost_analysis()``: ``{"flops", "bytes_accessed"}``
-    floats, or None when the backend exposes neither (never guessed).
-
-    jax versions disagree on the return shape (a dict, or a one-element
-    list of dicts per partition) and backends disagree on which keys they
-    populate; this folds both to one optional dict keyed by our fact
-    names. Duck-typed on the compiled object — no jax import, keeping this
-    module stdlib-only."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # noqa: BLE001 — backends without a cost model raise backend-specific types; absent pricing is the documented None contract, not a wedge
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    out: Dict[str, float] = {}
-    for key, fact in (("flops", "flops"), ("bytes accessed", "bytes_accessed")):
-        value = ca.get(key)
-        if isinstance(value, (int, float)) and value == value and value >= 0:
-            out[fact] = float(value)
-    return out or None
-
-
 def entry_parameter_bytes(
     compiled_text: str, unknown: Optional[List[str]] = None
 ) -> Dict[str, int]:
@@ -178,26 +124,66 @@ def entry_parameter_bytes(
     return {}
 
 
-def classify_location(op_name: str) -> str:
-    """hot-loop / hot-loop-cond / cond / prologue, from op_name metadata.
+#: One enclosing loop level of an op_name: the body or the predicate of a
+#: ``lax.while_loop``, in the plain spelling and in the batched
+#: ``vmap(while)`` one the tenant fleet's vmapped loops trace under.
+_LOOP_SCOPE_RE = re.compile(r"(?:/while|vmap\(while\))/(?:body|cond)")
 
-    Both loop spellings count: the plain ``…/while/body/…`` scope and the
-    batched ``…vmap(while)/body/…`` scope the tenant fleet's vmapped loops
-    trace under — a fleet hot-loop collective must never pass as prologue.
+#: The scope every engine round opens first (``fd_tick`` of
+#: ``utils/dispatch.ENGINE_SCOPES``), plain and as a top-level ``vmap``
+#: spells it: the loop around it IS the round loop, whatever else nests.
+_ROUND_SCOPE_RE = re.compile(r"[/(]fd_tick[/)]")
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def round_loop(compiled_text: str) -> Optional[str]:
+    """The op_name prefix of the body of the program's ROUND loop: the
+    innermost loop around the round's own ``fd_tick`` scope
+    (``jit(program)/while/body/while/body`` in a wave). None when the
+    program names no round (``sync``, a corpus miniature) or runs its one
+    round outside any loop (``step``). A program that names rounds under
+    two different loops has no one round loop, and raises."""
+    bodies = set()
+    for name in _OP_NAME_RE.findall(compiled_text):
+        scope = _ROUND_SCOPE_RE.search(name)
+        if scope is None:
+            continue
+        loops = list(_LOOP_SCOPE_RE.finditer(name, 0, scope.start()))
+        if loops:
+            bodies.add(name[:loops[-1].end()])
+    if len(bodies) > 1:
+        raise ValueError(f"rounds under more than one loop: {sorted(bodies)}")
+    return bodies.pop() if bodies else None
+
+
+def classify_location(op_name: str, round_body: Optional[str] = None) -> str:
+    """hot-loop / hot-loop-cond / wave-loop / wave-loop-cond / cond /
+    prologue, from op_name metadata.
+
+    ``round_body`` is the program's :func:`round_loop`
+    (:func:`audit_collectives` derives it from the text). ``hot-loop`` is
+    that loop alone, with whatever nests inside it: a wave program runs it
+    inside the per-convergence loop, and every other loop level reads
+    ``wave-loop`` (the mask build at the head of each convergence is
+    per-cut work, not per-round work). Without a ``round_body`` every loop
+    level is ``hot-loop``.
+
+    A ``-cond`` suffix (and plain ``cond`` outside any loop) marks an op
+    under a ``lax.cond`` branch. A loop PREDICATE runs unconditionally
+    every iteration: its ``/while/cond`` scope is a loop level, never a
+    gated branch. Both loop spellings count: a fleet hot-loop collective
+    must never pass as prologue.
     """
-    for marker in ("/while/body", "vmap(while)/body"):
-        if marker in op_name:
-            if "/cond/" in op_name.split(marker, 1)[1]:
-                return "hot-loop-cond"
-            return "hot-loop"
-    if "/while/cond" in op_name or "vmap(while)/cond" in op_name:
-        # The while PREDICATE runs unconditionally every round — it is hot
-        # loop, not a gated branch (a generic '/cond/' test would exempt it
-        # from the invariants).
-        return "hot-loop"
-    if "/cond/" in op_name:
-        return "cond"
-    return "prologue"
+    first = _LOOP_SCOPE_RE.search(op_name)
+    if first is None:
+        return "cond" if "/cond/" in op_name else "prologue"
+    in_round = round_body is None or op_name.startswith(
+        (round_body + "/", round_body[:-len("body")] + "cond/")
+    )
+    level = "hot-loop" if in_round else "wave-loop"
+    inside = _LOOP_SCOPE_RE.sub("", op_name[first.start():])
+    return level + "-cond" if "/cond/" in inside else level
 
 
 def source_of(op_name: str) -> str:
@@ -214,10 +200,7 @@ def source_of(op_name: str) -> str:
         ("sort", "sort"),
         ("reduce", "reduction"),
         # Lowering-artifact spellings: GSPMD re-shards around these ops and
-        # the resulting collectives inherit their op_name leaf. Naming them
-        # keeps the dataflow gate's cost join total — an unnamed source
-        # would land in "other" and the sparse-opportunity map could not
-        # attribute its payload bytes (dataflow.py joins on these labels).
+        # the resulting collectives inherit their op_name leaf.
         ("scatter", "scatter update"),
         ("concatenate", "concatenate"),
         ("dynamic_slice", "dynamic slice"),
@@ -233,8 +216,8 @@ def source_of(op_name: str) -> str:
 def payload_class(nbytes: int, n: int, c: int) -> str:
     """Scale class of a collective payload at engine shapes: ``cn`` ([c,n]
     or larger), ``n`` (at least [n]-proportional), ``scalar`` otherwise.
-    The lockfile freezes the CLASS, not raw bytes, so a benign constant
-    tweak does not drift the gate while a scale-class jump always does."""
+    The invariants are stated over the CLASS, not raw bytes, so a benign
+    constant tweak does not trip them while a scale-class jump always does."""
     if nbytes >= c * n:
         return "cn"
     if nbytes >= n:
@@ -254,6 +237,7 @@ def audit_collectives(compiled_text: str, n: int, c: int) -> List[Dict]:
     Matches both synchronous ops and the async ``-start`` halves TPU
     compiles emit (``all-reduce-start``/``all-reduce-done`` pairs — the
     ``-done`` half is skipped so pairs are not double-counted)."""
+    round_body = round_loop(compiled_text)
     rows = []
     for line in compiled_text.splitlines():
         m = re.search(
@@ -265,22 +249,17 @@ def audit_collectives(compiled_text: str, n: int, c: int) -> List[Dict]:
         if not m:
             continue
         shape, kind = m.group(1), m.group(2)
-        op_name_m = re.search(r'op_name="([^"]*)"', line)
+        op_name_m = _OP_NAME_RE.search(line)
         op_name = op_name_m.group(1) if op_name_m else ""
         unknown: List[str] = []
-        operand_bytes = shape_operand_bytes(shape, unknown=unknown)
-        payload = sum(operand_bytes)
+        payload = shape_bytes(shape, unknown=unknown)
         rows.append({
             "kind": kind,
             "shape": shape.split("{")[0],
-            # "bytes" is the TOTAL payload (sum over tuple operands) —
-            # the fact the ladder fit consumes; "largest_operand_bytes"
-            # prices the biggest single array so a variadic fusion can
-            # neither hide growth in the sum nor in one operand.
+            # The TOTAL payload: a variadic collective carries a tuple
+            # shape, summed over its operands.
             "bytes": payload,
-            "operand_bytes": operand_bytes,
-            "largest_operand_bytes": max(operand_bytes, default=0),
-            "location": classify_location(op_name),
+            "location": classify_location(op_name, round_body),
             "source": source_of(op_name),
             "cn_scale": payload >= c * n,
             "n_scale": payload >= n,
@@ -379,7 +358,11 @@ def groups_cross_blocks(
 
 
 def collective_violations(rows: List[Dict]) -> Dict[str, List[Dict]]:
-    """The two invariants the sharded design guarantees."""
+    """The two invariants the sharded design guarantees on the 1-D mesh.
+    The first is not true of the 2-D one, whose round loop also re-lays the
+    [c]-sized tally out across the cohort axis every round (scalar-class
+    all-to-alls and all-gathers; tests/test_hlo_gate.py holds them to that
+    class, ROADMAP A12 would remove them)."""
     return {
         # Every round, unconditionally: reductions only — an unconditional
         # gather here would ship O(n)+ bytes per round for no reason.

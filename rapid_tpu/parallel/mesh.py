@@ -23,10 +23,13 @@ its cross-shard permutation gathers are the one collective-heavy op (XLA
 inserts what it needs). This is not just a docstring claim:
 ``tools/collective_audit.py`` classifies every collective in the compiled
 HLO (EVALUATION.md §3c), ``tests/test_parallel.py`` pins the invariants,
-and the ``device_program`` gate freezes both the 1-D and the 2-D compiled
-programs' collective/donation budgets into ``tools/analysis/hlo.lock.json``
-— the convergence hot loop's unconditional traffic stays reduce-class, with
-[c,n]-scale gathers confined to lax.cond branches.
+and ``tests/test_hlo_gate.py`` asserts, on both the 1-D and the 2-D compiled
+wave, that every donated leaf is aliased and what the round loop carries
+outside a conditional: on the 1-D mesh all-reduces alone, of scalar or [n]
+class; on the 2-D mesh also two all-to-alls and two all-gathers of scalar
+class every round, GSPMD's re-layout of the [c]-sized tally across the
+cohort axis (an open cost, ROADMAP A12). [c,n]-scale gathers sit in
+lax.cond branches or in the per-convergence mask build on both.
 
 This is the TPU equivalent of the reference's scale story (§ SURVEY 5.7):
 the reference keeps per-node load O(K) as N grows; here the whole cluster's
@@ -73,7 +76,7 @@ COHORT_AXIS = "cohort"
 #: The multi-tenant batch axis (rapid_tpu/tenancy): a LEADING [t] dimension
 #: stacked over the whole engine pytree, sharded fully parallel — tenants
 #: never communicate, so no collective may ever carry the tenant axis in
-#: its replica groups (the device_program gate freezes that budget).
+#: its replica groups (the device_program gate holds it to zero).
 TENANT_AXIS = "tenant"
 
 #: Spec tuples are PartitionSpec entries by position: an axis name, or None

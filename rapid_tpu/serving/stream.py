@@ -16,7 +16,7 @@ that workload:
   returns immediately and starts building the NEXT wave while the device
   chews through this one.
 - **Double-buffered inputs.** Every engine entrypoint donates its state
-  pytree (38/38 leaves aliased, frozen in ``hlo.lock.json``), so the state
+  pytree (every leaf aliased: ``tests/test_hlo_gate.py``), so the state
   buffers ping-pong in place — and with them the per-edge masks the meshless
   step carries from round to round, rebuilt only after an injection and in a
   cut's taken arm (``CarriedMasks``: a wave of eight rounds builds them

@@ -21,9 +21,9 @@ Sharding: the leading ``[t]`` axis shards on the ``'tenant'`` axis of the
 ``fleet_state_shardings`` prepends the tenant axis to the SAME rule table —
 an uncovered leaf stays a hard error). Tenants never communicate: no
 collective in the compiled fleet program may carry the tenant axis in its
-replica groups, and the ``device_program`` gate freezes that budget
-(``fleet3d_step``/``fleet3d_wave`` in ``hlo.lock.json``,
-``cross_tenant_collectives: 0`` — drift fails the build).
+replica groups, and the ``device_program`` gate holds the live compiled
+programs to that (``fleet3d_step``/``fleet3d_wave``,
+``cross_tenant_collectives == 0`` — one such collective fails the build).
 
 Batched-control-flow tradeoffs, stated plainly:
 
@@ -731,8 +731,8 @@ fleet_trace_digest = jax.jit(jax.vmap(trace_digest_impl))
 def make_fleet_step(cfg: EngineConfig, mesh: Mesh):
     """jit the fleet step with explicit in-shardings over a
     ``('tenant', 'cohort', 'nodes')`` mesh — the audited batched-step
-    entrypoint (``fleet3d_step`` in the HLO lock: zero cross-tenant
-    collectives, donation fully aliased)."""
+    entrypoint (``fleet3d_step`` in the ``device_program`` registry: zero
+    cross-tenant collectives, donation fully aliased)."""
     st_sh = fleet_state_shardings(mesh)
     ft_sh = fleet_fault_shardings(mesh)
     kn_sh = knob_shardings(mesh)
@@ -1315,8 +1315,8 @@ class TenantFleet(DispatchSeam):
         wave-path freeze lanes to it — the lockstep ``done`` mask the fleet
         wave already carries holds the tenant bit-frozen from iteration 0,
         with no recompile (the lanes are data) and zero effect on the other
-        B-1 tenants (vmap independence, the zero-cross-tenant budget frozen
-        in hlo.lock.json). The batched STEP path has no freeze lane (a
+        B-1 tenants (vmap independence, the zero-cross-tenant budget of the
+        ``device_program`` gate). The batched STEP path has no freeze lane (a
         per-tenant gate there would be a new program input — a recompile,
         which this mechanism exists to avoid): step dispatches keep
         executing the quarantined tenant's rounds, harmlessly to the

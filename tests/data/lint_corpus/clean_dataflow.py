@@ -1,11 +1,9 @@
 """Lint corpus (clean): dataflow provenance with every proof holding.
 
 The silent twin of ``dataflow_observer_leak.py``: telemetry is written
-from the engine but never read back (a one-way plane), every fleet op
-stays inside its own tenant row (elementwise + per-tenant reduction),
-and the dense cumulative tally runs unconditionally — real work, not a
-mask-gated sparse opportunity. The ``dataflow`` family must stay
-silent on all three.
+from the engine but never read back (a one-way plane), and every fleet
+op stays inside its own tenant row (elementwise + per-tenant reduction).
+The ``dataflow`` family must stay silent on both.
 """
 
 from typing import NamedTuple
@@ -58,37 +56,14 @@ def _per_tenant_fleet():
     }
 
 
-def _ungated_dense_round():
-    # Dense over all N, but unconditional: no mask gates it, so it is
-    # honest work and not an opportunity-map entry.
-    def round_body(state):
-        return EngineState(alive=state.alive, cuts=jnp.cumsum(state.cuts))
-
-    return {
-        "jit": jax.jit(round_body),
-        "args": (
-            EngineState(
-                alive=jnp.ones((N,), jnp.bool_),
-                cuts=jnp.zeros((N,), jnp.int32),
-            ),
-        ),
-    }
-
-
 DATAFLOW_AUDIT_PROGRAMS = {
     "observer_silent": {
         "build": _observer_silent,
-        "checks": ("observer-effect", "dense-op"),
-        "dense_n": N,
+        "checks": ("observer-effect",),
     },
     "per_tenant_fleet": {
         "build": _per_tenant_fleet,
         "checks": ("cross-tenant",),
         "tenants": TENANTS,
-    },
-    "ungated_dense_round": {
-        "build": _ungated_dense_round,
-        "checks": ("dense-op",),
-        "dense_n": N,
     },
 }

@@ -1,12 +1,10 @@
 """Lint corpus: dataflow provenance defects, one per proof check.
 
-Three miniature traced programs in the registry spec shape, each
+Two miniature traced programs in the registry spec shape, each
 violating one property the ``dataflow`` family proves over the real
 engine: a telemetry lane read back into an engine lane (the observer
-perturbs its subject), a gather whose indices cross the fleet's tenant
-axis (tenant ``t`` reads tenant ``t+1``'s lanes), and a dense
-full-``N`` op inside an activity-gated ``cond`` branch (provably
-maskable work — a sparse-opportunity candidate the map must name).
+perturbs its subject), and a gather whose indices cross the fleet's
+tenant axis (tenant ``t`` reads tenant ``t+1``'s lanes).
 ``clean_dataflow.py`` is the silent twin.
 """
 
@@ -60,29 +58,6 @@ def _cross_tenant_gather():
     }
 
 
-def _gated_dense_round():
-    # The cumulative tally runs over all N slots, but the cond predicate
-    # derives from the activity mask: the whole branch is provably
-    # skippable when nothing is alive, yet it prices dense.
-    def round_body(state):
-        def busy(s):
-            return EngineState(alive=s.alive, cuts=jnp.cumsum(s.cuts))
-
-        return jax.lax.cond(
-            jnp.any(state.alive), busy, lambda s: s, state
-        )
-
-    return {
-        "jit": jax.jit(round_body),
-        "args": (
-            EngineState(
-                alive=jnp.ones((N,), jnp.bool_),
-                cuts=jnp.zeros((N,), jnp.int32),
-            ),
-        ),
-    }
-
-
 DATAFLOW_AUDIT_PROGRAMS = {
     "observer_feedback": {  # expect: dataflow-observer-effect
         "build": _observer_feedback,
@@ -92,10 +67,5 @@ DATAFLOW_AUDIT_PROGRAMS = {
         "build": _cross_tenant_gather,
         "checks": ("cross-tenant",),
         "tenants": TENANTS,
-    },
-    "gated_dense_round": {  # expect: dataflow-dense-op
-        "build": _gated_dense_round,
-        "checks": ("dense-op",),
-        "dense_n": N,
     },
 }

@@ -96,11 +96,6 @@ def test_cpu_run_emits_complete_ledger(tmp_path):
             # checkpoint resume, bit-identity check.
             "RAPID_TPU_BENCH_RECOVERY_N": "48",
             "RAPID_TPU_BENCH_RECOVERY_WAVES": "4",
-            # Suppress the cost-model geometry ladder (ISSUE 18): the
-            # fitted classes are gate territory (test_cost_model /
-            # test_lint); here only the never-silently-absent contract is
-            # under test, and the ladder would cost ~40 s of compiles.
-            "RAPID_TPU_BENCH_COST_LADDER": "0",
         },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -286,18 +281,6 @@ def test_cpu_run_emits_complete_ledger(tmp_path):
     assert result["hlo_audit"]["step_compact"]["argument_bytes"] < (
         result["hlo_audit"]["step"]["argument_bytes"]
     )
-    # ISSUE 18 cost axis, same run and stage: quiescent_round_cost and
-    # cost_fit are NEVER silently absent. The quiescent block is either
-    # the measured sharded-step cost (when this run got the 8-device
-    # mesh) or a named unavailability; the suppressed ladder names its
-    # knob rather than vanishing.
-    quiescent = result["quiescent_round_cost"]
-    assert ("collective_payload_bytes" in quiescent) or (
-        quiescent["status"].startswith("unavailable")
-    ), quiescent
-    assert result["cost_fit"] == {
-        "status": "suppressed:RAPID_TPU_BENCH_COST_LADDER=0"
-    }
 
 
 def test_headline_plan_is_never_silently_absent(monkeypatch):

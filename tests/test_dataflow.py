@@ -1,20 +1,20 @@
 """Proof + unit gate for the jaxpr dataflow provenance family.
 
 The expensive half traces the REAL registry once per session (compile
-free — ``jitted.trace``) and pins the ISSUE-19 acceptance surface: the
-observer-silence and tenant-isolation proofs hold over every registered
-entrypoint, the sparse-opportunity map explains >= 90% of the frozen
-quiescent payload bytes, and the committed ``dataflow.lock.json``
-round-trips byte-identically. The cheap half runs synthetic jaxprs
-through the taint interpreter — most importantly the scan-carry /
-donated-buffer aliasing cases where a union-carry interpreter would
-fabricate influence edges the per-slot fixpoint must not.
+free — ``jitted.trace``) and asserts the two proofs on the live trace, one
+case an entrypoint: no observer lane (``telem.*`` / ``trace.*``) sits in
+the influence set of a ``state.*`` / ``events.*`` output, and every output
+lane of a fleet program keeps the tenant axis with no axis rule falling
+back. The cheap half runs synthetic jaxprs through the interpreters — most
+importantly the scan-carry / donated-buffer aliasing cases where a
+union-carry interpreter would fabricate influence edges the per-slot
+fixpoint must not, and the same-width bitcast the ring walk's scan word
+takes.
 """
 
 from __future__ import annotations
 
 import ast
-import json
 import sys
 from pathlib import Path
 
@@ -22,15 +22,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "tools"))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
 
 import staticcheck  # noqa: E402
 from analysis import dataflow, device_program  # noqa: E402
 from analysis.core import Finding  # noqa: E402
+from tests.test_hlo_gate import REGISTRY  # noqa: E402
 
-
-def _registry_trees():
-    """The minimal (tree, rel) set that opens the presence gate."""
-    return [(ast.parse(""), src) for src in device_program.REGISTRY_SOURCES]
+#: What the proofs trace: the compiled registry and the meshless fleet step.
+TRACED = REGISTRY + ("fleet_step",)
+FLEET = ("fleet3d_step", "fleet3d_wave", "fleet_step")
 
 
 # ---------------------------------------------------------------------------
@@ -38,123 +39,44 @@ def _registry_trees():
 # ---------------------------------------------------------------------------
 
 
-def test_head_proofs_hold_over_every_registered_entrypoint():
-    payload, findings = staticcheck.collect_dataflow()
-    assert not findings, "\n".join(str(f) for f in findings)
-    registry = set(device_program._build_registry())
-    assert set(payload["entrypoints"]) == registry | {"fleet_step"}
-    for name, entry in payload["entrypoints"].items():
-        assert entry["observer_silent"] is True, name
-    for name, proof in payload["tenant_isolation"].items():
-        assert proof["proven"] is True, name
-        assert proof["mixed_outputs"] == [], name
-        assert proof["axis_rule_fallbacks"] == [], name
+def _findings_about(name):
+    return [
+        str(f) for f in staticcheck.collect_dataflow()[1]
+        if f.message.startswith(name + ": ")
+    ]
 
 
-def test_opportunity_map_explains_the_frozen_quiescent_bytes():
-    payload, _ = staticcheck.collect_dataflow()
-    opp = payload["opportunity_map"]
-    frozen = json.loads(
-        (staticcheck.core.REPO / staticcheck.COST_LOCK_REL).read_text()
-    )
-    assert opp["total_collective_payload_bytes"] == (
-        frozen["quiescent_round_cost"]["collective_payload_bytes"]
-    )
-    assert opp["coverage_pct"] >= 90.0
-    # Every claimed bucket names the mask lane(s) gating its dense ops —
-    # that attribution is what makes the map a work-list, not a listing.
-    for bucket in opp["dense_gated"]:
-        for op in bucket["dense_ops"]:
-            assert op["gated_by"], (bucket, op)
+@pytest.mark.parametrize("name", TRACED)
+def test_no_observer_lane_influences_the_engine(name):
+    proofs, _ = staticcheck.collect_dataflow()
+    assert set(proofs["observer_silent"]) == set(TRACED)
+    assert proofs["observer_silent"][name] is True, _findings_about(name)
 
 
-def test_carry_only_lanes_reconcile_with_the_deadcode_collector():
-    # The two liveness families must never disagree: every lane the jaxpr
-    # says is carry-only is host-fetched by name (attribute reads,
-    # getattr strings, f-string fields — the deadcode family's collector),
-    # which is exactly why no dataflow-dead-lane finding fires on HEAD.
-    payload, findings = staticcheck.collect_dataflow()
-    referenced = dataflow._tree_reference_names()
-    for lane in payload["carry_only_lanes"]:
-        assert dataflow._field_of(lane) in referenced, lane
-    assert not [f for f in findings if f.check == "dataflow-dead-lane"]
+@pytest.mark.parametrize("name", FLEET)
+def test_every_output_lane_of_a_fleet_program_keeps_the_tenant_axis(name):
+    proofs, _ = staticcheck.collect_dataflow()
+    assert set(proofs["tenant_isolation"]) == set(FLEET)
+    proof = proofs["tenant_isolation"][name]
+    assert proof["axis_rule_fallbacks"] == [], proof
+    assert proof["mixed_outputs"] == [], _findings_about(name)
+    assert proof["proven"] is True
 
 
-def test_committed_lock_matches_the_live_trace():
-    assert staticcheck.check_dataflow_lock(_registry_trees()) == []
-
-
-# ---------------------------------------------------------------------------
-# Lock machinery
-# ---------------------------------------------------------------------------
-
-
-def test_update_dataflow_lock_is_a_deterministic_round_trip(
-    tmp_path, monkeypatch, capsys
-):
-    # Regenerating over an unchanged tree produces the byte-identical
-    # lock, into a REDIRECTED path so the committed file is never
-    # silently overwritten (same discipline as the wire-lock round trip).
-    committed = (
-        staticcheck.core.REPO / staticcheck.DATAFLOW_LOCK_REL
-    ).read_text()
-    target = tmp_path / "dataflow.lock.json"
-    monkeypatch.setattr(dataflow, "DATAFLOW_LOCK_REL", str(target))
-    rc = staticcheck.main(["--update-dataflow-lock"])
-    assert rc == 0
-    assert "wrote" in capsys.readouterr().out
-    assert target.read_text() == committed
-
-
-def test_update_refuses_while_any_proof_fails(tmp_path, monkeypatch):
+def test_the_tree_gate_reports_the_live_proofs_findings(monkeypatch):
+    # The sweep reports what the proofs find on the live trace; trees
+    # without the engine sources (a tmp_path unit-test tree) never pay a
+    # registry trace.
     leak = Finding(
-        "tools/analysis/dataflow.lock.json", 1, "dataflow-observer-effect",
-        "observer lane telem.tl_enq influences subject lane state.cuts",
+        device_program.REGISTRY_REL, 1, "dataflow-observer-effect",
+        "step_telem: observer lane(s) telem.tl_enq influence subject lane "
+        "state.cuts",
     )
-    monkeypatch.setattr(
-        dataflow, "collect_dataflow", lambda force=False: ({}, [leak])
-    )
-    target = tmp_path / "dataflow.lock.json"
-    monkeypatch.setattr(dataflow, "DATAFLOW_LOCK_REL", str(target))
-    findings, lock_path = dataflow.update_dataflow_lock()
-    assert lock_path is None and not target.exists()
-    assert [f.check for f in findings] == ["dataflow-observer-effect"]
-    assert findings[0].message.startswith("refusing to freeze: ")
-
-
-def test_lock_drift_is_reported_per_block(tmp_path, monkeypatch):
-    tampered = json.loads(
-        (staticcheck.core.REPO / staticcheck.DATAFLOW_LOCK_REL).read_text()
-    )
-    tampered["carry_only_lanes"] = ["state.no_such_lane"]
-    target = tmp_path / "dataflow.lock.json"
-    target.write_text(json.dumps(tampered, indent=2, sort_keys=True) + "\n")
-    monkeypatch.setattr(dataflow, "DATAFLOW_LOCK_REL", str(target))
-    findings = staticcheck.check_dataflow_lock(_registry_trees())
-    assert [f.check for f in findings] == ["dataflow-lock-drift"]
-    assert "carry_only_lanes" in findings[0].message
-
-
-def test_presence_gate_skips_retargeted_trees():
-    # A tree without the engine sources (a tmp_path unit-test tree) must
-    # never pay a registry trace or compare against the lock.
-    trees = [(ast.parse(""), "some/other/module.py")]
-    assert staticcheck.check_dataflow_lock(trees) == []
-
-
-def test_coverage_floor_and_two_lock_total_are_enforced():
-    opp = {
-        "total_collective_payload_bytes": 100,
-        "coverage_pct": 50.0,
-        "unclaimed": [
-            {"location": "cond", "source": "reduction", "bytes": 50},
-        ],
-    }
-    findings = dataflow._coverage_findings(opp, ("probe", 1))
-    messages = [f.message for f in findings]
-    assert any("does not match the cost lock" in m for m in messages)
-    assert any("floor 90%" in m for m in messages)
-    assert all(f.check == "dataflow-dense-op" for f in findings)
+    monkeypatch.setattr(dataflow, "collect_dataflow", lambda: ({}, [leak]))
+    trees = [(ast.parse(""), src) for src in device_program.REGISTRY_SOURCES]
+    assert staticcheck.check_dataflow_proofs(trees) == [leak]
+    other = [(ast.parse(""), "some/other/module.py")]
+    assert staticcheck.check_dataflow_proofs(other) == []
 
 
 # ---------------------------------------------------------------------------
@@ -188,6 +110,36 @@ def test_scan_carry_slots_stay_separate():
     assert a_final == frozenset([0])
     assert b_final == frozenset([1])
     assert ys == frozenset([1, 2])
+
+
+def test_a_same_width_bitcast_keeps_the_tenant_axis_and_a_narrowing_one_does_not():
+    # The ring walk's scan word is a uint32 <-> int32 bitcast: elementwise,
+    # so a vmap'd round trip keeps every tenant in its own row. A bitcast
+    # to a narrower dtype grows a trailing dimension the axis interpreter
+    # does not track: reported, conservatively.
+    tenants = 4
+
+    def axes(fn):
+        spec = {
+            "jit": jax.jit(jax.vmap(fn)),
+            "args": (jnp.arange(tenants * 8, dtype=jnp.uint32).reshape(tenants, 8),),
+        }
+        entry = dataflow._trace_entry("probe", spec)
+        fallbacks = []
+        out = dataflow._axis_closed(
+            entry["closed"], dataflow._tenant_in_axes(entry, spec, tenants),
+            tenants, fallbacks,
+        )
+        return out, fallbacks
+
+    def round_trip(word):
+        signed = jax.lax.bitcast_convert_type(word, jnp.int32)
+        return jax.lax.bitcast_convert_type(signed + 1, jnp.uint32)
+
+    assert axes(round_trip) == ([0], [])
+    out, fallbacks = axes(lambda word: jax.lax.bitcast_convert_type(word, jnp.uint8))
+    assert out == [dataflow._MIXED]
+    assert fallbacks == ["bitcast_convert_type"]
 
 
 def test_donated_while_carry_reuse_keeps_slots_apart():
@@ -231,5 +183,5 @@ def test_corpus_mode_reports_a_broken_probe_as_a_finding(tmp_path):
         "DATAFLOW_AUDIT_PROGRAMS = {}\nraise RuntimeError('boom')\n"
     )
     findings = staticcheck.check_dataflow(probe)
-    assert [f.check for f in findings] == ["dataflow-lock-drift"]
+    assert [f.check for f in findings] == ["dataflow-probe-error"]
     assert "failed to execute" in findings[0].message

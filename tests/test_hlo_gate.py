@@ -1,26 +1,32 @@
 """The compiled-program conformance gate (analysis family 12-13 pins).
 
-What must hold, per ISSUE 8's acceptance criteria:
+What holds of the LIVE compiled programs, one case an entrypoint, every
+assertion over the session-cached ``collect_facts()`` (one collection pays
+for all of them, and for the tree sweeps in test_lint.py /
+test_staticcheck.py); nothing is compared with a committed number:
 
-- ``staticcheck --update-hlo-lock`` on a clean tree is a byte-identical
-  round trip against the committed ``tools/analysis/hlo.lock.json``;
-- an injected hot-loop all-gather in a corpus-compiled program fails the
-  gate naming the entrypoint, the location class, and the payload delta;
-- every registered engine entrypoint's ``donate_argnums`` buffers are
-  verified aliased in the compiled output (or carry an explicit waiver),
-  on the forced 8-device CPU mesh — no TPU required;
-- the payload accounting never guesses an unknown dtype;
+- the registry is the thirteen names, compiled once a session inside its
+  budget;
+- every ``donate_argnums`` leaf is aliased in the compiled output (or
+  carries an explicit waiver), on the forced 8-device CPU mesh;
+- the one-device programs hold no collective and no host transfer;
+- ``hot-loop`` means, in every registered program, the loop around the
+  round's own ``fd_tick`` scope, at the nest pinned here an entrypoint;
+- the mesh programs hold collectives; a wave's ROUND loop carries, outside
+  a conditional, all-reduces of scalar / [n] class (and on the 2-D mesh the
+  scalar-class re-layout of the [c] tally); the mask build's [c, n]-class
+  gather is per-convergence work one loop level up; the fleet pair holds
+  zero cross-tenant collectives;
+- the compact and the wide state agree, and trace-on equals trace-off;
+- an injected hot-loop all-gather or a dropped donation in a
+  corpus-compiled program fails naming the entrypoint, the location class
+  and the payload delta; the payload accounting never guesses a dtype;
 - each registered entrypoint recalled with fresh same-shape inputs does
   NOT recompile (the executable check behind ``retrace-hazard``).
-
-The entrypoint compiles are collected once per process
-(``collect_facts``'s session cache) and shared with the tree sweeps in
-test_lint.py / test_staticcheck.py.
 """
 
 from __future__ import annotations
 
-import json
 import sys
 from pathlib import Path
 
@@ -33,6 +39,29 @@ import staticcheck  # noqa: E402
 from analysis import device_program, hlo_facts  # noqa: E402
 
 CORPUS = REPO / "tests" / "data" / "lint_corpus"
+
+#: ``device_program._build_registry()``, by name: the parametrised cases
+#: below are collected before anything is compiled.
+ONE_DEVICE = (
+    "step", "run_to_decision", "run_until_membership", "sync",
+    "step_compact", "step_telem", "step_trace",
+)
+MESH = (
+    "sharded_step", "sharded_step_telem", "sharded_wave", "sharded2d_wave",
+    "fleet3d_step", "fleet3d_wave",
+)
+REGISTRY = ONE_DEVICE + MESH
+
+#: The loop nest around each program's round, below its ``jit(...)`` name
+#: (``facts[name]["round_loop"]``): what "hot-loop" means there. The rest
+#: run one round outside any loop, or none (``sync``).
+ROUND_LOOP = {
+    "run_to_decision": "while/body",
+    "run_until_membership": "while/body/while/body",
+    "sharded_wave": "while/body/while/body",
+    "sharded2d_wave": "while/body/while/body",
+    "fleet3d_wave": "vmap()/while/body",
+}
 
 
 # ---------------------------------------------------------------------------
@@ -79,21 +108,25 @@ def test_unknown_dtype_surfaces_as_a_finding():
 
 
 # ---------------------------------------------------------------------------
-# The committed lock: clean gate + byte-identical regeneration
+# The live registry: one collection, one case an entrypoint
 # ---------------------------------------------------------------------------
 
 
-def test_registered_entrypoints_audit_clean_against_committed_lock():
-    # The real gate over the real engine on the forced 8-device CPU mesh —
-    # and the session cache is real (every later sweep reuses this compile
-    # round). When THIS call is the session's first collection (it is, in
-    # both tier-1 and check.sh ordering), it pays the fresh backend
-    # compiles — budget them here, where the cost is guaranteed to be
-    # real (test_lint's sweep budget would otherwise measure a cache hit).
-    # 90 s since the tenant-fleet pair joined the registry (nine
-    # entrypoints; two- and three-axis GSPMD partitioning costs real
-    # compile time — the compile-inclusive budget may grow, the
-    # analysis-only sweep budget in test_lint.py must not).
+def _round_loop(collectives):
+    """The unconditional collectives of a wave's round loop."""
+    return {k: v for k, v in collectives.items() if k.startswith("hot-loop/")}
+
+
+def test_the_registry_is_the_thirteen_names_compiled_once_inside_its_budget(
+    record_property,
+):
+    # When THIS call is the session's first collection (it is, in both
+    # tier-1 and check.sh ordering), it pays the fresh backend compiles —
+    # budget them here, where the cost is guaranteed to be real (test_lint's
+    # sweep budget would otherwise measure a cache hit). 90 s since the
+    # tenant-fleet pair joined the registry (two- and three-axis GSPMD
+    # partitioning costs real compile time — the compile-inclusive budget
+    # may grow, the analysis-only sweep budget in test_lint.py must not).
     import time
 
     fresh = device_program._FACTS_CACHE is None
@@ -101,62 +134,192 @@ def test_registered_entrypoints_audit_clean_against_committed_lock():
     facts = staticcheck.collect_facts()
     elapsed = time.process_time() - started
     if fresh:
+        record_property("fresh_compile_cpu_s", round(elapsed, 2))
         assert elapsed < 90.0, (
             f"fresh entrypoint compile collection used {elapsed:.1f}s CPU "
             f"(budget 90s)"
         )
-    assert set(facts) == {
-        "step", "run_to_decision", "run_until_membership", "sync",
-        "step_compact", "step_telem", "step_trace",
-        "sharded_step", "sharded_step_telem", "sharded_wave",
-        "sharded2d_wave",
-        "fleet3d_step", "fleet3d_wave",
-    }
+    assert set(facts) == set(REGISTRY)
     trees = [(None, rel) for rel in device_program.REGISTRY_SOURCES]
-    assert device_program.check_hlo_lock(trees) == []
+    assert device_program.check_compiled_programs(trees) == []
     assert staticcheck.collect_facts() is facts  # cached, not recompiled
 
 
-def test_sharded_entrypoints_have_collectives_single_device_do_not():
+@pytest.mark.parametrize("name", ONE_DEVICE)
+def test_a_one_device_program_holds_no_collective_and_no_transfer(name):
+    entry = staticcheck.collect_facts()[name]
+    assert entry["collectives"] == {}
+    assert entry["transfers"] == {}
+
+
+@pytest.mark.parametrize("name", REGISTRY)
+def test_hot_loop_means_the_loop_around_the_rounds_own_scope(name):
+    # The classifier anchors "hot-loop" on the loop around ``fd_tick``, not
+    # on a depth: a loop that later nests in a round, or beside one under a
+    # wave-level branch, cannot move the name to another loop unseen.
+    found = staticcheck.collect_facts()[name]["round_loop"]
+    nest = found.split("/", 1)[1] if found else None
+    assert nest == ROUND_LOOP.get(name)
+
+
+@pytest.mark.parametrize("name", MESH)
+def test_a_mesh_program_keeps_its_round_loop_reduce_class(name):
+    """A mesh program holds collectives and no host transfer. In a wave,
+    the ROUND loop (the one around ``fd_tick``) outside a conditional
+    carries all-reduces of scalar / [n] class, and on the 2-D mesh the
+    scalar-class re-layout of the [c]-sized tally across the cohort axis;
+    [c, n]-scale traffic is cond-gated, or it is the mask build at the head
+    of each convergence, one loop level up (``wave-loop``, since PR 34).
+    The fleet pair holds zero cross-tenant collectives: its step is
+    straight-line (all prologue), its wave rides the vmapped loop and must
+    be classified there (a vmap(while) scope must never pass as
+    prologue)."""
+    entry = staticcheck.collect_facts()[name]
+    collectives = entry["collectives"]
+    assert collectives
+    assert entry["transfers"] == {}
+    locations = {key.split("/")[0] for key in collectives}
+    if name in ("sharded_wave", "sharded2d_wave"):
+        round_loop = _round_loop(collectives)
+        assert "hot-loop/all-reduce" in round_loop
+        for key, group in round_loop.items():
+            if key == "hot-loop/all-reduce":
+                assert group["class"] in ("scalar", "n"), (key, group)
+            else:
+                assert name == "sharded2d_wave", (key, group)
+                assert group["class"] == "scalar", (key, group)
+        assert collectives["wave-loop/all-gather"]["class"] in ("n", "cn")
+        assert locations <= {
+            "hot-loop", "hot-loop-cond", "wave-loop", "wave-loop-cond",
+        }
+    elif name == "fleet3d_wave":
+        assert locations == {"hot-loop"}
+    else:
+        assert locations <= {"prologue", "cond"}
+    if name.startswith("fleet3d"):
+        assert entry["cross_tenant_collectives"] == 0
+
+
+def test_2d_wave_round_loop_adds_only_scalar_kinds_to_the_live_1d_waves():
+    """ISSUE 9 acceptance, against the LIVE 1-D wave: meshing the cohort
+    axis must not smuggle new [n]-or-larger unconditional traffic into the
+    round loop. What the 2-D round loop holds beyond the 1-D one's kinds
+    (all-reduce alone) is scalar class: two all-to-alls and two all-gathers
+    of the [c]-sized tally, 266 bytes between them at the audit shape."""
     facts = staticcheck.collect_facts()
-    for name in ("sharded_step", "sharded_step_telem", "sharded_wave",
-                 "sharded2d_wave", "fleet3d_step", "fleet3d_wave"):
-        assert facts[name]["collectives"], name
-    for name in ("step", "run_to_decision", "run_until_membership", "sync",
-                 "step_compact", "step_telem", "step_trace"):
-        assert facts[name]["collectives"] == {}, name
-    # Both waves' unconditional hot loops stay reduce-class at scalar/[n]
-    # payloads; [c,n]-scale traffic is cond-gated — the parallel/audit
-    # invariant, now lockfile-frozen for the 1-D AND the 2-D mesh.
-    for name in ("sharded_wave", "sharded2d_wave"):
-        for key, entry in facts[name]["collectives"].items():
-            if key.startswith("hot-loop/"):
-                assert entry["class"] in ("scalar", "n"), (name, key, entry)
-                assert key == "hot-loop/all-reduce", (name, key, entry)
+    one_d = _round_loop(facts["sharded_wave"]["collectives"])
+    two_d = _round_loop(facts["sharded2d_wave"]["collectives"])
+    assert set(one_d) == {"hot-loop/all-reduce"}
+    assert set(one_d) <= set(two_d)
+    for key in set(two_d) - set(one_d):
+        assert two_d[key]["class"] == "scalar", (key, two_d[key])
 
 
-def test_2d_wave_hot_loop_adds_no_new_collectives_vs_1d_baseline():
-    """ISSUE 9 acceptance: on the forced 8-device mesh the 2-D
-    ('cohort','nodes') wave compiles with every donated leaf aliased and
-    NO hot-loop collective kind the 1-D baseline lock does not already
-    carry — meshing the cohort axis must not smuggle new unconditional
-    traffic into the convergence hot loop."""
-    facts = staticcheck.collect_facts()
-    baseline = json.loads((REPO / staticcheck.HLO_LOCK_REL).read_text())
-    locked_1d = baseline["entrypoints"]["sharded_wave"]["collectives"]
+@pytest.mark.parametrize("name", REGISTRY)
+def test_every_donated_leaf_is_aliased_or_waived(name):
+    # Every donate_argnums declaration is verified against the compiled
+    # artifact; on this backend all of them land.
+    donation = staticcheck.collect_facts()[name]["donation"]
+    assert donation["dropped"] == 0 or donation.get("waiver"), donation
+    if name != "sync":
+        assert donation["aliased"] == donation["donated_leaves"] > 0
 
-    def hot_kinds(colls):
-        return {k for k in colls if k.startswith("hot-loop/")}
 
-    donation = facts["sharded2d_wave"]["donation"]
-    assert donation["dropped"] == 0
-    assert donation["aliased"] == donation["donated_leaves"] > 0
-    assert hot_kinds(facts["sharded2d_wave"]["collectives"]) <= hot_kinds(
-        locked_1d
-    ), (
-        facts["sharded2d_wave"]["collectives"],
-        locked_1d,
+@pytest.mark.parametrize("differential", ["compaction", "trace"])
+def test_a_differential_drive_is_bit_identical(differential):
+    # wide == widened compact, and trace-on == trace-off, leaf for leaf,
+    # on a crash+join scenario: None, or the first divergent lane.
+    check = {
+        "compaction": device_program.compaction_differential_ok,
+        "trace": device_program.trace_differential_ok,
+    }[differential]
+    assert check() is None
+
+
+def test_the_tree_gate_reports_live_defects_without_a_committed_number(
+    monkeypatch,
+):
+    # The sweep's bite: a host transfer, an unwaived dropped donation, a
+    # cross-tenant collective and an unknown dtype in a registered program
+    # are findings whatever the program was yesterday.
+    clean = {
+        "collectives": {}, "transfers": {}, "memory": {}, "unknown_dtypes": [],
+        "donation": {"donated_leaves": 2, "aliased": 2, "dropped": 0},
+    }
+    sick = {
+        "collectives": {}, "transfers": {"outfeed": 1}, "memory": {},
+        "donation": {"donated_leaves": 2, "aliased": 1, "dropped": 1},
+        "unknown_dtypes": ["q7"], "cross_tenant_collectives": 3,
+    }
+    waived = dict(clean, donation={
+        "donated_leaves": 1, "aliased": 0, "dropped": 1, "waiver": "scalar",
+    })
+    monkeypatch.setattr(
+        device_program, "collect_facts",
+        lambda: {"clean": clean, "sick": sick, "waived": waived},
     )
+    trees = [(None, rel) for rel in device_program.REGISTRY_SOURCES]
+    findings = device_program.check_compiled_programs(trees)
+    assert sorted(f.check for f in findings) == [
+        "hlo-cross-tenant-collective", "hlo-donation-dropped",
+        "hlo-transfer-budget", "hlo-unknown-dtype",
+    ]
+    assert all(f.message.startswith("sick: ") for f in findings)
+    # Retargeted trees (no engine sources in the sweep) never pay a compile.
+    assert device_program.check_compiled_programs([(None, "other.py")]) == []
+
+
+def test_the_classifier_tells_the_round_loop_from_the_wave_loop():
+    """A wave nests the round loop in the per-convergence loop. Given the
+    round loop's body, ``hot-loop`` is that loop with whatever nests in it,
+    and every other loop level reads ``wave-loop``; a loop predicate is its
+    loop's level, never a gated branch; without a round loop every loop
+    level is hot."""
+    classify = hlo_facts.classify_location
+    wave, rounds = "jit(p)/while/body", "jit(p)/while/body/while/body"
+    assert classify(rounds + "/fd_tick/reduce_or", rounds) == "hot-loop"
+    assert classify(rounds + "/cond/branch_1_fun/classic/gather", rounds) == (
+        "hot-loop-cond"
+    )
+    assert classify(wave + "/while/cond/lt", rounds) == "hot-loop"
+    assert classify(rounds + "/tally/while/body/add", rounds) == "hot-loop"
+    assert classify(wave + "/edge_masks/gather", rounds) == "wave-loop"
+    assert classify(wave + "/cond/branch_1_fun/view_change/gather", rounds) == (
+        "wave-loop-cond"
+    )
+    # As deep as the round loop, and not it: a loop under a wave-level branch.
+    assert classify(
+        wave + "/cond/branch_1_fun/view_change/while/body/add", rounds
+    ) == "wave-loop-cond"
+    assert classify("jit(p)/while/cond/lt", rounds) == "wave-loop"
+    assert classify(wave + "/edge_masks/gather", wave) == "hot-loop"
+    assert classify(wave + "/edge_masks/gather") == "hot-loop"
+    fleet = "jit(f)/vmap(while)/body"
+    assert classify(fleet + "/tally/reduce_sum", fleet) == "hot-loop"
+    assert classify("jit(f)/vmap(while)/cond/lt", fleet) == "hot-loop"
+    assert classify("jit(p)/cond/branch_1_fun/classic/gather", rounds) == "cond"
+    assert classify("jit(p)/edge_masks/gather", rounds) == "prologue"
+
+    def module(*op_names):
+        return "HloModule m\n" + "".join(
+            f'  %v{i} = pred[] all-reduce(%x), metadata={{op_name="{name}"}}\n'
+            for i, name in enumerate(op_names)
+        )
+
+    text = module(
+        wave + "/edge_masks/gather", rounds + "/fd_tick/reduce_or",
+        rounds + "/tally/while/body/reduce_sum",
+    )
+    assert hlo_facts.round_loop(text) == rounds  # not the deepest loop
+    assert [r["location"] for r in hlo_facts.audit_collectives(text, 256, 8)] == [
+        "wave-loop", "hot-loop", "hot-loop",
+    ]
+    assert hlo_facts.round_loop(module("jit(f)/vmap(fd_tick)/add")) is None
+    assert hlo_facts.round_loop(module(wave + "/add")) is None
+    with pytest.raises(ValueError, match="rounds under more than one loop"):
+        hlo_facts.round_loop(
+            module(wave + "/fd_tick/add", rounds + "/fd_tick/add")
+        )
 
 
 def test_2d_cohort_state_memory_is_sharded_not_replicated():
@@ -241,15 +404,13 @@ def test_compact_entrypoints_shrink_argument_bytes():
     """ISSUE 13 acceptance, from the compiled artifact: the compact-policy
     step carries >= 30% fewer per-device argument bytes than the wide
     oracle at the audit shape (the wave's argument surface is
-    byte-identical modulo three int32 control scalars — registering the
-    step freezes the claim for both, the PR-9 single-representative
-    convention), its entry signature actually carries the narrow dtypes
+    byte-identical modulo three int32 control scalars — the registered
+    step stands for both, the PR-9 single-representative convention), its entry signature actually carries the narrow dtypes
     (s16/s8/u8 — the policy landed, not just the formula), donation stays
     fully aliased, and its hot-loop collective and transfer budgets match
     the wide twin's (empty/none on the single-device audit programs —
     compaction adds no communication)."""
     facts = staticcheck.collect_facts()
-    locked = json.loads((REPO / staticcheck.HLO_LOCK_REL).read_text())
     for wide_name, compact_name in (
         ("step", "step_compact"),
     ):
@@ -258,9 +419,6 @@ def test_compact_entrypoints_shrink_argument_bytes():
         assert compact_args <= 0.7 * wide_args, (
             wide_name, wide_args, compact_args,
         )
-        assert locked["entrypoints"][compact_name]["memory"][
-            "argument_bytes"
-        ] == compact_args
         dtypes = facts[compact_name]["parameter_dtype_bytes"]
         assert {"s16", "s8", "u8"} <= set(dtypes), dtypes
         wide_dtypes = facts[wide_name]["parameter_dtype_bytes"]
@@ -281,74 +439,40 @@ def test_compact_entrypoints_shrink_argument_bytes():
         assert facts[compact_name]["transfers"] == facts[wide_name]["transfers"]
 
 
-def test_compact_formula_matches_compiled_argument_bytes():
-    """The bench's bytes/member formula (models/state.state_bytes_total) is
-    the compiled artifact's own argument accounting: state+faults bytes at
-    the audit geometry equal memory_analysis()'s argument bytes minus the
-    non-state scalars (the wave carries three int32 control scalars; the
-    step none)."""
-    from rapid_tpu.models.state import EngineConfig, state_bytes_total
+@pytest.mark.parametrize("name", ["step", "step_compact", "step_telem", "step_trace"])
+def test_the_bytes_formulas_match_the_compiled_argument_bytes(name):
+    """The bench's bytes/member formula (models/state.state_bytes_total),
+    and the observers' (telemetry_bytes_total, trace_bytes_total), are the
+    compiled artifact's own argument accounting: state+faults bytes at the
+    audit geometry, plus the lanes and the ring where the entrypoint
+    carries them, equal memory_analysis()'s argument bytes (a step carries
+    no control scalars)."""
+    from rapid_tpu.models.state import (
+        EngineConfig,
+        state_bytes_total,
+        telemetry_bytes_total,
+        trace_bytes_total,
+    )
 
-    facts = staticcheck.collect_facts()
     cfg = EngineConfig(
         n=device_program.AUDIT_N, k=device_program.AUDIT_K, h=3, l=1,
         c=device_program.AUDIT_C, fd_threshold=2, delivery_spread=2,
+        compact=int(name == "step_compact"),
     )
-    for name, compact in (("step", 0), ("step_compact", 1)):
-        formula = state_bytes_total(cfg._replace(compact=compact))
-        measured = facts[name]["memory"]["argument_bytes"]
-        assert measured == formula, (name, measured, formula)
-
-
-def test_update_lock_refuses_on_compaction_differential_mismatch(monkeypatch):
-    """`--update-hlo-lock` must not freeze memory budgets while the
-    compact engine disagrees with its wide oracle: a reported mismatch
-    becomes a blocking finding and no lock is written."""
-    monkeypatch.setattr(
-        device_program, "compaction_differential_ok",
-        lambda: "wide<->compact differential disagrees on state lane 'fd_count'",
-    )
-    findings, path = device_program.update_hlo_lock()
-    assert path is None
-    assert any(
-        "wide<->compact differential" in f.message for f in findings
-    ), findings
-
-
-def test_fleet_entrypoints_have_zero_cross_tenant_collectives():
-    """ISSUE 10 acceptance: the batched step/wave compile with the tenant
-    axis FULLY parallel — no collective's replica groups span tenant device
-    blocks (cross_tenant_collectives == 0, frozen in the lock), and every
-    donated fleet buffer is aliased. The fleet wave's hot loop may carry
-    within-tenant gathers (vmap select-applies the view change — the
-    batched-serving tradeoff fleet.py documents) but never cross-tenant
-    traffic of ANY class."""
-    facts = staticcheck.collect_facts()
-    locked = json.loads((REPO / staticcheck.HLO_LOCK_REL).read_text())
-    for name in ("fleet3d_step", "fleet3d_wave"):
-        assert facts[name]["cross_tenant_collectives"] == 0, name
-        assert locked["entrypoints"][name]["cross_tenant_collectives"] == 0
-        donation = facts[name]["donation"]
-        assert donation["dropped"] == 0
-        assert donation["aliased"] == donation["donated_leaves"] > 0
-    # The step is straight-line (no loop): all its collectives are
-    # prologue-class; the wave's ride the vmapped hot loop and must be
-    # classified there (a vmap(while) scope must never pass as prologue).
-    assert all(
-        key.startswith("prologue/")
-        for key in facts["fleet3d_step"]["collectives"]
-    )
-    assert facts["fleet3d_wave"]["collectives"]
-    assert all(
-        key.startswith("hot-loop")
-        for key in facts["fleet3d_wave"]["collectives"]
-    )
+    formula = state_bytes_total(cfg)
+    if name in ("step_telem", "step_trace"):
+        formula += telemetry_bytes_total(cfg)
+    if name == "step_trace":
+        formula += trace_bytes_total(
+            cfg._replace(trace=device_program.AUDIT_TRACE_R)
+        )
+    measured = staticcheck.collect_facts()[name]["memory"]["argument_bytes"]
+    assert measured == formula
 
 
 def test_cross_tenant_collective_is_a_blocking_finding():
     """A fleet program with a tenant-spanning collective must fail the gate
-    with its own check name — and can never be frozen (update refuses it,
-    the dropped-donation discipline)."""
+    with its own check name."""
     entry = {
         "collectives": {}, "transfers": {}, "memory": {},
         "donation": {"donated_leaves": 0, "aliased": 0, "dropped": 0},
@@ -360,7 +484,7 @@ def test_cross_tenant_collective_is_a_blocking_finding():
     assert [f.check for f in findings] == ["hlo-cross-tenant-collective"]
     assert "2 collective(s)" in findings[0].message
     assert "never communicate" in findings[0].message
-    # Zero-vs-locked drift (a lock claiming nonzero) is ordinary drift.
+    # Zero-vs-claimed drift (an inline claim of nonzero) is ordinary drift.
     entry["cross_tenant_collectives"] = 0
     findings = device_program.compare_facts(
         "fleet3d_step", entry, {"cross_tenant_collectives": 1}, ("hlo.lock", 1)
@@ -401,55 +525,6 @@ def test_replica_group_parsing_covers_all_hlo_spellings():
     assert hlo_facts.groups_cross_blocks(None, block)  # all-participants
 
 
-def test_every_donation_is_aliased_or_waived():
-    # Acceptance: every donate_argnums declaration is verified against the
-    # compiled artifact; on this backend all of them land.
-    facts = staticcheck.collect_facts()
-    for name, entry in facts.items():
-        donation = entry["donation"]
-        assert donation["dropped"] == 0 or donation.get("waiver"), (
-            name, donation,
-        )
-        if name != "sync":
-            assert donation["aliased"] == donation["donated_leaves"] > 0, name
-
-
-def test_update_hlo_lock_is_a_byte_identical_round_trip(
-    tmp_path, monkeypatch, capsys
-):
-    # Same contract as the wire lock: regenerating over an unchanged tree
-    # reproduces the committed file byte for byte. Redirected target so a
-    # real divergence is caught, not silently overwritten.
-    committed = (REPO / staticcheck.HLO_LOCK_REL).read_text()
-    target = tmp_path / "hlo.lock.json"
-    monkeypatch.setattr(device_program, "HLO_LOCK_REL", str(target))
-    rc = staticcheck.main(["--update-hlo-lock"])
-    assert rc == 0
-    assert "wrote" in capsys.readouterr().out
-    assert target.read_text() == committed
-
-
-def test_tampered_lock_fails_the_gate_naming_the_delta(tmp_path, monkeypatch):
-    # Drop the sharded wave's hot-loop all-reduce budget from a copy of the
-    # lock: the live compiled program now exceeds it, and the finding names
-    # the entrypoint, the location, and the payload delta.
-    locked = json.loads((REPO / staticcheck.HLO_LOCK_REL).read_text())
-    removed = locked["entrypoints"]["sharded_wave"]["collectives"].pop(
-        "hot-loop/all-reduce"
-    )
-    target = tmp_path / "hlo.lock.json"
-    target.write_text(json.dumps(locked))
-    monkeypatch.setattr(device_program, "HLO_LOCK_REL", str(target))
-    trees = [(None, rel) for rel in device_program.REGISTRY_SOURCES]
-    findings = device_program.check_hlo_lock(trees)
-    assert len(findings) == 1
-    f = findings[0]
-    assert f.check == "hlo-collective-budget"
-    assert "sharded_wave" in f.message
-    assert "HOT-LOOP" in f.message and "all-reduce" in f.message
-    assert f"{removed['bytes']} bytes" in f.message
-
-
 # ---------------------------------------------------------------------------
 # The injected-defect acceptance case (corpus-compiled)
 # ---------------------------------------------------------------------------
@@ -467,7 +542,7 @@ def test_injected_hot_loop_all_gather_fails_with_entrypoint_and_delta():
     assert "HOT-LOOP" in f.message and "hot-loop" in f.message  # location
     assert "all-gather" in f.message
     assert "256 bytes" in f.message and "class n" in f.message  # the delta
-    assert "--update-hlo-lock" in f.message
+    assert "inline HLO_LOCK" in f.message
 
 
 def test_dropped_donation_reports_xla_reason():

@@ -483,136 +483,6 @@ def test_trajectory_renders_trace_column_and_flags_missing(tmp_path, capsys):
     assert "trace-missing" not in lines["BENCH_r80"]  # pre-audit history
 
 
-def test_trajectory_renders_costfit_column_and_flags_missing(
-    tmp_path, capsys
-):
-    """ISSUE 18: the scaling-law cost model renders as the COSTFIT
-    trajectory column (the WORST fitted class across the round's audited
-    entrypoints, quiescent collective payload beside it) under the same
-    trust discipline as the other axes: an AUDITED round that omits both
-    the ``cost_fit`` table and its explicit status marker flags
-    cost-missing; pre-audit historical rounds are exempt."""
-    audit = {"step": {"collectives": 0, "hot_loop_collectives": 0,
-                      "temp_bytes": 10, "donation_dropped": 0}}
-    base = {"n1M_status": "ramped:256", "tenant_fleet_status": "ramped:8x64",
-            "stream_status": "ramped:12x96", "chaos_status": "ramped:12x12",
-            "mem_status": "computed:cpu", "recovery_status": "skipped-budget",
-            "activity_status": "skipped-budget",
-            "trace_status": "skipped-budget"}
-    points = {
-        # Pre-audit historical round: exempt (sorts first).
-        "BENCH_r90.json": {"metric": "m", "value": 1.0, "platform": "cpu"},
-        # Audited + fitted table: the worst class (here the step's O(N*K)
-        # dominates the sync's O(N)) + quiescent payload in the column.
-        "BENCH_r91.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base,
-                           "cost_fit": {
-                               "step": {"argument_bytes": "O(N*K)",
-                                        "temp_bytes": "O(N)"},
-                               "sync": {"argument_bytes": "O(N)"},
-                           },
-                           "quiescent_round_cost": {
-                               "entrypoint": "sharded_step",
-                               "collective_payload_bytes": 53218,
-                               "hot_loop_payload_bytes": 0,
-                           }},
-        # Audited + explicit suppressed marker (smoke run): status cell,
-        # no flag.
-        "BENCH_r92.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base,
-                           "cost_fit": {"status":
-                                        "suppressed:RAPID_TPU_BENCH_"
-                                        "COST_LADDER=0"}},
-        # Audited round that silently dropped the cost axis: flagged.
-        "BENCH_r93.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base},
-    }
-    paths = []
-    for name, data in points.items():
-        p = tmp_path / name
-        p.write_text(json.dumps(data))
-        paths.append(str(p))
-    assert perfview.main(paths) == 0
-    out = capsys.readouterr().out
-    assert "COSTFIT" in out.splitlines()[1]  # the trajectory header row
-    lines = {line.split()[0]: line for line in out.splitlines()
-             if line.startswith("BENCH_r9")}
-    assert "worst=O(N*K) q=53218B" in lines["BENCH_r91"]
-    assert "cost-missing" not in lines["BENCH_r91"]
-    assert "suppressed:RAPID_TPU_BENCH_COST_LADDER=0" in lines["BENCH_r92"]
-    assert "cost-missing" not in lines["BENCH_r92"]
-    assert "cost-missing" in lines["BENCH_r93"]
-    assert "cost-missing" not in lines["BENCH_r90"]  # pre-audit history
-
-
-def test_trajectory_renders_oppty_column_and_flags_missing(
-    tmp_path, capsys
-):
-    """ISSUE 19: the jaxpr dataflow provenance axis renders as the OPPTY
-    trajectory column (opportunity-map coverage of the quiescent payload
-    bytes + the proof verdicts) under the same trust discipline as the
-    other axes: an AUDITED round that omits the ``dataflow`` block flags
-    dataflow-missing; pre-provenance historical rounds are exempt."""
-    audit = {"step": {"collectives": 0, "hot_loop_collectives": 0,
-                      "temp_bytes": 10, "donation_dropped": 0}}
-    base = {"n1M_status": "ramped:256", "tenant_fleet_status": "ramped:8x64",
-            "stream_status": "ramped:12x96", "chaos_status": "ramped:12x12",
-            "mem_status": "computed:cpu", "recovery_status": "skipped-budget",
-            "activity_status": "skipped-budget",
-            "trace_status": "skipped-budget",
-            "cost_fit": {"status": "suppressed:RAPID_TPU_BENCH_"
-                                   "COST_LADDER=0"}}
-    points = {
-        # Pre-provenance historical round: exempt (sorts first).
-        "BENCH_r95.json": {"metric": "m", "value": 1.0, "platform": "cpu"},
-        # Audited + proofs + coverage: both render in the column.
-        "BENCH_r96.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base,
-                           "dataflow": {
-                               "status": "ok",
-                               "observer_silent": True,
-                               "tenant_isolated": True,
-                               "opportunity_coverage_pct": 99.69,
-                           }},
-        # Audited + explicit suppressed marker (smoke run): status cell,
-        # no flag.
-        "BENCH_r97.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base,
-                           "dataflow": {"status":
-                                        "suppressed:RAPID_TPU_BENCH_"
-                                        "DATAFLOW=0"}},
-        # Audited round that silently dropped the provenance axis: flagged.
-        "BENCH_r98.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base},
-        # A failed proof must be visible at a glance, never "ok".
-        "BENCH_r99.json": {"metric": "m", "value": 1.0, "platform": "cpu",
-                           "hlo_audit": audit, **base,
-                           "dataflow": {
-                               "status": "findings:1",
-                               "observer_silent": False,
-                               "tenant_isolated": True,
-                               "opportunity_coverage_pct": 95.0,
-                           }},
-    }
-    paths = []
-    for name, data in points.items():
-        p = tmp_path / name
-        p.write_text(json.dumps(data))
-        paths.append(str(p))
-    assert perfview.main(paths) == 0
-    out = capsys.readouterr().out
-    assert "OPPTY" in out.splitlines()[1]  # the trajectory header row
-    lines = {line.split()[0]: line for line in out.splitlines()
-             if line.startswith("BENCH_r9")}
-    assert "100%/ok" in lines["BENCH_r96"]
-    assert "dataflow-missing" not in lines["BENCH_r96"]
-    assert "suppressed:RAPID_TPU_BENCH_DATAFLOW=0" in lines["BENCH_r97"]
-    assert "dataflow-missing" not in lines["BENCH_r97"]
-    assert "dataflow-missing" in lines["BENCH_r98"]
-    assert "dataflow-missing" not in lines["BENCH_r95"]  # pre-provenance
-    assert "95%/LEAK" in lines["BENCH_r99"]
-
-
 def test_chrome_trace_envelope(tmp_path, capsys):
     path = _complete_ledger(tmp_path)
     chrome_path = tmp_path / "trace.json"

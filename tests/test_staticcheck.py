@@ -272,11 +272,29 @@ def test_dead_definition_liveness_channels(tmp_path):
     assert _dead_defs(tmp_path) == []
 
 
+def test_an_autouse_fixture_is_live_and_a_plain_unused_one_is_not(tmp_path):
+    # pytest applies an autouse fixture to every test of its scope: no
+    # test names it, and it is no dead definition for that.
+    (tmp_path / "mod.py").write_text(textwrap.dedent(
+        '''
+        import pytest
+        from pytest import fixture
+        @pytest.fixture(scope="module", autouse=True)
+        def release_maps(): yield
+        @fixture(autouse=True)
+        def also_live(): yield
+        @pytest.fixture(autouse=False)
+        def named_by_nobody(): yield
+        '''
+    ))
+    assert sorted(f.message for f in _dead_defs(tmp_path)) == [
+        "module-level 'named_by_nobody' is referenced nowhere in the tree",
+    ]
+
+
 def test_dead_definition_sees_getattr_and_fstring_references(tmp_path):
     # ISSUE 19 regression: a definition consumed only via
-    # getattr(obj, "name") or named inside an f-string fragment is live —
-    # the dataflow family's dead-lane check proves such lanes reachable,
-    # and the two families must never disagree on liveness.
+    # getattr(obj, "name") or named inside an f-string fragment is live.
     (tmp_path / "mod.py").write_text(textwrap.dedent(
         '''
         def fd_hist_decode(): return 1
@@ -303,28 +321,33 @@ def test_narrowed_roots_skip_liveness(tmp_path, monkeypatch):
     assert findings == []
 
 
-def test_whole_tree_is_finding_free():
+def test_whole_tree_is_finding_free(record_property):
     # The gate itself: resolution-tier findings fail the build exactly the
-    # way error-prone fails the reference's. All seventeen check families
-    # run — including the compiled-program gate (device_program), the
-    # ISSUE-18 cost-model ladder (cost_model), and the ISSUE-19 jaxpr
-    # provenance gate (dataflow), whose entrypoint compiles/traces are
-    # collected ONCE per process; pre-warm the session caches here so
+    # way error-prone fails the reference's. All sixteen check families
+    # run — including the compiled-program gate (device_program) and the
+    # jaxpr provenance gate (dataflow), whose entrypoint compiles/traces
+    # are collected ONCE per process; pre-warm the session caches here so
     # this budget pins the ANALYSIS cost, not the compile cost
     # (tests/test_lint.py budgets the compile-inclusive sweep
-    # separately). Process CPU time, not wall-clock: a loaded CI machine
-    # must not fail the gate — only an analyzer going superlinear.
+    # separately). Process CPU time, not wall-clock, and still a number
+    # that the machine moves: the same sweep reads 6-7 s in an idle
+    # process, 10 s as a pytest session's first (it imports every module of
+    # the tree, the test files through pytest's rewriting hook) and
+    # 12.6-15.4 s under tier-1's six workers (PR 47's readings: CHANGES).
+    # The budget is three times the highest of them, so that it fails on
+    # an analyzer going superlinear and not on a loaded machine; the
+    # reading goes to the junit XML (`sweep_cpu_s`) on every run.
     import time
 
     staticcheck.collect_facts()  # session-shared; test_hlo_gate.py pins it
-    staticcheck.collect_ladder()  # session-shared; test_lint.py pins it
     staticcheck.collect_dataflow()  # session-shared; test_dataflow.py pins it
     started = time.process_time()
     findings = staticcheck.run()
     elapsed = time.process_time() - started
+    record_property("sweep_cpu_s", round(elapsed, 2))
     assert not findings, "\n".join(str(f) for f in findings)
-    assert elapsed < 15.0, (
-        f"seventeen-family tree sweep used {elapsed:.1f}s CPU (budget 15s)"
+    assert elapsed < 45.0, (
+        f"sixteen-family tree sweep used {elapsed:.1f}s CPU (budget 45s)"
     )
 
 
@@ -413,16 +436,9 @@ _CORPUS_CHECKERS = {
     # the decoded host-side summaries stay free.
     "trace_unmarked_fetch.py": ("rapid_tpu/serving/_corpus.py", "check_telemetry"),
     "clean_trace_fetch.py": ("rapid_tpu/serving/_corpus.py", "check_telemetry"),
-    # ISSUE 18: the cost-model corpus COMPILES its miniature programs
-    # across the module's inline COST_LADDER and fits each audited fact to
-    # a scaling class — the O(N^2) defect trio (regression past the lock,
-    # ceiling breach, dtype-step refusal) against the linear clean twin.
-    "cost_scaling_regression.py": ("rapid_tpu/models/_corpus.py", "check_cost_model"),
-    "clean_cost_model.py": ("rapid_tpu/models/_corpus.py", "check_cost_model"),
     # ISSUE 19: the dataflow corpus TRACES its miniature programs (no
     # compile) and runs the jaxpr provenance proofs over each — observer
-    # feedback, a cross-tenant gather, and a mask-gated dense round body
-    # against the silent clean twin.
+    # feedback and a cross-tenant gather against the silent clean twin.
     "dataflow_observer_leak.py": ("rapid_tpu/models/_corpus.py", "check_dataflow"),
     "clean_dataflow.py": ("rapid_tpu/models/_corpus.py", "check_dataflow"),
 }
@@ -861,11 +877,15 @@ def test_cli_json_select_ignore_and_exit_codes(tmp_path):
 
 
 def test_cli_families_lists_all_families():
-    assert len(staticcheck.FAMILIES) == 17
+    assert len(staticcheck.FAMILIES) == 16
     result = _run_cli("--families")
     assert result.returncode == 0
     for name, _description in staticcheck.FAMILIES:
         assert name in result.stdout, name
+    assert "cost_model" not in result.stdout
+    helped = _run_cli("--help").stdout
+    # the one lockfile: a wire format peers must agree on
+    assert set(re.findall(r"--update-[a-z-]+", helped)) == {"--update-wire-lock"}
 
 
 def test_cli_update_wire_lock_is_a_deterministic_round_trip(

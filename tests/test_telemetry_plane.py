@@ -12,9 +12,7 @@ match a per-cluster drive exactly (the wave's coast-gating pin promised in
 
 Budget (the PR-10 convention): the small-grid cluster+fleet+stream
 differentials are the compile-bearing tier-1 representatives; the larger
-geometry grid rides the unfiltered check.sh pass behind ``slow``. The
-quiescent-zero pin mirrors the ``quiescent_round_activity == 0`` fact
-frozen in tools/analysis/hlo.lock.json.
+geometry grid rides the unfiltered check.sh pass behind ``slow``.
 """
 
 import numpy as np
@@ -281,8 +279,7 @@ def test_sharded_telem_wave_bit_identical_and_fleet_lanes_shard():
 
 
 def test_quiescent_soak_reads_exactly_zero_activity():
-    """The zero-churn fact frozen in the HLO lock
-    (``quiescent_round_activity == 0``): an event-free soak counts its
+    """The zero-churn fact: an event-free soak counts its
     rounds and NOTHING else — any nonzero counter here is phantom
     activity."""
     vc = _cluster(telemetry=True, seed=5)

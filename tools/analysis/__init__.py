@@ -30,8 +30,11 @@ Check families (one module each; ``core`` owns the driver/CLI/Finding):
                       STAGE_NAMES)
 12. ``device_program`` — the compiled artifact itself: every registered
                       jitted engine entrypoint compiled on a forced
-                      8-device CPU mesh, its collectives/transfers/
-                      donation/memory facts frozen in ``hlo.lock.json``
+                      8-device CPU mesh and its collectives/transfers/
+                      donation/memory facts read live (no committed
+                      number: an unknown dtype, an unwaived dropped
+                      donation, a cross-tenant collective or a host
+                      transfer is a finding)
 13. ``sharding``    — source seams that produce bad compiled programs:
                       partition-spec coverage of the engine state pytree,
                       host syncs inside traced hot paths AND anywhere in
@@ -39,10 +42,16 @@ Check families (one module each; ``core`` owns the driver/CLI/Finding):
                       blocking read there is a declared fetch boundary or
                       a finding), jit callsites that forget buffer
                       donation or invite retraces (ops/models/parallel)
+14. ``telemetry``   — the TelemetryLanes field mirror and the declared
+                      fetch boundaries of the lanes and the trace ring
+15. ``chaosvocab``  — FaultEvent kinds, scenario families and the chaosrun
+                      CLI against the registered registries
+16. ``dataflow``    — jaxpr lane provenance of the same registry's live
+                      trace: observer silence and fleet tenant isolation
 
-``staticcheck --families`` prints this catalog; ``--update-wire-lock`` /
-``--update-hlo-lock`` regenerate the lockfiles after an intentional
-schema / compiled-budget change.
+``staticcheck --families`` prints this catalog; ``--update-wire-lock``
+regenerates the one lockfile (the wire format peers must agree on) after
+an intentional schema change.
 
 Shared philosophy: conservative resolution, zero-false-positive findings,
 skip-don't-guess. Run via ``python tools/staticcheck.py`` (the compatible
@@ -55,14 +64,6 @@ from . import core
 from .chaosvocab import check_chaosvocab
 from .clocks import CLOCK_DISCIPLINE_PREFIXES, check_clock_injection
 from .concurrency import CONCURRENCY_PREFIXES, check_concurrency
-from .cost_model import (
-    COST_LOCK_REL,
-    check_cost_lock,
-    check_cost_model,
-    collect_ladder,
-    fit_scaling,
-    update_cost_lock,
-)
 from .core import (
     ALL_CHECK_NAMES,
     DEFAULT_ROOTS,
@@ -73,20 +74,16 @@ from .core import (
     run,
 )
 from .dataflow import (
-    DATAFLOW_LOCK_REL,
     check_dataflow,
-    check_dataflow_lock,
+    check_dataflow_proofs,
     collect_dataflow,
-    update_dataflow_lock,
 )
 from .deadcode import check_dead_definitions
 from .determinism import DETERMINISM_PREFIXES, check_determinism
 from .device_program import (
-    HLO_LOCK_REL,
+    check_compiled_programs,
     check_device_program,
-    check_hlo_lock,
     collect_facts,
-    update_hlo_lock,
 )
 from .dispatch import DISPATCH_PREFIXES, check_dispatch
 from .ledger import LEDGER_PREFIXES, check_ledger
@@ -118,14 +115,11 @@ __all__ = [
     "ALL_CHECK_NAMES",
     "CLOCK_DISCIPLINE_PREFIXES",
     "CONCURRENCY_PREFIXES",
-    "COST_LOCK_REL",
-    "DATAFLOW_LOCK_REL",
     "DEFAULT_ROOTS",
     "DETERMINISM_PREFIXES",
     "DISPATCH_PREFIXES",
     "FAMILIES",
     "Finding",
-    "HLO_LOCK_REL",
     "LEDGER_PREFIXES",
     "LOCK_REL",
     "SHARDING_PREFIXES",
@@ -138,16 +132,14 @@ __all__ = [
     "check_call_signatures",
     "check_chaosvocab",
     "check_clock_injection",
+    "check_compiled_programs",
     "check_concurrency",
-    "check_cost_lock",
-    "check_cost_model",
     "check_dataflow",
-    "check_dataflow_lock",
+    "check_dataflow_proofs",
     "check_dead_definitions",
     "check_determinism",
     "check_device_program",
     "check_dispatch",
-    "check_hlo_lock",
     "check_lane_mirror",
     "check_ledger",
     "check_partition_specs",
@@ -160,14 +152,9 @@ __all__ = [
     "check_wire_schema",
     "collect_dataflow",
     "collect_facts",
-    "collect_ladder",
     "core",
-    "fit_scaling",
     "iter_files",
     "main",
     "run",
-    "update_cost_lock",
-    "update_dataflow_lock",
-    "update_hlo_lock",
     "update_wire_lock",
 ]

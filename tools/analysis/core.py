@@ -85,14 +85,14 @@ ALL_CHECK_NAMES = frozenset({
     # ledger family
     "ledger-event-name",
     "ledger-stage-name",
-    # device_program family (compiled-HLO budgets vs hlo.lock.json)
+    # device_program family (the live compiled programs; the budget and
+    # drift checks compare a corpus module with its own inline HLO_LOCK)
     "hlo-collective-budget",
     "hlo-transfer-budget",
     "hlo-donation-dropped",
-    "hlo-memory-budget",
     "hlo-unknown-dtype",
+    "hlo-cross-tenant-collective",
     "hlo-lock-drift",
-    "hlo-quiescent-activity",
     # telemetry family
     "telemetry-lane-drift",
     "telemetry-unmarked-fetch",
@@ -106,18 +106,10 @@ ALL_CHECK_NAMES = frozenset({
     # chaosvocab family
     "chaos-unknown-kind",
     "chaos-family-drift",
-    # cost_model family (fitted scaling classes vs cost.lock.json)
-    "cost-unexplained",
-    "cost-scaling-regression",
-    "cost-superlinear",
-    "cost-quiescent",
-    "cost-lock-drift",
-    # dataflow family (jaxpr lane provenance vs dataflow.lock.json)
+    # dataflow family (jaxpr lane provenance of the live trace)
     "dataflow-observer-effect",
     "dataflow-cross-tenant",
-    "dataflow-dense-op",
-    "dataflow-dead-lane",
-    "dataflow-lock-drift",
+    "dataflow-probe-error",
 })
 
 #: The check families, in documentation order — one (name, description)
@@ -141,9 +133,10 @@ FAMILIES = (
                     "are pure functions of their seed"),
     ("ledger", "run-ledger vocabulary discipline: emit() events from "
                "LedgerEvent, stage() names from STAGE_NAMES"),
-    ("device_program", "compiled-HLO budgets for the registered engine "
-                       "entrypoints (collectives, transfers, donation, "
-                       "memory) frozen in hlo.lock.json"),
+    ("device_program", "the compiled HLO of the registered engine "
+                       "entrypoints, read live: no unknown dtype, no "
+                       "unwaived dropped donation, no cross-tenant "
+                       "collective, no host transfer"),
     ("telemetry", "device telemetry plane discipline: the TelemetryLanes "
                   "field set mirrored into the analyzer, and every host "
                   "fetch of the lanes annotated as a declared sync "
@@ -155,18 +148,10 @@ FAMILIES = (
     ("chaosvocab", "chaos vocabulary discipline: FaultEvent kinds, scenario "
                    "FAMILIES, fleet mix tables, and the chaosrun CLI cannot "
                    "drift from the registered registries"),
-    ("cost_model", "scaling-law cost model: every registered entrypoint's "
-                   "compiled facts fitted across N/K/tenant geometry "
-                   "ladders to O(1)/O(log N)/O(N)/O(N*K)/O(N^2) classes "
-                   "and frozen in cost.lock.json (nothing in the round "
-                   "body may exceed O(N*K))"),
     ("dataflow", "jaxpr dataflow provenance: per-lane taint over every "
                  "registered entrypoint's closed jaxpr, proving observer "
                  "silence (telemetry/trace lanes never influence engine "
-                 "lanes) and fleet tenant isolation, plus the "
-                 "sparse-opportunity map of mask-gated dense round-body "
-                 "ops priced against the quiescent payload bytes — all "
-                 "frozen in dataflow.lock.json"),
+                 "lanes) and fleet tenant isolation on the live trace"),
 )
 
 
@@ -232,9 +217,9 @@ def run(roots: Sequence[str] = DEFAULT_ROOTS) -> List[Finding]:
     # The per-file check imports live here (not module top level) so the
     # CLI shim can import this module before sys.path is fully arranged.
     from . import (
-        chaosvocab, clocks, concurrency, cost_model, dataflow, deadcode,
-        determinism, device_program, dispatch, ledger, names, sharding,
-        signatures, taskflow, telemetry, trace_safety, wire_schema,
+        chaosvocab, clocks, concurrency, dataflow, deadcode, determinism,
+        device_program, dispatch, ledger, names, sharding, signatures,
+        taskflow, telemetry, trace_safety, wire_schema,
     )
 
     per_file_checks = [
@@ -299,15 +284,10 @@ def run(roots: Sequence[str] = DEFAULT_ROOTS) -> List[Finding]:
         # device_program family's session-cached compiles).
         findings.extend(sharding.check_partition_specs(trees))
         findings.extend(telemetry.check_lane_mirror(trees))
-        findings.extend(device_program.check_hlo_lock(trees))
-        # The cost-model ladder runs right after the HLO gate so its base
-        # point rides the collect_facts session cache the gate just paid
-        # for; it presence-gates on the same engine sources.
-        findings.extend(cost_model.check_cost_lock(trees))
+        findings.extend(device_program.check_compiled_programs(trees))
         # The dataflow provenance gate traces (no compile) the same
-        # registry and prices its opportunity map off the facts the two
-        # gates above already cached; same presence gate, same session.
-        findings.extend(dataflow.check_dataflow_lock(trees))
+        # registry; same presence gate, same session.
+        findings.extend(dataflow.check_dataflow_proofs(trees))
     return findings
 
 
@@ -341,27 +321,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                         help="regenerate tools/analysis/wire.lock.json from "
                              "the live schema mirrors (refuses while the "
                              "mirrors disagree with each other)")
-    parser.add_argument("--update-hlo-lock", action="store_true",
-                        dest="update_hlo_lock",
-                        help="recompile the registered engine entrypoints "
-                             "and regenerate tools/analysis/hlo.lock.json "
-                             "(refuses while an unknown dtype or an "
-                             "unwaived dropped donation is present)")
-    parser.add_argument("--update-cost-lock", action="store_true",
-                        dest="update_cost_lock",
-                        help="refit the geometry ladders and regenerate "
-                             "tools/analysis/cost.lock.json (refuses while "
-                             "any fit is unexplained, any fact exceeds its "
-                             "ceiling, or the hlo.lock differentials "
-                             "disagree)")
-    parser.add_argument("--update-dataflow-lock", action="store_true",
-                        dest="update_dataflow_lock",
-                        help="retrace the registered entrypoints and "
-                             "regenerate tools/analysis/dataflow.lock.json "
-                             "(refuses while any provenance proof fails: "
-                             "an observer leak, a cross-tenant edge, a "
-                             "dead lane, or an opportunity map under the "
-                             "90% coverage floor)")
     args = parser.parse_args(argv)
     if args.families:
         for name, description in FAMILIES:
@@ -376,44 +335,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                 print(f)
             print("staticcheck: refusing to lock an inconsistent wire "
                   "surface — fix the mirror disagreements above first")
-            return 1
-        print(f"wrote {lock_path}")
-        return 0
-    if args.update_hlo_lock:
-        from . import device_program
-
-        findings, lock_path = device_program.update_hlo_lock()
-        if findings:
-            for f in findings:
-                print(f)
-            print("staticcheck: refusing to lock a compiled-program surface "
-                  "the gate would immediately fail — fix the findings above "
-                  "first")
-            return 1
-        print(f"wrote {lock_path}")
-        return 0
-    if args.update_cost_lock:
-        from . import cost_model
-
-        findings, lock_path = cost_model.update_cost_lock()
-        if findings:
-            for f in findings:
-                print(f)
-            print("staticcheck: refusing to lock a scaling surface the gate "
-                  "would immediately fail — fix the findings above first")
-            return 1
-        print(f"wrote {lock_path}")
-        return 0
-    if args.update_dataflow_lock:
-        from . import dataflow as dataflow_mod
-
-        findings, lock_path = dataflow_mod.update_dataflow_lock()
-        if findings:
-            for f in findings:
-                print(f)
-            print("staticcheck: refusing to lock a provenance surface the "
-                  "gate would immediately fail — fix the findings above "
-                  "first")
             return 1
         print(f"wrote {lock_path}")
         return 0

@@ -1,21 +1,14 @@
 """Check family 17: jaxpr dataflow provenance gate (``dataflow``).
 
-The families up to here gate the compiled artifact's COST (hlo.lock.json
-budgets, cost.lock.json scaling classes). This family gates its
-INFLUENCE STRUCTURE: every registered ``device_program`` entrypoint is
-traced (no XLA compile — ``jitted.trace``) to its closed jaxpr and a
-deterministic per-lane taint/provenance propagation runs over it, through
-``pjit``/``scan``/``while``/``cond`` sub-jaxprs, producing:
-
-* a lane -> lane influence relation per entrypoint (which input lanes can
-  affect which output lanes, with ``while``/``scan`` carries tracked
-  PER SLOT so carry/donated-buffer reuse never fabricates an edge);
-* a per-equation provenance classification (prologue / cond / hot-loop
-  scope, dense-over-N or not, mask-gated or not).
-
-Both are frozen in ``tools/analysis/dataflow.lock.json`` and regenerated
-byte-identically by ``python tools/staticcheck.py --update-dataflow-lock``
-(which REFUSES while any proof below fails). Checks:
+The ``device_program`` family reads the compiled artifact. This family
+reads its INFLUENCE STRUCTURE: every registered ``device_program``
+entrypoint is traced (no XLA compile — ``jitted.trace``) to its closed
+jaxpr and a deterministic per-lane taint propagation runs over it, through
+``pjit``/``scan``/``while``/``cond`` sub-jaxprs, producing a lane -> lane
+influence relation per entrypoint (which input lanes can affect which
+output lanes, with ``while``/``scan`` carries tracked PER SLOT so
+carry/donated-buffer reuse never fabricates an edge). Two proofs run over
+the live trace, and nothing is compared with a committed file:
 
 ``dataflow-observer-effect``
     No telemetry (``tl_*``) or trace-ring (``tr_*``) lane may influence
@@ -32,48 +25,21 @@ byte-identically by ``python tools/staticcheck.py --update-dataflow-lock``
     lanes are not). Complements the HLO gate's zero-cross-tenant-
     collective budget at the dataflow level.
 
-``dataflow-dense-op``
-    The sparse-opportunity map: round-body equations that compute over
-    the full N slots yet are provably gated by the activity/alert/freeze
-    masks (structurally inside an activity-gated ``cond`` branch, or all
-    of whose consumers are activity-masked selects). Each is priced by
-    joining against the quiescent collective rows (the cost.lock.json
-    ``quiescent_round_cost`` block) on (location, source), so the map
-    states what share of the frozen quiescent payload bytes each dense
-    op explains. ROADMAP item 3's sparse restructure consumes this map
-    as its work-list; the check fires when the map stops explaining >=
-    90% of the frozen bytes, or the two locks disagree on the total.
+``dataflow-probe-error``
+    A corpus module's audit program failed to execute or to trace.
 
-``dataflow-dead-lane``
-    State lanes written by some entrypoint but never influencing any
-    output or fetched digest, under the transitive closure of the
-    step relation — dead weight the deadcode family (name-based) cannot
-    see and must never disagree with.
-
-``dataflow-lock-drift``
-    The committed lock no longer matches the live trace.
-
-Tracing is cheap (~2 s for the whole registry, no compile) and the
-byte-pricing join reuses the HLO gate's session-cached compiles, so this
-family rides in the same session budget as the cost ladder.
+Tracing is cheap (~2 s for the whole registry, no compile), and cached
+once a session like the compiled facts.
 """
 
 from __future__ import annotations
 
 import ast
-import json
 from pathlib import Path
 from typing import Any, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
-from . import core, hlo_facts
+from . import core
 from .core import Finding
-
-DATAFLOW_LOCK_REL = "tools/analysis/dataflow.lock.json"
-
-_REGEN_HINT = (
-    "rerun `python tools/staticcheck.py --update-dataflow-lock` after "
-    "reviewing the influence change"
-)
 
 #: Containers whose fields become lane-label prefixes. Anything else
 #: labels by field path alone (corpus probes may define their own
@@ -92,32 +58,6 @@ _OBSERVER_CONTAINERS = ("telem", "trace")
 _OBSERVER_FIELDS = ("tl_", "tr_")
 #: Subject planes the observer-effect proof protects.
 _SUBJECT_CONTAINERS = ("state", "events")
-
-#: Activity/alert/freeze masks: a dense op counts as mask-GATED when the
-#: predicate deciding whether its result is used derives from one of
-#: these lanes (field names, container-agnostic — the fleet's batched
-#: lanes carry the same labels).
-GATING_LANE_FIELDS = frozenset({
-    "alive", "crashed", "probe_fail", "rx_block",
-    "fd_fired", "fd_count", "fd_hist", "fire_round",
-    "report_bits", "seen_down", "released", "announced",
-    "prop_mask", "join_pending", "vote_valid", "retired",
-    "rounds_undecided", "decided", "round_idx",
-})
-
-#: How a jaxpr primitive spells its HLO op_name leaf — the join key that
-#: lets a dense jaxpr equation claim the collective rows its lowering
-#: produced (GSPMD strips Python function scopes from op_names; only the
-#: primitive leaf and surviving inner-jit scopes remain, so the join runs
-#: through hlo_facts.source_of applied to BOTH sides).
-_PRIM_HLO_LEAF = {
-    "cumsum": "cumsum", "cummax": "reduce_window", "cummin": "reduce_window",
-    "cumprod": "reduce_window",
-    "reduce_min": "reduce", "reduce_and": "reduce", "reduce_prod": "reduce",
-    "argmax": "reduce", "argmin": "reduce",
-    "select_n": "select",
-    "dynamic_update_slice": "dynamic_update_slice",
-}
 
 
 def _is_literal(atom: Any) -> bool:
@@ -189,10 +129,6 @@ def _is_subject_lane(label: str) -> bool:
     return _container_of(label) in _SUBJECT_CONTAINERS and not _field_of(
         label
     ).startswith(_OBSERVER_FIELDS)
-
-
-def _is_gating_lane(label: str) -> bool:
-    return _field_of(label) in GATING_LANE_FIELDS
 
 
 # ---------------------------------------------------------------------------
@@ -285,194 +221,6 @@ def _eqn_taints(eqn: Any, in_t: List[FrozenSet[int]]) -> List[FrozenSet[int]]:
 
 
 # ---------------------------------------------------------------------------
-# provenance walk (per-equation classification + sparse-opportunity map)
-# ---------------------------------------------------------------------------
-
-
-class _Provenance:
-    """Instrumented re-walk of one traced entrypoint: same recursion as
-    the taint interpreter, but recording per-equation (location, scope,
-    dense, gated) records and location counts. ``location`` follows
-    hlo_facts.classify_location semantics: a while body/cond is hot-loop,
-    a cond branch is cond (hot-loop-cond inside a loop), else prologue."""
-
-    def __init__(self, in_labels: List[str], dense_threshold: int):
-        self.in_labels = in_labels
-        self.dense_threshold = dense_threshold
-        self.dense_records: List[Dict[str, Any]] = []
-        self.location_counts: Dict[str, int] = {}
-
-    def _labels_for(self, taint: FrozenSet[int]) -> List[str]:
-        return sorted(self.in_labels[i] for i in taint)
-
-    def _gating(self, taint: FrozenSet[int]) -> List[str]:
-        return sorted(
-            self.in_labels[i] for i in taint if _is_gating_lane(self.in_labels[i])
-        )
-
-    def run(self, closed: Any, in_taints: List[FrozenSet[int]]) -> None:
-        self._walk(closed, in_taints, scopes=(), location="prologue",
-                   gate_lanes=frozenset())
-
-    def _walk(self, closed: Any, in_taints: List[FrozenSet[int]],
-              scopes: Tuple[str, ...], location: str,
-              gate_lanes: FrozenSet[int]) -> None:
-        jaxpr = getattr(closed, "jaxpr", closed)
-        if len(in_taints) != len(jaxpr.invars):
-            return
-        env: Dict[Any, FrozenSet[int]] = {}
-        for var in jaxpr.constvars:
-            env[var] = frozenset()
-        for var, taint in zip(jaxpr.invars, in_taints):
-            env[var] = taint
-
-        def read(atom: Any) -> FrozenSet[int]:
-            if _is_literal(atom):
-                return frozenset()
-            return env.get(atom, frozenset())
-
-        consumers: Dict[Any, List[Any]] = {}
-        for eqn in jaxpr.eqns:
-            for atom in eqn.invars:
-                if not _is_literal(atom):
-                    consumers.setdefault(atom, []).append(eqn)
-        escaping = {v for v in jaxpr.outvars if not _is_literal(v)}
-
-        for eqn in jaxpr.eqns:
-            prim = eqn.primitive.name
-            params = eqn.params
-            in_t = [read(a) for a in eqn.invars]
-            self.location_counts[location] = (
-                self.location_counts.get(location, 0) + 1
-            )
-            if prim == "cond":
-                branch_loc = "hot-loop-cond" if location.startswith("hot-loop") else "cond"
-                branch_gates = gate_lanes | frozenset(
-                    i for i in in_t[0] if _is_gating_lane(self.in_labels[i])
-                )
-                for branch in params["branches"]:
-                    self._walk(branch, list(in_t[1:]), scopes + ("cond",),
-                               branch_loc, branch_gates)
-            elif prim == "while":
-                cn, bn = params["cond_nconsts"], params["body_nconsts"]
-                carry = self._fixpoint_while(params, in_t)
-                self._walk(params["cond_jaxpr"], in_t[:cn] + carry,
-                           scopes + ("while",), "hot-loop", gate_lanes)
-                self._walk(params["body_jaxpr"], in_t[cn:cn + bn] + carry,
-                           scopes + ("while",), "hot-loop", gate_lanes)
-            elif prim == "scan":
-                nc, nk = params["num_consts"], params["num_carry"]
-                carry = self._fixpoint_scan(params, in_t)
-                self._walk(params["jaxpr"],
-                           in_t[:nc] + carry + list(in_t[nc + nk:]),
-                           scopes + ("scan",), location, gate_lanes)
-            else:
-                sub = _sub_jaxpr(params)
-                if sub is not None:
-                    name = params.get("name") or prim
-                    self._walk(sub, list(in_t), scopes + (str(name),),
-                               location, gate_lanes)
-                else:
-                    self._record(eqn, in_t, read, consumers, escaping,
-                                 scopes, location, gate_lanes)
-            outs = _eqn_taints(eqn, in_t)
-            for var, taint in zip(eqn.outvars, outs):
-                if not _is_dropvar(var):
-                    env[var] = taint
-
-    def _fixpoint_while(self, params, in_t):
-        cn, bn = params["cond_nconsts"], params["body_nconsts"]
-        carry = list(in_t[cn + bn:])
-        while True:
-            pred_outs = _taint_closed(params["cond_jaxpr"], in_t[:cn] + carry)
-            pred = pred_outs[0] if pred_outs else frozenset()
-            body = _taint_closed(params["body_jaxpr"], in_t[cn:cn + bn] + carry)
-            merged = [c | b | pred for c, b in zip(carry, body)]
-            if merged == carry:
-                return carry
-            carry = merged
-
-    def _fixpoint_scan(self, params, in_t):
-        nc, nk = params["num_consts"], params["num_carry"]
-        carry = list(in_t[nc:nc + nk])
-        xs = list(in_t[nc + nk:])
-        while True:
-            outs = _taint_closed(params["jaxpr"], in_t[:nc] + carry + xs)
-            merged = [c | o for c, o in zip(carry, outs[:nk])]
-            if merged == carry:
-                return carry
-            carry = merged
-
-    def _record(self, eqn, in_t, read, consumers, escaping, scopes,
-                location, gate_lanes) -> None:
-        sizes = [
-            int(getattr(a.aval, "size", 0))
-            for a in list(eqn.invars) + list(eqn.outvars)
-            if not _is_literal(a) and hasattr(a, "aval")
-        ]
-        if not sizes or max(sizes) < self.dense_threshold:
-            return
-        prim = eqn.primitive.name
-        gated_by: FrozenSet[int] = frozenset()
-        if gate_lanes:
-            gated_by = gate_lanes
-        else:
-            select_gates = self._select_gated(eqn, read, consumers, escaping)
-            if select_gates is not None:
-                gated_by = select_gates
-        leaf = _PRIM_HLO_LEAF.get(prim, prim)
-        op_name = "/".join(scopes + (leaf,))
-        self.dense_records.append({
-            "prim": prim,
-            "scope": op_name,
-            "location": location,
-            "source": hlo_facts.source_of(op_name),
-            "elems": max(sizes),
-            "gated": bool(gated_by),
-            "gated_by": sorted(
-                {self.in_labels[i] for i in gated_by}
-            ),
-        })
-
-    def _select_gated(self, eqn, read, consumers, escaping) -> Optional[FrozenSet[int]]:
-        """Consumer rule: every use of every output is a select whose
-        predicate carries an activity-mask taint and which consumes the
-        value as a CASE (not as the predicate). An output escaping this
-        sub-jaxpr counts as an ungated use — the caller's context is not
-        visible here, so the claim stays conservative."""
-        gates: FrozenSet[int] = frozenset()
-        for var in eqn.outvars:
-            if _is_dropvar(var):
-                continue
-            if var in escaping:
-                return None
-            uses = consumers.get(var, [])
-            if not uses:
-                continue
-            for use in uses:
-                pred = self._select_pred(use)
-                if pred is None or pred is var:
-                    return None
-                pred_gates = frozenset(
-                    i for i in read(pred) if _is_gating_lane(self.in_labels[i])
-                )
-                if not pred_gates:
-                    return None
-                gates = gates | pred_gates
-        return gates if gates else None
-
-    @staticmethod
-    def _select_pred(eqn) -> Optional[Any]:
-        if eqn.primitive.name == "select_n" and eqn.invars:
-            return eqn.invars[0]
-        if eqn.primitive.name == "pjit" and str(
-            eqn.params.get("name", "")
-        ).startswith("_where") and eqn.invars:
-            return eqn.invars[0]
-        return None
-
-
-# ---------------------------------------------------------------------------
 # tenant-axis abstract interpretation (cross-tenant proof)
 # ---------------------------------------------------------------------------
 
@@ -536,6 +284,13 @@ def _axis_eqn(eqn: Any, in_a: List[Any], tenants: int,
         return [None] * n_out
     if prim in _ELEMENTWISE_SAFE:
         return [_unify_axes(in_a)] * n_out
+    if prim == "bitcast_convert_type":
+        # Elementwise between dtypes of one width (the ring walk's
+        # uint32 <-> int32 scan word). A width change adds or drops a
+        # trailing dimension: not tracked, so it falls through to mixed.
+        widths = {v.aval.dtype.itemsize for v in (eqn.invars[0], eqn.outvars[0])}
+        if len(widths) == 1:
+            return [in_a[0]] * n_out
     if prim == "broadcast_in_dim":
         axis = in_a[0]
         if axis in (None, _MIXED):
@@ -790,14 +545,7 @@ def _registry_with_fleet() -> Dict[str, Dict[str, Any]]:
     from . import device_program
 
     registry = dict(device_program._build_registry())
-    if "fleet_step" not in registry:
-        registry["fleet_step"] = device_program.build_ladder_spec(
-            "fleet_step",
-            device_program.AUDIT_N,
-            device_program.AUDIT_K,
-            device_program.AUDIT_C,
-            tenants=device_program.AUDIT_TENANTS,
-        )
+    registry["fleet_step"] = device_program.fleet_step_spec()
     return registry
 
 
@@ -865,117 +613,8 @@ def cross_tenant_findings(
     return findings
 
 
-def _opportunity_map(
-    entry: Dict[str, Any], prov: "_Provenance", quiescent_rows: List[Dict[str, Any]],
-) -> Dict[str, Any]:
-    """Join the dense gated jaxpr equations against the quiescent
-    entrypoint's collective rows on (location, source). A bucket is
-    CLAIMED when at least one gated dense op shares its (location,
-    source) — those payload bytes are provably maskable and belong on
-    ROADMAP item 3's work-list; the rest stay listed as unclaimed."""
-    buckets: Dict[Tuple[str, str], int] = {}
-    for row in quiescent_rows:
-        key = (row["location"], row["source"])
-        buckets[key] = buckets.get(key, 0) + int(row["bytes"])
-    total = sum(buckets.values())
-
-    ops_by_key: Dict[Tuple[str, str], List[Dict[str, Any]]] = {}
-    for rec in prov.dense_records:
-        if not rec["gated"]:
-            continue
-        ops_by_key.setdefault((rec["location"], rec["source"]), []).append(rec)
-
-    entries: List[Dict[str, Any]] = []
-    unclaimed: List[Dict[str, Any]] = []
-    claimed_bytes = 0
-    for (location, source), nbytes in sorted(buckets.items()):
-        ops = ops_by_key.get((location, source), [])
-        if ops:
-            claimed_bytes += nbytes
-            grouped: Dict[Tuple[str, str], Dict[str, Any]] = {}
-            for rec in ops:
-                gkey = (rec["prim"], rec["scope"])
-                slot = grouped.setdefault(gkey, {
-                    "prim": rec["prim"], "scope": rec["scope"], "count": 0,
-                    "gated_by": set(),
-                })
-                slot["count"] += 1
-                slot["gated_by"].update(rec["gated_by"])
-            entries.append({
-                "location": location,
-                "source": source,
-                "bytes": nbytes,
-                "share_pct": round(100.0 * nbytes / total, 2) if total else 0.0,
-                "dense_ops": [
-                    {
-                        "prim": g["prim"], "scope": g["scope"],
-                        "count": g["count"],
-                        "gated_by": sorted(g["gated_by"]),
-                    }
-                    for _, g in sorted(grouped.items())
-                ],
-            })
-        else:
-            unclaimed.append({
-                "location": location, "source": source, "bytes": nbytes,
-            })
-    coverage = (claimed_bytes / total) if total else 0.0
-    return {
-        "entrypoint": entry["name"],
-        "total_collective_payload_bytes": total,
-        "claimed_bytes": claimed_bytes,
-        "coverage_pct": round(100.0 * coverage, 2),
-        "dense_gated": entries,
-        "unclaimed": unclaimed,
-    }
-
-
-def _carry_only_lanes(entries: List[Dict[str, Any]],
-                      influence: Dict[str, Dict[str, List[str]]]) -> List[str]:
-    """State lanes written by some entrypoint but unreachable (through
-    the transitive step relation) from any non-state output — the jaxpr
-    side of the dead-lane check. A carry-only lane is NOT yet dead: the
-    full state pytree is a program output, so the host may fetch the lane
-    directly (config_id reads config_hi/config_lo; the admission path
-    reads retired). The finding fires only when the tree-wide reference
-    scan (the deadcode family's collector — attribute reads, getattr
-    strings, f-string fields) cannot find the lane consumed by name
-    anywhere either; that join is what keeps the two liveness families
-    from ever disagreeing."""
-    written: set = set()
-    edges: Dict[str, set] = {}
-    live_now: set = set()
-    for entry in entries:
-        rel = influence[entry["name"]]
-        for out_label, in_labels in rel.items():
-            if out_label.startswith("state."):
-                field = out_label
-                if in_labels != [out_label]:
-                    written.add(field)
-                for src in in_labels:
-                    if src.startswith("state."):
-                        edges.setdefault(src, set()).add(field)
-            else:
-                for src in in_labels:
-                    if src.startswith("state."):
-                        live_now.add(src)
-    live = set(live_now)
-    frontier = list(live_now)
-    reverse: Dict[str, set] = {}
-    for src, dsts in edges.items():
-        for dst in dsts:
-            reverse.setdefault(dst, set()).add(src)
-    while frontier:
-        lane = frontier.pop()
-        for src in reverse.get(lane, ()):
-            if src not in live:
-                live.add(src)
-                frontier.append(src)
-    return sorted(written - live)
-
-
 # ---------------------------------------------------------------------------
-# collection + lock
+# collection + tree mode
 # ---------------------------------------------------------------------------
 
 _DATAFLOW_CACHE: Optional[Tuple[Dict[str, Any], List[Finding], bool]] = None
@@ -984,12 +623,14 @@ _DATAFLOW_CACHE: Optional[Tuple[Dict[str, Any], List[Finding], bool]] = None
 def collect_dataflow(
     force: bool = False, require_mesh: bool = True,
 ) -> Tuple[Dict[str, Any], List[Finding]]:
-    """Trace the full registry, run every proof, and build the lock
-    payload. Cached per session like the HLO facts/cost ladder (the trace
-    itself is compile-free; the byte-pricing join reuses the session's
-    collect_facts cache). Raises RuntimeError without the 8-device mesh
-    when ``require_mesh`` — a partial registry must never be frozen or
-    compared against the committed lock."""
+    """Trace the full registry and run both proofs: ``(proofs, findings)``,
+    cached per session like the HLO facts (the trace is compile-free).
+    ``proofs["observer_silent"][name]`` is the observer verdict of every
+    entrypoint, ``proofs["tenant_isolation"][name]`` the
+    tenant-axis verdict of every fleet one (``proven``, ``mixed_outputs``,
+    ``axis_rule_fallbacks``). Raises RuntimeError without the 8-device mesh
+    when ``require_mesh`` — a partial registry proves nothing about the
+    mesh programs."""
     global _DATAFLOW_CACHE
     import jax
 
@@ -1006,257 +647,59 @@ def collect_dataflow(
     if _DATAFLOW_CACHE is not None and not force and _DATAFLOW_CACHE[2] == have_mesh:
         return _DATAFLOW_CACHE[0], _DATAFLOW_CACHE[1]
 
-    payload, findings = _build_payload(have_mesh)
-    _DATAFLOW_CACHE = (payload, findings, have_mesh)
-    return payload, findings
+    proofs, findings = _prove_registry()
+    _DATAFLOW_CACHE = (proofs, findings, have_mesh)
+    return proofs, findings
 
 
-def _build_payload(have_mesh: bool) -> Tuple[Dict[str, Any], List[Finding]]:
+def _prove_registry() -> Tuple[Dict[str, Any], List[Finding]]:
     from . import device_program
 
-    loc = (DATAFLOW_LOCK_REL, 1)
+    loc = (device_program.REGISTRY_REL, 1)
     registry = _registry_with_fleet()
     findings: List[Finding] = []
-    entries: List[Dict[str, Any]] = []
-    influence: Dict[str, Dict[str, List[str]]] = {}
-    eqn_counts: Dict[str, Dict[str, int]] = {}
     observer_silent: Dict[str, bool] = {}
-    tenant_axes_out: Dict[str, Dict[str, Any]] = {}
-    provenances: Dict[str, "_Provenance"] = {}
+    tenant_isolation: Dict[str, Dict[str, Any]] = {}
 
     tenants = device_program.AUDIT_TENANTS
     for name in sorted(registry):
         spec = registry[name]
         entry = _trace_entry(name, spec)
-        entries.append(entry)
-        n_in = len(entry["in_labels"])
-        in_taints = [frozenset([i]) for i in range(n_in)]
+        in_taints = [frozenset([i]) for i in range(len(entry["in_labels"]))]
         out_taints = _taint_closed(entry["closed"], in_taints)
-        labels = entry["in_labels"]
-        influence[name] = {
-            out_label: sorted(labels[i] for i in taint)
-            for out_label, taint in zip(entry["out_labels"], out_taints)
-        }
-        obs = observer_effect_findings(entry, out_taints, loc)
-        findings.extend(obs)
-        observer_silent[name] = not obs
-
-        prov = _Provenance(labels, device_program.AUDIT_N)
-        prov.run(entry["closed"], in_taints)
-        provenances[name] = prov
-        eqn_counts[name] = dict(sorted(prov.location_counts.items()))
+        leaks = observer_effect_findings(entry, out_taints, loc)
+        findings.extend(leaks)
+        observer_silent[name] = not leaks
 
         if name.startswith("fleet"):
             in_axes = _tenant_in_axes(entry, spec, tenants)
             fallbacks: List[str] = []
             out_axes = _axis_closed(entry["closed"], in_axes, tenants, fallbacks)
             findings.extend(cross_tenant_findings(entry, out_axes, fallbacks, loc))
-            tenant_axes_out[name] = {
-                "proven": not any(a == _MIXED for a in out_axes),
-                "mixed_outputs": sorted(
-                    lbl for lbl, a in zip(entry["out_labels"], out_axes)
-                    if a == _MIXED
-                ),
+            mixed = sorted(
+                lbl for lbl, a in zip(entry["out_labels"], out_axes)
+                if a == _MIXED
+            )
+            tenant_isolation[name] = {
+                "proven": not mixed,
+                "mixed_outputs": mixed,
                 "axis_rule_fallbacks": sorted(set(fallbacks)),
             }
-
-    # Sparse-opportunity map: priced against the quiescent entrypoint's
-    # live collective rows, cross-checked against the cost lock's frozen
-    # total (the two-lock coupling).
-    opportunity: Dict[str, Any] = {
-        "entrypoint": "sharded_step",
-        "status": "unavailable: no 8-device mesh",
-    }
-    if have_mesh:
-        facts = device_program.collect_facts(require_mesh=True)
-        fact_entry = facts.get("sharded_step")
-        if fact_entry is not None and "sharded_step" in provenances:
-            entry_obj = next(e for e in entries if e["name"] == "sharded_step")
-            opportunity = _opportunity_map(
-                entry_obj, provenances["sharded_step"], fact_entry["rows"]
-            )
-            findings.extend(_coverage_findings(opportunity, loc))
-
-    carry_only = _carry_only_lanes(entries, influence)
-    referenced = _tree_reference_names()
-    for lane in carry_only:
-        if _field_of(lane) not in referenced:
-            findings.append(Finding(
-                loc[0], loc[1], "dataflow-dead-lane",
-                f"state lane {lane} is written by the engine but "
-                f"influences no output or fetched digest in any "
-                f"registered entrypoint, and no host code references it "
-                f"by name — dead weight in the donated state buffers",
-            ))
-
-    payload = {
-        "_comment": (
-            "Lane-level dataflow provenance of every registered "
-            "device_program entrypoint, traced (compile-free) from the "
-            "closed jaxpr: the lane->lane influence relation, "
-            "per-location equation counts, the observer-silence and "
-            "tenant-isolation proofs, and the sparse-opportunity map "
-            "(dense mask-gated round-body ops priced against the "
-            "cost.lock.json quiescent payload bytes) that ROADMAP item "
-            "3's sparse restructure consumes as its work-list. Generated "
-            "by `python tools/staticcheck.py --update-dataflow-lock`; do "
-            "not edit by hand — any drift from the live trace fails the "
-            "staticcheck gate."
-        ),
-        "entrypoints": {
-            e["name"]: {
-                "influence": influence[e["name"]],
-                "eqn_locations": eqn_counts[e["name"]],
-                "observer_silent": observer_silent[e["name"]],
-            }
-            for e in entries
-        },
-        "tenant_isolation": tenant_axes_out,
-        "opportunity_map": opportunity,
-        "carry_only_lanes": carry_only,
-    }
-    return payload, findings
+    return (
+        {"observer_silent": observer_silent, "tenant_isolation": tenant_isolation},
+        findings,
+    )
 
 
-def _coverage_findings(opportunity: Dict[str, Any],
-                       loc: Tuple[str, int]) -> List[Finding]:
-    """The map must EXPLAIN the frozen quiescent bytes: >= 90% of the
-    payload attributed to provably mask-gated dense ops, and the live
-    join total must agree with the cost lock's frozen
-    quiescent_round_cost (two locks, one artifact)."""
-    path, lineno = loc
-    findings = []
-    from .cost_model import COST_LOCK_REL
-
-    cost_lock = core.REPO / COST_LOCK_REL
-    if cost_lock.exists():
-        try:
-            frozen = json.loads(cost_lock.read_text())
-            frozen_bytes = frozen.get("quiescent_round_cost", {}).get(
-                "collective_payload_bytes"
-            )
-        except json.JSONDecodeError:
-            frozen_bytes = None
-        live_total = opportunity.get("total_collective_payload_bytes")
-        if frozen_bytes is not None and live_total != frozen_bytes:
-            findings.append(Finding(
-                path, lineno, "dataflow-dense-op",
-                f"sparse-opportunity join total ({live_total} B) does not "
-                f"match the cost lock's frozen quiescent "
-                f"collective_payload_bytes ({frozen_bytes} B) — refreeze "
-                f"the cost lock first, then this one",
-            ))
-    coverage = opportunity.get("coverage_pct", 0.0)
-    if coverage < 90.0:
-        unclaimed = opportunity.get("unclaimed", [])
-        detail = ", ".join(
-            f"{u['location']}/{u['source']} ({u['bytes']} B)" for u in unclaimed
-        )
-        findings.append(Finding(
-            path, lineno, "dataflow-dense-op",
-            f"sparse-opportunity map explains only {coverage}% of the "
-            f"quiescent payload bytes (floor 90%) — unclaimed buckets: "
-            f"{detail or 'none'}; a dense op whose bytes the map cannot "
-            f"attribute to a mask gate is not provably sparsifiable",
-        ))
-    return findings
-
-
-def _tree_reference_names() -> set:
-    """Every identifier the analyzed tree consumes, per the deadcode
-    family's reference collector (attribute reads, getattr-string
-    arguments, f-string field fragments) — the host-side 'fetched'
-    evidence the jaxpr cannot see. Parsed fresh from disk (cheap next to
-    the trace) so tree-less callers — the lock updater, bench — get the
-    same answer as the driver's tree mode."""
-    from . import deadcode
-
-    names: set = set()
-    for path in core.iter_files():
-        try:
-            tree = ast.parse(path.read_text(), filename=str(path))
-        except (SyntaxError, OSError):
-            continue
-        names |= deadcode._collect_references(tree)
-    return names
-
-
-def check_dataflow_lock(trees: Sequence[Tuple[ast.AST, str]]) -> List[Finding]:
-    """Tree-mode gate: trace the registry (session-cached), run the
-    proofs, and compare against the committed lock. Presence-gated on the
-    engine sources exactly like the HLO/cost gates, so retargeted test
-    trees never pay a trace."""
+def check_dataflow_proofs(trees: Sequence[Tuple[ast.AST, str]]) -> List[Finding]:
+    """Tree-mode gate: trace the registry (session-cached) and report what
+    the two proofs find. Presence-gated on the engine sources exactly like
+    the HLO gate, so retargeted test trees never pay a trace."""
     from . import device_program
 
-    rels = {rel.replace("\\", "/") for _, rel in trees}
-    if not all(src in rels for src in device_program.REGISTRY_SOURCES):
+    if not device_program.covers_registry(trees):
         return []
-    try:
-        payload, findings = collect_dataflow()
-    except RuntimeError as exc:
-        return [Finding(DATAFLOW_LOCK_REL, 1, "dataflow-lock-drift",
-                        f"cannot trace the registry: {exc}")]
-    findings = list(findings)
-    lock_path = core.REPO / DATAFLOW_LOCK_REL
-    if not lock_path.exists():
-        findings.append(Finding(
-            DATAFLOW_LOCK_REL, 1, "dataflow-lock-drift",
-            "dataflow lockfile missing — generate it via "
-            "`python tools/staticcheck.py --update-dataflow-lock`",
-        ))
-        return findings
-    try:
-        locked = json.loads(lock_path.read_text())
-    except json.JSONDecodeError as exc:
-        findings.append(Finding(
-            DATAFLOW_LOCK_REL, 1, "dataflow-lock-drift",
-            f"dataflow lockfile is not valid JSON ({exc.msg}) — "
-            f"regenerate via `python tools/staticcheck.py "
-            f"--update-dataflow-lock`",
-        ))
-        return findings
-    live = _canonical(payload)
-    committed = _canonical(locked)
-    for key in sorted(set(live) | set(committed)):
-        if live.get(key) != committed.get(key):
-            findings.append(Finding(
-                DATAFLOW_LOCK_REL, 1, "dataflow-lock-drift",
-                f"{key!r} block drifted from the committed dataflow lock "
-                f"— {_REGEN_HINT}",
-            ))
-    return findings
-
-
-def _canonical(payload: Dict[str, Any]) -> Dict[str, Any]:
-    """JSON round-trip (tuples -> lists, key ordering) minus the prose
-    comment, so live and committed payloads compare structurally."""
-    slim = {k: v for k, v in payload.items() if k != "_comment"}
-    return json.loads(json.dumps(slim, sort_keys=True))
-
-
-def update_dataflow_lock() -> Tuple[List[Finding], Optional[Path]]:
-    """Regenerate the dataflow lockfile from a fresh trace. Refuses while
-    ANY proof fails — an observer leak, a cross-tenant edge, a dead lane,
-    or an opportunity map that stops explaining the quiescent bytes must
-    be fixed, never frozen. Byte-identical when nothing changed (the
-    trace and the joins are pure deterministic walks)."""
-    try:
-        payload, findings = collect_dataflow(force=True)
-    except RuntimeError as exc:
-        return [Finding(DATAFLOW_LOCK_REL, 1, "dataflow-lock-drift",
-                        str(exc))], None
-    if findings:
-        return (
-            [Finding(f.path, f.lineno, f.check,
-                     f"refusing to freeze: {f.message}")
-             for f in findings],
-            None,
-        )
-    lock_path = core.REPO / DATAFLOW_LOCK_REL
-    lock_path.write_text(
-        json.dumps(payload, indent=2, sort_keys=True) + "\n"
-    )
-    return [], lock_path
+    return list(collect_dataflow()[1])
 
 
 # ---------------------------------------------------------------------------
@@ -1270,11 +713,11 @@ def check_dataflow(
     """Corpus/per-file mode: execute a module that declares
     ``DATAFLOW_AUDIT_PROGRAMS`` (name -> {"build": zero-arg callable
     returning a registry-shaped spec, "checks": subset of
-    ("observer-effect", "cross-tenant", "dense-op"), optional
-    "tenants"/"dense_n"}) and run the requested proofs over each traced
-    program. Findings anchor at the program's dict-key line, mirroring
-    the cost-model corpus convention. Files without the marker are
-    skipped — this family's tree mode runs against the real registry."""
+    ("observer-effect", "cross-tenant"), optional "tenants"}) and run the
+    requested proofs over each traced program. Findings anchor at the
+    program's dict-key line, mirroring the device_program corpus
+    convention. Files without the marker are skipped — this family's tree
+    mode runs against the real registry."""
     rel = _rel(path)
     if source is None:
         try:
@@ -1293,7 +736,7 @@ def check_dataflow(
     try:
         exec(compile(source, str(path), "exec"), namespace)  # noqa: S102
     except Exception as exc:  # noqa: BLE001 — a broken probe is a finding
-        return [Finding(rel, 1, "dataflow-lock-drift",
+        return [Finding(rel, 1, "dataflow-probe-error",
                         f"dataflow audit module failed to execute: {exc!r}")]
     programs = namespace.get("DATAFLOW_AUDIT_PROGRAMS")
     if not isinstance(programs, dict):
@@ -1308,7 +751,7 @@ def check_dataflow(
             entry = _trace_entry(name, spec)
         except Exception as exc:  # noqa: BLE001
             findings.append(Finding(
-                rel, lineno, "dataflow-lock-drift",
+                rel, lineno, "dataflow-probe-error",
                 f"{name}: audit program failed to trace: {exc!r}"))
             continue
         checks = tuple(cfg.get("checks", ()))
@@ -1324,18 +767,6 @@ def check_dataflow(
             out_axes = _axis_closed(entry["closed"], in_axes, tenants, fallbacks)
             findings.extend(
                 cross_tenant_findings(entry, out_axes, fallbacks, loc))
-        if "dense-op" in checks:
-            prov = _Provenance(entry["in_labels"], int(cfg.get("dense_n", 1)))
-            prov.run(entry["closed"], in_taints)
-            for rec in prov.dense_records:
-                if rec["gated"]:
-                    findings.append(Finding(
-                        rel, lineno, "dataflow-dense-op",
-                        f"{name}: dense {rec['prim']} over {rec['elems']} "
-                        f"elements is provably gated by "
-                        f"{', '.join(rec['gated_by'])} yet computes over "
-                        f"the full lane — a sparse-opportunity candidate",
-                    ))
     return sorted(set(findings), key=lambda f: (f.lineno, f.check, f.message))
 
 

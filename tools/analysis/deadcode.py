@@ -13,10 +13,26 @@ _DEF_ALLOW_NAMES = {"main", "entry", "dryrun_multichip"}  # external entry point
 _IDENT = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 
+def _is_autouse_fixture(node: ast.AST) -> bool:
+    """``@pytest.fixture(..., autouse=True)``: pytest applies it to every
+    test of its scope, so no test names it."""
+    for dec in node.decorator_list:
+        if not isinstance(dec, ast.Call):
+            continue
+        name = getattr(dec.func, "attr", getattr(dec.func, "id", None))
+        if name == "fixture" and any(
+            kw.arg == "autouse" and getattr(kw.value, "value", None) is True
+            for kw in dec.keywords
+        ):
+            return True
+    return False
+
+
 def _collect_definitions(tree: ast.AST, rel: str):
     for node in tree.body:
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.ClassDef)):
-            yield node.name, rel, node.lineno
+            if not _is_autouse_fixture(node):
+                yield node.name, rel, node.lineno
         # Simple module constants too (plain Name targets only: tuple
         # unpacking legitimately discards elements, so it is out of scope;
         # dunders like __all__ fall to the allowlist).
@@ -33,8 +49,7 @@ def _collect_references(tree: ast.AST) -> set:
     attribute accesses, function parameter names (pytest fixtures are used
     by naming them as parameters), ``getattr``/``setattr``/``hasattr``/
     ``delattr`` with a literal field name (dynamic lane access is still
-    access — the dataflow family's dead-lane check and this one must
-    never disagree on liveness), identifiers inside f-string fragments
+    access), identifiers inside f-string fragments
     (a lane named in a debug label is consumed by whoever reads the
     label), and identifiers inside CODE-LOOKING string constants
     (multi-line or call-shaped — subprocess job scripts, ``python -c``

@@ -1,4 +1,4 @@
-"""Check family 12: compiled-program conformance (the HLO budget gate).
+"""Check family 12: compiled-program conformance (the HLO gate).
 
 The engine's communication story is a claim about what XLA emits, so this
 family checks the compiled artifact itself: every registered jitted engine
@@ -8,37 +8,36 @@ compiled via ``jax.jit(...).lower().compile()`` and its facts extracted
 from ``as_text()`` + ``memory_analysis()``:
 
 - every cross-device collective, classified by kind, payload bytes/class,
-  and location (hot-loop / hot-loop-cond / cond / prologue — the
-  ``hlo_facts`` classifier that absorbed ``rapid_tpu/parallel/audit.py``);
+  and location (hot-loop / wave-loop / cond / prologue — the ``hlo_facts``
+  classifier that absorbed ``rapid_tpu/parallel/audit.py``);
 - host<->device transfer ops (infeed/outfeed/send/recv);
 - donation outcomes: each ``donate_argnums`` leaf either aliased in the
   compiled output (``input_output_alias``) or dropped — a drop without an
-  explicit registry waiver is a finding, never a frozen fact;
+  explicit registry waiver is a finding;
 - argument/output/temp/generated-code memory bytes.
 
-The facts freeze into the committed lockfile
-``tools/analysis/hlo.lock.json``. Drift — a new hot-loop collective, a
-payload-class increase, a lost donation, temp-memory growth beyond
-tolerance — fails the gate naming the entrypoint and the delta, until the
-developer regenerates via ``python tools/staticcheck.py --update-hlo-lock``
-and reviews the diff (the ``wire.lock.json`` workflow, applied to the
-compiled program instead of the wire schema).
+No fact is compared with a committed number. The tree sweep
+(:func:`check_compiled_programs`) reports what is wrong with the LIVE
+programs whatever they were yesterday: an unknown HLO dtype, an unwaived
+dropped donation, a collective that crosses tenants, a host transfer.
+What holds of each program beyond that (which collectives sit in the round
+loop, what the compact layout saves) is asserted, one case an entrypoint,
+by ``tests/test_hlo_gate.py`` over the same facts.
 
-Compiling is expensive relative to AST checks (~15 s for the six
+Compiling is expensive relative to AST checks (~25 s for the thirteen
 entrypoints), so facts are collected ONCE per process and cached: the
-tree-sweep gate, the lock regenerator, the bench's ``hlo_audit`` stage and
-every test share one collection. ``check_device_program`` is the per-file
-mode for the seeded lint corpus: a module defining ``HLO_AUDIT_PROGRAMS``
-(name -> zero-arg builder returning ``{"jit": jitted, "args": (...),
-"donated_leaves": int}``) and ``HLO_LOCK`` is compiled and compared against
-its own inline lock — the corpus way to pin an injected hot-loop
-all-gather or a dropped donation, finding by finding.
+tree sweep, the bench's ``hlo_audit`` stage and every test share one
+collection. ``check_device_program`` is the per-file mode for the seeded
+lint corpus: a module defining ``HLO_AUDIT_PROGRAMS`` (name -> zero-arg
+builder returning ``{"jit": jitted, "args": (...), "donated_leaves":
+int}``) and ``HLO_LOCK`` is compiled and compared against its own inline
+claim — the corpus way to pin an injected hot-loop all-gather or a dropped
+donation, finding by finding.
 """
 
 from __future__ import annotations
 
 import ast
-import json
 import warnings
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -46,8 +45,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from . import core, hlo_facts
 from .core import Finding
 
-#: The committed freeze of the compiled-program facts, repo-relative.
-HLO_LOCK_REL = "tools/analysis/hlo.lock.json"
+#: Where a finding about a registered program anchors: the registry's home.
+REGISTRY_REL = "tools/analysis/device_program.py"
 
 #: The source files the registry compiles — the tree-mode gate only runs
 #: when a sweep actually covers this repo's engine (tests that retarget
@@ -77,26 +76,14 @@ AUDIT_COHORT_DEVICES = 2
 AUDIT_TENANTS = 4
 AUDIT_FLEET_MESH = (2, 2, 2)
 AUDIT_TENANT_BLOCK = AUDIT_DEVICES // AUDIT_FLEET_MESH[0]
-#: Ring capacity for the round-trace audit entrypoint: small enough that
-#: the ring's argument bytes stay a rounding error next to the state, big
-#: enough that the soak below (QUIESCENT_SOAK_ROUNDS rounds) wraps it —
-#: the cursor fact is measured across a wrap, not just a partial fill.
+#: Ring capacity of the ``step_trace`` entrypoint and of the trace-on side
+#: of :func:`trace_differential_ok`: small, so that the ring's argument
+#: bytes stay a rounding error next to the state.
 AUDIT_TRACE_R = 8
 
-#: Relative tolerance + absolute slack for the temp/codegen memory
-#: comparison: XLA's buffer assignment may legitimately wobble a little
-#: between versions; growth beyond this is a real regression.
-MEMORY_REL_TOL = 0.10
-MEMORY_ABS_SLACK = 4096
-
-#: Memory keys compared exactly (shape-determined) vs under tolerance
-#: (scheduler-determined).
-_EXACT_MEMORY_KEYS = ("argument_bytes", "output_bytes")
-_TOLERANT_MEMORY_KEYS = ("temp_bytes", "generated_code_bytes")
-
 _REGEN_HINT = (
-    "if this compiled-program change is intentional, regenerate via "
-    "`python tools/staticcheck.py --update-hlo-lock` and review the diff"
+    "if this compiled-program change is intentional, update the module's "
+    "inline HLO_LOCK and review the diff"
 )
 
 
@@ -140,11 +127,10 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     state_leaves = len(jax.tree_util.tree_leaves(state))
 
     # The compact-state twin (ISSUE 13): identical geometry/seed, state
-    # stored at the config-derived narrow dtypes. Registered so the lock
-    # freezes the per-device argument-byte saving of the [k,n]/[c,n]-
-    # dominated entrypoints against the wide layout above — and so any
-    # future compiled-program drift of the compact path fails the gate
-    # like every other entrypoint.
+    # stored at the config-derived narrow dtypes. Registered so the
+    # per-device argument-byte saving of the [k,n]/[c,n]-dominated
+    # entrypoints is read off the compiled artifact beside the wide layout
+    # above, and so the compact path is audited like every other entrypoint.
     vc_c = VirtualCluster.create(
         AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
         fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
@@ -189,7 +175,7 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
         # Only the compact STEP is registered (the PR-9 convention that
         # kept the 2-D step unregistered): the wave's argument surface is
         # byte-identical to the step's modulo three trailing int32 control
-        # scalars, so the step alone freezes the compaction saving, while
+        # scalars, so the step alone shows the compaction saving, while
         # a second compact while-loop compile would cost ~10 s of every
         # tier-1 session. The compact wave path stays differentially
         # driven against the wide oracle in tests/test_state_compaction.py
@@ -204,8 +190,8 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     }
     # The telemetry-plane step (ISSUE 16): identical geometry with
     # telemetry=1 and the TelemetryLanes pytree donated alongside the
-    # state. Registered so the lock freezes the plane's entire compiled
-    # cost — the lanes' argument bytes, ZERO new hot-loop collectives
+    # state. Registered so the plane's entire compiled cost is audited —
+    # the lanes' argument bytes, ZERO new hot-loop collectives
     # (the digest is a separate boundary dispatch, never traced here),
     # and zero host<->device transfer ops. Only the STEP is registered
     # (the step_compact convention): the telem wave shares the round
@@ -225,7 +211,7 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     }
     # The round-trace ring step (ISSUE 17): the telemetry geometry with an
     # AUDIT_TRACE_R-slot TraceRing donated alongside the state and lanes.
-    # Registered so the lock freezes the ring's entire compiled footprint —
+    # Registered so the ring's entire compiled footprint is audited —
     # its argument bytes, ZERO new hot-loop collectives (ring writes are
     # slot-local dynamic-update-slices; the digest is a boundary dispatch,
     # never traced here) and zero host<->device transfer ops. Only the STEP
@@ -300,9 +286,8 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
         # AUDIT_TENANTS independent clusters with per-tenant H/L/fd knob
         # lanes, batched into one program. These entries carry
         # ``tenant_block`` so extract_facts computes the cross-tenant
-        # replica-group count — the budget the fleet freezes at ZERO
-        # (tenants never communicate; a group spanning two tenant device
-        # blocks can never become a frozen fact).
+        # replica-group count — the budget the fleet holds at ZERO
+        # (tenants never communicate).
         from jax.sharding import NamedSharding, PartitionSpec
 
         from rapid_tpu.parallel.mesh import (
@@ -311,23 +296,12 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
             shard_fleet_state,
         )
         from rapid_tpu.tenancy.fleet import (
-            TenantFleet,
             knob_shardings,
             make_fleet_step,
             make_fleet_wave,
         )
 
-        tenants = []
-        for i in range(AUDIT_TENANTS):
-            h, l = ((3, 1), (4, 2))[i % 2]
-            tvc = VirtualCluster.create(
-                AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=h,
-                l=l, fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2,
-                seed=i,
-            )
-            tvc.assign_cohorts_roundrobin()
-            tenants.append(tvc)
-        fleet = TenantFleet.from_clusters(tenants)
+        fleet = _audit_fleet()
         mesh3d = make_mesh(jax.devices()[:AUDIT_DEVICES], shape=AUDIT_FLEET_MESH)
         fl_state = shard_fleet_state(fleet.state, mesh3d)
         fl_faults = shard_fleet_faults(fleet.faults, mesh3d)
@@ -358,152 +332,43 @@ def _build_registry() -> "Dict[str, Dict[str, Any]]":
     return registry
 
 
-#: Entrypoints :func:`build_ladder_spec` can rebuild at arbitrary geometry
-#: — the single-device dispatch surface plus the meshless vmapped fleet
-#: step. The cost-model family (tools/analysis/cost_model.py) sweeps these
-#: across its N/K/tenant ladders; the mesh-gated GSPMD entrypoints are
-#: deliberately absent (a ladder of sharded compiles would cost minutes of
-#: every tier-1 session — their base-shape facts still feed the quiescent
-#: cost block via :func:`collect_facts`).
-LADDER_ENTRYPOINTS = (
-    "step",
-    "run_to_decision",
-    "run_until_membership",
-    "sync",
-    "step_compact",
-    "step_telem",
-    "step_trace",
-    "fleet_step",
-)
+def _audit_fleet() -> Any:
+    """AUDIT_TENANTS tenant clusters at the audit geometry, two H/L knob
+    settings alternating, as one ``TenantFleet``."""
+    from rapid_tpu.models.virtual_cluster import VirtualCluster
+    from rapid_tpu.tenancy.fleet import TenantFleet
+
+    tenants = []
+    for i in range(AUDIT_TENANTS):
+        h, l = ((3, 1), (4, 2))[i % 2]
+        tvc = VirtualCluster.create(
+            AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=h, l=l,
+            fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=i,
+        )
+        tvc.assign_cohorts_roundrobin()
+        tenants.append(tvc)
+    return TenantFleet.from_clusters(tenants)
 
 
-def build_ladder_spec(
-    name: str,
-    n: int,
-    k: int,
-    c: int = AUDIT_C,
-    tenants: Optional[int] = None,
-) -> Dict[str, Any]:
-    """One registry-shaped spec (``{"jit", "args", "donated_leaves"}``) for
-    a single entrypoint at an arbitrary ``(n, k, c)`` geometry — the
-    cost-model ladder plumbing. At the audit geometry this builds exactly
-    what :func:`_build_registry` builds for the same name (the cost ladder
-    reuses the session's :func:`collect_facts` entry for that point instead
-    of recompiling); at every other point the caller compiles fresh via
-    :func:`_compile_program`. ``fleet_step`` is the MESHLESS vmapped
-    :func:`rapid_tpu.tenancy.fleet.fleet_step_impl` over ``tenants``
-    per-tenant clusters of ``n`` slots each — usable without the 8-device
-    mesh, which is what keeps the tenant ladder inside the tier-1 budget."""
+def fleet_step_spec() -> Dict[str, Any]:
+    """The MESHLESS vmapped fleet step at the audit geometry, registry
+    shaped: what single-host deployments run, and what the dataflow
+    family's tenant-isolation proof must cover beside the mesh pair. It is
+    traced, never compiled, so it is no entry of :func:`_build_registry`."""
     import jax
-    import jax.numpy as jnp
 
-    if name not in LADDER_ENTRYPOINTS:
-        raise ValueError(f"unknown ladder entrypoint {name!r}")
+    from rapid_tpu.tenancy.fleet import fleet_step_impl
 
-    from rapid_tpu.models.state import initial_telemetry, initial_trace
-    from rapid_tpu.models.virtual_cluster import (
-        VirtualCluster,
-        engine_step_impl,
-        run_to_decision_impl,
-        run_until_membership_impl,
-        sync_checksum_impl,
-    )
-
-    if name == "fleet_step":
-        from rapid_tpu.tenancy.fleet import TenantFleet, fleet_step_impl
-
-        clusters = []
-        for i in range(int(tenants or 1)):
-            h, l = ((3, 1), (4, 2))[i % 2]
-            tvc = VirtualCluster.create(
-                n - AUDIT_DEVICES, n_slots=n, k=k, h=h, l=l, fd_threshold=2,
-                cohorts=c, delivery_spread=2, seed=i,
-            )
-            tvc.assign_cohorts_roundrobin()
-            clusters.append(tvc)
-        fleet = TenantFleet.from_clusters(clusters)
-        fcfg = fleet.cfg
-        return {
-            "jit": jax.jit(
-                lambda s, f, kb: fleet_step_impl(fcfg, s, f, kb),
-                donate_argnums=(0,),
-            ),
-            "args": (fleet.state, fleet.faults, fleet.knobs),
-            "donated_leaves": len(jax.tree_util.tree_leaves(fleet.state)),
-        }
-
-    vc = VirtualCluster.create(
-        n - AUDIT_DEVICES, n_slots=n, k=k, h=3, l=1, fd_threshold=2,
-        cohorts=c, delivery_spread=2, seed=0, compact=(name == "step_compact"),
-    )
-    vc.assign_cohorts_roundrobin()
-    cfg, state, faults = vc.cfg, vc.state, vc.faults
-    state_leaves = len(jax.tree_util.tree_leaves(state))
-    if name in ("step", "step_compact"):
-        return {
-            "jit": jax.jit(
-                lambda s, f: engine_step_impl(cfg, s, f), donate_argnums=(0,)
-            ),
-            "args": (state, faults),
-            "donated_leaves": state_leaves,
-        }
-    if name == "run_to_decision":
-        return {
-            "jit": jax.jit(
-                lambda s, f: run_to_decision_impl(cfg, s, f, jnp.int32(96)),
-                donate_argnums=(0,),
-            ),
-            "args": (state, faults),
-            "donated_leaves": state_leaves,
-        }
-    if name == "run_until_membership":
-        return {
-            "jit": jax.jit(
-                lambda s, f: run_until_membership_impl(
-                    cfg, s, f, jnp.int32(n - AUDIT_DEVICES),
-                    jnp.int32(192), 8, jnp.int32(0),
-                ),
-                donate_argnums=(0,),
-            ),
-            "args": (state, faults),
-            "donated_leaves": state_leaves,
-        }
-    if name == "sync":
-        return {
-            "jit": jax.jit(sync_checksum_impl),
-            "args": (state, faults),
-            "donated_leaves": 0,
-        }
-    if name == "step_telem":
-        cfg_t = cfg._replace(telemetry=1)
-        telem = initial_telemetry(cfg_t)
-        return {
-            "jit": jax.jit(
-                lambda s, t, f: engine_step_impl(cfg_t, s, t, f),
-                donate_argnums=(0, 1),
-            ),
-            "args": (state, telem, faults),
-            "donated_leaves": (
-                state_leaves + len(jax.tree_util.tree_leaves(telem))
-            ),
-        }
-    if name == "step_trace":
-        cfg_tr = cfg._replace(telemetry=1, trace=AUDIT_TRACE_R)
-        telem = initial_telemetry(cfg_tr)
-        ring = initial_trace(cfg_tr)
-        return {
-            "jit": jax.jit(
-                lambda s, t, r, f: engine_step_impl(cfg_tr, s, t, r, f),
-                donate_argnums=(0, 1, 2),
-            ),
-            "args": (state, telem, ring, faults),
-            "donated_leaves": (
-                state_leaves
-                + len(jax.tree_util.tree_leaves(telem))
-                + len(jax.tree_util.tree_leaves(ring))
-            ),
-        }
-    raise ValueError(f"unknown ladder entrypoint {name!r}")
+    fleet = _audit_fleet()
+    fcfg = fleet.cfg
+    return {
+        "jit": jax.jit(
+            lambda s, f, kb: fleet_step_impl(fcfg, s, f, kb),
+            donate_argnums=(0,),
+        ),
+        "args": (fleet.state, fleet.faults, fleet.knobs),
+        "donated_leaves": len(jax.tree_util.tree_leaves(fleet.state)),
+    }
 
 
 # -- fact extraction --------------------------------------------------------
@@ -519,10 +384,10 @@ def extract_facts(
 ) -> Dict[str, Any]:
     """All budget-relevant facts of one compiled executable. ``rows`` holds
     the per-collective detail (the evidence-table grain); everything else
-    is the lock grain. ``tenant_block`` (devices per tenant slice, fleet
-    entrypoints only) additionally counts collectives whose replica groups
-    span tenant blocks — the ``cross_tenant_collectives`` fact the fleet
-    budget freezes at zero."""
+    is summed per location and kind. ``tenant_block`` (devices per tenant
+    slice, fleet entrypoints only) additionally counts collectives whose
+    replica groups span tenant blocks — the ``cross_tenant_collectives``
+    fact the fleet holds at zero."""
     text = compiled.as_text()
     rows = hlo_facts.audit_collectives(text, n, c)
     collectives: Dict[str, Dict[str, Any]] = {}
@@ -545,8 +410,8 @@ def extract_facts(
     try:
         analysis = compiled.memory_analysis()
     except Exception:  # noqa: BLE001 — memory analysis is platform-optional
-        # (mirrors engine_telemetry.compiled_memory_analysis); the lock
-        # simply omits the section and the comparison is presence-gated.
+        # (mirrors engine_telemetry.compiled_memory_analysis); the section
+        # is then empty.
         analysis = None
     if analysis is not None:
         memory = {
@@ -557,12 +422,14 @@ def extract_facts(
         }
     facts = {
         "collectives": collectives,
+        # Which loop the "hot-loop" keys above mean: the body of the loop
+        # around the round's own scope (tests pin it per entrypoint).
+        "round_loop": hlo_facts.round_loop(text),
         # Entry-signature bytes per dtype: the artifact-level proof of the
         # state-compaction policy (compact entrypoints carry s8/s16/u8
-        # argument lanes; the wide oracle only s32/u32/pred). Informational
-        # in the lock — argument_bytes is the exact-compared budget; an
-        # unknown dtype here surfaces through the same hlo-unknown-dtype
-        # finding as the payload accounting.
+        # argument lanes; the wide oracle only s32/u32/pred). An unknown
+        # dtype here surfaces through the same hlo-unknown-dtype finding as
+        # the payload accounting.
         "parameter_dtype_bytes": hlo_facts.entry_parameter_bytes(
             text, unknown=unknown
         ),
@@ -574,12 +441,6 @@ def extract_facts(
             "reasons": sorted(set(donation_reasons or [])),
         },
         "memory": memory,
-        # Normalized ``compiled.cost_analysis()`` (flops / bytes_accessed
-        # where the backend exposes them, None otherwise — never guessed).
-        # Informational to the HLO lock (facts_to_lock keeps its explicit
-        # key list, so this cannot perturb hlo.lock.json); budget grain for
-        # the cost-model ladder fit (tools/analysis/cost_model.py).
-        "cost": hlo_facts.compiled_cost_analysis(compiled),
         "unknown_dtypes": sorted(set(unknown)),
         "rows": rows,
     }
@@ -608,101 +469,8 @@ def _compile_program(spec: Dict[str, Any]) -> Tuple[Any, List[str]]:
 
 #: (facts, complete) — ``complete`` records whether the sharded mesh
 #: entrypoints were included, so a partial (observational) collection can
-#: never satisfy the lockfile gate's full-registry requirement.
+#: never satisfy the gate's full-registry requirement.
 _FACTS_CACHE: Optional[Tuple[Dict[str, Any], bool]] = None
-
-#: Rounds of the zero-churn telemetry soak behind the
-#: ``quiescent_round_activity`` lock fact.
-QUIESCENT_SOAK_ROUNDS = 16
-
-_TELEMETRY_FACTS_CACHE: Optional[Dict[str, int]] = None
-
-
-def collect_telemetry_facts(force: bool = False) -> Dict[str, int]:
-    """The telemetry plane's own lock block, measured live:
-
-    - ``lane_bytes_per_device`` — the TelemetryLanes argument bytes at the
-      audit geometry (single-device grain; on a mesh the [c, n] lanes split
-      by the axis sizes like the state they observe);
-    - ``quiescent_round_activity`` — every digest counter EXCEPT ``rounds``
-      summed after a :data:`QUIESCENT_SOAK_ROUNDS`-round zero-churn soak.
-      A healthy plane reads exactly ZERO here: no churn means no alerts, no
-      active subjects, no proposals, no decisions — a nonzero value is a
-      phantom-activity bug and can never be frozen (``update_hlo_lock``
-      refuses it, like a dropped donation).
-    """
-    global _TELEMETRY_FACTS_CACHE
-    if _TELEMETRY_FACTS_CACHE is not None and not force:
-        return _TELEMETRY_FACTS_CACHE
-    import numpy as np
-
-    from rapid_tpu.models.state import telemetry_bytes_total
-    from rapid_tpu.models.virtual_cluster import (
-        VirtualCluster,
-        telemetry_digest,
-    )
-    from rapid_tpu.utils.engine_telemetry import TELEMETRY_DIGEST_FIELDS
-
-    vc = VirtualCluster.create(
-        AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
-        fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
-        telemetry=True,
-    )
-    vc.assign_cohorts_roundrobin()
-    for _ in range(QUIESCENT_SOAK_ROUNDS):
-        vc.step()
-    # telemetry-fetch-ok: audit boundary — a one-off gate measurement,
-    # not an engine hot path.
-    digest = np.asarray(telemetry_digest(vc.telem))
-    rounds = int(digest[list(TELEMETRY_DIGEST_FIELDS).index("rounds")])
-    _TELEMETRY_FACTS_CACHE = {
-        "lane_bytes_per_device": int(telemetry_bytes_total(vc.cfg)),
-        "quiescent_rounds": rounds,
-        "quiescent_round_activity": int(digest.sum()) - rounds,
-    }
-    return _TELEMETRY_FACTS_CACHE
-
-
-_TRACE_FACTS_CACHE: Optional[Dict[str, int]] = None
-
-
-def collect_trace_facts(force: bool = False) -> Dict[str, int]:
-    """The round-trace ring's own lock block, measured live:
-
-    - ``ring_bytes_per_device`` — the TraceRing argument bytes at the audit
-      geometry with ``capacity`` = :data:`AUDIT_TRACE_R` slots;
-    - ``soak_cursor_delta`` — ring cursor minus the telemetry plane's round
-      counter after a :data:`QUIESCENT_SOAK_ROUNDS`-round zero-churn soak
-      (which wraps the AUDIT_TRACE_R-slot ring, so the cursor fact covers
-      rotation too). A healthy recorder reads exactly ZERO here: every
-      round writes exactly one record, wrap or no wrap — a nonzero delta
-      is a miscounting recorder and can never be frozen (``update_hlo_lock``
-      refuses it, like phantom telemetry activity).
-    """
-    global _TRACE_FACTS_CACHE
-    if _TRACE_FACTS_CACHE is not None and not force:
-        return _TRACE_FACTS_CACHE
-
-    from rapid_tpu.models.state import trace_bytes_total
-    from rapid_tpu.models.virtual_cluster import VirtualCluster
-
-    vc = VirtualCluster.create(
-        AUDIT_N - AUDIT_DEVICES, n_slots=AUDIT_N, k=AUDIT_K, h=3, l=1,
-        fd_threshold=2, cohorts=AUDIT_C, delivery_spread=2, seed=0,
-        telemetry=True, trace=AUDIT_TRACE_R,
-    )
-    vc.assign_cohorts_roundrobin()
-    for _ in range(QUIESCENT_SOAK_ROUNDS):
-        vc.step()
-    rounds = int(vc.activity["rounds"])
-    cursor = int(vc.trace["rounds_recorded"])
-    _TRACE_FACTS_CACHE = {
-        "ring_bytes_per_device": int(trace_bytes_total(vc.cfg)),
-        "capacity": AUDIT_TRACE_R,
-        "soak_cursor_delta": cursor - rounds,
-    }
-    return _TRACE_FACTS_CACHE
-
 
 def collect_facts(
     force: bool = False, require_mesh: bool = True
@@ -711,10 +479,9 @@ def collect_facts(
     process (compiles dominate the gate's cost; every consumer shares this
     cache).
 
-    ``require_mesh=True`` (the lockfile gate): raises RuntimeError when the
-    process cannot provide the 8-device mesh — the gate turns that into a
-    loud finding rather than silently passing with sharded entrypoints
-    unaudited. ``require_mesh=False`` (observational consumers, e.g. the
+    ``require_mesh=True`` (the gate): raises RuntimeError when the process
+    cannot provide the 8-device mesh, rather than silently passing with
+    sharded entrypoints unaudited. ``require_mesh=False`` (observational consumers, e.g. the
     bench's ``hlo_audit`` stage on a single-chip backend): audits whatever
     the registry can build — the four single-device entrypoints always,
     the sharded pair when devices allow. A partial collection never
@@ -751,137 +518,17 @@ def collect_facts(
     return facts
 
 
-# -- lock construction + comparison -----------------------------------------
-
-
-def facts_to_lock(
-    facts: Dict[str, Any],
-    telemetry: Optional[Dict[str, int]] = None,
-    trace: Optional[Dict[str, int]] = None,
-) -> Dict[str, Any]:
-    """The canonical freeze: per-entrypoint collectives/transfers/donation/
-    memory, minus the per-row detail (evidence grain, not budget grain).
-    ``telemetry`` (from :func:`collect_telemetry_facts`) adds the plane's
-    own block — lane bytes and the zero-churn activity fact; ``trace``
-    (from :func:`collect_trace_facts`) adds the ring block — ring bytes,
-    audit capacity, and the zero cursor-vs-rounds delta."""
-    lock: Dict[str, Any] = {
-        "audit_config": {
-            "n": AUDIT_N, "c": AUDIT_C, "k": AUDIT_K,
-            "devices": AUDIT_DEVICES,
-            "cohort_devices": AUDIT_COHORT_DEVICES,
-            "tenants": AUDIT_TENANTS,
-            "fleet_mesh": list(AUDIT_FLEET_MESH),
-        },
-        "entrypoints": {},
-    }
-    for name, entry in sorted(facts.items()):
-        donation = {
-            k: v for k, v in entry["donation"].items() if k != "reasons"
-        }
-        lock["entrypoints"][name] = {
-            "collectives": entry["collectives"],
-            "transfers": entry["transfers"],
-            "donation": donation,
-            "memory": entry["memory"],
-            "parameter_dtype_bytes": entry["parameter_dtype_bytes"],
-        }
-        if "cross_tenant_collectives" in entry:
-            lock["entrypoints"][name]["cross_tenant_collectives"] = entry[
-                "cross_tenant_collectives"
-            ]
-    if telemetry is not None:
-        lock["telemetry"] = dict(telemetry)
-    if trace is not None:
-        lock["trace"] = dict(trace)
-    return lock
-
-
-def compare_telemetry_facts(
-    current: Dict[str, int], locked: Dict[str, Any], lock_path: str
-) -> List[Finding]:
-    """Drift report for the lock's ``telemetry`` block. A nonzero
-    quiescent activity is its own finding (a phantom-activity bug — never
-    freezable); lane-byte or soak-length drift is ordinary lock drift."""
-    findings: List[Finding] = []
-    if current["quiescent_round_activity"] != 0:
-        findings.append(Finding(
-            lock_path, 1, "hlo-quiescent-activity",
-            f"telemetry plane counted "
-            f"{current['quiescent_round_activity']} unit(s) of activity "
-            f"over a {current['quiescent_rounds']}-round ZERO-churn soak — "
-            f"phantom activity; the quiescent fact is frozen at zero and "
-            f"cannot be locked in",
-        ))
-    for key in ("lane_bytes_per_device", "quiescent_rounds"):
-        if locked.get(key) != current[key]:
-            findings.append(Finding(
-                lock_path, 1, "hlo-lock-drift",
-                f"telemetry block: {key} {locked.get(key)} in the lock, "
-                f"{current[key]} now — {_REGEN_HINT}",
-            ))
-    if locked.get("quiescent_round_activity") != 0:
-        findings.append(Finding(
-            lock_path, 1, "hlo-lock-drift",
-            f"telemetry block: quiescent_round_activity must be frozen at "
-            f"0, the lock carries "
-            f"{locked.get('quiescent_round_activity')!r} — {_REGEN_HINT}",
-        ))
-    return findings
-
-
-def compare_trace_facts(
-    current: Dict[str, int], locked: Dict[str, Any], lock_path: str
-) -> List[Finding]:
-    """Drift report for the lock's ``trace`` block. A nonzero cursor delta
-    after the soak is its own finding (a miscounting recorder — never
-    freezable); ring-byte or capacity drift is ordinary lock drift."""
-    findings: List[Finding] = []
-    if current["soak_cursor_delta"] != 0:
-        findings.append(Finding(
-            lock_path, 1, "hlo-trace-cursor",
-            f"trace ring cursor drifted {current['soak_cursor_delta']} "
-            f"record(s) from the telemetry round counter over the "
-            f"zero-churn soak — every round must write exactly one record; "
-            f"the cursor fact is frozen at zero and cannot be locked in",
-        ))
-    for key in ("ring_bytes_per_device", "capacity"):
-        if locked.get(key) != current[key]:
-            findings.append(Finding(
-                lock_path, 1, "hlo-lock-drift",
-                f"trace block: {key} {locked.get(key)} in the lock, "
-                f"{current[key]} now — {_REGEN_HINT}",
-            ))
-    if locked.get("soak_cursor_delta") != 0:
-        findings.append(Finding(
-            lock_path, 1, "hlo-lock-drift",
-            f"trace block: soak_cursor_delta must be frozen at 0, the lock "
-            f"carries {locked.get('soak_cursor_delta')!r} — {_REGEN_HINT}",
-        ))
-    return findings
-
-
-def _within_tolerance(locked: int, current: int) -> bool:
-    slack = max(int(locked * MEMORY_REL_TOL), MEMORY_ABS_SLACK)
-    return abs(current - locked) <= slack
-
-
-def compare_facts(
+def live_findings(
     name: str,
     entry: Dict[str, Any],
-    locked: Dict[str, Any],
     loc: Tuple[str, int],
+    waiver: Optional[str] = None,
 ) -> List[Finding]:
-    """Budget-drift report for ONE entrypoint against its locked facts,
-    each finding naming the entrypoint and the delta. Sections present in
-    the lock are enforced; absent sections are skipped (the corpus locks
-    pin only the facts each defect class is about)."""
+    """What is wrong with ONE compiled program whatever it is compared
+    with: a dtype the payload accounting cannot size, a collective that
+    crosses tenants, a donated buffer dropped without a waiver."""
     path, lineno = loc
     findings: List[Finding] = []
-
-    def fail(check: str, message: str) -> None:
-        findings.append(Finding(path, lineno, check, f"{message} — {_REGEN_HINT}"))
-
     if entry["unknown_dtypes"]:
         findings.append(Finding(
             path, lineno, "hlo-unknown-dtype",
@@ -890,28 +537,52 @@ def compare_facts(
             f"payload accounting cannot size them; add the dtype, do not "
             f"guess",
         ))
-
-    # The fleet's hard budget: tenants never communicate. A collective
-    # whose replica groups span tenant device blocks is a finding in its
-    # own right — never freezable (update_hlo_lock refuses it, like a
-    # dropped donation).
+    # The fleet's hard budget: tenants never communicate.
     cross = entry.get("cross_tenant_collectives")
     if cross:
         findings.append(Finding(
             path, lineno, "hlo-cross-tenant-collective",
             f"{name}: {cross} collective(s) carry the tenant axis in their "
             f"replica groups — tenants must never communicate; fix the "
-            f"batched program (this budget is frozen at ZERO and cannot be "
-            f"locked in)",
+            f"batched program (this budget is ZERO)",
         ))
-    elif (
-        "cross_tenant_collectives" in locked
-        and locked["cross_tenant_collectives"] != (cross or 0)
-    ):
+    donation = entry["donation"]
+    if donation["dropped"] > 0 and not (donation.get("waiver") or waiver):
+        reasons = "; ".join(donation.get("reasons", [])) or "no XLA reason captured"
+        findings.append(Finding(
+            path, lineno, "hlo-donation-dropped",
+            f"{name}: {donation['dropped']} of {donation['donated_leaves']} "
+            f"donated buffer(s) NOT aliased in the compiled output "
+            f"({reasons}) — donation silently dropped; fix the "
+            f"entrypoint or add an explicit registry waiver",
+        ))
+    return findings
+
+
+def compare_facts(
+    name: str,
+    entry: Dict[str, Any],
+    locked: Dict[str, Any],
+    loc: Tuple[str, int],
+) -> List[Finding]:
+    """The corpus comparison: ONE compiled program against the claim its
+    module makes inline (``HLO_LOCK``), each finding naming the entrypoint
+    and the delta, after :func:`live_findings`. Sections present in the
+    claim are enforced; absent sections are skipped (the corpus claims pin
+    only the facts each defect class is about)."""
+    path, lineno = loc
+    findings = live_findings(
+        name, entry, loc, waiver=locked.get("donation", {}).get("waiver")
+    )
+
+    def fail(check: str, message: str) -> None:
+        findings.append(Finding(path, lineno, check, f"{message} — {_REGEN_HINT}"))
+
+    cross = entry.get("cross_tenant_collectives") or 0
+    if not cross and locked.get("cross_tenant_collectives", 0) != 0:
         fail("hlo-lock-drift",
              f"{name}: cross_tenant_collectives "
-             f"{locked['cross_tenant_collectives']} in the lock, "
-             f"{cross or 0} now")
+             f"{locked['cross_tenant_collectives']} claimed, 0 now")
 
     if "collectives" in locked:
         cur = entry["collectives"]
@@ -924,12 +595,12 @@ def compare_facts(
                 fail("hlo-collective-budget",
                      f"{name}: {hot} {kind} in location {location} "
                      f"({cur[key]['count']} op(s), {cur[key]['bytes']} bytes, "
-                     f"class {cur[key]['class']}) not in the HLO lock")
+                     f"class {cur[key]['class']}) not in the claim")
             elif key not in cur:
                 fail("hlo-collective-budget",
                      f"{name}: collective {kind} in location {location} "
-                     f"vanished since the HLO lock (was "
-                     f"{old[key]['count']} op(s), {old[key]['bytes']} bytes)")
+                     f"vanished (claimed {old[key]['count']} op(s), "
+                     f"{old[key]['bytes']} bytes)")
             else:
                 rank_old = hlo_facts.PAYLOAD_CLASS_RANK[old[key]["class"]]
                 rank_cur = hlo_facts.PAYLOAD_CLASS_RANK[cur[key]["class"]]
@@ -962,135 +633,49 @@ def compare_facts(
     if "donation" in locked:
         cur_d = entry["donation"]
         old_d = locked["donation"]
-        waiver = cur_d.get("waiver") or old_d.get("waiver")
-        if cur_d["dropped"] > 0 and not waiver:
-            reasons = "; ".join(cur_d.get("reasons", [])) or "no XLA reason captured"
-            findings.append(Finding(
-                path, lineno, "hlo-donation-dropped",
-                f"{name}: {cur_d['dropped']} of {cur_d['donated_leaves']} "
-                f"donated buffer(s) NOT aliased in the compiled output "
-                f"({reasons}) — donation silently dropped; fix the "
-                f"entrypoint or add an explicit registry waiver",
-            ))
-        elif (cur_d["donated_leaves"], cur_d["aliased"]) != (
+        dropped = any(f.check == "hlo-donation-dropped" for f in findings)
+        if not dropped and (cur_d["donated_leaves"], cur_d["aliased"]) != (
             old_d.get("donated_leaves"), old_d.get("aliased")
         ):
             fail("hlo-lock-drift",
                  f"{name}: donation outcome drift: "
                  f"{old_d.get('aliased')}/{old_d.get('donated_leaves')} "
-                 f"aliased in the lock, "
+                 f"aliased in the claim, "
                  f"{cur_d['aliased']}/{cur_d['donated_leaves']} now")
-
-    if "memory" in locked and locked["memory"] and entry["memory"]:
-        cur_m = entry["memory"]
-        old_m = locked["memory"]
-        for key in _EXACT_MEMORY_KEYS:
-            if key in old_m and cur_m.get(key) != old_m[key]:
-                fail("hlo-memory-budget",
-                     f"{name}: {key} {old_m[key]} -> {cur_m.get(key)}")
-        for key in _TOLERANT_MEMORY_KEYS:
-            if key in old_m and not _within_tolerance(
-                old_m[key], cur_m.get(key, 0)
-            ):
-                direction = (
-                    "GREW" if cur_m.get(key, 0) > old_m[key] else "shrank"
-                )
-                fail("hlo-memory-budget",
-                     f"{name}: {key} {direction} beyond tolerance: "
-                     f"{old_m[key]} -> {cur_m.get(key)} (allowed ±"
-                     f"{max(int(old_m[key] * MEMORY_REL_TOL), MEMORY_ABS_SLACK)}"
-                     f" bytes)")
-    return findings
-
-
-def compare_lock(
-    facts: Dict[str, Any], locked: Dict[str, Any], lock_path: str
-) -> List[Finding]:
-    findings: List[Finding] = []
-    locked_eps: Dict[str, Any] = locked.get("entrypoints", {})
-    for name in sorted(set(facts) | set(locked_eps)):
-        if name not in locked_eps:
-            findings.append(Finding(
-                lock_path, 1, "hlo-lock-drift",
-                f"entrypoint {name} compiled but has no entry in the HLO "
-                f"lock — {_REGEN_HINT}",
-            ))
-        elif name not in facts:
-            findings.append(Finding(
-                lock_path, 1, "hlo-lock-drift",
-                f"entrypoint {name} is in the HLO lock but no longer "
-                f"registered — {_REGEN_HINT}",
-            ))
-        else:
-            findings.extend(
-                compare_facts(name, facts[name], locked_eps[name], (lock_path, 1))
-            )
     return findings
 
 
 # -- tree-mode gate ----------------------------------------------------------
 
 
-def check_hlo_lock(trees: Sequence[Tuple[ast.AST, str]]) -> List[Finding]:
-    """Tree-mode gate the driver runs on full sweeps: compile the registered
-    entrypoints (session-cached) and compare against the committed lock.
-    Presence-gated on the engine sources being part of the sweep, so tests
-    that retarget ``core.REPO`` at temporary trees never pay a compile."""
+def covers_registry(trees: Sequence[Tuple[ast.AST, str]]) -> bool:
+    """Whether a sweep holds the engine sources the registry compiles: the
+    presence gate of this family's tree mode and the dataflow family's."""
     rels = {rel.replace("\\", "/") for _, rel in trees}
-    if not all(src in rels for src in REGISTRY_SOURCES):
+    return all(src in rels for src in REGISTRY_SOURCES)
+
+
+def check_compiled_programs(
+    trees: Sequence[Tuple[ast.AST, str]],
+) -> List[Finding]:
+    """Tree-mode gate the driver runs on full sweeps: compile the registered
+    entrypoints (session-cached) and report, from the live facts alone,
+    :func:`live_findings` and any host<->device transfer op. Presence-gated
+    on the engine sources being part of the sweep, so tests that retarget
+    ``core.REPO`` at temporary trees never pay a compile."""
+    if not covers_registry(trees):
         return []
-    try:
-        facts = collect_facts()
-    except RuntimeError as exc:
-        return [Finding(HLO_LOCK_REL, 1, "hlo-lock-drift",
-                        f"cannot audit compiled programs: {exc}")]
-    lock_path = core.REPO / HLO_LOCK_REL
-    if not lock_path.exists():
-        return [Finding(
-            HLO_LOCK_REL, 1, "hlo-lock-drift",
-            "HLO lockfile missing — generate it via "
-            "`python tools/staticcheck.py --update-hlo-lock`",
-        )]
-    try:
-        locked = json.loads(lock_path.read_text())
-    except json.JSONDecodeError as exc:
-        return [Finding(
-            HLO_LOCK_REL, 1, "hlo-lock-drift",
-            f"HLO lockfile is not valid JSON ({exc.msg}) — regenerate via "
-            f"`python tools/staticcheck.py --update-hlo-lock`",
-        )]
-    audit_cfg = {"n": AUDIT_N, "c": AUDIT_C, "k": AUDIT_K,
-                 "devices": AUDIT_DEVICES,
-                 "cohort_devices": AUDIT_COHORT_DEVICES,
-                 "tenants": AUDIT_TENANTS,
-                 "fleet_mesh": list(AUDIT_FLEET_MESH)}
-    if locked.get("audit_config") != audit_cfg:
-        return [Finding(
-            HLO_LOCK_REL, 1, "hlo-lock-drift",
-            f"HLO lock audit_config {locked.get('audit_config')} does not "
-            f"match the registry's {audit_cfg} — {_REGEN_HINT}",
-        )]
-    findings = compare_lock(facts, locked, HLO_LOCK_REL)
-    if "telemetry" not in locked:
-        findings.append(Finding(
-            HLO_LOCK_REL, 1, "hlo-lock-drift",
-            f"HLO lock carries no telemetry block (lane bytes + the "
-            f"zero-churn quiescent fact) — {_REGEN_HINT}",
-        ))
-    else:
-        findings.extend(compare_telemetry_facts(
-            collect_telemetry_facts(), locked["telemetry"], HLO_LOCK_REL
-        ))
-    if "trace" not in locked:
-        findings.append(Finding(
-            HLO_LOCK_REL, 1, "hlo-lock-drift",
-            f"HLO lock carries no trace block (ring bytes + the zero "
-            f"cursor-vs-rounds soak fact) — {_REGEN_HINT}",
-        ))
-    else:
-        findings.extend(compare_trace_facts(
-            collect_trace_facts(), locked["trace"], HLO_LOCK_REL
-        ))
+    loc = (REGISTRY_REL, 1)
+    findings: List[Finding] = []
+    for name, entry in sorted(collect_facts().items()):
+        findings.extend(live_findings(name, entry, loc))
+        for op, count in sorted(entry["transfers"].items()):
+            findings.append(Finding(
+                loc[0], loc[1], "hlo-transfer-budget",
+                f"{name}: {count} host<->device transfer op(s) {op} in a "
+                f"registered engine program — a dispatch must not hold a "
+                f"host round-trip",
+            ))
     return findings
 
 
@@ -1098,9 +683,8 @@ def compaction_differential_ok() -> Optional[str]:
     """Run a small mixed crash+join scenario through the WIDE engine and
     the COMPACT engine (same geometry/seed) and compare the widened compact
     state leaf-for-leaf. Returns None on bit-identity, else a message
-    naming the first divergent lane. ``update_hlo_lock`` refuses to freeze
-    new memory budgets while this disagrees: a compact layout that has
-    drifted from its oracle must be fixed, not locked in."""
+    naming the first divergent lane (``tests/test_hlo_gate.py`` holds it
+    to None)."""
     import numpy as np
 
     from rapid_tpu.models.state import widen_state
@@ -1125,8 +709,7 @@ def compaction_differential_ok() -> Optional[str]:
         if a.dtype != b.dtype or not (a == b).all():
             return (
                 f"wide<->compact differential disagrees on state lane "
-                f"{field!r} (crash+join scenario at n=64) — fix the "
-                f"compaction layer before regenerating the lock"
+                f"{field!r} (crash+join scenario at n=64)"
             )
     if wide.config_id != compact.config_id:
         return "wide<->compact differential disagrees on the configuration id"
@@ -1137,11 +720,10 @@ def trace_differential_ok() -> Optional[str]:
     """Run the compaction differential's crash+join scenario through the
     telemetry engine with the trace ring OFF and ON (same geometry/seed)
     and compare state AND telemetry leaf-for-leaf. Returns None on
-    bit-identity, else a message naming the first divergent lane.
-    ``update_hlo_lock`` refuses while this disagrees: the ring is
-    write-only by construction, so a trace knob that perturbs the engine
-    or its telemetry is a recorder bug that must be fixed, not locked
-    in."""
+    bit-identity, else a message naming the first divergent lane
+    (``tests/test_hlo_gate.py`` holds it to None): the ring is write-only
+    by construction, so a trace knob that perturbs the engine or its
+    telemetry is a recorder bug."""
     import numpy as np
 
     from rapid_tpu.models.virtual_cluster import VirtualCluster
@@ -1169,8 +751,7 @@ def trace_differential_ok() -> Optional[str]:
                 return (
                     f"trace-on<->trace-off differential disagrees on "
                     f"{label} lane {field!r} (crash+join scenario at n=64) "
-                    f"— the ring must be write-only; fix the trace layer "
-                    f"before regenerating the lock"
+                    f"— the ring must be write-only"
                 )
     if off.config_id != on.config_id:
         return (
@@ -1178,69 +759,6 @@ def trace_differential_ok() -> Optional[str]:
             "configuration id"
         )
     return None
-
-
-def update_hlo_lock() -> Tuple[List[Finding], Optional[Path]]:
-    """Regenerate the lockfile from freshly-collected facts. Refuses while
-    an unknown dtype, an unwaived dropped donation, a wide<->compact state
-    differential disagreement, or a trace-on<->trace-off differential
-    disagreement is present — a budget the gate would immediately fail (or
-    a compact layout / trace ring that no longer matches its oracle) must
-    be fixed, not frozen."""
-    try:
-        facts = collect_facts()
-    except RuntimeError as exc:
-        return [Finding(HLO_LOCK_REL, 1, "hlo-lock-drift", str(exc))], None
-    blocking: List[Finding] = []
-    for name, entry in sorted(facts.items()):
-        blocking.extend(
-            f for f in compare_facts(name, entry, {"donation": {}}, (HLO_LOCK_REL, 1))
-            if f.check in ("hlo-unknown-dtype", "hlo-donation-dropped",
-                           "hlo-cross-tenant-collective")
-        )
-    mismatch = compaction_differential_ok()
-    if mismatch:
-        blocking.append(Finding(HLO_LOCK_REL, 1, "hlo-lock-drift", mismatch))
-    mismatch_tr = trace_differential_ok()
-    if mismatch_tr:
-        blocking.append(Finding(HLO_LOCK_REL, 1, "hlo-lock-drift", mismatch_tr))
-    telem_facts = collect_telemetry_facts()
-    if telem_facts["quiescent_round_activity"] != 0:
-        # A zero-churn soak with nonzero activity counters is a telemetry
-        # bug, not a fact to freeze.
-        blocking.append(Finding(
-            HLO_LOCK_REL, 1, "hlo-quiescent-activity",
-            f"refusing to freeze quiescent_round_activity="
-            f"{telem_facts['quiescent_round_activity']} — the zero-churn "
-            f"soak must read exactly zero activity",
-        ))
-    trace_facts = collect_trace_facts()
-    if trace_facts["soak_cursor_delta"] != 0:
-        # A ring whose cursor disagrees with the round counter is a
-        # recorder bug, not a fact to freeze.
-        blocking.append(Finding(
-            HLO_LOCK_REL, 1, "hlo-trace-cursor",
-            f"refusing to freeze soak_cursor_delta="
-            f"{trace_facts['soak_cursor_delta']} — every soak round must "
-            f"write exactly one trace record",
-        ))
-    if blocking:
-        return blocking, None
-    lock_path = core.REPO / HLO_LOCK_REL
-    payload = {
-        "_comment": (
-            "Frozen compiled-program facts for the registered engine "
-            "entrypoints on the forced 8-device CPU mesh: collectives by "
-            "location/kind (count, payload bytes, scale class), "
-            "host<->device transfer ops, donation outcomes, and XLA memory "
-            "analysis. Generated by `python tools/staticcheck.py "
-            "--update-hlo-lock`; do not edit by hand — any drift from the "
-            "live compiled artifacts fails the staticcheck gate."
-        ),
-        **facts_to_lock(facts, telemetry=telem_facts, trace=trace_facts),
-    }
-    lock_path.write_text(json.dumps(payload, indent=2, sort_keys=True) + "\n")
-    return [], lock_path
 
 
 # -- per-file mode (the seeded lint corpus) ---------------------------------

@@ -28,14 +28,13 @@ from rapid_tpu.parallel.hlo_facts import (  # noqa: E402,F401 — re-exported
     classify_location,
     collective_groups,
     collective_violations,
-    compiled_cost_analysis,
     count_transfer_ops,
     entry_parameter_bytes,
     groups_cross_blocks,
     input_output_aliases,
     payload_class,
+    round_loop,
     shape_bytes,
-    shape_operand_bytes,
     source_of,
 )
 
@@ -48,13 +47,12 @@ __all__ = [
     "classify_location",
     "collective_violations",
     "collective_groups",
-    "compiled_cost_analysis",
     "count_transfer_ops",
     "entry_parameter_bytes",
     "groups_cross_blocks",
     "input_output_aliases",
     "payload_class",
+    "round_loop",
     "shape_bytes",
-    "shape_operand_bytes",
     "source_of",
 ]

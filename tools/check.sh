@@ -13,43 +13,22 @@ echo "== static checks (AST lint + resolution tier + compiled-program gate) =="
 # device_program._build_registry), the multi-tenant fleet pair on the
 # 3-D ('tenant','cohort','nodes') mesh (fleet3d_step/fleet3d_wave, the
 # zero-cross-tenant-collective budget), and the compact-state step
-# (step_compact — the memory budget that freezes the dtype-narrowing
-# saving; one representative per the PR-9 compile-cost convention) —
-# so the lint/staticcheck tree
-# sweeps in the same session reuse the facts instead of recompiling.
-#
-# Memory-budget regen after a compaction-policy change: run
-#   python tools/staticcheck.py --update-hlo-lock
-# (under XLA_FLAGS=--xla_force_host_platform_device_count=8). It refuses
-# while the wide<->compact state differential disagrees — a compact layout
-# that drifted from its oracle must be fixed, never frozen into the lock.
-#
-# test_cost_model.py rides immediately after the HLO gate: the scaling-law
-# cost ladder (ISSUE 18, cost.lock.json) reuses the gate's session-cached
-# base compiles, and the tree sweeps in test_lint/test_staticcheck then
-# fit over the cached ladder instead of recompiling. Scaling-class regen
-# after an intentional asymptotics change:
-#   python tools/staticcheck.py --update-cost-lock
-# It refuses while any fit is unexplained or any fact exceeds its O(N*K)
-# ceiling — an unexplained or superlinear cost must be fixed, never frozen.
-#
-# test_dataflow.py rides immediately after the cost-model gate: the jaxpr
-# provenance proofs (ISSUE 19, dataflow.lock.json) trace compile-free and
-# their byte-pricing join reuses the same session-cached compiles. Regen
-# after an intentional influence-structure change:
-#   python tools/staticcheck.py --update-dataflow-lock
-# It refuses while any proof fails — an observer leak, a cross-tenant
-# edge, or an opportunity map that stops explaining the quiescent bytes
-# must be fixed, never frozen.
-python -m pytest tests/test_hlo_gate.py tests/test_cost_model.py tests/test_dataflow.py tests/test_lint.py tests/test_staticcheck.py -q -p no:randomly
+# (step_compact — the dtype-narrowing saving, read off the compiled
+# artifact; one representative per the PR-9 compile-cost convention) —
+# so test_dataflow.py's compile-free trace of the same registry and the
+# lint/staticcheck tree sweeps in the same session reuse the facts instead
+# of recompiling. Nothing here is compared with a committed number: the
+# cases assert on the live programs, and a PR shows that it changed no
+# program with `python tools/program_digests.py digests` on both checkouts
+# and `diff PARENT.json OUT.json`.
+python -m pytest tests/test_hlo_gate.py tests/test_dataflow.py tests/test_lint.py tests/test_staticcheck.py -q -p no:randomly
 
 echo "== full suite (CPU, 8 virtual devices) =="
 # The static gates just ran above; the resolution tier re-imports and
 # re-analyzes the whole tree, so don't pay it twice in one invocation.
 python -m pytest tests/ -q \
   --ignore=tests/test_lint.py --ignore=tests/test_staticcheck.py \
-  --ignore=tests/test_hlo_gate.py --ignore=tests/test_cost_model.py \
-  --ignore=tests/test_dataflow.py
+  --ignore=tests/test_hlo_gate.py --ignore=tests/test_dataflow.py
 
 echo "== driver gates =="
 XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \

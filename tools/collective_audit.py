@@ -18,9 +18,9 @@ family (tools/analysis/device_program.py): classification lives in
 ``rapid_tpu/parallel/hlo_facts.py`` (re-exported by rapid_tpu/parallel/audit.py,
 pinned by tests/test_parallel.py), fact extraction — including donation
 outcomes and XLA memory analysis — in ``device_program.extract_facts``. The
-difference from the committed gate: the gate compiles at fixed small audit
-shapes and freezes the facts into ``hlo.lock.json``; this tool compiles at
-evidence scale (10K+ slots) and writes the full table.
+difference from the gate: the gate compiles at fixed small audit shapes
+and asserts on the live facts; this tool compiles at evidence scale (10K+
+slots) and writes the full table.
 
     python tools/collective_audit.py [--n 10240] [--devices 8] \
         [--cohort-devices 2] [--out FILE]

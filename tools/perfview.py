@@ -34,13 +34,11 @@ runs, and can I trust the numbers". Two input kinds, freely mixed:
   ``recovery_status``), and ``activity-missing`` (same discipline for the
   device telemetry plane: an audited round omitting BOTH
   ``stream_active_fraction`` and ``activity_status`` — a zero-churn soak
-  must publish ``activity=0`` explicitly, never silence), and
-  ``cost-missing`` (same discipline for the scaling-law cost model: an
-  audited round omitting the ``cost_fit`` table AND its status marker).
-  The N1M, FLEET, STREAM, CHAOS, MEM, RECOVERY, ACTIVITY, and COSTFIT
+  must publish ``activity=0`` explicitly, never silence).
+  The N1M, FLEET, STREAM, CHAOS, MEM, RECOVERY, and ACTIVITY
   columns render the headline / fleet / sustained-stream /
-  chaos-throughput / bytes-per-member / resume-MTTR / active-fraction /
-  worst-fitted-scaling-class values (or their status markers) per round.
+  chaos-throughput / bytes-per-member / resume-MTTR / active-fraction
+  values (or their status markers) per round.
 
 ``--chrome out.json`` additionally writes Chrome trace-event JSON (the same
 envelope tools/traceview.py emits — Perfetto/chrome://tracing load it):
@@ -259,7 +257,11 @@ def hlo_drift(prev: Optional[Dict[str, Any]],
               cur: Optional[Dict[str, Any]]) -> bool:
     """True when two audited rounds disagree on any shared entrypoint's
     collective counts — the compiled communication budget moved between
-    rounds (intentionally or not: the trajectory must show it either way)."""
+    rounds (intentionally or not: the trajectory must show it either way).
+    ``hot_loop_collectives`` counts the round loop alone since PR 47 (the
+    loop around the round's ``fd_tick`` scope, ``hlo_facts.round_loop``);
+    rounds recorded before it counted every loop level, so the first round
+    audited after it drifts on the two mesh waves for that reason alone."""
     if not prev or not cur:
         return False
     for name in set(prev) & set(cur):
@@ -375,20 +377,6 @@ def point_flags(
         and not data.get("trace_status")
     ):
         flags.append("trace-missing")
-    # Cost-model discipline (ISSUE 18): same rule for the scaling-law
-    # axis — an audited round must carry the cost_fit table (fitted
-    # per-entrypoint scaling classes) or its explicit status marker
-    # (suppressed ladder / unavailable backend). Pre-audit historical
-    # rounds are exempt.
-    if hlo_audit_table(data) is not None and not data.get("cost_fit"):
-        flags.append("cost-missing")
-    # Dataflow-provenance discipline (ISSUE 19): same rule for the jaxpr
-    # proof axis — an audited round must carry the dataflow block (proof
-    # verdicts + opportunity coverage, or its explicit suppressed/
-    # unavailable status inside it). Pre-provenance historical rounds are
-    # exempt.
-    if hlo_audit_table(data) is not None and not data.get("dataflow"):
-        flags.append("dataflow-missing")
     if hlo_drift(prev, hlo_audit_table(data)):
         flags.append("hlo-drift")
     if not flags:
@@ -528,69 +516,10 @@ def trace_cell(data: Dict[str, Any]) -> str:
     return str(status) if status else "-"
 
 
-#: Scaling-class vocabulary, weakest to strongest — mirrors
-#: tools/analysis/cost_model.CLASSES (perfview stays import-light; the
-#: spelling is part of the bench artifact contract). Classes this tool
-#: does not know sort WORST — a future stronger class must never render
-#: as better than the ones it replaced.
-_COST_CLASS_ORDER = ("O(1)", "O(log N)", "O(N)", "O(N*K)", "O(N^2)")
-
-
-def cost_cell(data: Dict[str, Any]) -> str:
-    """The COSTFIT column: the WORST fitted scaling class across the
-    round's audited entrypoints (with the quiescent round's collective
-    payload beside it when measured), else the explicit cost_fit status
-    marker, else '-' (pre-cost rounds)."""
-    fit = data.get("cost_fit")
-    if isinstance(fit, dict) and "status" in fit:
-        return str(fit["status"])
-    if isinstance(fit, dict) and fit:
-        classes = [
-            cls for per in fit.values() if isinstance(per, dict)
-            for cls in per.values()
-        ]
-        if classes:
-            worst = max(
-                classes,
-                key=lambda cls: (
-                    _COST_CLASS_ORDER.index(cls)
-                    if cls in _COST_CLASS_ORDER else len(_COST_CLASS_ORDER)
-                ),
-            )
-            quiescent = data.get("quiescent_round_cost") or {}
-            payload = quiescent.get("collective_payload_bytes")
-            suffix = (
-                f" q={int(payload)}B" if isinstance(payload, (int, float))
-                else ""
-            )
-            return f"worst={worst}{suffix}"
-    return "-"
-
-
-def oppty_cell(data: Dict[str, Any]) -> str:
-    """The OPPTY column: the sparse-opportunity map's coverage of the
-    quiescent payload bytes with the proof verdicts beside it (ok = both
-    observer-silence and tenant-isolation proven), else the explicit
-    dataflow status marker, else '-' (pre-provenance rounds)."""
-    df = data.get("dataflow")
-    if not isinstance(df, dict):
-        return "-"
-    coverage = df.get("opportunity_coverage_pct")
-    if isinstance(coverage, (int, float)):
-        proofs = (
-            "ok" if df.get("observer_silent")
-            and df.get("tenant_isolated") is not False
-            else "LEAK"
-        )
-        return f"{float(coverage):.0f}%/{proofs}"
-    status = df.get("opportunity_status") or df.get("status")
-    return str(status) if status else "-"
-
-
 def render_trajectory(points: List[Tuple[str, Dict[str, Any]]]) -> str:
     lines = ["== perf trajectory =="]
     header = ("ROUND", "METRIC", "VALUE", "N1M", "FLEET", "STREAM", "CHAOS",
-              "MEM", "RECOVERY", "ACTIVITY", "TRACE", "COSTFIT", "OPPTY",
+              "MEM", "RECOVERY", "ACTIVITY", "TRACE",
               "PLATFORM", "VSBASE", "FLAGS")
     rows: List[Tuple[str, ...]] = []
     flag_rows: List[Tuple[str, List[str]]] = []
@@ -614,8 +543,6 @@ def render_trajectory(points: List[Tuple[str, Dict[str, Any]]]) -> str:
             recovery_cell(data),
             activity_cell(data),
             trace_cell(data),
-            cost_cell(data),
-            oppty_cell(data),
             str(data.get("platform", "-")),
             "-" if vs is None else f"{float(vs):.2f}x"
             + ("@capture" if "vs_baseline_at_capture" in data else ""),

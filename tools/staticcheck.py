@@ -29,14 +29,11 @@ from analysis import (  # noqa: E402,F401 — re-exported API surface
     ALL_CHECK_NAMES,
     CLOCK_DISCIPLINE_PREFIXES,
     CONCURRENCY_PREFIXES,
-    COST_LOCK_REL,
-    DATAFLOW_LOCK_REL,
     DEFAULT_ROOTS,
     DETERMINISM_PREFIXES,
     DISPATCH_PREFIXES,
     FAMILIES,
     Finding,
-    HLO_LOCK_REL,
     LEDGER_PREFIXES,
     LOCK_REL,
     SHARDING_PREFIXES,
@@ -49,16 +46,14 @@ from analysis import (  # noqa: E402,F401 — re-exported API surface
     check_call_signatures,
     check_chaosvocab,
     check_clock_injection,
+    check_compiled_programs,
     check_concurrency,
-    check_cost_lock,
-    check_cost_model,
     check_dataflow,
-    check_dataflow_lock,
+    check_dataflow_proofs,
     check_dead_definitions,
     check_determinism,
     check_device_program,
     check_dispatch,
-    check_hlo_lock,
     check_lane_mirror,
     check_ledger,
     check_partition_specs,
@@ -71,14 +66,9 @@ from analysis import (  # noqa: E402,F401 — re-exported API surface
     check_wire_schema,
     collect_dataflow,
     collect_facts,
-    collect_ladder,
-    fit_scaling,
     iter_files,
     main,
     run,
-    update_cost_lock,
-    update_dataflow_lock,
-    update_hlo_lock,
     update_wire_lock,
 )
 
@@ -90,14 +80,11 @@ __all__ = [
     "ALL_CHECK_NAMES",
     "CLOCK_DISCIPLINE_PREFIXES",
     "CONCURRENCY_PREFIXES",
-    "COST_LOCK_REL",
-    "DATAFLOW_LOCK_REL",
     "DEFAULT_ROOTS",
     "DETERMINISM_PREFIXES",
     "DISPATCH_PREFIXES",
     "FAMILIES",
     "Finding",
-    "HLO_LOCK_REL",
     "LEDGER_PREFIXES",
     "LOCK_REL",
     "REPO",
@@ -111,16 +98,14 @@ __all__ = [
     "check_call_signatures",
     "check_chaosvocab",
     "check_clock_injection",
+    "check_compiled_programs",
     "check_concurrency",
-    "check_cost_lock",
-    "check_cost_model",
     "check_dataflow",
-    "check_dataflow_lock",
+    "check_dataflow_proofs",
     "check_dead_definitions",
     "check_determinism",
     "check_device_program",
     "check_dispatch",
-    "check_hlo_lock",
     "check_lane_mirror",
     "check_ledger",
     "check_partition_specs",
@@ -133,15 +118,10 @@ __all__ = [
     "check_wire_schema",
     "collect_dataflow",
     "collect_facts",
-    "collect_ladder",
     "core",
-    "fit_scaling",
     "iter_files",
     "main",
     "run",
-    "update_cost_lock",
-    "update_dataflow_lock",
-    "update_hlo_lock",
     "update_wire_lock",
 ]
 
