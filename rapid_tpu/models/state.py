@@ -548,6 +548,7 @@ TELEMETRY_LANE_SPECS: Dict[str, Tuple[str, ...]] = {
     "tl_fast_decisions": (),
     "tl_classic_decisions": (),
     "tl_conflict_rounds": (),
+    "tl_dissent": (),
     "tl_invalidation_rounds": (),
     "tl_invalidation_dense_rounds": (),
     "tl_undecided_hist": ("b",),
@@ -589,6 +590,11 @@ class TelemetryLanes(NamedTuple):
     # decide — the per-tenant conflict-rate numerator ("The Performance of
     # Paxos and Fast Paxos": the fast path's win hinges on collision rate).
     tl_conflict_rounds: jnp.ndarray  # [] int32
+    # Cohorts that, at a decision, had announced a cut other than the decided
+    # one, summed over decisions: the paper's own conflict count (Fig. 11
+    # counts the receivers whose announced cut missed a victim), where
+    # ``tl_conflict_rounds`` counts the ROUNDS the fast path stood undecided.
+    tl_dissent: jnp.ndarray  # [] int32
     # Rounds in which this cluster (this tenant) needed the implicit-
     # invalidation arm (some cohort had a subject in flux after a DOWN
     # report), and those of them in which it took the DENSE loop over all n

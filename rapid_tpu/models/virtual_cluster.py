@@ -296,6 +296,36 @@ def _deliver_alerts(cfg: EngineConfig, state: EngineState, fire_round, blocked_r
     return new_bits
 
 
+def delivery_delays(cfg: EngineConfig, config_epoch, slots) -> jnp.ndarray:
+    """``int32[c, len(slots), k]``: the rounds after its firing at which the
+    alert of edge (``slots[i]``, ring) reaches cohort c in configuration
+    ``config_epoch``: the schedule :func:`_deliver_alerts` (and the Mosaic
+    kernel, hash-stream identical to it) follows, handed out as data.
+
+    Eager and for set-up alone: a plain reference that replays the detector
+    (``benchmarks/detector_model.py``) is handed the network's schedule
+    through it. No round program calls it; ``tests/test_grid_fleet.py`` holds
+    it to ``_deliver_alerts``' delivered bits round by round."""
+    slots = jnp.asarray(slots, dtype=jnp.uint32).reshape(-1)
+    shape = (cfg.c, slots.shape[0], cfg.k)
+    if cfg.delivery_spread <= 0:
+        return jnp.zeros(shape, dtype=jnp.int32)
+    rings = jnp.arange(cfg.k, dtype=jnp.uint32)
+    rnd = mix32(
+        (jnp.arange(cfg.c, dtype=jnp.uint32) * jnp.uint32(0x9E3779B1))[:, None, None]
+        ^ (slots * jnp.uint32(0x85EBCA77))[None, :, None]
+        ^ (rings * jnp.uint32(0xC2B2AE3D))[None, None, :]
+        ^ (jnp.asarray(config_epoch).astype(jnp.uint32) * jnp.uint32(0x27D4EB2F))
+    )
+    if cfg.delivery_prob_permille >= 1000:
+        return (rnd % jnp.uint32(cfg.delivery_spread + 1)).astype(jnp.int32)
+    gate = (mix32(rnd ^ jnp.uint32(0xA511E9B3)) % jnp.uint32(1000)) < jnp.uint32(
+        cfg.delivery_prob_permille
+    )
+    magnitude = 1 + (rnd % jnp.uint32(cfg.delivery_spread)).astype(jnp.int32)
+    return jnp.where(gate, magnitude, 0)
+
+
 @scope("cut_detection")
 def _cohort_cut_detection(
     cfg: EngineConfig, state: EngineState, new_bits, heard_down, batch_axis=None,
@@ -792,6 +822,11 @@ def _compute_round(
             tl_classic_decisions=telem.tl_classic_decisions + fb_decided.astype(jnp.int32),
             tl_conflict_rounds=telem.tl_conflict_rounds
             + (jnp.any(announced) & ~fast_decided).astype(jnp.int32),
+            tl_dissent=telem.tl_dissent
+            + jnp.sum(
+                decided & announced & (value_of != value_of[winner_cohort]),
+                dtype=jnp.int32,
+            ),
             tl_invalidation_rounds=telem.tl_invalidation_rounds
             + invalidation_own[0].astype(jnp.int32),
             tl_invalidation_dense_rounds=telem.tl_invalidation_dense_rounds
@@ -1105,6 +1140,7 @@ def telemetry_digest_impl(telem: TelemetryLanes) -> jnp.ndarray:
             telem.tl_fast_decisions,
             telem.tl_classic_decisions,
             telem.tl_conflict_rounds,
+            telem.tl_dissent,
             telem.tl_invalidation_rounds,
             telem.tl_invalidation_dense_rounds,
         ]),
@@ -2332,6 +2368,14 @@ class VirtualCluster(DispatchSeam):
     def config_id(self) -> int:
         self._account_d2h(8)
         return (int(self.state.config_hi) << 32) | int(self.state.config_lo)
+
+    def delivery_delays(self, slots) -> np.ndarray:
+        """``int32[c, len(slots), k]``: :func:`delivery_delays` of these
+        slots' edges in the configuration the cluster is in now (set-up
+        only: one eager computation and one fetch)."""
+        out = np.asarray(delivery_delays(self.cfg, self.state.config_epoch, slots))
+        self._account_d2h(out.nbytes)
+        return out
 
     # -- observability (utils/exposition.py schema) ---------------------
 

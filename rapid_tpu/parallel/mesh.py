@@ -130,8 +130,9 @@ PARTITION_RULES: Tuple[Tuple[str, Spec], ...] = (
     (r"tl_proposals", (COHORT_AXIS,)),
     (
         r"tl_rounds|tl_alerts|tl_tally_sum|tl_fast_decisions"
-        r"|tl_classic_decisions|tl_conflict_rounds|tl_invalidation_rounds"
-        r"|tl_invalidation_dense_rounds|tl_undecided_hist",
+        r"|tl_classic_decisions|tl_conflict_rounds|tl_dissent"
+        r"|tl_invalidation_rounds|tl_invalidation_dense_rounds"
+        r"|tl_undecided_hist",
         (),  # replicated-ok: per-engine scalar counters + the 8-bucket histogram
     ),
     # Round-trace ring (models/state.TraceRing): every lane is a per-round

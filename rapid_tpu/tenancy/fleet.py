@@ -127,6 +127,7 @@ from rapid_tpu.models.virtual_cluster import (
     _compute_round,
     _edge_masks,
     apply_view_change_impl,
+    delivery_delays,
     engine_step_impl,
     jit_per_observer_count,
     run_to_decision_impl,
@@ -1367,6 +1368,28 @@ class TenantFleet(DispatchSeam):
         out = np.asarray(self.state.config_epoch)
         self._account_d2h(out.nbytes)
         return out
+
+    def delivery_delays(self, slots) -> List[np.ndarray]:
+        """Per tenant ``int32[c, len(slots[t]), k]``: the delivery delay of
+        every (cohort, slot, ring) edge of ``slots[t]`` in the configuration
+        tenant t is in now (``models/virtual_cluster.delivery_delays``).
+        Set-up only: the delay is a function of the epoch and not of the
+        tenant, so it is one eager computation over all slots and one fetch
+        for each distinct epoch, indexed on the host."""
+        if len(slots) != self.b:
+            raise ValueError(f"need {self.b} slot lists, got {len(slots)}")
+        epochs = self.config_epochs()
+        tables = {
+            int(epoch): np.asarray(
+                delivery_delays(self.cfg, epoch, np.arange(self.cfg.n))
+            )
+            for epoch in np.unique(epochs)
+        }
+        self._account_d2h(sum(table.nbytes for table in tables.values()))
+        return [
+            tables[int(epoch)][:, np.asarray(tenant_slots, dtype=np.int64)]
+            for epoch, tenant_slots in zip(epochs, slots)
+        ]
 
     def health(self) -> NodeHealth:
         """Fleet-wide health in the host vocabulary: PROPOSING while any

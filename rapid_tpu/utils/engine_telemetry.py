@@ -449,6 +449,7 @@ TELEMETRY_DIGEST_FIELDS = (
     "decisions_fast",
     "decisions_classic",
     "conflict_rounds",
+    "dissent",
     "invalidation_rounds",
     "invalidation_dense_rounds",
 )

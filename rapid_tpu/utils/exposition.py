@@ -220,6 +220,7 @@ _ENGINE_ACTIVITY_COUNTERS = (
     "proposals",
     "tally_sum",
     "conflict_rounds",
+    "dissent",
     "invalidation_rounds",
     "invalidation_dense_rounds",
 )

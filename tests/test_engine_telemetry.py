@@ -829,6 +829,7 @@ GOLDEN_ACTIVITY_METRIC_NAMES = [
     "rapid_engine_activity_alerts_total",
     "rapid_engine_activity_conflict_rate",
     "rapid_engine_activity_conflict_rounds_total",
+    "rapid_engine_activity_dissent_total",
     "rapid_engine_activity_fast_path_share",
     "rapid_engine_activity_invalidation_dense_rounds_total",
     "rapid_engine_activity_invalidation_rounds_total",

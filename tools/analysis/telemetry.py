@@ -60,6 +60,7 @@ TELEMETRY_LANE_FIELDS = (
     "tl_fast_decisions",
     "tl_classic_decisions",
     "tl_conflict_rounds",
+    "tl_dissent",
     "tl_invalidation_rounds",
     "tl_invalidation_dense_rounds",
     "tl_undecided_hist",
