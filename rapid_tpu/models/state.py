@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from rapid_tpu.ops.hashing import masked_set_hash
-from rapid_tpu.ops.rings import ring_perms, ring_topology_from_perm
+from rapid_tpu.ops.rings import ring_perms, ring_positions, ring_topology_from_perm
 
 # Sentinel for "this edge's alert has not fired": far enough in the future
 # that (round_idx - FIRE_NEVER) stays hugely negative in int32. The compact
@@ -185,7 +185,7 @@ WIDE_POLICY = CompactionPolicy(
 #: arithmetic on exactly these names; the two sets are pinned equal by
 #: tests/test_state_compaction.py.
 NARROWABLE_LANES = frozenset({
-    "ring_perm", "obs_idx", "inval_obs", "cohort_of",
+    "ring_perm", "ring_pos", "obs_idx", "inval_obs", "cohort_of",
     "fd_count", "fd_hist", "fire_round", "report_bits",
     "cp_rnd_r", "cp_rnd_i", "cp_vrnd_r", "cp_vrnd_i", "cp_vval_src",
     "classic_epoch", "rounds_undecided",
@@ -243,6 +243,7 @@ LANE_SPECS: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "key_hi": (("k", "n"), "uint32"),
     "key_lo": (("k", "n"), "uint32"),
     "ring_perm": (("k", "n"), "idx"),
+    "ring_pos": (("k", "n"), "idx"),
     "id_hi": (("n",), "uint32"),
     "id_lo": (("n",), "uint32"),
     "alive": (("n",), "bool"),
@@ -310,6 +311,7 @@ class EngineState(NamedTuple):
     key_hi: jnp.ndarray  # [k, n] uint32
     key_lo: jnp.ndarray  # [k, n] uint32
     ring_perm: jnp.ndarray  # [k, n] int32 — static key-order permutation per ring
+    ring_pos: jnp.ndarray  # [k, n] int32 — its inverse: a slot's position on each ring
     id_hi: jnp.ndarray  # [n] uint32 — node-identity lanes for set hashes
     id_lo: jnp.ndarray  # [n] uint32
     alive: jnp.ndarray  # [n] bool — current membership
@@ -405,6 +407,7 @@ def initial_state(cfg: EngineConfig, key_hi, key_lo, id_hi, id_lo, alive) -> Eng
         key_hi=jnp.asarray(key_hi, dtype=jnp.uint32),
         key_lo=jnp.asarray(key_lo, dtype=jnp.uint32),
         ring_perm=perm,
+        ring_pos=ring_positions(perm),
         id_hi=jnp.asarray(id_hi, dtype=jnp.uint32),
         id_lo=jnp.asarray(id_lo, dtype=jnp.uint32),
         alive=alive,

@@ -2009,10 +2009,11 @@ class VirtualCluster(DispatchSeam):
             # predecessors of its keys. Everything below is device-side
             # gather/scatter — only the slot indices cross the boundary, which
             # is what keeps a bootstrap wave from paying O(k*n) transfer traffic.
+            # One masked maximum a joiner over the slots' static ring
+            # positions, no order of the rings built: this sits in
+            # bootstrap's timed path.
             pred = predecessor_of_keys(
-                state.key_hi, state.key_lo, state.alive,
-                state.key_hi[:, idx], state.key_lo[:, idx],
-                perm=state.ring_perm,  # sort-free: this sits in bootstrap's timed path
+                state.ring_pos, state.ring_perm, state.alive, idx
             )  # [k, j]
 
             # The gatekeeper IS the joiner's observer pre-admission (for both

@@ -11,6 +11,7 @@ from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
     ring_perms,
+    ring_positions,
     ring_topology,
     ring_topology_from_perm,
 )
@@ -30,6 +31,7 @@ __all__ = [
     "endpoint_ring_keys",
     "predecessor_of_keys",
     "ring_perms",
+    "ring_positions",
     "ring_topology",
     "ring_topology_from_perm",
 ]

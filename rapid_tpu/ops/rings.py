@@ -312,46 +312,58 @@ def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopolo
 
 
 @jax.jit
+def ring_positions(perm: jnp.ndarray) -> jnp.ndarray:
+    """The inverse of :func:`ring_perms`, at ``perm``'s dtype: ``pos[k, s]``
+    is the position of slot ``s`` in ring k's fixed key order, so ``pos[k,
+    perm[k, p]] == p``. Static like the keys, so made ONCE, beside the perms
+    (``EngineState.ring_pos``): a slot's position is its whole 64-bit key's
+    order in one word, which is what lets a joiner's gatekeeper be one
+    single-word maximum (:func:`predecessor_of_keys`). One scatter a ring;
+    long rings one at a time, as the rebuild takes them
+    (:data:`RING_AT_A_TIME_SLOTS`)."""
+    perm = jnp.asarray(perm)
+    where = jnp.arange(perm.shape[-1], dtype=perm.dtype)
+    invert = lambda ring: jnp.zeros_like(ring).at[ring].set(where)
+    if perm.shape[-1] >= RING_AT_A_TIME_SLOTS:
+        return jax.lax.map(invert, perm)
+    return jax.vmap(invert)(perm)
+
+
+@jax.jit
 @scope("join_predecessors")
 def predecessor_of_keys(
-    key_hi: jnp.ndarray,
-    key_lo: jnp.ndarray,
+    ring_pos: jnp.ndarray,
+    ring_perm: jnp.ndarray,
     alive: jnp.ndarray,
-    query_hi: jnp.ndarray,
-    query_lo: jnp.ndarray,
-    perm: "jnp.ndarray | None" = None,
+    slots: jnp.ndarray,
 ) -> jnp.ndarray:
-    """Expected observers of joiners: for each query key (one per ring per
-    joiner), the alive slot that precedes it on that ring — the semantics of
-    ``getExpectedObserversOf`` (MembershipView.java:292-322).
+    """Expected observers of joiners: for each joiner slot and ring, the
+    alive slot that precedes the joiner's key on that ring — the semantics
+    of ``getExpectedObserversOf`` (MembershipView.java:292-322).
 
-    key_hi/key_lo: [K, N]; query_hi/query_lo: [K, J]. Returns [K, J] slot
-    indices (-1 when no node is alive). Rank is computed by a masked
-    comparison sum — O(N·J) elementwise work that maps cleanly onto sharded N.
-    With ``perm`` (the static key-order permutations, ``ring_perms``) the
-    alive-first order comes from O(N) partition scans instead of a K-ring
-    argsort — this sits inside a bootstrap wave's timed path, where the
-    engine passes its ``state.ring_perm``. Results are identical either way.
+    ring_pos/ring_perm: [K, N] (:func:`ring_positions`, :func:`ring_perms`);
+    alive: [N]; slots: [J], slots of the same N (a joiner holds its slot, and
+    so its keys and ring positions, before it is admitted). Returns [K, J]
+    int32 slot indices, -1 when no node is alive. The gatekeeper is the alive
+    slot at the greatest ring position below the joiner's own, and with none
+    below, the alive slot at the greatest position of all (the ring wraps):
+    ONE masked single-word maximum over the slot axis a query — O(N·J) fused
+    elementwise work, a ``max`` across the shards of a sharded N — then J
+    look-ups of ``ring_perm``. No order of the alive slots is built, sorted
+    or gathered: this sits inside a bootstrap wave's timed path, where J is
+    a handful and N·K is not. The position orders slots by (64-bit key,
+    slot), the order the view change's walk gives the joiner once admitted;
+    for distinct keys that is ``order[rank - 1]`` of the alive-first key
+    order (tests/test_ops_rings.py keeps that spelling as the reference).
     """
-
-    n_alive = jnp.sum(alive.astype(jnp.int32))
-
-    if perm is None:
-        dead = (~alive).astype(jnp.uint32)
-        orders = jax.vmap(lambda h, low: lex_argsort((dead, h, low)))(
-            key_hi, key_lo
-        )
-    else:
-        orders = jax.vmap(_alive_first_order, in_axes=(0, None))(perm, alive)
-
-    def one_ring(khi, klo, qhi, qlo, order):
-        def one_query(h, low):
-            less = (khi < h) | ((khi == h) & (klo < low))
-            rank = jnp.sum((less & alive).astype(jnp.int32))
-            # Predecessor = alive node at sorted position (rank - 1) mod n_alive.
-            pred_pos = jnp.where(rank - 1 < 0, n_alive - 1, rank - 1)
-            return jnp.where(n_alive >= 1, order[pred_pos], -1).astype(jnp.int32)
-
-        return jax.vmap(one_query)(qhi, qlo)
-
-    return jax.vmap(one_ring)(key_hi, key_lo, query_hi, query_lo, orders)
+    ring_pos, alive = jnp.asarray(ring_pos), jnp.asarray(alive, dtype=bool)
+    none = jnp.asarray(-1, ring_pos.dtype)
+    own = ring_pos[:, jnp.asarray(slots)]  # [K, J]
+    below = jnp.max(
+        jnp.where(alive & (ring_pos[:, None, :] < own[:, :, None]), ring_pos[:, None, :], none),
+        axis=-1,
+    )
+    last = jnp.max(jnp.where(alive, ring_pos, none), axis=-1)  # [K]
+    at = jnp.where(below >= 0, below, last[:, None]).astype(jnp.int32)
+    pred = jnp.take_along_axis(jnp.asarray(ring_perm), jnp.maximum(at, 0), axis=1)
+    return jnp.where(at >= 0, pred.astype(jnp.int32), -1)

@@ -101,7 +101,7 @@ class ShardingShapeError(ValueError):
 #: justify itself with ``# replicated-ok: <reason>`` on its line.
 PARTITION_RULES: Tuple[Tuple[str, Spec], ...] = (
     # [k, n] ring/key/topology tables: slots on the last axis.
-    (r"key_hi|key_lo|ring_perm|obs_idx|inval_obs", (None, NODE_AXIS)),
+    (r"key_hi|key_lo|ring_perm|ring_pos|obs_idx|inval_obs", (None, NODE_AXIS)),
     # [n, k] per-edge failure-detector state: slots on the first axis.
     (r"fd_count|fd_hist|fd_fired|fire_round|probe_fail", (NODE_AXIS, None)),
     # [c] cohort lanes (watermark flags + proposal-id lanes): sharded over

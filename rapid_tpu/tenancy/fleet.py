@@ -98,7 +98,9 @@ Batched-control-flow tradeoffs, stated plainly:
 - joins reach a stacked fleet through :meth:`TenantFleet.inject_join_wave`:
   ``(tenant, slot)`` pairs, padded per tenant on the device and placed by
   ``predecessor_of_keys`` vmapped over the tenant axis
-  (:func:`fleet_join_place_impl`), bit-identical to
+  (:func:`fleet_join_place_impl`: a masked maximum over each tenant's slot
+  axis a query, which reads ``alive`` and the slots' static ring positions
+  and builds no ring order), bit-identical to
   ``VirtualCluster.inject_join_wave`` on every tenant before stacking.
 """
 
@@ -285,8 +287,10 @@ def fleet_join_place_impl(cfg: EngineConfig, state: EngineState, idx, width: int
     width]`` on the device (a stable sort by tenant; a tenant with fewer
     joiners, or none, is padded with slot ``n``, which every scatter drops),
     then each tenant's gatekeepers come from ``predecessor_of_keys`` over its
-    own rings and go into ``join_pending``, ``obs_idx``, ``inval_obs`` and
-    the fired-edge stamps exactly as the cluster's method writes them."""
+    own rings (one masked maximum over the slots' ring positions a query, no
+    ring order rebuilt) and go into ``join_pending``, ``obs_idx``,
+    ``inval_obs`` and the fired-edge stamps exactly as the cluster's method
+    writes them."""
     n, (m, tenants) = cfg.n, (idx.shape[0], state.alive.shape[0])
     never = compaction_policy(cfg).fire_never
     order = jnp.argsort(idx[:, 0], stable=True)
@@ -296,10 +300,9 @@ def fleet_join_place_impl(cfg: EngineConfig, state: EngineState, idx, width: int
     slots = jnp.full((tenants, width), n, jnp.int32).at[tenant, place].set(slot)
 
     def one(state, slots):
-        at = jnp.minimum(slots, n - 1)  # a padded query reads a real key; its answer is dropped
+        at = jnp.minimum(slots, n - 1)  # a padded query asks for a real slot; its answer is dropped
         pred = predecessor_of_keys(
-            state.key_hi, state.key_lo, state.alive,
-            state.key_hi[:, at], state.key_lo[:, at], perm=state.ring_perm,
+            state.ring_pos, state.ring_perm, state.alive, at
         )  # [k, width]
         pred_n = pred.astype(state.obs_idx.dtype)
         fired = (pred >= 0).T  # [width, k]

@@ -255,12 +255,19 @@ def _open_npz(path) -> _LoadedNpz:
         ) from exc
 
 
+#: EngineState lanes that are functions of the key lanes alone: never
+#: written by :func:`save_engine_state`, always recomputed on load.
+_DERIVED_LANES = ("ring_perm", "ring_pos")
+
+
 def save_engine_state(path, cfg: "EngineConfig", state: "EngineState") -> None:
     arrays = {field: np.asarray(value) for field, value in state._asdict().items()}
-    # Derived data is never persisted: ring_perm is a pure function of the
-    # key lanes, and loading a stale/corrupted copy would silently diverge
-    # topology from the keys. Load always recomputes it (one sort).
-    arrays.pop("ring_perm", None)
+    # Derived data is never persisted: ring_perm and its inverse ring_pos
+    # are pure functions of the key lanes, and loading a stale/corrupted
+    # copy would silently diverge topology from the keys. Load always
+    # recomputes them (one sort, one scatter a ring).
+    for derived in _DERIVED_LANES:
+        arrays.pop(derived, None)
     _atomic_write(path, _seal(_npz_bytes({**_cfg_entries(cfg), **arrays})))
 
 
@@ -328,10 +335,12 @@ def load_engine_state(path) -> Tuple["EngineConfig", "EngineState"]:
             "ring_perm": lambda: _ring_perms(
                 jnp.asarray(data["key_hi"]), jnp.asarray(data["key_lo"])
             ).astype(dts["ring_perm"]),
+            # ... and its inverse from it (EngineState lists it after).
+            "ring_pos": lambda: _ring_positions_of(arrays["ring_perm"]),
         }
         arrays = {}
         for field in EngineState._fields:
-            if field == "ring_perm":
+            if field in _DERIVED_LANES:
                 # Always derived from the key lanes — a persisted copy (from
                 # any writer) is ignored rather than trusted for coherence.
                 arrays[field] = defaults[field]()
@@ -408,6 +417,11 @@ def load_serving_state(path):
             arrays = {}
             for field in cls._fields:
                 key = f"{prefix}__{field}"
+                if key == "state__ring_pos" and key not in data:
+                    # A writer older than the lane: it is the inverse of the
+                    # perms the archive does hold, a tenant at a time.
+                    arrays[field] = _ring_positions_of(arrays["ring_perm"])
+                    continue
                 if key not in data:
                     raise KeyError(
                         f"serving checkpoint missing {key!r} (not written "
@@ -424,6 +438,16 @@ def load_serving_state(path):
 
             knobs = tree(TenantKnobs, "knobs")
     return cfg, state, faults, knobs, meta
+
+
+def _ring_positions_of(perm):
+    """``ring_positions`` of a cluster's ``[k, n]`` perms or a fleet's
+    stacked ``[t, k, n]``."""
+    import jax
+
+    from rapid_tpu.ops.rings import ring_positions
+
+    return ring_positions(perm) if perm.ndim == 2 else jax.vmap(ring_positions)(perm)
 
 
 def load_link_faults(path):

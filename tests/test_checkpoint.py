@@ -157,7 +157,7 @@ def test_engine_state_loads_checkpoint_missing_new_fields(tmp_path):
     for legacy_missing in (
         "fire_round", "round_idx", "cp_rnd_r", "cp_rnd_i",
         "cp_vrnd_r", "cp_vrnd_i", "cp_vval_src", "classic_epoch",
-        "ring_perm",  # derived: must backfill from the saved key lanes
+        "ring_perm", "ring_pos",  # derived: must backfill from the saved key lanes
     ):
         kept.pop(legacy_missing, None)
     stripped = tmp_path / "legacy.npz"
@@ -167,6 +167,9 @@ def test_engine_state_loads_checkpoint_missing_new_fields(tmp_path):
     assert cfg == vc.cfg
     np.testing.assert_array_equal(
         np.asarray(state.ring_perm), np.asarray(vc.state.ring_perm)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(state.ring_pos), np.asarray(vc.state.ring_pos)
     )
     restored = VirtualCluster(cfg, state)
     restored.crash([7])
@@ -351,6 +354,32 @@ def test_fleet_stacked_checkpoint_roundtrips_and_resumes(tmp_path):
     (tmp_path / "missing.npz").write_bytes(buf.getvalue())
     with pytest.raises(KeyError, match="faults__crashed"):
         load_serving_state(tmp_path / "missing.npz")
+
+
+@pytest.mark.parametrize("target", ["cluster", "compact_cluster", "fleet"])
+def test_a_serving_checkpoint_older_than_ring_pos_gets_it_from_its_perms(tmp_path, target):
+    """``EngineState.ring_pos`` (PR 49) is the inverse of ``ring_perm``, which
+    a serving checkpoint has always held: an archive written before the lane
+    loads to the state the engine would have built, a tenant at a time."""
+    import io
+
+    if target == "fleet":
+        from rapid_tpu.tenancy import TenantFleet
+
+        served = TenantFleet.from_clusters([_small_cluster(seed=s) for s in (5, 6)])
+        knobs = served.knobs
+    else:
+        served, knobs = _small_cluster(compact=target == "compact_cluster"), None
+    path = tmp_path / "now.npz"
+    save_serving_state(path, served.cfg, served.state, served.faults, knobs=knobs)
+    with np.load(io.BytesIO(path.read_bytes()[:-12])) as data:  # less the seal
+        kept = {k: data[k] for k in data.files if k != "state__ring_pos"}
+    assert len(kept) == len(data.files) - 1
+    buf = io.BytesIO()
+    np.savez_compressed(buf, **kept)
+    (tmp_path / "older.npz").write_bytes(buf.getvalue())
+    _cfg, state, _faults, _knobs, _meta = load_serving_state(tmp_path / "older.npz")
+    _trees_bit_identical(state, served.state)
 
 
 def test_wide_checkpoint_loads_under_a_compact_config(tmp_path):

@@ -24,7 +24,9 @@ from rapid_tpu.ops.cut_detection import (
     process_alert_batch,
 )
 from rapid_tpu.ops.pallas_kernels import watermark_merge_classify_impl
-from rapid_tpu.ops.rings import endpoint_ring_keys, predecessor_of_keys, ring_topology
+from rapid_tpu.ops.rings import (
+    endpoint_ring_keys, predecessor_of_keys, ring_perms, ring_positions, ring_topology,
+)
 from rapid_tpu.protocol.cut_detector import MultiNodeCutDetector
 from rapid_tpu.protocol.view import MembershipView
 from rapid_tpu.types import AlertMessage, EdgeStatus, Endpoint, NodeId
@@ -53,8 +55,12 @@ def build_inval_obs(view, members, joiners):
     topo = ring_topology(key_hi, key_lo, alive)
     obs = np.asarray(topo.obs_idx)  # [K, n]
     if joiners:
-        qhi, qlo = endpoint_ring_keys(joiners, K)
-        pred = np.asarray(predecessor_of_keys(key_hi, key_lo, alive, qhi, qlo))  # [K, j]
+        # joiners hold the slots after the members', not alive yet
+        perm = ring_perms(*endpoint_ring_keys(members + joiners, K))
+        pred = np.asarray(predecessor_of_keys(
+            ring_positions(perm), perm, np.arange(n + len(joiners)) < n,
+            np.arange(n, n + len(joiners)),
+        ))  # [K, j]
         obs = np.concatenate([obs, pred], axis=1)
     return obs
 

@@ -4,12 +4,15 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from rapid_tpu.ops import rings
+from rapid_tpu.ops.hashing import lex_argsort
 from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
     ring_perms,
+    ring_positions,
     ring_topology,
     ring_topology_from_perm,
 )
@@ -175,17 +178,15 @@ def test_from_perm_matches_sorting_topology(n, k, alive_frac, idx):
     np.testing.assert_array_equal(np.asarray(got.subj_idx), np.asarray(want.subj_idx))
     np.testing.assert_array_equal(np.asarray(got.order), np.asarray(want.order))
 
-    # The joiner-gatekeeper query must agree between its sorting and
-    # perm-scan paths too (inject_join_wave passes the engine's perm).
-    j = min(5, n)
-    qhi = rng.integers(0, 2**32, size=(k, j), dtype=np.uint32)
-    qlo = rng.integers(0, 2**32, size=(k, j), dtype=np.uint32)
-    np.testing.assert_array_equal(
-        np.asarray(predecessor_of_keys(key_hi, key_lo, alive, qhi, qlo)),
-        np.asarray(
-            predecessor_of_keys(key_hi, key_lo, alive, qhi, qlo, perm=perm)
-        ),
-    )
+    # The joiner-gatekeeper query, which builds no order, must read what the
+    # order it no longer builds would give, sorted or scanned from the perm.
+    slots = rng.choice(n, size=min(5, n), replace=False)
+    narrow = perm.astype(idx)
+    got = np.asarray(predecessor_of_keys(ring_positions(narrow), narrow, alive, slots))
+    for order in (None, want.order):
+        np.testing.assert_array_equal(
+            got, np.asarray(_predecessors_by_rank(key_hi, key_lo, alive, slots, order))
+        )
 
 
 @pytest.mark.parametrize("n,alive_frac", [
@@ -222,10 +223,13 @@ def test_expected_observers_of_joiners():
     for i, ep in enumerate(members):
         view.ring_add(ep, NodeId(0, i))
 
-    key_hi, key_lo = endpoint_ring_keys(members, k)
-    qhi, qlo = endpoint_ring_keys(joiners, k)
-    alive = np.ones(n, dtype=bool)
-    pred = np.asarray(predecessor_of_keys(key_hi, key_lo, alive, qhi, qlo))
+    # A joiner holds a slot, and so its keys and ring positions, before it
+    # is admitted: the members' slots first, alive, then the joiners'.
+    perm = ring_perms(*endpoint_ring_keys(endpoints, k))
+    alive = np.arange(n + j) < n
+    pred = np.asarray(
+        predecessor_of_keys(ring_positions(perm), perm, alive, np.arange(n, n + j))
+    )
 
     slot_of = {ep: i for i, ep in enumerate(members)}
     for jx, joiner in enumerate(joiners):
@@ -233,15 +237,152 @@ def test_expected_observers_of_joiners():
         assert pred[:, jx].tolist() == expected
 
 
-def _primitives(jaxpr):
-    """Every primitive of a jaxpr, the bodies of its calls and loops included."""
+def _predecessors_by_rank(key_hi, key_lo, alive, slots, orders=None):
+    """The plain reference of ``predecessor_of_keys``, which was the function
+    itself up to PR 49: sort every ring alive-first by key (or take
+    ``orders``, ``RingTopology.order``), rank each query by a masked
+    comparison sum, and read ``order[rank - 1]``, wrapping below rank 0. A
+    query is a slot's own key; where another slot holds the SAME 64-bit key
+    the lower slot comes first, as in the static order (the parent counted
+    strictly smaller keys alone, so it differs there and only there)."""
+    key_hi, key_lo, alive = jnp.asarray(key_hi), jnp.asarray(key_lo), jnp.asarray(alive)
+    slots = jnp.asarray(slots)
+    n_alive = jnp.sum(alive.astype(jnp.int32))
+    if orders is None:
+        dead = (~alive).astype(jnp.uint32)
+        orders = jax.vmap(lambda h, low: lex_argsort((dead, h, low)))(key_hi, key_lo)
+    slot = jnp.arange(key_hi.shape[-1])
+
+    def one_ring(khi, klo, order):
+        def one_query(q):
+            h, low = khi[q], klo[q]
+            less = (khi < h) | ((khi == h) & ((klo < low) | ((klo == low) & (slot < q))))
+            rank = jnp.sum((less & alive).astype(jnp.int32))
+            pred_pos = jnp.where(rank - 1 < 0, n_alive - 1, rank - 1)
+            return jnp.where(n_alive >= 1, order[pred_pos], -1).astype(jnp.int32)
+
+        return jax.vmap(one_query)(slots)
+
+    return jax.vmap(one_ring)(key_hi, key_lo, orders)
+
+
+#: name -> (key bits, who is alive, which slots ask, the index dtype). One
+#: shape for all of them: 2-bit keys tie among the members and between a
+#: joiner and members; a "member" asks for its own key, whose gatekeeper is
+#: the alive slot BEFORE it, never itself; "padded" is the fleet's layout.
+GATEKEEPER_CASES = {
+    "random_32_bit_keys": (32, 0.7, "joiners", np.int32),
+    "two_bit_keys": (2, 0.7, "joiners", np.int32),
+    "two_bit_keys_all_alive": (2, "all", "members", np.int32),
+    "nobody_alive": (32, "none", "joiners", np.int32),
+    "one_alive": (32, "one", "joiners", np.int32),
+    "one_alive_two_bit_keys": (2, "one", "any", np.int32),
+    "all_alive": (32, "all", "members", np.int32),
+    "queries_are_members_own_keys": (32, 0.7, "members", np.int32),
+    "compact_index_dtype": (32, 0.7, "any", np.int8),
+    "tenant_vmap_padded_queries": (32, 0.7, "padded", np.int16),
+}
+
+
+@pytest.mark.parametrize("case", list(GATEKEEPER_CASES))
+def test_the_gatekeeper_is_the_one_the_alive_first_order_gives(case):
+    bits, who, asks, idx = GATEKEEPER_CASES[case]
+    tenants, k, n, j = 3, 4, 96, 12
+    rng = np.random.default_rng(sorted(GATEKEEPER_CASES).index(case))
+    key_hi = rng.integers(0, 2**bits, size=(tenants, k, n), dtype=np.uint32)
+    key_lo = rng.integers(0, 2**bits, size=(tenants, k, n), dtype=np.uint32)
+    alive = {
+        "none": np.zeros((tenants, n), bool),
+        "all": np.ones((tenants, n), bool),
+        "one": np.arange(n) == rng.integers(0, n, size=(tenants, 1)),
+    }.get(who)
+    if alive is None:
+        alive = rng.random((tenants, n)) < who
+    pools = {"joiners": ~alive, "members": alive}
+    slots = np.stack([
+        rng.choice(np.flatnonzero(pools.get(asks, np.ones((tenants, n), bool))[t]), size=j, replace=False)
+        for t in range(tenants)
+    ])
+    if asks == "padded":
+        # As the fleet's placement lays them out: tenant 1 has fewer than the
+        # width and tenant 2 none, padded with slot ``n``, which asks for
+        # slot ``n - 1`` (and whose answer every scatter drops).
+        slots[1, j // 2:] = n
+        slots[2, :] = n
+        slots = np.minimum(slots, n - 1)
+    perm = jax.vmap(ring_perms)(key_hi, key_lo).astype(idx)
+    pos = jax.vmap(ring_positions)(perm)
+    assert pos.dtype == idx
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(pos), np.asarray(perm).astype(np.int64), axis=2),
+        np.broadcast_to(np.arange(n), (tenants, k, n)),
+    )
+
+    got = jax.vmap(predecessor_of_keys)(pos, perm, alive, slots)
+    assert got.dtype == np.int32 and got.shape == (tenants, k, j)
+    want = np.stack([
+        np.asarray(_predecessors_by_rank(key_hi[t], key_lo[t], alive[t], slots[t]))
+        for t in range(tenants)
+    ])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # alone as under the tenants' vmap
+    np.testing.assert_array_equal(
+        np.asarray(predecessor_of_keys(pos[0], perm[0], alive[0], slots[0])), want[0]
+    )
+    assert (want >= 0).all() == bool(alive.any(axis=1).all())  # -1 only with nobody alive
+    # The engine narrows the gatekeepers to its index width on store: every
+    # slot and the -1 survive it.
+    np.testing.assert_array_equal(np.asarray(got.astype(idx)).astype(np.int32), want)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, the bodies of its calls and loops included."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else (value,):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _primitives(sub)
+                    yield from _equations(sub)
+
+
+def _primitives(jaxpr):
+    """Every primitive of a jaxpr, the bodies of its calls and loops included."""
+    return (eqn.primitive.name for eqn in _equations(jaxpr))
+
+
+@pytest.mark.parametrize("shape,queries,tenants", [
+    ((10, 102_500), 2500, None),  # cluster-100k.churn5
+    ((10, 102_500), 16, None),    # cluster-100k.trickle's padded small wave
+    ((10, 2000), 242, 128),       # paper-fleet-2k.bootstrap
+])
+def test_the_placement_builds_no_order_of_the_rings(shape, queries, tenants):
+    """Traced over shapes alone: the gatekeepers' jaxpr holds no scatter, no
+    scan and no sort (``_alive_first_order``'s, which it ran for every ring
+    up to PR 49), and no gather whose indices are K·N: the two there are the
+    J joiners' own positions and the J answers' slots. What reads the K·N
+    positions is ONE single-word maximum a query and one a ring."""
+    fn, lead = predecessor_of_keys, ()
+    if tenants is not None:
+        fn, lead = jax.vmap(predecessor_of_keys), (tenants,)
+    spec = lambda *dims, dtype=np.int32: jax.ShapeDtypeStruct(lead + dims, dtype)
+    k, n = shape
+    traced = jax.make_jaxpr(fn)(spec(k, n), spec(k, n), spec(n, dtype=np.bool_), spec(queries))
+    eqns = list(_equations(traced.jaxpr))
+    names = [eqn.primitive.name for eqn in eqns]
+    assert not [
+        name for name in names if name.startswith(("scatter", "cum", "sort", "reduce_sum"))
+    ], names
+    gathers = [eqn for eqn in eqns if eqn.primitive.name == "gather"]
+    assert len(gathers) == 2
+    for eqn in gathers:  # indices [..., K, J, 1] or [..., J, 1]: never K·N of them
+        assert int(np.prod(eqn.invars[1].aval.shape)) <= int(np.prod(lead + (k, queries, 2)))
+    reduces = [eqn for eqn in eqns if eqn.primitive.name == "reduce_max"]
+    assert sorted(eqn.outvars[0].aval.shape for eqn in reduces) == sorted(
+        [lead + (k,), lead + (k, queries)]
+    )
+    assert [v.aval.shape for v in traced.jaxpr.outvars] == [lead + (k, queries)]
+    assert [v.aval.dtype for v in traced.jaxpr.outvars] == [np.int32]
 
 
 #: The form ``ring_topology_from_perm`` takes is a static fact of the ring

@@ -143,7 +143,7 @@ def lowered():
             traced.cfg, traced.state, traced.telem, traced.trace_ring, traced.faults),
         "sync_checksum": vcm.sync_checksum.lower(s, vc.faults),
         "predecessor_of_keys": predecessor_of_keys.lower(
-            s.key_hi, s.key_lo, s.alive, s.key_hi[:, idx], s.key_lo[:, idx], perm=s.ring_perm),
+            s.ring_pos, s.ring_perm, s.alive, idx),
     })
     return out
 
@@ -345,14 +345,17 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: ``_edge_masks``, which now gathers once an edge (the packed ``rx_block``
 #: words and the ``active`` bit in one table; ``tests/test_edge_masks.py``
 #: holds its outputs to the two-gather body). Nothing else in them moved.
+#: At PR 49 all five were re-taken for their signature alone: the state has
+#: one more lane, ``ring_pos``, which a round hands through unread (only the
+#: join placement reads it), so each text gains an operand and a result.
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "a9aaeaefe5df6b28",
-    "fleet_run_to_decision": "e6128c17d81d0869",
-    "mesh_run_to_decision": "9aa4e92bfc4b0239",
-    "mesh_step": "3c7084d0051bee1e",
-    "mesh_fleet_step": "4055ed74d22a4b65",
+    "run_until_membership": "1811af9c78689ae5",
+    "fleet_run_to_decision": "33c287ed9273585e",
+    "mesh_run_to_decision": "6894315e5bdfd33e",
+    "mesh_step": "999c4f064a025c0f",
+    "mesh_fleet_step": "eb360e4db9c669d1",
 }
 
 

@@ -447,7 +447,7 @@ def _check_retrace(
 #: package imports no jax-bearing library module; the two sets are pinned
 #: equal by tests/test_state_compaction.py so they cannot drift).
 NARROWED_LANES = frozenset({
-    "ring_perm", "obs_idx", "inval_obs", "cohort_of",
+    "ring_perm", "ring_pos", "obs_idx", "inval_obs", "cohort_of",
     "fd_count", "fd_hist", "fire_round", "report_bits",
     "cp_rnd_r", "cp_rnd_i", "cp_vrnd_r", "cp_vrnd_i", "cp_vval_src",
     "classic_epoch", "rounds_undecided",
