@@ -25,7 +25,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from rapid_tpu.ops.hashing import masked_set_hash
-from rapid_tpu.ops.rings import ring_perms, ring_positions, ring_topology_from_perm
+from rapid_tpu.ops.rings import (
+    ring_liveness,
+    ring_perms,
+    ring_positions,
+    ring_topology_from_perm,
+)
 
 # Sentinel for "this edge's alert has not fired": far enough in the future
 # that (round_idx - FIRE_NEVER) stays hugely negative in int32. The compact
@@ -244,6 +249,7 @@ LANE_SPECS: Dict[str, Tuple[Tuple[str, ...], str]] = {
     "key_lo": (("k", "n"), "uint32"),
     "ring_perm": (("k", "n"), "idx"),
     "ring_pos": (("k", "n"), "idx"),
+    "ring_alive": (("k", "n"), "bool"),
     "id_hi": (("n",), "uint32"),
     "id_lo": (("n",), "uint32"),
     "alive": (("n",), "bool"),
@@ -312,6 +318,10 @@ class EngineState(NamedTuple):
     key_lo: jnp.ndarray  # [k, n] uint32
     ring_perm: jnp.ndarray  # [k, n] int32 — static key-order permutation per ring
     ring_pos: jnp.ndarray  # [k, n] int32 — its inverse: a slot's position on each ring
+    # Liveness by ring position: ring_alive[k, p] == alive[ring_perm[k, p]],
+    # always. ``alive`` stays the source of truth; this is what the view
+    # change's walk reads, kept exact by flipping a cut's own positions.
+    ring_alive: jnp.ndarray  # [k, n] bool
     id_hi: jnp.ndarray  # [n] uint32 — node-identity lanes for set hashes
     id_lo: jnp.ndarray  # [n] uint32
     alive: jnp.ndarray  # [n] bool — current membership
@@ -400,7 +410,9 @@ def initial_state(cfg: EngineConfig, key_hi, key_lo, id_hi, id_lo, alive) -> Eng
     # The one sort: ring keys are static per slot, so every topology after
     # this (including every view change) is O(N) scans over these perms.
     perm = ring_perms(jnp.asarray(key_hi), jnp.asarray(key_lo)).astype(idt)
-    topo = ring_topology_from_perm(perm, alive)
+    # The walk's one gather, made here for the state to keep.
+    ring_alive = ring_liveness(perm, alive)
+    topo = ring_topology_from_perm(perm, alive, ring_alive)
     config_hi, config_lo = masked_set_hash(jnp.asarray(id_hi), jnp.asarray(id_lo), alive)
     n, k, c = cfg.n, cfg.k, cfg.c
     return EngineState(
@@ -408,6 +420,7 @@ def initial_state(cfg: EngineConfig, key_hi, key_lo, id_hi, id_lo, alive) -> Eng
         key_lo=jnp.asarray(key_lo, dtype=jnp.uint32),
         ring_perm=perm,
         ring_pos=ring_positions(perm),
+        ring_alive=ring_alive,
         id_hi=jnp.asarray(id_hi, dtype=jnp.uint32),
         id_lo=jnp.asarray(id_lo, dtype=jnp.uint32),
         alive=alive,
@@ -554,6 +567,7 @@ TELEMETRY_LANE_SPECS: Dict[str, Tuple[str, ...]] = {
     "tl_dissent": (),
     "tl_invalidation_rounds": (),
     "tl_invalidation_dense_rounds": (),
+    "tl_view_change_dense": (),
     "tl_undecided_hist": ("b",),
 }
 
@@ -607,6 +621,12 @@ class TelemetryLanes(NamedTuple):
     # says the bucket held it.
     tl_invalidation_rounds: jnp.ndarray  # [] int32
     tl_invalidation_dense_rounds: jnp.ndarray  # [] int32
+    # Commits whose view change gathered ``ring_alive`` whole instead of
+    # flipping the cut's own positions: the cut overflowed
+    # ``ops/rings.view_change_bucket``, or the program traces the gather
+    # alone (a mesh's: every commit). 0 over a window of traffic says the
+    # bucket held every cut.
+    tl_view_change_dense: jnp.ndarray  # [] int32
     tl_undecided_hist: jnp.ndarray  # [TELEMETRY_BUCKETS] int32 — log2(rounds-undecided) at decision
 
 

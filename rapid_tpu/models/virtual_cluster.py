@@ -49,6 +49,8 @@ from rapid_tpu.ops.pallas_kernels import (
 from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
+    ring_liveness,
+    ring_liveness_after_cut,
     ring_topology_from_perm,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
@@ -329,7 +331,7 @@ def delivery_delays(cfg: EngineConfig, config_epoch, slots) -> jnp.ndarray:
 @scope("cut_detection")
 def _cohort_cut_detection(
     cfg: EngineConfig, state: EngineState, new_bits, heard_down, batch_axis=None,
-    dense_invalidation=False,
+    dense_arms=False,
 ):
     """The engine's cut-detection seam: C independent watermark detectors
     batched over the (mesh-sharded) cohort axis. The pass itself lives in
@@ -349,7 +351,7 @@ def _cohort_cut_detection(
         cfg.l,
         cfg.k,
         batch_axis,
-        dense_invalidation,
+        dense_arms,
     )
 
 
@@ -362,7 +364,7 @@ def _compute_round(
     links: Optional[LinkFaults] = None,
     observer_loss=None,
     paths=None,
-    dense_invalidation=False,
+    dense_arms=False,
 ):
     """One protocol round WITHOUT view-change application: returns the
     round-advanced state plus (decided, winner_mask, events). Keeping the
@@ -420,15 +422,18 @@ def _compute_round(
     round computed anyway added in: whether the classic attempt ran
     (``fallback_due``), whether it decided, whether the fast round did.
 
-    ``dense_invalidation`` (handed by a program's builder, never by a user): a
-    sixth Python-level branch. ``False`` is every one-device program: the
-    ``invalidation`` arm looks observers up for the subjects in flux alone
-    and falls back to the dense loop when they overflow its bucket
-    (``ops/cut_detection.cohort_watermark_pass``). ``True`` traces the dense
-    loop alone, the program of before: ``parallel/mesh.sharded_program``
-    (the compaction is a global operation over the node axis the mesh
-    shards) and the fleet's unnamed-``vmap`` programs (a nested conditional
-    would be a select that runs both forms)."""
+    ``dense_arms`` (handed by a program's builder, never by a user): a
+    sixth Python-level branch, one flag for "this program takes no compacted
+    form". ``False`` is every one-device program: the ``invalidation`` arm
+    looks observers up for the subjects in flux alone and falls back to the
+    dense loop when they overflow its bucket
+    (``ops/cut_detection.cohort_watermark_pass``), and the view change
+    flips its cut's own ring positions and falls back to the whole gather
+    likewise (:func:`apply_view_change_impl`). ``True`` traces the dense
+    forms alone, the program of before: ``parallel/mesh.sharded_program``
+    (a compaction is a global operation over the node axis the mesh shards)
+    and the fleet's unnamed-``vmap`` programs (a nested conditional would be
+    a select that runs both forms)."""
     n, k, c = cfg.n, cfg.k, cfg.c
 
     # 1. Failure-detector tick -> fresh DOWN alerts per (subject, ring) edge.
@@ -493,7 +498,7 @@ def _compute_round(
         report_bits, released, announced, seen_down, proposed_now, prop_masks,
         invalidation_ran, invalidation_own,
     ) = _cohort_cut_detection(
-        cfg, state, new_bits, heard_down, batch_axis, dense_invalidation
+        cfg, state, new_bits, heard_down, batch_axis, dense_arms
     )
     # Proposal identity = commutative set-hash of the cut's member identities
     # (the canonical-sort-free equivalent of the ring-0-sorted endpoint list,
@@ -831,6 +836,8 @@ def _compute_round(
             + invalidation_own[0].astype(jnp.int32),
             tl_invalidation_dense_rounds=telem.tl_invalidation_dense_rounds
             + invalidation_own[1].astype(jnp.int32),
+            # the commit's, not the round's: :func:`_count_dense_commit`
+            tl_view_change_dense=telem.tl_view_change_dense,
             tl_undecided_hist=telem.tl_undecided_hist.at[bucket].add(decided_i),
         )
     if trace is None:
@@ -894,31 +901,54 @@ def classic_coordinator_targets(epoch: int, n_active: int, racers: int):
 
 @scope("view_change")
 def apply_view_change_impl(
-    cfg: EngineConfig, state: EngineState, winner_mask
-) -> EngineState:
+    cfg: EngineConfig, state: EngineState, winner_mask, *, batch_axis=None,
+    commits=None, dense_arms=False,
+):
     """Commit a decided cut: flip membership, re-derive ring topology, reset
-    all per-configuration state (MembershipService.java:385-444).
+    all per-configuration state (MembershipService.java:385-444). Returns
+    ``(state, took_dense)``.
 
     Joiners NOT in this cut stay pending into the new configuration: their
     UP edges remain armed (gatekeeper observers kept, fired edges re-stamped
     to round 0) so the alerts redeliver and a later cut admits them — unlike
     DOWN alerts, which re-fire from the persistent crash masks, a wiped UP
-    edge would never re-fire and the joiner would be stranded forever."""
+    edge would never re-fire and the joiner would be stranded forever.
+
+    The ring walk reads liveness by ring position from the state's
+    ``ring_alive`` lane, and this is the lane's one writer
+    (``ops/rings.ring_liveness_after_cut``): the cut's own positions are
+    flipped, K·B look-ups and updates where ``alive2[ring_perm]`` gathers
+    K·N, and a cut that overflows the bucket takes that gather.
+    ``took_dense`` says which, for the telemetry plane
+    (:func:`_count_dense_commit`). ``dense_arms`` (:func:`_compute_round`)
+    traces the gather alone and ``took_dense`` is True. ``batch_axis`` and
+    ``commits`` go down to the lane's conditional: under a ``vmap`` that
+    names its axis it stays a conditional, opened by the members whose cut
+    the caller commits."""
     n, k, c = cfg.n, cfg.k, cfg.c
     pol = compaction_policy(cfg)
     idt, cdt = jnp.dtype(pol.idx), jnp.dtype(pol.cohort)
     ndt, rdt = jnp.dtype(pol.counter), jnp.dtype(pol.round)
     alive2 = state.alive ^ winner_mask
+    if dense_arms:
+        ring_alive2 = ring_liveness(state.ring_perm, alive2)
+        took_dense = jnp.bool_(True)
+    else:
+        ring_alive2, took_dense = ring_liveness_after_cut(
+            state.ring_alive, state.ring_perm, state.ring_pos, alive2,
+            winner_mask, batch_axis, commits,
+        )
     # Sort-free: O(N) scans over the static key-order perms, not a K-ring
     # argsort — at N=1M the re-sort was the commit path's largest block.
     # The topology kernels compute at int32; stores narrow to the policy's
     # index dtype (lossless: values in [-1, n-1]).
-    topo = ring_topology_from_perm(state.ring_perm, alive2)
+    topo = ring_topology_from_perm(state.ring_perm, alive2, ring_alive2)
     config_hi, config_lo = masked_set_hash(state.id_hi, state.id_lo, alive2)
     still_pending = state.join_pending & ~winner_mask  # [n]
     fd_fired2 = state.fd_fired & still_pending[:, None]
-    return state._replace(
+    committed = state._replace(
         alive=alive2,
+        ring_alive=ring_alive2,
         # Departing members' identity lanes are spent forever.
         retired=state.retired | (winner_mask & state.alive),
         obs_idx=jnp.where(
@@ -955,22 +985,49 @@ def apply_view_change_impl(
         classic_epoch=jnp.zeros((), dtype=ndt),
         round_idx=jnp.int32(0),
     )
+    return committed, took_dense
 
 
-def _view_change_gate(cfg: EngineConfig, state: EngineState, decided, winner_mask):
+def _count_dense_commit(observers, took_dense):
+    """``observers`` with the commits that gathered ``ring_alive`` whole
+    (``took_dense``: this round's, False where none committed) added to the
+    telemetry plane's ``tl_view_change_dense``. Outside the gate's
+    conditional, so that no ``[c, n]`` lane rides through it as an operand;
+    with no observer nothing is traced."""
+    if not observers:
+        return observers
+    telem, *rest = observers
+    with scope("observers"):
+        telem = telem._replace(
+            tl_view_change_dense=telem.tl_view_change_dense
+            + took_dense.astype(jnp.int32)
+        )
+    return [telem, *rest]
+
+
+def _view_change_gate(
+    cfg: EngineConfig, state: EngineState, observers, decided, winner_mask,
+    batch_axis=None, dense_arms=False,
+):
     """THE gate around the commit for a body that carries no masks: the
-    view change if the round ``decided``, else the state as it came."""
-    return jax.lax.cond(
+    view change if the round ``decided``, else the state as it came.
+    Returns ``(state, observers)``, the telemetry plane's count of dense
+    commits brought up to date (:func:`_count_dense_commit`)."""
+    state, took_dense = jax.lax.cond(
         decided,
-        lambda s: apply_view_change_impl(cfg, s, winner_mask),
-        scope("view_keep")(lambda s: s),
+        lambda s: apply_view_change_impl(
+            cfg, s, winner_mask, batch_axis=batch_axis, commits=decided,
+            dense_arms=dense_arms,
+        ),
+        scope("view_keep")(lambda s: (s, jnp.bool_(False))),
         state,
     )
+    return state, _count_dense_commit(observers, took_dense)
 
 
 def _view_change_gate_masks(
-    cfg: EngineConfig, state: EngineState, faults: FaultInputs, masks,
-    decided, winner_mask,
+    cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
+    masks, decided, winner_mask,
 ):
     """The gate for the per-round step, which hands the per-edge masks
     back to its driver beside the state: the view change AND the mask
@@ -981,15 +1038,18 @@ def _view_change_gate_masks(
     the cut inside a streamed wave read what the arm built. (The whole-wave
     loop carries no masks across a commit: it builds at the head of each
     convergence, :func:`run_until_membership_impl`.) Returns ``(state,
-    masks)``, the masks those of the returned state and ``faults``."""
+    observers, masks)``, the masks those of the returned state and
+    ``faults``."""
 
     def commit(s):
-        committed = apply_view_change_impl(cfg, s, winner_mask)
-        return committed, _edge_masks(cfg, committed, faults)
+        committed, took_dense = apply_view_change_impl(cfg, s, winner_mask)
+        return committed, _edge_masks(cfg, committed, faults), took_dense
 
-    return jax.lax.cond(
-        decided, commit, scope("view_keep")(lambda s: (s, masks)), state
+    state, masks, took_dense = jax.lax.cond(
+        decided, commit,
+        scope("view_keep")(lambda s: (s, masks, jnp.bool_(False))), state,
     )
+    return state, _count_dense_commit(observers, took_dense), masks
 
 
 # Every round program below follows ONE convention, the one
@@ -1028,7 +1088,7 @@ def _lane_off(outputs, *lanes):
 
 def engine_step_impl(
     cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None,
-    dense_invalidation=False,
+    dense_arms=False,
 ):
     """One full protocol round including conditional view-change application:
     the MESH's per-round step (``sharded_program("step")``) and the
@@ -1039,11 +1099,13 @@ def engine_step_impl(
     (round_state, decided, winner_mask, events, *observers), links, paths = _lane_off(
         _compute_round(
             cfg, state, faults, None, *observers, links=links, paths=paths,
-            dense_invalidation=dense_invalidation,
+            dense_arms=dense_arms,
         ),
         links, paths,
     )
-    new_state = _view_change_gate(cfg, round_state, decided, winner_mask)
+    new_state, observers = _view_change_gate(
+        cfg, round_state, observers, decided, winner_mask, dense_arms=dense_arms
+    )
     return (new_state, *observers, events, *_lane_tail(links, paths))
 
 
@@ -1080,8 +1142,8 @@ def engine_step_carried_impl(
         ),
         links, paths,
     )
-    new_state, masks = _view_change_gate_masks(
-        cfg, round_state, faults, masks, decided, winner_mask
+    new_state, observers, masks = _view_change_gate_masks(
+        cfg, round_state, observers, faults, masks, decided, winner_mask
     )
     return (new_state, *observers, events, masks, *_lane_tail(links, paths))
 
@@ -1143,6 +1205,7 @@ def telemetry_digest_impl(telem: TelemetryLanes) -> jnp.ndarray:
             telem.tl_dissent,
             telem.tl_invalidation_rounds,
             telem.tl_invalidation_dense_rounds,
+            telem.tl_view_change_dense,
         ]),
         telem.tl_undecided_hist,
     ])
@@ -1207,7 +1270,7 @@ sync_checksum = jax.jit(sync_checksum_impl)  # donate-ok: read-only barrier; the
 def _converge(
     cfg: EngineConfig, state: EngineState, observers, faults: FaultInputs,
     masks, steps, max_steps, batch_axis=None, links=None, paths=None,
-    dense_invalidation=False,
+    dense_arms=False,
 ):
     """THE inner convergence loop: rounds over fixed per-edge ``masks``
     (topology and faults are fixed until a cut commits, so the per-edge
@@ -1226,7 +1289,7 @@ def _converge(
     carry's end and ages with every round, and its one gather (the loss at
     every edge's observer) is made here, once, beside the masks. The
     consensus-path counts (``paths``) ride behind it the same way.
-    ``dense_invalidation`` goes down to the round like ``batch_axis``."""
+    ``dense_arms`` goes down to the round like ``batch_axis``."""
     observer_loss = None if links is None else _observer_loss(cfg, state, links)
 
     def cond(carry):
@@ -1239,7 +1302,7 @@ def _converge(
             _compute_round(
                 cfg, state, faults, masks, *observers, batch_axis=batch_axis,
                 links=links, observer_loss=observer_loss, paths=paths,
-                dense_invalidation=dense_invalidation,
+                dense_arms=dense_arms,
             ),
             links, paths,
         )
@@ -1265,7 +1328,7 @@ def _converge(
 
 def run_to_decision_impl(
     cfg: EngineConfig, state: EngineState, *rest, batch_axis=None, links=None,
-    paths=None, dense_invalidation=False,
+    paths=None, dense_arms=False,
 ):
     """Protocol rounds until a view change commits — entirely on device.
 
@@ -1283,9 +1346,11 @@ def run_to_decision_impl(
     masks = _edge_masks(cfg, state, faults)
     state, observers, steps, decided, winner, arm_rounds, links, paths = _converge(
         cfg, state, observers, faults, masks, jnp.int32(0), max_steps, batch_axis,
-        links, paths, dense_invalidation,
+        links, paths, dense_arms,
     )
-    state = _view_change_gate(cfg, state, decided, winner)
+    state, observers = _view_change_gate(
+        cfg, state, observers, decided, winner, batch_axis, dense_arms
+    )
     lanes = _lane_tail(links, paths)
     if batch_axis is None:
         return (state, *observers, steps, decided, winner, *lanes)
@@ -1294,7 +1359,7 @@ def run_to_decision_impl(
 
 def run_until_membership_impl(
     cfg: EngineConfig, state: EngineState, *rest, links=None, paths=None,
-    dense_invalidation=False,
+    dense_arms=False,
 ):
     """Protocol rounds through MULTIPLE view changes until the membership
     reaches ``target`` — one device dispatch for a whole churn/bootstrap
@@ -1342,9 +1407,11 @@ def run_until_membership_impl(
         masks = _edge_masks(cfg, state, faults)
         state, observers, steps, decided, winner, _, links, paths = _converge(
             cfg, state, observers, faults, masks, steps, max_steps, links=links,
-            paths=paths, dense_invalidation=dense_invalidation,
+            paths=paths, dense_arms=dense_arms,
         )
-        state = _view_change_gate(cfg, state, decided, winner)
+        state, observers = _view_change_gate(
+            cfg, state, observers, decided, winner, dense_arms=dense_arms
+        )
         with scope("loop_result"):
             sizes = jnp.where(
                 decided, sizes.at[cuts].set(state.n_members), sizes

@@ -10,6 +10,8 @@ from rapid_tpu.ops.rings import (
     RingTopology,
     endpoint_ring_keys,
     predecessor_of_keys,
+    ring_liveness,
+    ring_liveness_after_cut,
     ring_perms,
     ring_positions,
     ring_topology,
@@ -30,6 +32,8 @@ __all__ = [
     "RingTopology",
     "endpoint_ring_keys",
     "predecessor_of_keys",
+    "ring_liveness",
+    "ring_liveness_after_cut",
     "ring_perms",
     "ring_positions",
     "ring_topology",

@@ -153,7 +153,7 @@ def _count_below(table: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(table < t[:, None], axis=1, dtype=jnp.int32)
 
 
-def _first_set_slots(need: jnp.ndarray, cap: int) -> jnp.ndarray:
+def first_set_slots(need: jnp.ndarray, cap: int) -> jnp.ndarray:
     """The positions of the first ``cap`` set entries of ``need[n]``, in
     order; entries past the count of set ones are some slot of ``[0, n)``.
 
@@ -323,7 +323,7 @@ def cohort_watermark_pass(
             # slot named once, nothing for a padding row, whatever a backend
             # makes of a repeated index.
             live = jnp.arange(cap, dtype=jnp.int32) < count
-            idx = jnp.where(live, _first_set_slots(need, cap), 0)
+            idx = jnp.where(live, first_set_slots(need, cap), 0)
             in_union = (stable & ~released) | flux  # [c, n]
             bdt = report_bits.dtype
             armed = flux[:, idx] & seen_down[:, None] & live[None, :]  # [c, cap]

@@ -18,9 +18,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from rapid_tpu.ops.cut_detection import first_set_slots
 from rapid_tpu.ops.hashing import lex_argsort
 from rapid_tpu.protocol.view import ring_key
-from rapid_tpu.utils.dispatch import scope
+from rapid_tpu.utils.dispatch import cond_across, scope
 
 
 class RingTopology(NamedTuple):
@@ -148,7 +149,7 @@ def _ordered_uint32(word):
 
 
 @jax.jit
-def _from_perm_single(perm, alive):
+def _from_perm_single(perm, alive, ao=None):
     """One ring, sort-free: (obs_idx[N], subj_idx[N], order[N]) from the
     static key order. Successor among alive = slot at the next alive
     position in the fixed circular order, predecessor = slot at the
@@ -188,9 +189,14 @@ def _from_perm_single(perm, alive):
     the walk's forty small operations: ``initial_state`` below
     :data:`RING_AT_A_TIME_SLOTS` is eager, and a fleet builds hundreds of
     tenants through it. Inside a traced caller the jit is inlined.
+
+    ``ao`` is the alive bit per ring position, ``alive[perm]``, where the
+    caller holds it (``EngineState.ring_alive``, kept exact by every view
+    change: :func:`ring_liveness_after_cut`); without it the walk gathers it.
     """
     n = perm.shape[0]
-    ao = alive[perm]  # alive bit per ring position
+    if ao is None:
+        ao = alive[perm]  # alive bit per ring position
     n_alive = jnp.sum(ao.astype(jnp.int32))
     piece_bits, pieces = ring_walk_pieces(n)
     pos = jnp.arange(n, dtype=jnp.uint32)
@@ -229,14 +235,14 @@ def _from_perm_single(perm, alive):
     # sentinel, never as valid slot 0.
     obs_idx = jnp.full((n,), -1, dtype=jnp.int32).at[perm].set(succ_slot)
     subj_idx = jnp.full((n,), -1, dtype=jnp.int32).at[perm].set(pred_slot)
-    return obs_idx, subj_idx, _alive_first_order(perm, alive)
+    return obs_idx, subj_idx, _alive_first_order(perm, ao)
 
 
-def _alive_first_order(perm, alive):
+def _alive_first_order(perm, ao):
     """``lex_argsort((dead, keys...))`` without the sort: stable partition
-    of the static key order into alive-first via rank scans + one scatter."""
+    of the static key order into alive-first via rank scans + one scatter
+    (``ao``: the alive bit per ring position)."""
     n = perm.shape[0]
-    ao = alive[perm]
     n_alive = jnp.sum(ao.astype(jnp.int32))
     alive_rank = jnp.cumsum(ao.astype(jnp.int32)) - 1
     dead_rank = n_alive + jnp.cumsum((~ao).astype(jnp.int32)) - 1
@@ -286,7 +292,17 @@ def _alive_first_order(perm, alive):
 RING_AT_A_TIME_SLOTS = 32_000
 
 
-def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopology:
+def _per_ring(fn, *rows):
+    """``fn`` over the leading (ring) axis of ``rows``, under the schedule
+    the ring length picks (:data:`RING_AT_A_TIME_SLOTS`)."""
+    if rows[0].shape[-1] >= RING_AT_A_TIME_SLOTS:
+        return jax.lax.map(lambda ring: fn(*ring), rows)
+    return jax.vmap(fn)(*rows)
+
+
+def ring_topology_from_perm(
+    perm: jnp.ndarray, alive: jnp.ndarray, ring_alive=None
+) -> RingTopology:
     """``ring_topology`` without the sort: derive all K rings' topology from
     the static key-order permutations (``ring_perms``) and the current alive
     mask with O(N) scans. Output is bit-identical to ``ring_topology``
@@ -298,17 +314,102 @@ def ring_topology_from_perm(perm: jnp.ndarray, alive: jnp.ndarray) -> RingTopolo
     directly; the returned tables are int32 (position arithmetic
     accumulates wide here) and the caller narrows on store. Long rings go
     one at a time (:data:`RING_AT_A_TIME_SLOTS`), same values; a ring's walk
-    scans one word pair a piece of the slot (:func:`ring_walk_pieces`)."""
+    scans one word pair a piece of the slot (:func:`ring_walk_pieces`).
+
+    ``ring_alive`` is :func:`ring_liveness` of the same ``perm`` and
+    ``alive`` where the caller holds it (the engine's state does): the walk
+    then reads liveness by position from it and gathers nothing by ``perm``."""
     perm, alive = jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
-    if perm.shape[-1] >= RING_AT_A_TIME_SLOTS:
-        obs, subj, order = jax.lax.map(
-            lambda ring: _from_perm_single(ring, alive), perm
-        )
-    else:
-        obs, subj, order = jax.vmap(_from_perm_single, in_axes=(0, None))(
-            perm, alive
-        )
+    rows = (perm,) if ring_alive is None else (perm, jnp.asarray(ring_alive, dtype=bool))
+    obs, subj, order = _per_ring(
+        lambda ring, ao=None: _from_perm_single(ring, alive, ao), *rows
+    )
     return RingTopology(obs_idx=obs, subj_idx=subj, order=order)
+
+
+def ring_liveness(perm: jnp.ndarray, alive: jnp.ndarray) -> jnp.ndarray:
+    """Liveness by ring position, ``[K, N]`` bool: ``ring_alive[k, p] ==
+    alive[perm[k, p]]``. The walk's one gather (a scalar look-up for every
+    position of every ring: 7.6 ms a ring at 1M on the v5e, the dearest
+    form there is), made ONCE where the state is made or loaded
+    (``EngineState.ring_alive``); a view change then flips its cut's own
+    positions (:func:`ring_liveness_after_cut`) and comes here only for a
+    cut its bucket cannot hold, or in a program whose node axis is
+    sharded (a compaction is a global operation over that axis)."""
+    perm, alive = jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
+    return _per_ring(lambda ring: alive[ring], perm)
+
+
+def view_change_bucket(n: int) -> int:
+    """Slots of a cut that a view change flips in place
+    (:func:`ring_liveness_after_cut`): an eighth of the ``n`` slots, rounded
+    up to whole 128-lane tiles as ``ops/cut_detection.invalidation_bucket``
+    is. The largest cut any cell commits is a bootstrap wave's 242 joiners
+    of 2,000 slots (12.1 %; 5,000 of 102,500, 10,000 of 1,000,000 and 16 of
+    1,000 elsewhere); a cut with more members gathers the lane whole
+    (:func:`ring_liveness`). A size read off the shape, not an option."""
+    return -(-n // (8 * 128)) * 128
+
+
+def _flip_positions(ring_alive, ring_pos, alive, slots):
+    """``ring_alive`` with ``alive[s]`` written at ``ring_pos[k, s]`` for
+    every ``s`` of ``slots`` (``[B]``) and every ring k: K·B look-ups of
+    ``ring_pos`` and K·B updates. Right for ANY slot, so ``slots`` may
+    repeat and may name slots whose bit did not change: no mask of valid
+    entries. ONE ``[K, B]`` scatter at every ring length: unlike the walk's
+    N-update scatter, which long rings take one at a time
+    (:data:`RING_AT_A_TIME_SLOTS`), B updates a ring into ``[K, N]`` run
+    faster together than a ring at a time (14.4 against 21.5 ms at 1M, 1.9
+    against 3.1 at 102,500, a tie under the fleets' ``vmap``; ``bool``,
+    ``int8`` and ``int32`` lanes within 15 % of each other: PERF.md section
+    6, PR 50's probe, TPU v5e)."""
+    rings = jnp.arange(ring_alive.shape[0], dtype=jnp.int32)[:, None]
+    at = ring_pos[:, slots].astype(jnp.int32)  # stored at int8 / int16 by the compact engine
+    return ring_alive.at[rings, at].set(alive[slots][None, :])
+
+
+def ring_liveness_after_cut(
+    ring_alive: jnp.ndarray, ring_perm: jnp.ndarray, ring_pos: jnp.ndarray,
+    alive: jnp.ndarray, cut: jnp.ndarray, batch_axis=None, commits=None,
+):
+    """:func:`ring_liveness` of ``ring_perm`` and ``alive``, the membership
+    AFTER a cut, from ``ring_alive``, the lane of the membership before it:
+    the work is the cut's and not the ring's. ``cut`` (``[N]`` bool) names at
+    least every slot whose bit differs; its set slots are compacted into one
+    bucket read off the slot count (:func:`view_change_bucket`; the form of
+    ``ops/cut_detection.first_set_slots``, whose filler entries are some
+    slot, harmless here) and flipped where they sit on each ring
+    (:func:`_flip_positions`). A cut with more members than the bucket holds
+    takes the whole gather, the overflow arm and the oracle, so the result
+    is exact for any cut. Returns ``(lane, took_dense)``, the second a bool:
+    this cut took the gather.
+
+    ``batch_axis`` names the batch axis of an enclosing ``vmap`` (the
+    meshless fleet programs hand one): the conditional is then taken on
+    "some member's cut overflows" (``utils/dispatch.cond_across``) and stays
+    a conditional, where an unnamed ``vmap`` would make it a select that
+    runs the gather for everybody, always. ``commits`` (this member's
+    ``[]`` bool, or ``None``) is whether the caller keeps this member's
+    result at all: a cut it drops never opens the arm for the batch."""
+    ring_pos = jnp.asarray(ring_pos)
+    alive, cut = jnp.asarray(alive, dtype=bool), jnp.asarray(cut, dtype=bool)
+    # Made outside the conditional: what an arm captures becomes its operand
+    # and stays in HBM (PERF.md section 6, PR 43); the K·N arrays are there
+    # anyway.
+    bucket = view_change_bucket(cut.shape[0])
+    slots = first_set_slots(cut, bucket)
+    overflows = jnp.sum(cut, dtype=jnp.int32) > bucket
+    if commits is not None:
+        overflows = overflows & commits
+    lane, _ = cond_across(
+        batch_axis,
+        overflows,
+        lambda lane, slots: ring_liveness(ring_perm, alive),
+        lambda lane, slots: _flip_positions(lane, ring_pos, alive, slots),
+        jnp.asarray(ring_alive, dtype=bool),
+        slots,
+    )
+    return lane, overflows
 
 
 @jax.jit
@@ -323,10 +424,7 @@ def ring_positions(perm: jnp.ndarray) -> jnp.ndarray:
     (:data:`RING_AT_A_TIME_SLOTS`)."""
     perm = jnp.asarray(perm)
     where = jnp.arange(perm.shape[-1], dtype=perm.dtype)
-    invert = lambda ring: jnp.zeros_like(ring).at[ring].set(where)
-    if perm.shape[-1] >= RING_AT_A_TIME_SLOTS:
-        return jax.lax.map(invert, perm)
-    return jax.vmap(invert)(perm)
+    return _per_ring(lambda ring: jnp.zeros_like(ring).at[ring].set(where), perm)
 
 
 @jax.jit

@@ -101,7 +101,7 @@ class ShardingShapeError(ValueError):
 #: justify itself with ``# replicated-ok: <reason>`` on its line.
 PARTITION_RULES: Tuple[Tuple[str, Spec], ...] = (
     # [k, n] ring/key/topology tables: slots on the last axis.
-    (r"key_hi|key_lo|ring_perm|ring_pos|obs_idx|inval_obs", (None, NODE_AXIS)),
+    (r"key_hi|key_lo|ring_perm|ring_pos|ring_alive|obs_idx|inval_obs", (None, NODE_AXIS)),
     # [n, k] per-edge failure-detector state: slots on the first axis.
     (r"fd_count|fd_hist|fd_fired|fire_round|probe_fail", (NODE_AXIS, None)),
     # [c] cohort lanes (watermark flags + proposal-id lanes): sharded over
@@ -132,6 +132,7 @@ PARTITION_RULES: Tuple[Tuple[str, Spec], ...] = (
         r"tl_rounds|tl_alerts|tl_tally_sum|tl_fast_decisions"
         r"|tl_classic_decisions|tl_conflict_rounds|tl_dissent"
         r"|tl_invalidation_rounds|tl_invalidation_dense_rounds"
+        r"|tl_view_change_dense"
         r"|tl_undecided_hist",
         (),  # replicated-ok: per-engine scalar counters + the 8-bucket histogram
     ),
@@ -488,16 +489,19 @@ def sharded_program(
         state_shardings(mesh), telemetry_shardings(mesh), trace_shardings(mesh)
     )[:carried]
     # The invalidation arm's compacted form reduces over the cohort axis and
-    # compacts over the node axis, both of which a mesh shards: every
-    # sharded program keeps the dense loop (ops/cut_detection.py).
+    # compacts over the node axis, and the view change's bounded update of
+    # ``ring_alive`` compacts its cut over the node axis too; a mesh shards
+    # both, so every sharded program keeps the dense forms: the dense loop
+    # (ops/cut_detection.py) and the whole ``alive[ring_perm]`` gather
+    # (ops/rings.ring_liveness), the operations these programs always ran.
     if verb == "wave":
         def program(*args):
             return impl(
-                cfg, *args[:-1], max_cuts, args[-1], dense_invalidation=True
+                cfg, *args[:-1], max_cuts, args[-1], dense_arms=True
             )
     else:
         def program(*args):
-            return impl(cfg, *args, dense_invalidation=True)
+            return impl(cfg, *args, dense_arms=True)
     return jax.jit(
         program,
         in_shardings=(*tables, fault_shardings(mesh), *(None,) * controls),

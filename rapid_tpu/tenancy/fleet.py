@@ -127,6 +127,7 @@ from rapid_tpu.models.virtual_cluster import (
     CarriedMasks,
     VirtualCluster,
     _compute_round,
+    _count_dense_commit,
     _edge_masks,
     apply_view_change_impl,
     delivery_delays,
@@ -259,7 +260,7 @@ def fleet_step_impl(
     def one(state, faults, kn):
         # no named axis: a nested conditional would be a select (both forms)
         return engine_step_impl(
-            _tenant_cfg(cfg, kn), state, faults, dense_invalidation=True
+            _tenant_cfg(cfg, kn), state, faults, dense_arms=True
         )
 
     return jax.vmap(one)(state, faults, knobs)
@@ -371,29 +372,39 @@ def _gated_round(
     commits = decided if active is None else decided & active
 
     def commit_one(kn, round_state, winner, commits):
-        committed = apply_view_change_impl(_tenant_cfg(cfg, kn), round_state, winner)
+        committed, took_dense = apply_view_change_impl(
+            _tenant_cfg(cfg, kn), round_state, winner,
+            batch_axis=FLEET_BATCH_AXIS, commits=commits,
+        )
         with scope("view_change"):
             return jax.tree_util.tree_map(
                 lambda com, rnd: jnp.where(commits, com, rnd), committed, round_state
-            )
+            ), took_dense
 
     def commit(s):
-        return jax.vmap(commit_one)(knobs, s, winner, commits)
+        # named, so that the view change's overflow arm stays a conditional
+        # on "some committing tenant's cut overflows" (apply_view_change_impl)
+        return jax.vmap(commit_one, axis_name=FLEET_BATCH_AXIS)(
+            knobs, s, winner, commits
+        )
 
     def commit_and_build(s):
-        committed = commit(s)
-        return committed, fleet_edge_masks_impl(cfg, committed, faults)
+        committed, took_dense = commit(s)
+        return committed, fleet_edge_masks_impl(cfg, committed, faults), took_dense
 
     any_commits = jnp.any(commits)
+    nobody = jnp.zeros_like(commits)
     if rebuild_masks:
-        new_state, masks = jax.lax.cond(
+        new_state, masks, took_dense = jax.lax.cond(
             any_commits, commit_and_build,
-            scope("view_keep")(lambda s: (s, masks)), round_state,
+            scope("view_keep")(lambda s: (s, masks, nobody)), round_state,
         )
     else:
-        new_state = jax.lax.cond(
-            any_commits, commit, scope("view_keep")(lambda s: s), round_state
+        new_state, took_dense = jax.lax.cond(
+            any_commits, commit, scope("view_keep")(lambda s: (s, nobody)),
+            round_state,
         )
+    round_observers = _count_dense_commit(round_observers, took_dense)
     gates = jnp.concatenate([any_commits.astype(jnp.int32)[None], arms_ran])
     if active is not None:
         new_state, round_observers = jax.vmap(
@@ -598,10 +609,13 @@ def fleet_wave_lockstep_impl(cfg: EngineConfig, state: EngineState, *rest):
             state, *observers, steps, cuts, sizes, done = carry
             active = ~done & (steps < max_steps)
             round_state, decided, winner, _, *round_observers = _compute_round(
-                tcfg, state, faults, None, *observers, dense_invalidation=True
+                tcfg, state, faults, None, *observers, dense_arms=True
             )
-            committed = apply_view_change_impl(tcfg, round_state, winner)
+            committed, took_dense = apply_view_change_impl(
+                tcfg, round_state, winner, dense_arms=True
+            )
             commit = active & decided
+            round_observers = _count_dense_commit(round_observers, commit & took_dense)
             picked = jax.tree_util.tree_map(
                 lambda old, rnd, com: jnp.where(
                     active, jnp.where(commit, com, rnd), old

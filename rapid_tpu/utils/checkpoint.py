@@ -255,17 +255,26 @@ def _open_npz(path) -> _LoadedNpz:
         ) from exc
 
 
-#: EngineState lanes that are functions of the key lanes alone: never
-#: written by :func:`save_engine_state`, always recomputed on load.
-_DERIVED_LANES = ("ring_perm", "ring_pos")
+#: EngineState lanes that are functions of other lanes (``ring_perm`` and
+#: its inverse of the keys, ``ring_alive`` of ``ring_perm`` and ``alive``):
+#: never written by :func:`save_engine_state`, always recomputed on load,
+#: in this order.
+_DERIVED_LANES = ("ring_perm", "ring_pos", "ring_alive")
+
+#: The one of them a serving checkpoint leaves out as well: it holds the
+#: perms and the membership at their stored shapes, and the lane is what
+#: the one says of the other. No writer ever wrote it, so every archive
+#: loads alike.
+_SERVING_DERIVED_LANES = ("ring_alive",)
 
 
 def save_engine_state(path, cfg: "EngineConfig", state: "EngineState") -> None:
     arrays = {field: np.asarray(value) for field, value in state._asdict().items()}
     # Derived data is never persisted: ring_perm and its inverse ring_pos
-    # are pure functions of the key lanes, and loading a stale/corrupted
-    # copy would silently diverge topology from the keys. Load always
-    # recomputes them (one sort, one scatter a ring).
+    # are pure functions of the key lanes (and ring_alive of ring_perm and
+    # alive), and loading a stale/corrupted copy would silently diverge
+    # topology from the keys. Load always recomputes them (one sort, one
+    # scatter and one gather a ring).
     for derived in _DERIVED_LANES:
         arrays.pop(derived, None)
     _atomic_write(path, _seal(_npz_bytes({**_cfg_entries(cfg), **arrays})))
@@ -337,6 +346,11 @@ def load_engine_state(path) -> Tuple["EngineConfig", "EngineState"]:
             ).astype(dts["ring_perm"]),
             # ... and its inverse from it (EngineState lists it after).
             "ring_pos": lambda: _ring_positions_of(arrays["ring_perm"]),
+            # ... and liveness by ring position from the perms and the
+            # (always-saved) membership.
+            "ring_alive": lambda: _ring_liveness_of(
+                arrays["ring_perm"], jnp.asarray(data["alive"])
+            ),
         }
         arrays = {}
         for field in EngineState._fields:
@@ -376,7 +390,9 @@ def save_serving_state(
     bit-packed, and fleet-stacked layouts all come back bit-identical
     (unlike :func:`save_engine_state`, ``ring_perm`` is persisted too: the
     stacked/packed shapes cannot be re-derived by the single-cluster
-    recompute, and bit-exact resume is the whole point here). ``meta`` is a
+    recompute, and bit-exact resume is the whole point here; ``ring_alive``
+    alone is left out and rebuilt from the two lanes it is a function of).
+    ``meta`` is a
     small JSON-serializable dict (the supervisor's wave cursor). ``links`` is
     a cluster's link-fault lane where one is set (``VirtualCluster.links``);
     :func:`load_link_faults` reads it back. Sealed + atomic like every
@@ -391,6 +407,8 @@ def save_serving_state(
         if tree is None:
             continue
         for field, value in tree._asdict().items():
+            if prefix == "state" and field in _SERVING_DERIVED_LANES:
+                continue
             entries[f"{prefix}__{field}"] = np.asarray(value)
     _atomic_write(path, _seal(_npz_bytes(entries)))
 
@@ -417,6 +435,11 @@ def load_serving_state(path):
             arrays = {}
             for field in cls._fields:
                 key = f"{prefix}__{field}"
+                if prefix == "state" and field in _SERVING_DERIVED_LANES:
+                    arrays[field] = _ring_liveness_of(
+                        arrays["ring_perm"], jnp.asarray(data["state__alive"])
+                    )
+                    continue
                 if key == "state__ring_pos" and key not in data:
                     # A writer older than the lane: it is the inverse of the
                     # perms the archive does hold, a tenant at a time.
@@ -448,6 +471,20 @@ def _ring_positions_of(perm):
     from rapid_tpu.ops.rings import ring_positions
 
     return ring_positions(perm) if perm.ndim == 2 else jax.vmap(ring_positions)(perm)
+
+
+def _ring_liveness_of(perm, alive):
+    """``ring_liveness`` of a cluster's ``[k, n]`` perms and ``[n]``
+    membership or a fleet's stacked ones; a bit-packed membership
+    (``models/state.pack_masks``) is unpacked for the look-up."""
+    import jax
+
+    from rapid_tpu.models.state import unpack_bool
+    from rapid_tpu.ops.rings import ring_liveness
+
+    if alive.dtype != bool:
+        alive = unpack_bool(alive)
+    return ring_liveness(perm, alive) if perm.ndim == 2 else jax.vmap(ring_liveness)(perm, alive)
 
 
 def load_link_faults(path):

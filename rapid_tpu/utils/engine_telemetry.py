@@ -452,6 +452,7 @@ TELEMETRY_DIGEST_FIELDS = (
     "dissent",
     "invalidation_rounds",
     "invalidation_dense_rounds",
+    "view_change_dense",
 )
 
 

@@ -223,6 +223,7 @@ _ENGINE_ACTIVITY_COUNTERS = (
     "dissent",
     "invalidation_rounds",
     "invalidation_dense_rounds",
+    "view_change_dense",
 )
 
 #: ``engine.activity`` derived gauges (``rapid_engine_activity_<name>``):

@@ -382,6 +382,63 @@ def test_a_serving_checkpoint_older_than_ring_pos_gets_it_from_its_perms(tmp_pat
     _trees_bit_identical(state, served.state)
 
 
+@pytest.mark.parametrize("target", ["cluster", "compact_cluster", "packed_cluster", "fleet"])
+@pytest.mark.parametrize("archive", ["as_written", "with_a_stale_lane"])
+def test_no_checkpoint_holds_ring_alive_and_every_load_rebuilds_it(tmp_path, target, archive):
+    """``EngineState.ring_alive`` (PR 50) is what ``ring_perm`` says of
+    ``alive``: neither writer stores it, so an archive written before the
+    lane is an archive written now, and a load gives the lane the engine
+    holds, a tenant at a time, after a view change has moved it, from a
+    bit-packed membership too. A file that does hold one (no writer of this
+    repo's) has it ignored, as ``ring_perm`` is in an engine checkpoint."""
+    import io
+
+    from rapid_tpu.models.state import pack_masks, unpack_masks
+
+    if target == "fleet":
+        from rapid_tpu.tenancy import TenantFleet
+
+        served = TenantFleet.from_clusters([_small_cluster(seed=s) for s in (5, 6)])
+        served.faults = served.faults._replace(
+            crashed=served.faults.crashed.at[0, 3].set(True).at[1, 7].set(True))
+        served.run_to_decision(max_steps=32)
+        knobs = served.knobs
+    else:
+        served, knobs = _small_cluster(compact=target == "compact_cluster"), None
+        served.crash([3, 7])
+        served.run_until_converged(max_steps=32)
+    state, faults = served.state, served.faults
+    assert not np.asarray(state.ring_alive).all()  # the commit moved the lane
+    if target == "packed_cluster":
+        state, faults = pack_masks(state), pack_masks(faults)
+    writers = [("serving", "state__ring_alive", "state__alive")]
+    if target in ("cluster", "compact_cluster"):
+        writers.append(("engine", "ring_alive", "alive"))
+    for writer, key, alive_key in writers:
+        path = tmp_path / f"{writer}.npz"
+        if writer == "serving":
+            save_serving_state(path, served.cfg, state, faults, knobs=knobs)
+        else:
+            save_engine_state(path, served.cfg, state)
+        with np.load(io.BytesIO(path.read_bytes()[:-12])) as data:  # less the seal
+            held = {k: data[k] for k in data.files}
+        assert key not in held and alive_key in held
+        if archive == "with_a_stale_lane":
+            held[key] = ~np.asarray(served.state.ring_alive)
+            buf = io.BytesIO()
+            np.savez_compressed(buf, **held)
+            path.write_bytes(buf.getvalue())
+        loaded = load_serving_state(path)[1] if writer == "serving" else load_engine_state(path)[1]
+        _trees_bit_identical(loaded, state)
+        lane = np.asarray(loaded.ring_alive)
+        alive = np.asarray((unpack_masks(loaded) if target == "packed_cluster" else loaded).alive)
+        perm = np.asarray(loaded.ring_perm)
+        for t in range(alive.size // alive.shape[-1]):
+            np.testing.assert_array_equal(
+                lane.reshape(-1, *lane.shape[-2:])[t],
+                alive.reshape(-1, alive.shape[-1])[t][perm.reshape(-1, *perm.shape[-2:])[t]])
+
+
 def test_wide_checkpoint_loads_under_a_compact_config(tmp_path):
     """Migration path: a checkpoint written by a WIDE deployment is brought
     up compact — validate the envelope, narrow, and the widened view is

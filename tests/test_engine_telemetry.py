@@ -839,6 +839,7 @@ GOLDEN_ACTIVITY_METRIC_NAMES = [
     "rapid_engine_activity_rounds_total",
     "rapid_engine_activity_rounds_undecided_total",
     "rapid_engine_activity_tally_sum_total",
+    "rapid_engine_activity_view_change_dense_total",
     "rapid_engine_activity_winning_tally_mean",
     "rapid_engine_decision_path_total",
 ]
@@ -926,9 +927,15 @@ def test_the_invalidation_lanes_count_the_rounds_a_tenant_needed_the_arm():
         assert activity["invalidation_dense_rounds"] == 0
     digest = np.asarray(fleet_digest(fleet))
     fields = engine_telemetry.TELEMETRY_DIGEST_FIELDS
-    assert fields[-2:] == ("invalidation_rounds", "invalidation_dense_rounds")
-    assert digest[:, len(fields) - 2].tolist() == own
+    assert fields[-3:] == (
+        "invalidation_rounds", "invalidation_dense_rounds", "view_change_dense",
+    )
+    assert digest[:, len(fields) - 3].tolist() == own
+    assert digest[:, len(fields) - 2].tolist() == [0] * 3
+    # ... and every commit flipped its cut's own ring positions: no tenant's
+    # cut (3 and 1 of 32 slots) overflowed the view change's bucket of 128.
     assert digest[:, len(fields) - 1].tolist() == [0] * 3
+    assert sum(int(a["decisions_fast"]) for a in fleet.tenant_activity) == 2
     text = fleet.prometheus_text()
     assert (
         'rapid_engine_activity_invalidation_rounds_total'

@@ -200,6 +200,35 @@ def test_a_mesh_program_keeps_its_round_loop_reduce_class(name):
         assert entry["cross_tenant_collectives"] == 0
 
 
+#: Where a mesh program's view change lies (``hlo_facts.classify_location``)
+#: and the collective KINDS that location holds: kinds, not counts or bytes,
+#: so a compiler's regrouping moves nothing here.
+MESH_COMMIT_KINDS = {
+    "sharded_step": ("cond", {"all-gather", "all-reduce", "collective-permute"}),
+    "sharded_step_telem": ("cond", {"all-gather", "all-reduce", "collective-permute"}),
+    "sharded_wave": ("wave-loop-cond", {"all-gather", "all-reduce", "collective-permute"}),
+    "sharded2d_wave": (
+        "wave-loop-cond", {"all-gather", "all-reduce", "all-to-all", "collective-permute"},
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MESH_COMMIT_KINDS))
+def test_a_mesh_programs_commit_holds_the_collective_kinds_it_always_held(name):
+    """``EngineState.ring_alive`` (PR 50) is kept on a mesh by the gather the
+    walk always made there (``dense_arms``: no compaction over a sharded node
+    axis), so the arm that holds the view change communicates as it did
+    before the lane: the kinds below are the parent's, and the lane adds
+    none."""
+    where, kinds = MESH_COMMIT_KINDS[name]
+    found = {
+        key.split("/", 1)[1]
+        for key in staticcheck.collect_facts()[name]["collectives"]
+        if key.split("/", 1)[0] == where
+    }
+    assert found == kinds
+
+
 def test_2d_wave_round_loop_adds_only_scalar_kinds_to_the_live_1d_waves():
     """ISSUE 9 acceptance, against the LIVE 1-D wave: meshing the cohort
     axis must not smuggle new [n]-or-larger unconditional traffic into the

@@ -1,6 +1,6 @@
 """The compacted implicit-invalidation arm at engine level: twins of the
 benchmark's cells' traffic driven through the drivers' own programs and through
-the same programs with the dense loop alone (``dense_invalidation=True``, what
+the same programs with the dense loop alone (``dense_arms=True``, what
 ``parallel/mesh.sharded_program`` builds and what every program was before the
 arm was compacted): same rounds, same cuts, same view, same state leaf for
 leaf, and the telemetry plane's two lanes say which form ran. The pass itself,
@@ -108,9 +108,9 @@ def drive() -> None:
 
     dense_programs = {
         "decision": vcm.jit_per_observer_count(
-            functools.partial(vcm.run_to_decision_impl, dense_invalidation=True)),
+            functools.partial(vcm.run_to_decision_impl, dense_arms=True)),
         "wave": vcm.jit_per_observer_count(
-            functools.partial(vcm.run_until_membership_impl, dense_invalidation=True),
+            functools.partial(vcm.run_until_membership_impl, dense_arms=True),
             static=(5,)),
     }
     own_programs = dict(vcm._ROUND_PROGRAMS)

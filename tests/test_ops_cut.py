@@ -17,7 +17,7 @@ import pytest
 
 from rapid_tpu.ops.cut_detection import (
     CutState,
-    _first_set_slots,
+    first_set_slots,
     alerts_to_report_matrix,
     cohort_watermark_pass,
     invalidation_bucket,
@@ -423,7 +423,7 @@ def test_the_compaction_looks_the_set_slots_up_in_order(n):
     # one level of rows to 131,072 slots (1,024 rows of 128), two beyond;
     # nothing set, a few, exactly the bucket, more than it, everything
     cap = invalidation_bucket(n)
-    compact = jax.jit(_first_set_slots, static_argnums=1)
+    compact = jax.jit(first_set_slots, static_argnums=1)
     rng = np.random.default_rng(n)
     for share in (0.0, 0.001, 0.05, None, 0.2, 1.0):
         need = rng.random(n) < share if share is not None else np.zeros(n, dtype=bool)

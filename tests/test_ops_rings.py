@@ -11,10 +11,13 @@ from rapid_tpu.ops.hashing import lex_argsort
 from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
+    ring_liveness,
+    ring_liveness_after_cut,
     ring_perms,
     ring_positions,
     ring_topology,
     ring_topology_from_perm,
+    view_change_bucket,
 )
 from rapid_tpu.protocol.view import MembershipView
 from rapid_tpu.types import Endpoint, NodeId
@@ -437,8 +440,9 @@ WALK_PIECES = [
 @pytest.mark.parametrize("n,pieces", WALK_PIECES)
 def test_the_ring_length_gives_the_walks_piece_count(n, pieces):
     # Traced over shapes alone (nothing of ten million slots is made or run):
-    # one prefix-max and one suffix-min a piece, and the only gathers are
-    # ``alive[perm]`` (the walk's and ``_alive_first_order``'s own).
+    # one prefix-max and one suffix-min a piece, and the only gather is
+    # ``alive[perm]`` (the walk's; ``_alive_first_order`` reads the same bits
+    # since PR 50).
     piece_bits, got = rings.ring_walk_pieces(n)
     assert got == pieces
     slot_bits = 32 - piece_bits
@@ -448,7 +452,7 @@ def test_the_ring_length_gives_the_walks_piece_count(n, pieces):
     )
     names = list(_primitives(traced.jaxpr))
     assert names.count("cummax") == names.count("cummin") == pieces
-    assert names.count("gather") == 2
+    assert names.count("gather") == 1
     assert [v.aval.dtype for v in traced.jaxpr.outvars] == [np.int32] * 3
 
 
@@ -469,3 +473,134 @@ def test_the_walk_gathers_nothing_by_a_position_it_computed():
     gather = next(l for l in text.splitlines() if '"stablehlo.gather"(' in l)
     assert "tensor<4096xi1>" in gather  # it reads the alive mask
 
+
+
+# ---------------------------------------------------------------------------
+# Liveness by ring position as a lane a view change keeps up to date (PR 50)
+# ---------------------------------------------------------------------------
+
+
+def test_the_view_change_bucket_is_a_function_of_the_slot_count_alone():
+    # An eighth of the slots in whole 128-lane tiles, and it holds the
+    # largest cut any cell commits (the bootstrap's 242 joiners of 2,000).
+    sizes = (1_000, 2_000, 50_000, 102_500, 1_000_000)
+    assert [view_change_bucket(n) for n in sizes] == [128, 256, 6_272, 12_928, 125_056]
+    for n, largest_cut in zip(sizes, (16, 242, 500, 5_000, 10_000)):
+        assert largest_cut <= view_change_bucket(n) < n
+
+
+def _cut_case(spec, n, alive, bucket, rng):
+    """The cut a case names, ``[n]`` bools (the slots whose bit flips)."""
+    cut = np.zeros(n, dtype=bool)
+    if spec == "one":
+        cut[n // 3] = True
+    elif spec == "mixed":  # leaves and joins in one cut
+        cut[rng.choice(np.flatnonzero(alive), size=min(bucket, n) // 3, replace=False)] = True
+        cut[rng.choice(np.flatnonzero(~alive), size=min(bucket, n) // 5, replace=False)] = True
+    elif spec == "last_slot":  # where the compaction's filler entries point
+        cut[[0, n - 1]] = True
+    elif spec == "exactly_the_bucket":
+        cut[rng.choice(n, size=min(bucket, n), replace=False)] = True
+    elif spec == "one_over_the_bucket":  # the overflow arm (a ring of under 128 slots has none)
+        cut[rng.choice(n, size=min(bucket + 1, n), replace=False)] = True
+    elif spec == "everybody":
+        cut[:] = True
+    elif spec != "empty":
+        raise ValueError(spec)
+    return cut
+
+
+@pytest.mark.parametrize("cut_spec", [
+    "empty", "one", "mixed", "last_slot", "exactly_the_bucket", "one_over_the_bucket",
+    "everybody",
+])
+@pytest.mark.parametrize("n,k,idx,one_at_a_time", [
+    (100, 4, np.int8, False),       # the compact engine's index widths
+    (100, 4, np.int8, True),
+    (2000, 10, np.int16, False),    # paper-fleet-2k's ring, all rings at once
+    (2000, 3, np.int16, True),
+    (33_000, 2, np.int32, True),    # over RING_AT_A_TIME_SLOTS as it stands
+    (31_000, 2, np.int32, False),   # and under it
+])
+def test_a_cut_flips_its_own_ring_positions(n, k, idx, one_at_a_time, cut_spec, monkeypatch):
+    """The lane after a cut, by update, is ``alive2[perm]`` bit for bit, for
+    cuts at the bucket's corners (the overflow arm among them: one member
+    more than it holds) and with the compaction's filler entries repeating a
+    slot; and the walk fed the lane gives the tables of the walk that
+    gathers. Both ring schedules, the compact index widths included."""
+    if one_at_a_time != (n >= rings.RING_AT_A_TIME_SLOTS):
+        monkeypatch.setattr(rings, "RING_AT_A_TIME_SLOTS", n if one_at_a_time else n + 1)
+    rng = np.random.default_rng(n * 7 + k + len(cut_spec))
+    key_hi, key_lo = _keys(n, k, rng)
+    perm = ring_perms(key_hi, key_lo).astype(idx)
+    pos = ring_positions(perm)
+    assert pos.dtype == idx
+    alive = rng.random(n) < 0.7
+    bucket = view_change_bucket(n)
+    cut = _cut_case(cut_spec, n, alive, bucket, rng)
+    alive2 = alive ^ cut
+
+    @jax.jit  # traced after the patch
+    def commit(lane, perm, pos, alive2, cut):
+        lane2, took_dense = ring_liveness_after_cut(lane, perm, pos, alive2, cut)
+        fed = ring_topology_from_perm(perm, alive2, lane2)
+        return lane2, took_dense, fed, ring_topology_from_perm(perm, alive2)
+
+    loops = [
+        name for name in _primitives(jax.make_jaxpr(commit)(
+            ring_liveness(perm, alive), perm, pos, alive2, cut).jaxpr)
+        if name in ("scan", "while")
+    ]
+    assert bool(loops) == one_at_a_time
+    lane = ring_liveness(perm, alive)
+    assert lane.dtype == np.bool_ and lane.shape == perm.shape
+    np.testing.assert_array_equal(np.asarray(lane), alive[np.asarray(perm)])
+    lane2, took_dense, fed, gathering = commit(lane, perm, pos, alive2, cut)
+    np.testing.assert_array_equal(np.asarray(lane2), alive2[np.asarray(perm)])
+    assert bool(took_dense) == (int(cut.sum()) > bucket)
+    for got, want in zip(fed, gathering):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    want = ring_topology(key_hi, key_lo, alive2)
+    np.testing.assert_array_equal(np.asarray(fed.obs_idx), np.asarray(want.obs_idx))
+
+
+def test_the_overflow_arm_stays_a_conditional_under_a_named_vmap():
+    """Under a ``vmap`` that names its axis the choice between the update and
+    the whole gather is ONE conditional for the batch, opened by the members
+    whose cut is both too large and committed; unnamed, it is a select (no
+    conditional left: both forms run for everybody). Per committing member
+    the result is the gather's either way."""
+    tenants, n, k = 3, 1000, 3
+    rng = np.random.default_rng(50)
+    bucket = view_change_bucket(n)
+    perms, alives, cuts = [], [], []
+    for size in (5, bucket + 1, bucket + 7):  # one tenant fits, two overflow
+        perms.append(np.asarray(ring_perms(*_keys(n, k, rng))))
+        alives.append(rng.random(n) < 0.8)
+        cut = np.zeros(n, dtype=bool)
+        cut[rng.choice(n, size=size, replace=False)] = True
+        cuts.append(cut)
+    perm, alive, cut = (jnp.asarray(np.stack(x)) for x in (perms, alives, cuts))
+    pos = jax.vmap(ring_positions)(perm)
+    lane = jax.vmap(ring_liveness)(perm, alive)
+    alive2 = alive ^ cut
+    want = jax.vmap(ring_liveness)(perm, alive2)
+
+    def commit(axis):
+        def one(lane, perm, pos, alive2, cut, commits):
+            return ring_liveness_after_cut(lane, perm, pos, alive2, cut, axis, commits)
+        return jax.jit(jax.vmap(one, axis_name=axis))
+
+    def conditionals(fn, *args):
+        return [p for p in _primitives(jax.make_jaxpr(fn)(*args).jaxpr) if p == "cond"]
+
+    for commits in ([True, True, True], [True, False, True], [True, False, False]):
+        args = (lane, perm, pos, alive2, cut, jnp.asarray(commits))
+        got, took_dense = commit("tenants")(*args)
+        kept = np.asarray(commits)  # a member that does not commit is dropped by its caller
+        np.testing.assert_array_equal(np.asarray(got)[kept], np.asarray(want)[kept])
+        assert took_dense.tolist() == [False, commits[1], commits[2]]
+        assert len(conditionals(commit("tenants"), *args)) == 1
+    got, _ = commit(None)(*args)
+    np.testing.assert_array_equal(np.asarray(got)[kept], np.asarray(want)[kept])
+    assert conditionals(commit(None), *args) == []

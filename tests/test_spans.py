@@ -60,9 +60,9 @@ EXPECTED.update({
     "predecessor_of_keys": {"join_predecessors"},
 })
 _WRAPPED = re.compile(r"^\w+\((.*)\)$")
-#: The fixture keeps a whole-wave loop's jaxpr beside its lowering, under
+#: The fixture keeps a driver program's jaxpr beside its lowering, under
 #: this prefix: op-name paths say under WHICH arm an operation lies, not under
-#: which conditional, and those loops have two.
+#: which conditional (the whole-wave loops have two), and carry no shapes.
 JAXPR = "jaxpr:"
 
 
@@ -118,14 +118,14 @@ def lowered():
             program = vcm._ROUND_PROGRAMS[verb][level].trace(
                 one.cfg, *carried, one.faults, *controls)
             out[CLUSTER_VERBS[verb] + LEVELS[level]] = program.lower()
-        out[JAXPR + CLUSTER_VERBS["wave"] + LEVELS[level]] = program.jaxpr.jaxpr  # the wave: traced last
+            out[JAXPR + CLUSTER_VERBS[verb] + LEVELS[level]] = program.jaxpr.jaxpr
         carried = [t for t in (many.state, many.telem, many.trace_ring) if t is not None]
         for verb, controls in (("step", (jnp.zeros((3,), i32), fleet_masks)), ("decision", (i32(16),)),
                                ("wave", (per_tenant[0], i32(16), 4, per_tenant[1]))):
             program = fleetm._FLEET_PROGRAMS[verb][level].trace(
                 many.cfg, *carried, many.faults, many.knobs, *controls)
             out[FLEET_VERBS[verb] + LEVELS[level]] = program.lower()
-        out[JAXPR + FLEET_VERBS["wave"] + LEVELS[level]] = program.jaxpr.jaxpr  # the wave: traced last
+            out[JAXPR + FLEET_VERBS[verb] + LEVELS[level]] = program.jaxpr.jaxpr
     out.update({
         "engine_step": vcm.engine_step.lower(vc.cfg, s, vc.faults),
         "edge_masks_build": vcm.edge_masks_build.lower(vc.cfg, s, vc.faults),
@@ -138,6 +138,11 @@ def lowered():
         "mesh_step": make_sharded_step(vc.cfg, mesh).lower(s, vc.faults),
         "mesh_run_to_decision": sharded_program("decision", vc.cfg, mesh).lower(
             s, vc.faults, i32(16)),
+        JAXPR + "mesh_run_to_decision": sharded_program("decision", vc.cfg, mesh).trace(
+            s, vc.faults, i32(16)).jaxpr.jaxpr,
+        JAXPR + "mesh_fleet_wave": fleetm.make_fleet_wave(fleet.cfg, mesh3d, max_cuts=4).trace(
+            fleet.state, fleet.faults, fleet.knobs, per_tenant[0], i32(16), per_tenant[1]
+        ).jaxpr.jaxpr,
         # the mesh's step body with both observers riding
         "engine_step_trace": jax.jit(vcm.engine_step_impl, static_argnums=(0,)).lower(
             traced.cfg, traced.state, traced.telem, traced.trace_ring, traced.faults),
@@ -238,7 +243,11 @@ def test_the_meshless_fleet_programs_gate_the_view_change_on_one_conditional(low
         p for p in paths
         if p.startswith("jit(") and "cond/" in p and "/vmap(" not in p.split("cond/")[0]
     ]
-    assert {m.group(1) for m in arms} == set(re.findall(r"cond/(branch_\d+_fun)/", " ".join(outside)))
+    # (the first conditional of a path: the view change's arm holds one of
+    # its own, inside the vmap, since PR 50: ``ring_alive``'s overflow gate)
+    assert {m.group(1) for m in arms} == {
+        m.group(1) for p in outside if (m := re.search(r"cond/(branch_\d+_fun)/", p))
+    }
 
 
 def _placed(jaxpr, inside=(), path="", conds=None):
@@ -273,10 +282,14 @@ def test_a_whole_wave_loop_builds_its_masks_where_a_round_will_read_them(lowered
     def where(name):
         return {inside for inside, path in placed if name in _scopes({path})}
 
-    builds, (view_change,) = where("edge_masks"), where("view_change")
+    builds, view_change = where("edge_masks"), where("view_change")
     # the view change: the taken arm of one conditional of the loop's body
-    loop, (cut, taken) = view_change
+    # (and under it the two arms of its own one conditional: ``ring_alive``
+    # by update, or gathered whole)
+    (loop, (cut, taken)), = {at[:2] for at in view_change}
     assert (loop, taken) == ("while", 1)
+    (own,) = {at[2][0] for at in view_change if len(at) > 2}
+    assert view_change == {("while", (cut, 1)), ("while", (cut, 1), (own, 0)), ("while", (cut, 1), (own, 1))}
     if program == "fleet_wave":
         # one build before the loop, one in the taken arm of a conditional of
         # its own at the head of the body (no conditional of the round comes
@@ -315,6 +328,65 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
     assert all(re.match(r"jit\(\w+\)/(?:vmap\()?edge_masks\)?/", p) for p in paths)
 
 
+def _equations_placed(jaxpr, path=""):
+    """``(op-name path, equation)`` of every equation of a jaxpr, inner
+    jaxprs walked; a conditional's arms read ``cond/branch_<i>`` as they do
+    in a lowering's debug locations."""
+    for eqn in jaxpr.eqns:
+        here = "/".join(part for part in (path, str(eqn.source_info.name_stack)) if part)
+        yield here, eqn
+        if eqn.primitive.name == "cond":
+            for arm, branch in enumerate(eqn.params["branches"]):
+                yield from _equations_placed(branch.jaxpr, f"{here}/cond/branch_{arm}")
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations_placed(sub, here)
+
+
+def _view_change_facts(jaxpr, k, n):
+    """Of the equations traced under ``view_change``: the paths of the
+    conditionals, and of the gathers that look a bool up for every position
+    of every ring (``alive[ring_perm]``: ``[..., n]`` bools, ``k * n`` or
+    more of them; the update's own look-ups are a bucket long)."""
+    conditionals, gathers = [], []
+    for path, eqn in _equations_placed(jaxpr):
+        if "view_change" not in _scopes({path}):
+            continue
+        if eqn.primitive.name == "cond":
+            conditionals.append(path)
+        out = eqn.outvars[0].aval if eqn.outvars else None
+        if (eqn.primitive.name == "gather" and out.dtype == jnp.bool_
+                and out.shape[-1] == n and out.size >= k * n):
+            gathers.append(path)
+    return conditionals, gathers
+
+
+@pytest.mark.parametrize("program", [
+    name + suffix for name in (*CLUSTER_VERBS.values(), *FLEET_VERBS.values())
+    for suffix in LEVELS])
+def test_a_one_device_view_change_gathers_liveness_only_in_its_overflow_arm(lowered, program):
+    """Every one-device round program: the view change holds ONE conditional
+    of its own (a real ``cond``, under the fleet's ``vmap`` too: named, its
+    predicate is reduced over the tenants; a select would run the gather for
+    everybody, always), and the only look-up of a bool for every position of
+    every ring, ``alive[ring_perm]``, lies in that conditional's taken arm:
+    a cut that fits the bucket gathers nothing of ring length."""
+    conditionals, gathers = _view_change_facts(lowered[JAXPR + program], k=3, n=32)
+    assert len(conditionals) == 1, conditionals
+    (gate,) = conditionals
+    assert gathers and all(p.startswith(gate + "/cond/branch_1") for p in gathers), gathers
+    if program.startswith("fleet"):
+        assert "vmap(view_change)" in gate
+
+
+@pytest.mark.parametrize("program", ["mesh_run_to_decision", "mesh_fleet_wave"])
+def test_a_mesh_view_change_keeps_the_whole_gather_and_no_conditional(lowered, program):
+    # the programs whose node axis (or tenant axis) is sharded trace the
+    # dense form alone: the operations they always ran, one more result
+    conditionals, gathers = _view_change_facts(lowered[JAXPR + program], k=3, n=32)
+    assert conditionals == [] and len(gathers) == 1
+
+
 #: First sixteen hex digits of the SHA-256 of ``lowered.as_text()`` at commit
 #: f450378 (the parent of PR 28), at this module's tiny shapes: the programs
 #: of the cells the carried masks bypass (churn5's fused wave, crash10's
@@ -339,7 +411,7 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: At PR 45 the two one-device programs were re-taken: their ``invalidation``
 #: arm compacts the subjects in flux and keeps the dense loop as its overflow
 #: arm (``ops/cut_detection.py``). The three mesh programs were NOT: they
-#: trace the dense loop alone (``dense_invalidation=True``) and lower to PR
+#: trace the dense loop alone (``dense_arms=True`` now) and lower to PR
 #: 44's text, as do the four programs of the fixture without the arm.
 #: At PR 46 all five were re-taken for the mask build alone: each holds
 #: ``_edge_masks``, which now gathers once an edge (the packed ``rx_block``
@@ -348,14 +420,20 @@ def test_the_build_program_carries_the_scope_at_top_level(lowered, program):
 #: At PR 49 all five were re-taken for their signature alone: the state has
 #: one more lane, ``ring_pos``, which a round hands through unread (only the
 #: join placement reads it), so each text gains an operand and a result.
+#: At PR 50 all five were re-taken: the state has one more lane,
+#: ``ring_alive`` (liveness by ring position), which the view change's walk
+#: reads and its commit writes. In the two one-device programs the commit
+#: flips the cut's own positions under a conditional of its own, the whole
+#: gather its other arm; the three mesh programs gather it whole, the
+#: operation their walk always made, and hand it out as one more result.
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "1811af9c78689ae5",
-    "fleet_run_to_decision": "33c287ed9273585e",
-    "mesh_run_to_decision": "6894315e5bdfd33e",
-    "mesh_step": "999c4f064a025c0f",
-    "mesh_fleet_step": "eb360e4db9c669d1",
+    "run_until_membership": "5af09d9e45e5b7a9",
+    "fleet_run_to_decision": "beaea0df27750918",
+    "mesh_run_to_decision": "368d054b5d8a823b",
+    "mesh_step": "07bc72c6e6905c1c",
+    "mesh_fleet_step": "d12c1f4b2ca30c05",
 }
 
 

@@ -258,7 +258,14 @@ def test_sharded_telem_wave_bit_identical_and_fleet_lanes_shard():
     assert resolved
     assert (steps, cuts) == (r1, c1)
     assert _trees_equal(vc.state, single.state)
-    assert _trees_equal(_lanes_host(vc.telem), _lanes_host(single.telem))
+    # One lane tells the two programs apart, and says so: a mesh's view
+    # change gathers ``ring_alive`` whole at every commit, the one-device
+    # program's flipped this cut's own positions.
+    assert (int(vc.telem.tl_view_change_dense), int(single.telem.tl_view_change_dense)) == (cuts, 0)
+    assert _trees_equal(
+        _lanes_host(vc.telem._replace(tl_view_change_dense=single.telem.tl_view_change_dense)),
+        _lanes_host(single.telem),
+    )
     assert off_table(vc.telem, mesh) == () and off_table(vc.state, mesh) == ()
 
     # Tenant-stacked lanes on the ('tenant', 'cohort', 'nodes') mesh.

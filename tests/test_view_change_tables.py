@@ -72,13 +72,16 @@ def _assert_same_leaves(ours, theirs, where):
 def test_the_compiled_view_change_scatters_one_ring_table(n):
     jaxpr, compiled = _view_change_programs(n)
     assert (" while(" in compiled) == (n >= rings.RING_AT_A_TIME_SLOTS)  # the form the length picks
-    # all K rings in one scatter (batched), or one in the loop's body (in turn)
-    assert len(re.findall(r" scatter\(", compiled)) == 1
+    # all K rings in one scatter (batched), or one in the loop's body (in turn);
+    # the other scatter is the lane's: the cut's positions flipped in
+    # ``ring_alive`` (PR 50), a scatter of bools and not of ring indices
+    scatters = re.findall(r"= (\w+)\[[\d,]*\][^=]* scatter\(", compiled)
+    assert sorted(scatters) == ["pred", "s32"], scatters
     # and what jax hands the compiler: the dead half of the walk is gone before
     # XLA sees it, through the inner jit, the vmap and the lax.map alike
     found = _live_primitives(jaxpr)
     _, pieces = rings.ring_walk_pieces(n)
-    assert found["scatter"] == 1 and found["cummin"] == pieces
+    assert found["scatter"] == 2 and found["cummin"] == pieces  # the table's, the lane's
     assert found["cummax"] == found["sort"] == 0
     # the ops still give both tables to a caller that takes both
     both = jax.make_jaxpr(lambda p, a: rings.ring_topology_from_perm(p, a)[:2])(
@@ -103,7 +106,8 @@ def test_the_whole_wave_loop_scatters_one_ring_table(driver):
             jnp.full((2,), 28, i32), i32(16), 4, jnp.ones((2,), i32))
     commit = [line for line in traced.lower().compile().as_text().splitlines()
               if "view_change" in line]
-    assert sum(" scatter(" in line for line in commit) == 1, driver
+    tables = [line for line in commit if " scatter(" in line and "= pred[" not in line]
+    assert len(tables) == 1, driver  # (the pred scatter beside it is ``ring_alive``'s update)
 
 
 def test_the_state_names_no_predecessor_table():
@@ -121,7 +125,7 @@ def _step_that_sorts(cfg, state, faults):
     ``ring_topology``, the argsort over (dead, key): the oracle the sort-free
     walk is held to, here through the whole round."""
 
-    def by_sorting(_perm, alive):
+    def by_sorting(_perm, alive, _ring_alive):
         return rings.ring_topology(state.key_hi, state.key_lo, alive)
 
     with mock.patch.object(vcm, "ring_topology_from_perm", by_sorting):

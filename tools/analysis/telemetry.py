@@ -63,6 +63,7 @@ TELEMETRY_LANE_FIELDS = (
     "tl_dissent",
     "tl_invalidation_rounds",
     "tl_invalidation_dense_rounds",
+    "tl_view_change_dense",
     "tl_undecided_hist",
 )
 
