@@ -19,7 +19,7 @@ instances, ``MultiNodeCutDetector.java:31-37``, sampled at C of them).
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -506,15 +506,17 @@ class LinkFaults(NamedTuple):
     probes_lost: jnp.ndarray  # [] int32 — probes the lane failed since it was set
 
     @staticmethod
-    def none(cfg: EngineConfig) -> "LinkFaults":
-        """A set lane that names nobody (the setter scatters into it)."""
+    def none(cfg: EngineConfig, tenants: Optional[int] = None) -> "LinkFaults":
+        """A set lane that names nobody (the setter scatters into it); with
+        ``tenants``, a fleet's: every leaf under a leading tenant axis."""
+        stacked = () if tenants is None else (tenants,)
         return LinkFaults(
-            loss_permille=jnp.zeros((cfg.n,), dtype=jnp.int32),
-            on_rounds=jnp.int32(0),
-            off_rounds=jnp.int32(0),
-            seed=jnp.uint32(0),
-            age=jnp.int32(0),
-            probes_lost=jnp.int32(0),
+            loss_permille=jnp.zeros((*stacked, cfg.n), dtype=jnp.int32),
+            on_rounds=jnp.zeros(stacked, dtype=jnp.int32),
+            off_rounds=jnp.zeros(stacked, dtype=jnp.int32),
+            seed=jnp.zeros(stacked, dtype=jnp.uint32),
+            age=jnp.zeros(stacked, dtype=jnp.int32),
+            probes_lost=jnp.zeros(stacked, dtype=jnp.int32),
         )
 
 

@@ -1074,6 +1074,12 @@ def _lane_tail(*lanes) -> tuple:
     return tuple(lane for lane in lanes if lane is not None)
 
 
+def _set_lanes(**lanes) -> dict:
+    """The keywords a program is called with: the lanes that are set (a call
+    that names no lane is the call, and the program, of before)."""
+    return {name: lane for name, lane in lanes.items() if lane is not None}
+
+
 def _lane_off(outputs, *lanes):
     """``(outputs, *lanes)`` of a return that ends with :func:`_lane_tail`
     of ``lanes``: each lane is the element it went back as, or ``None``
@@ -2206,13 +2212,11 @@ class VirtualCluster(DispatchSeam):
             )
         if self.links is not None and self.links is not self._links_kept:
             self._link_lost_seen = 0
-        lanes = {
-            name: lane
-            for name, lane in (("links", self.links), ("paths", self.paths))
-            if lane is not None
-        }
         out, self.links, self.paths = _lane_off(
-            program(*carried, self.faults, *controls, **lanes),
+            program(
+                *carried, self.faults, *controls,
+                **_set_lanes(links=self.links, paths=self.paths),
+            ),
             self.links, self.paths,
         )
         self._links_kept = self.links

@@ -171,7 +171,8 @@ def resume(
     else:
         target = VirtualCluster(cfg, state)
         target.faults = faults
-        target.links = load_link_faults(path)
+    # a cluster's lane or a fleet's stacked one, as saved; None where none was set
+    target.links = load_link_faults(path)
     wave_index = int(meta["wave_index"])
     supervisor = Supervisor(
         target,

@@ -113,9 +113,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from rapid_tpu.models.state import (
+    LINK_LOSS_DEAD,
     EngineConfig,
     EngineState,
     FaultInputs,
+    LinkFaults,
     StepEvents,
     TelemetryLanes,
     TraceRing,
@@ -129,6 +131,10 @@ from rapid_tpu.models.virtual_cluster import (
     _compute_round,
     _count_dense_commit,
     _edge_masks,
+    _lane_off,
+    _lane_tail,
+    _observer_loss,
+    _set_lanes,
     apply_view_change_impl,
     delivery_delays,
     engine_step_impl,
@@ -272,6 +278,25 @@ def fleet_edge_masks_impl(cfg: EngineConfig, state: EngineState, faults: FaultIn
     return jax.vmap(lambda s, f: _edge_masks(cfg, s, f))(state, faults)
 
 
+def fleet_link_faults_place_impl(cfg: EngineConfig, tenants: int, packed) -> LinkFaults:
+    """A new stacked link-fault lane in one upload and one dispatch, the
+    batched ``link_faults_place``: ``packed`` is ``uint32[4 * t + 2 * m]``,
+    every tenant's four controls (``[4, t]``: loss in permille, on rounds,
+    off rounds, seed) and then the ``m`` faulty ``(tenant, slot)`` pairs. A
+    faulty member gets its own tenant's loss, a tenant without a pair loss 0
+    everywhere. Every clock and every count starts at 0."""
+    controls = packed[: 4 * tenants].reshape(4, tenants)
+    loss, on_rounds, off_rounds = controls[:3].astype(jnp.int32)
+    idx = packed[4 * tenants:].reshape(-1, 2).astype(jnp.int32)
+    blank = LinkFaults.none(cfg, tenants)
+    return blank._replace(
+        loss_permille=blank.loss_permille.at[idx[:, 0], idx[:, 1]].set(loss[idx[:, 0]]),
+        on_rounds=on_rounds,
+        off_rounds=off_rounds,
+        seed=controls[3],
+    )
+
+
 def fleet_join_admit_impl(state: EngineState, idx):
     """The rejoin discipline's lane at ``idx`` (``[m, 2]`` of ``(tenant,
     slot)``): ``[m]`` bools, True where the slot is a member, already
@@ -324,7 +349,7 @@ def fleet_join_place_impl(cfg: EngineConfig, state: EngineState, idx, width: int
 
 def _gated_round(
     cfg: EngineConfig, state: EngineState, observers, faults, knobs, masks,
-    active=None, rebuild_masks=True,
+    active=None, rebuild_masks=True, links=None, observer_loss=None,
 ):
     """One protocol round for every tenant on the stacked ``masks`` it is
     handed, with the view change under ONE scalar gate: the body the gated
@@ -353,22 +378,36 @@ def _gated_round(
     FROZEN: it does not commit, and its state and observer lanes come back
     as they went in (its masks with them, by the rule above).
 
-    Returns ``(state, observers, masks, commits[t], events, gates)``, the
-    last ``int32[3]`` in :data:`GATE_ROUND_COUNTERS`' order: whether the
-    view-change gate opened, and whether ``invalidation`` and ``classic``
-    ran, in this round."""
+    ``links`` (the stacked link-fault lane, ``None`` for a fleet that set
+    none: a Python-level branch that then adds not one traced operation)
+    rides the vmap to ``_compute_round`` and comes back LAST, a round older;
+    a frozen tenant's clock and count stand still with its state.
+    ``observer_loss`` is the lane's stacked gather (``_observer_loss`` of
+    every tenant, ``uint32[t, n, k]``) where the caller's loop carries it
+    beside the masks; without it every round gathers its own.
 
-    def one_round(state, faults, kn, masks, *observers):
+    Returns ``(state, observers, masks, commits[t], events, gates)`` and,
+    with a lane, the lane; ``gates`` is ``int32[3]`` in
+    :data:`GATE_ROUND_COUNTERS`' order: whether the view-change gate opened,
+    and whether ``invalidation`` and ``classic`` ran, in this round."""
+
+    def one_round(state, faults, kn, masks, *observers, **lane):
         return _compute_round(
             _tenant_cfg(cfg, kn), state, faults, masks, *observers,
-            batch_axis=FLEET_BATCH_AXIS,
+            batch_axis=FLEET_BATCH_AXIS, **lane,
         )
 
-    # the round's last output, which arms ran, is one value for the fleet
-    round_state, decided, winner, events, *round_observers, arms_ran = jax.vmap(
-        one_round, axis_name=FLEET_BATCH_AXIS,
-        out_axes=(*(0,) * (4 + len(observers)), None),
-    )(state, faults, knobs, masks, *observers)
+    # the round's one output that is one value for the fleet: which arms ran
+    (round_state, decided, winner, events, *round_observers, arms_ran), new_links = _lane_off(
+        jax.vmap(
+            one_round, axis_name=FLEET_BATCH_AXIS,
+            out_axes=(*(0,) * (4 + len(observers)), None, *(0,) * len(_lane_tail(links))),
+        )(
+            state, faults, knobs, masks, *observers,
+            **_set_lanes(links=links, observer_loss=observer_loss),
+        ),
+        links,
+    )
     commits = decided if active is None else decided & active
 
     def commit_one(kn, round_state, winner, commits):
@@ -407,15 +446,24 @@ def _gated_round(
     round_observers = _count_dense_commit(round_observers, took_dense)
     gates = jnp.concatenate([any_commits.astype(jnp.int32)[None], arms_ran])
     if active is not None:
-        new_state, round_observers = jax.vmap(
-            lambda on, new, old: jax.tree_util.tree_map(
-                lambda n, o: jnp.where(on, n, o), new, old
-            )
-        )(active, (new_state, round_observers), (state, list(observers)))
-    return new_state, round_observers, masks, commits, events, gates
+        (new_state, round_observers), new_links = _lane_off(
+            jax.vmap(
+                lambda on, new, old: jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(on, n, o), new, old
+                )
+            )(
+                active, (new_state, round_observers, *_lane_tail(new_links)),
+                (state, list(observers), *_lane_tail(links)),
+            ),
+            links,
+        )
+    return (
+        new_state, round_observers, masks, commits, events, gates,
+        *_lane_tail(new_links),
+    )
 
 
-def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
+def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
     """The MESHLESS fleet step the drivers dispatch (module docstring): one
     protocol round for every tenant with the view change under ONE scalar
     gate and the per-edge masks CARRIED from round to round
@@ -435,15 +483,22 @@ def fleet_step_gated_impl(cfg: EngineConfig, state: EngineState, *rest):
     opened and those in which ``invalidation`` and ``classic`` ran, fetched
     only at the driver's host-sync boundaries.
 
-    Returns ``(state, *observers, gate_rounds, masks, events)``."""
+    Returns ``(state, *observers, gate_rounds, masks, events)`` and, with a
+    link-fault lane (the keyword ``links``), the lane, last."""
     *observers, faults, knobs, gate_rounds, masks = rest
-    new_state, observers, masks, _, events, gates = _gated_round(
-        cfg, state, observers, faults, knobs, masks
+    (new_state, observers, masks, _, events, gates), links = _lane_off(
+        _gated_round(cfg, state, observers, faults, knobs, masks, links=links),
+        links,
     )
-    return (new_state, *observers, gate_rounds + gates, masks, events)
+    return (
+        new_state, *observers, gate_rounds + gates, masks, events,
+        *_lane_tail(links),
+    )
 
 
-def fleet_run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
+def fleet_run_to_decision_impl(
+    cfg: EngineConfig, state: EngineState, *rest, links=None
+):
     """Per-tenant single-dispatch convergence: ``run_to_decision_impl``
     vmapped; ``rest`` is ``(*observers, faults, knobs, max_steps)``, the
     observers' lanes stacked like the state. The batched while's predicate
@@ -454,26 +509,32 @@ def fleet_run_to_decision_impl(cfg: EngineConfig, state: EngineState, *rest):
     ``invalidation`` or ``classic`` runs neither (module docstring).
 
     Returns ``(state, *observers, steps[t], decided[t], winner[t, n],
-    arm_rounds)``, the last ``int32[2]``: the loop's rounds in which
-    ``invalidation`` and ``classic`` ran."""
+    arm_rounds)``, ``arm_rounds`` being ``int32[2]``: the loop's rounds in
+    which ``invalidation`` and ``classic`` ran; and, with a link-fault lane
+    (the keyword ``links``), the lane, last. Each tenant's ``_converge``
+    makes the lane's one gather before its rounds, and the batched while
+    freezes a decided tenant's clock and count with the rest of its carry."""
     *observers, faults, knobs, max_steps = rest
 
-    def one(state, *rest):
+    def one(state, *rest, **lane):
         *observers, faults, kn = rest
         return run_to_decision_impl(
             _tenant_cfg(cfg, kn), state, *observers, faults, max_steps,
-            batch_axis=FLEET_BATCH_AXIS,
+            batch_axis=FLEET_BATCH_AXIS, **lane,
         )
 
-    *out, arm_rounds = jax.vmap(one, axis_name=FLEET_BATCH_AXIS)(
-        state, *observers, faults, knobs
+    (*out, arm_rounds), links = _lane_off(
+        jax.vmap(one, axis_name=FLEET_BATCH_AXIS)(
+            state, *observers, faults, knobs, **_set_lanes(links=links)
+        ),
+        links,
     )
     # The batched while freezes a tenant's carry once it has decided, its
     # count with it: the tenant that ran longest counted every round.
-    return (*out, jnp.max(arm_rounds, axis=0))
+    return (*out, jnp.max(arm_rounds, axis=0), *_lane_tail(links))
 
 
-def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
+def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest, links=None):
     """The fleet's whole-wave loop: every tenant runs convergences through
     MULTIPLE view changes until its own ``target`` membership (at least its
     own ``min_cuts`` cuts), all in one dispatch — the batched twin of
@@ -513,9 +574,27 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
     opened and in which ``invalidation`` and ``classic`` ran, then the mask
     builds the loop ran (its ``stale`` arm: the rounds that followed a
     commit; the build before the loop is not among them).
+
+    With a link-fault lane (the keyword ``links``) the lane rides the carry
+    and comes back LAST. Its one gather, the loss at every edge's observer,
+    reads the lane and ``obs_idx`` alone, so it lives where the masks live:
+    made before the loop and again only in the ``stale`` arm, never once a
+    round (tenants commit in different rounds, so no convergence brackets
+    it as the cluster's does). A tenant outside ``active`` keeps its
+    ``age`` and ``probes_lost`` as it keeps its state.
     """
     *observers, faults, knobs, target, max_steps, max_cuts, min_cuts = rest
     tenants = target.shape[0]
+
+    def lookups(state, links):
+        """What a round reads of the topology as it stands: the per-edge
+        masks and, with a lane, its loss at every edge's observer."""
+        return (
+            fleet_edge_masks_impl(cfg, state, faults),
+            None if links is None else jax.vmap(
+                lambda s, lane: _observer_loss(cfg, s, lane)
+            )(state, links),
+        )
 
     def active_of(steps, done):
         return ~done & (steps < max_steps)
@@ -525,15 +604,20 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
         return jnp.any(active_of(steps, done))
 
     def body(carry):
-        state, *observers, masks, stale, steps, cuts, sizes, done, loop_rounds = carry
+        (state, *observers, masks, observer_loss, links, stale, steps, cuts, sizes,
+         done, loop_rounds) = carry
         active = active_of(steps, done)
         # The round before this one committed: its view change left the
         # masks behind, and this round is the first to read them.
-        masks = jax.lax.cond(
-            stale, lambda: fleet_edge_masks_impl(cfg, state, faults), lambda: masks
+        masks, observer_loss = jax.lax.cond(
+            stale, lambda: lookups(state, links), lambda: (masks, observer_loss)
         )
-        state, observers, masks, commits, _, gates = _gated_round(
-            cfg, state, observers, faults, knobs, masks, active, rebuild_masks=False
+        (state, observers, masks, commits, _, gates), links = _lane_off(
+            _gated_round(
+                cfg, state, observers, faults, knobs, masks, active,
+                rebuild_masks=False, links=links, observer_loss=observer_loss,
+            ),
+            links,
         )
         with scope("loop_result"):
             steps = steps + active.astype(jnp.int32)
@@ -550,12 +634,16 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
             loop_rounds = loop_rounds + jnp.concatenate(
                 [jnp.ones((1,), jnp.int32), gates, stale.astype(jnp.int32)[None]]
             )
-        return (state, *observers, masks, gates[0] > 0, steps, cuts, sizes, done, loop_rounds)
+        return (
+            state, *observers, masks, observer_loss, links, gates[0] > 0, steps,
+            cuts, sizes, done, loop_rounds,
+        )
 
     init = (
         state,
         *observers,
-        fleet_edge_masks_impl(cfg, state, faults),
+        *lookups(state, links),
+        links,
         jnp.bool_(False),
         jnp.zeros((tenants,), jnp.int32),
         jnp.zeros((tenants,), jnp.int32),
@@ -566,12 +654,15 @@ def fleet_wave_impl(cfg: EngineConfig, state: EngineState, *rest):
         (state.n_members == target) & (min_cuts <= 0),
         jnp.zeros((len(WAVE_LOOP_COUNTERS),), jnp.int32),
     )
-    state, *observers, _, _, steps, cuts, sizes, _, loop_rounds = jax.lax.while_loop(
-        cond, body, init
-    )
+    (
+        state, *observers, _, _, links, _, steps, cuts, sizes, _, loop_rounds
+    ) = jax.lax.while_loop(cond, body, init)
     with scope("loop_result"):
         resolved = (state.n_members == target) & (cuts >= min_cuts)
-    return (state, *observers, steps, cuts, resolved, sizes, loop_rounds)
+    return (
+        state, *observers, steps, cuts, resolved, sizes, loop_rounds,
+        *_lane_tail(links),
+    )
 
 
 def fleet_wave_lockstep_impl(cfg: EngineConfig, state: EngineState, *rest):
@@ -731,10 +822,15 @@ fleet_join_admit = jax.jit(fleet_join_admit_impl)  # donate-ok: reads three lane
 fleet_join_place = jax.jit(
     fleet_join_place_impl, static_argnums=(0, 3), donate_argnums=(1,)
 )
+#: The link-fault lane's placement: one upload in, a new stacked lane out.
+fleet_link_faults_place = jax.jit(fleet_link_faults_place_impl, static_argnums=(0, 1))
 #: A fleet verb's programs by observer count, like the cluster's
 #: ``_ROUND_PROGRAMS`` (the knobs ride after the faults). The step's
 #: gate-round counters and carried masks (its last two arguments) are donated
 #: too; the wave's static ``max_cuts`` sits after faults, knobs and two controls.
+#: Each takes the stacked link-fault lane as the keyword ``links`` and hands it
+#: back last, the round programs' one convention; a call without the keyword
+#: is the call, and the program, of a fleet that has none.
 _FLEET_PROGRAMS = {
     "step": jit_per_observer_count(fleet_step_gated_impl, donated=(4, 5)),
     "decision": jit_per_observer_count(fleet_run_to_decision_impl),
@@ -746,11 +842,23 @@ fleet_telemetry_digest = jax.jit(jax.vmap(telemetry_digest_impl))
 fleet_trace_digest = jax.jit(jax.vmap(trace_digest_impl))
 
 
-def make_fleet_step(cfg: EngineConfig, mesh: Mesh):
+def _no_lane_on_a_mesh(links) -> None:
+    """The mesh factories' refusal, in ``VirtualCluster.set_link_faults``'s
+    words: the lane has no partition rule, stacked or not."""
+    if links is not None:
+        raise ValueError(
+            "link faults are off under a mesh: the lane has no "
+            "partition rule (parallel/mesh.PARTITION_RULES)"
+        )
+
+
+def make_fleet_step(cfg: EngineConfig, mesh: Mesh, links=None):
     """jit the fleet step with explicit in-shardings over a
     ``('tenant', 'cohort', 'nodes')`` mesh — the audited batched-step
     entrypoint (``fleet3d_step`` in the ``device_program`` registry: zero
-    cross-tenant collectives, donation fully aliased)."""
+    cross-tenant collectives, donation fully aliased). ``links`` is the
+    fleet's link-fault lane, which has to be ``None``: a mesh takes none."""
+    _no_lane_on_a_mesh(links)
     st_sh = fleet_state_shardings(mesh)
     ft_sh = fleet_fault_shardings(mesh)
     kn_sh = knob_shardings(mesh)
@@ -767,12 +875,14 @@ def make_fleet_step(cfg: EngineConfig, mesh: Mesh):
     )
 
 
-def make_fleet_wave(cfg: EngineConfig, mesh: Mesh, max_cuts: int = 8):
+def make_fleet_wave(cfg: EngineConfig, mesh: Mesh, max_cuts: int = 8, links=None):
     """jit the lockstep fleet wave with the mesh's shardings — the audited
     batched-wave entrypoint (``fleet3d_wave``). ``target``/``min_cuts`` are
     [t] lanes sharded on 'tenant'; ``max_steps`` is a replicated scalar (it
     is the lockstep loop's only predicate input — the reason the compiled
-    hot loop carries no cross-tenant collective)."""
+    hot loop carries no cross-tenant collective). ``links`` as
+    :func:`make_fleet_step` takes it: ``None``, or the factory raises."""
+    _no_lane_on_a_mesh(links)
     st_sh = fleet_state_shardings(mesh)
     ft_sh = fleet_fault_shardings(mesh)
     kn_sh = knob_shardings(mesh)
@@ -811,7 +921,10 @@ class TenantFleet(DispatchSeam):
     separately. Crashes and join waves also reach the STACKED state
     (:meth:`stream_crash`, :meth:`inject_join_wave`: ``(tenant, slot)``
     pairs, device-side), bit-identical to the cluster's methods run per
-    tenant before stacking (``tests/test_fleet_joins.py``)."""
+    tenant before stacking (``tests/test_fleet_joins.py``). So do one-way
+    link faults (:meth:`set_link_faults`): the clusters' ``LinkFaults`` lane
+    stacked over the tenants, ``None`` until set, riding every verb as the
+    cluster's rides its own (``tests/test_fleet_link_faults.py``)."""
 
     def __init__(
         self,
@@ -849,6 +962,17 @@ class TenantFleet(DispatchSeam):
         self._gate_rounds_seen = np.zeros((len(GATE_ROUND_COUNTERS),), dtype=np.int32)
         self._gate_rounds_stale = False
         self._carried = CarriedMasks(fleet_edge_masks)
+        # The stacked link-fault lane (models/state.LinkFaults under a
+        # leading tenant axis): None until ``set_link_faults`` sets one, and
+        # while it is None every verb dispatches the program of a fleet that
+        # has none.
+        # ``_link_lost_seen`` is every tenant's ``probes_lost`` as last
+        # fetched and ``_links_kept`` the lane the last dispatch handed back:
+        # a lane put there from outside (the setter, a restored copy) starts
+        # the fetched counts again.
+        self.links: Optional[LinkFaults] = None
+        self._links_kept: Optional[LinkFaults] = None
+        self._link_lost_seen = np.zeros((b,), dtype=np.int64)
         # Device telemetry plane: per-tenant lanes + the host-side activity
         # cache, zero-minted at attach (every series exists from scrape 0)
         # and refreshed ONLY at host-sync boundaries.
@@ -921,6 +1045,15 @@ class TenantFleet(DispatchSeam):
         # (the per-cluster builders already charged their own uploads to
         # their own metrics registries, which the fleet does not inherit).
         fleet._account_h2d(*jax.tree_util.tree_leaves(fleet.state))
+        if any(vc.links is not None for vc in clusters):
+            # The clusters' link-fault lanes, clocks and counts as they
+            # stand; a cluster that set none rides as one that names nobody.
+            fleet.links = stack_pytrees([
+                LinkFaults.none(base) if vc.links is None else vc.links
+                for vc in clusters
+            ])
+            fleet.metrics.inc("engine_link_probes_lost", 0)
+            fleet._account_h2d(*jax.tree_util.tree_leaves(fleet.links))
         if base.telemetry:
             # Carry each tenant's accumulated lanes into the stack (a fleet
             # assembled mid-run keeps its tenants' activity stories).
@@ -985,9 +1118,16 @@ class TenantFleet(DispatchSeam):
         )
         if max_cuts is not None:  # the wave's static argument, by position
             controls = (*controls[:2], max_cuts, *controls[2:])
-        out = _FLEET_PROGRAMS[verb][len(carried) - 1](
-            self.cfg, *carried, self.faults, self.knobs, *controls
+        if self.links is not None and self.links is not self._links_kept:
+            self._link_lost_seen = np.zeros((self.b,), dtype=np.int64)
+        out, self.links = _lane_off(
+            _FLEET_PROGRAMS[verb][len(carried) - 1](
+                self.cfg, *carried, self.faults, self.knobs, *controls,
+                **_set_lanes(links=self.links),
+            ),
+            self.links,
         )
+        self._links_kept = self.links
         self.state = out[0]
         if self.telem is not None:
             self.telem = out[1]
@@ -1030,10 +1170,10 @@ class TenantFleet(DispatchSeam):
             self._carried.keep(self, masks)
         return events
 
-    def _pair_index(self, pairs) -> jnp.ndarray:
-        """Host-side bounds check of ``(tenant, slot)`` pairs, then upload:
-        jnp gathers and scatters CLAMP out-of-range indices, which would
-        silently touch tenant b-1 / slot n-1 on a typo."""
+    def _checked_pairs(self, pairs) -> np.ndarray:
+        """``(tenant, slot)`` pairs as ``int32[m, 2]``, bounds-checked on
+        the host: jnp gathers and scatters CLAMP out-of-range indices, which
+        would silently touch tenant b-1 / slot n-1 on a typo."""
         arr = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
         if arr.size and (
             arr[:, 0].min() < 0 or arr[:, 0].max() >= self.b
@@ -1043,6 +1183,11 @@ class TenantFleet(DispatchSeam):
                 f"(tenant, slot) pairs out of range [0, {self.b}) x "
                 f"[0, {self.cfg.n}): {arr.tolist()}"
             )
+        return arr
+
+    def _pair_index(self, pairs) -> jnp.ndarray:
+        """:meth:`_checked_pairs`, then the upload."""
+        arr = self._checked_pairs(pairs)
         self._account_h2d(arr)
         return jnp.asarray(arr)
 
@@ -1056,6 +1201,83 @@ class TenantFleet(DispatchSeam):
             self.faults = self.faults._replace(
                 crashed=self.faults.crashed.at[idx[:, 0], idx[:, 1]].set(True)
             )
+
+    def _per_tenant(self, name: str, value) -> np.ndarray:
+        """A control given as one scalar or one value a tenant, as ``int64[t]``."""
+        try:
+            return np.broadcast_to(np.asarray(value, dtype=np.int64), (self.b,))
+        except ValueError:
+            raise ValueError(
+                f"{name} takes a scalar or one value for each of the "
+                f"{self.b} tenants, got shape {np.shape(value)}"
+            ) from None
+
+    def set_link_faults(
+        self, pairs, loss_permille=LINK_LOSS_DEAD, on_rounds=0, off_rounds=0,
+        seeds=0,
+    ) -> None:
+        """One-way link faults on ``(tenant, slot)`` pairs: the batched
+        ``VirtualCluster.set_link_faults`` (its docstring has the fault), and
+        bit-identical to calling it on every tenant before stacking. The
+        loss, the schedule and the seed of the probe draws are one scalar for
+        the fleet or one value a tenant; tenants may have different numbers
+        of faulty members, and one without a pair gets loss 0 everywhere. The
+        call replaces whatever lane stood before; with no pairs it clears it,
+        and the fleet is back on the programs of a fleet that never had one.
+        ONE upload (every tenant's four controls and the pairs) and ONE
+        placement program, enqueued without a fetch. The carried per-edge
+        masks stay valid (they read ``alive``, ``crashed`` and ``rx_block``
+        only)."""
+        loss = self._per_tenant("loss_permille", loss_permille)
+        on = self._per_tenant("on_rounds", on_rounds)
+        off = self._per_tenant("off_rounds", off_rounds)
+        if ((loss < 0) | (loss > LINK_LOSS_DEAD)).any():
+            raise ValueError(
+                f"loss_permille must be in [0, {LINK_LOSS_DEAD}], got {loss.tolist()}"
+            )
+        if ((on < 0) | (off < 0) | ((off > 0) & (on == 0))).any():
+            raise ValueError(
+                f"need on_rounds >= 0 and off_rounds >= 0, and an on-phase "
+                f"where there is an off-phase: got {on.tolist()} on, {off.tolist()} off"
+            )
+        seeds = self._per_tenant("seeds", seeds) & 0xFFFFFFFF
+        idx = self._checked_pairs(pairs)
+        with self._dispatch("inject_link_faults"):
+            # Minted with the first lane, so the series is in every scrape
+            # from then on; a fleet that never sets one never grows it.
+            self.metrics.inc("engine_link_probes_lost", 0)
+            if not len(idx):
+                self.links = None
+                return
+            packed = np.concatenate([loss, on, off, seeds, idx.reshape(-1)]).astype(np.uint32)
+            self._account_h2d(packed)
+            self.links = fleet_link_faults_place(self.cfg, self.b, jnp.asarray(packed))
+
+    def link_probes_lost(self) -> np.ndarray:
+        """``int64[t]``: the probes each tenant's lane has failed since it
+        was set, as the last fetching verb brought them (``run_to_decision``
+        / ``run_until_membership``; a ``step`` fetches nothing). Reading it
+        never touches the device; their sum since the lane was set is what
+        ``engine_link_probes_lost`` got."""
+        return self._link_lost_seen.copy()
+
+    def _fetch(self, *parts) -> np.ndarray:
+        """A verb's int32 observation: ``parts`` concatenated on the device,
+        fetched flat in one transfer and charged to the transfer counter. A
+        set lane's ``probes_lost[t]`` rides the same transfer, 4·t bytes
+        more, and ``engine_link_probes_lost`` gets what the tenants' lanes
+        lost since their last fetch."""
+        fetched = np.asarray(jnp.concatenate(
+            [*parts, *_lane_tail(None if self.links is None else self.links.probes_lost)]
+        ))
+        self._account_d2h(fetched.nbytes)
+        if self.links is not None:
+            fetched, lost = fetched[: -self.b], fetched[-self.b:].astype(np.int64)
+            self.metrics.inc(
+                "engine_link_probes_lost", int((lost - self._link_lost_seen).sum())
+            )
+            self._link_lost_seen = lost
+        return fetched
 
     def inject_join_wave(self, pairs, check_admissible: bool = True) -> None:
         """Admit ``(tenant, slot)`` joiners into the STACKED state: the
@@ -1100,13 +1322,9 @@ class TenantFleet(DispatchSeam):
             steps, decided, winner, arm_rounds = self._advance(
                 "decision", jnp.int32(max_steps)
             )
-            obs = np.asarray(
-                jnp.concatenate(
-                    [steps, decided.astype(jnp.int32), self.state.n_members,
-                     arm_rounds]
-                )
+            obs = self._fetch(
+                steps, decided.astype(jnp.int32), self.state.n_members, arm_rounds
             )
-        self._account_d2h(obs.nbytes)
         self._refresh_gate_rounds()
         rounds, was_decided, members, arm_rounds = np.split(
             obs, [self.b, 2 * self.b, 3 * self.b]
@@ -1165,13 +1383,9 @@ class TenantFleet(DispatchSeam):
                 "wave", jnp.asarray(targets), jnp.int32(max_steps),
                 jnp.asarray(min_cuts), max_cuts=int(max_cuts),
             )
-            obs = np.asarray(
-                jnp.concatenate(
-                    [steps, cuts, resolved.astype(jnp.int32), sizes.reshape(-1),
-                     loop_rounds]
-                )
+            obs = self._fetch(
+                steps, cuts, resolved.astype(jnp.int32), sizes.reshape(-1), loop_rounds
             )
-        self._account_d2h(obs.nbytes)
         self._refresh_gate_rounds()
         b = self.b
         rounds, n_cuts, resolved_h, sizes_h, loop_rounds = np.split(
@@ -1184,8 +1398,9 @@ class TenantFleet(DispatchSeam):
         return rounds, n_cuts, resolved_h.astype(bool), sizes_h.reshape(b, max_cuts)
 
     def sync(self) -> None:
-        """Complete all pending uploads/compute on the fleet state."""
-        jax.block_until_ready(self.state)
+        """Complete all pending uploads/compute on the fleet state, a set
+        link-fault lane's placement included."""
+        jax.block_until_ready((self.state, *_lane_tail(self.links)))
         self._refresh_activity()
 
     def _refresh_activity(self) -> None:

@@ -394,9 +394,10 @@ def save_serving_state(
     alone is left out and rebuilt from the two lanes it is a function of).
     ``meta`` is a
     small JSON-serializable dict (the supervisor's wave cursor). ``links`` is
-    a cluster's link-fault lane where one is set (``VirtualCluster.links``);
-    :func:`load_link_faults` reads it back. Sealed + atomic like every
-    writer in this module."""
+    the target's link-fault lane where one is set (``VirtualCluster.links``,
+    or ``TenantFleet.links`` with its leading tenant axis);
+    :func:`load_link_faults` reads it back at its saved shapes. Sealed +
+    atomic like every writer in this module."""
     entries = dict(_cfg_entries(cfg))
     entries["__meta__"] = np.frombuffer(
         json.dumps(meta or {}, sort_keys=True).encode(), dtype=np.uint8
@@ -489,9 +490,9 @@ def _ring_liveness_of(perm, alive):
 
 def load_link_faults(path):
     """The link-fault lane of a serving checkpoint, or ``None`` where the
-    archive holds none (a cluster that had none set, or a writer older than
-    the lane): such a cluster resumes unset. Reads the lane's members only;
-    :func:`load_serving_state` returns what it always did."""
+    archive holds none (a cluster or a fleet that had none set, or a writer
+    older than the lane): such a target resumes unset. Reads the lane's
+    members only; :func:`load_serving_state` returns what it always did."""
     import jax.numpy as jnp
 
     from rapid_tpu.models.state import LinkFaults
