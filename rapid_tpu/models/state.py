@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Dict, NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -326,7 +327,19 @@ class EngineState(NamedTuple):
     id_lo: jnp.ndarray  # [n] uint32
     alive: jnp.ndarray  # [n] bool — current membership
     obs_idx: jnp.ndarray  # [k, n] int32 — ring successor (observer) per slot
-    inval_obs: jnp.ndarray  # [k, n] int32 — invalidation-observer table
+    # The invalidation-observer table, and the TRUE ring topology of the
+    # membership: inval_obs == ring_topology_from_perm(ring_perm, alive).obs_idx
+    # at every slot that is not a pending joiner, always (a pending joiner's
+    # column holds its gatekeepers). Writers: ``initial_state`` (the walk), a
+    # join placement (gatekeepers, at pending columns only, into both lanes)
+    # and the view change, which REPAIRS this lane at its cut's slots and
+    # their ring predecessors and derives ``obs_idx`` from the same repaired
+    # table (``ops/rings.ring_tables_after_cut``). A leave rewrites ``obs_idx``
+    # alone; nothing else may write either lane, or the repair starts from a
+    # table that is not the ring's. A load makes the lane whole off the
+    # pending columns (``with_observer_table_rebuilt``), as it makes
+    # ``ring_alive``: an archive is not trusted for it.
+    inval_obs: jnp.ndarray  # [k, n] int32
     config_epoch: jnp.ndarray  # int32 — counts view changes
     config_hi: jnp.ndarray  # uint32 — commutative config-id lanes
     config_lo: jnp.ndarray  # uint32
@@ -458,6 +471,38 @@ def initial_state(cfg: EngineConfig, key_hi, key_lo, id_hi, id_lo, alive) -> Eng
         round_idx=jnp.int32(0),
         retired=jnp.zeros((n,), dtype=bool),
     )
+
+
+@jax.jit
+def _observer_table_off_pending(perm, ring_alive, alive, pending, saved):
+    """:func:`with_observer_table_rebuilt`'s one program a load shape."""
+
+    def one(perm, ring_alive, alive, pending, saved):
+        walked = ring_topology_from_perm(perm, alive, ring_alive).obs_idx
+        return jnp.where(pending[None, :], saved, walked.astype(saved.dtype))
+
+    return (jax.vmap(one) if perm.ndim == 3 else one)(
+        perm, ring_alive, alive, pending, saved
+    )
+
+
+def with_observer_table_rebuilt(state: EngineState) -> EngineState:
+    """``state`` (a cluster's, or a fleet's stacked one; its masks may be
+    bit-packed) with ``inval_obs`` made whole, as a loader makes the ring
+    lanes: the walk's table of the perms and the membership it holds, the
+    held column where a joiner is pending (gatekeepers are history, not
+    topology). A view change repairs that lane in place and derives
+    ``obs_idx`` from it, so a wrong column that came in with an archive
+    would ride through every later commit; a sound state comes back bit for
+    bit. One walk, a tenant at a time: ``utils/checkpoint``'s loaders call
+    it, outside any round program."""
+    alive, pending = (
+        lane if lane.dtype == bool else unpack_bool(lane)
+        for lane in (state.alive, state.join_pending)
+    )
+    return state._replace(inval_obs=_observer_table_off_pending(
+        state.ring_perm, state.ring_alive, alive, pending, state.inval_obs
+    ))
 
 
 class FaultInputs(NamedTuple):

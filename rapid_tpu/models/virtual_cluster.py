@@ -50,7 +50,7 @@ from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
     ring_liveness,
-    ring_liveness_after_cut,
+    ring_tables_after_cut,
     ring_topology_from_perm,
 )
 from rapid_tpu.utils import engine_telemetry, exposition
@@ -914,17 +914,23 @@ def apply_view_change_impl(
     DOWN alerts, which re-fire from the persistent crash masks, a wiped UP
     edge would never re-fire and the joiner would be stranded forever.
 
-    The ring walk reads liveness by ring position from the state's
-    ``ring_alive`` lane, and this is the lane's one writer
-    (``ops/rings.ring_liveness_after_cut``): the cut's own positions are
-    flipped, K·B look-ups and updates where ``alive2[ring_perm]`` gathers
-    K·N, and a cut that overflows the bucket takes that gather.
-    ``took_dense`` says which, for the telemetry plane
-    (:func:`_count_dense_commit`). ``dense_arms`` (:func:`_compute_round`)
-    traces the gather alone and ``took_dense`` is True. ``batch_axis`` and
-    ``commits`` go down to the lane's conditional: under a ``vmap`` that
-    names its axis it stays a conditional, opened by the members whose cut
-    the caller commits."""
+    Both ring tables are UPDATED, not rebuilt
+    (``ops/rings.ring_tables_after_cut``, their one writer here): the
+    ``ring_alive`` lane has the cut's own positions flipped, and the observer
+    table is ``inval_obs`` repaired at the cut's slots and at their
+    predecessors, 2·K·B updates where the walk scatters K·N; a cut that
+    overflows the bucket, or a ring with fewer than two alive, takes the
+    gather and the walk whole. The repair stands on ``inval_obs`` being the
+    walk's table at every slot that is not a pending joiner
+    (``models/state.EngineState``); ``obs_idx`` is derived from the SAME
+    repaired table, so a column a leave had pointed at the leaver itself
+    (:meth:`VirtualCluster.initiate_leave`) comes back onto the ring as the
+    rebuild puts it back. ``took_dense`` says which form ran, for the
+    telemetry plane (:func:`_count_dense_commit`). ``dense_arms``
+    (:func:`_compute_round`) traces the rebuild alone and ``took_dense`` is
+    True. ``batch_axis`` and ``commits`` go down to the conditional: under a
+    ``vmap`` that names its axis it stays a conditional, opened by the
+    members whose cut the caller commits."""
     n, k, c = cfg.n, cfg.k, cfg.c
     pol = compaction_policy(cfg)
     idt, cdt = jnp.dtype(pol.idx), jnp.dtype(pol.cohort)
@@ -933,16 +939,15 @@ def apply_view_change_impl(
     if dense_arms:
         ring_alive2 = ring_liveness(state.ring_perm, alive2)
         took_dense = jnp.bool_(True)
+        # Sort-free: O(N) scans over the static key-order perms, not a K-ring
+        # argsort. The topology kernels compute at int32; the stores below
+        # narrow to the policy's index dtype (lossless: values in [-1, n-1]).
+        observers = ring_topology_from_perm(state.ring_perm, alive2, ring_alive2).obs_idx
     else:
-        ring_alive2, took_dense = ring_liveness_after_cut(
-            state.ring_alive, state.ring_perm, state.ring_pos, alive2,
-            winner_mask, batch_axis, commits,
+        ring_alive2, observers, took_dense = ring_tables_after_cut(
+            state.inval_obs, state.ring_alive, state.ring_perm, state.ring_pos,
+            alive2, winner_mask, batch_axis, commits,
         )
-    # Sort-free: O(N) scans over the static key-order perms, not a K-ring
-    # argsort — at N=1M the re-sort was the commit path's largest block.
-    # The topology kernels compute at int32; stores narrow to the policy's
-    # index dtype (lossless: values in [-1, n-1]).
-    topo = ring_topology_from_perm(state.ring_perm, alive2, ring_alive2)
     config_hi, config_lo = masked_set_hash(state.id_hi, state.id_lo, alive2)
     still_pending = state.join_pending & ~winner_mask  # [n]
     fd_fired2 = state.fd_fired & still_pending[:, None]
@@ -951,11 +956,10 @@ def apply_view_change_impl(
         ring_alive=ring_alive2,
         # Departing members' identity lanes are spent forever.
         retired=state.retired | (winner_mask & state.alive),
-        obs_idx=jnp.where(
-            still_pending[None, :], state.obs_idx, topo.obs_idx.astype(idt)
-        ),
+        # A joiner still pending keeps its gatekeepers in both lanes.
+        obs_idx=jnp.where(still_pending[None, :], state.obs_idx, observers.astype(idt)),
         inval_obs=jnp.where(
-            still_pending[None, :], state.inval_obs, topo.obs_idx.astype(idt)
+            still_pending[None, :], state.inval_obs, observers.astype(idt)
         ),
         config_epoch=state.config_epoch + 1,
         config_hi=config_hi,

@@ -11,9 +11,9 @@ from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
     ring_liveness,
-    ring_liveness_after_cut,
     ring_perms,
     ring_positions,
+    ring_tables_after_cut,
     ring_topology,
     ring_topology_from_perm,
 )
@@ -33,9 +33,9 @@ __all__ = [
     "endpoint_ring_keys",
     "predecessor_of_keys",
     "ring_liveness",
-    "ring_liveness_after_cut",
     "ring_perms",
     "ring_positions",
+    "ring_tables_after_cut",
     "ring_topology",
     "ring_topology_from_perm",
 ]

@@ -148,15 +148,15 @@ def _ordered_uint32(word):
     return jax.lax.bitcast_convert_type(word, jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
-@jax.jit
-def _from_perm_single(perm, alive, ao=None):
-    """One ring, sort-free: (obs_idx[N], subj_idx[N], order[N]) from the
-    static key order. Successor among alive = slot at the next alive
-    position in the fixed circular order, predecessor = slot at the
-    previous one; the alive-first ``order`` is a stable partition
-    (rank scans + one scatter). Bit-identical to ``_ring_topology_single``:
-    restricting a fixed total order to the alive subset IS the alive
-    order, and lex_argsort is stable so dead slots tie-break identically.
+def _neighbour_slots(perm, ao):
+    """One ring's walk proper: ``(succ_slot[N], pred_slot[N])`` as uint32, the
+    slot at the first alive position strictly after each ring position and at
+    the last one strictly before it (cyclic), from the static key order
+    ``perm`` and the alive bit per ring position ``ao``. Defined at DEAD
+    positions too (a dead position's neighbours are those its slot would have
+    if it were alive), which is what a repair of the table at a cut's
+    positions reads (:func:`_repair_observers`); arbitrary where nobody is
+    alive.
 
     The walk scans WHO sits at the neighbouring position, not WHERE it is:
     the scanned 32-bit word holds the position in its high bits and a piece
@@ -182,22 +182,9 @@ def _from_perm_single(perm, alive, ao=None):
     alive position the walk wraps to the first alive slot, at or before the
     first to the last. They are a ``min`` and a ``max`` of their own, not
     ends of the scans, so each side needs its own scan only: a caller that
-    takes ``obs_idx`` alone (the engine) compiles one scan a piece and one
-    scatter. With fewer than two alive every entry is -1 anyway.
-
-    Jitted, so that an eager caller dispatches one program and not each of
-    the walk's forty small operations: ``initial_state`` below
-    :data:`RING_AT_A_TIME_SLOTS` is eager, and a fleet builds hundreds of
-    tenants through it. Inside a traced caller the jit is inlined.
-
-    ``ao`` is the alive bit per ring position, ``alive[perm]``, where the
-    caller holds it (``EngineState.ring_alive``, kept exact by every view
-    change: :func:`ring_liveness_after_cut`); without it the walk gathers it.
-    """
+    takes ``obs_idx`` alone (the engine's rebuild) compiles one scan a piece
+    and one scatter."""
     n = perm.shape[0]
-    if ao is None:
-        ao = alive[perm]  # alive bit per ring position
-    n_alive = jnp.sum(ao.astype(jnp.int32))
     piece_bits, pieces = ring_walk_pieces(n)
     pos = jnp.arange(n, dtype=jnp.uint32)
     pos_field = pos << piece_bits
@@ -226,6 +213,35 @@ def _from_perm_single(perm, alive, ao=None):
         )
         succ_slot |= (nxt & piece_mask) << shift
         pred_slot |= (prv & piece_mask) << shift
+    return succ_slot, pred_slot
+
+
+@jax.jit
+def _from_perm_single(perm, alive, ao=None):
+    """One ring, sort-free: (obs_idx[N], subj_idx[N], order[N]) from the
+    static key order. Successor among alive = slot at the next alive
+    position in the fixed circular order, predecessor = slot at the
+    previous one (:func:`_neighbour_slots`, the walk); the alive-first
+    ``order`` is a stable partition (rank scans + one scatter).
+    Bit-identical to ``_ring_topology_single``: restricting a fixed total
+    order to the alive subset IS the alive order, and lex_argsort is stable
+    so dead slots tie-break identically. With fewer than two alive every
+    entry is -1.
+
+    Jitted, so that an eager caller dispatches one program and not each of
+    the walk's forty small operations: ``initial_state`` below
+    :data:`RING_AT_A_TIME_SLOTS` is eager, and a fleet builds hundreds of
+    tenants through it. Inside a traced caller the jit is inlined.
+
+    ``ao`` is the alive bit per ring position, ``alive[perm]``, where the
+    caller holds it (``EngineState.ring_alive``, kept exact by every view
+    change: :func:`ring_tables_after_cut`); without it the walk gathers it.
+    """
+    n = perm.shape[0]
+    if ao is None:
+        ao = alive[perm]  # alive bit per ring position
+    n_alive = jnp.sum(ao.astype(jnp.int32))
+    succ_slot, pred_slot = _neighbour_slots(perm, ao)
 
     valid = ao & (n_alive >= 2)
     succ_slot = jnp.where(valid, succ_slot.astype(jnp.int32), -1)
@@ -333,7 +349,7 @@ def ring_liveness(perm: jnp.ndarray, alive: jnp.ndarray) -> jnp.ndarray:
     position of every ring: 7.6 ms a ring at 1M on the v5e, the dearest
     form there is), made ONCE where the state is made or loaded
     (``EngineState.ring_alive``); a view change then flips its cut's own
-    positions (:func:`ring_liveness_after_cut`) and comes here only for a
+    positions (:func:`ring_tables_after_cut`) and comes here only for a
     cut its bucket cannot hold, or in a program whose node axis is
     sharded (a compaction is a global operation over that axis)."""
     perm, alive = jnp.asarray(perm), jnp.asarray(alive, dtype=bool)
@@ -342,7 +358,7 @@ def ring_liveness(perm: jnp.ndarray, alive: jnp.ndarray) -> jnp.ndarray:
 
 def view_change_bucket(n: int) -> int:
     """Slots of a cut that a view change flips in place
-    (:func:`ring_liveness_after_cut`): an eighth of the ``n`` slots, rounded
+    (:func:`ring_tables_after_cut`): an eighth of the ``n`` slots, rounded
     up to whole 128-lane tiles as ``ops/cut_detection.invalidation_bucket``
     is. The largest cut any cell commits is a bootstrap wave's 242 joiners
     of 2,000 slots (12.1 %; 5,000 of 102,500, 10,000 of 1,000,000 and 16 of
@@ -351,65 +367,153 @@ def view_change_bucket(n: int) -> int:
     return -(-n // (8 * 128)) * 128
 
 
-def _flip_positions(ring_alive, ring_pos, alive, slots):
-    """``ring_alive`` with ``alive[s]`` written at ``ring_pos[k, s]`` for
-    every ``s`` of ``slots`` (``[B]``) and every ring k: K·B look-ups of
-    ``ring_pos`` and K·B updates. Right for ANY slot, so ``slots`` may
-    repeat and may name slots whose bit did not change: no mask of valid
-    entries. ONE ``[K, B]`` scatter at every ring length: unlike the walk's
-    N-update scatter, which long rings take one at a time
+def _flip_positions(ring_alive, at, stays):
+    """``ring_alive`` with ``stays[j]``, the bit of slot j of a cut's bucket
+    AFTER the cut, written at ``at[k, j]``, its position on every ring k
+    (``ring_pos[:, slots]``, the K·B look-ups a view change's two updates
+    share): K·B updates. Right for ANY slot, so the bucket
+    may repeat a slot and may name slots whose bit did not change: no mask
+    of valid entries. ONE ``[K, B]`` scatter at every ring length: unlike
+    the walk's N-update scatter, which long rings take one at a time
     (:data:`RING_AT_A_TIME_SLOTS`), B updates a ring into ``[K, N]`` run
     faster together than a ring at a time (14.4 against 21.5 ms at 1M, 1.9
     against 3.1 at 102,500, a tie under the fleets' ``vmap``; ``bool``,
     ``int8`` and ``int32`` lanes within 15 % of each other: PERF.md section
     6, PR 50's probe, TPU v5e)."""
     rings = jnp.arange(ring_alive.shape[0], dtype=jnp.int32)[:, None]
-    at = ring_pos[:, slots].astype(jnp.int32)  # stored at int8 / int16 by the compact engine
-    return ring_alive.at[rings, at].set(alive[slots][None, :])
+    return ring_alive.at[rings, at].set(stays[None, :])
 
 
-def ring_liveness_after_cut(
-    ring_alive: jnp.ndarray, ring_perm: jnp.ndarray, ring_pos: jnp.ndarray,
-    alive: jnp.ndarray, cut: jnp.ndarray, batch_axis=None, commits=None,
+def _repair_observers(table, lane, ring_perm, at, slots, stays):
+    """The observer table of the membership AFTER a cut, from ``table``, the
+    one before it, by writing only the entries the cut can have changed:
+    2·K·B updates where the rebuild (:func:`ring_topology_from_perm`)
+    scatters K·N. ``lane`` is liveness by ring position after the cut,
+    ``slots`` (``[B]``) names at least the cut's slots, ``at`` (``[K, B]``)
+    their ring positions and ``stays`` whether each is alive after the cut.
+    For each of them, at its position d on each ring:
+
+    1. the slot itself reads its new successor ``succ'(d)``, the slot at the
+       first position alive after the cut strictly after d, if it is alive
+       after the cut, else -1;
+    2. the slot at ``pred'(d)``, the last position alive after the cut
+       strictly before d, reads the cut slot itself if that is alive after
+       the cut and ``succ'(d)`` otherwise (nothing alive lies between
+       ``pred'(d)`` and d, so that IS its successor).
+
+    Nothing else changes: a position p alive before and after the cut and
+    outside it has ``next(p) != next'(p)`` only if the nearer of the two is
+    a cut position d, and p is then the last alive-after position before d,
+    ``pred'(d)``. Both values are right for ANY d, changed or not, so
+    ``slots`` may repeat, may name slots the cut left alone (the
+    compaction's filler entries) and two entries that meet on one slot write
+    the same value; the one exception is a slot that is a joiner still
+    pending after the cut, whose column holds its gatekeepers and not a ring
+    neighbour: write 1 puts -1 there, and the caller's select on
+    ``still_pending`` puts the old column back
+    (``models/virtual_cluster.apply_view_change_impl``). Needs two or more
+    alive before and after the cut (else every entry changes to or from -1:
+    the rebuild's case, :func:`ring_tables_after_cut`).
+
+    ``succ'`` and the slot at ``pred'`` by position are the walk's own scans
+    read before its mask (:func:`_neighbour_slots`). A ring's scans, its
+    look-ups by position and its ONE ``[2B]`` scatter run under the schedule
+    the ring length picks (:func:`_per_ring`), UNLIKE the lane's update: B
+    look-ups a ring by a ring's own positions run faster a ring at a time
+    than as one ``[K, B]`` look-up into ``[K, N]`` (the whole repair 48.6
+    against 81.5 ms at 1M, where the walk it replaces is 70.4; 5.05 against
+    5.89 at 102,500; a tie under the fleets' ``vmap``: PERF.md section 6,
+    PR 52's probe, TPU v5e). Where a slot takes one piece of the walk's word
+    (:func:`ring_walk_pieces`: up to 65,536 slots, so at most 16 bits) both
+    neighbours ride ONE word and one look-up fetches both: a look-up by a
+    computed position is the dearest operation here (13 ns an index under
+    the fleets' ``vmap``), a shift is free. With two look-ups the repair
+    ties with the walk there and gains nothing in a cell; with one it reads
+    8.2 against 12.3 ms at ``[256, 10, 1000]`` (the walk 13.0), 7.5 against
+    10.9 at ``[128, 10, 2000]``, 2.24 against 2.70 at ``[10, 50000]``, and a
+    bootstrap 621.5 against 662.6 ms (the parent 655.7; same section, the
+    second probe and its cells)."""
+    _, pieces = ring_walk_pieces(ring_perm.shape[-1])
+
+    def ring(row, ao, perm, at):
+        succ, pred = _neighbour_slots(perm, ao)
+        if pieces == 1:
+            both = ((succ << 16) | pred)[at]
+            succ_of, pred_of = (both >> 16).astype(jnp.int32), (both & 0xFFFF).astype(jnp.int32)
+        else:
+            succ_of, pred_of = succ[at].astype(jnp.int32), pred[at].astype(jnp.int32)
+        return row.at[jnp.concatenate([slots, pred_of])].set(
+            jnp.concatenate(
+                [jnp.where(stays, succ_of, -1), jnp.where(stays, slots, succ_of)]
+            ).astype(row.dtype)
+        )
+
+    return _per_ring(ring, table, lane, ring_perm, at)
+
+
+def ring_tables_after_cut(
+    table: jnp.ndarray, ring_alive: jnp.ndarray, ring_perm: jnp.ndarray,
+    ring_pos: jnp.ndarray, alive: jnp.ndarray, cut: jnp.ndarray,
+    batch_axis=None, commits=None,
 ):
-    """:func:`ring_liveness` of ``ring_perm`` and ``alive``, the membership
-    AFTER a cut, from ``ring_alive``, the lane of the membership before it:
-    the work is the cut's and not the ring's. ``cut`` (``[N]`` bool) names at
-    least every slot whose bit differs; its set slots are compacted into one
-    bucket read off the slot count (:func:`view_change_bucket`; the form of
+    """A view change's two ring tables, ``(lane, table, took_dense)``:
+    :func:`ring_liveness` of ``ring_perm`` and ``alive``, the membership
+    AFTER a cut, and the observer table ``ring_topology_from_perm(ring_perm,
+    alive).obs_idx`` (at ``table``'s dtype), from ``ring_alive`` and
+    ``table``, those of the membership before it: the work is the cut's and
+    not the ring's. ``cut`` (``[N]`` bool) names at least every slot whose
+    bit differs; its set slots are compacted into one bucket read off the
+    slot count (:func:`view_change_bucket`; the form of
     ``ops/cut_detection.first_set_slots``, whose filler entries are some
-    slot, harmless here) and flipped where they sit on each ring
-    (:func:`_flip_positions`). A cut with more members than the bucket holds
-    takes the whole gather, the overflow arm and the oracle, so the result
-    is exact for any cut. Returns ``(lane, took_dense)``, the second a bool:
-    this cut took the gather.
+    slot), flipped where they sit on each ring (:func:`_flip_positions`) and
+    the table repaired there and at their predecessors
+    (:func:`_repair_observers`, which says what a caller with pending
+    joiners still has to select). ``table`` must equal the walk's at every
+    slot the caller keeps from the result (``EngineState.inval_obs`` does).
+
+    A cut with more members than the bucket holds, and a ring with fewer
+    than two alive before or after it, takes the whole gather and the whole
+    walk (N-update scatter a ring): the overflow arm and the oracle, so the
+    result is exact for any cut. ``took_dense`` (a bool) says this cut took
+    it.
 
     ``batch_axis`` names the batch axis of an enclosing ``vmap`` (the
     meshless fleet programs hand one): the conditional is then taken on
     "some member's cut overflows" (``utils/dispatch.cond_across``) and stays
     a conditional, where an unnamed ``vmap`` would make it a select that
-    runs the gather for everybody, always. ``commits`` (this member's
+    runs the rebuild for everybody, always. ``commits`` (this member's
     ``[]`` bool, or ``None``) is whether the caller keeps this member's
     result at all: a cut it drops never opens the arm for the batch."""
     ring_pos = jnp.asarray(ring_pos)
     alive, cut = jnp.asarray(alive, dtype=bool), jnp.asarray(cut, dtype=bool)
+    lane = jnp.asarray(ring_alive, dtype=bool)
     # Made outside the conditional: what an arm captures becomes its operand
     # and stays in HBM (PERF.md section 6, PR 43); the K·N arrays are there
     # anyway.
     bucket = view_change_bucket(cut.shape[0])
     slots = first_set_slots(cut, bucket)
-    overflows = jnp.sum(cut, dtype=jnp.int32) > bucket
-    if commits is not None:
-        overflows = overflows & commits
-    lane, _ = cond_across(
-        batch_axis,
-        overflows,
-        lambda lane, slots: ring_liveness(ring_perm, alive),
-        lambda lane, slots: _flip_positions(lane, ring_pos, alive, slots),
-        jnp.asarray(ring_alive, dtype=bool),
-        slots,
+    dense = (
+        (jnp.sum(cut, dtype=jnp.int32) > bucket)
+        | (jnp.sum(lane[0], dtype=jnp.int32) < 2)  # alive before the cut
+        | (jnp.sum(alive, dtype=jnp.int32) < 2)
     )
-    return lane, overflows
+    if commits is not None:
+        dense = dense & commits
+
+    def rebuild(lane, table, slots):
+        lane = ring_liveness(ring_perm, alive)
+        return lane, ring_topology_from_perm(ring_perm, alive, lane).obs_idx.astype(table.dtype)
+
+    def repair(lane, table, slots):
+        # stored at int8 / int16 by the compact engine
+        at, stays = ring_pos[:, slots].astype(jnp.int32), alive[slots]
+        lane = _flip_positions(lane, at, stays)
+        return lane, _repair_observers(table, lane, ring_perm, at, slots, stays)
+
+    (lane, table), _ = cond_across(
+        batch_axis, dense, rebuild, repair, lane, jnp.asarray(table), slots
+    )
+    return lane, table, dense
 
 
 @jax.jit

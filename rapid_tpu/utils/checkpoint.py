@@ -286,6 +286,7 @@ def load_engine_state(path) -> Tuple["EngineConfig", "EngineState"]:
         EngineState,
         compaction_policy,
         lane_dtypes,
+        with_observer_table_rebuilt,
     )
 
     with _open_npz(path) as data:
@@ -366,7 +367,7 @@ def load_engine_state(path) -> Tuple["EngineConfig", "EngineState"]:
                 raise KeyError(
                     f"checkpoint missing field {field!r} with no known default"
                 )
-        state = EngineState(**arrays)
+        state = with_observer_table_rebuilt(EngineState(**arrays))
     return cfg, state
 
 
@@ -422,7 +423,12 @@ def load_serving_state(path):
     whole by this module — absence means a foreign or damaged file)."""
     import jax.numpy as jnp
 
-    from rapid_tpu.models.state import EngineConfig, EngineState, FaultInputs
+    from rapid_tpu.models.state import (
+        EngineConfig,
+        EngineState,
+        FaultInputs,
+        with_observer_table_rebuilt,
+    )
 
     with _open_npz(path) as data:
         vals = [int(v) for v in data["__cfg__"]]
@@ -454,7 +460,7 @@ def load_serving_state(path):
                 arrays[field] = jnp.asarray(data[key])
             return cls(**arrays)
 
-        state = tree(EngineState, "state")
+        state = with_observer_table_rebuilt(tree(EngineState, "state"))
         faults = tree(FaultInputs, "faults")
         knobs = None
         if any(k.startswith("knobs__") for k in data.files):

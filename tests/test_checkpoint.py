@@ -439,6 +439,64 @@ def test_no_checkpoint_holds_ring_alive_and_every_load_rebuilds_it(tmp_path, tar
                 alive.reshape(-1, alive.shape[-1])[t][perm.reshape(-1, *perm.shape[-2:])[t]])
 
 
+@pytest.mark.parametrize("target", ["cluster", "compact_cluster", "packed_cluster", "fleet"])
+@pytest.mark.parametrize("archive", ["as_written", "with_a_wrong_column"])
+def test_every_load_makes_inval_obs_whole_off_the_pending_columns(tmp_path, target, archive):
+    """A view change REPAIRS ``EngineState.inval_obs`` (PR 52) and derives
+    ``obs_idx`` from it, so the lane has to be the walk's table wherever no
+    joiner is pending: a load does not take an archive's word for that. After
+    a commit and with a joiner placed and still pending, an archive loads to
+    the state that was saved, bit for bit; one whose table holds a wrong
+    column (no writer of this repo's) loads to that state too, and the
+    pending joiner's column keeps the gatekeepers that were saved."""
+    import io
+
+    from rapid_tpu.models.state import pack_masks
+
+    joiner = 31
+    if target == "fleet":
+        from rapid_tpu.tenancy import TenantFleet
+
+        served = TenantFleet.from_clusters([_small_cluster(seed=s) for s in (5, 6)])
+        served.faults = served.faults._replace(
+            crashed=served.faults.crashed.at[0, 3].set(True).at[1, 7].set(True))
+        served.run_to_decision(max_steps=32)
+        served.inject_join_wave([(0, joiner), (1, joiner)])
+        knobs = served.knobs
+    else:
+        served, knobs = _small_cluster(compact=target == "compact_cluster"), None
+        served.crash([3, 7])
+        served.run_until_converged(max_steps=32)
+        served.inject_join_wave([joiner])
+    state, faults = served.state, served.faults
+    assert np.asarray(state.join_pending)[..., joiner].all()
+    assert (np.asarray(state.inval_obs)[..., joiner] >= 0).all()  # its gatekeepers
+    if target == "packed_cluster":
+        state, faults = pack_masks(state), pack_masks(faults)
+    writers = [("serving", "state__inval_obs")]
+    if target in ("cluster", "compact_cluster"):
+        writers.append(("engine", "inval_obs"))
+    for writer, key in writers:
+        path = tmp_path / f"{writer}.npz"
+        if writer == "serving":
+            save_serving_state(path, served.cfg, state, faults, knobs=knobs)
+        else:
+            save_engine_state(path, served.cfg, state)
+        if archive == "with_a_wrong_column":
+            with np.load(io.BytesIO(path.read_bytes()[:-12])) as data:  # less the seal
+                held = {k: data[k] for k in data.files}
+            wrong = held[key].copy()
+            wrong[..., 5] = wrong[..., 6]  # a member's column: somebody else's observers
+            wrong[..., 3] = 0  # a removed member's: no longer -1
+            assert (wrong != held[key]).any()
+            held[key] = wrong
+            buf = io.BytesIO()
+            np.savez_compressed(buf, **held)
+            path.write_bytes(buf.getvalue())
+        loaded = load_serving_state(path)[1] if writer == "serving" else load_engine_state(path)[1]
+        _trees_bit_identical(loaded, state)
+
+
 def test_wide_checkpoint_loads_under_a_compact_config(tmp_path):
     """Migration path: a checkpoint written by a WIDE deployment is brought
     up compact — validate the envelope, narrow, and the widened view is

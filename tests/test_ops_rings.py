@@ -12,9 +12,9 @@ from rapid_tpu.ops.rings import (
     endpoint_ring_keys,
     predecessor_of_keys,
     ring_liveness,
-    ring_liveness_after_cut,
     ring_perms,
     ring_positions,
+    ring_tables_after_cut,
     ring_topology,
     ring_topology_from_perm,
     view_change_bucket,
@@ -523,11 +523,12 @@ def _cut_case(spec, n, alive, bucket, rng):
     (31_000, 2, np.int32, False),   # and under it
 ])
 def test_a_cut_flips_its_own_ring_positions(n, k, idx, one_at_a_time, cut_spec, monkeypatch):
-    """The lane after a cut, by update, is ``alive2[perm]`` bit for bit, for
-    cuts at the bucket's corners (the overflow arm among them: one member
-    more than it holds) and with the compaction's filler entries repeating a
-    slot; and the walk fed the lane gives the tables of the walk that
-    gathers. Both ring schedules, the compact index widths included."""
+    """The lane after a cut, by update, is ``alive2[perm]`` bit for bit, and
+    the observer table, by repair, the walk's (PR 52), for cuts at the
+    bucket's corners (the overflow arm among them: one member more than it
+    holds) and with the compaction's filler entries repeating a slot; and
+    the walk fed the lane gives the tables of the walk that gathers. Both
+    ring schedules, the compact index widths included."""
     if one_at_a_time != (n >= rings.RING_AT_A_TIME_SLOTS):
         monkeypatch.setattr(rings, "RING_AT_A_TIME_SLOTS", n if one_at_a_time else n + 1)
     rng = np.random.default_rng(n * 7 + k + len(cut_spec))
@@ -541,35 +542,38 @@ def test_a_cut_flips_its_own_ring_positions(n, k, idx, one_at_a_time, cut_spec, 
     alive2 = alive ^ cut
 
     @jax.jit  # traced after the patch
-    def commit(lane, perm, pos, alive2, cut):
-        lane2, took_dense = ring_liveness_after_cut(lane, perm, pos, alive2, cut)
+    def commit(table, lane, perm, pos, alive2, cut):
+        lane2, table2, took_dense = ring_tables_after_cut(table, lane, perm, pos, alive2, cut)
         fed = ring_topology_from_perm(perm, alive2, lane2)
-        return lane2, took_dense, fed, ring_topology_from_perm(perm, alive2)
+        return lane2, table2, took_dense, fed, ring_topology_from_perm(perm, alive2)
 
+    table = ring_topology_from_perm(perm, alive).obs_idx.astype(idx)
     loops = [
         name for name in _primitives(jax.make_jaxpr(commit)(
-            ring_liveness(perm, alive), perm, pos, alive2, cut).jaxpr)
+            table, ring_liveness(perm, alive), perm, pos, alive2, cut).jaxpr)
         if name in ("scan", "while")
     ]
     assert bool(loops) == one_at_a_time
     lane = ring_liveness(perm, alive)
     assert lane.dtype == np.bool_ and lane.shape == perm.shape
     np.testing.assert_array_equal(np.asarray(lane), alive[np.asarray(perm)])
-    lane2, took_dense, fed, gathering = commit(lane, perm, pos, alive2, cut)
+    lane2, table2, took_dense, fed, gathering = commit(table, lane, perm, pos, alive2, cut)
     np.testing.assert_array_equal(np.asarray(lane2), alive2[np.asarray(perm)])
     assert bool(took_dense) == (int(cut.sum()) > bucket)
     for got, want in zip(fed, gathering):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     want = ring_topology(key_hi, key_lo, alive2)
     np.testing.assert_array_equal(np.asarray(fed.obs_idx), np.asarray(want.obs_idx))
+    assert table2.dtype == idx
+    np.testing.assert_array_equal(np.asarray(table2), np.asarray(want.obs_idx))
 
 
 def test_the_overflow_arm_stays_a_conditional_under_a_named_vmap():
     """Under a ``vmap`` that names its axis the choice between the update and
-    the whole gather is ONE conditional for the batch, opened by the members
+    the whole rebuild is ONE conditional for the batch, opened by the members
     whose cut is both too large and committed; unnamed, it is a select (no
     conditional left: both forms run for everybody). Per committing member
-    the result is the gather's either way."""
+    the result is the rebuild's either way, lane and observer table."""
     tenants, n, k = 3, 1000, 3
     rng = np.random.default_rng(50)
     bucket = view_change_bucket(n)
@@ -583,24 +587,170 @@ def test_the_overflow_arm_stays_a_conditional_under_a_named_vmap():
     perm, alive, cut = (jnp.asarray(np.stack(x)) for x in (perms, alives, cuts))
     pos = jax.vmap(ring_positions)(perm)
     lane = jax.vmap(ring_liveness)(perm, alive)
+    table = jax.vmap(lambda p, a: ring_topology_from_perm(p, a).obs_idx)(perm, alive)
     alive2 = alive ^ cut
     want = jax.vmap(ring_liveness)(perm, alive2)
+    want_table = jax.vmap(lambda p, a: ring_topology_from_perm(p, a).obs_idx)(perm, alive2)
 
     def commit(axis):
-        def one(lane, perm, pos, alive2, cut, commits):
-            return ring_liveness_after_cut(lane, perm, pos, alive2, cut, axis, commits)
+        def one(table, lane, perm, pos, alive2, cut, commits):
+            return ring_tables_after_cut(table, lane, perm, pos, alive2, cut, axis, commits)
         return jax.jit(jax.vmap(one, axis_name=axis))
 
     def conditionals(fn, *args):
         return [p for p in _primitives(jax.make_jaxpr(fn)(*args).jaxpr) if p == "cond"]
 
     for commits in ([True, True, True], [True, False, True], [True, False, False]):
-        args = (lane, perm, pos, alive2, cut, jnp.asarray(commits))
-        got, took_dense = commit("tenants")(*args)
+        args = (table, lane, perm, pos, alive2, cut, jnp.asarray(commits))
+        got, got_table, took_dense = commit("tenants")(*args)
         kept = np.asarray(commits)  # a member that does not commit is dropped by its caller
         np.testing.assert_array_equal(np.asarray(got)[kept], np.asarray(want)[kept])
+        np.testing.assert_array_equal(np.asarray(got_table)[kept], np.asarray(want_table)[kept])
         assert took_dense.tolist() == [False, commits[1], commits[2]]
         assert len(conditionals(commit("tenants"), *args)) == 1
-    got, _ = commit(None)(*args)
+    got, got_table, _ = commit(None)(*args)
     np.testing.assert_array_equal(np.asarray(got)[kept], np.asarray(want)[kept])
+    np.testing.assert_array_equal(np.asarray(got_table)[kept], np.asarray(want_table)[kept])
     assert conditionals(commit(None), *args) == []
+
+
+# ---------------------------------------------------------------------------
+# The observer table as a lane a view change repairs (PR 52)
+# ---------------------------------------------------------------------------
+
+
+def _repair_case(spec, n, perm, rng):
+    """``(alive before, cut)`` a case names. ``perm`` is the ``[k, n]`` key
+    order; with ``_keys`` slot 0 is first and slot n - 1 last on EVERY ring,
+    so the cases that speak of the ring's two ends hold on all of them."""
+    alive, cut = rng.random(n) < 0.8, np.zeros(n, dtype=bool)
+    if spec == "three_neighbours_removed":  # a run of cut positions, on each ring its own
+        for ring, start in enumerate(rng.choice(n - 3, size=perm.shape[0], replace=False)):
+            run = perm[ring, start:start + 3]
+            alive[run], cut[run] = True, True
+    elif spec == "joiner_between_two_removed":
+        for ring, start in enumerate(rng.choice(n - 3, size=perm.shape[0], replace=False)):
+            run = perm[ring, start:start + 3]
+            alive[run], cut[run] = [True, False, True], True
+    elif spec == "both_ends_leave":  # the wrap on both sides
+        alive[[0, n - 1]], cut[[0, n - 1]] = True, True
+    elif spec == "both_ends_join":
+        alive[[0, n - 1]], cut[[0, n - 1]] = False, True
+    elif spec == "first_leaves_last_joins":
+        alive[[0, n - 1]], cut[[0, n - 1]] = [True, False], True
+    elif spec in ("none_alive_before", "one_alive_before", "two_alive_before"):
+        alive[:] = False
+        alive[rng.choice(n, size=("none", "one", "two").index(spec.split("_")[0]), replace=False)] = True
+        cut[rng.choice(np.flatnonzero(~alive), size=5, replace=False)] = True
+    elif spec in ("none_alive_after", "one_alive_after", "two_alive_after"):
+        alive[:] = False
+        alive[rng.choice(n, size=6, replace=False)] = True
+        left = ("none", "one", "two").index(spec.split("_")[0])
+        cut[rng.choice(np.flatnonzero(alive), size=6 - left, replace=False)] = True
+    elif spec == "two_replaced_by_two":
+        alive[:] = False
+        alive[rng.choice(n, size=2, replace=False)] = True
+        cut[alive] = True
+        cut[rng.choice(np.flatnonzero(~alive), size=2, replace=False)] = True
+    else:
+        raise ValueError(spec)
+    return alive, cut
+
+
+_REPAIR_SHAPES = [
+    (64, 3, np.int8, True),        # n an exact power of two, both schedules,
+    (256, 10, np.int16, False),    # the compact engine's index widths
+    (4096, 2, np.int16, True),
+    (1000, 3, np.int32, False),    # paper-fleet-1k's ring
+    (33_000, 2, np.int32, True),   # over RING_AT_A_TIME_SLOTS as it stands
+    (66_000, 2, np.int32, True),   # two pieces a slot in the walk's word: a look-up a neighbour
+]
+
+
+@pytest.mark.parametrize("spec", [
+    "three_neighbours_removed", "joiner_between_two_removed", "both_ends_leave",
+    "both_ends_join", "first_leaves_last_joins", "none_alive_before", "one_alive_before",
+    "two_alive_before", "none_alive_after", "one_alive_after", "two_alive_after",
+    "two_replaced_by_two",
+])
+@pytest.mark.parametrize("n,k,idx,one_at_a_time", _REPAIR_SHAPES)
+def test_a_cut_repairs_only_the_observers_it_changed(n, k, idx, one_at_a_time, spec, monkeypatch):
+    """The observer table by repair is the walk's on the same ``(perm,
+    alive2)``, entry for entry, where the cut's positions are neighbours,
+    hold both ends of the ring, or leave the ring at the floor of two alive;
+    under it, before or after the cut, every entry changes to or from -1 and
+    the commit takes the rebuild and says so."""
+    if one_at_a_time != (n >= rings.RING_AT_A_TIME_SLOTS):
+        monkeypatch.setattr(rings, "RING_AT_A_TIME_SLOTS", n if one_at_a_time else n + 1)
+    rng = np.random.default_rng(n * 11 + k + len(spec))
+    key_hi, key_lo = _keys(n, k, rng)
+    perm = ring_perms(key_hi, key_lo).astype(idx)
+    pos = ring_positions(perm)
+    alive, cut = _repair_case(spec, n, np.asarray(perm), rng)
+    alive2 = alive ^ cut
+    table = ring_topology_from_perm(perm, alive).obs_idx.astype(idx)
+    commit = jax.jit(ring_tables_after_cut)  # traced after the patch
+    loops = {
+        name for name in _primitives(
+            jax.make_jaxpr(ring_tables_after_cut)(table, ring_liveness(perm, alive), perm, pos, alive2, cut).jaxpr)
+        if name in ("scan", "while")
+    }
+    assert bool(loops) == one_at_a_time
+    lane2, table2, took_dense = commit(table, ring_liveness(perm, alive), perm, pos, alive2, cut)
+    assert bool(took_dense) == (alive.sum() < 2 or alive2.sum() < 2), spec
+    assert table2.dtype == idx
+    np.testing.assert_array_equal(np.asarray(lane2), alive2[np.asarray(perm)])
+    want = ring_topology(key_hi, key_lo, alive2).obs_idx
+    np.testing.assert_array_equal(np.asarray(table2), np.asarray(want))
+    if alive2.sum() < 2:
+        assert (np.asarray(table2) == -1).all()
+
+
+@pytest.mark.parametrize("idx", [np.int16, np.int32])
+def test_the_repair_is_right_for_any_slot_but_a_joiner_still_pending(idx):
+    """The repair's two writes hold for ANY slot of the bucket, changed by the
+    cut or not: slots may repeat and may name members and dead slots the cut
+    left alone. The one exception is a joiner still pending after the cut (a
+    filler entry of the compaction may name one: it names slot n - 1): its
+    column holds its gatekeepers, the repair writes -1 there, and the commit's
+    select on ``still_pending`` keeps the gatekeepers in both lanes."""
+    n, k = 1000, 3
+    rng = np.random.default_rng(52)
+    key_hi, key_lo = _keys(n, k, rng)
+    perm = ring_perms(key_hi, key_lo).astype(idx)
+    pos = ring_positions(perm)
+    alive = rng.random(n) < 0.7
+    joiner = n - 1  # pending before and after the cut: where the filler entries point
+    alive[joiner] = False
+    gatekeepers = np.asarray(predecessor_of_keys(pos, perm, alive, np.asarray([joiner])))[:, 0]
+    assert (gatekeepers >= 0).all()
+    table = np.array(ring_topology_from_perm(perm, alive).obs_idx.astype(idx))
+    table[:, joiner] = gatekeepers
+    cut = np.zeros(n, dtype=bool)
+    cut[rng.choice(np.flatnonzero(alive), size=7, replace=False)] = True
+    cut[rng.choice(np.flatnonzero(~alive)[:-1], size=2, replace=False)] = True  # two joiners admitted
+    alive2 = alive ^ cut
+    want = np.asarray(ring_topology_from_perm(perm, alive2).obs_idx)
+    still_pending = np.arange(n) == joiner
+
+    bystanders = np.concatenate([
+        rng.choice(np.flatnonzero(alive & ~cut), size=6), rng.choice(np.flatnonzero(~alive2)[:-1], size=6)])
+    slots = jnp.asarray(np.concatenate(
+        [np.flatnonzero(cut), np.flatnonzero(cut)[::-1], bystanders, [joiner] * 3]).astype(np.int32))
+    at, stays = pos[:, slots].astype(jnp.int32), jnp.asarray(alive2)[slots]
+    repaired = np.asarray(rings._repair_observers(
+        jnp.asarray(table), ring_liveness(perm, alive2), perm, at, slots, stays))
+    assert repaired.dtype == idx
+    np.testing.assert_array_equal(repaired[:, ~still_pending], want[:, ~still_pending])
+    assert (repaired[:, joiner] == -1).all()  # write 1 over the gatekeepers: the exception
+
+    # through the commit's compaction, whose filler entries name slot n - 1
+    assert int(np.asarray(rings.first_set_slots(jnp.asarray(cut), view_change_bucket(n)))[-1]) == joiner
+    _, repaired, took_dense = ring_tables_after_cut(
+        jnp.asarray(table), ring_liveness(perm, alive), perm, pos, alive2, cut)
+    assert not bool(took_dense)
+    assert (np.asarray(repaired)[:, joiner] == -1).all()
+    for lane in (table, np.where(np.arange(n) < 5, -7, table).astype(idx)):  # inval_obs; an obs_idx a leave rewrote
+        kept = np.where(still_pending[None, :], lane, np.asarray(repaired))
+        np.testing.assert_array_equal(kept, np.where(still_pending[None, :], lane, want))
+        np.testing.assert_array_equal(kept[:, joiner], lane[:, joiner])

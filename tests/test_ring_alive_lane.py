@@ -1,16 +1,20 @@
-"""``EngineState.ring_alive`` (liveness by ring position, PR 50) on the live
-drivers: CPU twins of the benchmark's cells' traffic through every driver that
-commits a view change, each beside a twin whose programs trace the DENSE arm
-alone (``dense_arms=True``: the whole ``alive[ring_perm]`` gather at every
-commit, what every program did before and what a mesh's still do). After every
-commit: ``ring_alive == alive[ring_perm]`` on both, and the two states are
-equal lane for lane; the telemetry plane's ``view_change_dense`` reads 0 over
-the cells' traffic, 1 for the one cut that overflows the bucket by one member,
-and every commit under the dense-only programs.
+"""``EngineState.ring_alive`` (liveness by ring position, PR 50) and the
+observer tables a view change repairs (PR 52) on the live drivers: CPU twins of
+the benchmark's cells' traffic through every driver that commits a view
+change, each beside a twin whose programs trace the DENSE arm alone
+(``dense_arms=True``: the whole ``alive[ring_perm]`` gather and the whole ring
+walk at every commit, what every program did before and what a mesh's still
+do). After every commit: ``ring_alive == alive[ring_perm]`` on both,
+``inval_obs`` is the walk's table at every slot that is not a pending joiner
+(the invariant the repair stands on), and the two states are equal lane for
+lane; the telemetry plane's ``view_change_dense`` reads 0 over the cells'
+traffic, 1 for the one cut that overflows the bucket by one member, and every
+commit under the dense-only programs. Two states no cell sends ride along: a
+leaver the next cut does not contain, and a joiner still pending after a cut.
 
-The pure function (the bounded update against the gather at the bucket's
-corners) is ``tests/test_ops_rings.py``'s, the structure of the compiled
-programs ``tests/test_spans.py``'s.
+The pure functions (the bounded updates against the gather and the walk at the
+bucket's corners) are ``tests/test_ops_rings.py``'s, the structure of the
+compiled programs ``tests/test_spans.py``'s.
 
 The drives run in ONE process of their own (``python tests/test_ring_alive_lane.py``
 prints one JSON record a driver): the executables they compile stay out of this
@@ -43,6 +47,24 @@ def _lane_holds(shot) -> bool:
         alive, perm, lane = alive[None], perm[None], lane[None]
     return lane.dtype == np.bool_ and all(
         np.array_equal(lane[t], alive[t][perm[t]]) for t in range(alive.shape[0])
+    )
+
+
+def _table_holds(shot) -> bool:
+    """``inval_obs`` is the ring walk's observer table of the membership at
+    every slot that is not a pending joiner (a pending joiner's column holds
+    its gatekeepers), a tenant at a time under a fleet."""
+    from rapid_tpu.ops.rings import ring_topology_from_perm
+
+    alive, perm, table, pending = (
+        shot[field] for field in ("alive", "ring_perm", "inval_obs", "join_pending"))
+    if alive.ndim == 1:
+        alive, perm, table, pending = alive[None], perm[None], table[None], pending[None]
+    return all(
+        np.array_equal(
+            table[t][:, ~pending[t]],
+            np.asarray(ring_topology_from_perm(perm[t], alive[t]).obs_idx)[:, ~pending[t]])
+        for t in range(alive.shape[0])
     )
 
 
@@ -96,6 +118,75 @@ def cluster_wave_overflow(vcm):
     crashes at once (23 %; detectors in step, so that they go in ONE cut),
     which takes the overflow arm; the cut after it fits again."""
     return cluster_wave(vcm, members=1100, waves=((257, 0), (4, 0)), stagger=False)
+
+
+def _unheard(vc, senders):
+    """Every cohort deaf to ``senders`` (none: healed)."""
+    lane = np.zeros((vc.cfg.c, vc.cfg.n), dtype=bool)
+    lane[:, list(senders)] = True
+    vc.set_rx_block(lane)
+
+
+def _observed_by(vc, watchers):
+    """The slots with one of ``watchers`` among their ring observers."""
+    return np.flatnonzero(np.isin(np.asarray(vc.state.inval_obs), list(watchers)).any(axis=0))
+
+
+def cluster_leaver(vcm):
+    """No cell's: a member announces its leave (``initiate_leave`` points its
+    ``obs_idx`` column at itself) and nobody hears it, while six others crash:
+    the cut that commits does NOT contain the leaver, and the view change has
+    to put its column back onto the ring in ``obs_idx`` (only then do its
+    real observers probe it, find it gone and evict it in the next cut)."""
+    vc = vcm.VirtualCluster.create(400, n_slots=440, seed=50, **CLUSTER)
+    vc.assign_cohorts_roundrobin()
+    leaver, shots = 123, [_snapshot(vc.state)]
+    standing = np.setdiff1d(np.arange(400), np.append(_observed_by(vc, [leaver]), leaver))
+    victims = np.random.default_rng(52).choice(standing, size=6, replace=False)
+    _unheard(vc, [leaver])
+    vc.initiate_leave([leaver])
+    assert (np.asarray(vc.state.obs_idx)[:, leaver] == leaver).all()
+    vc.crash(victims)
+    vc.run_until_membership(394, max_steps=192, max_cuts=1, min_cuts=1)
+    shots.append(_snapshot(vc.state))
+    assert vc.membership_size == 394 and shots[-1]["alive"][leaver]
+    np.testing.assert_array_equal(  # back on the ring: the trap of a repair of obs_idx itself
+        shots[-1]["obs_idx"][:, leaver], shots[-1]["inval_obs"][:, leaver])
+    assert (shots[-1]["obs_idx"][:, leaver] != leaver).all()
+    _unheard(vc, [])
+    vc.run_until_membership(393, max_steps=192, max_cuts=1, min_cuts=1)
+    shots.append(_snapshot(vc.state))
+    assert vc.membership_size == 393 and not shots[-1]["alive"][leaver]
+    return shots, _dense_counts(vc)
+
+
+def cluster_pending_joiner(vcm):
+    """No cell's: a joiner whose gatekeepers nobody hears stays pending while
+    five members crash: the cut that commits does not admit it, and its column
+    keeps the gatekeepers in both lanes (it sits in the LAST slot, which the
+    compaction's filler entries name, so the repair does write -1 there and
+    the commit's select puts the gatekeepers back). Healed, the next cut
+    admits it."""
+    vc = vcm.VirtualCluster.create(400, n_slots=440, seed=50, **CLUSTER)
+    vc.assign_cohorts_roundrobin()
+    joiner, shots = 439, [_snapshot(vc.state)]
+    vc.inject_join_wave([joiner])
+    gatekeepers = np.unique(np.asarray(vc.state.inval_obs)[:, joiner])
+    assert (gatekeepers >= 0).all()
+    standing = np.setdiff1d(np.arange(400), np.append(_observed_by(vc, gatekeepers), gatekeepers))
+    victims = np.random.default_rng(53).choice(standing, size=5, replace=False)
+    _unheard(vc, gatekeepers)
+    vc.crash(victims)
+    vc.run_until_membership(395, max_steps=192, max_cuts=1, min_cuts=1)
+    shots.append(_snapshot(vc.state))
+    assert vc.membership_size == 395 and shots[-1]["join_pending"][joiner]
+    for lane in ("obs_idx", "inval_obs"):
+        assert np.isin(shots[-1][lane][:, joiner], gatekeepers).all()
+    _unheard(vc, [])
+    vc.run_until_membership(396, max_steps=192, max_cuts=1, min_cuts=1)
+    shots.append(_snapshot(vc.state))
+    assert vc.membership_size == 396 and shots[-1]["alive"][joiner]
+    return shots, _dense_counts(vc)
 
 
 def stream_step(vcm):
@@ -191,6 +282,8 @@ def mesh_cluster(vcm, mesh=None):
 DRIVERS = {
     "cluster_wave": cluster_wave,
     "cluster_wave_overflow": cluster_wave_overflow,
+    "cluster_leaver": cluster_leaver,
+    "cluster_pending_joiner": cluster_pending_joiner,
     "stream_step": stream_step,
     "fleet_wave": fleet_wave,
     "fleet_decision": fleet_decision,
@@ -278,6 +371,7 @@ def drive() -> None:
             "bucket": bucket,
             "same_commits": len(ours[0]) == len(theirs[0]) and commits == _commits(theirs[0]),
             "lane_holds": all(_lane_holds(shot) for shot in (*ours[0], *theirs[0])),
+            "table_holds": all(_table_holds(shot) for shot in (*ours[0], *theirs[0])),
             "leaves_that_differ": sorted({
                 field for left, right in zip(ours[0], theirs[0]) for field in left
                 if left[field].dtype != right[field].dtype
@@ -310,6 +404,9 @@ def test_a_driver_keeps_the_lane_exact_and_its_state_the_dense_arms(drives, driv
     # the update and under the gather alike, and every lane of the state is
     # the one the dense arm gives
     assert record["lane_holds"]
+    # what the repair of the observer table stands on: inval_obs is the
+    # walk's table wherever no joiner is pending
+    assert record["table_holds"]
     assert record["leaves_that_differ"] == []
     # which arm ran, by the telemetry plane: the dense-only programs (and a
     # mesh's) count every commit ...

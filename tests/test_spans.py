@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from rapid_tpu.ops.rings import view_change_bucket
 from rapid_tpu.utils import profiling
 from rapid_tpu.utils.dispatch import ENGINE_DISPATCH_PHASES, ENGINE_SCOPES, scope
 
@@ -345,10 +346,11 @@ def _equations_placed(jaxpr, path=""):
 
 def _view_change_facts(jaxpr, k, n):
     """Of the equations traced under ``view_change``: the paths of the
-    conditionals, and of the gathers that look a bool up for every position
-    of every ring (``alive[ring_perm]``: ``[..., n]`` bools, ``k * n`` or
-    more of them; the update's own look-ups are a bucket long)."""
-    conditionals, gathers = [], []
+    conditionals, of the gathers that look a bool up for every position of
+    every ring (``alive[ring_perm]``: ``[..., n]`` bools, ``k * n`` or more of
+    them; the update's own look-ups are a bucket long), and ``(path, updates'
+    shape)`` of the scatters."""
+    conditionals, gathers, scatters = [], [], []
     for path, eqn in _equations_placed(jaxpr):
         if "view_change" not in _scopes({path}):
             continue
@@ -358,12 +360,17 @@ def _view_change_facts(jaxpr, k, n):
         if (eqn.primitive.name == "gather" and out.dtype == jnp.bool_
                 and out.shape[-1] == n and out.size >= k * n):
             gathers.append(path)
-    return conditionals, gathers
+        if eqn.primitive.name == "scatter":
+            scatters.append((path, eqn.invars[2].aval.shape))
+    return conditionals, gathers, scatters
 
 
-@pytest.mark.parametrize("program", [
+_ONE_DEVICE_PROGRAMS = [
     name + suffix for name in (*CLUSTER_VERBS.values(), *FLEET_VERBS.values())
-    for suffix in LEVELS])
+    for suffix in LEVELS]
+
+
+@pytest.mark.parametrize("program", _ONE_DEVICE_PROGRAMS)
 def test_a_one_device_view_change_gathers_liveness_only_in_its_overflow_arm(lowered, program):
     """Every one-device round program: the view change holds ONE conditional
     of its own (a real ``cond``, under the fleet's ``vmap`` too: named, its
@@ -371,7 +378,7 @@ def test_a_one_device_view_change_gathers_liveness_only_in_its_overflow_arm(lowe
     everybody, always), and the only look-up of a bool for every position of
     every ring, ``alive[ring_perm]``, lies in that conditional's taken arm:
     a cut that fits the bucket gathers nothing of ring length."""
-    conditionals, gathers = _view_change_facts(lowered[JAXPR + program], k=3, n=32)
+    conditionals, gathers, _ = _view_change_facts(lowered[JAXPR + program], k=3, n=32)
     assert len(conditionals) == 1, conditionals
     (gate,) = conditionals
     assert gathers and all(p.startswith(gate + "/cond/branch_1") for p in gathers), gathers
@@ -379,12 +386,40 @@ def test_a_one_device_view_change_gathers_liveness_only_in_its_overflow_arm(lowe
         assert "vmap(view_change)" in gate
 
 
+@pytest.mark.parametrize("program", _ONE_DEVICE_PROGRAMS)
+def test_a_one_device_view_change_scatters_a_ring_table_only_in_its_overflow_arm(lowered, program):
+    """PR 52: the same conditional picks the observer table's form. Its
+    bounded arm scatters by no ``perm``: two updates a bucket long, ``[k, B]``
+    bools into ``ring_alive`` and ``2B`` ring indices a ring (``[k, 2B]`` here) into the
+    table the state holds (the cut's slots and their predecessors); the
+    N-update scatter a ring, the walk's, lies in the taken arm alone (where,
+    under the fleet's named ``vmap``, the bounded form is computed beside it
+    for the tenants whose cut fits). Nothing of the view change scatters
+    outside the conditional."""
+    k, n, bucket = 3, 32, view_change_bucket(32)
+    (gate,), _, scatters = _view_change_facts(lowered[JAXPR + program], k=k, n=n)
+    assert all(path.startswith(gate + "/cond/branch_") for path, _ in scatters), scatters
+    arms = [
+        sorted(shape[-2:] for path, shape in scatters if path.startswith(f"{gate}/cond/branch_{arm}"))
+        for arm in (0, 1)
+    ]
+    # 32 slots: all rings at once; as traced, before the dead-code pass takes
+    # the walk's other two outputs out (tests/test_view_change_tables.py)
+    bounded, by_perm = [(k, bucket), (k, 2 * bucket)], [(k, n)] * 3
+    assert arms[0] == bounded, arms
+    assert arms[1] == sorted(by_perm + (bounded if program.startswith("fleet") else [])), arms
+
+
 @pytest.mark.parametrize("program", ["mesh_run_to_decision", "mesh_fleet_wave"])
 def test_a_mesh_view_change_keeps_the_whole_gather_and_no_conditional(lowered, program):
     # the programs whose node axis (or tenant axis) is sharded trace the
     # dense form alone: the operations they always ran, one more result
-    conditionals, gathers = _view_change_facts(lowered[JAXPR + program], k=3, n=32)
+    conditionals, gathers, scatters = _view_change_facts(lowered[JAXPR + program], k=3, n=32)
     assert conditionals == [] and len(gathers) == 1
+    # and the walk's N-update scatters (as traced: of its three outputs the
+    # compiler keeps one), no update a bucket long: PR 52's repair is the
+    # one-device programs'
+    assert [shape[-2:] for _, shape in scatters] == [(3, 32)] * 3
 
 
 #: First sixteen hex digits of the SHA-256 of ``lowered.as_text()`` at commit
@@ -426,11 +461,18 @@ def test_a_mesh_view_change_keeps_the_whole_gather_and_no_conditional(lowered, p
 #: flips the cut's own positions under a conditional of its own, the whole
 #: gather its other arm; the three mesh programs gather it whole, the
 #: operation their walk always made, and hand it out as one more result.
+#: At PR 52 the two one-device programs were re-taken: the same conditional
+#: now picks the observer table's form too (its bounded arm repairs
+#: ``inval_obs`` at the cut's slots and their predecessors, the walk moved
+#: into its taken arm). The three mesh programs were NOT: ``dense_arms``
+#: traces the rebuild alone and they lower to PR 50's text, as do all ten
+#: programs of the fixture without a one-device view change
+#: (``tools/program_digests.py``).
 #: A PR that means to change one of them replaces its digest with the one
 #: the failure prints.
 PARENT_PROGRAMS = {
-    "run_until_membership": "5af09d9e45e5b7a9",
-    "fleet_run_to_decision": "beaea0df27750918",
+    "run_until_membership": "bb1a55e58f1f5012",
+    "fleet_run_to_decision": "573303abb6d84759",
     "mesh_run_to_decision": "368d054b5d8a823b",
     "mesh_step": "07bc72c6e6905c1c",
     "mesh_fleet_step": "d12c1f4b2ca30c05",

@@ -28,7 +28,7 @@ from rapid_tpu.ops import rings
 from rapid_tpu.parallel.mesh import PARTITION_RULES
 from rapid_tpu.tenancy import fleet as fleetm
 from rapid_tpu.utils import checkpoint
-from tests.test_ops_rings import _primitives
+from tests.test_ops_rings import _equations, _primitives
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -68,21 +68,65 @@ def _assert_same_leaves(ours, theirs, where):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y), err_msg=f"{where}: {field}")
 
 
+def _arm_scatters(compiled):
+    """``[repair's, rebuild's]``: ``(element type, dims)`` of the scatters of a
+    compiled program that lie in either arm of the view change's own
+    conditional (the false arm first), by the op-name path each carries."""
+    arms = [[], []]
+    for line in compiled.splitlines():
+        scatter = re.search(r"= (\w+)\[([\d,]*)\][^=]* scatter\(", line)
+        arm = re.search(r"view_change\)?/cond/branch_(\d)_fun/", line)
+        if scatter and arm:
+            arms[int(arm.group(1))].append(
+                (scatter.group(1), tuple(map(int, scatter.group(2).split(",")))))
+    return [sorted(arm) for arm in arms]
+
+
+def _view_change_gate(jaxpr):
+    """The arms ``(repair, rebuild)`` of the one ``cond`` of a view change's
+    jaxpr, dead code taken out as lowering does."""
+    from jax._src.interpreters import partial_eval as pe
+
+    live, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+    (gate,) = [eqn for eqn in _equations(live) if eqn.primitive.name == "cond"]
+    repair, rebuild = gate.params["branches"]  # the false arm first
+    return repair.jaxpr, rebuild.jaxpr
+
+
 @pytest.mark.parametrize("n", [1000, rings.RING_AT_A_TIME_SLOTS], ids=["batched", "rings_in_turn"])
 def test_the_compiled_view_change_scatters_one_ring_table(n):
+    """Turned round at PR 52: the view change's bounded arm scatters NO ring
+    table by ``perm`` any more. It makes two bounded updates, the cut's
+    positions flipped in ``ring_alive`` (``[K, B]`` bools, PR 50) and the
+    observer table repaired at the cut's slots and their predecessors (``2B``
+    updates of ring indices a ring, under the schedule the ring length picks,
+    where the walk made N); the overflow arm holds what the whole commit held, the lane's
+    gather and the one N-update scatter a ring."""
+    k, bucket = 10, rings.view_change_bucket(n)
     jaxpr, compiled = _view_change_programs(n)
     assert (" while(" in compiled) == (n >= rings.RING_AT_A_TIME_SLOTS)  # the form the length picks
-    # all K rings in one scatter (batched), or one in the loop's body (in turn);
-    # the other scatter is the lane's: the cut's positions flipped in
-    # ``ring_alive`` (PR 50), a scatter of bools and not of ring indices
-    scatters = re.findall(r"= (\w+)\[[\d,]*\][^=]* scatter\(", compiled)
-    assert sorted(scatters) == ["pred", "s32"], scatters
-    # and what jax hands the compiler: the dead half of the walk is gone before
-    # XLA sees it, through the inner jit, the vmap and the lax.map alike
-    found = _live_primitives(jaxpr)
+    repair, rebuild = _arm_scatters(compiled)
+    # a table's K rings in one scatter (batched), or one in the loop's body (in
+    # turn), in either arm; the lane's flip is one scatter at every length
+    one_at_a_time = n >= rings.RING_AT_A_TIME_SLOTS
+    table = ("s32", (n,) if one_at_a_time else (k, n))
+    assert repair == [("pred", (k, n)), table]
+    assert rebuild == [table]
+    # and what jax hands the compiler: the updates' sizes, and the dead half of
+    # the rebuild's walk gone before XLA sees it, through the inner jit, the
+    # vmap and the lax.map alike
     _, pieces = rings.ring_walk_pieces(n)
-    assert found["scatter"] == 2 and found["cummin"] == pieces  # the table's, the lane's
-    assert found["cummax"] == found["sort"] == 0
+    repair, rebuild = _view_change_gate(jaxpr)
+    updates = sorted(
+        eqn.invars[2].aval.shape for eqn in _equations(repair) if eqn.primitive.name == "scatter")
+    assert updates == sorted([(k, bucket), (2 * bucket,) if one_at_a_time else (k, 2 * bucket)]), updates
+    found = Counter(_primitives(repair))
+    assert found["cummin"] == found["cummax"] == pieces  # succ' and the slot at pred', by position
+    assert found["sort"] == 0
+    by_perm = [eqn for eqn in _equations(rebuild) if eqn.primitive.name == "scatter"]
+    assert [eqn.invars[2].aval.shape[-1] for eqn in by_perm] == [n]  # N updates a ring
+    found = Counter(_primitives(rebuild))
+    assert found["cummin"] == pieces and found["cummax"] == found["sort"] == 0
     # the ops still give both tables to a caller that takes both
     both = jax.make_jaxpr(lambda p, a: rings.ring_topology_from_perm(p, a)[:2])(
         jax.ShapeDtypeStruct((10, n), jnp.int32), jax.ShapeDtypeStruct((n,), jnp.bool_))
@@ -90,10 +134,30 @@ def test_the_compiled_view_change_scatters_one_ring_table(n):
     assert found["scatter"] == 2 and found["cummax"] == found["cummin"] == pieces
 
 
+def test_a_dense_only_view_change_scatters_the_one_ring_table_it_did():
+    """``dense_arms=True`` (a mesh's programs, the two unnamed-``vmap`` fleet
+    programs): no conditional, the one N-update scatter a ring and no update
+    of a bucket's size."""
+    n = 1000
+    cfg = EngineConfig(n=n, k=10, h=9, l=4, c=4)
+    keys, ids = jax.ShapeDtypeStruct((cfg.k, n), jnp.uint32), jax.ShapeDtypeStruct((n,), jnp.uint32)
+    mask = jax.ShapeDtypeStruct((n,), jnp.bool_)
+    state = jax.eval_shape(
+        lambda kh, kl, ih, il, a: initial_state(cfg, kh, kl, ih, il, a), keys, keys, ids, ids, mask)
+    traced = jax.jit(
+        lambda s, w: vcm.apply_view_change_impl(cfg, s, w, dense_arms=True)).trace(state, mask)
+    found = _live_primitives(traced.jaxpr.jaxpr)
+    _, pieces = rings.ring_walk_pieces(n)
+    assert found["cond"] == 0 and found["scatter"] == 1
+    assert found["cummin"] == pieces and found["cummax"] == 0
+
+
 @pytest.mark.parametrize("driver", ["cluster", "fleet"])
 def test_the_whole_wave_loop_scatters_one_ring_table(driver):
     """There the view change sits under a ``while`` and a ``cond``, where jax's
-    pass leaves the walk whole and the compiler's own has to take the half out."""
+    pass leaves the walk whole and the compiler's own has to take the half out:
+    of the rebuild's arm, that is (PR 52); the repair's arm reads both halves
+    of the walk's scans and scatters one bounded update of ring indices."""
     i32, kw = jnp.int32, dict(n_slots=32, k=3, cohorts=2, delivery_spread=1)
     if driver == "cluster":
         vc = vcm.VirtualCluster.create(28, h=3, l=1, fd_threshold=2, **kw)
@@ -104,10 +168,14 @@ def test_the_whole_wave_loop_scatters_one_ring_table(driver):
         traced = fleetm._FLEET_PROGRAMS["wave"][0].trace(
             fleet.cfg, fleet.state, fleet.faults, fleet.knobs,
             jnp.full((2,), 28, i32), i32(16), 4, jnp.ones((2,), i32))
-    commit = [line for line in traced.lower().compile().as_text().splitlines()
-              if "view_change" in line]
-    tables = [line for line in commit if " scatter(" in line and "= pred[" not in line]
-    assert len(tables) == 1, driver  # (the pred scatter beside it is ``ring_alive``'s update)
+    repair, rebuild = _arm_scatters(traced.lower().compile().as_text())
+    # one table in each arm: the repair's bounded update, the rebuild's by
+    # ``perm`` (the pred scatter is ``ring_alive``'s update); under the fleet's
+    # named ``vmap`` the taken arm computes both forms and selects a tenant at
+    # a time (``utils/dispatch.cond_across``)
+    assert [kind for kind, _ in repair] == ["pred", "s32"]
+    assert [kind for kind, _ in rebuild] == (
+        ["s32"] if driver == "cluster" else ["pred", "s32", "s32"])
 
 
 def test_the_state_names_no_predecessor_table():
@@ -123,13 +191,15 @@ def test_the_state_names_no_predecessor_table():
 def _step_that_sorts(cfg, state, faults):
     """``engine_step_impl`` with the view change's rings from
     ``ring_topology``, the argsort over (dead, key): the oracle the sort-free
-    walk is held to, here through the whole round."""
+    walk, and since PR 52 the repair of the table the state holds, is held
+    to, here through the whole round. ``dense_arms``: the twin rebuilds at
+    every commit, which is where the engine calls the walk by that name."""
 
     def by_sorting(_perm, alive, _ring_alive):
         return rings.ring_topology(state.key_hi, state.key_lo, alive)
 
     with mock.patch.object(vcm, "ring_topology_from_perm", by_sorting):
-        return vcm.engine_step_impl(cfg, state, faults)
+        return vcm.engine_step_impl(cfg, state, faults, dense_arms=True)
 
 
 _ORACLE_STEP = jax.jit(_step_that_sorts, static_argnums=(0,))  # donate-ok: the twin's state is compared afterwards
