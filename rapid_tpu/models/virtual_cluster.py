@@ -17,7 +17,6 @@ reduction here is a sum/any over N, which XLA lowers to psum over ICI.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
@@ -1553,6 +1552,7 @@ class VirtualCluster(DispatchSeam):
     """
 
     def __init__(self, cfg: EngineConfig, state: EngineState, mesh=None):
+        DispatchSeam.__init__(self)
         self.cfg = cfg
         self.mesh = mesh
         self.state = state if mesh is None else _mesh_lib().adopt(state, mesh)
@@ -1990,6 +1990,7 @@ class VirtualCluster(DispatchSeam):
         )
         if tail:
             observation = jnp.concatenate([jnp.ravel(observation), *tail])
+        self._wait_begins()
         fetched = np.asarray(observation).reshape(-1)
         self._account_d2h(fetched.nbytes)
         if self.paths is not None:
@@ -2071,8 +2072,10 @@ class VirtualCluster(DispatchSeam):
             # bools, not the whole [n] state.
             with self._dispatch("inject_join_admit"):
                 idx = self._slot_index(slots)
-                bad = np.asarray((state.alive | state.join_pending | state.retired)[idx])
-            self._account_d2h(bad.nbytes)
+                bad = (state.alive | state.join_pending | state.retired)[idx]
+                self._wait_begins()
+                bad = np.asarray(bad)
+                self._account_d2h(bad.nbytes)
             if bad.any():
                 raise ValueError(
                     f"slots not admissible as joiners (member/pending/retired): "
@@ -2287,10 +2290,10 @@ class VirtualCluster(DispatchSeam):
         state and return a cheap checksum (``sync_checksum_impl`` — one
         compiled dispatch, audited by the device_program gate)."""
         with self._dispatch("sync"):
-            checksum = int(
-                sync_checksum(self.state, self.faults, *_lane_tail(self.links))
-            )
-        self._account_d2h(4)
+            checksum = sync_checksum(self.state, self.faults, *_lane_tail(self.links))
+            self._wait_begins()
+            checksum = int(checksum)
+            self._account_d2h(4)
         self._refresh_activity()
         return checksum
 
@@ -2372,6 +2375,7 @@ class VirtualCluster(DispatchSeam):
                 was_decided = bool(packed >> 8)
                 members = int(self.state.n_members)
                 self._account_d2h(4)
+            self._rounds = rounds
         self.metrics.inc("engine_convergence_steps", rounds)
         if was_decided:
             self.metrics.inc("engine_cuts_committed")
@@ -2406,21 +2410,11 @@ class VirtualCluster(DispatchSeam):
                     [jnp.stack([steps, cuts, resolved.astype(jnp.int32)]), sizes]
                 )
             )
+            rounds = self._rounds = int(obs[0])
         n_cuts = int(obs[1])
-        self.metrics.inc("engine_convergence_steps", int(obs[0]))
+        self.metrics.inc("engine_convergence_steps", rounds)
         self.metrics.inc("engine_cuts_committed", n_cuts)
-        return int(obs[0]), n_cuts, bool(obs[2]), tuple(obs[3 : 3 + n_cuts].tolist())
-
-    def timed_convergence(self, max_steps: int = 64) -> Tuple[int, float]:
-        """(rounds, wall_ms) for a convergence run, excluding compilation
-        (callers should run one throwaway convergence first to warm the
-        cache)."""
-        start = time.perf_counter()
-        rounds, events = self.run_until_converged(max_steps)
-        jax.block_until_ready(self.state.alive)
-        elapsed_ms = (time.perf_counter() - start) * 1000.0
-        assert events is not None, "did not converge"
-        return rounds, elapsed_ms
+        return rounds, n_cuts, bool(obs[2]), tuple(obs[3 : 3 + n_cuts].tolist())
 
     # -- observers ------------------------------------------------------
 

@@ -60,7 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from rapid_tpu.sim.faults import FaultEvent
-from rapid_tpu.utils.histogram import LogHistogram
 
 #: The subset of the sim fault vocabulary the streaming pipeline carries:
 #: membership churn. Environment faults (loss, delay, partitions) ride the
@@ -362,18 +361,18 @@ class StreamDriver:
         else:
             with target._dispatch("stream_fetch"):
                 state = target.state
+                occupied = state.alive | state.join_pending | state.retired
+                target._wait_begins()
                 # np.array, not asarray: the mirror is mutated per wave and
                 # a jax export can surface as a read-only view.
-                self._inadmissible = np.array(  # host-sync-ok: one pre-stream lifecycle snapshot
-                    state.alive | state.join_pending | state.retired
-                )
-            target._account_d2h(int(self._inadmissible.nbytes))
-        #: (wave index, submit perf_counter, device-resident ticket).
-        self._pending: Deque[Tuple[int, float, object]] = deque()
+                self._inadmissible = np.array(occupied)  # host-sync-ok: one pre-stream lifecycle snapshot
+                target._account_d2h(int(self._inadmissible.nbytes))
+        #: (wave index, submit clock reading, device-resident ticket, the
+        #: wave's membership-change id in the dispatch journal).
+        self._pending: Deque[Tuple[int, float, object, int]] = deque()
         self.waves_submitted = 0
         self.waves_completed = 0
         self._cuts_reported = 0  # already inc'd into engine_stream_cuts
-        self._latency = LogHistogram()
         self._t0_stream: Optional[float] = None
         self._last_result: Optional[StreamResult] = None
         # Baselines for the drain-time deltas (epoch fetch is the one
@@ -422,14 +421,22 @@ class StreamDriver:
             # records this wave must wait behind.
             self._wave_queue_depth.append(len(self._pending))
         t_submit = self._clock()
-        self._apply(wave)
-        events = None
-        for _ in range(self.rounds_per_wave):
-            events = self.target.stream_step(wave=self.waves_submitted)
+        # A wave IS a membership change: its injections and its enqueues
+        # carry the id, and its retirement closes it (_record_completion).
+        change = self.target._open_change()
+        try:
+            with self.target._serving(change):
+                self._apply(wave)
+                events = None
+                for _ in range(self.rounds_per_wave):
+                    events = self.target.stream_step(wave=self.waves_submitted)
+        except BaseException:
+            self.target._forget_change(change)  # a wave that was never queued
+            raise
         # The last round's decided flag is the wave's ticket: a fresh
         # output buffer (never donated away by later rounds), ready exactly
         # when every dispatch of this wave has executed.
-        self._pending.append((self.waves_submitted, t_submit, events.decided))
+        self._pending.append((self.waves_submitted, t_submit, events.decided, change))
         self.waves_submitted += 1
         self.target.metrics.inc("engine_stream_waves")
 
@@ -477,9 +484,7 @@ class StreamDriver:
             view_changes_per_sec=(
                 cuts / (wall_ms / 1000.0) if wall_ms > 0 else 0.0
             ),
-            p99_alert_to_commit_ms=(
-                float(self._latency.quantile(0.99)) if self._latency.count else None
-            ),
+            p99_alert_to_commit_ms=self._p99_alert_to_commit_ms(),
             overlap_efficiency=overlap,
             fetch_blocked_ms=fetch_blocked_ms,
             h2d_bytes=int(counters.get("engine_h2d_bytes", 0)) - self._h2d0,
@@ -533,13 +538,15 @@ class StreamDriver:
         names WHY the host is blocking (backpressure inside ``submit``, the
         ``drain`` sweep) for the injected deadline waiter; the telemetry
         phase stays ``stream_fetch`` either way."""
-        idx, t_submit, ticket = self._pending.popleft()
-        with self.target._dispatch("stream_fetch", wave=idx):
+        idx, t_submit, ticket, change = self._pending.popleft()
+        # the fetch that retires a wave is the wave's
+        with self.target._serving(change), self.target._dispatch("stream_fetch", wave=idx):
+            self.target._wait_begins()
             if self._ticket_wait is not None:
                 self._ticket_wait(budget_phase, idx, ticket)
             else:
                 jax.block_until_ready(ticket)  # host-sync-ok: the explicit fetch boundary
-        self._record_completion(t_submit)
+        self._record_completion(t_submit, change)
 
     def _reap_ready(self) -> None:
         """Retire already-completed waves without blocking (is_ready probe,
@@ -551,14 +558,18 @@ class StreamDriver:
             if self._ticket_ready is not None
             else _ticket_ready(self._pending[0][2])
         ):
-            _idx, t_submit, _ticket = self._pending.popleft()
-            self._record_completion(t_submit)
+            _idx, t_submit, _ticket, change = self._pending.popleft()
+            self._record_completion(t_submit, change)
 
-    def _record_completion(self, t_submit: float) -> None:
+    def _record_completion(self, t_submit: float, change: int) -> None:
         latency_ms = (self._clock() - t_submit) * 1000.0
-        self._latency.observe(latency_ms)
         self.target.metrics.record_ms("engine_stream_alert_to_commit", latency_ms)
+        self.target._close_change(change)
         self.waves_completed += 1
+
+    def _p99_alert_to_commit_ms(self) -> Optional[float]:
+        hist = self.target.metrics.timings.get("engine_stream_alert_to_commit")
+        return float(hist.quantile(0.99)) if hist is not None and hist.count else None
 
     def _trace_summaries(self) -> List[dict]:
         """The target's cached decoded ring summaries, one per lane (the
@@ -644,16 +655,18 @@ class StreamDriver:
         inside the fetch."""
         with self.target._dispatch("stream_fetch"):
             epoch = self.target.state.config_epoch
-            if self._ticket_wait is not None:
-                self._ticket_wait("stream_fetch", self.waves_submitted, epoch)
             quarantined = getattr(self.target, "quarantined", ())
             if quarantined:
                 serving = np.ones(epoch.shape, dtype=bool)
                 serving[list(quarantined)] = False
                 self.target._account_h2d(serving)
                 epoch = jnp.where(jnp.asarray(serving), epoch, 0)
-            total = int(jnp.sum(epoch))  # host-sync-ok: fetch boundary
-        self.target._account_d2h(4)
+            total = jnp.sum(epoch)
+            self.target._wait_begins()
+            if self._ticket_wait is not None:
+                self._ticket_wait("stream_fetch", self.waves_submitted, total)
+            total = int(total)  # host-sync-ok: fetch boundary
+            self.target._account_d2h(4)
         return total
 
     # -- observability --------------------------------------------------
@@ -684,8 +697,8 @@ class StreamDriver:
                 else None
             ),
             "p99_alert_to_commit_ms": (
-                round(float(self._latency.quantile(0.99)), 3)
-                if self._latency.count
+                round(p99, 3)
+                if (p99 := self._p99_alert_to_commit_ms()) is not None
                 else None
             ),
             # Ring-derived decomposition, present only on trace>0 targets

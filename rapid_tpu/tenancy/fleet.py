@@ -940,6 +940,7 @@ class TenantFleet(DispatchSeam):
                     f"fleet pytrees must share the leading tenant axis "
                     f"({b}); got a leaf of shape {leaf.shape}"
                 )
+        DispatchSeam.__init__(self)
         self.cfg = cfg
         self.state = state
         self.faults = faults
@@ -1267,9 +1268,11 @@ class TenantFleet(DispatchSeam):
         set lane's ``probes_lost[t]`` rides the same transfer, 4·t bytes
         more, and ``engine_link_probes_lost`` gets what the tenants' lanes
         lost since their last fetch."""
-        fetched = np.asarray(jnp.concatenate(
+        packed = jnp.concatenate(
             [*parts, *_lane_tail(None if self.links is None else self.links.probes_lost)]
-        ))
+        )
+        self._wait_begins()
+        fetched = np.asarray(packed)
         self._account_d2h(fetched.nbytes)
         if self.links is not None:
             fetched, lost = fetched[: -self.b], fetched[-self.b:].astype(np.int64)
@@ -1297,8 +1300,10 @@ class TenantFleet(DispatchSeam):
         if check_admissible:
             with self._dispatch("inject_join_admit"):
                 idx = self._pair_index(pairs)
-                bad = np.asarray(fleet_join_admit(self.state, idx))
-            self._account_d2h(bad.nbytes)
+                bad = fleet_join_admit(self.state, idx)
+                self._wait_begins()
+                bad = np.asarray(bad)
+                self._account_d2h(bad.nbytes)
             if bad.any():
                 raise ValueError(
                     f"(tenant, slot) pairs not admissible as joiners "
@@ -1325,6 +1330,7 @@ class TenantFleet(DispatchSeam):
             obs = self._fetch(
                 steps, decided.astype(jnp.int32), self.state.n_members, arm_rounds
             )
+            self._rounds = int(obs[: self.b].max())  # lockstep: the slowest tenant's
         self._refresh_gate_rounds()
         rounds, was_decided, members, arm_rounds = np.split(
             obs, [self.b, 2 * self.b, 3 * self.b]
@@ -1386,6 +1392,8 @@ class TenantFleet(DispatchSeam):
             obs = self._fetch(
                 steps, cuts, resolved.astype(jnp.int32), sizes.reshape(-1), loop_rounds
             )
+            # the loop's own count of the lockstep rounds it ran
+            self._rounds = int(obs[-len(WAVE_LOOP_COUNTERS)])
         self._refresh_gate_rounds()
         b = self.b
         rounds, n_cuts, resolved_h, sizes_h, loop_rounds = np.split(
@@ -1493,8 +1501,10 @@ class TenantFleet(DispatchSeam):
         violated). Cheap enough to run between waves — the supervisor's
         poisoned-tenant tripwire."""
         with self._dispatch("health_scan"):
-            ok = np.asarray(tenant_health(self.cfg, self.state))
-        self._account_d2h(ok.nbytes)
+            ok = tenant_health(self.cfg, self.state)
+            self._wait_begins()
+            ok = np.asarray(ok)
+            self._account_d2h(ok.nbytes)
         self._refresh_activity()
         return ~ok
 

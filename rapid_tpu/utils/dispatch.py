@@ -35,6 +35,20 @@ Set-up has the same treatment. The constructors' work runs inside
 setup_snapshot()`` adds up; and a dispatch phase or a stage, while open, is the
 span under which ``engine_telemetry``'s collector files every trace, lowering,
 compile and cache load that happens (``compile_snapshot()["by_span"]``).
+
+The dispatch journal keeps the single calls the histograms add up. A closing
+``_dispatch`` block writes one row (``engine_telemetry.DISPATCH_RECORD``): its
+start, the moment its wait for the device began (:meth:`DispatchSeam.
+_wait_begins`, stamped at the one line where a fetching phase starts to wait;
+an enqueue-only phase has none) and its end, under the id of the membership
+change it serves. A driver's first dispatch from :data:`INJECTING_PHASES`
+after its last from :data:`DECIDING_PHASES` opens a change, and every
+dispatch up to and including the next deciding one carries its id; a dispatch
+outside carries 0. Under a ``StreamDriver`` a wave is the change: ``submit``
+opens it and the wave's retirement closes it, so the driver's own injections
+open nothing. A closed change is one row too (``CHANGE_RECORD``) and one
+sample of ``engine_change_ms``; ``engine_telemetry.journal_snapshot()`` reads
+both rings.
 """
 
 from __future__ import annotations
@@ -85,6 +99,43 @@ ENGINE_DISPATCH_PHASES = frozenset({
     # enqueued without a fetch.
     "inject_partition",
 })
+
+
+def _registered_phases(name: str, phases) -> frozenset:
+    """``phases`` as a subset of :data:`ENGINE_DISPATCH_PHASES`: a name the
+    vocabulary does not hold fails the import, as a typo'd phase fails its
+    ``_dispatch``."""
+    unknown = sorted(set(phases) - ENGINE_DISPATCH_PHASES)
+    if unknown:
+        raise ValueError(
+            f"{name} names unregistered engine dispatch phases {unknown}; "
+            f"add them to rapid_tpu.utils.dispatch.ENGINE_DISPATCH_PHASES"
+        )
+    return frozenset(phases)
+
+
+#: The phases that hand the engine a fault or a membership request: a
+#: driver's first of these after its last deciding dispatch opens a
+#: membership change in the journal.
+INJECTING_PHASES = _registered_phases("INJECTING_PHASES", {
+    "inject_crash",
+    "inject_join_admit",
+    "inject_join_place",
+    "inject_link_faults",
+    "inject_partition",
+})
+
+#: The phases whose fetch carries a decision: one of these closes the change
+#: that is open on its driver. (A stream has none: its waves close theirs.)
+DECIDING_PHASES = _registered_phases("DECIDING_PHASES", {
+    "run_to_decision",
+    "run_until_membership",
+    "fleet_decision",
+    "fleet_wave",
+})
+
+#: Phase name -> the number the journal's rows store for it.
+_PHASE_IDS = engine_telemetry.journal_phases(sorted(ENGINE_DISPATCH_PHASES))
 
 #: Prefix of a dispatch phase's span on the profiler's clock.
 SPAN_PREFIX = "rapid:"
@@ -202,12 +253,30 @@ def cond_across(axis, pred, taken, skipped, *operands):
     return jax.lax.cond(opened, taken_by_some, skipped, *operands), opened
 
 
+#: ``t_wait`` of a block that never waited.
+_NO_WAIT = float("nan")
+
+
 class DispatchSeam:
     """Mixin: transfer-byte accounting + the phase-validated dispatch timer.
 
     Hosts must provide ``self.metrics`` (a :class:`rapid_tpu.utils.metrics.
-    Metrics` registry); everything here writes through it.
+    Metrics` registry) and ``self.stream`` (the attached ``StreamDriver`` or
+    None), and call this constructor; everything here writes through the
+    registry and into ``engine_telemetry``'s journal.
     """
+
+    def __init__(self) -> None:
+        #: This driver's id in the journal's rows.
+        self._driver = engine_telemetry.new_driver()
+        #: The membership change the next dispatch serves (0: none).
+        self._change = 0
+        #: Per open change ``[t_open, first seq, last seq, dispatch seconds]``:
+        #: one entry for a batch driver, up to ``depth`` under a stream.
+        self._open_changes: dict = {}
+        # The open block's wait mark, reported rounds and fetched bytes.
+        self._t_wait = _NO_WAIT
+        self._rounds = self._fetched = 0
 
     def _account_h2d(self, *arrays) -> None:
         """Charge host->device uploads (indices, masks, initial state) to
@@ -221,6 +290,7 @@ class DispatchSeam:
 
     def _account_d2h(self, nbytes: int) -> None:
         self.metrics.inc("engine_d2h_bytes", int(nbytes))
+        self._fetched += int(nbytes)  # the open block's row takes it
 
     @contextmanager
     def _dispatch(self, entry: str, **tags):
@@ -235,26 +305,100 @@ class DispatchSeam:
         The same block is the span ``rapid:<entry>`` on the profiler's
         clock, tagged ``seq`` (this driver's operation count, so the spans
         of one commit or one wave read in order and nesting on the thread
-        gives the parent) and the caller's ``tags`` (the stream's
-        ``wave=<index>``). With no trace running the span is a flag test.
-        While the block is open it is also the span that a compile inside it
-        is filed under (``compile_snapshot()["by_span"][entry]``)."""
-        if entry not in ENGINE_DISPATCH_PHASES:
+        gives the parent), ``change`` (the membership change it serves, 0
+        for none: the spans of one view change share it) and the caller's
+        ``tags`` (the stream's ``wave=<index>``). With no trace running the
+        span is a flag test. While the block is open it is also the span
+        that a compile inside it is filed under
+        (``compile_snapshot()["by_span"][entry]``), and when it closes it is
+        one row of the journal, on the two clock reads that feed the
+        histogram."""
+        phase_id = _PHASE_IDS.get(entry)
+        if phase_id is None:
             raise ValueError(
                 f"unregistered engine dispatch phase {entry!r}; add it to "
                 f"rapid_tpu.utils.dispatch.ENGINE_DISPATCH_PHASES"
             )
-        self.metrics.inc("engine_dispatches")
-        seq = self.metrics.counters["engine_dispatches"]
+        metrics = self.metrics
+        metrics.inc("engine_dispatches")
+        seq = metrics.counters["engine_dispatches"]
+        # The open block's wait mark, reported rounds and fetched bytes
+        # (blocks do not nest: one set of marks a driver).
+        self._t_wait = _NO_WAIT
+        self._rounds = self._fetched = 0
         start = time.perf_counter()
-        with annotate(SPAN_PREFIX + entry, seq=seq, **tags):
+        change = self._change
+        if not change and self.stream is None and entry in INJECTING_PHASES:
+            change = self._change = self._open_change(start)
+        with annotate(SPAN_PREFIX + entry, seq=seq, change=change, **tags):
             depth = engine_telemetry.push_span(entry)
             try:
                 yield
             finally:
-                engine_telemetry.pop_span(depth)
-                self.metrics.record_ms(
-                    "engine_dispatch",
-                    (time.perf_counter() - start) * 1000.0,
-                    phase=entry,
+                compiles, gc_s = engine_telemetry.pop_span(depth)
+                end = time.perf_counter()
+                timed = metrics.record_ms(
+                    "engine_dispatch", (end - start) * 1000.0, phase=entry
                 )
+                engine_telemetry.record_dispatch(
+                    phase_id, self._driver, seq, change, start, self._t_wait, end,
+                    compiles, gc_s, self._fetched, self._rounds, timed.sum,
+                )
+                if change:
+                    opened = self._open_changes.get(change)
+                    if opened is not None:
+                        opened[1] = opened[1] or seq
+                        opened[2] = seq
+                        opened[3] += end - start
+                        if entry in DECIDING_PHASES:
+                            self._close_change(change, end)
+
+    def _wait_begins(self) -> None:
+        """Stamp the open block: the host starts to wait for the device on
+        the next line. Before the stamp the block is the host's own work
+        (the jitted call, any eager packing), after it the wait; a block
+        that only enqueues is never stamped."""
+        self._t_wait = time.perf_counter()
+
+    def _open_change(self, t_open=None) -> int:
+        """A membership change opens on this driver (a batch driver's first
+        injection, a stream's ``submit``); returns its id, which the
+        dispatches carry while ``self._change`` holds it."""
+        change = engine_telemetry.new_change()
+        self._open_changes[change] = [
+            time.perf_counter() if t_open is None else t_open, 0, 0, 0.0,
+        ]
+        return change
+
+    @contextmanager
+    def _serving(self, change: int):
+        """The dispatches inside the block serve ``change``: how a stream,
+        which holds several changes open at once, names the wave it is
+        enqueueing or retiring."""
+        outer, self._change = self._change, change
+        try:
+            yield
+        finally:
+            self._change = outer
+
+    def _forget_change(self, change: int) -> None:
+        """An opened change that never came to be (a wave whose injection
+        raised): no row, no sample."""
+        self._open_changes.pop(change, None)
+
+    def _close_change(self, change: int, t_close=None) -> None:
+        """The change is decided (a deciding dispatch's end, a stream wave's
+        retirement): one row of the journal's changes and one sample of
+        ``engine_change_ms``, the time it was pending."""
+        if self._change == change:
+            self._change = 0
+        opened = self._open_changes.pop(change, None)
+        if opened is None:
+            return
+        t_open, seq_first, seq_last, dispatch_s = opened
+        if t_close is None:
+            t_close = time.perf_counter()
+        engine_telemetry.record_change(
+            change, self._driver, t_open, t_close, seq_first, seq_last, dispatch_s
+        )
+        self.metrics.record_ms("engine_change", (t_close - t_open) * 1000.0)

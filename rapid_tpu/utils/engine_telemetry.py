@@ -19,6 +19,13 @@ to the program that caused it and to the program span that was open
 ``setup_snapshot()`` holds the wall seconds of those stages. (``CompileDelta``
 diffs two snapshots around a block, for the bench.)
 
+Beside the span stack the collector keeps the dispatch journal: one row a
+closed ``_dispatch`` block and one a closed membership change, in two
+preallocated rings that overwrite (:data:`DISPATCH_RECORD`,
+:data:`CHANGE_RECORD`, ``journal_snapshot()``). The histograms are the sums;
+the journal keeps the single calls, each with what the process was doing
+while it was open.
+
 Everything degrades gracefully: a JAX build without ``jax.monitoring`` (or
 without ``memory_stats``/``live_arrays``) yields zero counters / ``None``
 gauges, never an exception — telemetry must not be able to take down the
@@ -27,10 +34,16 @@ engine it observes.
 
 from __future__ import annotations
 
+import array
 import collections
+import gc
+import itertools
 import logging
 import threading
-from typing import Any, Dict, List, Optional
+import time
+from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
 
 from rapid_tpu.utils.histogram import LogHistogram
 
@@ -84,6 +97,75 @@ OUTERMOST = "outermost"
 
 _STAGE_FIELDS = ("trace_s", "lower_s", "load_s")
 
+#: Rows each ring of the dispatch journal holds before it overwrites its
+#: oldest (the busiest cell makes about 450 dispatches a second).
+JOURNAL_CAPACITY = 65_536
+
+#: One closed ``_dispatch`` block. Times are ``time.perf_counter()`` seconds.
+DISPATCH_RECORD = np.dtype([
+    ("phase", np.uint8),      # index into ``journal_snapshot()["phases"]``
+    ("driver", np.uint32),    # the driver's id (``new_driver``)
+    ("seq", np.int64),        # the driver's operation count: the span's ``seq``
+    ("change", np.int64),     # the membership change it served, 0 for none
+    ("t_start", np.float64),  # the block opened
+    ("t_wait", np.float64),   # the host began to wait for the device; NaN: it never did
+    ("t_end", np.float64),    # the block closed
+    ("compiles", np.uint32),  # programs loaded (compiled or read from the cache) meanwhile
+    ("gc_s", np.float64),     # seconds of garbage collection meanwhile
+    ("bytes", np.int64),      # device->host bytes it was charged
+    ("rounds", np.int32),     # engine rounds its observation reported, 0 for none
+    ("cum_ms", np.float64),   # the phase's ``engine_dispatch`` sum of this driver after it
+])
+
+#: One closed membership change (``utils/dispatch.py`` says what opens and
+#: closes one).
+CHANGE_RECORD = np.dtype([
+    ("change", np.int64),
+    ("driver", np.uint32),
+    ("t_open", np.float64),
+    ("t_close", np.float64),
+    ("seq_first", np.int64),   # ``seq`` of its first and last dispatch,
+    ("seq_last", np.int64),    # 0 where it had none
+    ("dispatch_s", np.float64),  # the sum of its dispatches' durations
+])
+
+
+#: ``array`` type codes of the record types' fields, by numpy type string.
+_ARRAY_CODES = {"u1": "B", "u4": "I", "i4": "i", "i8": "q", "f8": "d"}
+
+
+class _Ring:
+    """``capacity`` preallocated rows of one record type, overwritten oldest
+    first: one ``array.array`` a field (parallel arrays, so a row is scalar
+    stores at one index and no Python object is kept for it). A writer claims
+    its row with ``next(claims)``, which the interpreter makes atomic, so the
+    hot path takes no lock; ``written`` counts the rows put. (``record_dispatch``
+    stores into :attr:`columns` itself, unrolled: it runs in every driver call.)"""
+
+    def __init__(self, dtype: np.dtype, capacity: int) -> None:
+        self.dtype, self.capacity, self.written = dtype, capacity, 0
+        self.claims = itertools.count()
+        self.columns = tuple(
+            array.array(code, bytes(capacity * array.array(code).itemsize))
+            for code in (_ARRAY_CODES[dtype[name].str[1:]] for name in dtype.names)
+        )
+
+    def put(self, *fields) -> None:
+        claimed = next(self.claims)
+        i = claimed % self.capacity
+        for column, value in zip(self.columns, fields):
+            column[i] = value
+        self.written = claimed + 1
+
+    def ordered(self) -> np.ndarray:
+        """A copy of the rows held as one structured array, oldest first."""
+        held = min(self.written, self.capacity)
+        oldest = self.written % self.capacity if self.written > self.capacity else 0
+        out = np.empty(held, dtype=self.dtype)
+        for name, column in zip(self.dtype.names, self.columns):
+            out[name] = np.roll(np.frombuffer(column, dtype=self.dtype[name]), -oldest)[:held]
+        return out
+
 
 def _program_of(fun_name: Any) -> str:
     """One key for a program's three events: the trace event names the
@@ -123,9 +205,19 @@ class _CompileCollector:
         self.by_program: Dict[str, Dict[str, float]] = {}
         self.recent: collections.deque = collections.deque(maxlen=RECENT_LOADS)
         self.spans: List[str] = []
+        #: Per open span, what the process had done when it opened:
+        #: ``(compiles, gc_s)``; :meth:`pop_span` hands back the differences.
+        self._span_marks: List[Tuple[int, float]] = []
         self.setup: Dict[str, Dict[str, float]] = {
             OUTERMOST: {"count": 0, "wall_s": 0.0}
         }
+        self.gc_s = 0.0
+        self._gc_t0 = 0.0
+        self.dispatches = _Ring(DISPATCH_RECORD, JOURNAL_CAPACITY)
+        self.changes = _Ring(CHANGE_RECORD, JOURNAL_CAPACITY)
+        self.phases: Tuple[str, ...] = ()
+        self._driver_ids = itertools.count(1)
+        self._change_ids = itertools.count(1)
 
     # -- listeners -------------------------------------------------------
 
@@ -245,10 +337,26 @@ class _CompileCollector:
         """Open a program span (a dispatch phase, ``setup.<stage>``); returns
         what :meth:`pop_span` takes to close it and everything above it."""
         self.spans.append(name)
+        self._span_marks.append((self.compiles, self.gc_s))
         return len(self.spans) - 1
 
-    def pop_span(self, depth: int) -> None:
+    def pop_span(self, depth: int) -> Tuple[int, float]:
+        """Close the span at ``depth`` and everything above it; returns the
+        programs loaded and the seconds of garbage collection while it was
+        open (the journal's evidence, free to have)."""
+        compiles, gc_s = self._span_marks[depth]
         del self.spans[depth:]
+        del self._span_marks[depth:]
+        return self.compiles - compiles, self.gc_s - gc_s
+
+    def on_gc(self, phase: str, _info: Dict[str, Any]) -> None:
+        """The ``gc.callbacks`` hook: adds up the seconds of the collections
+        that start while a span is open, and does nothing while none is."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter() if self.spans else 0.0
+        elif self._gc_t0:
+            self.gc_s += time.perf_counter() - self._gc_t0
+            self._gc_t0 = 0.0
 
     def close_stage(self, stage: str, depth: int, wall_s: float) -> None:
         """A set-up stage block ends: its span goes (:meth:`pop_span`) and its
@@ -261,6 +369,58 @@ class _CompileCollector:
                 row = self.setup.setdefault(name, {"count": 0, "wall_s": 0.0})
                 row["count"] += 1
                 row["wall_s"] += wall_s
+
+    # -- the dispatch journal ------------------------------------------------
+
+    def new_driver(self) -> int:
+        """A driver's id in the journal, from 1 in order of construction."""
+        return next(self._driver_ids)
+
+    def new_change(self) -> int:
+        """The next membership change's id: from 1, one sequence for the
+        process, so a change is named by its id alone."""
+        return next(self._change_ids)
+
+    def journal_phases(self, names) -> Dict[str, int]:
+        """The phase vocabulary of the journal's rows, registered once by
+        ``utils/dispatch.py``: returns name -> the number a row stores for it
+        (``journal_snapshot()["phases"]`` maps it back)."""
+        self.phases = tuple(names)
+        return {name: i for i, name in enumerate(self.phases)}
+
+    def record_dispatch(
+        self, phase_id, driver, seq, change, t_start, t_wait, t_end,
+        compiles, gc_s, fetched, rounds, cum_ms,
+    ) -> None:
+        """One closed ``_dispatch`` block: :data:`DISPATCH_RECORD`'s fields,
+        in order."""
+        ring = self.dispatches
+        (phase_c, driver_c, seq_c, change_c, t_start_c, t_wait_c, t_end_c,
+         compiles_c, gc_s_c, bytes_c, rounds_c, cum_ms_c) = ring.columns
+        claimed = next(ring.claims)
+        i = claimed % ring.capacity
+        phase_c[i] = phase_id
+        driver_c[i] = driver
+        seq_c[i] = seq
+        change_c[i] = change
+        t_start_c[i] = t_start
+        t_wait_c[i] = t_wait
+        t_end_c[i] = t_end
+        compiles_c[i] = compiles
+        gc_s_c[i] = gc_s
+        bytes_c[i] = fetched
+        rounds_c[i] = rounds
+        cum_ms_c[i] = cum_ms
+        ring.written = claimed + 1
+
+    def journal_snapshot(self) -> Dict[str, Any]:
+        return {
+            "phases": self.phases,
+            "dispatches": self.dispatches.ordered(),
+            "dispatches_written": self.dispatches.written,
+            "changes": self.changes.ordered(),
+            "changes_written": self.changes.written,
+        }
 
     # -- reading -----------------------------------------------------------
 
@@ -317,6 +477,7 @@ def install() -> bool:
         except Exception as exc:  # noqa: BLE001 — without the start scalars
             # nested intervals count twice; the totals still mean something.
             logger.warning("pipeline intervals will not nest: %r", exc)
+        gc.callbacks.append(_COLLECTOR.on_gc)
         _installed = True
         return True
 
@@ -351,11 +512,33 @@ def setup_snapshot() -> Dict[str, Dict[str, float]]:
     return _COLLECTOR.setup_snapshot()
 
 
-#: The span stack and the stage sums, for ``utils/dispatch.py`` (the one
-#: module that opens spans): ``_dispatch`` and ``setup_stage`` push and pop.
+def journal_snapshot() -> Dict[str, Any]:
+    """The dispatch journal as plain arrays, oldest row first:
+
+    - ``dispatches``: the newest :data:`JOURNAL_CAPACITY` closed ``_dispatch``
+      blocks (:data:`DISPATCH_RECORD`), ``phases[row["phase"]]`` their names;
+    - ``changes``: the newest closed membership changes
+      (:data:`CHANGE_RECORD`); ``t_close - t_open`` is the time a change was
+      pending, and that less the union of its dispatches' intervals the host
+      time inside it that no phase covers;
+    - ``dispatches_written`` / ``changes_written``: rows ever written, so a
+      reader knows how many the rings have dropped.
+
+    The arrays are copies; nothing is written to disk."""
+    return _COLLECTOR.journal_snapshot()
+
+
+#: The span stack, the stage sums and the journal's writers, for
+#: ``utils/dispatch.py`` (the one module that opens spans): ``_dispatch`` and
+#: ``setup_stage`` push and pop, ``DispatchSeam`` writes the journal.
 push_span = _COLLECTOR.push_span
 pop_span = _COLLECTOR.pop_span
 close_stage = _COLLECTOR.close_stage
+new_driver = _COLLECTOR.new_driver
+new_change = _COLLECTOR.new_change
+journal_phases = _COLLECTOR.journal_phases
+record_dispatch = _COLLECTOR.record_dispatch
+record_change = _COLLECTOR.changes.put
 
 
 class CompileDelta:

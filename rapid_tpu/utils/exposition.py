@@ -93,6 +93,12 @@ ENGINE_KNOWN_COUNTERS = (
     "engine_edge_mask_reuses",
 )
 
+#: The engine drivers' one histogram of whole membership changes: the time
+#: from a change's first injection (a stream wave's ``submit``) to the end of
+#: the dispatch whose fetch carried its decision (the wave's retirement), as
+#: ``DispatchSeam._close_change`` records it. Zero-filled like the counters.
+ENGINE_CHANGE_TIMER = "engine_change_ms"
+
 #: The cluster driver's counters of the consensus path, in the order of the
 #: ``int32[3]`` the round programs carry them in (``VirtualCluster.paths``):
 #: rounds in which the classic-Paxos attempt ran (the fast round had not
@@ -431,6 +437,10 @@ def prometheus_text(snapshot: Dict[str, Any]) -> str:
     engine_section = snapshot.get("engine")
     if "engine" in snapshot:
         counters.update({name: 0 for name in ENGINE_KNOWN_COUNTERS})
+        # The time a membership change was pending (cluster, fleet and stream
+        # alike; utils/dispatch.py) takes its first sample when the first
+        # change closes: zero-filled until then, the stable-series rule.
+        metrics.setdefault(ENGINE_CHANGE_TIMER, _EMPTY_HISTOGRAM_SUMMARY)
     if isinstance(engine_section, dict) and "tenancy" in engine_section:
         counters.update({name: 0 for name in TENANCY_KNOWN_COUNTERS})
     if isinstance(engine_section, dict) and "recovery" in engine_section:

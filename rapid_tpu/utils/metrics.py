@@ -52,7 +52,9 @@ class Metrics:
     def inc(self, name: str, value: int = 1) -> None:
         self.counters[name] += value
 
-    def record_ms(self, name: str, value_ms: float, phase: Optional[str] = None) -> None:
+    def record_ms(self, name: str, value_ms: float, phase: Optional[str] = None) -> LogHistogram:
+        """Feed one sample; returns the histogram that took it (its ``sum``
+        after the sample is what the dispatch journal keeps)."""
         if phase is None:
             hist = self.timings.get(name)
             if hist is None:
@@ -63,6 +65,7 @@ class Metrics:
             if hist is None:
                 hist = family[phase] = LogHistogram()
         hist.observe(value_ms)
+        return hist
 
     @contextmanager
     def timer(self, name: str):

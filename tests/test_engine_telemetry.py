@@ -48,6 +48,11 @@ GOLDEN_ENGINE_METRIC_NAMES = [
     "rapid_config_sync_unchanged_total",
     "rapid_configuration_id",
     "rapid_decision_missing_joiner_uuid_total",
+    # The time a membership change was pending (ISSUE 54): one histogram,
+    # zero-filled until the first change closes.
+    "rapid_engine_change_ms_bucket",
+    "rapid_engine_change_ms_count",
+    "rapid_engine_change_ms_sum",
     "rapid_engine_compile_cache_requests_total",
     "rapid_engine_compile_ms_bucket",
     "rapid_engine_compile_ms_count",

@@ -631,3 +631,27 @@ def test_a_waves_enqueues_and_the_fetch_that_retires_it_share_a_wave(spans):
     assert any(name == "rapid:stream_fetch" and "wave" not in tags for name, tags in spans["streamed"])
     # the crash before each wave's rounds is a span too
     assert sum(name == "rapid:inject_crash" for name, _ in spans["streamed"]) == 4
+
+
+@pytest.mark.parametrize("drive", ["checked", "unchecked", "streamed"])
+def test_the_spans_of_one_membership_change_share_a_change_tag(spans, drive):
+    """``change`` is the dispatch journal's id (``utils/dispatch.py``): the
+    spans of one view change carry the same one, those outside carry 0, so a
+    trace and ``journal_snapshot()`` join on ``seq`` and group on ``change``."""
+    assert all("change" in tags for _, tags in spans[drive])
+    if drive != "streamed":
+        (change,) = {tags["change"] for _, tags in spans[drive]}
+        assert change > 0  # one commit: inject ... run_until_membership
+        return
+    by_wave, without = {}, []
+    for name, tags in spans["streamed"]:
+        if "wave" in tags:
+            by_wave.setdefault(tags["wave"], set()).add(tags["change"])
+        elif name == "rapid:inject_crash":
+            without.append(tags["change"])  # a wave's crash: the next wave's id
+        else:
+            assert name == "rapid:stream_fetch" and tags["change"] == 0  # constructor, drain
+    changes = [change for wave in sorted(by_wave) for change in by_wave[wave]]
+    assert len(changes) == 4 and all(len(ids) == 1 for ids in by_wave.values())  # one id a wave
+    assert changes == sorted(changes) and changes[0] > 0 and len(set(changes)) == 4
+    assert without == changes  # each wave's injection carries the wave's id
